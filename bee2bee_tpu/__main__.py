@@ -98,6 +98,12 @@ def _serve(backend: str, model: str, **kw):
     from .meshnet.runtime import run_p2p_node
 
     _setup_logging()
+    if backend == "tpu":
+        from .utils import enable_compile_cache
+
+        logging.getLogger("bee2bee_tpu").info(
+            "jax compile cache: %s", enable_compile_cache()
+        )
     cfg = _apply_common_cfg(load_config(), kw)
     try:
         asyncio.run(
@@ -257,8 +263,10 @@ def serve_stage(model, n_stages, stage, checkpoint, max_seq_len, quantize, **kw)
     --n-stages the stage loads immediately, otherwise the node waits for
     a coordinator's part_load."""
     from .meshnet.runtime import run_p2p_node
+    from .utils import enable_compile_cache
 
     _setup_logging()
+    enable_compile_cache()
     cfg = _apply_common_cfg(load_config(), kw)
 
     async def main():
@@ -502,6 +510,9 @@ def train(model, data_path, steps, batch_size, seq_len, lr, ckpt_dir, ckpt_every
         from .parallel.multihost import init_multihost
 
         init_multihost(coordinator, num_processes=num_hosts, process_id=host_id)
+    from .utils import enable_compile_cache
+
+    enable_compile_cache()
     from .datasets import PreprocessConfig, from_text_file
     from .engine.tokenizer import ByteTokenizer
     from .models.config import get_config

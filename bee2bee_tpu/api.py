@@ -539,19 +539,15 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
         return web.json_response(view)
 
     def _platform_stamp() -> str:
-        """Best-effort accelerator platform for /metrics/history, so
-        benchdiff --live can apply the PR 6 cross-platform refusal. Reads
-        jax only if something else already imported it — a control-plane
-        node must not pay a jax import for a telemetry stamp."""
-        import sys as _sys
-
-        jax = _sys.modules.get("jax")
-        if jax is not None:
-            try:
-                return jax.devices()[0].platform
-            except Exception:  # noqa: BLE001 — stamp is best-effort
-                pass
-        return "unknown"
+        """Platform of the devices this node's engine runs on (its mesh —
+        what engine.info reports) for /metrics/history, so benchdiff
+        --live can apply the cross-platform refusal. "none" for a node
+        that hosts no engine: it measures no device."""
+        for svc in node.local_services.values():
+            engine = getattr(svc, "engine", None)
+            if engine is not None:
+                return engine.introspect.platform
+        return "none"
 
     def _parse_history_query(request):
         """(names, window_s) shared by /metrics/history + /mesh/history;

@@ -1,8 +1,4 @@
-"""Version compatibility shims for the jax API surface.
-
-THE one place cross-version differences are absorbed — call sites use the
-newest API spelling and this module maps it onto older installs.
-"""
+"""jax API spellings shared across call sites."""
 
 from __future__ import annotations
 
@@ -10,32 +6,10 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map with the modern keyword surface on any jax.
-
-    Newer jax exports ``jax.shard_map`` (replication checking flag named
-    ``check_vma``); 0.4.x ships it as ``jax.experimental.shard_map`` with
-    the flag named ``check_rep``. The two flags mean the same thing ONLY
-    at the False setting (skip the static replication/varying-manual-axes
-    check) — which is therefore the default and what every caller uses;
-    the True settings differ in strictness across versions."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:
-            # transition-band jax: top-level shard_map exists but the
-            # flag is still named check_rep (the promotion landed before
-            # the rename) — wrapping raises TypeError immediately, so
-            # this fallback is hit at wrap time, not at trace time
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
+    """``jax.shard_map`` with the static replication check OFF by default
+    (jax's own default is on): every body here carries manual collectives
+    or a pallas_call the check cannot see through."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )

@@ -139,14 +139,32 @@ _G_OVERLAP = _REG.gauge(
 # ---------------------------------------------------------------- FLOPs model
 
 
+# bf16 peak FLOP/s of one chip, keyed by ``device_kind`` exactly as jax
+# reports it (jax knows two spellings for the lite parts). Source: Google
+# Cloud TPU documentation, "System architecture" page of each version.
+TPU_PEAK_BF16_FLOPS = {
+    "TPU v2": 45e12,
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,  # v5p
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,  # v6e
+    "TPU v6e": 918e12,
+}
+
+
 def peak_flops_per_device(platform: str, device_kind: str = "") -> float:
     """Peak dense FLOP/s for one device, for the MFU denominator.
 
     ``BEE2BEE_PEAK_FLOPS`` (per device) overrides everything — the only
-    honest number for exotic parts. The TPU table is bf16 peak per chip
-    (public spec sheets); the CPU value is a NOMINAL placeholder so the
-    gauge exists on dev boxes — CPU "MFU" is a proxy number, never a
-    hardware claim (docs/OBSERVABILITY.md)."""
+    honest number for a part the table lacks. An accelerator that is not
+    in the table is an ERROR, never a default: a utilization over a
+    guessed peak is a wrong number with a real device's name on it. The
+    CPU value is a NOMINAL placeholder so the gauge exists on dev boxes —
+    CPU "MFU" is a proxy number, never a hardware claim
+    (docs/OBSERVABILITY.md)."""
     env = os.environ.get("BEE2BEE_PEAK_FLOPS")
     if env:
         try:
@@ -155,18 +173,15 @@ def peak_flops_per_device(platform: str, device_kind: str = "") -> float:
                 return v
         except ValueError:
             logger.warning("BEE2BEE_PEAK_FLOPS=%r is not a number", env)
-    kind = (device_kind or "").lower()
-    if platform == "tpu":
-        for pat, peak in (
-            ("v6", 918e12), ("v5p", 459e12), ("v5", 197e12),  # v5e/lite
-            ("v4", 275e12), ("v3", 123e12), ("v2", 45e12),
-        ):
-            if pat in kind:
-                return peak
-        return 197e12  # unknown TPU: the v5e figure bench.py already uses
-    if platform == "gpu":
-        return 1e14  # nominal; set BEE2BEE_PEAK_FLOPS for real numbers
-    return 1e11  # nominal CPU placeholder (proxy MFU only)
+    if platform == "cpu":
+        return 1e11  # nominal CPU placeholder (proxy MFU only)
+    if platform == "tpu" and device_kind in TPU_PEAK_BF16_FLOPS:
+        return TPU_PEAK_BF16_FLOPS[device_kind]
+    raise ValueError(
+        f"no peak FLOP/s known for platform={platform!r} "
+        f"device_kind={device_kind!r}: add it to TPU_PEAK_BF16_FLOPS with "
+        "its source, or set BEE2BEE_PEAK_FLOPS (per device)"
+    )
 
 
 class FlopsModel:
@@ -431,13 +446,14 @@ class RetraceSentinel:
 # ---------------------------------------------------------------- HBM ledger
 
 
-def _tree_device_bytes(tree) -> int:
-    """Per-process live bytes of a pytree of (possibly sharded) arrays:
-    the sum of each leaf's addressable shard buffers — replicated leaves
-    count once per local device holding them, which IS the HBM truth."""
+def _tree_bytes_by_device(tree) -> dict:
+    """{device id: live bytes} of a pytree of (possibly sharded) arrays:
+    each leaf's addressable shard buffers, booked to the device that
+    holds them — replicated leaves count once per local device holding
+    them, which IS the HBM truth. Host (numpy) leaves book under None."""
     import jax
 
-    total = 0
+    out: dict = {}
     for leaf in jax.tree.leaves(tree):
         try:
             # even the attribute READ raises on a donated/deleted array —
@@ -445,14 +461,15 @@ def _tree_device_bytes(tree) -> int:
             # must count as 0 bytes, not break the whole snapshot
             shards = getattr(leaf, "addressable_shards", None)
             if shards:
-                total += sum(s.data.nbytes for s in shards)
+                for sh in shards:
+                    out[sh.device.id] = out.get(sh.device.id, 0) + sh.data.nbytes
                 continue
             nbytes = getattr(leaf, "nbytes", None)
             if nbytes:
-                total += int(nbytes)
+                out[None] = out.get(None, 0) + int(nbytes)
         except Exception:  # noqa: BLE001 — deleted buffers count as 0
             continue
-    return total
+    return out
 
 
 class HbmLedger:
@@ -485,37 +502,40 @@ class HbmLedger:
         with self._lock:
             self._sources.clear()
 
-    def _device_stats(self) -> tuple[int | None, int | None]:
-        """(bytes_in_use, bytes_limit) across this process's devices, or
-        (None, None) when the backend has no memory stats (CPU). An env
-        ``BEE2BEE_HBM_BYTES`` budget substitutes for the limit so
+    def _device_stats(self) -> tuple[list[dict], int | None]:
+        """(per-device memory stats, bytes_limit across them). The list
+        has one entry per device of this ledger whose backend reports
+        ``memory_stats()`` (TPU does; CPU returns None — empty list). An
+        env ``BEE2BEE_HBM_BYTES`` budget substitutes for the limit so
         headroom still computes on stats-less backends."""
         import jax
 
         devices = self._devices
         if devices is None:
             devices = jax.local_devices()
-        in_use = limit = 0
-        seen = False
+        per_device = []
         for d in devices:
-            try:
-                st = d.memory_stats()
-            except Exception:  # noqa: BLE001
-                st = None
+            st = d.memory_stats()
             if not st:
                 continue
-            seen = True
-            in_use += int(st.get("bytes_in_use") or 0)
-            limit += int(st.get("bytes_limit") or st.get("bytes_reservable_limit") or 0)
-        if seen:
-            return in_use, (limit or None)
+            per_device.append({
+                "id": d.id,
+                "bytes_in_use": int(st.get("bytes_in_use") or 0),
+                "peak_bytes_in_use": int(st.get("peak_bytes_in_use") or 0),
+                "bytes_limit": int(
+                    st.get("bytes_limit")
+                    or st.get("bytes_reservable_limit") or 0
+                ),
+            })
+        if per_device:
+            return per_device, (sum(d["bytes_limit"] for d in per_device) or None)
         env = os.environ.get("BEE2BEE_HBM_BYTES")
         if env:
             try:
-                return None, int(float(env))
+                return [], int(float(env))
             except ValueError:
                 pass
-        return None, None
+        return [], None
 
     def snapshot(self) -> dict:
         """Never-throw: a ledger read must not take down a scrape."""
@@ -529,20 +549,36 @@ class HbmLedger:
         with self._lock:
             sources = dict(self._sources)
         components: dict[str, int] = {}
+        by_device: dict = {}  # device id -> {component: bytes}
         for name, src in sources.items():
             try:
                 tree = src()
             except Exception:  # noqa: BLE001 — a torn-down engine reads 0
                 tree = None
-            components[name] = _tree_device_bytes(tree) if tree is not None else 0
+            placed = _tree_bytes_by_device(tree) if tree is not None else {}
+            components[name] = sum(placed.values())
+            for dev_id, b in placed.items():
+                if dev_id is not None:
+                    by_device.setdefault(dev_id, {})[name] = b
         accounted = sum(components.values())
-        in_use, limit = self._device_stats()
+        per_device, limit = self._device_stats()
+        in_use = sum(d["bytes_in_use"] for d in per_device) if per_device else None
         out: dict = {
             "components": components,
             "accounted_bytes": accounted,
         }
         for name, b in components.items():
             _G_HBM_BYTES.set(b, component=name)
+        # the per-device view (engine.info; not gossiped): what each chip
+        # holds of each component, beside the backend's own stats for it —
+        # a tensor-parallel model must spread, not pile onto device 0
+        stats_by_id = {d["id"]: d for d in per_device}
+        if by_device or per_device:
+            out["devices"] = [
+                {"id": i, "components": by_device.get(i, {}),
+                 **{k: v for k, v in stats_by_id.get(i, {}).items() if k != "id"}}
+                for i in sorted(set(by_device) | set(stats_by_id))
+            ]
         if in_use is not None:
             out["bytes_in_use"] = in_use
             # XLA workspace, fragmentation, and whatever we don't track
@@ -1009,18 +1045,17 @@ class EngineIntrospection:
     point; ``close()`` unhooks the engine from the digest provider."""
 
     def __init__(self, model_cfg, mesh=None, peak_flops: float | None = None):
-        platform = "cpu"
-        kind = ""
-        try:
-            if mesh is not None:
-                dev = mesh.devices.flat[0]
-                platform, kind = dev.platform, dev.device_kind
-            n_dev = mesh.devices.size if mesh is not None else 1
-        except Exception:  # noqa: BLE001
-            n_dev = 1
+        # the MESH's own devices, never jax.devices(): an explicit CPU
+        # mesh on a TPU-default host is a CPU engine
+        platform, kind, n_dev = "cpu", "", 1
+        if mesh is not None:
+            dev = mesh.devices.flat[0]
+            platform, kind, n_dev = dev.platform, dev.device_kind, mesh.devices.size
         if peak_flops is None:
             peak_flops = peak_flops_per_device(platform, kind) * n_dev
         self.platform = platform
+        self.device_kind = kind
+        self.device_count = int(n_dev)
         self.sentinel = RetraceSentinel()
         self.ledger = HbmLedger(
             devices=list(mesh.devices.flat) if mesh is not None else None

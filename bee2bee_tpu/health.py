@@ -255,6 +255,20 @@ def register_digest_provider(key: str, fn: Callable[[], dict | None]) -> None:
     _DIGEST_PROVIDERS[key] = fn
 
 
+def swap_digest_providers(
+    providers: dict[str, Callable[[], dict | None]],
+) -> dict[str, Callable[[], dict | None]]:
+    """Replace the provider table; returns the previous one (the
+    ``set_clock`` shape). The simulation harness runs engine-less control
+    planes with an EMPTY table: a provider reads live engines of the whole
+    process on the wall clock, and one left running by earlier work would
+    leak wall-time digits into frames a replay must reproduce byte for
+    byte."""
+    global _DIGEST_PROVIDERS
+    prev, _DIGEST_PROVIDERS = _DIGEST_PROVIDERS, dict(providers)
+    return prev
+
+
 def run_digest_providers() -> dict[str, dict]:
     """Every provider's current payload (never-throw per provider). Also
     the scrape-time gauge-refresh hook: api.py calls this at /metrics so

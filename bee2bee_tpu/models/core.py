@@ -41,7 +41,11 @@ def _dense_init(key, shape, scale=None, dtype=jnp.float32):
     # fan-in is the second-to-last dim: layer-stacked weights are [L, in, out]
     fan_in = shape[-2] if len(shape) > 1 else shape[0]
     scale = scale if scale is not None else 1.0 / math.sqrt(fan_in)
-    return jax.random.normal(key, shape, dtype) * scale
+    # the barrier keeps the scale multiply out of the sampler's fusion, so
+    # the values are bit-identical whether this runs eagerly or inside
+    # init_params' one jitted program (checked on the CPU backend; seeded
+    # tests pin near-tie greedy tokens to these exact weights)
+    return lax.optimization_barrier(jax.random.normal(key, shape, dtype)) * scale
 
 
 def matmul_params_per_token(cfg: ModelConfig) -> int:
@@ -70,8 +74,15 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     return L * (attn + mlp) + D * cfg.vocab_size
 
 
-def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Params:
+def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
+                out_shardings=None) -> Params:
     """Random-init params with the layout the whole framework shares.
+
+    The init runs as ONE jitted program, so with ``out_shardings`` (a
+    matching pytree of shardings — partition.param_shardings over
+    ``jax.eval_shape`` of this function) every device generates only its
+    own shard: a 7B model on a model:4 mesh never lands whole on device 0.
+    The values depend on (cfg, key, dtype) alone, not on the sharding.
 
     Schema (leading L = n_layers stacked dim):
       tok_embed [V, D]; pos_embed [P, D] (learned-pos only);
@@ -87,6 +98,15 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16) -> Params:
         moe: router [L, D, E], experts w_up|w_gate [L, E, D, F],
              w_down [L, E, F, D]
     """
+    # cfg and dtype are static arguments of the one module-level function,
+    # so repeated inits of a config reuse its trace (a fresh partial or
+    # lambda per call would retrace every time)
+    return jax.jit(
+        _init_params, static_argnums=(0, 2), out_shardings=out_shardings
+    )(cfg, key, jnp.dtype(dtype))
+
+
+def _init_params(cfg: ModelConfig, key, dtype) -> Params:
     D, F, V, L = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_layers
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     keys = iter(jax.random.split(key, 32))

@@ -123,11 +123,12 @@ def kv_replicated(cfg: ModelConfig, mesh: Mesh) -> bool:
 _KV_PARAM_SUFFIXES = ("attn/wk", "attn/wv", "attn/bk", "attn/bv")
 
 
-def shard_params(params, mesh: Mesh, cfg: ModelConfig | None = None):
-    """Place params onto the mesh per the rules (host → device transfer).
-    Params whose sharded dim doesn't divide the mesh axis (e.g. gpt2's prime
-    vocab on tok_embed/lm_head) are replicated instead. With `cfg` given,
-    MQA models replicate the K/V projections (see kv_replicated)."""
+def param_shardings(params, mesh: Mesh, cfg: ModelConfig | None = None):
+    """Pytree of NamedSharding matching `params` (arrays or
+    ShapeDtypeStructs — only shapes are read) per the rules. Params whose
+    sharded dim doesn't divide the mesh axis (e.g. gpt2's prime vocab on
+    tok_embed/lm_head) are replicated instead. With `cfg` given, MQA
+    models replicate the K/V projections (see kv_replicated)."""
     specs = partition_specs(params)
     if cfg is not None and kv_replicated(cfg, mesh):
         specs = jax.tree_util.tree_map_with_path(
@@ -138,7 +139,8 @@ def shard_params(params, mesh: Mesh, cfg: ModelConfig | None = None):
             ),
             specs,
         )
-    def place(leaf, spec):
+
+    def sharding(leaf, spec):
         t = tuple(spec)
         if len(t) > getattr(leaf, "ndim", 0):
             # rules are written against STACKED [L, ...] weights; unstacked
@@ -153,11 +155,15 @@ def shard_params(params, mesh: Mesh, cfg: ModelConfig | None = None):
                     f"leaf: would drop sharded axes {drop}"
                 )
         spec = P(*t)
-        return jax.device_put(
-            leaf, NamedSharding(mesh, spec if _fits(leaf, spec, mesh) else P())
-        )
+        return NamedSharding(mesh, spec if _fits(leaf, spec, mesh) else P())
 
-    return jax.tree.map(place, params, specs)
+    return jax.tree.map(sharding, params, specs)
+
+
+def shard_params(params, mesh: Mesh, cfg: ModelConfig | None = None):
+    """Place params onto the mesh per the rules (host → device transfer:
+    each device receives only its own shard of a host array)."""
+    return jax.device_put(params, param_shardings(params, mesh, cfg))
 
 
 def cache_spec(

@@ -7,9 +7,12 @@ native data plane where it pays: content-hashing model-weight pieces.
 serially — the C++ codec hashes all pieces of a checkpoint across cores
 in one call.
 
-Degrades gracefully: if the shared object is missing we try one quiet
-`make` (g++ is in the image); if that fails, every function falls back
-to hashlib so the framework never hard-requires the native build.
+The shared object is a build product (git-ignored): the first use in a
+process runs one quiet `make`, which builds it when it is missing OR
+older than its sources — a binary left over from an earlier checkout is
+never loaded in place of the code that is there. If the build fails and
+no binary exists, every function falls back to hashlib so the framework
+never hard-requires the native build.
 """
 
 from __future__ import annotations
@@ -30,6 +33,7 @@ _load_attempted = False
 
 
 def _try_build() -> bool:
+    """`make` the codec (a no-op when it is newer than its sources)."""
     if not (_NATIVE_DIR / "Makefile").exists():
         return False
     try:
@@ -52,7 +56,7 @@ def _load():
     _load_attempted = True
     if os.environ.get("BEE2BEE_DISABLE_NATIVE", "").lower() in ("1", "true", "yes"):
         return None
-    if not _SO_PATH.exists() and not _try_build():
+    if not _try_build() and not _SO_PATH.exists():
         logger.info("native codec unavailable; using hashlib fallback")
         return None
     try:
@@ -84,9 +88,9 @@ def _load():
         lib.b2b_sha256_accelerated.restype = ctypes.c_int
         _lib = lib
     except (OSError, AttributeError) as e:
-        # AttributeError = a stale prebuilt .so missing a newer symbol
-        # (the file is gitignored, so it survives source updates); degrade
-        # to hashlib rather than crashing every entry point
+        # AttributeError = a prebuilt .so missing a newer symbol that
+        # could not be rebuilt (no make/g++ here); degrade to hashlib
+        # rather than crashing every entry point
         logger.warning(
             "failed to load native codec (%s); falling back to hashlib — "
             "run `make -C native clean all` to rebuild", e

@@ -5,8 +5,9 @@ The reference has zero native/kernel code (SURVEY §2 native inventory:
 flash attention tiles that keep the MXU fed from VMEM instead of
 materializing [T, S] score matrices in HBM.
 
-Kernels auto-fall back to interpret mode off-TPU, so the whole test
-suite exercises them on the CPU mesh.
+On devices that are not TPUs the kernels run in pallas interpret mode
+(ops/flash.interpret_off_tpu), so the whole test suite exercises them on
+the CPU mesh.
 """
 
 from .flash import flash_attention  # noqa: F401

@@ -12,8 +12,9 @@ Design notes (pallas_guide.md patterns):
 - `offset` rides SMEM as a [1,1] scalar so the SAME compiled kernel
   serves prefill (offset=0 mask within the chunk) and cached decode
   (queries live at positions offset..offset+T).
-- Off-TPU the kernels run in pallas interpret mode — the CPU test suite
-  exercises the exact kernel code path.
+- On devices that are not TPUs the kernels run in pallas interpret mode
+  (`interpret_off_tpu`) — the CPU test suite exercises the exact kernel
+  code path. On a TPU a kernel compiles or raises.
 
 Replaces the dense [B,H,T,S] score materialization of models/core
 ._attention on the hot path (engine flag attention="flash").
@@ -33,11 +34,15 @@ NEG_INF = -1e30
 _LANES = 128  # m/l scratch lane padding (min f32 tile is (8, 128))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def interpret_off_tpu(mesh=None) -> bool:
+    """Should a kernel call run in pallas interpret mode? Only where the
+    devices it runs on cannot take a Mosaic kernel: the mesh's devices
+    when the caller has a mesh, else the default backend's (where an
+    unplaced jit runs). A failure to reach the backend propagates — it
+    must never read as "not a TPU" and quietly select the interpreter."""
+    if mesh is not None:
+        return mesh.devices.flat[0].platform != "tpu"
+    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------- prefill
@@ -144,7 +149,7 @@ def flash_attention(
     S, Hkv = k.shape[1], k.shape[2]
     group = H // Hkv
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_off_tpu() if interpret is None else interpret
 
     block_q = min(block_q, max(T, 8))
     block_k = min(block_k, max(S, 8))

@@ -62,9 +62,9 @@ the pool:
   pool capacity. The f32 m/l/acc scratch already isolates accumulation
   from storage precision, so the quantized path changes no softmax math.
 
-Off-TPU the kernel runs in pallas interpret mode (the `_on_tpu()` /
-`interpret` pattern from ops/flash.py), so the CPU test suite exercises
-the exact kernel code path.
+On devices that are not TPUs the kernel runs in pallas interpret mode
+(ops/flash.interpret_off_tpu), so the CPU test suite exercises the exact
+kernel code path; on a TPU it compiles or raises.
 """
 
 from __future__ import annotations
@@ -78,7 +78,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..compat import shard_map
-from .flash import NEG_INF, _LANES, _on_tpu, validate_flash_mesh
+from .flash import NEG_INF, _LANES, interpret_off_tpu, validate_flash_mesh
 
 
 def _ragged_kernel(
@@ -217,7 +217,7 @@ def ragged_paged_attention(
     MB = block_tables.shape[1]
     G = H // Hkv
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_off_tpu() if interpret is None else interpret
     quantized = k_scale is not None
     if quantized and v_scale is None:
         raise ValueError("quantized pool needs BOTH k_scale and v_scale")
@@ -323,19 +323,24 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
     replicates. The pool's block/slot dims never shard here — any row
     gathers arbitrary blocks (partition.paged_cache_spec).
 
-    Called WITHOUT block tables (a no-cache forward that still passes an
-    attn_fn), it falls back to the dense reference — correctness over
-    speed on a path that never serves decode (`mask` is a REAL bool mask
-    there; core.forward only swaps in the window selector on the
-    block-tables path).
+    ``interpret=None`` resolves from the MESH's devices
+    (interpret_off_tpu), once, here. Called WITHOUT block tables it
+    raises: the serving path always passes them (every engine root runs
+    over the paged pool), and a quiet dense stand-in would let a run
+    that asked for the kernel pass without it.
     """
     from jax.sharding import PartitionSpec as P
 
+    if interpret is None:
+        interpret = interpret_off_tpu(mesh)
+
     def attn(q, k, v, mask, cfg, positions=None, block_tables=None):
         if block_tables is None:
-            from ..models.core import _attention
-
-            return _attention(q, k, v, mask, cfg)
+            raise ValueError(
+                "the ragged paged-attention attn_fn needs block tables "
+                "(a paged pool); pass attn_fn=None for a cache-less or "
+                "rectangular-cache forward"
+            )
         # int8 pool: the kv_hook hands (pool slice, scale slice) pairs
         # through — unpack them here so the kernel dequants in-loop
         k_scale = v_scale = None

@@ -35,6 +35,7 @@ import json
 from typing import Any
 
 from ..clock import set_clock
+from ..health import swap_digest_providers
 from ..meshnet.chaos import hard_kill
 from ..meshnet.node import P2PNode
 from ..metrics import get_registry
@@ -117,6 +118,7 @@ class FleetSim:
         self.nodes: list[P2PNode] = []
         self.dead: set[str] = set()
         self._prev_clock = None
+        self._prev_providers: dict = {}
         self._started = False
 
     # ------------------------------------------------------------ build
@@ -154,6 +156,9 @@ class FleetSim:
 
     async def start(self, bootstrap: bool = True) -> "FleetSim":
         self._prev_clock = set_clock(self.clock)
+        # engine-less control planes: no live-engine digest blocks (they
+        # read the wall clock — see health.swap_digest_providers)
+        self._prev_providers = swap_digest_providers({})
         self._started = True
         # zero shared-registry counters: telemetry digests carry their
         # values, and a replay must produce the same frame bytes
@@ -177,6 +182,7 @@ class FleetSim:
         await self.clock.settle()
         self._started = False
         set_clock(self._prev_clock)
+        swap_digest_providers(self._prev_providers)
 
     async def __aenter__(self) -> "FleetSim":
         return await self.start()

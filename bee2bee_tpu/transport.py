@@ -62,7 +62,10 @@ class WebsocketsTransport(Transport):
     name = "websockets"
 
     def __init__(self):
-        import websockets  # noqa: F401 — hard dependency of this backend
+        import websockets  # hard dependency of this backend
+        # the submodule is NOT an attribute of the package until imported
+        # (websockets >= 14 loads its public names lazily)
+        import websockets.exceptions
 
         self._ws = websockets
         self.exceptions = websockets.exceptions

@@ -41,6 +41,31 @@ def data_file(name: str) -> Path:
     return bee2bee_home() / name
 
 
+def compile_cache_dir() -> str:
+    """Where this checkout's processes keep jax's persistent compilation
+    cache — THE one place the directory is chosen for the serve, bench
+    and smoke paths. ``JAX_COMPILATION_CACHE_DIR`` when it is set, else
+    ONE fixed path beside the package (``<checkout>/.jax_cache``,
+    git-ignored): the directory is part of the cache key, so a temporary,
+    per-pid or dated directory would never hit. Touches no jax."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        Path(__file__).resolve().parent.parent / ".jax_cache"
+    )
+
+
+def enable_compile_cache() -> str:
+    """Give this process the persistent compilation cache (call before
+    the first jit); returns the directory in use. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax already uses it, and nothing
+    is set here."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def save_json(path: Path | str, obj: Any) -> None:
     """Atomic JSON write: tmp file + os.replace (reference utils.py:37-40)."""
     path = Path(path)

@@ -53,7 +53,7 @@ _WATCH_KEY_RE = re.compile(
     r"|^mfu$)"
 )
 # context keys that are measurements but not perf gates (counts, sizes)
-_SKIP_SUBTREES = ("telemetry", "chip_watch", "introspect")
+_SKIP_SUBTREES = ("telemetry", "introspect")
 
 KNOWN_SCHEMA_MAJOR = 2
 

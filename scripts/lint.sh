@@ -26,8 +26,8 @@ if [ "${SKIP_BENCHDIFF:-0}" != "1" ]; then
   # 6x-scale, far outside it. Cross-platform runs exit 2 = refused,
   # which is a skip, not a failure (benchdiff's own contract).
   echo "[lint] decode_hotloop rung vs BENCH_decode_hotloop_r01.json"
-  FRESH="$(mktemp /tmp/decode_hotloop.XXXXXX.json)"
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" BEE2BEE_BENCH_NO_PROBE=1 \
+  FRESH="$(mktemp "${TMPDIR:-/tmp}/decode_hotloop.XXXXXX.json")"
+  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     "$PY" bench.py decode_hotloop | tail -1 > "$FRESH"
   rc=0
   "$PY" scripts/benchdiff.py BENCH_decode_hotloop_r01.json "$FRESH" \
@@ -46,8 +46,8 @@ if [ "${SKIP_BENCHDIFF:-0}" != "1" ]; then
   # Threshold 0.5: the metric multiplies tok/s by acceptance, so shared-
   # CPU noise compounds; the off/ngram cells this must beat sit at ~0.
   echo "[lint] spec_model rung vs BENCH_spec_model_r01.json"
-  FRESH="$(mktemp /tmp/spec_model.XXXXXX.json)"
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" BEE2BEE_BENCH_NO_PROBE=1 \
+  FRESH="$(mktemp "${TMPDIR:-/tmp}/spec_model.XXXXXX.json")"
+  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     "$PY" bench.py spec_model | tail -1 > "$FRESH"
   rc=0
   "$PY" scripts/benchdiff.py BENCH_spec_model_r01.json "$FRESH" \
@@ -68,7 +68,7 @@ if [ "${SKIP_BENCHDIFF:-0}" != "1" ]; then
   # a sampler regression big enough to matter at the production cadence
   # would crater the compressed-cadence ratio far past it.
   echo "[lint] obs_overhead rung vs BENCH_obs_overhead_r01.json"
-  FRESH="$(mktemp /tmp/obs_overhead.XXXXXX.json)"
+  FRESH="$(mktemp "${TMPDIR:-/tmp}/obs_overhead.XXXXXX.json")"
   "$PY" bench.py obs_overhead | tail -1 > "$FRESH"
   rc=0
   "$PY" scripts/benchdiff.py BENCH_obs_overhead_r01.json "$FRESH" \

@@ -1,10 +1,14 @@
 """Test fixtures. The 8-device virtual CPU mesh is enforced by the ROOT
-conftest (/root/repo/conftest.py), which re-execs pytest with the right env
+conftest (../conftest.py), which re-execs pytest with the right env
 before fd capture starts; here we only verify it took effect."""
 
 import os
+import tempfile
 
-os.environ.setdefault("BEE2BEE_TPU_HOME", "/tmp/bee2bee_tpu_test_home")
+os.environ.setdefault(
+    "BEE2BEE_TPU_HOME",
+    os.path.join(tempfile.gettempdir(), "bee2bee_tpu_test_home"),
+)
 
 import asyncio  # noqa: E402
 import inspect  # noqa: E402
@@ -25,7 +29,6 @@ import pytest  # noqa: E402
 try:  # pragma: no cover - environment-dependent
     import atexit  # noqa: E402
     import shutil  # noqa: E402
-    import tempfile  # noqa: E402
 
     import jax
 
@@ -36,10 +39,7 @@ try:  # pragma: no cover - environment-dependent
         atexit.register(shutil.rmtree, _cache_base, ignore_errors=True)
     jax.config.update("jax_compilation_cache_dir", _cache_base)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        pass  # older jax: flag absent, executables still cached
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
 
     # Quarantine of the pre-existing XLA segfault (CHANGES.md PR 12
     # note): ~545 tests into a tier-1 run this container died at rc=139

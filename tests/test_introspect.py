@@ -281,6 +281,25 @@ def test_peak_flops_env_override_and_tpu_table(monkeypatch):
     assert peak_flops_per_device("tpu", "TPU v3") == pytest.approx(123e12)
 
 
+def test_peak_flops_table_is_keyed_by_jax_device_kind_and_unknown_is_an_error(
+    monkeypatch,
+):
+    """The table matches `device_kind` AS JAX REPORTS IT (a v5e chip says
+    "TPU v5 lite"), and an accelerator it lacks raises — a utilization
+    over a guessed peak is a wrong number with a real device's name on
+    it. The CPU keeps its nominal placeholder (proxy MFU only)."""
+    monkeypatch.delenv("BEE2BEE_PEAK_FLOPS", raising=False)
+    assert peak_flops_per_device("tpu", "TPU v5 lite") == pytest.approx(197e12)
+    assert peak_flops_per_device("tpu", "TPU v6 lite") == pytest.approx(918e12)
+    assert peak_flops_per_device("cpu", "cpu") == pytest.approx(1e11)
+    for platform, kind in (("tpu", "TPU v9"), ("tpu", ""), ("gpu", "H100")):
+        with pytest.raises(ValueError, match="no peak FLOP/s known"):
+            peak_flops_per_device(platform, kind)
+    # the env override is the escape hatch for a part the table lacks
+    monkeypatch.setenv("BEE2BEE_PEAK_FLOPS", "1e15")
+    assert peak_flops_per_device("gpu", "H100") == pytest.approx(1e15)
+
+
 # ---------------------------------------------------------- goodput meter
 
 
@@ -319,6 +338,8 @@ def test_hbm_ledger_components_sum_and_unregister_clears(monkeypatch):
     monkeypatch.delenv("BEE2BEE_HBM_BYTES", raising=False)
 
     class _Dev:  # a stats-less device (CPU contract)
+        id = 0
+
         def memory_stats(self):
             return None
 
@@ -347,6 +368,8 @@ def test_hbm_ledger_components_sum_and_unregister_clears(monkeypatch):
 
 def test_hbm_ledger_device_stats_add_workspace_residual():
     class _Dev:
+        id = 0
+
         def memory_stats(self):
             return {"bytes_in_use": 1000, "bytes_limit": 4000}
 
@@ -356,6 +379,29 @@ def test_hbm_ledger_device_stats_add_workspace_residual():
     assert snap["bytes_in_use"] == 1000
     assert snap["components"]["workspace_other"] == 900
     assert snap["headroom_frac"] == pytest.approx(0.75)
+
+
+def test_hbm_ledger_reports_each_device_separately():
+    """Tensor-parallel serving must spread, not pile onto device 0: the
+    snapshot books every component's shard bytes to the device holding
+    them, beside that device's own memory stats."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bee2bee_tpu.parallel import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(model=4))
+    devices = list(mesh.devices.flat)
+    w = jax.device_put(
+        np.zeros((8, 64), np.float32), NamedSharding(mesh, P(None, "model"))
+    )  # 2048 B, a quarter per device
+    rep = jax.device_put(np.zeros((16,), np.float32), NamedSharding(mesh, P()))
+    ledger = HbmLedger(devices=devices)
+    ledger.register("weights", lambda: {"w": w, "rep": rep})
+    snap = ledger.snapshot()
+    assert snap["components"]["weights"] == 2048 + 4 * 64
+    assert [d["id"] for d in snap["devices"]] == sorted(d.id for d in devices)
+    assert all(d["components"] == {"weights": 512 + 64} for d in snap["devices"])
 
 
 def test_pool_forecast_eta_projection():

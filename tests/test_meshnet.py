@@ -277,3 +277,49 @@ def test_parse_dht_bootstrap():
     import pytest as _pytest
     with _pytest.raises(ValueError, match="invalid port"):
         _parse_dht_bootstrap("10.0.0.5:84O8")
+
+
+# ------------------------------------------------------ the Transport seam
+
+
+def test_default_transport_is_real_websockets_in_a_fresh_interpreter():
+    """websockets >= 14 loads `websockets.exceptions` only on explicit
+    import: in a process where nothing else imported it first, building
+    the default transport used to die with AttributeError (and every CLI
+    entry point with it). A fresh interpreter is the only honest check —
+    in this process some earlier test has long imported the submodule."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from bee2bee_tpu.transport import default_transport\n"
+        "assert 'websockets.exceptions' not in sys.modules\n"
+        "t = default_transport()\n"
+        "assert t.name == 'websockets', t.name\n"
+        "assert issubclass(t.exceptions.ConnectionClosed, Exception)\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_default_transport_does_not_swallow_non_import_errors(monkeypatch):
+    """Only a MISSING package selects the loopback shim; a package that is
+    there and breaks must surface, not quietly change the wire."""
+    from bee2bee_tpu import transport
+
+    def broken(self):
+        raise AttributeError("module 'websockets' has no attribute 'exceptions'")
+
+    monkeypatch.setattr(transport, "_DEFAULT", None)
+    monkeypatch.setattr(transport.WebsocketsTransport, "__init__", broken)
+    with pytest.raises(AttributeError):
+        transport.default_transport()
+
+    def missing(self):
+        raise ImportError("No module named 'websockets'")
+
+    monkeypatch.setattr(transport.WebsocketsTransport, "__init__", missing)
+    assert transport.default_transport().name == "loopback"

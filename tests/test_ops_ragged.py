@@ -215,6 +215,31 @@ def test_ragged_int8_pool_dequant_matches_dense_on_dequantized_view():
         _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
+def test_ragged_attn_fn_needs_block_tables_and_reads_interpret_from_mesh():
+    """No quiet stand-ins on the kernel path: called without block tables
+    the ragged attn_fn raises (it used to become the dense reference), and
+    interpret mode is decided from the devices the call runs on — the
+    mesh's — never from an exception swallowed while asking."""
+    import types
+
+    from bee2bee_tpu.ops.flash import interpret_off_tpu
+    from bee2bee_tpu.ops.ragged import make_ragged_attn_fn
+
+    attn = make_ragged_attn_fn(None)
+    q = jnp.zeros((1, 1, 4, 16))
+    with pytest.raises(ValueError, match="needs block tables"):
+        attn(q, q, q, None, get_config("tiny-llama"))
+
+    def fake_mesh(platform):
+        return types.SimpleNamespace(
+            devices=np.array([types.SimpleNamespace(platform=platform)])
+        )
+
+    assert interpret_off_tpu(fake_mesh("cpu")) is True
+    assert interpret_off_tpu(fake_mesh("tpu")) is False
+    assert interpret_off_tpu() is True  # the suite's default backend is the CPU
+
+
 def test_ragged_int8_requires_both_scales():
     q, kp, vp, tb, off, *_ = _pool_case(offs=[4], T=1, H=4, Hkv=2, hd=16)
     kq, ks, _vq, _vs = _quantize_pool(kp, vp)

@@ -63,6 +63,44 @@ def test_sharded_params_actually_distributed():
     assert shard_shapes == {(full[0], full[1], full[2] // 2)}
 
 
+def test_random_init_is_generated_sharded_and_equals_the_unsharded_init():
+    """Seeded random weights are created under jit with the partition
+    rules as out_shardings: every device generates only its own shard (a
+    7B model on model:4 never lands whole on device 0), and the values
+    depend on the seed alone, not on the sharding — one-chip and TP
+    engines built from one seed hold the same model."""
+    cfg = get_config("tiny-llama")
+    mesh = build_mesh(MeshSpec(model=2))
+    key = jax.random.key(3)
+    plain = core.init_params(cfg, key)
+    shapes = jax.eval_shape(lambda: core.init_params(cfg, key))
+    shardings = partition.param_shardings(shapes, mesh, cfg)
+    sharded = core.init_params(cfg, key, out_shardings=shardings)
+    wq = sharded["layers"]["attn"]["wq"]
+    assert wq.sharding.spec == P(None, None, "model")
+    assert {s.data.shape[2] for s in wq.addressable_shards} == {wq.shape[2] // 2}
+    same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), plain, sharded)
+    assert all(jax.tree.leaves(same))
+    # the engine's own random init goes through it
+    eng = InferenceEngine(
+        cfg, mesh=mesh,
+        engine_config=EngineConfig(max_seq_len=64, rng_seed=3),
+    )
+    try:
+        ewq = eng.params["layers"]["attn"]["wq"]
+        assert ewq.sharding.spec == P(None, None, "model")
+        assert bool(jnp.array_equal(ewq, plain["layers"]["attn"]["wq"]))
+        # engine.info reports the mesh's own devices, per device
+        info = eng.info
+        assert (info["platform"], info["device_count"]) == ("cpu", 2)
+        assert info["device_kind"] == mesh.devices.flat[0].device_kind
+        per_dev = info["introspect"]["hbm"]["devices"]
+        assert len(per_dev) == 2
+        assert per_dev[0]["components"]["weights"] == per_dev[1]["components"]["weights"]
+    finally:
+        eng.close()
+
+
 def test_moe_expert_parallel_matches_single_device():
     cfg = get_config("tiny-mixtral")
     mesh = build_mesh(MeshSpec(expert=4, model=2))
