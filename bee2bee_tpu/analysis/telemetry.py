@@ -9,11 +9,12 @@ cardinality explosion). Request-varying data belongs in span ATTRS or
 metric LABELS (which are themselves chosen from bounded sets), never in
 the name.
 
-- ML-T001 — the name argument of a ``span(...)`` / ``annotate(...)`` /
+- ML-T001 — the name argument of a ``span(...)`` / ``phase(...)`` /
   ``counter(...)`` / ``gauge(...)`` / ``histogram(...)`` call is built
   dynamically: an f-string, a ``%`` / ``+`` expression, or ``.format()``.
-  Plain variables pass (a forwarding helper like ``tracing.annotate`` is
-  fine — the literal lives at ITS call site and is checked there).
+  Plain variables pass (a forwarding helper like the scheduler's
+  ``_phase`` decorator is fine — the literal lives at ITS call site and
+  is checked there, which is why ``_phase`` is a name call too).
 
 Scope: the whole package — telemetry calls live in engine/, meshnet/,
 services/, web/ and api.py alike.
@@ -23,11 +24,10 @@ from __future__ import annotations
 
 import ast
 
-# call targets whose first argument is a span/metric NAME. "count" is
-# deliberately absent: str.count / list.count collisions would drown the
-# rule in false positives, and Tracer.count shares the counters dict with
-# bounded literal callers anyway.
-_NAME_CALLS = frozenset({"span", "annotate", "counter", "gauge", "histogram"})
+# call targets whose first argument is a span/metric/phase NAME
+_NAME_CALLS = frozenset(
+    {"span", "phase", "_phase", "counter", "gauge", "histogram"}
+)
 
 
 def _last_attr(func: ast.AST) -> str:

@@ -13,10 +13,12 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import hmac
 import json
 import logging
 import os
+import time
 from typing import Any
 
 from aiohttp import web
@@ -37,7 +39,7 @@ from .metrics import PROMETHEUS_CONTENT_TYPE, get_registry
 from .obs import SERIES_BY_NAME, SERIES_NAMES
 from .protocol import copy_sampling
 from .router import DEFAULT_TENANT, AdmissionReject
-from .tracing import get_tracer, stitch_trace
+from .tracing import current_timing, get_tracer, request_timing, stitch_trace
 
 logger = logging.getLogger("bee2bee_tpu.api")
 
@@ -62,6 +64,66 @@ _G_ACCEL_MEM = _REG.gauge(
 _G_P50_LATENCY = _REG.gauge(
     "p50_latency_seconds", "rolling p50 request latency"
 )
+
+# a streamed request's time to its first written byte, split where it is
+# spent (tracing.RequestTiming; one clock). All observed together, once a
+# request, when the write of its first content frame returns — so all cover
+# the SAME requests, and with engine.queue_wait_ms (submit -> row) and
+# engine.prefill_ms (row -> first token), which the scheduler observes,
+# the segments sum to gateway.ttft_ms. (histogram, from stamp, to stamp)
+_TTFT_SEGMENTS = (
+    (_REG.histogram(
+        "gateway.ttft_ms",
+        "handler entry to the first content frame written, streamed requests (ms)",
+    ), "t_accept", "t_first_write"),
+    (_REG.histogram(
+        "gateway.admission_wait_ms",
+        "handler entry (body not yet parsed) until admission.acquire returned (ms)",
+    ), "t_accept", "t_admitted"),
+    (_REG.histogram(
+        "gateway.dispatch_ms",
+        "admitted until the engine built its request: response headers, the "
+        "wait for an executor thread, argument parsing, tokenisation (ms)",
+    ), "t_admitted", "t_submit"),
+    (_REG.histogram(
+        "engine.first_text_ms",
+        "first token on the host until the first stream event with non-empty "
+        "text: ring position, parked prefills, U+FFFD hold-back (ms)",
+    ), "t_first", "t_first_text"),
+    (_REG.histogram(
+        "service.holdback_ms",
+        "first text event queued until the service yielded its first content "
+        "line: the generator hop, stop-marker scrub (ms)",
+    ), "t_first_text", "t_first_line"),
+    (_REG.histogram(
+        "gateway.write_ms",
+        "service's first content line until its frame was written: thread to "
+        "loop hop, queue, parsing, framing, socket (ms)",
+    ), "t_first_line", "t_first_write"),
+)
+# what a request that never streams a byte still has (observed when it ends)
+_UNARY_SEGMENTS = _TTFT_SEGMENTS[1:3]
+
+
+def _observe_segments(record, segments) -> None:
+    """Observe each segment whose two stamps the request reached (a
+    service without an engine leaves the middle of the timeline empty)."""
+    for hist, start, end in segments:
+        t0, t1 = getattr(record, start), getattr(record, end)
+        if t0 and t1:
+            hist.observe((t1 - t0) * 1000.0)
+
+
+def _timed(handler):
+    """Open the request's timeline at a generation handler's entry,
+    before the body is parsed."""
+
+    @functools.wraps(handler)
+    async def run(request):
+        with request_timing():
+            return await handler(request)
+
+    return run
 
 
 def _cors_headers(api_key: str | None) -> dict[str, str]:
@@ -257,6 +319,8 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
         ticket = await node.admission.acquire(
             params["tenant"], cost_tokens=params["max_new_tokens"]
         )
+        record = current_timing()
+        record.t_admitted = time.perf_counter()
         try:
             if stream:
                 return await _stream_service(
@@ -268,6 +332,7 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
                 svc, params, stream=False, on_chunk=None
             )
             ticket.note_tokens(result.get("tokens") or 0)
+            _observe_segments(record, _UNARY_SEGMENTS)
             return result
         finally:
             ticket.release()
@@ -1008,11 +1073,11 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
     app.router.add_get("/fleet", fleet_status)
     app.router.add_post("/fleet/override", fleet_override)
     app.router.add_post("/connect", connect)
-    app.router.add_post("/chat", chat)
-    app.router.add_post("/generate", chat)  # alias (reference api.py:190-191)
+    app.router.add_post("/chat", _timed(chat))
+    app.router.add_post("/generate", _timed(chat))  # alias (reference api.py:190-191)
     app.router.add_get("/v1/models", v1_models)
-    app.router.add_post("/v1/completions", v1_completions)
-    app.router.add_post("/v1/chat/completions", v1_chat_completions)
+    app.router.add_post("/v1/completions", _timed(v1_completions))
+    app.router.add_post("/v1/chat/completions", _timed(v1_chat_completions))
     app.router.add_route("OPTIONS", "/{tail:.*}", lambda r: web.Response(headers=cors))
     return app
 
@@ -1131,24 +1196,27 @@ async def _stream_service(
             loop.call_soon_threadsafe(q.put_nowait, DONE)
 
     # span + copy_context mirror node._execute_local (the service lines pass
-    # through verbatim here, so we can't reuse it directly)
-    import time as _time
-
+    # through verbatim here, so we can't reuse it directly). The copy also
+    # takes the request's timeline into the pump thread: the engine and the
+    # service stamp it there, this loop stamps the first write
+    record = current_timing()
     with get_tracer().span("gen.local", service=svc.name, stream=True) as span:
         ctx = contextvars.copy_context()
         task = loop.run_in_executor(None, ctx.run, pump)
         chunks = 0
         text_chars = 0
-        t0 = _time.time()
+        t0 = time.perf_counter()
         try:
             while True:
                 item = await q.get()
                 if item is DONE:
                     break
                 chunks += 1
+                first = False  # is this the request's first content frame?
                 try:  # count streamed text for the node's measured throughput
                     obj = json.loads(item)
                     text_chars += len(obj.get("text") or "")
+                    first = bool(obj.get("text")) and not record.t_first_write
                     # the span must tell the request's story, not just its
                     # setup: real token count + timing ride the done line,
                     # service failures ride error lines (ISSUE 5 satellite)
@@ -1159,7 +1227,11 @@ async def _stream_service(
                                 # per-tenant completed-token accounting
                                 # must not exclude streaming traffic
                                 ticket.note_tokens(int(obj["tokens"]))
-                        if obj.get("timing") is not None:
+                        if isinstance(obj.get("timing"), dict):
+                            # the engine built its timeline at retirement;
+                            # the stamps after that are known only here
+                            obj["timing"]["timeline_ms"] = record.timeline_ms()
+                            item = json.dumps(obj) + "\n"
                             span.attrs["timing"] = obj["timing"]
                     if obj.get("status") == "error":
                         span.error = str(obj.get("message") or "stream error")
@@ -1168,6 +1240,9 @@ async def _stream_service(
                     # non-string "text" from custom services pass through
                     pass
                 await resp.write(frame(item))
+                if first:
+                    record.t_first_write = time.perf_counter()
+                    _observe_segments(record, _TTFT_SEGMENTS)
             await resp.write_eof()
         except (ConnectionResetError, asyncio.CancelledError):
             logger.info("stream client disconnected; aborting generation pump")
@@ -1178,7 +1253,7 @@ async def _stream_service(
             await task
             # node-level measured throughput must not miss the streaming
             # path (chars/4 = the reference's own token estimate)
-            node.throughput.record(max(0, text_chars // 4), _time.time() - t0)
+            node.throughput.record(max(0, text_chars // 4), time.perf_counter() - t0)
     return resp
 
 

@@ -48,6 +48,7 @@ from ..metrics import get_registry
 from ..models import config as model_config
 from ..models import core, partition
 from ..parallel.mesh import local_mesh
+from ..tracing import current_timing
 from ..utils import MetricsAggregator
 from .tokenizer import load_tokenizer
 
@@ -1007,6 +1008,11 @@ class InferenceEngine:
             if not self.adapter_pool.has(adapter):
                 raise UnknownAdapter(f"adapter {adapter!r} is not resident")
         stop, eos = self._stop_set(stop_tokens)
+        # the gateway's timeline record, when this call runs under one
+        # that no engine request has taken yet (one record, one request)
+        timing = current_timing()
+        if timing is not None and timing.t_submit:
+            timing = None
         return Request(
             ids, max_new_tokens, temperature, top_k, top_p, stop, eos,
             self.tokenizer, stream=stream,
@@ -1016,6 +1022,7 @@ class InferenceEngine:
             min_p=min_p,
             tenant=tenant,
             adapter=adapter,
+            timing=timing,
         )
 
     def _build_result(self, req) -> GenerationResult:
@@ -1054,6 +1061,9 @@ class InferenceEngine:
                 round((t_first - t.t_admit) * 1000.0, 3) if t.t_admit else None
             ),
             "ttft_ms": round(ttft_ms, 3),
+            # every stamp reached so far, ms after the earliest; a gateway
+            # that streams the done line completes it (tracing.RequestTiming)
+            "timeline_ms": t.timeline_ms(),
             "decode_tokens": n_out,
             "tokens_per_s": round(tps, 2),
             "spec_acceptance": (

@@ -66,6 +66,7 @@ from typing import Callable
 
 from ..health import get_recorder, register_digest_provider
 from ..metrics import get_registry
+from ..tracing import PhaseClock, get_tracer
 from ..utils import new_id
 
 logger = logging.getLogger("bee2bee_tpu.introspect")
@@ -129,6 +130,11 @@ _C_SYNC_STALLS = _REG.counter(
     "host syncs that blocked with NO other decode window in flight — the "
     "device sat idle while the host processed tokens (0 when overlap "
     "keeps the ring full)",
+)
+_C_PHASE_SECONDS = _REG.counter(
+    "engine.phase_seconds",
+    "scheduler-thread wall seconds by loop phase (phase label: admit, "
+    "dispatch, fetch = blocked on the device, process, compact)",
 )
 _G_OVERLAP = _REG.gauge(
     "engine.overlap_inflight",
@@ -368,6 +374,11 @@ class RetraceSentinel:
                     key = key_fn(*args, **kwargs)
                 except Exception:  # noqa: BLE001
                     key = None
+            # every counted compile says which shape: /trace?name=
+            # engine.compile lists what compiled and when, the log line
+            # puts it beside the server's other events
+            with get_tracer().span("engine.compile", root=root.name, key=repr(key)):
+                logger.info("compile: root=%s key=%r", root.name, key)
             self._classify(root, key)
         except Exception:  # noqa: BLE001 — telemetry never throws
             pass
@@ -1062,6 +1073,9 @@ class EngineIntrospection:
         )
         self.meter = GoodputMeter(FlopsModel(model_cfg), peak_flops)
         self.forecast = PoolForecast()
+        # the scheduler loop's phases; refresh() credits the open one, so
+        # a scrape reads engine.phase_seconds up to that instant
+        self.phases = PhaseClock("sched", _C_PHASE_SECONDS)
         with _INSTANCES_LOCK:
             _INSTANCES[id(self)] = self
         _wire_provider()
@@ -1092,6 +1106,7 @@ class EngineIntrospection:
     def refresh(self) -> dict:
         """Refresh every gauge this plane owns; return the snapshot that
         rides engine.info / the digest / bench ``extras.introspect``."""
+        self.phases.flush()
         out = {
             "compiles": self.sentinel.snapshot(),
             "goodput": self.meter.refresh(),

@@ -49,6 +49,7 @@ never touch jax state — the single-owner rule that keeps this race-free.
 
 from __future__ import annotations
 
+import functools
 import logging
 import queue
 import threading
@@ -65,7 +66,7 @@ from ..metrics import get_registry
 from ..models import core
 from ..router.fairness import WdrrQueue
 from ..router.tenants import load_tenant_config
-from ..tracing import get_tracer
+from ..tracing import RequestTiming, get_tracer
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
 from .paged import (
     BlockAllocator,
@@ -115,12 +116,20 @@ _C_SPEC_DEGRADED = _REG.counter(
 )
 
 
-@dataclass
-class _Timing:
-    t_submit: float = 0.0
-    t_admit: float = 0.0  # popped off the queue (queue_wait endpoint)
-    t_first: float = 0.0  # first token available (ttft reference point)
-    t_done: float = 0.0
+def _phase(name: str):
+    """Run a BatchScheduler method as loop phase `name` (tracing.PhaseClock:
+    a `sched.<name>` annotation on the profiler's host plane + exclusive
+    seconds on engine.phase_seconds)."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(self, *args, **kwargs):
+            with self._phases.phase(name):
+                return fn(self, *args, **kwargs)
+
+        return run
+
+    return deco
 
 
 class Request:
@@ -144,6 +153,7 @@ class Request:
         min_p: float = 0.0,
         tenant: str = "default",
         adapter: str | None = None,
+        timing: RequestTiming | None = None,
     ):
         self.stream = stream
         # fairness identity (router/tenants.py): keys the scheduler's WDRR
@@ -182,7 +192,10 @@ class Request:
         self.events: queue.Queue = queue.Queue()
         self.out_ids: list[int] = []
         self.finish: str | None = None
-        self.timing = _Timing(t_submit=time.perf_counter())
+        # the gateway's record when the request came through it (one
+        # timeline from its accept to its first written byte), else fresh
+        self.timing = timing if timing is not None else RequestTiming()
+        self.timing.t_submit = time.perf_counter()
         self.prompt_tokens = len(ids)
         self.bucket = 0
         self.chunks_decoded = 0  # observability: early-exit is visible here
@@ -236,6 +249,15 @@ class Request:
         delta = full[len(self._flushed_text):]
         self._flushed_text = full
         return delta
+
+    def emit(self, tokens: list[int]) -> None:
+        """Queue one stream event for `tokens` (just accepted). The text
+        may be empty — a trailing U+FFFD is held back — so the timeline's
+        first-text stamp waits for the first event that carries some."""
+        text = self.text_delta(final=self.done)
+        if text and not self.timing.t_first_text:
+            self.timing.t_first_text = time.perf_counter()
+        self.events.put({"token": tokens[-1], "tokens": tokens, "text": text})
 
     @property
     def done(self) -> bool:
@@ -593,6 +615,8 @@ class BatchScheduler:
         )
         self._draft_tier: dict[int, str] = {}  # row -> tier that drafted
 
+        # the loop's phases (only the scheduler thread enters them)
+        self._phases = e.introspect.phases
         self._thread = threading.Thread(
             target=self._loop, name="bee2bee-batch-scheduler", daemon=True
         )
@@ -1140,6 +1164,7 @@ class BatchScheduler:
             return True
         return frac is None or frac > self._GROW_HEADROOM_MIN
 
+    @_phase("compact")
     def _resize(self, new_bsz: int):
         """Move to a new batch bucket. The pool is batch-bucket-
         independent (row identity lives in the block table), so only the
@@ -1170,6 +1195,7 @@ class BatchScheduler:
         self._bsz = new_bsz
         self._row_params_dirty = True
 
+    @_phase("compact")
     def _compact_and_shrink(self):
         """Close retirement holes by moving the highest active row down,
         then drop to a smaller bucket when occupancy allows."""
@@ -1349,6 +1375,7 @@ class BatchScheduler:
             self._release_row(b)
             raise
 
+    @_phase("admit")
     def _admit(self):
         """Prefill queued requests into free rows, growing the batch bucket
         up to max_batch. All prefills/inserts of an admission burst are
@@ -1616,9 +1643,7 @@ class BatchScheduler:
             if accepted and req.stream:
                 # token events (and their cumulative re-decode) are only
                 # for streaming consumers; generate() reads the done event
-                req.events.put(
-                    {"token": tok, "tokens": [tok], "text": req.text_delta(final=req.done)}
-                )
+                req.emit([tok])
             if req.done:  # instant stop/zero-budget: free the row again
                 self._rows[b] = None
                 self._release_row(b)
@@ -1897,6 +1922,7 @@ class BatchScheduler:
             )
         self._spec_transition(req, tier)
 
+    @_phase("dispatch")
     def _spec_drafts(self):
         """Collect per-row drafts for one spec step, grouped by tier so
         each drafter sees its rows in ONE batched propose call (the model
@@ -2004,30 +2030,31 @@ class BatchScheduler:
             and any(r is not None and r.penalized for r in self._rows)
         )
         t_step = time.perf_counter()
-        with get_tracer().span(
-            "engine.spec_verify", active=self.active, drafted=int(lens.sum())
-        ):
-            if pen:
-                nxt_d, self._cache, acc_d, self._counts = e._spec_verify(
-                    e.params, self._cur, drafts, lens, self._cache,
-                    self._offsets, temps, topks, topps, minps,
-                    e._next_key(), tables, **self._lora_args(),
-                    counts=self._counts, reps=self._reps,
-                    press=self._press, freqs=self._freqs,
-                )
-                self.stats.counts_windows += 1
-            else:
-                nxt_d, self._cache, acc_d = e._spec_verify(
-                    e.params, self._cur, drafts, lens, self._cache,
-                    self._offsets, temps, topks, topps, minps,
-                    e._next_key(), tables, **self._lora_args(),
-                )
-            # a spec step is always a serialized sync: the drafter needs
-            # the verdict before it can propose again
-            _C_HOST_SYNCS.inc()
-            _C_SYNC_STALLS.inc()
-            _G_OVERLAP.set(0)
-            nxt, acc = (np.asarray(x) for x in jax.device_get((nxt_d, acc_d)))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
+        with self._phases.phase("fetch"):
+            with get_tracer().span(
+                "engine.spec_verify", active=self.active, drafted=int(lens.sum())
+            ):
+                if pen:
+                    nxt_d, self._cache, acc_d, self._counts = e._spec_verify(
+                        e.params, self._cur, drafts, lens, self._cache,
+                        self._offsets, temps, topks, topps, minps,
+                        e._next_key(), tables, **self._lora_args(),
+                        counts=self._counts, reps=self._reps,
+                        press=self._press, freqs=self._freqs,
+                    )
+                    self.stats.counts_windows += 1
+                else:
+                    nxt_d, self._cache, acc_d = e._spec_verify(
+                        e.params, self._cur, drafts, lens, self._cache,
+                        self._offsets, temps, topks, topps, minps,
+                        e._next_key(), tables, **self._lora_args(),
+                    )
+                # a spec step is always a serialized sync: the drafter needs
+                # the verdict before it can propose again
+                _C_HOST_SYNCS.inc()
+                _C_SYNC_STALLS.inc()
+                _G_OVERLAP.set(0)
+                nxt, acc = (np.asarray(x) for x in jax.device_get((nxt_d, acc_d)))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
         _H_STEP.observe((time.perf_counter() - t_step) * 1000.0)
         self._last_dispatch_t = time.perf_counter()
         self._cur = nxt.astype(np.int32).copy()
@@ -2098,6 +2125,7 @@ class BatchScheduler:
         ]
         return sum(depths) / len(depths) if depths else 0.0
 
+    @_phase("process")
     def _process_row_tokens(self, b: int, req: Request, tokens) -> bool:
         """THE per-row token-intake protocol, shared by the decode-window
         and spec-step paths (a retirement/streaming semantics change must
@@ -2118,11 +2146,7 @@ class BatchScheduler:
         # cancelled-row tokens all stay scheduled-only
         self._meter.note_useful(len(emitted))
         if emitted and req.stream:
-            req.events.put({
-                "token": emitted[-1],
-                "tokens": emitted,
-                "text": req.text_delta(final=req.done),
-            })
+            req.emit(emitted)
         if req.done:
             self._rows[b] = None
             self._release_row(b)
@@ -2192,6 +2216,7 @@ class BatchScheduler:
             # already pays)
             self._compact_and_shrink()
 
+    @_phase("dispatch")
     def _dispatch_window(self, pending: int = 0) -> bool:
         """Dispatch one W-chunk decode window (async — no host sync) and
         push its record onto the readback ring. Chains device state off
@@ -2357,6 +2382,7 @@ class BatchScheduler:
         # reclaims prefix pins and never migrates/retires a row
         return need <= self._alloc.free_count
 
+    @_phase("fetch")
     def _fetch_window(self, rec) -> np.ndarray:
         """THE host sync of the decode hot loop: block on one in-flight
         window's token buffers. Everything else the step needs came back
@@ -2380,6 +2406,7 @@ class BatchScheduler:
         _H_STEP.observe((time.perf_counter() - rec["t0"]) * 1000.0)
         return toks_host
 
+    @_phase("process")
     def _process_window(self, rec, toks_host: np.ndarray) -> bool:
         """Route one fetched window's tokens through the shared per-row
         intake (_process_row_tokens). Rows that retired or moved since
@@ -2406,6 +2433,7 @@ class BatchScheduler:
         self._release_deferred()
         return retired_any
 
+    @_phase("process")
     def _release_deferred(self):
         """Free blocks whose rows retired while windows were in flight —
         only once the ring is empty (until then, in-flight windows still

@@ -8,6 +8,7 @@ from __future__ import annotations
 import time
 from typing import Any, Iterator
 
+from ..tracing import current_timing
 from .base import (
     BaseService,
     ServiceError,
@@ -185,6 +186,13 @@ class TPUService(BaseService):
             timing = None  # engine timing breakdown off the done event
             n_seen = 0  # tokens streamed so far (the billable count on a
             # stop hit — the engine's own total never arrives then)
+            record = current_timing()  # the gateway's timeline, if it opened one
+
+            def content_line(text: str) -> str:
+                if record is not None and not record.t_first_line:
+                    record.t_first_line = time.perf_counter()
+                return self.stream_line({"text": text})
+
             for ev in self.engine.generate_stream(**args):
                 if ev.get("done"):  # flush the held-back tail
                     res = ev.get("result")
@@ -193,13 +201,13 @@ class TPUService(BaseService):
                         timing = dict(res.timings)
                     tail = scrub_stop_words(acc, stops)
                     if tail[emitted:]:
-                        yield self.stream_line({"text": tail[emitted:]})
+                        yield content_line(tail[emitted:])
                     break
                 acc += ev.get("text", "")
                 n_seen += len(ev.get("tokens") or ([1] if ev.get("token") is not None else []))
                 delta, emitted, hit = scrub_stream_delta(acc, emitted, stops)
                 if delta:
-                    yield self.stream_line({"text": delta})
+                    yield content_line(delta)
                 if hit:
                     n_new = n_seen
                     break

@@ -1,5 +1,5 @@
 """Tracing and observability: request spans + cross-node trace propagation
-+ on-demand device profiles.
++ the per-request timeline + named loop phases.
 
 The reference has NO tracing (SURVEY §5) — the closest artifacts are
 per-request latency_ms (reference services.py:97-105) and ping RTTs
@@ -18,8 +18,16 @@ per-request latency_ms (reference services.py:97-105) and ping RTTs
   the ORIGINATING request across nodes. `/trace?trace_id=` on any node
   returns its local fragment; `stitch_trace()` merges fragments from
   several nodes into one cross-node timeline.
-- `device_profile()`: wraps `jax.profiler.trace` so one call captures an
-  XLA device trace viewable in TensorBoard/Perfetto.
+- `RequestTiming`: ONE record of `time.perf_counter()` stamps per request,
+  from the gateway's accept to its first written byte. The gateway opens
+  it (`request_timing()`), the `contextvars.copy_context()` the serving
+  paths already make carries it into the worker thread, the engine hangs
+  it on its `Request`, and each layer stamps its own fields.
+- `PhaseClock`: the named phases of one thread's loop, each a
+  `jax.profiler.TraceAnnotation` (the host plane of a `/debug/profile`
+  capture, beside the device ops) and exclusive seconds on a counter.
+  Device traces themselves come from `POST /debug/profile`
+  (engine/introspect.DeviceProfiler).
 
 Spans are cheap (monotonic clock + dict append) and bounded (ring
 buffer), so they stay on in production; mesh nodes surface them at the
@@ -144,6 +152,104 @@ def use_trace_ctx(ctx: TraceContext | None):
         _current_trace.reset(t_trace)
 
 
+@dataclass
+class RequestTiming:
+    """One request's timeline: `time.perf_counter()` seconds, 0.0 = not
+    reached. Each layer stamps its own fields; the order below is the
+    order a streamed request passes them (docs/OBSERVABILITY.md)."""
+
+    t_accept: float = 0.0  # gateway handler entry, before the body is parsed
+    t_admitted: float = 0.0  # admission.acquire returned
+    t_submit: float = 0.0  # engine Request built, handed to the scheduler
+    t_admit: float = 0.0  # popped off the queue (queue_wait endpoint)
+    t_first: float = 0.0  # first token available (ttft reference point)
+    t_first_text: float = 0.0  # first event with non-empty text queued
+    t_first_line: float = 0.0  # first content line yielded by the service
+    t_first_write: float = 0.0  # gateway's write of the first content frame returned
+    t_done: float = 0.0
+
+    def timeline_ms(self) -> dict[str, float]:
+        """The stamps reached so far as ms after the earliest one, under
+        the names the done line's `timing["timeline_ms"]` carries."""
+        stamps = {
+            "accept": self.t_accept, "admitted": self.t_admitted,
+            "submit": self.t_submit, "row": self.t_admit,
+            "first_token": self.t_first, "first_text": self.t_first_text,
+            "first_line": self.t_first_line, "first_write": self.t_first_write,
+        }
+        reached = {k: v for k, v in stamps.items() if v}
+        origin = min(reached.values(), default=0.0)
+        return {k: round((v - origin) * 1000.0, 3) for k, v in reached.items()}
+
+
+_current_timing: contextvars.ContextVar[RequestTiming | None] = contextvars.ContextVar(
+    "bee2bee_request_timing", default=None
+)
+
+
+@contextmanager
+def request_timing() -> Iterator[RequestTiming]:
+    """Open the current request's timeline at a gateway handler's entry.
+    The record travels by contextvar (worker threads get it through the
+    `copy_context()` the serving paths already make)."""
+    rec = RequestTiming(t_accept=time.perf_counter())
+    token = _current_timing.set(rec)
+    try:
+        yield rec
+    finally:
+        _current_timing.reset(token)
+
+
+def current_timing() -> RequestTiming | None:
+    return _current_timing.get()
+
+
+class PhaseClock:
+    """The named phases of ONE thread's loop. `phase(name)` opens a
+    `jax.profiler.TraceAnnotation("<prefix>.<name>")` — it lands on the
+    host plane of the same capture as the device ops, so an idle gap of
+    the device gets the phase's name — and adds the phase's EXCLUSIVE
+    seconds (a nested phase pauses its parent) to `counter{phase=name}`,
+    so the phases sum to the loop's busy wall time. `flush()`, from any
+    thread, credits the open phase up to now: called at a scrape, it
+    makes the counter's growth between two scrapes exact (a phase can
+    last seconds — the scheduler blocked on the device — and would
+    otherwise count only when it ends). No Tracer span: the span ring is
+    for requests. Names are literals (meshlint ML-T001)."""
+
+    def __init__(self, prefix: str, counter):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._prefix = prefix
+        self._counter = counter
+        self._lock = threading.Lock()
+        self._open: list[str] = []  # phases entered and not yet left
+        self._since = 0.0  # up to when the innermost open phase is credited
+
+    def flush(self) -> None:
+        with self._lock:
+            if self._open:
+                now = time.perf_counter()
+                self._counter.inc(now - self._since, phase=self._open[-1])
+                self._since = now
+
+    @contextmanager
+    def phase(self, name: str):
+        self.flush()  # the parent pauses here
+        with self._lock:
+            self._open.append(name)
+            self._since = time.perf_counter()
+        try:
+            with self._annotation(f"{self._prefix}.{name}"):
+                yield
+        finally:
+            self.flush()
+            with self._lock:
+                self._open.pop()
+                self._since = time.perf_counter()
+
+
 class Tracer:
     """Bounded in-memory span collector; thread-safe; never raises."""
 
@@ -151,7 +257,6 @@ class Tracer:
         self._spans: deque[Span] = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._epoch = time.time() * 1000.0 - time.monotonic() * 1000.0
-        self.counters: dict[str, int] = {}
         # completion listeners (health.FlightRecorder): called with each
         # closed Span outside the lock; listener errors are swallowed —
         # an observability consumer must never fail the traced code path
@@ -203,10 +308,6 @@ class Tracer:
                 except Exception:  # noqa: BLE001 — tracing never throws
                     pass
 
-    def count(self, name: str, n: int = 1) -> None:
-        with self._lock:
-            self.counters[name] = self.counters.get(name, 0) + n
-
     def recent(self, limit: int = 100, name: str | None = None) -> list[dict]:
         with self._lock:
             spans = list(self._spans)
@@ -226,7 +327,6 @@ class Tracer:
         """Per-span-name aggregates: count, p50/p95/max duration, errors."""
         with self._lock:
             spans = list(self._spans)
-            counters = dict(self.counters)
         by_name: dict[str, list[Span]] = {}
         for s in spans:
             by_name.setdefault(s.name, []).append(s)
@@ -240,14 +340,11 @@ class Tracer:
                 "p95_ms": round(_pct(durs, 0.95), 3),
                 "max_ms": round(durs[-1], 3),
             }
-        if counters:
-            out["_counters"] = counters
         return out
 
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
-            self.counters.clear()
 
 
 def stitch_trace(
@@ -310,32 +407,3 @@ _GLOBAL = Tracer()
 
 def get_tracer() -> Tracer:
     return _GLOBAL
-
-
-@contextmanager
-def device_profile(log_dir: str = "/tmp/bee2bee_trace"):
-    """Capture an XLA device trace (TensorBoard `trace_viewer` readable).
-
-    The TPU-native answer to "how do I see where the time goes": wraps
-    jax.profiler.trace around any block — jit compiles, collectives, HBM
-    transfers all appear in the timeline.
-    """
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        with get_tracer().span("device_profile", log_dir=log_dir):
-            yield log_dir
-
-
-def annotate(name: str, **attrs):
-    """jax.profiler.TraceAnnotation + host span in one: shows up both in
-    the device timeline and in /trace output."""
-    import jax
-
-    @contextmanager
-    def _cm():
-        with jax.profiler.TraceAnnotation(name):
-            with get_tracer().span(name, **attrs):
-                yield
-
-    return _cm()

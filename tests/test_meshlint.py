@@ -726,13 +726,13 @@ def test_telemetry_pass_known_bad_fixture():
     smuggle a request-varying string through — f-string, + concat,
     %-format, .format()."""
     src = '''
-from ..tracing import get_tracer, annotate
+from ..tracing import get_tracer
 from ..metrics import get_registry
 
-def f(rid, op):
+def f(rid, op, clock):
     with get_tracer().span(f"gen.{rid}"):
         pass
-    with annotate("stage." + op):
+    with clock.phase("stage." + op):
         pass
     get_registry().counter("frames_%s" % op).inc()
     get_registry().histogram(name="lat.{}".format(op)).observe(1.0)
@@ -757,9 +757,32 @@ def f(rid, op):
     with get_tracer().span(SPAN_NAME):
         pass
     get_registry().counter("mesh.frames_sent").inc(op=op)
-    "a,b".split(",")[0].count("a")  # str.count is not Tracer.count
+    "a,b".split(",")[0].count("a")  # str.count names no metric
 '''
     assert analyze_source(src, "meshnet/fixture.py") == []
+
+
+def test_telemetry_pass_covers_loop_phase_names():
+    """A loop phase is an annotation name and a counter label value: the
+    scheduler's `_phase` decorator and PhaseClock.phase take literals."""
+    src = '''
+def _phase(name):
+    return lambda fn: fn
+
+class S:
+    @_phase("admit")
+    def _admit(self):
+        with self._phases.phase("fetch"):
+            pass
+
+    @_phase(f"turn.{1}")
+    def _step(self, i):
+        with self._phases.phase("window_%d" % i):
+            pass
+'''
+    findings = analyze_source(src, "engine/fixture.py")
+    assert _rules(findings) == ["ML-T001"] * 2
+    assert [f.line for f in findings] == [11, 13]
 
 
 def test_telemetry_pass_scans_whole_package():

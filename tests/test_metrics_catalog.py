@@ -207,6 +207,14 @@ async def test_full_surface_scrape_matches_catalog():
                   "temperature": 0.0},
         )
         assert r.status == 200, f"/chat returned {r.status}"
+        # and one streamed: the time-to-first-byte histograms observe at
+        # the first content frame's write
+        r = await client.post(
+            "/chat",
+            json={"prompt": "the mesh hums", "model": "tiny-llama",
+                  "max_new_tokens": 8, "temperature": 0.0, "stream": True},
+        )
+        assert r.status == 200 and '"done": true' in await r.text()
         scraped = _scraped_families(await (await client.get("/metrics")).text())
     finally:
         if client is not None:

@@ -760,6 +760,7 @@ def test_queue_cancelled_request_skips_latency_histograms():
     from types import SimpleNamespace
 
     import bee2bee_tpu.engine.engine as eng_mod
+    from bee2bee_tpu.tracing import RequestTiming
 
     before = (eng_mod._H_TTFT.series_count(), eng_mod._H_E2E.series_count())
     fake_engine = SimpleNamespace(
@@ -769,7 +770,7 @@ def test_queue_cancelled_request_skips_latency_histograms():
     req = SimpleNamespace(
         # the scheduler's queue-cancel path: t_admit never set (0 marks
         # "never entered admission"), t_first = t_done = cancel time
-        timing=SimpleNamespace(t_submit=1.0, t_admit=0.0, t_first=9.0, t_done=9.0),
+        timing=RequestTiming(t_submit=1.0, t_admit=0.0, t_first=9.0, t_done=9.0),
         out_ids=[], bucket=None, chunks_decoded=0,
         spec_drafted=0, spec_accepted=0, finish="cancelled", prompt_tokens=3,
     )

@@ -61,13 +61,6 @@ def test_stats_percentiles():
     assert 0 <= st["p50_ms"] <= st["p95_ms"] <= st["max_ms"]
 
 
-def test_counters():
-    tr = Tracer()
-    tr.count("requests")
-    tr.count("requests", 2)
-    assert tr.stats()["_counters"] == {"requests": 3}
-
-
 def test_thread_safety_smoke():
     tr = Tracer(capacity=4096)
 
