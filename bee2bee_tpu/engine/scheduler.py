@@ -114,6 +114,16 @@ _C_SPEC_DEGRADED = _REG.counter(
     "engine.spec_mesh_degraded",
     "rows degraded off the mesh draft tier (reason label)",
 )
+_C_KV_PAGES_VISITED = _REG.counter(
+    "engine.kv_pages_visited",
+    "block-table entries handed to attention: batch rows x table width x "
+    "the dispatched window's attention calls a layer",
+)
+_C_KV_PAGES_LIVE = _REG.counter(
+    "engine.kv_pages_live",
+    "of engine.kv_pages_visited, the entries that map a row's own block "
+    "(live / visited = the share of the table the ragged kernel fetches)",
+)
 
 
 def _phase(name: str):
@@ -1777,11 +1787,13 @@ class BatchScheduler:
             w = min(w, 2)
         return max(1, min(w, e.engine_cfg.max_inflight_chunks))
 
-    def _prepare_window_tables(self, extra: int):
+    def _prepare_window_tables(self, extra: int, calls: int):
         """Paged: grow every active row's block table to cover the next
         device call's writes (positions < offset + extra — W*K for a
         decode window, K+1 for a spec verify), then build the [bsz, tw]
-        device argument at the pow2-bucketed width. A row the pool
+        device argument at the pow2-bucketed width; ``calls`` is how many
+        attention calls a layer will read it (W*K decode steps, 1 spec
+        verify), for the page counters. A row the pool
         cannot cover even after reclaiming prefix pins fails alone
         (explicitly undersized kv_pool_blocks); returns None when no
         active rows survive."""
@@ -1827,6 +1839,8 @@ class BatchScheduler:
         self.stats.paged_live_blocks = sum(live)
         self.stats.paged_blocks_read_last_step = self._bsz * tw
         self.stats.paged_blocks_in_use = self._alloc.used_count
+        _C_KV_PAGES_VISITED.inc(self._bsz * tw * calls)
+        _C_KV_PAGES_LIVE.inc(sum(live) * calls)
         return np.ascontiguousarray(self._tables[:self._bsz, :tw])
 
     def _spec_eligible(self, b: int, req: Request) -> bool:
@@ -2004,7 +2018,7 @@ class BatchScheduler:
         # cover the whole [offset, offset+K+1) write extent — blocks
         # claimed for later-rejected slots stay owned by the row
         # (over-allocated tail) and free normally at retirement
-        tables = self._prepare_window_tables(e.engine_cfg.spec_tokens + 1)
+        tables = self._prepare_window_tables(e.engine_cfg.spec_tokens + 1, 1)
         if tables is None:
             self._compact_and_shrink()
             return True  # nothing left to decode this step
@@ -2228,7 +2242,7 @@ class BatchScheduler:
         e = self.engine
         K = e.engine_cfg.decode_chunk
         W = self._window_size(pending)
-        tables = self._prepare_window_tables(W * K)
+        tables = self._prepare_window_tables(W * K, W * K)
         if tables is None:
             return False
         temps, topks, topps = self._row_sampling_arrays()

@@ -8,17 +8,31 @@ attention still paid the dense rectangle. This kernel (after "Ragged
 Paged Attention" — PAPERS.md, arxiv 2604.15464) reads K/V straight from
 the pool:
 
-- **Pool-direct gather**: the pool is head-major ``[Hkv, NB, BS, hd]``
-  (per-layer slice of core.init_paged_pool's ``[L, Hkv, NB, BS, hd]``)
-  and the grid's page dimension DMAs exactly one block per step via a
-  scalar-prefetched block-table lookup in the BlockSpec index_map —
-  ``(h, tables[b, j], 0, 0)``. No gathered view, no [T, S] score
-  materialization; per-step cache traffic is the table width, same as
-  the pool's design point. Every tensor operand's trailing block dims
-  are ``(rows, hd)`` — Mosaic-tileable (the [NB, BS, Hkv, hd] layout
-  would put a 1-blocked head axis second-to-last and fail to lower, and
-  a bool-mask operand blocked per 16-lane page would violate the same
-  rule — the constraint that shaped ops/flash.py's head-major layout).
+- **Pool-direct gather, a tile a step**: the pool is head-major
+  ``[Hkv, NB, BS, hd]`` (per-layer slice of core.init_paged_pool's
+  ``[L, Hkv, NB, BS, hd]``). One grid step carries ``Th`` KV heads x
+  ``Tp`` consecutive table entries of one row: the pool is passed as
+  ``Tp`` K and ``Tp`` V operands, operand p blocked ``(Th, 1, BS, hd)``
+  with the scalar-prefetched block-table lookup in its index_map —
+  ``(h, tables[b, tile*Tp + p], 0, 0)`` — so each is one strided copy of
+  Th heads of one pool block, the pipeline double-buffers all of them
+  and fetches the next step's tile while this one computes. The grid is
+  ``(B, Hkv/Th, q blocks, table width/Tp)``: phi-3-mini's decode step is
+  16 x 1 x 1 x 4 steps a layer where one page of one head a step made it
+  16 x 32 x 1 x 32. No gathered view, no [T, S] score materialization.
+  Every tensor operand's trailing block dims are ``(rows, hd)`` —
+  Mosaic-tileable (the [NB, BS, Hkv, hd] layout would put a 1-blocked
+  head axis second-to-last and fail to lower, and a bool-mask operand
+  blocked per 16-lane page would violate the same rule — the constraint
+  that shaped ops/flash.py's head-major layout). The copies stay
+  BlockSpec copies, not hand-started DMAs from an ``ANY`` operand: Mosaic
+  refuses to slice an HBM ref whose head size is off the 128-lane tiling
+  (phi-3's 96, gpt2's 64), and takes those as block shapes.
+- **The tile follows from the shapes** (_tile_plan: heads a shard holds,
+  group size, chunk length, head size, page size, table width, dtypes,
+  against a fixed VMEM budget), never from an argument, a flag or a
+  model's name: MHA-32 x 96 takes all heads and 8 pages, a 2-head GQA
+  shard 16 pages, a 2048-row prefill chunk 4 heads and 256 q rows.
 - **One kernel, every chunk shape**: queries fold to ``[B, Hkv, G*T,
   hd]`` rows (GQA group g major, chunk position t minor), so [B, 1]
   decode, [B, K+1] spec verify and ragged prefill chunks are all just
@@ -32,35 +46,43 @@ the pool:
   is_sliding_layer rule the dense mask builder uses; logit softcap and
   the gemma score-scale override are scalar params. Null-block table
   entries past a row's live extent are beyond ``offset + T`` and
-  therefore causally masked by construction. Two block-level skip
-  predicates (page past the causal frontier / entirely below the
-  window) avoid the dead MXU/VPU work on those pages — the BlockSpec
-  gather still DMAs every table-width page into VMEM (skipping the DMA
-  itself needs an index_map that can remap dead pages, a follow-up) —
-  so the compute cost of windowed decode follows ~ceil(w/BS) pages
-  while cache traffic remains the (pow2-bucketed) table width. ALiBi
-  stays dense-only (the bias needs absolute key positions per head;
-  the engine validates).
-- **Online softmax** over the page iterations with f32 m/l/acc VMEM
-  scratch, f32 MXU accumulation, storage dtype out — exactly
-  ops/flash.py's numerics, so greedy parity with the dense path holds
-  token-for-token.
+  therefore causally masked by construction.
+- **Live tiles only**: a row's live extent is known from the same
+  scalars (_live_tiles: the tiles between the window's start and the
+  causal frontier of THIS q block). A tile outside it — the pow2,
+  batch-wide table's padding, a retired row, the pages a window has
+  left behind — computes nothing and is not copied either: its step's
+  index maps name the tile the neighbouring step named (_fetched_tile),
+  and the pipeline copies a block only when its index changes. So both
+  the compute and the cache traffic of a step follow the row's live
+  pages rounded up to a tile, while the table (and with it the compile
+  space, one program per (T, table width)) stays as it was. Dead
+  entries INSIDE a live tile still copy the null block. ALiBi stays
+  dense-only (the bias needs absolute key positions per head; the
+  engine validates).
+- **Online softmax** over the tile iterations with f32 m/l/acc VMEM
+  scratch (a leading Th), f32 MXU accumulation, storage dtype out —
+  exactly ops/flash.py's numerics, so greedy parity with the dense path
+  holds token-for-token; the Th heads of a step go through one batched
+  dot, their chains interleaved by the compiler.
 
-- **Int8 pool dequant in the page loop**: with ``k_scale``/``v_scale``
+- **Int8 pool dequant in the tile**: with ``k_scale``/``v_scale``
   [Hkv, NB] f32 (the per-layer slice of core.init_paged_pool's
   per-page-per-head quantization scales), the pool blocks arrive int8
-  and each grid step dequantizes ITS one block in VMEM — K before the
-  QK^T dot, V before the PV dot — so the precision change rides the
-  existing gather: HBM cache traffic halves and nothing wider than one
-  block ever materializes. The scales ride the SAME scalar-prefetch
-  channel as the block tables — pre-gathered through the tables to
-  ``[Hkv, B, MB]`` outside the kernel, so the kernel reads one f32 per
-  grid step at ``[h, b, j]`` from SMEM (a (1, 1)-blocked VMEM operand
-  would violate the trailing-dims tiling rule above) and the SMEM
-  footprint is table-sized — 2 * Hkv/shard * B * MB * 4 bytes, bounded
-  by the pow2-bucketed LIVE width like every per-step operand, never by
-  pool capacity. The f32 m/l/acc scratch already isolates accumulation
-  from storage precision, so the quantized path changes no softmax math.
+  and each grid step dequantizes ITS tile in VMEM — every page with its
+  own scale, into a [Th, Tp*BS, hd] scratch the two dots then read — so
+  the precision change rides the existing gather: HBM cache traffic
+  halves and nothing wider than one tile ever materializes (a tile of
+  pages is also what makes int8's (32, 128) minimum tile meet a 16-slot
+  page). The scales ride the SAME scalar-prefetch channel as the block
+  tables — pre-gathered through the tables to ``[Hkv, B, MB]`` outside
+  the kernel, so the kernel reads Th x Tp f32 a step at ``[h, b, j]``
+  from SMEM (a (1, 1)-blocked VMEM operand would violate the
+  trailing-dims tiling rule above) and the SMEM footprint is table-sized
+  — 2 * Hkv/shard * B * MB * 4 bytes, bounded by the pow2-bucketed LIVE
+  width like every per-step operand, never by pool capacity. The f32
+  m/l/acc scratch already isolates accumulation from storage precision,
+  so the quantized path changes no softmax math.
 
 On devices that are not TPUs the kernel runs in pallas interpret mode
 (ops/flash.interpret_off_tpu), so the CPU test suite exercises the exact
@@ -81,113 +103,224 @@ from ..compat import shard_map
 from .flash import NEG_INF, _LANES, interpret_off_tpu, validate_flash_mesh
 
 
+# what the tile choice plans VMEM for (the q and o blocks, the f32
+# softmax state, the pipeline's two buffers a K/V page operand, one
+# head's score temporaries) and the limit handed to Mosaic: the plan is
+# an estimate, the limit leaves room for what the compiler adds (v5e's
+# scoped default is 16 MiB of 128)
+_VMEM_BUDGET = 12 * 2**20
+_VMEM_LIMIT = 32 * 2**20
+_TILE_BYTES = 2 * 2**20  # K+V bytes one page tile aims to move
+_TILE_TOKENS = 512  # most key positions a tile may span
+_TILE_PAGES = 16  # most table entries a step: each is a K and a V operand
+_SCORE_ELEMS = 64 * 1024  # most [bq, Tp*BS] f32 score elements a head
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
+    """(Th, Tp, bq): KV heads, table entries and q rows of one grid step —
+    a pure function of the call's shapes (``Hkv`` is the heads THIS shard
+    holds; ``itemsize`` the compute dtype's, the pool's is 1 when
+    ``quantized``). ``Th`` is the largest divisor of Hkv whose per-head
+    VMEM (double-buffered q and o blocks, f32 m/l/acc, the f32 score
+    temporaries of a 128-key tile) fits half the budget. ``Tp`` is the
+    largest power of two, at most 16, that keeps a tile's K+V at ~2 MB,
+    its span at 512 positions, one head's scores at 64 K elements and the
+    whole plan inside the budget, and does not pass the table (the pow2
+    ceiling of MB when MB is smaller; the wrapper pads a table whose
+    width ``Tp`` does not divide). So 32 MHA heads of 96 take the whole
+    head axis and 8 pages a step, a 2-head GQA shard 16 pages, a 2048-row
+    prefill chunk 4 heads."""
+    nq = G * T
+    bq = min(block_q, max(nq, 8))
+    lanes = _round_up(hd, _LANES)
+    pool_item = 1 if quantized else itemsize
+    state = (
+        4 * _round_up(bq, 32 // itemsize) * lanes * itemsize
+        + bq * (2 * _LANES + lanes) * 4
+    )
+
+    def scores(keys):  # s, p and the masks' worth of f32 [bq, keys] a head
+        return bq * keys * 16
+
+    Th = max(
+        d for d in range(1, Hkv + 1)
+        if Hkv % d == 0
+        and (d == 1 or d * (state + scores(_LANES)) <= _VMEM_BUDGET // 2)
+    )
+    # one table entry of one head as VMEM holds it: K and V, the
+    # pipeline's two buffers each (+ an int8 page's dequantized copy)
+    moved = 4 * _round_up(BS, 32 // pool_item) * lanes * pool_item
+    page = moved + (2 * BS * lanes * itemsize if quantized else 0)
+
+    def fits(tp):
+        keys = tp * BS
+        return (
+            tp <= _TILE_PAGES
+            and keys <= _TILE_TOKENS
+            and bq * keys <= _SCORE_ELEMS
+            and tp * Th * moved <= 2 * _TILE_BYTES
+            and Th * (state + scores(keys) + tp * page) <= _VMEM_BUDGET
+        )
+
+    Tp = 1
+    while Tp < MB and fits(2 * Tp):
+        Tp *= 2
+    return Th, Tp, bq
+
+
+def _live_tiles(off, win, i, *, chunk, block_q, tile_tokens, n_tiles):
+    """[lo, hi): the page tiles of one row that hold a key some query row
+    of q block ``i`` can see. Visible keys are the positions
+    (qlo - win, qhi] (from 0 when no window binds), qlo/qhi the block's
+    first/last query position: a q block that is a run of one chunk
+    (block_q divides T) has its own, any other spans the chunk. A tile
+    outside [lo, hi) is wholly past the causal frontier or wholly below
+    the window."""
+    if chunk % block_q == 0:
+        qlo = off + (i * block_q) % chunk
+        qhi = qlo + block_q - 1
+    else:
+        qlo, qhi = off, off + chunk - 1
+    hi = jnp.minimum(qhi // tile_tokens + 1, n_tiles)
+    lo = jnp.where(win > 0, jnp.maximum(qlo - win + 1, 0) // tile_tokens, 0)
+    return lo, hi
+
+
+def _fetched_tile(j, lo, hi, n_tiles):
+    """The tile whose pages grid step ``j`` of a row names in its K/V
+    index maps: ``j`` itself while live, else the nearest live tile — the
+    one the previous step (or the next, below a window) names too. The
+    pipeline copies a block only when its index changes between
+    consecutive steps, so a dead tile starts NO copy."""
+    return jnp.clip(jnp.clip(j, lo, hi - 1), 0, n_tiles - 1)
+
+
 def _ragged_kernel(
-    tables_ref,  # SMEM [B, MB] int32 (scalar-prefetch): per-row block tables
+    tables_ref,  # SMEM [B, MBp] int32 (scalar-prefetch): per-row block tables
+    #              (the K/V index maps read them; the body does not)
     off_ref,  # SMEM [B] int32 (scalar-prefetch): position of q[:, 0]
     win_ref,  # SMEM [1] int32 (scalar-prefetch): sliding window (0 = none)
     *refs,
     # quantized=True prepends two more scalar-prefetch refs:
-    #   kscale_ref, vscale_ref  SMEM [Hkv, B, MB] f32 scales, pre-gathered
+    #   kscale_ref, vscale_ref  SMEM [Hkv, B, MBp] f32 scales, pre-gathered
     #                           through the block tables per row
     # then the tensor operands either way:
-    #   q_ref    [1, 1, BQ, hd]  q rows: GQA group g major, chunk pos t minor
-    #   k_ref    [1, 1, BS, hd]  one pool block, gathered via index_map
-    #   v_ref    [1, 1, BS, hd]
-    #   o_ref    [1, 1, BQ, hd]
-    #   m_ref    VMEM [BQ, 128] f32 running max
-    #   l_ref    VMEM [BQ, 128] f32 running sum
-    #   acc_ref  VMEM [BQ, hd] f32
+    #   q_ref       [1, Th, BQ, hd]  q rows: GQA group g major, chunk pos t minor
+    #   k_refs[p]   [Th, 1, BS, hd]  Tp operands: Th heads of the pool block
+    #   v_refs[p]   [Th, 1, BS, hd]  at entry p of the step's table tile
+    #   o_ref       [1, Th, BQ, hd]
+    #   m_ref       VMEM [Th, BQ, 128] f32 running max
+    #   l_ref       VMEM [Th, BQ, 128] f32 running sum
+    #   acc_ref     VMEM [Th, BQ, hd] f32
+    # and, quantized, the tile's dequantized keys and values:
+    #   kdq_ref, vdq_ref  VMEM [Th, Tp*BS, hd] compute dtype
     sm_scale: float,
     softcap: float,
     block_size: int,
     block_q: int,
     chunk: int,  # T: query positions per row (row r is chunk position r % T)
+    tile_heads: int,
+    tile_pages: int,
     quantized: bool = False,
 ):
+    Th, Tp, BS = tile_heads, tile_pages, block_size
     if quantized:
-        (kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
-         o_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        kscale_ref = vscale_ref = None
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        kscale_ref, vscale_ref, *refs = refs
+    q_ref, *refs = refs
+    k_refs, v_refs = refs[:Tp], refs[Tp:2 * Tp]
+    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[2 * Tp:]
+    tile_tokens = Tp * BS
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    h0 = pl.program_id(1) * Th
     i = pl.program_id(2)
     j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     off = off_ref[b]
     win = win_ref[0]
-    # block-level skips, mirroring ops/flash.py's above-diagonal skip:
-    # a page starting past the causal frontier (every query position is
-    # <= off + chunk - 1) or ending below every query's window start
-    # (>= off - win + 1 when the window binds) contributes nothing
-    past_causal = j * block_size > off + chunk - 1
-    below_window = (win > 0) & (j * block_size + block_size - 1 < off - win + 1)
+    lo, hi = _live_tiles(
+        off, win, i, chunk=chunk, block_q=block_q, tile_tokens=tile_tokens,
+        n_tiles=pl.num_programs(3),
+    )
 
-    @pl.when(jnp.logical_not(past_causal | below_window))
+    # a dead tile was not copied (_fetched_tile) and computes nothing
+    @pl.when((lo <= j) & (j < hi))
     def _attend():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
+        q = q_ref[0]  # [Th, BQ, hd]
         if quantized:
-            # every key/value row of this block shares ONE scale per kv
-            # head: the wrapper pre-gathered the per-page scales through
-            # the block tables to [Hkv, B, MB], so the grid coordinates
-            # index them directly and the dequant touches only the one
-            # block already resident in VMEM
-            k = (k.astype(jnp.float32) * kscale_ref[h, b, j]).astype(q.dtype)
+            # every key/value row of a page shares ONE scale per kv head:
+            # the wrapper pre-gathered the per-page scales through the
+            # block tables to [Hkv, B, MBp], so the grid coordinates index
+            # them directly, and nothing wider than the tile dequantizes
+            kdq_ref, vdq_ref = dq_refs
+
+            def dequant(h, _):
+                for p in range(Tp):
+                    rows = pl.ds(p * BS, BS)
+                    for page_ref, scale_ref, out_ref in (
+                        (k_refs[p], kscale_ref, kdq_ref),
+                        (v_refs[p], vscale_ref, vdq_ref),
+                    ):
+                        out_ref[h, rows] = (
+                            page_ref[h, 0].astype(jnp.float32)
+                            * scale_ref[h0 + h, b, j * Tp + p]
+                        ).astype(out_ref.dtype)
+
+            jax.lax.fori_loop(0, Th, dequant, None)
+            k, v = kdq_ref[...], vdq_ref[...]
+        else:
+            k = jnp.concatenate([r[:, 0] for r in k_refs], axis=1)
+            v = jnp.concatenate([r[:, 0] for r in v_refs], axis=1)
+        # all Th heads in one batched dot: their dot -> softmax -> dot
+        # chains are independent, and the compiler interleaves them
         s = (
-            jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )
+            jnp.einsum("hqd,hkd->hqk", q, k, preferred_element_type=jnp.float32)
             * sm_scale
-        )  # [BQ, BS]
+        )  # [Th, BQ, Tp*BS]
         if softcap:  # gemma-2: tanh cap BEFORE masking, like core._attention
             s = jnp.tanh(s / softcap) * softcap
         # visibility from scalars: query row r sits at chunk position
-        # (i*BQ + r) % T, key column c at pool position j*BS + c
-        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_size), 0)
+        # (i*BQ + r) % T, key column c at pool position j*Tp*BS + c
+        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, tile_tokens), 0)
         qpos = off + (i * block_q + row) % chunk
-        kvpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_size), 1
+        kvpos = j * tile_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, tile_tokens), 1
         )
         msk = kvpos <= qpos
-        msk = msk & ((win <= 0) | (kvpos > qpos - win))
+        msk = (msk & ((win <= 0) | (kvpos > qpos - win)))[None]
         s = jnp.where(msk, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...][:, :, :1]
+        l_prev = l_ref[...][:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a fully-masked ROW would otherwise contribute exp(-1e30+1e30)=1
-        p = jnp.where(msk, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(msk, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-
-        v = v_ref[0, 0]
-        if quantized:
-            v = (v.astype(jnp.float32) * vscale_ref[h, b, j]).astype(q.dtype)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum(
+            "hqk,hkd->hqd", p.astype(v.dtype), v,
             preferred_element_type=jnp.float32,
         )
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + pv
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
-        # l == 0 only for rows with nothing visible (every page skipped —
-        # can't happen for live rows, but a dead batch row's stale offset
-        # may land there): emit 0, not 0/0 = NaN
-        l = l_ref[:, 0][:, None]
-        o_ref[0, 0] = (
-            acc_ref[:] / jnp.where(l == 0.0, 1.0, l)
-        ).astype(o_ref.dtype)
+        # l == 0 only for rows with nothing visible (no live tile — can't
+        # happen for live rows, but a dead batch row's stale offset may
+        # land there): emit 0, not 0/0 = NaN
+        l = l_ref[...][:, :, :1]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(
@@ -208,10 +341,11 @@ def ragged_paged_attention(
     """Causal attention for a [B, T] chunk over the paged pool; returns
     [B, T, H*hd] (core._attention ABI). T=1 is decode, T=K+1 spec verify,
     T=bucket a ragged prefill chunk — one compiled program per (T, table
-    width) pair, both already bucketed by the engine. With
+    width) pair, both already bucketed by the engine; the tile a grid
+    step carries follows from the shapes (_tile_plan). With
     ``k_scale``/``v_scale`` the pool is int8 (core.init_paged_pool's
-    quantized layout) and each gathered block dequantizes in VMEM before
-    its dot — same grid, same softmax math, half the pool HBM traffic."""
+    quantized layout) and each fetched page dequantizes in VMEM before
+    its dot — same tiles, same softmax math, half the pool HBM traffic."""
     B, T, H, hd = q.shape
     Hkv, NB, BS, _ = k_pool.shape
     MB = block_tables.shape[1]
@@ -223,8 +357,10 @@ def ragged_paged_attention(
         raise ValueError("quantized pool needs BOTH k_scale and v_scale")
 
     nq = G * T
-    bq = min(block_q, max(nq, 8))
-    nqp = -(-nq // bq) * bq
+    Th, Tp, bq = _tile_plan(
+        Hkv, G, T, hd, BS, MB, q.dtype.itemsize, quantized, block_q
+    )
+    nqp = _round_up(nq, bq)
     # [B, T, H, hd] -> [B, Hkv, G*T, hd]: head h = kvh*G + g attends kv
     # head kvh = h // G, so heads of one group are contiguous rows
     qT = q.reshape(B, T, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(B, Hkv, nq, hd)
@@ -232,13 +368,18 @@ def ragged_paged_attention(
         qT = jnp.pad(qT, ((0, 0), (0, 0), (0, nqp - nq), (0, 0)))
 
     tables = jnp.asarray(block_tables, jnp.int32)
+    if MB % Tp:
+        # a width the tile does not divide (the engine's are powers of
+        # two, so never on the serving path): null entries past the
+        # table's end, causally dead like the pow2 padding itself
+        tables = jnp.pad(tables, ((0, 0), (0, _round_up(MB, Tp) - MB)))
+    n_tiles = tables.shape[1] // Tp
     off = jnp.broadcast_to(
         jnp.asarray(offset if offset is not None else 0, jnp.int32).reshape(-1),
         (B,),
     )
     win = jnp.asarray(window if window is not None else 0, jnp.int32).reshape(-1)[:1]
 
-    grid = (B, Hkv, nqp // bq, MB)
     kernel = functools.partial(
         _ragged_kernel,
         sm_scale=sm_scale,
@@ -246,41 +387,46 @@ def ragged_paged_attention(
         block_size=BS,
         block_q=bq,
         chunk=T,
+        tile_heads=Th,
+        tile_pages=Tp,
         quantized=quantized,
     )
+
     # index maps take the scalar-prefetch refs as trailing args (3 of
     # them, or 5 with the quantization scales — the variadic tail keeps
-    # one lambda serving both); the K/V maps ARE the gather — page j of
-    # row b reads pool block tables[b, j]
+    # one lambda serving both). The K/V maps ARE the gather: entry p of
+    # the step's tile reads Th heads of pool block tables[b, tile*Tp + p],
+    # and a dead step names the tile its neighbour named (_fetched_tile),
+    # which the pipeline then does not copy again
+    def page_map(p):
+        def index(b, h, i, j, tb, off_, win_, *_):
+            lo, hi = _live_tiles(
+                off_[b], win_[0], i, chunk=T, block_q=bq,
+                tile_tokens=Tp * BS, n_tiles=n_tiles,
+            )
+            return h, tb[b, _fetched_tile(j, lo, hi, n_tiles) * Tp + p], 0, 0
+
+        return index
+
+    qo_spec = pl.BlockSpec((1, Th, bq, hd), lambda b, h, i, j, *_: (b, h, i, 0))
+    page_specs = [pl.BlockSpec((Th, 1, BS, hd), page_map(p)) for p in range(Tp)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 if quantized else 3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, bq, hd), lambda b, h, i, j, tb, *_: (b, h, i, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, BS, hd), lambda b, h, i, j, tb, *_: (h, tb[b, j], 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, BS, hd), lambda b, h, i, j, tb, *_: (h, tb[b, j], 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, bq, hd), lambda b, h, i, j, tb, *_: (b, h, i, 0)
-        ),
+        grid=(B, Hkv // Th, nqp // bq, n_tiles),
+        in_specs=[qo_spec] + page_specs + page_specs,
+        out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
+            pltpu.VMEM((Th, bq, _LANES), jnp.float32),
+            pltpu.VMEM((Th, bq, _LANES), jnp.float32),
+            pltpu.VMEM((Th, bq, hd), jnp.float32),
+        ] + [pltpu.VMEM((Th, Tp * BS, hd), q.dtype)] * (2 * quantized),
     )
     # pre-gather the per-page scales through the block tables OUTSIDE the
-    # kernel: the SMEM operand is then [Hkv, B, MB] — bounded by the
+    # kernel: the SMEM operand is then [Hkv, B, MBp] — bounded by the
     # pow2-bucketed LIVE table width like every other per-step operand —
     # instead of the pool-sized [Hkv, NB], which scales with total
     # capacity and would overflow SMEM on production-sized pools. The
-    # gather itself is B*MB*Hkv f32 per call — noise next to one block's
+    # gather itself is B*MB*Hkv f32 per call — noise next to one tile's
     # page traffic — and the kernel then indexes (h, b, j) directly.
     scales = (
         (
@@ -294,8 +440,12 @@ def ragged_paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, nqp, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(tables, off, win, *scales, qT, k_pool, v_pool)
+    )(tables, off, win, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
     # [B, Hkv, nqp, hd] -> [B, T, H*hd]
     out = out[:, :, :nq].reshape(B, Hkv, G, T, hd).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, T, H * hd)

@@ -262,6 +262,248 @@ def test_ragged_bf16_storage_f32_accumulation():
     )
 
 
+# --------------------------------------- tiles of heads x tiles of live pages
+
+# One grid step carries Th KV heads x Tp table entries (ops/ragged._tile_plan
+# decides from the shapes). The cases walk what the plan can choose and what
+# the shapes can force: MHA-32 / GQA / MQA head layouts at head sizes 96 and
+# 128, table widths below, at and far above a tile, rows whose live pages end
+# mid-tile, exactly at a tile edge, and nowhere (a dead row), a window that
+# kills leading tiles, decode / spec-verify / prefill-chunk row counts, the
+# bf16 and the int8 pool, eager and under jit. `offs` are q[:, 0]'s
+# positions: a row holds offs + T tokens in 16-slot pages.
+TILE_CASES = {
+    # 13 pages (ends mid-tile of 8), 8 pages (a tile edge), 3 pages, dead
+    "mha32-hd96-mb32-mid-edge-dead": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 127, 40, 9], dead=[3]),
+    "mha32-hd96-mb128-long": dict(
+        Hkv=32, G=1, hd=96, MB=128, T=1, offs=[1800, 2047, 1023]),
+    "mha32-hd96-mb2-below-a-tile": dict(
+        Hkv=32, G=1, hd=96, MB=2, T=1, offs=[0, 15, 16, 31]),
+    "gqa8x4-hd128-mb32": dict(
+        Hkv=8, G=4, hd=128, MB=32, T=1, offs=[255, 256, 300, 2]),
+    "gqa2x4-hd128-mb128-window-kills-leading-tiles": dict(
+        Hkv=2, G=4, hd=128, MB=128, T=1, offs=[1900, 1500, 255], window=300),
+    "gqa2x4-hd128-mb5-table-narrower-than-tile": dict(
+        Hkv=2, G=4, hd=128, MB=5, T=1, offs=[70, 33, 5], dead=[1]),
+    "mqa1x8-hd128-mb4": dict(
+        Hkv=1, G=8, hd=128, MB=4, T=1, offs=[63, 31, 0]),
+    "gqa8x4-hd96-spec-verify-t5": dict(
+        Hkv=8, G=4, hd=96, MB=32, T=5, offs=[123, 124, 250, 300]),
+    "gqa2x4-hd128-spec-verify-t5-window-softcap": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=5, offs=[400, 129, 60], window=130,
+        softcap=30.0),
+    "gqa2x4-hd128-prefill-chunk-t256": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=256, offs=[0, 250]),
+    "mha32-hd96-prefill-chunk-t256-window": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=256, offs=[256], window=200),
+    "mha32-hd96-mb32-bf16": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 127, 40], dtype="bfloat16"),
+    "mha32-hd96-mb32-int8-dead-tiles": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 127, 40, 9], dead=[3],
+        int8=True),
+    "gqa2x4-hd128-int8-t5-window": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=5, offs=[400, 129, 60], window=130,
+        int8=True),
+    "mha32-hd96-mb32-under-jit": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 127, 40, 9], dead=[3],
+        jit=True),
+    "gqa2x4-hd128-mb128-window-under-jit": dict(
+        Hkv=2, G=4, hd=128, MB=128, T=1, offs=[1900, 1500, 255], window=300,
+        jit=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_ragged_tiles_match_dense(case):
+    c = TILE_CASES[case]
+    Hkv, G, hd, MB, T = c["Hkv"], c["G"], c["hd"], c["MB"], c["T"]
+    offs, window = c["offs"], c.get("window", 0)
+    BS = 16
+    dtype = jnp.dtype(c.get("dtype", "float32"))
+    need = max(-(-(o + T) // BS) for o in offs)
+    assert need <= MB, "the case's rows must fit its table"
+    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+        offs=offs, T=T, H=Hkv * G, Hkv=Hkv, hd=hd, BS=BS,
+        extra_tables=MB - need, seed=len(case), dtype=dtype,
+    )
+    assert tb.shape == (len(offs), MB)
+    live = [b for b in range(len(offs)) if b not in c.get("dead", ())]
+    mapped = np.asarray(tb)  # the tables the dense view was gathered through
+    for b in c.get("dead", ()):  # retired mid-batch: the whole table nulled
+        tb = tb.at[b].set(0)
+    if window:
+        S = mask.shape[-1]
+        q_pos = np.asarray(off)[:, None] + np.arange(T)[None, :]
+        mask = mask & jnp.asarray(
+            np.arange(S)[None, None, :] > (q_pos[:, :, None] - window)
+        )
+    cfg = replace(CFG, attn_logit_softcap=c.get("softcap", 0.0))
+    kw = dict(logit_softcap=c.get("softcap", 0.0))
+    if c.get("int8"):
+        kq, ks, vq, vs = _quantize_pool(kp, vp)
+        kw.update(k_scale=ks, v_scale=vs)
+        # the dense view over the DEQUANTIZED pool, gathered like _pool_case
+        B, S = tb.shape[0], MB * BS
+        kdq = jnp.asarray(kq, jnp.float32) * ks[:, :, None, None]
+        vdq = jnp.asarray(vq, jnp.float32) * vs[:, :, None, None]
+        kg = jnp.transpose(kdq[:, mapped], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
+        vg = jnp.transpose(vdq[:, mapped], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
+        kp, vp = kq, vq
+
+    def run(q, kp, vp, tb, off, win):
+        return ragged_paged_attention(q, kp, vp, tb, off, window=win, **kw)
+
+    if c.get("jit"):
+        run = jax.jit(run)
+    out = run(q, kp, vp, tb, off, jnp.full((1,), window, jnp.int32))
+    assert out.shape == (len(offs), T, Hkv * G * hd) and out.dtype == dtype
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    want = _dense_ref(
+        q.astype(jnp.float32), kg.astype(jnp.float32), vg.astype(jnp.float32),
+        mask, cfg,
+    )
+    tol = dict(atol=0.08, rtol=0.08) if dtype == jnp.bfloat16 else dict(atol=5e-5)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32)[live], np.asarray(want)[live], **tol
+    )
+
+
+def test_tile_plan_follows_shapes_within_vmem_budget():
+    """(Th, Tp, bq) is a pure function of the call's shapes: the issue's
+    three examples, divisibility, the table-width cap, and the plan's own
+    VMEM arithmetic under the budget for every shape the engine issues."""
+    from bee2bee_tpu.ops import ragged
+
+    plan = ragged._tile_plan
+    # phi-3-mini decode: all 32 MHA heads, 8 pages a step (4 MB of K/V
+    # buffers), int8 pages the same; its 2048-row prefill chunk: fewer
+    # heads so that q, scores and accumulators fit, q rows 256
+    assert plan(32, 1, 1, 96, 16, 32, 2, False) == (32, 8, 8)
+    assert plan(32, 1, 1, 96, 16, 32, 2, True) == (32, 8, 8)
+    assert plan(32, 1, 2048, 96, 16, 128, 2, False) == (4, 16, 256)
+    # a mistral-7b shard under model:4 (2 KV heads, groups of 4, hd 128):
+    # both heads and a LARGER page tile than the MHA plan's
+    assert plan(2, 4, 1, 128, 16, 64, 2, False) == (2, 16, 8)
+    # a table narrower than a tile takes the table (pow2 ceiling)
+    assert plan(32, 1, 1, 96, 16, 2, 2, False)[1] == 2
+    assert plan(32, 1, 1, 96, 16, 4, 2, False)[1] == 4
+    assert plan(2, 4, 1, 128, 16, 5, 2, False)[1] == 8
+    assert plan(8, 1, 1, 256, 16, 1, 2, False)[1] == 1
+    for Hkv, G, hd in [(32, 1, 96), (8, 4, 128), (2, 4, 128), (1, 8, 256),
+                       (12, 1, 64), (16, 2, 256)]:
+        for T in (1, 5, 7, 64, 256, 2048):
+            for MB in (1, 2, 4, 32, 128, 256):
+                for itemsize, quantized in ((2, False), (4, False), (2, True)):
+                    Th, Tp, bq = plan(Hkv, G, T, hd, 16, MB, itemsize, quantized)
+                    assert Hkv % Th == 0 and Th >= 1
+                    assert Tp & (Tp - 1) == 0 and 1 <= Tp <= ragged._TILE_PAGES
+                    assert Tp < 2 * MB  # never past the table's pow2 ceiling
+                    assert bq == min(256, max(G * T, 8))
+                    lanes = -(-hd // 128) * 128
+                    pool_item = 1 if quantized else itemsize
+                    vmem = (
+                        Th * 2 * 2 * max(bq, 32 // itemsize) * lanes * itemsize  # q, o
+                        + Th * bq * (2 * 128 + lanes) * 4  # m, l, acc
+                        + Th * bq * Tp * 16 * 16  # f32 score temporaries
+                        + 2 * 2 * Tp * Th * max(16, 32 // pool_item) * lanes * pool_item
+                        + quantized * 2 * Th * Tp * 16 * lanes * itemsize  # dequantized
+                    )
+                    assert vmem <= ragged._VMEM_BUDGET or (Th == 1 and Tp == 1), (
+                        Hkv, G, T, hd, MB, itemsize, quantized, Th, Tp, bq, vmem
+                    )
+
+
+def test_dead_tile_starts_no_copy():
+    """The K/V index maps name, for a dead grid step, the tile a
+    neighbouring step names (the pipeline copies a block only when its
+    index changes): over every step of a row the distinct tiles fetched
+    are exactly the tiles with a visible key, brute-forced from the
+    visibility rule itself."""
+    from bee2bee_tpu.ops.ragged import _fetched_tile, _live_tiles
+
+    BS, Tp, n_tiles = 16, 8, 16
+    tt = BS * Tp
+    for chunk, block_q in ((1, 8), (5, 20), (256, 256), (512, 256), (64, 256)):
+        for win in (0, 1, 100, 300, 5000):
+            for off in (0, 1, 127, 128, 129, 1000, 1500, 2047 - chunk, 5000):
+                for i in range(max(1, chunk // block_q)):
+                    lo, hi = (int(x) for x in _live_tiles(
+                        off, win, i, chunk=chunk, block_q=block_q,
+                        tile_tokens=tt, n_tiles=n_tiles,
+                    ))
+                    rows = np.arange(i * block_q, (i + 1) * block_q)
+                    qpos = off + rows % chunk
+                    kv = np.arange(n_tiles * tt)
+                    vis = kv[None, :] <= qpos[:, None]
+                    if win > 0:
+                        vis &= kv[None, :] > qpos[:, None] - win
+                    want = sorted(set((kv[vis.any(axis=0)] // tt).tolist()))
+                    assert list(range(lo, hi)) == want, (chunk, win, off, i)
+                    steps = [
+                        int(_fetched_tile(j, lo, hi, n_tiles))
+                        for j in range(n_tiles)
+                    ]
+                    assert all(0 <= t < n_tiles for t in steps)
+                    # changes of index = copies started (the first step's
+                    # block is always fetched: one tile even for a row
+                    # with nothing visible)
+                    copies = 1 + sum(a != b for a, b in zip(steps, steps[1:]))
+                    assert copies == max(len(want), 1), (chunk, win, off, i, steps)
+                    if want:
+                        assert sorted(set(steps)) == want
+
+
+def test_scheduler_counts_visited_and_live_pages():
+    """engine.kv_pages_visited / engine.kv_pages_live grow, once a
+    dispatched window, by rows x table width x steps and by the rows'
+    mapped pages x steps."""
+    from bee2bee_tpu.engine import EngineConfig, InferenceEngine
+    from bee2bee_tpu.metrics import get_registry
+
+    reg = get_registry()
+
+    def counters():
+        return (
+            reg.counter("engine.kv_pages_visited").value(),
+            reg.counter("engine.kv_pages_live").value(),
+        )
+
+    eng = InferenceEngine("tiny-llama", engine_config=EngineConfig(
+        max_seq_len=128, max_batch=2, decode_chunk=4, kv_block_size=8,
+        attention="flash",
+    ))
+    sched = eng.scheduler
+    seen = []
+    orig = sched._prepare_window_tables
+
+    def spy(extra, calls):
+        before = counters()
+        tables = orig(extra, calls)
+        if tables is not None:
+            live = sum(
+                len(sched._row_blocks[b])
+                for b, r in enumerate(sched._rows) if r is not None
+            )
+            seen.append((counters()[0] - before[0], counters()[1] - before[1],
+                         tables.shape[0] * tables.shape[1] * calls, live * calls))
+        return tables
+
+    sched._prepare_window_tables = spy
+    v0, l0 = counters()
+    try:
+        eng.generate(list(range(3, 23)), max_new_tokens=12, temperature=0.0)
+    finally:
+        eng.close()
+    assert seen, "no decode window was dispatched"
+    for dv, dl, want_v, want_l in seen:
+        assert (dv, dl) == (want_v, want_l)
+        assert 0 < dl <= dv
+    v1, l1 = counters()
+    assert v1 - v0 == sum(s[2] for s in seen)
+    assert l1 - l0 == sum(s[3] for s in seen)
+
+
 # ------------------------------------------------- engine-level acceptance
 
 

@@ -100,6 +100,12 @@ RAGGED_CASES = {
     "llama-3-8b-gqa-decode": ("llama-3-8b", dict(B=8, T=1, MB=8)),
     "zephyr-7b-gqa-prefill-64": ("zephyr-7b", dict(B=1, T=64, MB=4)),
     "distilgpt2-mha-decode": ("distilgpt2", dict(B=8, T=1, MB=8)),
+    # head size 96 (off the 128-lane tiling: Mosaic refuses a hand-started
+    # DMA from such a pool, the kernel's BlockSpec copies pass): the
+    # benchmark's two cells and their 2048-bucket prefill
+    "phi-3-mini-decode": ("phi-3-mini", dict(B=16, T=1, MB=32)),
+    "phi-3-mini-decode-long": ("phi-3-mini", dict(B=4, T=1, MB=128)),
+    "phi-3-mini-prefill-2048": ("phi-3-mini", dict(B=1, T=2048, MB=128)),
 }
 
 
