@@ -134,6 +134,12 @@ class DraftModel(Drafter):
             self.cfg = model_config.resolve_model_config(model, checkpoint_path)
         except KeyError as e:
             raise DrafterLoadError(f"unknown drafter model {model!r}") from e
+        if self.cfg.has_ssm:
+            from .paged import RecurrentStateUnsupported
+
+            raise RecurrentStateUnsupported(
+                "spec_model_drafter", self.cfg.name,
+                "a rejected draft cannot be rolled back out of the state")
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

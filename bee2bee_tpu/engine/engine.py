@@ -50,6 +50,7 @@ from ..models import core, partition
 from ..parallel.mesh import local_mesh
 from ..tracing import current_timing
 from ..utils import MetricsAggregator
+from .paged import RecurrentStateUnsupported
 from .tokenizer import load_tokenizer
 
 logger = logging.getLogger("bee2bee_tpu.engine")
@@ -318,6 +319,7 @@ class InferenceEngine:
         # and the validation error message both read it
         self.max_seq_len = min(self.engine_cfg.max_seq_len, self.model_cfg.max_seq_len)
         partition.validate_divisibility(self.model_cfg, self.mesh)
+        self._validate_recurrent_features()
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -413,7 +415,8 @@ class InferenceEngine:
         # one jit object; it specializes per tokens shape (= per bucket)
         self._prefill = self.introspect.sentinel.watch(
             "prefill",
-            jax.jit(self._prefill_fn, donate_argnums=(2,)),
+            jax.jit(self._prefill_fn, donate_argnums=(2,),
+                    donate_argnames=("state",)),
             key_fn=self._prefill_key,
             allowed=lambda key: (
                 key[0] == 1 and key[1] in self._declared_prefill_widths
@@ -429,6 +432,11 @@ class InferenceEngine:
                 key[0] in self._declared_batch_sizes
                 and key[1] == self.engine_cfg.spec_tokens
             ),
+        )
+        self._state_zeros = jax.jit(
+            functools.partial(core.init_ssm_state, self.model_cfg,
+                              dtype=self.dtype),
+            static_argnums=0,
         )
         self._rng = jax.random.key(self.engine_cfg.rng_seed)
         # jitted split: an eager jax.random.split is a dispatch of its
@@ -508,7 +516,7 @@ class InferenceEngine:
     @staticmethod
     def _prefill_key(params, tokens, cache, true_len, offset,
                      block_tables=None, write_floor=None, write_ceil=None,
-                     adapters=None, aids=None, ascales=None):
+                     adapters=None, aids=None, ascales=None, state=None):
         """Sentinel shape key for the prefill root: the dims that select
         a compiled variant — batch rows, the padded token width (the
         bucket), the block-table width bucket, and the None-flags of the
@@ -678,9 +686,73 @@ class InferenceEngine:
 
             validate_sp_mesh(self.model_cfg, self.engine_cfg, self.mesh)
 
+    def _validate_recurrent_features(self):
+        """Refuse, by name, every configured feature that cannot carry a
+        recurrent row state yet (RecurrentStateUnsupported). Pipeline
+        stages refuse in stage_runner, a recurrent DRAFTER in drafter.py;
+        block-level migration snapshots are not refused but never made
+        (scheduler._snapshot_row ships metadata only, so the importer
+        takes the re-prefill rung)."""
+        cfg, ec = self.model_cfg, self.engine_cfg
+        if not cfg.has_ssm:
+            return
+
+        def refuse(feature, why):
+            raise RecurrentStateUnsupported(feature, cfg.name, why)
+
+        if ec.prefix_cache_entries > 0:
+            refuse("prefix_cache", "a pinned block holds K/V only — the "
+                   "state at the prefix's end would have to be snapshotted")
+        if ec.drafter == "mesh":
+            refuse("spec_mesh_drafter", "a rejected draft cannot be rolled "
+                   "back out of the state")
+        if ec.drafter:
+            refuse("spec_model_drafter", "a rejected draft cannot be rolled "
+                   "back out of the state")
+        if ec.spec_tokens > 0:
+            refuse("spec_ngram", "a rejected draft cannot be rolled back "
+                   "out of the state")
+        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
+            refuse("seq_attention", "the state is not sharded over a seq axis")
+        if self.mesh.shape.get("model", 1) > 1:
+            refuse("mesh_model", "the mixer's heads and state are not "
+                   "sharded over a model axis (--mesh-shape model:N)")
+        if ec.max_adapters > 0:
+            refuse("multi_lora", "the mixer's projections have no adapter path")
+        if ec.prefill_chunk and self.max_seq_len % ec.prefill_chunk:
+            refuse("prefill_chunk", f"a chunk of {ec.prefill_chunk} does not "
+                   f"divide max_seq_len {self.max_seq_len}, so the last "
+                   "window would re-feed tokens the state already absorbed")
+
+    @property
+    def state_info(self) -> dict | None:
+        """The recurrent state's identity for the boot record (/providers,
+        the ``boot`` line): shapes a row and dtypes — config arithmetic,
+        never allocates. None for models without one."""
+        cfg = self.model_cfg
+        if not cfg.has_ssm:
+            return None
+        ssm = (cfg.n_layers, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        conv = (cfg.n_layers, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
+        row = int(np.prod(ssm)) * 4 + int(np.prod(conv)) * self.dtype.itemsize
+        return {
+            "ssm_row_shape": list(ssm), "ssm_dtype": "float32",
+            "conv_row_shape": list(conv), "conv_dtype": str(self.dtype),
+            "bytes_per_row": row,
+            "max_rows": int(self.engine_cfg.max_batch),
+            "bytes_at_max_rows": row * int(self.engine_cfg.max_batch),
+        }
+
+    def new_state(self, rows: int):
+        """``rows`` slots of zeroed recurrent state (core.init_ssm_state),
+        made under jit so nothing lands eagerly; None for models without."""
+        if not self.model_cfg.has_ssm:
+            return None
+        return self._state_zeros(rows)
+
     def _prefill_fn(self, params, tokens, cache, true_len, offset,
                     block_tables=None, write_floor=None, write_ceil=None,
-                    adapters=None, aids=None, ascales=None):
+                    adapters=None, aids=None, ascales=None, state=None):
         """tokens [B, Tb] padded; returns (cache, last_logits [B, V]).
         `offset` is the global cache position of tokens[:, 0] — 0 for a
         whole-prompt prefill, the running position for chunked prefill.
@@ -693,13 +765,25 @@ class InferenceEngine:
         `adapters`/`aids`/`ascales` (adapters/pool.py): the row's LoRA
         factors apply to the PROMPT too — an adapted wk/wv writes
         adapter-specific K/V, which is exactly why adapter rows never
-        share the base model's prefix cache (scheduler guard)."""
+        share the base model's prefix cache (scheduler guard).
+        ``state`` (recurrent models): the prefilling ROW's state slot
+        ([L, 1, ...], zero for a fresh row, the previous chunk's output
+        otherwise), donated; returned third. The bucket's padded tail
+        leaves it untouched (``valid_len``), and only the last real
+        position's logits are computed."""
+        recurrent = state is not None
         logits, cache = core.forward(
-            params, self.model_cfg, tokens, cache, offset,
+            params, self.model_cfg, tokens,
+            dict(cache, **state) if recurrent else cache, offset,
             attn_fn=self._attn_fn(), block_tables=block_tables,
             paged_write_floor=write_floor, paged_write_ceil=write_ceil,
             adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
+            valid_len=true_len if recurrent else None,
+            last_index=true_len - 1 if recurrent else None,
         )
+        if recurrent:
+            state = {k: cache.pop(k) for k in tuple(state)}
+            return cache, logits[:, 0, :], state
         idx = (true_len - 1).reshape(-1, 1, 1)  # [B,1,1]
         last = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
         return cache, last[:, 0, :]
@@ -1335,6 +1419,8 @@ class InferenceEngine:
             "attention": self.engine_cfg.attention,  # 'auto' resolved
         }
         out["kv"] = self.kv_info
+        if self.model_cfg.has_ssm:
+            out["state"] = self.state_info
         # speculative-decode observability (dashboards read acceptance to
         # judge whether the workload repeats enough to keep K up). Read
         # _scheduler directly — info() must not allocate the batch cache.

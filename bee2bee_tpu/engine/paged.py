@@ -96,6 +96,22 @@ def best_prefix_key(keys, ids) -> tuple[tuple | None, int]:
     return best_key, best_m
 
 
+class RecurrentStateUnsupported(ValueError):
+    """An engine feature that assumes "a row's cache is its K/V pages" was
+    asked for with a model whose rows also own recurrent state (falcon-h1's
+    Mamba-2 mixer). ``feature`` names it. Raised when the engine is built:
+    rollback is not free for a recurrence (spec verify), pinned blocks do
+    not hold the state at a prefix's end (prefix cache), and the state is
+    not sharded over a mesh yet — none of these may be silently wrong."""
+
+    def __init__(self, feature: str, model: str, why: str):
+        self.feature = feature
+        super().__init__(
+            f"{feature} is not supported for {model!r}: its rows carry "
+            f"recurrent state beside their K/V pages, and {why}"
+        )
+
+
 def prefill_chunk_positions(n: int, start: int, bucket: int, S: int) -> list[int]:
     """THE chunk walk of admission prefill: start positions of each
     [pos, pos+bucket) window covering prompt tokens [start, n), with the

@@ -140,6 +140,26 @@ def _phase(name: str):
         return run
 
     return deco
+_G_STATE_ROWS = _REG.gauge(
+    "engine.state_rows",
+    "row slots of recurrent state allocated (= the batch bucket; recurrent "
+    "models only)",
+)
+_G_STATE_BYTES = _REG.gauge(
+    "engine.state_bytes",
+    "device bytes of the rows' recurrent state (ssm + conv; recurrent "
+    "models only)",
+)
+_C_SSM_STEP_ROWS = _REG.counter(
+    "engine.ssm_step_rows",
+    "one-step recurrent updates dispatched: rows x decode steps x layers "
+    "(kind label: live | dead rows of the batch bucket)",
+)
+_C_SSM_SCAN_TOKENS = _REG.counter(
+    "engine.ssm_scan_tokens",
+    "positions a prefill's chunked scan ran over, a layer counted once "
+    "(kind label: real | pad of the prefill bucket)",
+)
 
 
 class Request:
@@ -396,6 +416,14 @@ class BatchScheduler:
         self._tables = np.zeros((max_batch, e.blocks_per_row), np.int32)
         self._row_blocks: list[list[int]] = [[] for _ in range(max_batch)]
         self._cache = e.new_pool()
+        # the OTHER kind of row state (recurrent models, falcon-h1): one
+        # slot a row of the batch bucket beside the pool — [L, bsz, ...],
+        # re-shaped WITH the bucket (the pool is not: a block table gives
+        # a row its pages, nothing gives it another state). Zeroed at
+        # admission, moved by compaction, donated through every decode
+        # window and prefill chunk. None for every other model.
+        self._state = e.new_state(self._bsz)
+        self._recurrent = self._state is not None
         # cur/offsets live as HOST numpy mirrors: every eager device op is
         # a dispatch and a possible sync of its own (cost not measured on
         # the current machine),
@@ -448,6 +476,29 @@ class BatchScheduler:
 
             return jax.tree.map(cp, cache)
 
+        # the recurrent state's row helpers ([L, B, ...] leaves, row dim 1)
+        def s_insert(st, row, b):
+            return jax.tree.map(
+                lambda big, r: jax.lax.dynamic_update_slice_in_dim(
+                    big, r.astype(big.dtype), b, axis=1), st, row)
+
+        def s_move(st, src, dst):
+            return jax.tree.map(
+                lambda big: jax.lax.dynamic_update_slice_in_dim(
+                    big, jax.lax.dynamic_slice_in_dim(big, src, 1, axis=1),
+                    dst, axis=1), st)
+
+        self._state_insert = jax.jit(s_insert, donate_argnums=(0,))
+        self._state_move = jax.jit(s_move, donate_argnums=(0,))
+        # grow: the old rows lead the new zeroed bucket; shrink: active
+        # rows live in [0, active), so the leading rows carry them all
+        self._state_grow = jax.jit(
+            lambda new, old: s_insert(new, old, 0), donate_argnums=(0,)
+        )
+        self._state_shrink = jax.jit(
+            lambda st, n: jax.tree.map(lambda a: a[:, :n], st),
+            static_argnums=(1,),
+        )
         self._counts_zeros = jax.jit(
             lambda b: jnp.zeros((b, 2, V), jnp.int32), static_argnums=0
         )
@@ -471,6 +522,9 @@ class BatchScheduler:
         ic = engine.introspect
         self._meter = ic.meter
         ic.ledger.register("kv_pool", lambda: self._cache)
+        if self._recurrent:
+            ic.ledger.register("state", lambda: self._state)
+            self._set_state_gauges()
         tw_ok = self._declared_table_width
         bs_ok = engine._declared_batch_sizes
         # decode hot-loop mechanisms (docs/PERF.md "Decode hot loop"):
@@ -503,7 +557,8 @@ class BatchScheduler:
         self._chain_sharding: tuple | None = None
         self._decode = ic.sentinel.watch(
             "decode",
-            jax.jit(self._decode_fn, donate_argnums=(2,)),
+            jax.jit(self._decode_fn, donate_argnums=(2,),
+                    donate_argnames=("state",)),
             key_fn=self._decode_key,
             allowed=lambda key: key[0] in bs_ok and tw_ok(key[1]),
         )
@@ -514,7 +569,8 @@ class BatchScheduler:
         else:
             self._decode_pen = ic.sentinel.watch(
                 "decode_penalized",
-                jax.jit(self._decode_pen_fn, donate_argnums=(2, 4)),
+                jax.jit(self._decode_pen_fn, donate_argnums=(2, 4),
+                        donate_argnames=("state",)),
                 key_fn=self._decode_pen_key,
                 allowed=lambda key: key[0] in bs_ok and tw_ok(key[1]),
             )
@@ -711,7 +767,7 @@ class BatchScheduler:
     def _decode_key(params, cur, cache, offsets, temps, topks, topps,
                     minps, key, tables=None, adapters=None, aids=None,
                     ascales=None, counts=None, reps=None, press=None,
-                    freqs=None):
+                    freqs=None, state=None):
         """Sentinel shape key for the decode root: batch bucket, table
         width bucket, and the optional-operand None-flags (min_p, the
         adapter factors, and the fused penalty counts each select a
@@ -727,7 +783,7 @@ class BatchScheduler:
     def _decode_pen_key(params, cur, cache, offsets, counts,
                         temps, topks, topps, minps, reps, press, freqs,
                         key, tables=None, adapters=None, aids=None,
-                        ascales=None):
+                        ascales=None, state=None):
         return (
             int(cur.shape[0]),
             None if tables is None else int(tables.shape[1]),
@@ -737,9 +793,12 @@ class BatchScheduler:
     def _decode_fn(self, params, cur, cache, offsets, temps, topks, topps,
                    minps, key, tables=None, adapters=None, aids=None,
                    ascales=None, counts=None, reps=None, press=None,
-                   freqs=None):
+                   freqs=None, state=None):
         """One chunk: decode K tokens for ALL rows. Returns
-        (cur', cache', offsets', counts', toks [B, K]). `tables` [B, MBb]
+        (cur', cache', offsets', counts', toks [B, K], state').
+        ``state`` (recurrent models; None otherwise) rides the scan carry
+        INSIDE the cache dict — core.forward reads and writes each
+        layer's slice in place — and is split off again on the way out. `tables` [B, MBb]
         selects the paged-pool path: attention gathers only the mapped
         blocks. `adapters`/`aids`/`ascales` (adapters/pool.py) select
         per-row LoRA deltas inside the same step; None keeps the base
@@ -753,6 +812,8 @@ class BatchScheduler:
         counts-free graph (None is a valid scan-carry pytree leaf)."""
         e = self.engine
         B = cur.shape[0]
+        if state is not None:
+            cache = dict(cache, **state)
 
         def step(carry, key_t):
             cur, cache, off, cnt = carry
@@ -773,12 +834,14 @@ class BatchScheduler:
         (cur, cache, offsets, counts), toks = jax.lax.scan(
             step, (cur, cache, offsets, counts), keys
         )
-        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1)
+        if state is not None:
+            state = {k: cache.pop(k) for k in tuple(state)}
+        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1), state
 
     def _decode_pen_fn(
         self, params, cur, cache, offsets, counts,
         temps, topks, topps, minps, reps, press, freqs, key, tables=None,
-        adapters=None, aids=None, ascales=None,
+        adapters=None, aids=None, ascales=None, state=None,
     ):
         """Penalty-carrying decode chunk: counts ride the scan carry and
         every sampled token scatters into its row. The PRE-FUSION split
@@ -788,6 +851,8 @@ class BatchScheduler:
         key draws, so the two are token-for-token identical."""
         e = self.engine
         B = cur.shape[0]
+        if state is not None:
+            cache = dict(cache, **state)
 
         def step(carry, key_t):
             cur, cache, off, counts = carry
@@ -807,7 +872,9 @@ class BatchScheduler:
         (cur, cache, offsets, counts), toks = jax.lax.scan(
             step, (cur, cache, offsets, counts), keys
         )
-        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1)
+        if state is not None:
+            state = {k: cache.pop(k) for k in tuple(state)}
+        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1), state
 
     # ------------------------------------------------------------ loop
 
@@ -893,6 +960,8 @@ class BatchScheduler:
                 e.engine_cfg.prefix_cache_entries, self._alloc
             )
         self._cache = e.new_pool()
+        self._state = e.new_state(1)
+        self._set_state_gauges()
         self.stats.paged_blocks_in_use = 0
         self._cur = np.zeros((1,), np.int32)
         self._offsets = np.zeros((1,), np.int32)
@@ -1049,6 +1118,12 @@ class BatchScheduler:
         sampled token's K/V is written by the NEXT forward), so the
         blocks covering [0, offset) are the complete recoverable state."""
         snap = self._snapshot_meta(req)
+        if self._recurrent:
+            # a row's blocks are NOT its complete state here, and the
+            # recurrent state has no export format yet: ship the metadata
+            # alone, so the importer takes the re-prefill rung (prompt +
+            # accepted tokens rebuild K/V AND state; next tokens equal)
+            return snap
         offset = int(self._offsets[b])
         nb = ceil_div(offset, self._block_size)
         snap.update(offset=offset, cur=int(self._cur[b]), kv_blocks=nb)
@@ -1191,6 +1266,13 @@ class BatchScheduler:
                 )
             else:
                 self._counts = self._counts_shrink(self._counts, new_bsz)
+        if self._recurrent:
+            if new_bsz > old:
+                self._state = self._state_grow(
+                    self.engine.new_state(new_bsz), self._state
+                )
+            else:
+                self._state = self._state_shrink(self._state, new_bsz)
         cur = np.zeros((new_bsz,), np.int32)
         offs = np.zeros((new_bsz,), np.int32)
         aids = np.zeros((new_bsz,), np.int32)
@@ -1204,6 +1286,14 @@ class BatchScheduler:
         self._rows = self._rows[:keep] + [None] * (new_bsz - keep)
         self._bsz = new_bsz
         self._row_params_dirty = True
+        self._set_state_gauges()
+
+    def _set_state_gauges(self):
+        if self._recurrent:
+            _G_STATE_ROWS.set(self._bsz)
+            _G_STATE_BYTES.set(
+                sum(a.nbytes for a in jax.tree.leaves(self._state))
+            )
 
     @_phase("compact")
     def _compact_and_shrink(self):
@@ -1227,6 +1317,12 @@ class BatchScheduler:
             if self._counts is not None:
                 self._counts = self._counts_move(
                     self._counts, np.int32(last), np.int32(hole)
+                )
+            if self._recurrent:
+                # the row's state moves with it (the one device copy a
+                # compaction costs; its pages move by table alone)
+                self._state = self._state_move(
+                    self._state, np.int32(last), np.int32(hole)
                 )
             self._cur[hole] = self._cur[last]
             self._offsets[hole] = self._offsets[last]
@@ -1343,6 +1439,11 @@ class BatchScheduler:
             # guaranteed bit-identical, so the write floor keeps shared
             # donor blocks read-only (attention still reads the donor's
             # values there)
+            # a recurrent row's state is carried from chunk to chunk in a
+            # slot of its own ([L, 1, ...], zero = "no token seen") and
+            # joins the batch's state once the walk is over
+            row_state = e.new_state(1)
+            fed = start
             for pos in prefill_chunk_positions(n, start, bucket, e.max_seq_len):
                 # the write ceil (n) turns the bucket's padded-tail
                 # scatters into null-block writes, so the row only ever
@@ -1353,12 +1454,28 @@ class BatchScheduler:
                 tokens[0, :len(chunk)] = chunk
                 tw = self._table_width(len(row))
                 tbl = np.ascontiguousarray(self._tables[b:b + 1, :tw])
-                self._cache, last_logits = e._prefill(
+                if row_state is not None and pos != fed:
+                    # engine._validate_recurrent_features makes the walk
+                    # monotone; a re-fed token would be absorbed twice,
+                    # so never run past this
+                    raise RuntimeError(
+                        f"recurrent prefill walk re-anchored: window at "
+                        f"{pos}, state holds {fed} tokens"
+                    )
+                fed = pos + len(chunk)
+                out = e._prefill(
                     e.params, tokens, self._cache,
                     np.asarray([len(chunk)], np.int32),
                     np.int32(pos), tbl, np.int32(start), np.int32(n),
-                    **self._lora_args_row(req),
+                    **({"state": row_state} if row_state is not None
+                       else self._lora_args_row(req)),
                 )
+                if row_state is not None:
+                    self._cache, last_logits, row_state = out
+                    _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
+                    _C_SSM_SCAN_TOKENS.inc(bucket - len(chunk), kind="pad")
+                else:
+                    self._cache, last_logits = out
                 # economics: the bucket's padded width is what the chip
                 # ran; only the real prompt tokens were useful (and none
                 # on the re-prefill rung)
@@ -1367,6 +1484,10 @@ class BatchScheduler:
                 )
                 if not recompute:
                     self._meter.note_useful(len(chunk))
+            if row_state is not None:
+                self._state = self._state_insert(
+                    self._state, row_state, np.int32(b)
+                )
             # adapter rows NEVER enter the prefix cache: an adapted wk/wv
             # writes adapter-specific K/V, so sharing those blocks with a
             # base-model (or other-adapter) prompt would serve silently
@@ -2282,11 +2403,18 @@ class BatchScheduler:
                 # outputs carry keeps one executable per sentinel key.
                 cur_d = jax.device_put(cur_d, self._chain_sharding[0])
                 off_d = jax.device_put(off_d, self._chain_sharding[1])
-        lora = self._lora_args()
+        lora = dict(self._lora_args())
+        if self._recurrent:
+            steps = W * K * e.model_cfg.n_layers
+            _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
+            _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
         toks_parts = []
         for _ in range(W):
+            if self._recurrent:
+                # the state chains through the windows like the pool does
+                lora["state"] = self._state
             if self._fused:
-                cur_d, self._cache, off_d, cnts, toks = self._decode(
+                cur_d, self._cache, off_d, cnts, toks, self._state = self._decode(
                     e.params, cur_d, self._cache, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     counts=self._counts if pen else None,
@@ -2298,7 +2426,7 @@ class BatchScheduler:
                 if pen:
                     self._counts = cnts
             elif pen:
-                cur_d, self._cache, off_d, self._counts, toks = (
+                cur_d, self._cache, off_d, self._counts, toks, self._state = (
                     self._decode_pen(
                         e.params, cur_d, self._cache, off_d, self._counts,
                         temps, topks, topps, minps,
@@ -2311,7 +2439,7 @@ class BatchScheduler:
                 # left None it lowers to the counts-free graph, so the
                 # unfused setting differs only in routing pen windows to
                 # the split _decode_pen root above
-                cur_d, self._cache, off_d, _, toks = self._decode(
+                cur_d, self._cache, off_d, _, toks, self._state = self._decode(
                     e.params, cur_d, self._cache, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     **lora,

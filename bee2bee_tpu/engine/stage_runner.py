@@ -75,6 +75,12 @@ class StageRunner:
         # same any-checkpoint rule as the engine
         # (`serve-stage --model auto --checkpoint <dir>`)
         self.model_cfg = model_config.resolve_model_config(model, checkpoint_path)
+        if self.model_cfg.has_ssm:
+            from .paged import RecurrentStateUnsupported
+
+            raise RecurrentStateUnsupported(
+                "pipeline_stages", self.model_cfg.name,
+                "a stage's per-microbatch cache holds K/V only")
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

@@ -104,7 +104,53 @@ class ModelConfig:
     # bloom: LayerNorm over the embeddings before block 0
     embedding_norm: bool = False
 
+    # falcon-h1: a Mamba-2 (SSD) mixer IN PARALLEL with attention in every
+    # block — both read ln1's output and their outputs are summed before
+    # the one residual add. ssm_heads == 0 means "no mixer". Sizes under
+    # the names of the published config.json: mamba_n_heads, mamba_d_head,
+    # mamba_d_state, mamba_n_groups (B and C are shared by the heads of a
+    # group), mamba_d_conv (causal depthwise conv width), mamba_chunk_size
+    # (the chunked scan's block; prefill only — decode is the recurrence)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # falcon-h1's muP multipliers (all 1.0 = inert): embeddings, logits,
+    # the attention branch's input/output, k before the rotation, the
+    # mixer's input/output, (gate pre-activation, down output) of the MLP
+    # and the five zones (z, x, B, C, dt) of the mixer's in-projection
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+
     def __post_init__(self):
+        # json lists (the native-checkpoint model_config.json round-trip)
+        # back to hashable tuples: cfg is a static jit argument
+        object.__setattr__(self, "mlp_multipliers", tuple(self.mlp_multipliers))
+        object.__setattr__(self, "ssm_multipliers", tuple(self.ssm_multipliers))
+        if len(self.mlp_multipliers) != 2 or len(self.ssm_multipliers) != 5:
+            raise ValueError(
+                f"mlp_multipliers needs 2 entries and ssm_multipliers 5, got "
+                f"{self.mlp_multipliers!r} / {self.ssm_multipliers!r}"
+            )
+        if self.ssm_heads:
+            if min(self.ssm_head_dim, self.ssm_state, self.ssm_conv,
+                   self.ssm_chunk) < 1 or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    f"ssm mixer needs positive head/state/conv/chunk sizes "
+                    f"and ssm_groups dividing ssm_heads, got heads="
+                    f"{self.ssm_heads} head_dim={self.ssm_head_dim} state="
+                    f"{self.ssm_state} groups={self.ssm_groups} conv="
+                    f"{self.ssm_conv} chunk={self.ssm_chunk}"
+                )
         if self.sliding_window_residues != (0,):
             object.__setattr__(self, "sliding_window_residues",
                                tuple(self.sliding_window_residues))
@@ -167,6 +213,26 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def has_ssm(self) -> bool:
+        """A recurrent mixer runs in every block: each row then owns a
+        slot of recurrent state beside its K/V pages (core.init_ssm_state)."""
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the causal conv: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_dim(self) -> int:
+        """The in-projection's width: gate z, [x; B; C], dt (one a head)."""
+        return self.ssm_inner + self.ssm_conv_dim + self.ssm_heads
 
 
 def _gpt2(name, d_model, n_layers, n_heads, d_ff=None, vocab=50257, max_pos=1024):
@@ -504,6 +570,48 @@ CONFIGS["phi-2"] = ModelConfig(
 )
 
 
+_FALCON_H1_34B = dict(
+    # tiiuae/Falcon-H1-34B-Instruct config.json: every block runs a Mamba-2
+    # mixer (32 heads x 128, state 256, 2 groups, conv 4, chunk 128) in
+    # parallel with GQA 20/4 x 128 attention, then a SwiGLU MLP; untied
+    # head over 261,120 tokens; fourteen muP multipliers
+    vocab_size=261120, d_model=5120, n_heads=20, n_kv_heads=4, d_ff=21504,
+    head_dim_override=128, max_seq_len=262144, rope_theta=1e11,
+    norm_eps=1e-5, tie_embeddings=False,
+    ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+    ssm_conv=4, ssm_chunk=128,
+    embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+    attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+    ssm_out_multiplier=0.08838834764831845,
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+)
+CONFIGS["falcon-h1-34b"] = ModelConfig(
+    name="falcon-h1-34b", n_layers=72, **_FALCON_H1_34B)
+CONFIGS["falcon-h1-34b-6l"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/falcon-h1-34b-6l
+    # .json): six of the 72 identical blocks, every width, head and the
+    # whole vocabulary; the other 66 blocks would lie on further chips
+    name="falcon-h1-34b-6l", n_layers=6, **_FALCON_H1_34B)
+CONFIGS["tiny-falcon-h1"] = ModelConfig(
+    # every mechanism at CPU-test size: 2 groups, head size != state size
+    # (a transposed [.., head_dim, state] axis fails loudly), a chunk
+    # smaller than the test prompts, all fourteen multipliers off 1.0
+    name="tiny-falcon-h1", vocab_size=512, d_model=64, n_layers=2, n_heads=4,
+    n_kv_heads=2, d_ff=128, max_seq_len=256, tie_embeddings=False,
+    rope_theta=1e6,
+    ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_conv=4,
+    ssm_chunk=8,
+    embedding_multiplier=2.0, lm_head_multiplier=0.5,
+    attention_in_multiplier=0.9, attention_out_multiplier=0.7,
+    key_multiplier=0.6, ssm_in_multiplier=0.8, ssm_out_multiplier=0.75,
+    mlp_multipliers=(0.85, 0.65),
+    ssm_multipliers=(0.9, 0.8, 0.7, 0.6, 0.5),
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -555,6 +663,81 @@ def _parse_rope_scaling(d: dict, default_max_pos: int = 2048) -> tuple | None:
     raise ValueError(
         f"rope_scaling type {rtype!r} is not supported by the native core "
         f"(llama3/linear/yarn only); serve via the ollama/remote backends"
+    )
+
+
+def _falcon_h1_from_hf(d: dict, nm: str) -> ModelConfig:
+    """falcon_h1 (tiiuae/Falcon-H1-*): parallel Mamba-2 mixer + attention
+    blocks. What core.ssm_mixer does not implement is refused BY NAME —
+    a guessed variant under a real model's name would serve wrong logits
+    with no signal."""
+    published = {  # flag -> the one value the implementation covers
+        "mamba_rms_norm": True, "mamba_norm_before_gate": False,
+        "mamba_conv_bias": True, "mamba_proj_bias": False,
+        "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+        "mamba_use_mlp": True,
+    }
+    defaults = {"mamba_rms_norm": False, "mamba_norm_before_gate": True}
+    for flag, want in published.items():
+        got = d.get(flag, defaults.get(flag, want))
+        if bool(got) != want:
+            raise ValueError(
+                f"falcon_h1 config with {flag}={got!r} is not implemented "
+                f"(only {flag}={want!r}, the published Falcon-H1 setting)"
+            )
+    if d.get("attn_layer_indices") is not None:
+        raise ValueError(
+            "falcon_h1 config with attn_layer_indices set is not "
+            "implemented (every block runs attention beside its mixer)"
+        )
+    if d.get("rope_scaling") is not None:
+        raise ValueError(
+            f"falcon_h1 config with rope_scaling={d['rope_scaling']!r} is "
+            "not implemented (the published models use none)"
+        )
+    if d.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"falcon_h1 config with hidden_act={d['hidden_act']!r} is not "
+            "implemented (silu only)"
+        )
+    D, H = d["hidden_size"], d["num_attention_heads"]
+    inner = d.get("mamba_d_ssm")
+    if inner is None:
+        inner = int(d.get("mamba_expand", 2) * D)
+    heads = d["mamba_n_heads"]
+    d_head = d.get("mamba_d_head", "auto")
+    if d_head in (None, "auto"):
+        d_head = inner // heads
+    if d_head * heads != inner:
+        raise ValueError(
+            f"falcon_h1 config: mamba_n_heads {heads} x mamba_d_head "
+            f"{d_head} != mamba_d_ssm {inner}"
+        )
+    hd = d.get("head_dim") or D // H
+    return ModelConfig(
+        name=nm, vocab_size=d["vocab_size"], d_model=D,
+        n_layers=d["num_hidden_layers"], n_heads=H,
+        n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],
+        head_dim_override=None if hd * H == D else hd,
+        max_seq_len=d.get("max_position_embeddings", 8192),
+        rope_theta=float(d.get("rope_theta", 100000.0)),
+        norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        ssm_heads=heads, ssm_head_dim=d_head,
+        ssm_state=d.get("mamba_d_state", 256),
+        ssm_groups=d.get("mamba_n_groups", 1),
+        ssm_conv=d.get("mamba_d_conv", 4),
+        ssm_chunk=d.get("mamba_chunk_size", 256),
+        embedding_multiplier=float(d.get("embedding_multiplier", 1.0)),
+        lm_head_multiplier=float(d.get("lm_head_multiplier", 1.0)),
+        attention_in_multiplier=float(d.get("attention_in_multiplier", 1.0)),
+        attention_out_multiplier=float(d.get("attention_out_multiplier", 1.0)),
+        key_multiplier=float(d.get("key_multiplier", 1.0)),
+        ssm_in_multiplier=float(d.get("ssm_in_multiplier", 1.0)),
+        ssm_out_multiplier=float(d.get("ssm_out_multiplier", 1.0)),
+        mlp_multipliers=tuple(d.get("mlp_multipliers") or (1.0, 1.0)),
+        ssm_multipliers=tuple(d.get("ssm_multipliers") or (1.0,) * 5),
     )
 
 
@@ -847,6 +1030,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
             tie_embeddings=d.get("tie_word_embeddings", False),
             sliding_window=d.get("sliding_window"),
         )
+    if mt == "falcon_h1":
+        return _falcon_h1_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1018,7 +1203,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         return ModelConfig(**kw)
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
-        f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj; "
+        f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
+        f"falcon_h1; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

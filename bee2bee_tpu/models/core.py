@@ -61,7 +61,8 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     "dense" correctness impl physically computes all E experts, but MFU
     is defined on the model's useful math, not an impl's redundancy).
     Excluded: embeddings lookup, norms, biases, rope — O(D) noise next
-    to the O(D²) terms."""
+    to the O(D²) terms. A recurrent mixer (falcon-h1) adds its W_in and
+    W_out."""
     D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
@@ -71,7 +72,10 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
         mlp = D * cfg.n_experts + cfg.n_experts_per_tok * mlp_one
     else:
         mlp = mlp_one
-    return L * (attn + mlp) + D * cfg.vocab_size
+    # falcon-h1: the mixer's in- and out-projection (the scan itself is
+    # O(inner * state) a token — under 1 % of the block — and not counted)
+    ssm = D * cfg.ssm_proj_dim + cfg.ssm_inner * D if cfg.has_ssm else 0
+    return L * (attn + mlp + ssm) + D * cfg.vocab_size
 
 
 def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
@@ -97,6 +101,10 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
                    (+ b_up [L, F], b_down [L, D])
         moe: router [L, D, E], experts w_up|w_gate [L, E, D, F],
              w_down [L, E, F, D]
+        ssm (cfg.has_ssm — falcon-h1's Mamba-2 mixer): w_in [L, D, proj]
+             (proj = inner z + [x; B; C] + heads dt), conv_w [L, C, K],
+             conv_b [L, C], norm [L, inner], w_out [L, inner, D] in
+             ``dtype``; dt_bias, A_log, D [L, heads] ALWAYS float32
     """
     # cfg and dtype are static arguments of the one module-level function,
     # so repeated inits of a config reuse its trace (a fresh partial or
@@ -178,6 +186,29 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             mlp["b_up"] = jnp.zeros((L, F), dtype)
             mlp["b_down"] = jnp.zeros((L, D), dtype)
         layers["mlp"] = mlp
+
+    if cfg.has_ssm:
+        Hs, K, C = cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
+        # what random-normal does not suit, by the Mamba-2 convention:
+        # A_log = log(1..heads) (S4D-real), D = 1, dt_bias = the inverse
+        # softplus of dt drawn log-uniform in [1e-3, 1e-1], norm scale 1.
+        # The three per-head vectors stay float32 whatever the dtype:
+        # exp(A_log) and softplus(dt + dt_bias) set every step's decay
+        dt = jnp.exp(
+            jax.random.uniform(next(keys), (L, Hs), jnp.float32)
+            * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
+        )
+        layers["ssm"] = {
+            "w_in": dense((L, D, cfg.ssm_proj_dim)),
+            "conv_w": dense((L, C, K), scale=1.0 / math.sqrt(K)),
+            "conv_b": jnp.zeros((L, C), dtype),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, Hs + 1, dtype=jnp.float32)), (L, Hs)),
+            "D": jnp.ones((L, Hs), jnp.float32),
+            "norm": jnp.ones((L, cfg.ssm_inner), dtype),
+            "w_out": dense((L, cfg.ssm_inner, D)),
+        }
 
     params["layers"] = layers
     params["final_norm"] = {"scale": jnp.ones((D,), dtype)}
@@ -489,10 +520,15 @@ def _mlp(x, p, cfg: ModelConfig, lora=None):
     if "b_up" in p:
         up = up + p["b_up"]
     gate = lora_matmul(x, p["w_gate"], "w_gate", lora) if "w_gate" in p else None
+    gate_mult, down_mult = cfg.mlp_multipliers  # falcon-h1's muP pair
+    if gate is not None and gate_mult != 1.0:
+        gate = gate * jnp.asarray(gate_mult, gate.dtype)
     h = _activate(up, gate, cfg)
     out = lora_matmul(h, p["w_down"], "w_down", lora)
     if "b_down" in p:
         out = out + p["b_down"]
+    if down_mult != 1.0:
+        out = out * jnp.asarray(down_mult, out.dtype)
     return out
 
 
@@ -593,6 +629,171 @@ def _moe(x, p, cfg: ModelConfig):
     return jnp.einsum("bted,bte->btd", out, weights.astype(out.dtype))
 
 
+# ------------------------------------------- recurrent mixer (falcon-h1)
+
+_HI = lax.Precision.HIGHEST  # the scan's float32 products stay float32 on
+# the TPU's MXU (its default would round both operands to bf16)
+
+
+def init_ssm_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
+    """A batch of rows' recurrent state, all zero (= "no token seen"):
+    {"ssm": [L, B, heads, head_dim, state] float32 — the recurrence
+    accumulates over hundreds of steps, so it is not kept in the model
+    dtype; "conv": [L, B, K-1, C] in ``dtype`` — the conv's last K-1
+    inputs, channels minor (a trailing 3 would pad to 128 TPU lanes)}.
+    The engine keeps one such tree beside the paged pool, one slot a row
+    of the batch bucket (engine/scheduler.py)."""
+    L = cfg.n_layers
+    return {
+        "ssm": jnp.zeros(
+            (L, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+            jnp.float32),
+        "conv": jnp.zeros(
+            (L, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
+    }
+
+
+def _ssm_mup_vector(cfg: ModelConfig):
+    """The five zones of the in-projection (z, x, B, C, dt), each times its
+    ssm_multipliers entry — a trace-time constant."""
+    import numpy as np
+
+    gn = cfg.ssm_groups * cfg.ssm_state
+    widths = (cfg.ssm_inner, cfg.ssm_inner, gn, gn, cfg.ssm_heads)
+    return np.concatenate([
+        np.full((w,), m, np.float32)
+        for w, m in zip(widths, cfg.ssm_multipliers)
+    ])
+
+
+def _ssm_chunked_scan(x, dt, A, Bm, Cm, h0, chunk: int):
+    """Mamba-2's chunked (SSD) form of ``h_t = exp(dt_t A) h_{t-1} +
+    dt_t x_t (outer) B_t; y_t = h_t C_t`` over T positions, float32.
+
+    x [B,T,G,Hg,P]; dt [B,T,G,Hg] (0 at a position = that position leaves
+    the state untouched); A [G,Hg]; Bm, Cm [B,T,G,N]; h0 [B,G,Hg,P,N].
+    Inside a chunk of Q positions the outputs are one masked [Q, Q]
+    product (attention-like); across chunks only the chunk-end states are
+    carried, by a scan of T/Q steps. Returns (y [B,T,G,Hg,P], h_T)."""
+    B, T, G, Hg, P = x.shape
+    Q = min(chunk, T)
+    nc = -(-T // Q)
+    pad = nc * Q - T
+    if pad:  # pad positions: dt = 0 (no decay, no input), x = B = C = 0
+        pad_t = lambda a: jnp.pad(  # noqa: E731
+            a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        x, dt, Bm, Cm = pad_t(x), pad_t(dt), pad_t(Bm), pad_t(Cm)
+    xs = x.reshape(B, nc, Q, G, Hg, P)
+    dts = dt.reshape(B, nc, Q, G, Hg)
+    Bs = Bm.reshape(B, nc, Q, G, -1)
+    Cs = Cm.reshape(B, nc, Q, G, -1)
+    cum = jnp.cumsum(dts * A, axis=2)  # [B,nc,Q,G,Hg], <= 0, decreasing
+    # inside a chunk: y_i += sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) dt_j x_j
+    i_ge_j = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None, None]
+    diff = cum[:, :, :, None] - cum[:, :, None, :]  # [B,nc,i,j,G,Hg]
+    decay = jnp.where(i_ge_j, jnp.exp(jnp.where(i_ge_j, diff, 0.0)), 0.0)
+    cb = jnp.einsum("bcign,bcjgn->bcijg", Cs, Bs, precision=_HI)
+    m = cb[..., None] * decay * dts[:, :, None]
+    y = jnp.einsum("bcijgh,bcjghp->bcighp", m, xs, precision=_HI)
+    # each chunk's own contribution to its end state
+    to_end = jnp.exp(cum[:, :, -1:] - cum) * dts  # [B,nc,Q,G,Hg]
+    s_c = jnp.einsum("bcjghp,bcjgn->bcghpn", xs * to_end[..., None], Bs,
+                     precision=_HI)
+    chunk_decay = jnp.exp(cum[:, :, -1])  # [B,nc,G,Hg]
+
+    def carry_state(h, inp):
+        s, d = inp
+        return h * d[..., None, None] + s, h  # emit the state BEFORE the chunk
+
+    h_last, h_prev = lax.scan(
+        carry_state, h0,
+        (jnp.moveaxis(s_c, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    # what the earlier chunks left: y_i += exp(cum_i) (h_prev C_i)
+    y_off = jnp.einsum("bcign,cbghpn->bcighp", Cs, h_prev, precision=_HI)
+    y = y + y_off * jnp.exp(cum)[..., None]
+    return y.reshape(B, nc * Q, G, Hg, P)[:, :T], h_last
+
+
+def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None):
+    """falcon-h1's Mamba-2 mixer on ``u`` [B, T, D] (ln1's output).
+    Returns (out [B, T, D], new_state or None).
+
+    ``state`` is ONE layer's slice of init_ssm_state ({"ssm": [B, heads,
+    head_dim, state] f32, "conv": [B, K-1, C]}) or None for a stateless
+    full-sequence pass from zero (training / scoring / the cache-less
+    forward). T == 1 runs the one-step recurrence (decode); longer chunks
+    run the chunked scan from the carried state (prefill, chunked prefill).
+
+    ``valid_len`` [B]: only each row's first ``valid_len`` positions are
+    real (a prefill bucket's padded tail). Pads get dt = 0 and are left
+    out of the conv tail, so the returned state is the state after the
+    LAST REAL token: K/V pads are masked and later overwritten, a
+    recurrent state has no "later"."""
+    B, T, _ = u.shape
+    Hs, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    G, K = cfg.ssm_groups, cfg.ssm_conv
+    Hg, inner, C = Hs // G, cfg.ssm_inner, cfg.ssm_conv_dim
+    f32 = jnp.float32
+
+    with jax.named_scope("ssm.in_proj"):
+        proj = matmul(u * jnp.asarray(cfg.ssm_in_multiplier, u.dtype), p["w_in"])
+        proj = proj * jnp.asarray(_ssm_mup_vector(cfg), proj.dtype)
+        z, xbc, dt = jnp.split(proj, [inner, inner + C], axis=-1)
+
+    with jax.named_scope("ssm.conv"):
+        prev = (state["conv"].astype(xbc.dtype) if state is not None
+                else jnp.zeros((B, K - 1, C), xbc.dtype))
+        ext = jnp.concatenate([prev, xbc], axis=1)  # [B, K-1+T, C]
+        w = p["conv_w"].astype(f32)  # [C, K]; tap K-1 is the current token
+        acc = p["conv_b"].astype(f32)
+        for k in range(K):
+            acc = acc + ext[:, k:k + T].astype(f32) * w[:, k]
+        xbc_c = jax.nn.silu(acc)  # [B, T, C] f32
+        if state is None:
+            new_conv = None
+        elif valid_len is None:
+            new_conv = ext[:, T:]
+        else:  # the K-1 inputs that end at each row's last real token
+            new_conv = jax.vmap(
+                lambda e, n: lax.dynamic_slice_in_dim(e, n, K - 1, axis=0)
+            )(ext, valid_len)
+
+    x = xbc_c[..., :inner].reshape(B, T, G, Hg, P)
+    Bm = xbc_c[..., inner:inner + G * N].reshape(B, T, G, N)
+    Cm = xbc_c[..., inner + G * N:].reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"]).reshape(B, T, G, Hg)
+    if valid_len is not None:
+        real = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+        dt = jnp.where(real[..., None, None], dt, 0.0)
+    A = -jnp.exp(p["A_log"].astype(f32)).reshape(G, Hg)
+    h0 = (state["ssm"].reshape(B, G, Hg, P, N) if state is not None
+          else jnp.zeros((B, G, Hg, P, N), f32))
+
+    if T == 1:
+        with jax.named_scope("ssm.step"):
+            dt1, x1 = dt[:, 0], x[:, 0]  # [B,G,Hg], [B,G,Hg,P]
+            dBx = (dt1[..., None] * x1)[..., None] * Bm[:, 0, :, None, None, :]
+            h = h0 * jnp.exp(dt1 * A)[..., None, None] + dBx
+            y = jnp.sum(h * Cm[:, 0, :, None, None, :], axis=-1)[:, None]
+    else:
+        with jax.named_scope("ssm.scan"):
+            y, h = _ssm_chunked_scan(x, dt, A, Bm, Cm, h0, cfg.ssm_chunk)
+
+    with jax.named_scope("ssm.out_proj"):
+        y = y + p["D"].astype(f32).reshape(G, Hg)[..., None] * x
+        # gate first, then an RMS norm over each GROUP's channels
+        # (mamba_rms_norm, norm_before_gate=False), then the learned scale
+        g = y.reshape(B, T, inner) * jax.nn.silu(z.astype(f32))
+        g = g.reshape(B, T, G, inner // G)
+        g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.norm_eps)
+        g = g.reshape(B, T, inner) * p["norm"].astype(f32)
+        out = matmul(g.astype(u.dtype), p["w_out"])
+
+    if state is None:
+        return out, None
+    return out, {"ssm": h.reshape(B, Hs, P, N), "conv": new_conv.astype(state["conv"].dtype)}
+
+
 # ------------------------------------------------------- reusable blocks
 
 
@@ -601,6 +802,8 @@ def embed_tokens(params: Params, cfg: ModelConfig, input_ids, positions):
     x = jnp.take(params["tok_embed"], input_ids, axis=0)
     if cfg.embedding_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    if cfg.embedding_multiplier != 1.0:  # falcon-h1
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.pos_embedding == "learned":
         x = x + jnp.take(params["pos_embed"], positions, axis=0)
     if cfg.embedding_norm:  # bloom: LayerNorm before block 0
@@ -610,7 +813,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, input_ids, positions):
 
 def transformer_block(
     lp: Params, cfg: ModelConfig, x, positions, mask, kv_hook=None,
-    attn_fn=None, rope_local=None, lora=None,
+    attn_fn=None, rope_local=None, lora=None, ssm_hook=None,
 ):
     """One block. lp: a single layer's params (no leading L dim). x [B,T,D].
 
@@ -628,11 +831,24 @@ def transformer_block(
     stacked per-target A/B factors plus the batch's per-row slot ids —
     every projection goes through lora_matmul, which adds each row's
     low-rank delta after the (possibly quantized) base matmul.
+
+    ``ssm_hook(h) -> [B,T,D]`` (cfg.has_ssm, falcon-h1): the recurrent
+    mixer reads the SAME normed input as the attention and its output
+    joins the attention's before the one residual add. The cached paths
+    pass a hook that reads and writes the layer's recurrent state; no
+    hook = a stateless pass from zero state (ssm_mixer).
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h = x if cfg.no_pre_norms else _norm(x, lp["ln1"], cfg)
+    mix_out = None
+    if cfg.has_ssm:
+        mix_out = (ssm_hook(h) if ssm_hook is not None
+                   else ssm_mixer(lp["ssm"], cfg, h)[0])
+        mix_out = mix_out * jnp.asarray(cfg.ssm_out_multiplier, mix_out.dtype)
+        if cfg.attention_in_multiplier != 1.0:
+            h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
     q = lora_matmul(h, lp["attn"]["wq"], "wq", lora)
     k = lora_matmul(h, lp["attn"]["wk"], "wk", lora)
     v = lora_matmul(h, lp["attn"]["wv"], "wv", lora)
@@ -651,6 +867,8 @@ def transformer_block(
         # qwen3/gemma3: head-wise RMSNorm BEFORE rope
         q = _qk_rmsnorm(q, lp["attn"]["q_norm"], cfg.norm_eps)
         k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
+    if cfg.key_multiplier != 1.0:  # falcon-h1: k scaled BEFORE the rotation
+        k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
     if cfg.pos_embedding == "rope":
         if cfg.local_rope_theta is not None and rope_local is not None:
             # gemma-3: SLIDING layers rotate with the local theta and no
@@ -678,6 +896,10 @@ def transformer_block(
     attn_out = lora_matmul(attn_out, lp["attn"]["wo"], "wo", lora)
     if "bo" in lp["attn"]:
         attn_out = attn_out + lp["attn"]["bo"]
+    if mix_out is not None:
+        # two kinds of token mixer, one residual add
+        attn_out = mix_out + attn_out * jnp.asarray(
+            cfg.attention_out_multiplier, attn_out.dtype)
     if cfg.parallel_block:
         # parallel residual: attention and MLP branches sum into x. phi
         # (parallel_norms=1) feeds both from ln1's output; gpt-neox
@@ -708,6 +930,8 @@ def final_logits(params: Params, cfg: ModelConfig, x):
         logits = x @ params["lm_head"]
         if "lm_head_bias" in params:
             logits = logits + params["lm_head_bias"]
+    if cfg.lm_head_multiplier != 1.0:  # falcon-h1
+        logits = logits * jnp.asarray(cfg.lm_head_multiplier, logits.dtype)
     logits = logits.astype(jnp.float32)
     if cfg.logits_softcap:
         c = cfg.logits_softcap
@@ -801,6 +1025,8 @@ def forward(
     # factors {target: {"a": [L, N, din, r], "b": [L, N, r, dout]}}
     adapter_ids=None,  # [B] int32: each row's pool slot (0 = no adapter)
     adapter_scales=None,  # [N] f32: per-slot alpha/rank scaling
+    valid_len=None,  # [B] int32: real positions a row (rest = bucket pad)
+    last_index=None,  # [B] int32: logits of THIS position only -> [B, 1, V]
 ):
     """Run a [B, T] token chunk. Returns (logits [B, T, V], new_cache).
 
@@ -852,8 +1078,30 @@ def forward(
     The write-floor CoW argument carries over unchanged: redirected
     positions touch only the null block, so shared donor pages keep both
     their bytes AND their scales.
+
+    **Recurrent state** (cfg.has_ssm, falcon-h1): the cache dict also
+    carries ``ssm`` [L, B, heads, head_dim, state] f32 and ``conv``
+    [L, B, K-1, C] (init_ssm_state), one slot a BATCH ROW (not a pool
+    block: the state has no positions to page). Every layer's mixer reads
+    its slice and writes the state after this chunk back; ``valid_len``
+    marks a prefill bucket's padded tail, which must leave the state
+    untouched. Chunks of one row must arrive in order, each starting
+    where the last ended — a recurrent state cannot re-feed or skip a
+    token (the scheduler's chunk walk guarantees it).
+
+    ``last_index`` [B] computes the head for one position a row only
+    (prefill needs nothing else; at a 261,120-token vocabulary the full
+    [B, T, V] logits of a 512 bucket would be 0.5 GB).
     """
     B, T = input_ids.shape
+    if cfg.has_ssm and cache is not None and "ssm" not in cache:
+        raise ValueError(
+            f"{cfg.name!r} has a recurrent mixer: its cache must carry the "
+            "rows' state (core.init_ssm_state) beside K/V — decoding "
+            "without it would restart the recurrence at every call"
+        )
+    if valid_len is not None:
+        valid_len = jnp.asarray(valid_len, jnp.int32)
 
     off = jnp.asarray(offset, jnp.int32)
     off_b = jnp.broadcast_to(off.reshape(-1), (B,))  # [B]
@@ -932,6 +1180,26 @@ def forward(
                                   rope_local=rope_flag(layer_idx), lora=lora),
                 None,
             ), None
+
+        def ssm_hook(h):
+            # this layer's recurrent state in, the state after the chunk out
+            nonlocal lcache
+            out, new = ssm_mixer(
+                lp["ssm"], cfg, h,
+                {"ssm": lcache["ssm"][layer_idx],
+                 "conv": lcache["conv"][layer_idx]},
+                valid_len,
+            )
+            # the write-back is where the compiler puts the state's update
+            # (one in-place dynamic-update-slice fusion a layer): a scope of
+            # its own, or a device trace books it to no part of the mixer
+            with jax.named_scope("ssm.state_write"):
+                lcache = dict(
+                    lcache,
+                    ssm=lcache["ssm"].at[layer_idx].set(new["ssm"]),
+                    conv=lcache["conv"].at[layer_idx].set(new["conv"]),
+                )
+            return out
 
         def kv_hook(k, v):
             # write this chunk's K/V at [offset, offset+T) per batch row,
@@ -1040,6 +1308,7 @@ def forward(
             lp, cfg, x, positions, layer_mask(layer_idx),
             kv_hook=kv_hook, attn_fn=attn_fn,
             rope_local=rope_flag(layer_idx), lora=lora,
+            ssm_hook=ssm_hook if cfg.has_ssm else None,
         )
         return (x, lcache), None
 
@@ -1076,6 +1345,9 @@ def forward(
             xs = xs + (adapters,)
         (x, new_cache), _ = lax.scan(layer_body, (x, cache), xs)
 
+    if last_index is not None:
+        idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
+        x = jnp.take_along_axis(x, jnp.broadcast_to(idx, (B, 1, x.shape[2])), axis=1)
     return final_logits(params, cfg, x), new_cache
 
 

@@ -484,6 +484,36 @@ def _convert_phi3(state, cfg: ModelConfig) -> dict:
     return _convert_llama(unfused, cfg)
 
 
+def _convert_falcon_h1(state, cfg: ModelConfig) -> dict:
+    """HF falcon_h1 names -> our layout. The attention, MLP and norms are a
+    llama block under other names (feed_forward.*, pre_ff_layernorm,
+    final_layernorm): rename and DELEGATE to _convert_llama; the Mamba-2
+    mixer's tensors (mamba.*) stack into layers/ssm. conv1d.weight is
+    [C, 1, K] (depthwise) -> [C, K]; linears transpose as everywhere."""
+    renamed = {
+        k.replace(".feed_forward.", ".mlp.")
+         .replace(".pre_ff_layernorm.", ".post_attention_layernorm.")
+         .replace("final_layernorm.", "norm."): v
+        for k, v in state.items() if ".mamba." not in k
+    }
+    params = _convert_llama(renamed, cfg)
+    pre = "model." if any(k.startswith("model.") for k in state) else ""
+    t = lambda a: np.ascontiguousarray(a.T)
+    m = lambda i, k: state[f"{pre}layers.{i}.mamba.{k}"]
+    L = range(cfg.n_layers)
+    params["layers"]["ssm"] = {
+        "w_in": _stack([t(m(i, "in_proj.weight")) for i in L]),
+        "conv_w": _stack([m(i, "conv1d.weight")[:, 0, :] for i in L]),
+        "conv_b": _stack([m(i, "conv1d.bias") for i in L]),
+        "dt_bias": _stack([m(i, "dt_bias") for i in L]),
+        "A_log": _stack([m(i, "A_log") for i in L]),
+        "D": _stack([m(i, "D") for i in L]),
+        "norm": _stack([m(i, "norm.weight") for i in L]),
+        "w_out": _stack([t(m(i, "out_proj.weight")) for i in L]),
+    }
+    return params
+
+
 def _convert_llama(state, cfg: ModelConfig) -> dict:
     """HF Llama/Mistral names → our layout (weights transpose: HF linear is
     [out, in]; ours is [in, out])."""
@@ -590,10 +620,18 @@ def _materialize(params, dtype, host: bool):
     arrays (ml_dtypes handles bf16) when the caller wants to transform
     weights before the upload (e.g. int8 quantization: materializing the
     dense model in HBM first would double the load-time peak)."""
+    # the mixer's per-head decay vectors stay float32 whatever the dtype
+    # (core.init_params keeps them so too)
+    keep = lambda path: (  # noqa: E731
+        len(path) >= 2 and getattr(path[-2], "key", None) == "ssm"
+        and getattr(path[-1], "key", None) in ("A_log", "D", "dt_bias"))
     if host:
-        np_dtype = np.dtype(dtype)
-        return jax.tree.map(lambda a: np.asarray(a).astype(np_dtype), params)
-    return jax.tree.map(lambda a: jnp.asarray(a, dtype), params)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: np.asarray(a).astype(
+                np.float32 if keep(path) else np.dtype(dtype)), params)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: jnp.asarray(a, jnp.float32 if keep(path) else dtype),
+        params)
 
 
 def load_checkpoint(
@@ -634,6 +672,8 @@ def load_checkpoint(
         params = _convert_phi3(state, cfg)
     elif any(".mlp.fc_in." in k for k in state):  # gpt-j's unique mlp names
         params = _convert_gptj(state, cfg)
+    elif any(".mamba.in_proj." in k for k in state):  # falcon_h1's mixer
+        params = _convert_falcon_h1(state, cfg)
     else:
         params = _convert_llama(state, cfg)
     return _materialize(params, dtype, host)

@@ -42,6 +42,30 @@ class BaseService:
     def execute_stream(self, params: dict[str, Any]) -> Iterator[str]:
         raise NotImplementedError
 
+    # rows the backend decodes together (TPUService: its engine's
+    # max_batch); 0 = unknown. A stream's pump holds one thread for life
+    stream_rows: int = 0
+
+    def pump_executor(self):
+        """The executor this service's stream pumps run on (api.py's
+        _stream_service, _stream_via_thread): ``None`` — the loop's default
+        of min(32, cores + 4) threads — while that feeds every batch row
+        plus one queued stream, else a pool of ``stream_rows + 1`` threads
+        made once. A wide batch (a state-space model's reason to exist)
+        cannot be fed through fewer threads than it has rows."""
+        import os
+
+        need = int(self.stream_rows) + 1
+        if need <= min(32, (os.cpu_count() or 1) + 4):
+            return None
+        pool = getattr(self, "_pump_pool", None)
+        if pool is None:
+            from concurrent.futures import ThreadPoolExecutor
+
+            pool = self._pump_pool = ThreadPoolExecutor(
+                max_workers=need, thread_name_prefix="bee2bee-stream-pump")
+        return pool
+
     # -- shared helpers -------------------------------------------------------
 
     async def _execute_via_thread(self, params: dict[str, Any]) -> dict[str, Any]:
@@ -83,7 +107,7 @@ class BaseService:
         # caller as parent (run_in_executor alone drops contextvars — the
         # same guard node._execute_local applies)
         ctx = contextvars.copy_context()
-        fut = loop.run_in_executor(None, ctx.run, pump)
+        fut = loop.run_in_executor(self.pump_executor(), ctx.run, pump)
         try:
             while True:
                 kind, val = await q.get()

@@ -43,6 +43,12 @@ class TPUService(BaseService):
         self._engine_config = engine_config
         self._lora_path = lora_path
 
+    @property
+    def stream_rows(self) -> int:
+        cfg = (self.engine.engine_cfg if self.engine is not None
+               else self._engine_config)
+        return int(cfg.max_batch) if cfg is not None else 0
+
     # loading is split from construction so nodes can announce before the
     # (slow) compile finishes — same shape as the reference's load_sync/
     # load_async split (services.py:36-41)

@@ -166,3 +166,50 @@ def test_lora_with_quantize_int8():
         "tiny-llama", engine_config=EngineConfig(quantize="int8", **KW)
     ))
     assert merged != base_q  # the adapters actually reached the int8 weights
+
+
+# ---- a recurrent-state model (falcon-h1) and the features that assume "a
+# row's cache is its K/V pages": each either carries the state or is refused
+# when the engine (stage runner, drafter) is BUILT, by a typed error naming it.
+# The config-only refusals live in tests/test_falcon_h1.py::REFUSED.
+
+
+def _refused(feature, build):
+    from bee2bee_tpu.engine import RecurrentStateUnsupported
+
+    with pytest.raises(RecurrentStateUnsupported) as err:
+        build()
+    assert err.value.feature == feature and "tiny-falcon-h1" in str(err.value)
+
+
+@pytest.mark.parametrize("feature,mesh,over", [
+    ("mesh_model", MeshSpec(model=2), {}),
+    ("seq_attention", MeshSpec(seq=2), {"attention": "sp"}),
+    ("seq_attention", MeshSpec(seq=2), {}),
+])
+def test_recurrent_model_refuses_mesh_axes_that_do_not_shard_its_state(feature, mesh, over):
+    _refused(feature, lambda: InferenceEngine(
+        "tiny-falcon-h1", mesh=build_mesh(mesh), engine_config=EngineConfig(**over, **KW)))
+
+
+def test_recurrent_model_refuses_pipeline_stages_and_the_drafter_seat():
+    from bee2bee_tpu.engine.drafter import DraftModel
+    from bee2bee_tpu.engine.stage_runner import StageRunner
+
+    _refused("pipeline_stages", lambda: StageRunner(
+        "tiny-falcon-h1", n_stages=2, stage=0, max_seq_len=64, dtype="float32"))
+    _refused("spec_model_drafter", lambda: DraftModel(
+        "tiny-falcon-h1", spec_tokens=4, batch=2, target_max_seq_len=64, dtype="float32"))
+
+
+def test_recurrent_model_serves_with_int8_kv_and_int8_weights():
+    """What does NOT assume pages-only state keeps working: the quantized
+    pool and weight-only int8 compose with the recurrent state."""
+    want = _rollout(InferenceEngine("tiny-falcon-h1", engine_config=EngineConfig(
+        quantize="int8", **KW)))
+    got = _rollout(InferenceEngine("tiny-falcon-h1", engine_config=EngineConfig(
+        quantize="int8", prefill_chunk=16, **KW)))
+    assert got == want
+    r = _rollout(InferenceEngine("tiny-falcon-h1", engine_config=EngineConfig(
+        **{**KW, "cache_dtype": "int8"})))
+    assert len(r) == 8
