@@ -921,17 +921,32 @@ class InferenceEngine:
         scales (EngineConfig.cache_dtype='int8' / --kv-quant)."""
         return jnp.dtype(self.engine_cfg.cache_dtype) == jnp.int8
 
+    @property
+    def kv_in_place(self) -> bool:
+        """True where core.forward writes and reads the pool in place with
+        the ragged kernels alone (ops/ragged.py "Layouts"): the ragged
+        reader over a float pool."""
+        return self.engine_cfg.attention == "flash" and not self.kv_quantized
+
     def new_pool(self):
         """The paged KV block pool, placed with the kv-head `model` spec
         (partition.paged_cache_spec) so TP serving gathers stay local;
         under attention='sp' the slot dim additionally shards over `seq`
         (per-device pool memory 1/seq — the long-context scaling). An
         int8 pool (cache_dtype='int8') carries k_scale/v_scale arrays,
-        sharded like the pool's kv-head dim (partition.paged_scale_spec)."""
+        sharded like the pool's kv-head dim (partition.paged_scale_spec).
+        The in-place path's pool is lane-aligned (below)."""
+        from ..ops.flash import interpret_off_tpu
+
         make = functools.partial(
             core.init_paged_pool,
             self.model_cfg, self.pool_blocks, self.engine_cfg.kv_block_size,
             jnp.dtype(self.engine_cfg.cache_dtype),
+            # on a TPU the in-place path's pool is stored in the kernels'
+            # layout (core.init_paged_pool)
+            lane_aligned=(
+                self.kv_in_place and not interpret_off_tpu(self.mesh)
+            ),
         )
         spec = partition.paged_cache_spec(
             self.model_cfg, self.mesh,

@@ -124,6 +124,12 @@ _C_KV_PAGES_LIVE = _REG.counter(
     "of engine.kv_pages_visited, the entries that map a row's own block "
     "(live / visited = the share of the table the ragged kernel fetches)",
 )
+_C_KV_PAGES_WRITTEN = _REG.counter(
+    "engine.kv_pages_written",
+    "pool pages the page-write kernel copied in and out: batch rows x the "
+    "pages a chunk can touch x the write calls (K and V, every layer, "
+    "every attention call of the dispatch); 0 on the scatter paths",
+)
 
 
 def _phase(name: str):
@@ -593,12 +599,27 @@ class BatchScheduler:
         # data) so compile variants stay O(log) like the table widths;
         # pad writes land in the null block, which dead-row decode
         # scribbles on by design anyway.
+        # Pages travel at the model's head size: a lane-aligned pool's pad
+        # lanes (core.init_paged_pool) are cut on the way out and zeroed on
+        # the way in, so a peer's pool may be laid out either way.
+        hd = e.model_cfg.head_dim
+
         def gather_blocks(cache, idx):
-            return {name: arr[:, :, idx] for name, arr in cache.items()}
+            return {
+                name: arr[:, :, idx][..., :hd] if arr.ndim == 5
+                else arr[:, :, idx]
+                for name, arr in cache.items()
+            }
 
         def scatter_blocks(cache, new, idx):
+            def aligned(blocks, arr):
+                if arr.ndim != 5 or blocks.shape[-1] == arr.shape[-1]:
+                    return blocks
+                pad = arr.shape[-1] - blocks.shape[-1]
+                return jnp.pad(blocks, ((0, 0),) * 4 + ((0, pad),))
+
             return {
-                name: arr.at[:, :, idx].set(new[name])
+                name: arr.at[:, :, idx].set(aligned(new[name], arr))
                 for name, arr in cache.items()
             }
 
@@ -1470,6 +1491,7 @@ class BatchScheduler:
                     **({"state": row_state} if row_state is not None
                        else self._lora_args_row(req)),
                 )
+                self._count_pages_written(1, bucket)
                 if row_state is not None:
                     self._cache, last_logits, row_state = out
                     _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
@@ -1908,6 +1930,19 @@ class BatchScheduler:
             w = min(w, 2)
         return max(1, min(w, e.engine_cfg.max_inflight_chunks))
 
+    def _count_pages_written(self, rows: int, chunk: int, calls: int = 1):
+        """engine.kv_pages_written for one dispatch of ``calls`` forwards
+        over [rows, chunk] tokens — only where core.forward writes through
+        the page-write kernel."""
+        e = self.engine
+        if e.kv_in_place:
+            from ..ops.ragged import chunk_pages  # loaded with the attn_fn
+
+            _C_KV_PAGES_WRITTEN.inc(
+                rows * chunk_pages(chunk, e.engine_cfg.kv_block_size)
+                * calls * 2 * e.model_cfg.n_layers
+            )
+
     def _prepare_window_tables(self, extra: int, calls: int):
         """Paged: grow every active row's block table to cover the next
         device call's writes (positions < offset + extra — W*K for a
@@ -1962,6 +1997,8 @@ class BatchScheduler:
         self.stats.paged_blocks_in_use = self._alloc.used_count
         _C_KV_PAGES_VISITED.inc(self._bsz * tw * calls)
         _C_KV_PAGES_LIVE.inc(sum(live) * calls)
+        # extra / calls = the tokens a call writes: 1 a decode step, K+1
+        self._count_pages_written(self._bsz, extra // calls, calls)
         return np.ascontiguousarray(self._tables[:self._bsz, :tw])
 
     def _spec_eligible(self, b: int, req: Request) -> bool:

@@ -1041,10 +1041,14 @@ def forward(
     (block_tables[b, p // block_size], p % block_size) of every kv head.
     Writes scatter the chunk into the mapped blocks. Attention depends on
     the attn_fn: a RAGGED attn_fn (ops/ragged.make_ragged_attn_fn, marked
-    by its ``ragged`` attribute) reads the pool directly — the kv_hook
-    hands the per-layer pool slices through untouched and the kernel
-    gathers one block per grid step, so neither the [B, S, Hkv, hd] view
-    nor the [T, S] scores ever materialize. The dense path (attn_fn None)
+    by its ``ragged`` attribute) reads the pool directly — the kernel
+    gathers a tile of blocks per grid step, so neither the [B, S, Hkv, hd]
+    view nor the [T, S] scores ever materialize — and over a float pool
+    it WRITES it too: the kv_hook stores the chunk with the page-write
+    kernel and hands the stacked pool and the layer index through, so the
+    pool is the layer loop's carry, in place, and no layer slices it or
+    writes a slice back (the int8 pool's requantising write is XLA's and
+    keeps its per-layer slices). The dense path (attn_fn None)
     gathers the MB mapped blocks per row into that view; either way cache
     traffic per step scales with the table width the caller passes (live
     blocks, bucketed) instead of the pool capacity. The position→slot map
@@ -1144,6 +1148,12 @@ def forward(
     # the per-layer "mask" becomes the compact window selector — nothing
     # S-wide is materialized on this path at all
     ragged = bt is not None and getattr(attn_fn, "ragged", False)
+    # ... and on a float pool it writes the pages itself too: the stacked
+    # pool stays the layer loop's carry, touched only by the two Mosaic
+    # calls, so no layer slices it, re-lays it or writes a slice back
+    # (ops/ragged.py "Layouts"). The int8 pool's requantising write is
+    # XLA's and keeps the per-layer slices.
+    page_write = attn_fn.write if ragged and not quantized else None
     if ragged:
         attn_fn = functools.partial(attn_fn, block_tables=bt)
         layer_mask = make_layer_window(cfg)
@@ -1206,6 +1216,16 @@ def forward(
             # then attend over the whole cache row
             nonlocal lcache
 
+            if page_write is not None:
+                with jax.named_scope("kv.write"):
+                    lcache = dict(lcache, **{
+                        name: page_write(
+                            lcache[name], new, bt, off_b, layer_idx,
+                            wfloor, wceil,
+                        )
+                        for name, new in (("k", k), ("v", v))
+                    })
+                return lcache["k"], lcache["v"]
             if bt is not None:
                 # paged: scatter each position into its mapped (block, slot)
                 # of every kv head. Rows own disjoint blocks (the engine's
@@ -1306,7 +1326,11 @@ def forward(
 
         x = transformer_block(
             lp, cfg, x, positions, layer_mask(layer_idx),
-            kv_hook=kv_hook, attn_fn=attn_fn,
+            kv_hook=kv_hook,
+            attn_fn=(
+                attn_fn if page_write is None
+                else functools.partial(attn_fn, layer=layer_idx)
+            ),
             rope_local=rope_flag(layer_idx), lora=lora,
             ssm_hook=ssm_hook if cfg.has_ssm else None,
         )
@@ -1402,7 +1426,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int | None = None, dtype=j
 
 
 def init_paged_pool(
-    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16
+    cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
+    lane_aligned: bool = False,
 ):
     """Preallocate the paged KV block pool:
     {"k","v"}: [L, Hkv, num_blocks, block_size, hd]. Block 0 is the
@@ -1414,7 +1439,14 @@ def init_paged_pool(
     (kv_head, block) tile per grid step, and Mosaic needs the trailing
     two dims of that tile to be (block_size, hd) — a head axis blocked
     at 1 in trailing position fails to lower, the same constraint that
-    shaped ops/flash.py's head-major transpose.
+    shaped ops/flash.py's head-major transpose. On the ragged path a
+    float pool is written and read by Mosaic calls alone, in place, as
+    the layer loop's carry (forward's kv_hook): inside that loop it must
+    never be sliced, scattered into or selected by XLA, or the compiler
+    gives the carry XLA's layout (head size minor then KV heads for a
+    scatter; the block axis minor-most by default when hd pads to 128
+    lanes) and re-lays it for the kernel in every layer (ops/ragged.py,
+    "Layouts").
 
     With ``dtype=int8`` (EngineConfig.cache_dtype="int8") the pool pages
     store quantized K/V and the dict grows ``k_scale``/``v_scale``
@@ -1423,8 +1455,21 @@ def init_paged_pool(
     quantize-on-write takes it from there, and the scheduler re-zeroes a
     block's entry when the allocator recycles it). Pool HBM halves vs
     bf16 at a 4 / (block_size * head_dim) scale overhead (~0.4% at the
-    16x64 default)."""
-    shape = (cfg.n_layers, cfg.n_kv_heads, num_blocks, block_size, cfg.head_dim)
+    16x64 default).
+
+    ``lane_aligned`` allocates the last axis at the TPU's 128-lane width
+    (phi-3's 96 -> 128; pad lanes are zero and stay zero, the kernels pad
+    what they store and cut what they return). The device's default layout
+    for the stored array is then the kernels' own row-major one, so
+    entering and leaving a program re-lays nothing: at head size 96 the
+    default puts the BLOCK axis minor-most ("it pads nothing"), every
+    prefill call and decode window re-laid the whole pool in and out and
+    held the padded copy as a temporary anyway. Only the in-place path
+    (the ragged kernels over a float pool, on a TPU) asks for it; nothing
+    but ops/ragged.py and the scheduler's block export / import ever
+    looks at the pad."""
+    hd = -(-cfg.head_dim // 128) * 128 if lane_aligned else cfg.head_dim
+    shape = (cfg.n_layers, cfg.n_kv_heads, num_blocks, block_size, hd)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if jnp.dtype(dtype) == jnp.int8:
         sshape = (cfg.n_layers, cfg.n_kv_heads, num_blocks)

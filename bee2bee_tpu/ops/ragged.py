@@ -9,8 +9,9 @@ Paged Attention" — PAPERS.md, arxiv 2604.15464) reads K/V straight from
 the pool:
 
 - **Pool-direct gather, a tile a step**: the pool is head-major
-  ``[Hkv, NB, BS, hd]`` (per-layer slice of core.init_paged_pool's
-  ``[L, Hkv, NB, BS, hd]``). One grid step carries ``Th`` KV heads x
+  ``[Hkv, NB, BS, hd]`` (one layer of core.init_paged_pool's
+  ``[L, Hkv, NB, BS, hd]``: a slice, or the stacked pool with the layer as
+  a prefetched scalar — "Layouts" below). One grid step carries ``Th`` KV heads x
   ``Tp`` consecutive table entries of one row: the pool is passed as
   ``Tp`` K and ``Tp`` V operands, operand p blocked ``(Th, 1, BS, hd)``
   with the scalar-prefetched block-table lookup in its index_map —
@@ -83,6 +84,36 @@ the pool:
   width like every per-step operand, never by pool capacity. The f32
   m/l/acc scratch already isolates accumulation from storage precision,
   so the quantized path changes no softmax math.
+
+- **Layouts: the float pool is written and read IN PLACE, by Mosaic only**
+  (PR 29). On the serving path the stacked pool ``[L, Hkv, NB, BS, hd]`` is
+  the layer loop's carry, and core.forward touches it with two calls of
+  this module alone: ``paged_kv_write`` (aliased pool -> pool, one page of
+  all the shard's KV heads a grid step) and ``ragged_paged_attention`` with
+  a ``layer`` (the page index maps lead with the prefetched layer). Both
+  address it row-major, ``(BS, hd)`` minor, so the carry stays put and a
+  decode step moves B pages a layer instead of the pool. The pool must
+  never meet, inside the layer loop, the two other layouts the TPU compiler
+  would pick for it: XLA's scatter wants head size minor, then the KV-head
+  axis (``{3,0,2,1}``), and the device's default for the stored array puts
+  the BLOCK axis minor-most when the head size pads to the 128 lanes
+  (``{2,4,3,1,0}`` at phi-3's 96). Whenever XLA writes, slices or selects
+  what Mosaic reads, layout assignment gives the carry XLA's layout and
+  re-lays a layer's slice (or, with an XLA write straight into the stacked
+  pool, the WHOLE pool) for the kernel in every layer: eight passes over
+  38-50 MB a layer, 40-52 % of phi-3's device time before this
+  (tests/test_tpu_compile.py keeps that shut). So that the STORED array's
+  default layout is row-major too, and entering and leaving a program
+  re-lays nothing either, the engine allocates this path's pool with the
+  head at the lane width (core.init_paged_pool ``lane_aligned``): both
+  calls take a pool wider than the head, pad what they store and the
+  queries with zeros, and cut the output back. (Pinning the layout of the
+  96-wide array with ``jax.experimental.layout.Format`` compiles to the
+  same program, but an executable with a pinned parameter layout comes
+  back from the persistent compilation cache expecting the default one:
+  PERF.md, Findings PR 29.) The int8 pool's requantising write is XLA's
+  and stays on the per-layer slices (4-D operands, no ``layer``), as do
+  the dense readers, whose reads are XLA's too.
 
 On devices that are not TPUs the kernel runs in pallas interpret mode
 (ops/flash.interpret_off_tpu), so the CPU test suite exercises the exact
@@ -204,6 +235,8 @@ def _ragged_kernel(
     #              (the K/V index maps read them; the body does not)
     off_ref,  # SMEM [B] int32 (scalar-prefetch): position of q[:, 0]
     win_ref,  # SMEM [1] int32 (scalar-prefetch): sliding window (0 = none)
+    lay_ref,  # SMEM [1] int32 (scalar-prefetch): the stacked pool's layer
+    #           (the K/V index maps read it; 0 and unread for a 4-D slice)
     *refs,
     # quantized=True prepends two more scalar-prefetch refs:
     #   kscale_ref, vscale_ref  SMEM [Hkv, B, MBp] f32 scales, pre-gathered
@@ -325,8 +358,8 @@ def _ragged_kernel(
 
 def ragged_paged_attention(
     q,  # [B, T, H, hd]
-    k_pool,  # [Hkv, NB, BS, hd] — per-layer slice of the paged pool
-    v_pool,  # [Hkv, NB, BS, hd]
+    k_pool,  # [Hkv, NB, BS, hd], one layer's slice of the paged pool — or,
+    v_pool,  # with ``layer``, the stacked pool [L, Hkv, NB, BS, hd] itself
     block_tables,  # [B, MB] int32: pool block ids per row (0 = null block)
     offset,  # [] or [B] int32: global position of q[:, 0]
     window=None,  # [] or [1] int32 (traced ok) or python int: sliding
@@ -337,6 +370,8 @@ def ragged_paged_attention(
     interpret: bool | None = None,
     k_scale=None,  # [Hkv, NB] f32: int8-pool per-page-per-head scales;
     v_scale=None,  # both present = quantized pool, dequant in-kernel
+    layer=None,  # [] or [1] int32 (traced ok): the pool is STACKED and
+    #              this is the layer to read, in place (module docstring)
 ):
     """Causal attention for a [B, T] chunk over the paged pool; returns
     [B, T, H*hd] (core._attention ABI). T=1 is decode, T=K+1 spec verify,
@@ -345,12 +380,27 @@ def ragged_paged_attention(
     step carries follows from the shapes (_tile_plan). With
     ``k_scale``/``v_scale`` the pool is int8 (core.init_paged_pool's
     quantized layout) and each fetched page dequantizes in VMEM before
-    its dot — same tiles, same softmax math, half the pool HBM traffic."""
+    its dot — same tiles, same softmax math, half the pool HBM traffic.
+    With ``layer`` the pool operands are the stacked 5-D pool and the page
+    index maps lead with the prefetched layer: the same copies from the
+    same bytes, and no slice of the pool exists outside the kernel."""
     B, T, H, hd = q.shape
-    Hkv, NB, BS, _ = k_pool.shape
+    stacked = layer is not None
+    if k_pool.ndim != 4 + stacked:
+        raise ValueError(
+            f"pool of rank {k_pool.ndim}: a layer's slice [Hkv, NB, BS, hd] "
+            "takes no `layer`, the stacked pool [L, Hkv, NB, BS, hd] needs one"
+        )
+    Hkv, NB, BS, _ = k_pool.shape[-4:]
     MB = block_tables.shape[1]
     G = H // Hkv
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
+    hd_q, hd = hd, k_pool.shape[-1]
+    if hd != hd_q:
+        # a lane-aligned pool (core.init_paged_pool): its pad lanes hold
+        # zeros, so zero lanes on q leave every score as it was, and the
+        # output's pad lanes, cut off below, are zero
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, hd - hd_q),))
     interpret = interpret_off_tpu() if interpret is None else interpret
     quantized = k_scale is not None
     if quantized and v_scale is None:
@@ -379,6 +429,7 @@ def ragged_paged_attention(
         (B,),
     )
     win = jnp.asarray(window if window is not None else 0, jnp.int32).reshape(-1)[:1]
+    lay = jnp.asarray(layer if stacked else 0, jnp.int32).reshape(-1)[:1]
 
     kernel = functools.partial(
         _ragged_kernel,
@@ -392,26 +443,29 @@ def ragged_paged_attention(
         quantized=quantized,
     )
 
-    # index maps take the scalar-prefetch refs as trailing args (3 of
-    # them, or 5 with the quantization scales — the variadic tail keeps
+    # index maps take the scalar-prefetch refs as trailing args (4 of
+    # them, or 6 with the quantization scales — the variadic tail keeps
     # one lambda serving both). The K/V maps ARE the gather: entry p of
-    # the step's tile reads Th heads of pool block tables[b, tile*Tp + p],
-    # and a dead step names the tile its neighbour named (_fetched_tile),
-    # which the pipeline then does not copy again
+    # the step's tile reads Th heads of pool block tables[b, tile*Tp + p]
+    # (of layer lay[0] when the pool is stacked), and a dead step names
+    # the tile its neighbour named (_fetched_tile), which the pipeline
+    # then does not copy again
     def page_map(p):
-        def index(b, h, i, j, tb, off_, win_, *_):
+        def index(b, h, i, j, tb, off_, win_, lay_, *_):
             lo, hi = _live_tiles(
                 off_[b], win_[0], i, chunk=T, block_q=bq,
                 tile_tokens=Tp * BS, n_tiles=n_tiles,
             )
-            return h, tb[b, _fetched_tile(j, lo, hi, n_tiles) * Tp + p], 0, 0
+            page = (h, tb[b, _fetched_tile(j, lo, hi, n_tiles) * Tp + p], 0, 0)
+            return (lay_[0], *page) if stacked else page
 
         return index
 
     qo_spec = pl.BlockSpec((1, Th, bq, hd), lambda b, h, i, j, *_: (b, h, i, 0))
-    page_specs = [pl.BlockSpec((Th, 1, BS, hd), page_map(p)) for p in range(Tp)]
+    page_block = (None,) * stacked + (Th, 1, BS, hd)
+    page_specs = [pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5 if quantized else 3,
+        num_scalar_prefetch=6 if quantized else 4,
         grid=(B, Hkv // Th, nqp // bq, n_tiles),
         in_specs=[qo_spec] + page_specs + page_specs,
         out_specs=qo_spec,
@@ -445,10 +499,144 @@ def ragged_paged_attention(
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(tables, off, win, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
+    )(tables, off, win, lay, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
     # [B, Hkv, nqp, hd] -> [B, T, H*hd]
-    out = out[:, :, :nq].reshape(B, Hkv, G, T, hd).transpose(0, 3, 1, 2, 4)
-    return out.reshape(B, T, H * hd)
+    out = out[:, :, :nq, :hd_q].reshape(B, Hkv, G, T, hd_q).transpose(0, 3, 1, 2, 4)
+    return out.reshape(B, T, H * hd_q)
+
+
+# ----------------------------------------------------------- page write
+
+
+def chunk_pages(T: int, BS: int) -> int:
+    """Most pages a chunk of T positions can touch, whatever its start."""
+    return (T + BS - 2) // BS + 1
+
+
+def _write_limits(floor, ceil):
+    """[2] int32 (floor, ceil) of the written positions; None = no limit."""
+    return jnp.stack([
+        jnp.asarray(0 if floor is None else floor, jnp.int32).reshape(()),
+        jnp.asarray(
+            jnp.iinfo(jnp.int32).max if ceil is None else ceil, jnp.int32
+        ).reshape(()),
+    ])
+
+
+def _written_span(off, lim_ref, chunk):
+    """[lo, hi): the positions of a row's chunk that may be written — the
+    chunk itself, cut by the write floor and ceil."""
+    return jnp.maximum(off, lim_ref[0]), jnp.minimum(off + chunk, lim_ref[1])
+
+
+def _page_write_kernel(
+    tables_ref,  # SMEM [B, MB] int32 (the pool's index map reads it)
+    off_ref,  # SMEM [B] int32: position of the chunk's first token
+    lay_ref,  # SMEM [1] int32: layer of the stacked pool (index map)
+    lim_ref,  # SMEM [2] int32: write floor, write ceil
+    new_ref,  # [Hkv, BS, hd] the chunk's rows laid out as THIS page's slots
+    #           ([Hkv, 1, hd] for a one-token chunk: its one row)
+    page_ref,  # [Hkv, BS, hd] the page as the pool holds it
+    out_ref,  # the same block of the same buffer (aliased)
+    *,
+    block_size: int,
+    chunk: int,
+):
+    off = off_ref[pl.program_id(0)]
+    lo, hi = _written_span(off, lim_ref, chunk)
+    page = page_ref[...]
+    pos = (off // block_size + pl.program_id(1)) * block_size + (
+        jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+    )
+    out_ref[...] = jnp.where(
+        (pos >= lo) & (pos < hi), jnp.broadcast_to(new_ref[...], page.shape), page
+    )
+
+
+def paged_kv_write(
+    pool,  # [L, Hkv, NB, BS, hd]: the stacked K (or V) pool, written in place
+    new,  # [B, T, Hkv, hd]: the chunk's K (or V), any float dtype
+    block_tables,  # [B, MB] int32
+    offset,  # [] or [B] int32: position of new[:, 0]
+    layer,  # [] or [1] int32 (traced ok)
+    floor=None,  # [] int32: positions below it are not written
+    ceil=None,  # [] int32: positions at / over it are not written
+    interpret: bool | None = None,
+):
+    """Store a chunk's K (or V) into the pages its positions map to, IN
+    PLACE in the stacked pool; returns the pool (the same buffer: the
+    operand is aliased to the result). Row b's position ``p`` in
+    ``[offset_b, offset_b + T)`` and in ``[floor, ceil)`` goes to slot
+    ``p % BS`` of block ``tables[b, p // BS]`` of every KV head of
+    ``layer``; every other byte of every block a row owns keeps its value.
+
+    Grid ``(row, page of the chunk)``, a page of ALL the shard's KV heads a
+    step: the step copies the page in, replaces the slots the chunk owns,
+    copies it out (2 x Hkv x BS x hd a page; a decode step of B rows moves
+    B pages a layer). A chunk need not start on a page edge, so it may
+    touch ``(T + BS - 2) // BS + 1`` pages; the chunk is laid out in page
+    coordinates beforehand (a chunk-sized XLA gather, nothing pool-sized).
+    A grid page that holds no position to write — past the chunk, past the
+    table, wholly under the floor or over the ceil, a dead row's — names
+    the null block 0 and rewrites it unchanged. Rows own disjoint blocks
+    (the allocator's invariant), so no two steps write one live page.
+
+    This is the write half of the pool's in-place contract (module
+    docstring, "Layouts"): it must be a Mosaic call, like the read."""
+    _, Hkv, _, BS, hd = pool.shape
+    B, T = new.shape[:2]
+    MB = block_tables.shape[1]
+    interpret = interpret_off_tpu() if interpret is None else interpret
+    tables = jnp.asarray(block_tables, jnp.int32)
+    off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
+    lay = jnp.asarray(layer, jnp.int32).reshape(-1)[:1]
+    lim = _write_limits(floor, ceil)
+    n_pages = chunk_pages(T, BS)
+    new = new.astype(pool.dtype)
+    if new.shape[-1] != hd:  # a lane-aligned pool: its pad lanes hold zeros
+        new = jnp.pad(new, ((0, 0),) * 3 + ((0, hd - new.shape[-1]),))
+    if T == 1:
+        rows = new[:, :, :, None, :]  # [B, 1, Hkv, 1, hd]: every slot's candidate
+    else:
+        # slot s of the chunk's page p holds chunk position p*BS + s - off % BS
+        src = jnp.arange(n_pages * BS, dtype=jnp.int32)[None] - (off % BS)[:, None]
+        rows = jnp.take_along_axis(
+            new, jnp.clip(src, 0, T - 1)[:, :, None, None], axis=1
+        ).reshape(B, n_pages, BS, Hkv, hd).transpose(0, 1, 3, 2, 4)
+
+    def page_index(b, p, tb, off_, lay_, lim_):
+        page = off_[b] // BS + p
+        lo, hi = _written_span(off_[b], lim_, T)
+        live = (page * BS < hi) & (page * BS + BS > lo) & (page < MB)
+        return (
+            lay_[0], 0,
+            jnp.where(live, tb[b, jnp.minimum(page, MB - 1)], 0), 0, 0,
+        )
+
+    page_spec = pl.BlockSpec((None, Hkv, None, BS, hd), page_index)
+    return pl.pallas_call(
+        functools.partial(_page_write_kernel, block_size=BS, chunk=T),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, n_pages),
+            in_specs=[
+                pl.BlockSpec(
+                    (None, None, Hkv, rows.shape[3], hd),
+                    lambda b, p, *_: (b, p, 0, 0, 0),
+                ),
+                page_spec,
+            ],
+            out_specs=page_spec,
+        ),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={5: 0},  # the pool, after 4 scalars and the rows
+        compiler_params=pltpu.CompilerParams(
+            # in order: two steps may name the null block, never a live one
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+    )(tables, off, lay, lim, rows, pool)
 
 
 # ----------------------------------------------------- TP/mesh wrapper
@@ -457,13 +645,15 @@ def ragged_paged_attention(
 def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
     """Build an attn_fn (core.transformer_block ABI) that reads the paged
     pool directly. core.forward marks it via the ``ragged`` attribute: on
-    the block-tables path the kv_hook hands the POOL SLICES through as
-    (k, v), forward partials in the block tables, and the per-layer mask
-    argument becomes the compact [1] int32 window selector
+    the block-tables path forward partials in the block tables, and the
+    per-layer mask argument becomes the compact [1] int32 window selector
     (core.make_layer_window) instead of a bool mask — nothing S-wide is
-    ever built. On an int8 pool the hook hands (pool slice, [Hkv, NB]
-    scale slice) TUPLES through and the kernel dequantizes per gathered
-    block.
+    ever built. Over a float pool the kv_hook stores the chunk through
+    ``attn.write`` (paged_kv_write under this mesh) and hands the STACKED
+    pool through as (k, v) with ``layer=`` partialled in: written and read
+    in place (module docstring, "Layouts"). On an int8 pool the hook hands
+    (pool slice, [Hkv, NB] scale slice) TUPLES through and the kernel
+    dequantizes per gathered block.
 
     Under a non-trivial mesh the kernel runs per-shard via shard_map
     (pallas_call has no SPMD partitioning rule): q heads and the pool's
@@ -484,7 +674,30 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
     if interpret is None:
         interpret = interpret_off_tpu(mesh)
 
-    def attn(q, k, v, mask, cfg, positions=None, block_tables=None):
+    def mesh_axes(B, Hkv):
+        """(batch, q-head, kv-head) axis names under this mesh, or None
+        for a single device (no shard_map at all)."""
+        if mesh is None or all(n == 1 for n in mesh.shape.values()):
+            return None
+        tp = mesh.shape.get("model", 1)
+        data = mesh.shape.get("data", 1)
+        return (
+            "data" if data > 1 and B % data == 0 else None,
+            "model" if tp > 1 else None,
+            "model" if tp > 1 and Hkv % tp == 0 else None,
+        )
+
+    def scalars(B, offset, *rest):
+        off = jnp.broadcast_to(
+            jnp.asarray(offset if offset is not None else 0, jnp.int32).reshape(-1),
+            (B,),
+        )
+        return (off,) + tuple(
+            jnp.asarray(x if x is not None else 0, jnp.int32).reshape(-1)[:1]
+            for x in rest
+        )
+
+    def attn(q, k, v, mask, cfg, positions=None, block_tables=None, layer=None):
         if block_tables is None:
             raise ValueError(
                 "the ragged paged-attention attn_fn needs block tables "
@@ -497,42 +710,34 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
         if isinstance(k, tuple):
             k, k_scale = k
             v, v_scale = v
+        stacked = layer is not None  # k, v: the stacked pool, read in place
         window = mask  # the ragged path's per-layer [1] int32 selector
         offset = positions[:, 0] if positions is not None else None
         sm_scale = 1.0 / math.sqrt(cfg.attn_scale or cfg.head_dim)
         softcap = float(cfg.attn_logit_softcap or 0.0)
-        if mesh is None or all(n == 1 for n in mesh.shape.values()):
+        axes = mesh_axes(q.shape[0], k.shape[-4])
+        if axes is None:
             return ragged_paged_attention(
                 q, k, v, block_tables, offset, window,
                 sm_scale=sm_scale, logit_softcap=softcap, interpret=interpret,
-                k_scale=k_scale, v_scale=v_scale,
+                k_scale=k_scale, v_scale=v_scale, layer=layer,
             )
-        B = q.shape[0]
-        Hkv = k.shape[0]
-        tp = mesh.shape.get("model", 1)
-        data = mesh.shape.get("data", 1)
-        batch_ax = "data" if data > 1 and B % data == 0 else None
-        head_ax = "model" if tp > 1 else None
-        kv_ax = "model" if tp > 1 and Hkv % tp == 0 else None
-        off = jnp.broadcast_to(
-            jnp.asarray(offset if offset is not None else 0, jnp.int32).reshape(-1),
-            (B,),
-        )
-        win = jnp.asarray(
-            window if window is not None else 0, jnp.int32
-        ).reshape(-1)[:1]
+        batch_ax, head_ax, kv_ax = axes
+        off, win, lay = scalars(q.shape[0], offset, window, layer)
         # ONE shard_map for both pool precisions: the int8 scales shard
         # exactly like the pool's kv-head dim (their block dim, like the
         # pool's, never shards) and simply extend the operand tuple
         quant = k_scale is not None
         scale_args = (k_scale, v_scale) if quant else ()
+        pool_spec = P(None, kv_ax) if stacked else P(kv_ax)
 
-        def body(q_, k_, v_, t_, o_, w_, *sc):
+        def body(q_, k_, v_, t_, o_, w_, l_, *sc):
             return ragged_paged_attention(
                 q_, k_, v_, t_, o_, w_,
                 sm_scale=sm_scale, logit_softcap=softcap, interpret=interpret,
                 k_scale=sc[0] if sc else None,
                 v_scale=sc[1] if sc else None,
+                layer=l_ if stacked else None,
             )
 
         mapped = shard_map(
@@ -540,20 +745,51 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
             mesh=mesh,
             in_specs=(
                 P(batch_ax, None, head_ax, None),
-                P(kv_ax),
-                P(kv_ax),
+                pool_spec,
+                pool_spec,
                 P(batch_ax),
                 P(batch_ax),
+                P(),
                 P(),
             ) + (P(kv_ax),) * len(scale_args),
             out_specs=P(batch_ax, None, head_ax),
             check_vma=False,
         )
         return mapped(
-            q, k, v, jnp.asarray(block_tables, jnp.int32), off, win,
+            q, k, v, jnp.asarray(block_tables, jnp.int32), off, win, lay,
             *scale_args,
         )
 
+    def write(pool, new, block_tables, offset, layer, floor=None, ceil=None):
+        """paged_kv_write under this attn_fn's mesh: per shard of the
+        pool's kv heads, like the read. The batch is NOT split over
+        `data`: the pool is replicated there (partition.paged_cache_spec),
+        so every replica must store every row."""
+        axes = mesh_axes(new.shape[0], pool.shape[1])
+        if axes is None:
+            return paged_kv_write(
+                pool, new, block_tables, offset, layer, floor, ceil,
+                interpret=interpret,
+            )
+        kv_ax = axes[2]
+        off, lay = scalars(new.shape[0], offset, layer)
+        lim = _write_limits(floor, ceil)
+        mapped = shard_map(
+            lambda p_, n_, t_, o_, l_, m_: paged_kv_write(
+                p_, n_, t_, o_, l_, m_[0], m_[1], interpret=interpret
+            ),
+            mesh=mesh,
+            in_specs=(
+                P(None, kv_ax), P(None, None, kv_ax), P(), P(), P(), P(),
+            ),
+            out_specs=P(None, kv_ax),
+            check_vma=False,
+        )
+        return mapped(
+            pool, new, jnp.asarray(block_tables, jnp.int32), off, lay, lim
+        )
+
+    attn.write = write
     attn.ragged = True
     return attn
 

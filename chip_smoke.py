@@ -496,6 +496,8 @@ KERNEL_CASES = {
                                  window=4096, lengths=(64, 1000)),
     "phi3_prefill_2048": dict(heads=(32, 32, 96), B=1, T=2048, MB=128,
                               lengths=(2048, 2048)),
+    # falcon-h1's attention, the wide cell's decode: GQA 20/4 x 128, 64 rows
+    "h1_decode": dict(heads=(20, 4, 128), B=64, T=1, MB=32, lengths=(64, 500)),
 }
 # microseconds a call of the one-page-one-head kernel this one replaced, on
 # the same inputs (tree ee64104, TPU v5 lite, my chip run, PR 27; the two
@@ -571,7 +573,9 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
     us = (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
     diff = np.abs(got - want)
     worst = float(np.max(diff / (KERNEL_ATOL + KERNEL_RTOL * np.abs(want))))
+    in_place = _in_place_case(args, window)
     return {
+        **in_place,
         "H": H, "Hkv": Hkv, "hd": hd, "B": B, "T": T, "table_width": MB,
         "window": window, "live_pages": int(pages.sum()),
         "out_shape": list(got.shape),
@@ -584,8 +588,69 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
         "one_page_kernel_us_per_call": ONE_PAGE_KERNEL_US.get(name),
         "ok": bool(
             has_kernel and np.isfinite(got).all() and got.shape == (B, T, H * hd)
-            and worst <= 1.0
+            and worst <= 1.0 and in_place["write_matches_scatter"]
+            and in_place["stacked_read_matches_sliced_read"]
         ),
+    }
+
+
+def _in_place_case(args, window) -> dict:
+    """The same case on a STACKED, lane-aligned pool of two layers, as
+    core.forward runs it on a TPU since PR 29: the page-write of the chunk's K
+    into layer 1, compiled and donated, against XLA's scatter (bit for bit in
+    every block a row owns), then the kernel reading layer 1 in place against
+    its read of the unaligned slice."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.ops.ragged import paged_kv_write, ragged_paged_attention
+
+    q, k_pool, v_pool, tables, off = args
+    BS, T = KERNEL_BLOCK, q.shape[1]
+    new = (q[:, :, : k_pool.shape[0]] * 0.5).astype(k_pool.dtype)  # [B,T,Hkv,hd]
+    positions = off[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    blk = jnp.take_along_axis(tables, positions // BS, axis=1)
+    want = np.asarray(
+        k_pool.at[:, blk, positions % BS].set(jnp.transpose(new, (2, 0, 1, 3))),
+        np.float32,
+    )
+    hd = k_pool.shape[-1]
+    lanes = ((0, 0),) * 4 + ((0, -hd % 128),)
+    stacked = jnp.pad(jnp.stack([v_pool, k_pool]), lanes)
+    write = jax.jit(
+        lambda pool, new: paged_kv_write(
+            pool, new, tables, off, jnp.int32(1), interpret=False),
+        donate_argnums=(0,),
+    ).lower(stacked, new).compile()
+    stacked = write(stacked, new)
+    got = np.asarray(stacked, np.float32)
+    pad_is_zero = not got[..., hd:].any()
+    got = got[..., :hd]
+    t0 = time.perf_counter()
+    for _ in range(KERNEL_TIMED_CALLS):
+        stacked = write(stacked, new)
+    stacked.block_until_ready()
+    us = (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
+
+    def read(k, v, layer=None):
+        return ragged_paged_attention(
+            q, k, v, tables, off, window=window, interpret=False, layer=layer)
+
+    sliced = jax.jit(read)(stacked[1, ..., :hd], stacked[1, ..., :hd])
+    in_place = jax.jit(read)(stacked, stacked, jnp.int32(1))
+    return {
+        "write_matches_scatter": bool(
+            np.array_equal(got[1][:, 1:], want[:, 1:]) and pad_is_zero
+            and np.array_equal(got[0], np.asarray(v_pool, np.float32))
+        ),
+        "write_us_per_call": round(us, 1),
+        # the same pages through 128 lanes instead of hd: the kernel-vs-dense
+        # tolerance (the zero lanes may regroup the MXU's partial sums)
+        "stacked_read_matches_sliced_read": bool(np.all(
+            np.abs(np.asarray(in_place, np.float32) - np.asarray(sliced, np.float32))
+            <= KERNEL_ATOL + KERNEL_RTOL * np.abs(np.asarray(sliced, np.float32))
+        )),
     }
 
 

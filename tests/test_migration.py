@@ -105,6 +105,45 @@ def test_kv_import_roundtrip_greedy_parity():
         b.close()
 
 
+def test_lane_aligned_pool_exports_at_the_models_head_size():
+    """On a TPU the in-place path stores the pool lane-aligned
+    (core.init_paged_pool: tiny-llama's 16 in 128 lanes here, forced onto the
+    CPU engine). The ragged kernels serve from it token for token, blocks
+    leave it at the model's head size, and a peer laid out either way takes
+    them: aligned -> plain and plain -> aligned both resume with parity."""
+    import functools
+
+    import jax
+
+    from bee2bee_tpu.models import core
+
+    def aligned_engine():
+        eng = _engine(attention="flash")
+        sch = eng.scheduler
+        sch._cache = jax.jit(functools.partial(
+            core.init_paged_pool, eng.model_cfg, eng.pool_blocks,
+            eng.engine_cfg.kv_block_size, sch._cache["k"].dtype,
+            lane_aligned=True,
+        ))()
+        assert sch._cache["k"].shape[-1] == 128 != eng.model_cfg.head_dim
+        return eng
+
+    plain, a, b = _engine(), aligned_engine(), aligned_engine()
+    try:
+        base = plain.generate(PROMPT, max_new_tokens=24)
+        assert a.generate(PROMPT, max_new_tokens=24).token_ids == base.token_ids
+        for src, dst in ((a, plain), (plain, b)):
+            snap, kv, _req = _checkpoint_mid_decode(src)
+            assert kv["k"].shape[-1] == kv["v"].shape[-1] == src.model_cfg.head_dim
+            out, result = _drain_events(dst.import_generation(snap, kv), snap["out"])
+            assert out == base.token_ids
+            assert dst.scheduler.stats.import_reprefills == 0
+        assert not np.asarray(b.scheduler._cache["k"][..., 16:]).any()
+    finally:
+        for eng in (plain, a, b):
+            eng.close()
+
+
 def test_reprefill_import_rung_parity():
     """The fallback rung: same snapshot, no KV shipped — the target
     re-prefills prompt+accepted and still resumes token-for-token."""

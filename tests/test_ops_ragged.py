@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import get_config
 from bee2bee_tpu.ops import ragged_paged_attention
+from bee2bee_tpu.ops.ragged import chunk_pages, make_ragged_attn_fn, paged_kv_write
 
 CFG = get_config("tiny-llama")  # only shape-free code paths used
 
@@ -502,6 +503,205 @@ def test_scheduler_counts_visited_and_live_pages():
     v1, l1 = counters()
     assert v1 - v0 == sum(s[2] for s in seen)
     assert l1 - l0 == sum(s[3] for s in seen)
+
+
+# ------------------------------------- the pool written and read in place
+
+
+def _scatter_write(pool, new, tables, off, layer, floor, ceil):
+    """core.forward's kv_hook scatter (the dense readers' write, and the
+    page-write's specification), on one layer of a stacked pool."""
+    BS = pool.shape[3]
+    positions = off[:, None] + jnp.arange(new.shape[1], dtype=jnp.int32)[None]
+    blk = jnp.take_along_axis(tables, positions // BS, axis=1)
+    slot = positions % BS
+    if floor is not None:
+        blk = jnp.where(positions >= floor, blk, 0)
+    if ceil is not None:
+        blk = jnp.where(positions < ceil, blk, 0)
+    newT = jnp.transpose(new, (2, 0, 1, 3))
+    return pool.at[layer].set(
+        pool[layer].at[:, blk, slot].set(newT.astype(pool.dtype))
+    )
+
+
+# offs, T (BS = 16, tables 8 wide); floor / ceil as core.forward takes them
+PAGE_WRITE_CASES = {
+    "decode-slot-mid-page": dict(offs=[5, 16, 31, 100], T=1),
+    "spec-verify-straddles-a-page-edge": dict(offs=[5, 12, 31, 90], T=7),
+    "prefill-chunk-unaligned-tail": dict(offs=[37], T=64, ceil=37 + 50),
+    "prefill-chunk-on-a-page-edge": dict(offs=[32], T=64, ceil=32 + 64),
+    "floor-above-off": dict(offs=[32], T=64, floor=53, ceil=32 + 60),
+    "floor-above-the-whole-chunk": dict(offs=[0, 16], T=32, floor=64),
+    "ceil-inside-the-chunk": dict(offs=[0, 16], T=32, ceil=21),
+    "dead-row": dict(offs=[5, 16, 31, 100], T=1, dead=(2,)),
+    "chunk-runs-off-the-table": dict(offs=[125, 120], T=7),
+    "gqa-20-4-x128": dict(offs=[3, 47, 64, 1], T=1, Hkv=4, hd=128),
+    "mha-x96": dict(offs=[3, 47], T=7, Hkv=32, hd=96, dtype=jnp.bfloat16),
+    # a lane-aligned pool (core.init_paged_pool): 96 stored in 128 lanes
+    "mha-x96-in-128-lanes": dict(offs=[3, 47], T=7, Hkv=8, hd=96, lanes=128),
+    "decode-x64-in-128-lanes": dict(offs=[5, 16, 31, 100], T=1, hd=64, lanes=128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGE_WRITE_CASES))
+def test_page_write_matches_scatter_bit_for_bit(case):
+    """The Mosaic page-write stores exactly what kv_hook's scatter stores,
+    in every block a row owns, and touches no other layer. (The null block
+    0 is garbage by contract: the scatter dumps refused positions there,
+    the page-write only what a dead row's all-null table sends.)"""
+    c = dict(PAGE_WRITE_CASES[case])
+    offs, T = c.pop("offs"), c.pop("T")
+    Hkv, hd = c.pop("Hkv", 4), c.pop("hd", 64)
+    dtype, dead = c.pop("dtype", jnp.float32), c.pop("dead", ())
+    floor, ceil, lanes = c.get("floor"), c.get("ceil"), c.get("lanes", hd)
+    B, MB, BS, L, layer = len(offs), 8, 16, 3, 1
+    rng = np.random.default_rng(7)
+    pool = jnp.asarray(rng.standard_normal((L, Hkv, 1 + B * MB, BS, lanes)), dtype)
+    pool = pool.at[..., hd:].set(0)  # pad lanes hold zeros, and keep them
+    new = jnp.asarray(rng.standard_normal((B, T, Hkv, hd)), jnp.float32)
+    tables = np.arange(1, 1 + B * MB, dtype=np.int32).reshape(B, MB)
+    for b in dead:
+        tables[b] = 0
+    tables, off = jnp.asarray(tables), jnp.asarray(offs, jnp.int32)
+
+    want = _scatter_write(
+        pool, jnp.pad(new, ((0, 0),) * 3 + ((0, lanes - hd),)),
+        tables, off, layer, floor, ceil,
+    )
+    got = jax.jit(paged_kv_write)(
+        pool, new, tables, off, jnp.int32(layer), floor, ceil
+    )
+    want, got, was = (np.asarray(x, np.float32) for x in (want, got, pool))
+    assert np.array_equal(got[:, :, 1:], want[:, :, 1:])
+    if not dead:  # a dead row's table IS the null block: it writes there
+        assert np.array_equal(got[:, :, 0], was[:, :, 0])
+    if not dead and (floor is None or floor < max(offs) + T):
+        assert not np.array_equal(got[layer], was[layer]), "nothing was written"
+    assert chunk_pages(T, BS) == max(
+        (o % BS + T - 1) // BS + 1 for o in range(BS)
+    )
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["layer-0", "layer-L-1"])
+def test_stacked_pool_read_matches_sliced_read(layer):
+    """The kernel handed the stacked pool and a layer index makes the
+    copies it makes from that layer's slice: same bits out."""
+    q, kp, vp, tb, off, *_ = _pool_case(
+        offs=[0, 7, 8, 21], T=2, H=4, Hkv=2, hd=16, extra_tables=2
+    )
+    rng = np.random.default_rng(11)
+    ks, vs = (
+        jnp.asarray(rng.standard_normal((3, *one.shape)), one.dtype).at[layer].set(one)
+        for one in (kp, vp)
+    )
+    want = ragged_paged_attention(q, kp, vp, tb, off)
+    got = jax.jit(ragged_paged_attention)(q, ks, vs, tb, off, layer=jnp.int32(layer))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="stacked pool"):
+        ragged_paged_attention(q, ks, vs, tb, off)
+    with pytest.raises(ValueError, match="stacked pool"):
+        ragged_paged_attention(q, kp, vp, tb, off, layer=0)
+    # the same pool stored lane-aligned (16 -> 128 lanes, zeros beyond):
+    # q is padded with zeros, the output cut back - the same numbers
+    aligned = [jnp.pad(x, ((0, 0),) * 4 + ((0, 112),)) for x in (ks, vs)]
+    got = jax.jit(ragged_paged_attention)(q, *aligned, tb, off, layer=jnp.int32(layer))
+    assert got.shape == want.shape
+    _assert_close(got, want, atol=1e-6)
+
+
+def test_forward_writes_and_reads_the_stacked_pool_in_place():
+    """core.forward with the ragged attn_fn over a float pool: a prefill
+    chunk with a floor and a ceil leaves layer 0 of the pool bit-equal to
+    the scatter path's (its K/V depend on no attention), deeper layers and
+    the logits within the two readers' float difference."""
+    cfg = replace(CFG, n_layers=3)
+    params = core.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    BS, MB, T = 8, 6, 16
+    pool0 = jax.tree.map(
+        lambda a: jnp.asarray(
+            np.random.default_rng(3).standard_normal(a.shape), a.dtype),
+        core.init_paged_pool(cfg, 2 * MB + 1, BS, jnp.float32),
+    )
+    tables = jnp.arange(1, 2 * MB + 1, dtype=jnp.int32).reshape(2, MB)
+    ids = jnp.asarray(np.random.default_rng(4).integers(3, 200, (2, T)), jnp.int32)
+    off = jnp.asarray([11, 16], jnp.int32)
+
+    def run(attn_fn):
+        return jax.jit(lambda p, c: core.forward(
+            p, cfg, ids, c, off, attn_fn=attn_fn, block_tables=tables,
+            paged_write_floor=jnp.int32(13), paged_write_ceil=jnp.int32(30),
+        ))(params, pool0)
+
+    (lg_d, pool_d), (lg_r, pool_r) = run(None), run(make_ragged_attn_fn())
+    for name in ("k", "v"):
+        d, r = np.asarray(pool_d[name]), np.asarray(pool_r[name])
+        assert np.array_equal(r[0][:, 1:], d[0][:, 1:])
+        np.testing.assert_allclose(r[:, :, 1:], d[:, :, 1:], atol=1e-4)
+    np.testing.assert_allclose(np.asarray(lg_r), np.asarray(lg_d), atol=2e-4)
+
+
+def test_scheduler_counts_written_pages():
+    """engine.kv_pages_written: rows x the pages a chunk can touch x the
+    K and V write calls of every layer of every forward a dispatch runs;
+    nothing on the dense reader's scatter path."""
+    from bee2bee_tpu.engine import EngineConfig, InferenceEngine
+    from bee2bee_tpu.metrics import get_registry
+
+    written = get_registry().counter("engine.kv_pages_written")
+    kw = dict(max_seq_len=128, max_batch=2, decode_chunk=4, kv_block_size=8)
+
+    def serve(attention):
+        eng = InferenceEngine(
+            "tiny-llama", engine_config=EngineConfig(attention=attention, **kw))
+        sched, want = eng.scheduler, []
+        windows, prefill = sched._prepare_window_tables, eng._prefill
+
+        def spy_window(extra, calls):
+            tables = windows(extra, calls)
+            if tables is not None:
+                want.append(tables.shape[0] * chunk_pages(extra // calls, 8) * calls)
+            return tables
+
+        def spy_prefill(params, tokens, *a, **k):
+            want.append(tokens.shape[0] * chunk_pages(tokens.shape[1], 8))
+            return prefill(params, tokens, *a, **k)
+
+        sched._prepare_window_tables, eng._prefill = spy_window, spy_prefill
+        before = written.value()
+        try:
+            eng.generate(list(range(3, 23)), max_new_tokens=12, temperature=0.0)
+        finally:
+            eng.close()
+        return written.value() - before, sum(want) * 2 * eng.model_cfg.n_layers
+
+    got, want = serve("flash")
+    assert got == want > 0
+    assert serve("dense")[0] == 0
+
+
+def test_pool_is_lane_aligned_only_where_the_kernels_own_it_on_a_tpu():
+    """core.init_paged_pool(lane_aligned=True) stores the head at the 128-lane
+    width (so the TPU's default layout is the kernels' own); the engine asks
+    for it only on the in-place path on a TPU - never on this CPU mesh, where
+    every pool keeps the model's head size."""
+    from bee2bee_tpu.engine import EngineConfig, InferenceEngine
+
+    for model, lanes in (("phi-3-mini", 128), ("falcon-h1-34b-6l", 128), ("gemma-2b", 256)):
+        cfg = replace(get_config(model), n_layers=1)
+        shapes = jax.eval_shape(
+            lambda cfg=cfg: core.init_paged_pool(cfg, 4, 16, lane_aligned=True))
+        assert shapes["k"].shape == shapes["v"].shape == (1, cfg.n_kv_heads, 4, 16, lanes)
+        plain = jax.eval_shape(lambda cfg=cfg: core.init_paged_pool(cfg, 4, 16))
+        assert plain["k"].shape[-1] == cfg.head_dim
+    kw = dict(max_seq_len=128, max_batch=2, decode_chunk=4, kv_block_size=8)
+    for attention in ("flash", "dense"):
+        eng = InferenceEngine("tiny-llama", engine_config=EngineConfig(
+            attention=attention, **kw))
+        try:
+            assert eng.new_pool()["k"].shape[-1] == eng.model_cfg.head_dim
+        finally:
+            eng.close()
 
 
 # ------------------------------------------------- engine-level acceptance

@@ -18,7 +18,9 @@ library this worker holds).
 
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,9 +28,12 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from bee2bee_tpu.models import core, partition
 from bee2bee_tpu.models.config import get_config
 from bee2bee_tpu.ops.flash import flash_attention
-from bee2bee_tpu.ops.ragged import make_ragged_attn_fn, ragged_paged_attention
+from bee2bee_tpu.ops.ragged import (
+    make_ragged_attn_fn, paged_kv_write, ragged_paged_attention,
+)
 from bee2bee_tpu.parallel.mesh import AXES
 
 BS = 16  # EngineConfig.kv_block_size default
@@ -179,3 +184,168 @@ def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo):
         sds((1,), jnp.int32, P()),
     )
     assert "tpu_custom_call" in text
+
+
+# ------------------------- the pool written and read in place (PR 29)
+#
+# Whenever XLA writes (or slices) what Mosaic reads, layout assignment gives
+# the layer loop's carry XLA's layout and re-lays a pool slice - or the whole
+# pool - for the kernel in EVERY layer: 40-52 % of the device's time before
+# PR 29. These cases keep that trap shut: they compile whole `core.forward`
+# programs and read the compiler's own listing.
+
+PAGE_WRITE_CASES = {
+    # (model, B, T): decode, spec verify K+1 = 7, one prefill bucket
+    "phi-3-mini-decode": ("phi-3-mini", 16, 1),
+    "phi-3-mini-spec-verify-k6": ("phi-3-mini", 16, 7),
+    "phi-3-mini-prefill-2048": ("phi-3-mini", 1, 2048),
+    "falcon-h1-decode": ("falcon-h1-34b", 64, 1),
+    "gemma-2b-mqa-256-decode": ("gemma-2b", 8, 1),
+    "distilgpt2-x64-prefill-128": ("distilgpt2", 1, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGE_WRITE_CASES))
+def test_page_write_kernel_compiles_for_v5e(one_chip, case):
+    """The write half alone, on a stacked pool of two layers, donated: one
+    Mosaic call, aliased in place."""
+    model, B, T = PAGE_WRITE_CASES[case]
+    h = _heads(model)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda pool, new, t, o, lay: paged_kv_write(
+            pool, new, t, o, lay, 3, 2000, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        sds((2, h["Hkv"], 384, BS, h["hd"]), jnp.bfloat16),
+        sds((B, T, h["Hkv"], h["hd"]), jnp.bfloat16),
+        sds((B, 128), jnp.int32), sds((B,), jnp.int32), sds((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
+_HLO_ARRAY = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
+
+
+def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
+    """The instructions of a compiled module that PRODUCE an array with as
+    many elements as one layer's pool slice, or as the stacked pool
+    (whatever its dims: a relayout, a select, a slice, a write-back and
+    their bitcast-fused forms all keep the count; no weight or state of
+    these models shares it). Not counted: the plumbing that only passes
+    the pool along (parameter, tuple, get-tuple-element, bitcast, while),
+    the inside of fusions (a fusion counts by its result) and the Mosaic
+    calls, which alias the pool."""
+    fused = set(re.findall(r"calls=%?([\w.-]+)", text))
+    found, skip = [], True
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            skip = head[1] in fused
+            continue
+        m = None if skip else _HLO_LINE.match(line)
+        if not m or m[3] in (
+            "parameter", "tuple", "get-tuple-element", "bitcast", "while"
+        ) or "tpu_custom_call" in line:
+            continue
+        elems = {
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            for _, dims in _HLO_ARRAY.findall(m[2])
+        }
+        if elems & {slice_elems, slice_elems * layers}:
+            found.append(f"{m[1]} {m[3]}")
+    return found
+
+
+def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
+    """(lowered `core.forward` over a donated float pool, elements of one
+    layer's K slice a device): weights and pool are shapes placed by the
+    engine's own partition rules on ``mesh``, or whole on ``sharding``; the
+    pool is allocated as the engine allocates it for this path on a TPU,
+    lane-aligned (core.init_paged_pool), and laid out by the device's
+    default."""
+    attn = make_ragged_attn_fn(mesh, interpret=False)
+    whole = sharding if mesh is None else NamedSharding(mesh, P())
+
+    def place(tree, shardings=None):
+        return jax.tree.map(
+            lambda a, sh=whole: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree, *([shardings] if shardings is not None else []),
+        )
+
+    params = jax.eval_shape(
+        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    pool = jax.eval_shape(lambda: core.init_paged_pool(
+        cfg, nb, BS, jnp.bfloat16, lane_aligned=True))
+    stored = whole if mesh is None else NamedSharding(
+        mesh, partition.paged_cache_spec(cfg, mesh))
+    params = place(params, mesh and partition.param_shardings(params, mesh, cfg))
+    pool = place(pool, {"k": stored, "v": stored})
+    if cfg.has_ssm:  # the rows' recurrent state rides the same carry
+        pool = dict(pool, **place(
+            jax.eval_shape(lambda: core.init_ssm_state(cfg, B, jnp.float32))))
+
+    def step(params, ids, pool, off, tables, ceil):
+        return core.forward(
+            params, cfg, ids, pool, off, attn_fn=attn, block_tables=tables,
+            paged_write_ceil=ceil if T > 1 else None,
+        )
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=whole)
+
+    lowered = jax.jit(step, donate_argnums=(2,)).lower(
+        params, ints(B, T), pool, ints(B), ints(B, MB), ints())
+    shard = mesh.shape["model"] if mesh is not None else 1
+    return lowered, int(np.prod(pool["k"].shape[1:])) // shard
+
+
+# the cells' shapes, two layers deep (B, T, table width, pool blocks: one
+# more than the cells', so that no stacked weight has a pool slice's element
+# count - at 384 blocks phi-3's [2, 3072, 3072] projections do)
+IN_PLACE_CASES = {
+    "phi-3-mini-decode": ("phi-3-mini", 16, 1, 32, 385),
+    "phi-3-mini-prefill-128": ("phi-3-mini", 1, 128, 8, 385),
+    "falcon-h1-decode": ("falcon-h1-34b", 64, 1, 32, 3201),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
+def test_forward_keeps_the_pool_in_place(one_chip, case):
+    """No instruction of the program but the two kernels' aliased calls
+    produces an array as large as one layer's pool slice: no slice, no
+    relayout, no select, no write-back in the layer loop, and no relayout
+    of the pool where the program is entered and left (phi-3's 96 stored
+    in 128 lanes: at 96 the device's default puts the BLOCK axis minor-most
+    and every program re-laid the whole pool in and out). The temporaries
+    stay under one layer's slice."""
+    model, B, T, MB, nb = IN_PLACE_CASES[case]
+    cfg = dataclasses.replace(get_config(model), n_layers=2)
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, nb, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "while(" in text, "no layer loop in the compiled text"
+    assert text.count("tpu_custom_call") >= 3  # K write, V write, the read
+    assert _pool_sized_ops(text, slice_elems, 2) == []
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= 2 * 2 * slice_elems * 2  # K, V in place
+    if not cfg.has_ssm:  # falcon-h1 re-lays weights of its own (PERF.md)
+        assert analysis.temp_size_in_bytes < slice_elems * 2
+
+
+def test_forward_keeps_the_pool_in_place_under_model_4(topo):
+    """`model:4`: both kernels run per shard of the pool's kv heads inside
+    shard_maps over the same pool spec; the program still holds no op of a
+    (per-device) layer slice's size."""
+    cfg = dataclasses.replace(get_config("zephyr-7b"), n_layers=2)
+    mesh = Mesh(np.array(topo.devices, dtype=object).reshape(1, 1, 1, 4), AXES)
+    lowered, slice_elems = _forward_program(cfg, 8, 1, 8, NB, mesh=mesh)
+    text = lowered.compile().as_text()
+    assert "while(" in text, "no layer loop in the compiled text"
+    assert text.count("tpu_custom_call") >= 3
+    assert _pool_sized_ops(text, slice_elems, 2) == []
