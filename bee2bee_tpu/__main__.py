@@ -81,8 +81,6 @@ def _apply_common_cfg(cfg, kw):
         cfg.quantize = kw["quantize"]
     if kw.get("kv_quant"):
         cfg.kv_quant = True
-    if kw.get("paged"):
-        cfg.paged = True
     if kw.get("spec_tokens") is not None:
         cfg.spec_tokens = kw["spec_tokens"]
     if kw.get("drafter") is not None:
@@ -180,11 +178,6 @@ def cli():
                    "scales, dequantized inside the attention kernels — ~2x "
                    "resident sessions at fixed HBM and half the migration "
                    "bytes (BEE2BEE_KV_QUANT; bf16 pool default)")
-@click.option("--paged", is_flag=True, default=False,
-              help="DEPRECATED no-op: the paged KV block pool is now the "
-                   "only cache layout (per-step cache HBM traffic scales "
-                   "with live tokens; prefix-cache hits share prompt "
-                   "blocks copy-on-write, under every attention impl)")
 @click.option("--spec", "spec_tokens", type=int, default=None,
               help="self-speculative decoding: draft up to N tokens per "
                    "step by n-gram lookup over the request's own "
@@ -214,12 +207,12 @@ def cli():
                    "(zero local checkpoint)")
 @_common_opts
 def serve_tpu(model, checkpoint, lora, mesh_shape, attention, quantize,
-              kv_quant, paged, spec_tokens, drafter, adapters, max_adapters,
+              kv_quant, spec_tokens, drafter, adapters, max_adapters,
               publish_weights, from_mesh, **kw):
     """Serve a model on TPU via the jit engine (the flagship entrypoint)."""
     _serve(
         "tpu", model, checkpoint=checkpoint, lora=lora, mesh_shape=mesh_shape,
-        attention=attention, quantize=quantize, kv_quant=kv_quant, paged=paged,
+        attention=attention, quantize=quantize, kv_quant=kv_quant,
         spec_tokens=spec_tokens, drafter=drafter, adapters=adapters,
         max_adapters=max_adapters,
         publish_weights=publish_weights, from_mesh=from_mesh, **kw

@@ -30,7 +30,6 @@ _ENV_MAP = {
     "BEE2BEE_ATTENTION": "attention",
     "BEE2BEE_PREFILL_CHUNK": "prefill_chunk",
     "BEE2BEE_PREFIX_CACHE": "prefix_cache_entries",
-    "BEE2BEE_PAGED": "paged",
     "BEE2BEE_KV_BLOCK_SIZE": "kv_block_size",
     "BEE2BEE_KV_POOL_BLOCKS": "kv_pool_blocks",
     "BEE2BEE_KV_QUANT": "kv_quant",
@@ -49,7 +48,7 @@ _INT_FIELDS = {
     "dht_port", "prefill_chunk", "prefix_cache_entries", "kv_block_size",
     "kv_pool_blocks", "spec_tokens", "max_adapters",
 }
-_BOOL_FIELDS = {"auto_nat", "paged", "kv_quant"}
+_BOOL_FIELDS = {"auto_nat", "kv_quant"}
 
 
 @dataclass
@@ -84,9 +83,6 @@ class NodeConfig:
     prefix_cache_entries: int = 0
     # weight-only quantization: "none" | "int8" (halves decode HBM traffic)
     quantize: str = "none"
-    # DEPRECATED no-op (kept so BEE2BEE_PAGED / stored configs parse):
-    # the paged block pool is now the engine's only cache layout
-    paged: bool = False
     kv_block_size: int = 16  # tokens per pool block (EngineConfig knob)
     # int8 KV pool: pages stored int8 with per-page-per-head scales,
     # dequantized inside the attention kernels — ~2x resident sessions
@@ -144,7 +140,6 @@ class NodeConfig:
             prefix_cache_entries=self.prefix_cache_entries,
             quantize=self.quantize,
             cache_dtype="int8" if self.kv_quant else "bfloat16",
-            paged=self.paged,
             kv_block_size=self.kv_block_size,
             kv_pool_blocks=self.kv_pool_blocks or None,
             spec_tokens=self.spec_tokens,

@@ -159,13 +159,6 @@ class EngineConfig:
     # block. Pinned blocks are reclaimed LRU-first under pool pressure.
     # 0 = disabled.
     prefix_cache_entries: int = 0
-    # DEPRECATED no-op: the paged block pool (engine/paged.py) is now the
-    # ONLY cache layout — per-step cache HBM traffic scales with LIVE
-    # tokens under every attention impl (the old rectangular cache
-    # measured 4x decode cost at bsz=8 with one active row and is
-    # deleted). The field is accepted so existing configs/knobs
-    # (--paged / BEE2BEE_PAGED) keep parsing.
-    paged: bool = True
     # tokens per pool block. Smaller blocks track live length tighter
     # (less over-allocation, finer sharing granularity); larger blocks
     # shrink the table/gather overhead. 16 matches the TPU second-minor

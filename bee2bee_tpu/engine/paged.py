@@ -20,9 +20,12 @@ vLLM/"Ragged Paged Attention" (PAPERS.md, arxiv 2604.15464) pool model:
   copy-on-write (only the final partial block is ever copied, because
   the borrower will write into it from the match point).
 
-All allocator state is host-side python/numpy owned by the scheduler
-thread (single-owner rule); the only device arrays are the pool itself
-and the jitted single-block copy for CoW.
+``RowCache`` is the one owner of all of it: the allocator, the tables, the
+prefix pins, the pool arrays, the recurrent state of models that have one
+and the small jitted programs over them. The scheduler decides WHEN a row
+is covered, released, moved or exported; where its cache lives is decided
+here. Everything is touched by the scheduler thread alone (single-owner
+rule), and the host side is plain python/numpy.
 
 Why sharing whole blocks is sound: a cache entry claims validity for
 positions ``[0, n)`` of its prompt. Slots ``>= n`` in the entry's final
@@ -39,8 +42,11 @@ bit-identical, and a rewrite would perturb co-borrowers mid-decode.
 
 from __future__ import annotations
 
+import functools
 from collections.abc import Iterable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..metrics import get_registry
@@ -55,6 +61,22 @@ _G_BLOCKS_FREE = get_registry().gauge(
 )
 _G_BLOCKS_TOTAL = get_registry().gauge(
     "engine.paged_blocks_total", "paged KV pool size (incl. the null block)"
+)
+_C_KV_PAGES_WRITTEN = get_registry().counter(
+    "engine.kv_pages_written",
+    "pool pages the page-write kernel copied in and out: batch rows x the "
+    "pages a chunk can touch x the write calls (K and V, every layer, "
+    "every attention call of the dispatch); 0 on the scatter paths",
+)
+_G_STATE_ROWS = get_registry().gauge(
+    "engine.state_rows",
+    "row slots of recurrent state allocated (= the batch bucket; recurrent "
+    "models only)",
+)
+_G_STATE_BYTES = get_registry().gauge(
+    "engine.state_bytes",
+    "device bytes of the rows' recurrent state (ssm + conv; recurrent "
+    "models only)",
 )
 
 
@@ -110,6 +132,12 @@ class RecurrentStateUnsupported(ValueError):
             f"{feature} is not supported for {model!r}: its rows carry "
             f"recurrent state beside their K/V pages, and {why}"
         )
+
+
+class PoolExhausted(RuntimeError):
+    """Paged block pool has no free blocks (after reclaiming prefix pins).
+    Admission backpressure, not a crash — callers requeue or fail the one
+    request, never the whole scheduler."""
 
 
 def prefill_chunk_positions(n: int, start: int, bucket: int, S: int) -> list[int]:
@@ -259,3 +287,399 @@ class PagedPrefixCache:
     def clear(self) -> None:
         while self._evict_one():
             pass
+
+
+# ---- the jitted programs over the pool and the state. Pool leaves carry
+# their block dim on axis 2: the [L, Hkv, NB, BS, hd] pages and the int8
+# pool's [L, Hkv, NB] scales line up, so one program moves pages and
+# their scales together. State leaves are [L, B, ...], row dim 1. All but
+# the gather (a pure read: the pool keeps serving) donate what they update.
+
+_donating = functools.partial(jax.jit, donate_argnums=(0,))
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+def _copy_slot(axis, tree, src, dst):
+    """Slot ``src`` of every leaf copied over slot ``dst`` along ``axis``: a
+    pool block (axis 2, the CoW copy) or a state row (axis 1, compaction).
+    Scalar ids: one trace a tree, ever."""
+    return jax.tree.map(
+        lambda big: jax.lax.dynamic_update_slice_in_dim(
+            big, jax.lax.dynamic_slice_in_dim(big, src, 1, axis=axis),
+            dst, axis=axis), tree)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _gather_blocks(hd, pool, idx):
+    """Blocks ``idx`` of every leaf, pages cut to the model's head size
+    ``hd``: a lane-aligned pool's pad lanes (core.init_paged_pool) do not
+    travel, so a peer's pool may be laid out either way."""
+    return {
+        name: arr[:, :, idx][..., :hd] if arr.ndim == 5 else arr[:, :, idx]
+        for name, arr in pool.items()
+    }
+
+
+@_donating
+def _scatter_blocks(pool, new, idx):
+    """Write blocks ``new`` at ``idx``; pages narrower than the pool's
+    (head size vs lane-aligned) get their pad lanes zeroed."""
+    def aligned(blocks, arr):
+        if arr.ndim != 5 or blocks.shape[-1] == arr.shape[-1]:
+            return blocks
+        pad = arr.shape[-1] - blocks.shape[-1]
+        return jnp.pad(blocks, ((0, 0),) * 4 + ((0, pad),))
+
+    return {
+        name: arr.at[:, :, idx].set(aligned(new[name], arr))
+        for name, arr in pool.items()
+    }
+
+
+@_donating
+def _reset_scales(pool, idx):
+    return dict(
+        pool,
+        k_scale=pool["k_scale"].at[:, :, idx].set(0.0),
+        v_scale=pool["v_scale"].at[:, :, idx].set(0.0),
+    )
+
+
+@_donating
+def _state_insert(st, row, b):
+    return jax.tree.map(
+        lambda big, r: jax.lax.dynamic_update_slice_in_dim(
+            big, r.astype(big.dtype), b, axis=1), st, row)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _state_shrink(st, n):
+    return jax.tree.map(lambda a: a[:, :n], st)
+
+
+class RowCache:
+    """Everything a row keeps between steps, and where it lives.
+
+    - ONE block pool for every row + host-side tables; the pool never
+      resizes with the batch bucket (row identity lives in the block
+      table), so grow/shrink/compaction cost zero device copies and
+      per-step cache traffic follows the table width.
+    - The OTHER kind of row state (recurrent models, falcon-h1): one slot
+      a row of the batch bucket beside the pool — [L, bsz, ...], re-shaped
+      WITH the bucket (the pool is not: a block table gives a row its
+      pages, nothing gives it another state). Zeroed at admission, moved by
+      compaction. None for every other model.
+
+    ``pool`` and ``state`` are plain attributes: the scheduler hands them
+    to a jit root that donates them and stores the root's result back."""
+
+    def __init__(self, engine, max_batch: int):
+        self.engine = engine
+        self.max_batch = max_batch
+        self.block_size = engine.engine_cfg.kv_block_size
+        self.blocks_per_row = engine.blocks_per_row
+        self.recurrent = engine.model_cfg.has_ssm
+        ic = engine.introspect
+        # the CoW copy is scalar-arg'd (one trace ever): un-predicated,
+        # repeats storm
+        self._copy_block = ic.sentinel.watch(
+            "cow_copy", _copy_slot, key_fn=lambda axis, pool, src, dst: ()
+        )
+        ic.ledger.register("kv_pool", lambda: self.pool)
+        if self.recurrent:
+            ic.ledger.register("state", lambda: self.state)
+        self.rebuild()
+
+    def rebuild(self):
+        """An empty cache at batch bucket 1: the constructor's path, and the
+        recovery after a device-side failure — the pool was donated through
+        the failed call and may hold poisoned buffers, so allocator, tables,
+        prefix pins, pool and state are all made anew."""
+        e = self.engine
+        self.alloc = BlockAllocator(e.pool_blocks)
+        self.tables = np.zeros((self.max_batch, self.blocks_per_row), np.int32)
+        self.row_blocks: list[list[int]] = [[] for _ in range(self.max_batch)]
+        self._deferred: list[int] = []  # release(in_flight=True)
+        entries = e.engine_cfg.prefix_cache_entries
+        self.prefix = (
+            PagedPrefixCache(entries, self.alloc) if entries > 0 else None
+        )
+        self.pool = e.new_pool()
+        self.state = e.new_state(1)
+        self._set_state_gauges(1)
+
+    def _set_state_gauges(self, rows: int):
+        self.state_rows = rows
+        if self.recurrent:
+            _G_STATE_ROWS.set(rows)
+            _G_STATE_BYTES.set(
+                sum(a.nbytes for a in jax.tree.leaves(self.state))
+            )
+
+    # ---- widths: ONE rule for tables and for block-index arguments
+
+    def _width(self, nblocks: int) -> int:
+        """Pow2-bucketed width (bounds compile variants to O(log)) — never
+        below ``nblocks``, never past the physical table."""
+        return min(pow2_at_least(nblocks), self.blocks_per_row)
+
+    def declared_table_width(self, w) -> bool:
+        """Is ``w`` a width ``_width`` can emit? The decode roots' declared
+        compile space (None = a table-less call, also legal)."""
+        if w is None:
+            return True
+        limit = self.blocks_per_row
+        return w == limit or (w & (w - 1) == 0 and 0 < w <= limit)
+
+    def _padded_index(self, blocks) -> np.ndarray:
+        """``blocks`` as a device index argument at the bucketed width; pad
+        entries name the null block 0, which dead-row decode scribbles on by
+        design anyway."""
+        idx = np.zeros((self._width(len(blocks)),), np.int32)
+        idx[:len(blocks)] = blocks
+        return idx
+
+    # ---- blocks
+
+    def _alloc_fresh(self, n: int) -> list[int]:
+        """n fresh blocks, reclaiming LRU prefix pins under pressure;
+        raises PoolExhausted when even that can't cover it. On an int8
+        pool the fresh blocks' scale entries reset to zero here — the
+        quantize-on-write running max would otherwise inherit the PREVIOUS
+        tenant's amax and serve the new row at an inflated step forever.
+        Every allocation path (admission prefill, decode growth, CoW copy
+        targets, KV imports) funnels through this method (the CoW copy and
+        the import scatter then overwrite with the real scales)."""
+        fresh = self.alloc.alloc(n)
+        if fresh is None and self.prefix is not None:
+            if self.prefix.evict_for_pressure(n):
+                fresh = self.alloc.alloc(n)
+        if fresh is None:
+            raise PoolExhausted(
+                f"paged KV pool exhausted: need {n} blocks, "
+                f"{self.alloc.free_count} free of {self.alloc.num_blocks}"
+            )
+        if self.engine.kv_quantized and fresh:
+            self.pool = _reset_scales(
+                self.pool, self._padded_index(fresh)
+            )
+        return fresh
+
+    def cover(self, b: int, upto: int):
+        """Grow row b's block table to cover positions [0, upto) — the
+        lazy allocation that makes short rows cheap. Raises PoolExhausted
+        (with row state untouched beyond already-owned blocks)."""
+        need = ceil_div(upto, self.block_size)
+        have = len(self.row_blocks[b])
+        if need <= have:
+            return
+        assert need <= self.blocks_per_row, (need, upto)
+        fresh = self._alloc_fresh(need - have)
+        self.row_blocks[b].extend(fresh)
+        self.tables[b, have:need] = fresh
+
+    def growth_fits(self, growth: Iterable[tuple[int, int]]) -> bool:
+        """Would covering every ``(row, upto)`` fit the free list outright,
+        with no prefix pin reclaimed? (Look-ahead dispatch asks: it must
+        never be destructive.)"""
+        need = sum(
+            max(0, ceil_div(upto, self.block_size) - len(self.row_blocks[b]))
+            for b, upto in growth
+        )
+        return need <= self.alloc.free_count
+
+    def release(self, b: int, in_flight: bool = False):
+        """Drop row b's block references (shared blocks survive via their
+        other refs — prefix pins, CoW donors) and null its table row so
+        dead-row decode writes land in the null block. Decode windows still
+        ``in_flight`` keep dead-row-scattering into the blocks, so their
+        deref waits for ``flush_deferred`` (reallocating them early would
+        let an in-flight write corrupt another row's fresh block)."""
+        if self.row_blocks[b]:
+            if in_flight:
+                self._deferred.extend(self.row_blocks[b])
+            else:
+                self.alloc.deref(self.row_blocks[b])
+            self.row_blocks[b] = []
+        self.tables[b, :] = 0
+
+    def flush_deferred(self):
+        """Free the blocks of rows released while windows were in flight —
+        the caller's ring is empty now."""
+        if self._deferred:
+            self.alloc.deref(self._deferred)
+            self._deferred = []
+
+    # ---- the batch bucket: compaction and resize
+
+    def move(self, src: int, dst: int):
+        """Row ``src`` becomes row ``dst`` (a free slot): its pages move by
+        table alone; its state slot is the one device copy a compaction
+        costs."""
+        self.tables[dst] = self.tables[src]
+        self.tables[src] = 0
+        self.row_blocks[dst] = self.row_blocks[src]
+        self.row_blocks[src] = []
+        if self.recurrent:
+            self.state = _copy_slot(
+                1, self.state, np.int32(src), np.int32(dst)
+            )
+
+    def resize(self, bsz: int):
+        """Follow the batch bucket to ``bsz`` rows: the state is re-shaped,
+        the pool is not. Active rows live in [0, active), so the leading
+        slots carry them all — grown, they lead a new zeroed bucket."""
+        if not self.recurrent:
+            return
+        if bsz > self.state_rows:
+            self.state = _state_insert(
+                self.engine.new_state(bsz), self.state, np.int32(0)
+            )
+        else:
+            self.state = _state_shrink(self.state, bsz)
+        self._set_state_gauges(bsz)
+
+    def put_state(self, b: int, row_state):
+        """A prefilled row's state ([L, 1, ...]) into slot b."""
+        self.state = _state_insert(self.state, row_state, np.int32(b))
+
+    # ---- tables for a device call
+
+    def row_table(self, b: int) -> np.ndarray:
+        """[1, tw] — row b's table for its own prefill chunk."""
+        tw = self._width(len(self.row_blocks[b]))
+        return np.ascontiguousarray(self.tables[b:b + 1, :tw])
+
+    def window_table(self, live_rows: list[int], bsz: int):
+        """-> ([bsz, tw] table of the batch bucket, blocks the live rows
+        map): tw covers the longest live row. ``table.size`` is what
+        attention visits, the second value what is actually mapped (tests
+        and the page counters assert they track each other)."""
+        live = [len(self.row_blocks[b]) for b in live_rows]
+        tw = self._width(max(live))
+        return np.ascontiguousarray(self.tables[:bsz, :tw]), sum(live)
+
+    def count_pages_written(self, rows: int, chunk: int, calls: int = 1):
+        """engine.kv_pages_written for one dispatch of ``calls`` forwards
+        over [rows, chunk] tokens — only where core.forward writes through
+        the page-write kernel."""
+        e = self.engine
+        if e.kv_in_place:
+            from ..ops.ragged import chunk_pages  # loaded with the attn_fn
+
+            _C_KV_PAGES_WRITTEN.inc(
+                rows * chunk_pages(chunk, self.block_size)
+                * calls * 2 * e.model_cfg.n_layers
+            )
+
+    # ---- prefix sharing
+
+    def match_prefix(self, ids: list[int]):
+        """-> (start, blocks | None): the longest cached prefix of ``ids``
+        and its entry's block list; (0, None) on a miss or without a
+        prefix cache."""
+        return (0, None) if self.prefix is None else self.prefix.match(ids)
+
+    def adopt(self, b: int, n: int, start: int, cached) -> bool:
+        """Wire row b's table for an ``n``-token prefill that resumes at
+        ``start`` on the blocks ``cached`` (match_prefix; None = from
+        scratch): share the matched prefix's FULL blocks, CoW-copy at most
+        its final partial block — the borrower writes into it from
+        ``start``, which is therefore also the prefill's write floor.
+        Returns whether that copy ran. Raises PoolExhausted BEFORE any
+        device work when the pool cannot hold the whole prompt; the caller
+        releases the row."""
+        BS = self.block_size
+        row: list[int] = []
+        self.row_blocks[b] = row
+        self.tables[b, :] = 0
+        full = start // BS
+        partial: int | None = None
+        try:
+            if cached is not None:
+                shared = list(cached[:full])
+                # take our refs FIRST: the eviction below may reclaim
+                # prefix entries — including the donor — and must not free
+                # blocks this row is about to depend on
+                self.alloc.ref(shared)
+                row.extend(shared)
+                self.tables[b, :full] = shared
+                if start % BS:
+                    partial = int(cached[full])
+                    self.alloc.ref([partial])
+            # sufficiency precheck: the write ceil drops every scatter
+            # at/past position n, so prefill claims exactly the blocks
+            # covering the prompt — ceil(n / BS) — regardless of bucket
+            # padding (fresh blocks = that minus the shared fulls; the CoW
+            # copy target is the full-th block and is counted)
+            fresh_needed = ceil_div(n, BS) - full
+            if fresh_needed > self.alloc.free_count and not (
+                self.prefix is not None
+                and self.prefix.evict_for_pressure(fresh_needed)
+            ):
+                raise PoolExhausted(
+                    f"paged KV pool exhausted: admission needs "
+                    f"{fresh_needed} blocks, {self.alloc.free_count} free "
+                    f"of {self.alloc.num_blocks}"
+                )
+            if partial is None:
+                return False
+            fresh = self._alloc_fresh(1)
+            # the ONE CoW device copy
+            self.pool = self._copy_block(
+                2, self.pool, np.int32(partial), np.int32(fresh[0])
+            )
+            row.append(fresh[0])
+            self.tables[b, full] = fresh[0]
+            return True
+        finally:
+            if partial is not None:
+                self.alloc.deref([partial])
+
+    def publish_prefix(self, b: int, ids: list[int]):
+        """Pin row b's blocks covering exactly the positions of ``ids`` as
+        a prefix entry. Pinning is free (refcounts, no snapshot); a capacity
+        eviction inside may free other entries' blocks."""
+        if self.prefix is not None:  # put() keeps an entry it already has
+            self.prefix.put(
+                ids, self.row_blocks[b][:ceil_div(len(ids), self.block_size)]
+            )
+
+    # ---- migration: a row's pages as host arrays
+
+    def export_row(self, b: int, upto: int):
+        """-> (nb, {leaf: [L, Hkv, nb, ...]} | None): the pages holding
+        positions [0, upto) of row b at the model's head size, with the
+        int8 pool's scales under their own keys. Pure read."""
+        if self.recurrent:
+            # a row's blocks are NOT its complete state here
+            raise RecurrentStateUnsupported(
+                "kv_export", self.engine.model_cfg.name,
+                "the state has no export format yet",
+            )
+        nb = ceil_div(upto, self.block_size)
+        if not nb:
+            return 0, None
+        idx = self._padded_index(self.row_blocks[b][:nb])
+        hd = self.engine.model_cfg.head_dim
+        got = jax.device_get(_gather_blocks(hd, self.pool, idx))
+        return nb, {
+            name: np.asarray(arr[:, :, :nb]) for name, arr in got.items()
+        }
+
+    def import_row(self, b: int, upto: int, kv: dict):
+        """Row b from shipped pages covering [0, upto): fresh blocks, the
+        table, one scatter (``kv``'s leaves match the pool's —
+        engine.import_generation validated them). Raises PoolExhausted
+        with nothing taken."""
+        self.cover(b, upto)  # b is a free row: all of it is fresh
+        idx = self._padded_index(self.row_blocks[b])
+        # every leaf padded to the index width; pad columns are zero data
+        # aimed at the null block
+        pad = len(idx) - len(self.row_blocks[b])
+        new = {
+            name: np.pad(kv[name], [(0, 0), (0, 0), (0, pad)]
+                         + [(0, 0)] * (np.ndim(kv[name]) - 3))
+            for name in self.pool
+        }
+        self.pool = _scatter_blocks(self.pool, new, idx)

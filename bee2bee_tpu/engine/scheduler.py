@@ -69,10 +69,9 @@ from ..router.tenants import load_tenant_config
 from ..tracing import RequestTiming, get_tracer
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
 from .paged import (
-    BlockAllocator,
-    PagedPrefixCache,
-    ceil_div,
-    pow2_at_least,
+    PoolExhausted,
+    RecurrentStateUnsupported,
+    RowCache,
     prefill_chunk_positions,
 )
 from .sampling import sample_batched
@@ -124,12 +123,6 @@ _C_KV_PAGES_LIVE = _REG.counter(
     "of engine.kv_pages_visited, the entries that map a row's own block "
     "(live / visited = the share of the table the ragged kernel fetches)",
 )
-_C_KV_PAGES_WRITTEN = _REG.counter(
-    "engine.kv_pages_written",
-    "pool pages the page-write kernel copied in and out: batch rows x the "
-    "pages a chunk can touch x the write calls (K and V, every layer, "
-    "every attention call of the dispatch); 0 on the scatter paths",
-)
 
 
 def _phase(name: str):
@@ -146,16 +139,6 @@ def _phase(name: str):
         return run
 
     return deco
-_G_STATE_ROWS = _REG.gauge(
-    "engine.state_rows",
-    "row slots of recurrent state allocated (= the batch bucket; recurrent "
-    "models only)",
-)
-_G_STATE_BYTES = _REG.gauge(
-    "engine.state_bytes",
-    "device bytes of the rows' recurrent state (ssm + conv; recurrent "
-    "models only)",
-)
 _C_SSM_STEP_ROWS = _REG.counter(
     "engine.ssm_step_rows",
     "one-step recurrent updates dispatched: rows x decode steps x layers "
@@ -312,6 +295,9 @@ class Request:
 
 @dataclass
 class SchedulerStats:
+    # the scheduler's RowCache: pool occupancy is read off its allocator
+    # when asked for, never mirrored
+    cache: RowCache = field(repr=False)
     admitted: int = 0
     retired: int = 0
     chunks: int = 0  # batched decode chunks dispatched
@@ -324,8 +310,6 @@ class SchedulerStats:
     # tracking each other is the "cache HBM reads scale with live tokens"
     # property. The deleted rectangular layout's equivalent was
     # bsz * ceil(max_seq / block_size) regardless of occupancy.
-    paged_blocks_in_use: int = 0
-    paged_blocks_hwm: int = 0
     paged_blocks_copied: int = 0  # CoW copies (<= 1 per prefix hit)
     paged_blocks_read_last_step: int = 0
     paged_live_blocks: int = 0
@@ -364,23 +348,25 @@ class SchedulerStats:
     history: deque = field(default_factory=lambda: deque(maxlen=64))
 
     @property
+    def paged_blocks_in_use(self) -> int:
+        return self.cache.alloc.used_count
+
+    @property
+    def paged_blocks_hwm(self) -> int:
+        return self.cache.alloc.hwm
+
+    @property
     def spec_acceptance(self) -> float:
         return self.spec_accepted / self.spec_drafted if self.spec_drafted else 0.0
 
 
-class _PoolExhausted(RuntimeError):
-    """Paged block pool has no free blocks (after reclaiming prefix pins).
-    Admission backpressure, not a crash — callers requeue or fail the one
-    request, never the whole scheduler."""
-
-
 class BatchScheduler:
-    """Owns the shared cache + row table; see module docstring."""
+    """Decides when rows are admitted, stepped, moved and retired; where a
+    row's cache lives is engine/paged.RowCache's. See module docstring."""
 
     def __init__(self, engine, max_batch: int):
         self.engine = engine
         self.max_batch = max_batch
-        self.stats = SchedulerStats()
         # submit queue with per-tenant weighted-deficit fairness
         # (router/fairness.py): deque-compatible, FIFO within a tenant,
         # WDRR across tenants — cost is the request's token budget, so a
@@ -412,49 +398,15 @@ class BatchScheduler:
         self.handoff_after_prefill = False
 
         e = engine
-        self._bsz = 1  # current batch bucket (pow2-ish, <= max_batch)
-        # ONE block pool for every row + host-side tables; the pool never
-        # resizes with the batch bucket (row identity lives in the block
-        # table), so grow/shrink/compaction cost zero device copies and
-        # per-step cache traffic follows the table width.
-        self._block_size = e.engine_cfg.kv_block_size
-        self._alloc = BlockAllocator(e.pool_blocks)
-        self._tables = np.zeros((max_batch, e.blocks_per_row), np.int32)
-        self._row_blocks: list[list[int]] = [[] for _ in range(max_batch)]
-        self._cache = e.new_pool()
-        # the OTHER kind of row state (recurrent models, falcon-h1): one
-        # slot a row of the batch bucket beside the pool — [L, bsz, ...],
-        # re-shaped WITH the bucket (the pool is not: a block table gives
-        # a row its pages, nothing gives it another state). Zeroed at
-        # admission, moved by compaction, donated through every decode
-        # window and prefill chunk. None for every other model.
-        self._state = e.new_state(self._bsz)
-        self._recurrent = self._state is not None
-        # cur/offsets live as HOST numpy mirrors: every eager device op is
-        # a dispatch and a possible sync of its own (cost not measured on
-        # the current machine),
-        # so the scheduler never runs eager jnp — host state goes in as
-        # jit arguments (a cheap [B] transfer) and comes back with the
-        # token readback it needed anyway
-        self._cur = np.zeros((self._bsz,), np.int32)
-        self._offsets = np.zeros((self._bsz,), np.int32)
-        # per-row adapter slots (adapters/pool.py; 0 = base model). A host
-        # mirror like _cur/_offsets: rides into the jitted step as a [B]
-        # argument only when some row actually holds an adapter — the
-        # all-base batch keeps the adapter-free trace (per-row gating
-        # discipline, same as the penalized-counts split)
-        self._aids = np.zeros((self._bsz,), np.int32)
-        self._rows: list[Request | None] = [None] * self._bsz
-        self._row_params_dirty = True
+        # where every row's cache lives (engine/paged.py): the block pool,
+        # the tables, the prefix pins and, for recurrent models, the state
+        # — donated through every decode window and prefill chunk as
+        # self.cache.pool / self.cache.state
+        self.cache = RowCache(engine, max_batch)
+        self.stats = SchedulerStats(self.cache)
         self._temps = self._topps = self._topks = self._minps = None
         self._reps = self._press = self._freqs = None
-        # occurrence counts [bsz, V] int32 for penalty sampling — allocated
-        # lazily on the first penalized admission so the common (bench)
-        # path never allocates or threads it. Rows of non-penalized
-        # requests may hold stale counts; they are never read (rep=1/
-        # pres=0/freq=0 rows pass through apply_penalties unchanged) and
-        # every admission overwrites its row with a fresh prompt bincount.
-        self._counts = None
+        self._reset_rows()
         self._vocab = e.model_cfg.vocab_size
 
         # counts live [B, 2, V] (batch leading; channel 0 = prompt
@@ -468,43 +420,6 @@ class BatchScheduler:
             row = jax.lax.dynamic_slice(c, (src, 0, 0), (1, 2, V))
             return jax.lax.dynamic_update_slice(c, row, (dst, 0, 0))
 
-        # CoW single-block copy: one dim-1 slice of the pool's block dim
-        # ([L, Hkv, NB, BS, hd] dim 2) copied src -> dst, donating the pool
-        def copy_block(cache, src, dst):
-            def cp(big):
-                sizes = big.shape[:2] + (1,) + big.shape[3:]
-                row = jax.lax.dynamic_slice(
-                    big, (0, 0, src) + (0,) * (big.ndim - 3), sizes
-                )
-                return jax.lax.dynamic_update_slice(
-                    big, row, (0, 0, dst) + (0,) * (big.ndim - 3)
-                )
-
-            return jax.tree.map(cp, cache)
-
-        # the recurrent state's row helpers ([L, B, ...] leaves, row dim 1)
-        def s_insert(st, row, b):
-            return jax.tree.map(
-                lambda big, r: jax.lax.dynamic_update_slice_in_dim(
-                    big, r.astype(big.dtype), b, axis=1), st, row)
-
-        def s_move(st, src, dst):
-            return jax.tree.map(
-                lambda big: jax.lax.dynamic_update_slice_in_dim(
-                    big, jax.lax.dynamic_slice_in_dim(big, src, 1, axis=1),
-                    dst, axis=1), st)
-
-        self._state_insert = jax.jit(s_insert, donate_argnums=(0,))
-        self._state_move = jax.jit(s_move, donate_argnums=(0,))
-        # grow: the old rows lead the new zeroed bucket; shrink: active
-        # rows live in [0, active), so the leading rows carry them all
-        self._state_grow = jax.jit(
-            lambda new, old: s_insert(new, old, 0), donate_argnums=(0,)
-        )
-        self._state_shrink = jax.jit(
-            lambda st, n: jax.tree.map(lambda a: a[:, :n], st),
-            static_argnums=(1,),
-        )
         self._counts_zeros = jax.jit(
             lambda b: jnp.zeros((b, 2, V), jnp.int32), static_argnums=0
         )
@@ -523,15 +438,10 @@ class BatchScheduler:
         # engine economics plane (engine/introspect.py): the decode roots
         # register with the engine's retrace sentinel under the declared
         # compile space — batch sizes on the pow2 grow ladder, block-table
-        # widths on the pow2 width buckets. The CoW copy is scalar-arg'd
-        # (one trace ever): un-predicated, repeats storm.
+        # widths on the cache's width buckets.
         ic = engine.introspect
         self._meter = ic.meter
-        ic.ledger.register("kv_pool", lambda: self._cache)
-        if self._recurrent:
-            ic.ledger.register("state", lambda: self._state)
-            self._set_state_gauges()
-        tw_ok = self._declared_table_width
+        tw_ok = self.cache.declared_table_width
         bs_ok = engine._declared_batch_sizes
         # decode hot-loop mechanisms (docs/PERF.md "Decode hot loop"):
         # resolved once from EngineConfig (env knobs already folded in by
@@ -551,11 +461,6 @@ class BatchScheduler:
         # buffers, and its own (row, request) map — row bookkeeping may
         # drift (retirement nulls _rows[b]) between dispatch and fetch.
         self._inflight: deque = deque()
-        # blocks freed by a retirement while windows were still in flight:
-        # those windows keep dead-row-scattering into them, so the deref
-        # waits for the ring to drain (reallocating them early would let
-        # an in-flight write corrupt another row's fresh block)
-        self._deferred_blocks: list[int] = []
         # (cur, offsets) shardings of the decode root's outputs, captured
         # at the first dispatch. Ring-empty dispatches re-enter the chain
         # from the numpy host mirrors, which must be committed to these
@@ -583,68 +488,6 @@ class BatchScheduler:
         # jitted: sample_batched run eagerly is ~15 tiny ops = ~15
         # dispatches per admission
         self._sample_first = jax.jit(sample_batched)
-        self._copy_block = ic.sentinel.watch(
-            "cow_copy",
-            jax.jit(copy_block, donate_argnums=(0,)),
-            key_fn=lambda cache, src, dst: (),
-        )
-
-        # migration block transfer (pool block dim = axis 2 of EVERY pool
-        # leaf — the int8 pool's [L, Hkv, NB] scale arrays line up with
-        # the [L, Hkv, NB, BS, hd] pages, so one generic gather/scatter
-        # moves pages and their scales together): gather reads a row's
-        # blocks out for host export (no donation — the pool keeps
-        # serving), scatter writes imported blocks into freshly allocated
-        # slots. Index arrays pad to pow2 widths (null block 0 / zero
-        # data) so compile variants stay O(log) like the table widths;
-        # pad writes land in the null block, which dead-row decode
-        # scribbles on by design anyway.
-        # Pages travel at the model's head size: a lane-aligned pool's pad
-        # lanes (core.init_paged_pool) are cut on the way out and zeroed on
-        # the way in, so a peer's pool may be laid out either way.
-        hd = e.model_cfg.head_dim
-
-        def gather_blocks(cache, idx):
-            return {
-                name: arr[:, :, idx][..., :hd] if arr.ndim == 5
-                else arr[:, :, idx]
-                for name, arr in cache.items()
-            }
-
-        def scatter_blocks(cache, new, idx):
-            def aligned(blocks, arr):
-                if arr.ndim != 5 or blocks.shape[-1] == arr.shape[-1]:
-                    return blocks
-                pad = arr.shape[-1] - blocks.shape[-1]
-                return jnp.pad(blocks, ((0, 0),) * 4 + ((0, pad),))
-
-            return {
-                name: arr.at[:, :, idx].set(aligned(new[name], arr))
-                for name, arr in cache.items()
-            }
-
-        self._gather_blocks = jax.jit(gather_blocks)
-        self._scatter_blocks = jax.jit(scatter_blocks, donate_argnums=(0,))
-        # int8 pool: a recycled block's scale entry must drop to zero
-        # before its next tenant writes — the quantize-on-write running
-        # max would otherwise inherit the PREVIOUS tenant's amax and
-        # serve the new row at an inflated quantization step forever
-        self._quantized = e.kv_quantized
-        if self._quantized:
-            def reset_scales(cache, idx):
-                return dict(
-                    cache,
-                    k_scale=cache["k_scale"].at[:, :, idx].set(0.0),
-                    v_scale=cache["v_scale"].at[:, :, idx].set(0.0),
-                )
-
-            self._reset_scales = jax.jit(reset_scales, donate_argnums=(0,))
-        if e.engine_cfg.prefix_cache_entries > 0:
-            self._prefix_cache = PagedPrefixCache(
-                e.engine_cfg.prefix_cache_entries, self._alloc
-            )
-        else:
-            self._prefix_cache = None
 
         # self-speculative decoding (engine/spec.py): greedy rows draft
         # from their own prompt+output and one [B, K+1] verify call
@@ -773,16 +616,6 @@ class BatchScheduler:
         return sum(r is not None for r in self._rows)
 
     # ------------------------------------------------------------ device fns
-
-    def _declared_table_width(self, w) -> bool:
-        """Is ``w`` a legitimate block-table width for the sentinel's
-        declared compile space? _table_width emits pow2 widths capped at
-        blocks_per_row — anything else through a decode root is an
-        undeclared shape (None = a rect/table-less call, also legal)."""
-        if w is None:
-            return True
-        limit = self.engine.blocks_per_row
-        return w == limit or (w & (w - 1) == 0 and 0 < w <= limit)
 
     @staticmethod
     def _decode_key(params, cur, cache, offsets, temps, topks, topps,
@@ -924,7 +757,10 @@ class BatchScheduler:
                 try:
                     with self._cond:
                         self._fail_all(f"scheduler error: {e!r}")
-                    self._reset_device_state()
+                    # an empty bucket-1 batch over a cache rebuilt whole
+                    # (_fail_all dropped the ring and released every row)
+                    self.cache.rebuild()
+                    self._reset_rows()
                 except Exception:
                     # recovery itself failed (dead device): stop accepting
                     # work so submit() raises instead of queueing forever
@@ -945,13 +781,9 @@ class BatchScheduler:
         # poisoned, and with every row released below nobody needs them
         self._inflight.clear()
         _G_OVERLAP.set(0)
-        if self._deferred_blocks:
-            self._alloc.deref(self._deferred_blocks)
-            self._deferred_blocks = []
+        self.cache.flush_deferred()
         for req in list(self._queue) + [r for r in self._rows if r is not None]:
-            self._release_adapter(req)
-            req.finish = "error"
-            req.events.put({"done": True, "result": None, "error": reason})
+            self._fail(req, reason)
         self._queue.clear()
         # blocked checkpoint() callers get their None verdict too — a
         # dead scheduler must not make a drain wait out its timeout
@@ -961,53 +793,51 @@ class BatchScheduler:
         for b, r in enumerate(self._rows):
             if r is not None:
                 self._release_row(b)
-        self._rows = [None] * self._bsz
 
-    def _reset_device_state(self):
-        """Recover to an empty bucket-1 batch after a device-side failure:
-        the whole pool/allocator/prefix-pin state is rebuilt — the pool
-        was donated through the failed call and may hold poisoned
-        buffers."""
-        self._bsz = 1
-        e = self.engine
-        self._inflight.clear()
-        _G_OVERLAP.set(0)
-        self._deferred_blocks = []  # the allocator is rebuilt below
-        self._alloc = BlockAllocator(e.pool_blocks)
-        self._tables[:] = 0
-        self._row_blocks = [[] for _ in range(self.max_batch)]
-        if self._prefix_cache is not None:
-            self._prefix_cache = PagedPrefixCache(
-                e.engine_cfg.prefix_cache_entries, self._alloc
-            )
-        self._cache = e.new_pool()
-        self._state = e.new_state(1)
-        self._set_state_gauges()
-        self.stats.paged_blocks_in_use = 0
+    def _reset_rows(self):
+        """An empty batch at bucket 1: the host side of every row."""
+        self._bsz = 1  # current batch bucket (pow2-ish, <= max_batch)
+        # cur/offsets live as HOST numpy mirrors: every eager device op is
+        # a dispatch and a possible sync of its own (cost not measured on
+        # the current machine),
+        # so the scheduler never runs eager jnp — host state goes in as
+        # jit arguments (a cheap [B] transfer) and comes back with the
+        # token readback it needed anyway
         self._cur = np.zeros((1,), np.int32)
         self._offsets = np.zeros((1,), np.int32)
+        # per-row adapter slots (adapters/pool.py; 0 = base model). A host
+        # mirror like _cur/_offsets: rides into the jitted step as a [B]
+        # argument only when some row actually holds an adapter — the
+        # all-base batch keeps the adapter-free trace (per-row gating
+        # discipline, same as the penalized-counts split)
         self._aids = np.zeros((1,), np.int32)
-        self._rows = [None]
-        self._counts = None  # lazily reallocated by the next penalized admit
+        self._rows: list[Request | None] = [None]
         self._row_params_dirty = True
-
-    # ------------------------------------------------------------ paged state
+        # occurrence counts [bsz, V] int32 for penalty sampling — allocated
+        # lazily on the first penalized admission so the common (bench)
+        # path never allocates or threads it. Rows of non-penalized
+        # requests may hold stale counts; they are never read (rep=1/
+        # pres=0/freq=0 rows pass through apply_penalties unchanged) and
+        # every admission overwrites its row with a fresh prompt bincount.
+        self._counts = None
 
     def _release_row(self, b: int):
-        """Drop row b's block references (shared blocks survive via their
-        other refs — prefix pins, CoW donors) and null its table row so
-        dead-row decode writes land in the null block."""
-        if self._row_blocks[b]:
-            if self._inflight:
-                # in-flight windows still dead-row-scatter into these
-                # blocks; deref when the ring drains (_release_deferred)
-                self._deferred_blocks.extend(self._row_blocks[b])
-            else:
-                self._alloc.deref(self._row_blocks[b])
-            self._row_blocks[b] = []
-        self._tables[b, :] = 0
+        """Row b is free again: its cache goes back (deferred while windows
+        are in flight: they still dead-row-scatter into its blocks)."""
+        self._rows[b] = None
+        self.cache.release(b, in_flight=bool(self._inflight))
         self._aids[b] = 0  # dead rows gather the null adapter (zeros)
-        self.stats.paged_blocks_in_use = self._alloc.used_count
+        self._row_params_dirty = True
+
+    def _fail(self, req: Request, error: str, kind: str | None = None):
+        """Error-terminate one request: its adapter refcount goes back (a
+        lease nobody returns pins the slot until restart) and its blocked
+        caller gets the done event, typed with ``error_kind`` where the
+        serving surfaces map it (404 unknown_adapter, pool_exhausted)."""
+        self._release_adapter(req)
+        req.finish = "error"
+        typed = {"error_kind": kind} if kind else {}
+        req.events.put({"done": True, "result": None, "error": error, **typed})
 
     def _release_adapter(self, req: Request):
         """Return req's adapter-pool refcount (idempotent — retirement,
@@ -1016,51 +846,6 @@ class BatchScheduler:
         if getattr(req, "_adapter_acquired", False):
             req._adapter_acquired = False
             self.engine.adapter_pool.release(req.adapter_slot)
-
-    def _alloc_or_evict(self, n: int) -> list[int]:
-        """n fresh blocks, reclaiming LRU prefix pins under pressure;
-        raises _PoolExhausted when even that can't cover it. On an int8
-        pool the fresh blocks' scale entries reset to zero here — every
-        allocation path (admission prefill, decode growth, CoW copy
-        targets, KV imports) funnels through this method, so a new
-        tenant always quantizes from a clean slate (the CoW copy and
-        the import scatter then overwrite with the real scales)."""
-        fresh = self._alloc.alloc(n)
-        if fresh is None and self._prefix_cache is not None:
-            if self._prefix_cache.evict_for_pressure(n):
-                fresh = self._alloc.alloc(n)
-        if fresh is None:
-            raise _PoolExhausted(
-                f"paged KV pool exhausted: need {n} blocks, "
-                f"{self._alloc.free_count} free of {self._alloc.num_blocks}"
-            )
-        if self._quantized and fresh:
-            # pow2-padded index (null block 0 pad) bounds compile variants
-            width = pow2_at_least(len(fresh))
-            idx = np.zeros((width,), np.int32)
-            idx[:len(fresh)] = fresh
-            self._cache = self._reset_scales(self._cache, idx)
-        self.stats.paged_blocks_in_use = self._alloc.used_count
-        self.stats.paged_blocks_hwm = self._alloc.hwm
-        return fresh
-
-    def _ensure_blocks(self, b: int, upto: int):
-        """Grow row b's block table to cover positions [0, upto) — the
-        lazy allocation that makes short rows cheap. Raises _PoolExhausted
-        (with row state untouched beyond already-owned blocks)."""
-        need = ceil_div(upto, self._block_size)
-        have = len(self._row_blocks[b])
-        if need <= have:
-            return
-        assert need <= self.engine.blocks_per_row, (need, upto)
-        fresh = self._alloc_or_evict(need - have)
-        self._row_blocks[b].extend(fresh)
-        self._tables[b, have:need] = fresh
-
-    def _table_width(self, nblocks: int) -> int:
-        """Pow2-bucketed block-table width (bounds compile variants) —
-        never below what any row maps, never past the physical table."""
-        return min(pow2_at_least(nblocks), self.engine.blocks_per_row)
 
     # ------------------------------------------------------------ migration
 
@@ -1084,10 +869,8 @@ class BatchScheduler:
         b = next((i for i, r in enumerate(self._rows) if r is req), None)
         if b is not None:
             snap = self._snapshot_row(b, req)
-            self._rows[b] = None
             self._release_row(b)
             self._release_adapter(req)  # the target re-acquires its own pin
-            self._row_params_dirty = True
             self.stats.migrated_out += 1
             self._compact_and_shrink()
             return snap
@@ -1124,7 +907,7 @@ class BatchScheduler:
             # adapter before it can resume the row — KV AND future decode
             # both depend on the adapted projections
             "adapter": req.adapter,
-            "block_size": self._block_size,
+            "block_size": self.cache.block_size,
             "offset": 0,
             "cur": None,
             "kv_blocks": 0,
@@ -1139,25 +922,18 @@ class BatchScheduler:
         sampled token's K/V is written by the NEXT forward), so the
         blocks covering [0, offset) are the complete recoverable state."""
         snap = self._snapshot_meta(req)
-        if self._recurrent:
-            # a row's blocks are NOT its complete state here, and the
-            # recurrent state has no export format yet: ship the metadata
-            # alone, so the importer takes the re-prefill rung (prompt +
-            # accepted tokens rebuild K/V AND state; next tokens equal)
-            return snap
         offset = int(self._offsets[b])
-        nb = ceil_div(offset, self._block_size)
+        try:
+            nb, kv = self.cache.export_row(b, offset)
+        except RecurrentStateUnsupported:
+            # the recurrent state has no export format yet: ship the
+            # metadata alone, so the importer takes the re-prefill rung
+            # (prompt + accepted tokens rebuild K/V AND state; next
+            # tokens equal)
+            return snap
         snap.update(offset=offset, cur=int(self._cur[b]), kv_blocks=nb)
-        if nb:
-            width = min(pow2_at_least(nb), self.engine.blocks_per_row)
-            idx = np.zeros((width,), np.int32)
-            idx[:nb] = self._row_blocks[b][:nb]
-            got = jax.device_get(self._gather_blocks(self._cache, idx))
-            # int8 pool: the per-page scales ride under their own keys
-            # (k_scale/v_scale), halving the exported bytes with them
-            snap["_kv"] = {
-                name: np.asarray(arr[:, :, :nb]) for name, arr in got.items()
-            }
+        if kv is not None:
+            snap["_kv"] = kv
         return snap
 
     def _paged_import(self, req: Request, b: int, st: dict):
@@ -1167,87 +943,50 @@ class BatchScheduler:
         decode that follows is token-for-token the unmigrated rollout) or,
         KV-less, re-prefill prompt+accepted through the normal chunk walk
         (the fallback rung, counted in import_reprefills). Raises
-        _PoolExhausted with the row released — imports never requeue: the
+        PoolExhausted with the row released — imports never requeue: the
         exporting node needs a fast typed verdict to try its next rung."""
-        e = self.engine
-        BS = self._block_size
         kv = st.get("kv")
         try:
             if kv is not None:
                 offset = int(st["offset"])
-                need = ceil_div(offset, BS)
-                assert need <= e.blocks_per_row, (need, offset)
-                fresh = self._alloc_or_evict(need)
-                self._row_blocks[b] = list(fresh)
-                self._tables[b, :] = 0
-                self._tables[b, :need] = fresh
-                width = min(pow2_at_least(need), e.blocks_per_row)
-                idx = np.zeros((width,), np.int32)
-                idx[:need] = fresh
-                # pad every pool leaf (pages AND int8 scales — the key
-                # sets match: import_generation validated them against
-                # the pool layout) to the pow2 width; pad columns target
-                # the null block
-                new = {}
-                for name in self._cache:
-                    arr = np.asarray(kv[name])
-                    buf = np.zeros(
-                        arr.shape[:2] + (width,) + arr.shape[3:], arr.dtype
-                    )
-                    buf[:, :, :need] = arr
-                    new[name] = buf
-                self._cache = self._scatter_blocks(self._cache, new, idx)
+                self.cache.import_row(b, offset, kv)
                 self._offsets[b] = offset
-                self._cur[b] = int(st["cur"])
                 # prefix pins travel WITH the generation: the imported
                 # prompt K/V is exactly what a local prefill would have
                 # pinned, so repeat prompts hit CoW on the target too
-                n = len(req.ids)
-                if (self._prefix_cache is not None and offset >= n
-                        and not req.adapter
-                        and not self._prefix_cache.has(req.ids)):
-                    self._prefix_cache.put(req.ids, fresh[:ceil_div(n, BS)])
+                if offset >= len(req.ids) and not req.adapter:
+                    self.cache.publish_prefix(b, req.ids)
             else:
                 seq = [int(t) for t in st["seq"]]
-                start, cached = (
-                    self._prefix_cache.match(seq)
-                    if self._prefix_cache is not None and not req.adapter
-                    else (0, None)
-                )
-                C = e.engine_cfg.prefill_chunk
-                remaining = len(seq) - (start if cached is not None else 0)
-                if C is not None and remaining > C:
-                    bucket = C
-                else:
-                    bucket = e._bucket_for(remaining)
-                req.bucket = bucket
+                start, cached = self._plan_prefill(req, seq)
                 # last_logits discarded: the next token is already known
                 # (cur = out[-1]); decode resumes from it
-                self._paged_prefill(req, b, bucket, start, cached, seq=seq)
+                self._paged_prefill(req, b, start, cached, seq=seq)
                 self._offsets[b] = len(seq)
-                self._cur[b] = int(st["cur"])
                 self.stats.import_reprefills += 1
+            self._cur[b] = int(st["cur"])
             if req.penalized:
-                if self._counts is None:
-                    self._counts = self._counts_zeros(self._bsz)
-                ch0 = np.bincount(
-                    np.asarray(req.ids, np.int64), minlength=self._vocab
-                )[:self._vocab].astype(np.int32)
-                if req.out_ids:
-                    ch1 = np.bincount(
-                        np.asarray(req.out_ids, np.int64),
-                        minlength=self._vocab,
-                    )[:self._vocab].astype(np.int32)
-                else:
-                    ch1 = np.zeros_like(ch0)
-                self._counts = self._counts_insert(
-                    self._counts, np.stack([ch0, ch1])[None], np.int32(b)
-                )
+                self._seed_counts(req, b)
             self.stats.migrated_in += 1
-            self.stats.paged_blocks_in_use = self._alloc.used_count
-        except _PoolExhausted:
+        except PoolExhausted:
             self._release_row(b)
             raise
+
+    def _seed_counts(self, req: Request, b: int) -> np.ndarray:
+        """Row b's occurrence counts [1, 2, V], built host-side (bincount is
+        O(n+V) in numpy — no device round trip) and shipped as the row's
+        fresh counts. Channel 0: prompt (repetition's "seen"); channel 1:
+        generated (presence/frequency) — empty at admission, the accepted
+        output of an imported row. Returns what it inserted."""
+        if self._counts is None:
+            self._counts = self._counts_zeros(self._bsz)
+        V = self._vocab
+        row = np.stack([
+            np.bincount(np.asarray(ids, np.int64), minlength=V)[:V]
+            for ids in (req.ids, req.out_ids)
+        ]).astype(np.int32)[None]
+        self._counts = self._counts_insert(self._counts, row, np.int32(b))
+        return row
 
     # ------------------------------------------------------- batch resizing
 
@@ -1274,9 +1013,9 @@ class BatchScheduler:
     def _resize(self, new_bsz: int):
         """Move to a new batch bucket. The pool is batch-bucket-
         independent (row identity lives in the block table), so only the
-        host mirrors and the counts resize — zero cache copies. Active
-        rows live in [0, active); the copy of min(old, new) leading rows
-        carries them all."""
+        host mirrors, the counts and a recurrent model's state resize —
+        zero cache copies. Active rows live in [0, active); the copy of
+        min(old, new) leading rows carries them all."""
         old = self._bsz
         if new_bsz == old:
             return
@@ -1287,34 +1026,19 @@ class BatchScheduler:
                 )
             else:
                 self._counts = self._counts_shrink(self._counts, new_bsz)
-        if self._recurrent:
-            if new_bsz > old:
-                self._state = self._state_grow(
-                    self.engine.new_state(new_bsz), self._state
-                )
-            else:
-                self._state = self._state_shrink(self._state, new_bsz)
-        cur = np.zeros((new_bsz,), np.int32)
-        offs = np.zeros((new_bsz,), np.int32)
-        aids = np.zeros((new_bsz,), np.int32)
+        self.cache.resize(new_bsz)
         keep = min(old, new_bsz)
-        cur[:keep] = self._cur[:keep]
-        offs[:keep] = self._offsets[:keep]
-        aids[:keep] = self._aids[:keep]
-        self._cur = cur
-        self._offsets = offs
-        self._aids = aids
+
+        def fit(mirror):
+            out = np.zeros((new_bsz,), np.int32)
+            out[:keep] = mirror[:keep]
+            return out
+
+        self._cur, self._offsets = fit(self._cur), fit(self._offsets)
+        self._aids = fit(self._aids)
         self._rows = self._rows[:keep] + [None] * (new_bsz - keep)
         self._bsz = new_bsz
         self._row_params_dirty = True
-        self._set_state_gauges()
-
-    def _set_state_gauges(self):
-        if self._recurrent:
-            _G_STATE_ROWS.set(self._bsz)
-            _G_STATE_BYTES.set(
-                sum(a.nbytes for a in jax.tree.leaves(self._state))
-            )
 
     @_phase("compact")
     def _compact_and_shrink(self):
@@ -1330,20 +1054,11 @@ class BatchScheduler:
             )
             if hole is None or last is None or last < hole:
                 break
-            # compaction is a host table move — zero device copies
-            self._tables[hole] = self._tables[last]
-            self._tables[last] = 0
-            self._row_blocks[hole] = self._row_blocks[last]
-            self._row_blocks[last] = []
+            # a host table move for the pages — zero device copies
+            self.cache.move(last, hole)
             if self._counts is not None:
                 self._counts = self._counts_move(
                     self._counts, np.int32(last), np.int32(hole)
-                )
-            if self._recurrent:
-                # the row's state moves with it (the one device copy a
-                # compaction costs; its pages move by table alone)
-                self._state = self._state_move(
-                    self._state, np.int32(last), np.int32(hole)
                 )
             self._cur[hole] = self._cur[last]
             self._offsets[hole] = self._offsets[last]
@@ -1376,13 +1091,30 @@ class BatchScheduler:
             # boundary (A*2 <= bsz/2  ⇔  A <= bsz/4)
             self._resize(max(1, self._bsz // 2))
 
-    def _paged_prefill(self, req: Request, b: int, bucket: int, start: int,
-                       cached, seq: list | None = None) -> object:
-        """Admit one request onto the paged pool: wire row b's block table
-        (sharing a matched prefix's full blocks, CoW-copying at most its
-        final partial block), chunk-prefill the remainder straight into
-        the pool, and pin the prompt's blocks in the prefix cache.
-        Returns last_logits [1, V]. On _PoolExhausted every reference this
+    def _plan_prefill(self, req: Request, seq: list):
+        """-> (start, cached blocks | None), and req.bucket: the longest
+        cached prefix of ``seq`` — admit from there and prefill only the
+        remainder (chat transcripts grow by appending) — and the prefill
+        bucket for that remainder. Adapter rows skip the cache both ways:
+        their K/V diverges from the base model's under the adapted
+        projections."""
+        e = self.engine
+        start, cached = (0, None) if req.adapter else self.cache.match_prefix(seq)
+        C = e.engine_cfg.prefill_chunk
+        remaining = len(seq) - start
+        if C is not None and remaining > C:
+            req.bucket = C  # chunked: one compiled shape for all lengths
+        else:
+            req.bucket = e._bucket_for(remaining)
+        return start, cached
+
+    def _paged_prefill(self, req: Request, b: int, start: int, cached,
+                       seq: list | None = None) -> object:
+        """Admit one request onto the paged pool: row b adopts the matched
+        prefix (RowCache.adopt: shared full blocks, at most one CoW copy),
+        the remainder chunk-prefills straight into the pool, and the
+        prompt's blocks are pinned in the prefix cache.
+        Returns last_logits [1, V]. On PoolExhausted every reference this
         call took is released and the table row is nulled, so the caller
         can requeue the request cleanly — and the raise happens BEFORE any
         device work (block sufficiency is prechecked), so a requeue-retry
@@ -1392,8 +1124,7 @@ class BatchScheduler:
         ``seq`` overrides the token sequence prefilled (default: the
         prompt). The re-prefill import rung (_paged_import) passes
         prompt + accepted-so-far — one chunk walk, two consumers."""
-        e = self.engine
-        BS = self._block_size
+        e, bucket = self.engine, req.bucket  # _plan_prefill chose it
         # goodput accounting: a re-prefill (migration/failover import —
         # `seq` passed) recomputes K/V the fleet already paid for once;
         # its positions are scheduled work that produces zero USEFUL
@@ -1402,59 +1133,14 @@ class BatchScheduler:
         if seq is None:
             seq = req.ids
         n = len(seq)
-        if cached is None:
-            start = 0
-        row: list[int] = []
-        self._row_blocks[b] = row
-        self._tables[b, :] = 0
-        temp_ref: list[int] = []
         try:
-            full = start // BS
+            copied = self.cache.adopt(b, n, start, cached)
             if cached is not None:
-                shared = list(cached[:full])
-                # take our refs FIRST: the eviction below may reclaim
-                # prefix entries — including the donor — and must not free
-                # blocks this row is about to depend on
-                self._alloc.ref(shared)
-                row.extend(shared)
-                self._tables[b, :full] = shared
-                if start % BS:
-                    self._alloc.ref([int(cached[full])])
-                    temp_ref.append(int(cached[full]))
-            # sufficiency precheck before ANY device work: the write ceil
-            # drops every scatter at/past position n, so prefill claims
-            # exactly the blocks covering the prompt — ceil(n / BS) —
-            # regardless of bucket padding (fresh blocks = that minus the
-            # shared fulls; the CoW copy target is the full-th block and
-            # is counted)
-            fresh_needed = ceil_div(n, BS) - full
-            if fresh_needed > self._alloc.free_count and not (
-                self._prefix_cache is not None
-                and self._prefix_cache.evict_for_pressure(fresh_needed)
-            ):
-                raise _PoolExhausted(
-                    f"paged KV pool exhausted: admission needs "
-                    f"{fresh_needed} blocks, {self._alloc.free_count} free "
-                    f"of {self._alloc.num_blocks}"
-                )
-            if cached is not None:
-                if start % BS:
-                    src = temp_ref[0]
-                    fresh = self._alloc_or_evict(1)
-                    # the ONE CoW device copy: the borrower writes into
-                    # this block from position `start`, so it gets its own
-                    self._cache = self._copy_block(
-                        self._cache, np.int32(src), np.int32(fresh[0])
-                    )
-                    self.stats.paged_blocks_copied += 1
-                    row.append(fresh[0])
-                    self._tables[b, full] = fresh[0]
-                    self._alloc.deref(temp_ref)
-                    temp_ref.clear()
+                self.stats.paged_blocks_copied += copied
                 self.stats.prefix_hits += 1
                 self.stats.prefix_tokens_saved += start
-            # the chunk walk (paged.prefill_chunk_positions — the
-            # precheck above simulated exactly these windows). The
+            # the chunk walk (paged.prefill_chunk_positions — adopt's
+            # precheck simulated exactly these windows). The
             # capacity re-anchor can re-feed tokens BELOW `start`;
             # recomputed K/V under a different chunk geometry is not
             # guaranteed bit-identical, so the write floor keeps shared
@@ -1469,12 +1155,11 @@ class BatchScheduler:
                 # the write ceil (n) turns the bucket's padded-tail
                 # scatters into null-block writes, so the row only ever
                 # claims blocks covering real prompt positions
-                self._ensure_blocks(b, min(pos + bucket, n))
+                self.cache.cover(b, min(pos + bucket, n))
                 chunk = seq[pos:pos + bucket]
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :len(chunk)] = chunk
-                tw = self._table_width(len(row))
-                tbl = np.ascontiguousarray(self._tables[b:b + 1, :tw])
+                tbl = self.cache.row_table(b)
                 if row_state is not None and pos != fed:
                     # engine._validate_recurrent_features makes the walk
                     # monotone; a re-fed token would be absorbed twice,
@@ -1485,19 +1170,19 @@ class BatchScheduler:
                     )
                 fed = pos + len(chunk)
                 out = e._prefill(
-                    e.params, tokens, self._cache,
+                    e.params, tokens, self.cache.pool,
                     np.asarray([len(chunk)], np.int32),
                     np.int32(pos), tbl, np.int32(start), np.int32(n),
                     **({"state": row_state} if row_state is not None
                        else self._lora_args_row(req)),
                 )
-                self._count_pages_written(1, bucket)
+                self.cache.count_pages_written(1, bucket)
                 if row_state is not None:
-                    self._cache, last_logits, row_state = out
+                    self.cache.pool, last_logits, row_state = out
                     _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
                     _C_SSM_SCAN_TOKENS.inc(bucket - len(chunk), kind="pad")
                 else:
-                    self._cache, last_logits = out
+                    self.cache.pool, last_logits = out
                 # economics: the bucket's padded width is what the chip
                 # ran; only the real prompt tokens were useful (and none
                 # on the re-prefill rung)
@@ -1507,26 +1192,36 @@ class BatchScheduler:
                 if not recompute:
                     self._meter.note_useful(len(chunk))
             if row_state is not None:
-                self._state = self._state_insert(
-                    self._state, row_state, np.int32(b)
-                )
+                self.cache.put_state(b, row_state)
             # adapter rows NEVER enter the prefix cache: an adapted wk/wv
             # writes adapter-specific K/V, so sharing those blocks with a
             # base-model (or other-adapter) prompt would serve silently
             # wrong attention — sharing stays base-model-only
-            if (self._prefix_cache is not None and not req.adapter
-                    and not self._prefix_cache.has(seq)):
-                # pinning is free (refcounts, no snapshot): the entry
-                # claims the blocks covering exactly the prefilled positions
-                self._prefix_cache.put(seq, row[:ceil_div(n, BS)])
-                # a capacity eviction inside put() may have freed blocks
-                self.stats.paged_blocks_in_use = self._alloc.used_count
+            if not req.adapter:
+                self.cache.publish_prefix(b, seq)
             return last_logits
-        except _PoolExhausted:
-            if temp_ref:
-                self._alloc.deref(temp_ref)
+        except PoolExhausted:
             self._release_row(b)
             raise
+
+    def _refund(self, req: Request):
+        """A popped request that will never decode: the pop charged its
+        tenant's WDRR deficit for its token budget — give it back, same
+        as admission does for abandoned waiters."""
+        with self._cond:
+            self._queue.refund(req.tenant, max(1.0, float(req.max_new_tokens)))
+
+    def _requeue_front(self, req: Request):
+        """This admission attempt is over, the request is not: back to the
+        FRONT of the queue (which refunds the WDRR cost charged at the pop,
+        so the retry isn't double-billed), its adapter refcount returned (the
+        retry re-acquires)."""
+        self._release_adapter(req)
+        with self._cond:
+            self._queue.appendleft(
+                req, tenant=req.tenant,
+                cost=max(1.0, float(req.max_new_tokens)),
+            )
 
     @_phase("admit")
     def _admit(self):
@@ -1547,13 +1242,7 @@ class BatchScheduler:
                 req.finish = "cancelled"
                 req.timing.t_first = req.timing.t_done = time.perf_counter()
                 req.events.put({"done": True, "result": e._build_result(req)})
-                # the pop charged this tenant's WDRR deficit for tokens
-                # that will never decode — refund, same as admission does
-                # for abandoned waiters
-                with self._cond:
-                    self._queue.refund(
-                        req.tenant, max(1.0, float(req.max_new_tokens))
-                    )
+                self._refund(req)
                 continue
             req.timing.t_admit = time.perf_counter()
             if req.adapter:
@@ -1569,16 +1258,8 @@ class BatchScheduler:
                 except Exception as err:  # UnknownAdapter / pool races:
                     # typed retirement — the serving surfaces map the
                     # kind onto 404 (/v1) and gen_error (p2p)
-                    req.finish = "error"
-                    req.events.put({
-                        "done": True, "result": None,
-                        "error": f"unknown adapter: {err}",
-                        "error_kind": "unknown_adapter",
-                    })
-                    with self._cond:
-                        self._queue.refund(
-                            req.tenant, max(1.0, float(req.max_new_tokens))
-                        )
+                    self._fail(req, f"unknown adapter: {err}", "unknown_adapter")
+                    self._refund(req)
                     continue
             if self.active == self._bsz:
                 if not self._growth_headroom():
@@ -1587,14 +1268,7 @@ class BatchScheduler:
                     # wider bucket's footprint for good): requeue at the
                     # front — retirements free rows at the CURRENT width
                     # and the retry admits into a hole without growing
-                    self._release_adapter(req)
-                    with self._cond:
-                        # front requeue refunds the WDRR cost charged at
-                        # the pop, so the retry isn't double-billed
-                        self._queue.appendleft(
-                            req, tenant=req.tenant,
-                            cost=max(1.0, float(req.max_new_tokens)),
-                        )
+                    self._requeue_front(req)
                     self.stats.width_grow_denials += 1
                     break
                 self._resize(min(self._bsz * 2, self.max_batch))
@@ -1612,36 +1286,19 @@ class BatchScheduler:
                         kv=st.get("kv") is not None,
                     ):
                         self._paged_import(req, b, st)
-                except _PoolExhausted as err:
+                except PoolExhausted as err:
                     # typed, immediate: the exporter's fallback ladder
                     # (re-prefill elsewhere) beats parking the import on
                     # backpressure that may never clear
-                    self._release_adapter(req)
-                    req.finish = "error"
-                    req.events.put({
-                        "done": True, "result": None,
-                        "error": f"import failed: {err}",
-                        "error_kind": "pool_exhausted",
-                    })
-                    # the pop charged this tenant's WDRR deficit for
-                    # tokens that will never decode — refund, same as the
-                    # cancelled path above
-                    with self._cond:
-                        self._queue.refund(
-                            req.tenant, max(1.0, float(req.max_new_tokens))
-                        )
+                    self._fail(req, f"import failed: {err}", "pool_exhausted")
+                    self._refund(req)
                     continue
                 except Exception as err:
                     # this request is in neither _queue nor _rows, so the
                     # _fail_all sweep upstream can never release its slot
                     # lease — drop it here or the refcount pins the slot
                     # (and eventually the whole pool) until restart
-                    self._release_adapter(req)
-                    req.finish = "error"
-                    req.events.put({
-                        "done": True, "result": None,
-                        "error": f"import failed: {err!r}",
-                    })
+                    self._fail(req, f"import failed: {err!r}")
                     raise
                 self._rows[b] = req
                 self._aids[b] = req.adapter_slot
@@ -1654,22 +1311,8 @@ class BatchScheduler:
                 continue
 
             n = len(req.ids)
-            # longest cached prompt prefix: admit from there and prefill
-            # only the remainder (chat transcripts grow by appending).
-            # Adapter rows skip the cache both ways — their K/V diverges
-            # from the base model's under the adapted projections
-            start, cached = (
-                self._prefix_cache.match(req.ids)
-                if self._prefix_cache is not None and not req.adapter
-                else (0, None)
-            )
-            C = e.engine_cfg.prefill_chunk
-            remaining = n - (start if cached is not None else 0)
-            if C is not None and remaining > C:
-                bucket = C  # chunked: one compiled shape for all lengths
-            else:
-                bucket = e._bucket_for(remaining)
-            req.bucket = bucket
+            start, cached = self._plan_prefill(req, req.ids)
+            bucket = req.bucket
             try:
                 with get_tracer().span(
                     "engine.admit", row=b, prompt_tokens=n, bucket=bucket,
@@ -1680,9 +1323,7 @@ class BatchScheduler:
                     # Prefill straight into the shared pool through the
                     # row's block table; prefix hits share the donor's
                     # full blocks CoW (engine/paged.py)
-                    last_logits = self._paged_prefill(
-                        req, b, bucket, start, cached
-                    )
+                    last_logits = self._paged_prefill(req, b, start, cached)
                     # one arg tuple for plain and penalized rows: a
                     # marshalling change must hit both identically
                     sample_args = [
@@ -1695,30 +1336,15 @@ class BatchScheduler:
                          if req.min_p > 0 else None),
                     ]
                     if req.penalized:
-                        # prompt occurrences host-side (bincount is O(n+V)
-                        # in numpy — no device round trip), shipped as the
-                        # row's fresh counts; the first sample sees them.
-                        # Channel 0: prompt (repetition's "seen"); channel
-                        # 1: generated, fresh at zero (presence/frequency)
-                        if self._counts is None:
-                            self._counts = self._counts_zeros(self._bsz)
-                        prompt_counts = np.bincount(
-                            np.asarray(req.ids, np.int64), minlength=self._vocab
-                        )[:self._vocab].astype(np.int32)
-                        row_counts = np.stack(
-                            [prompt_counts, np.zeros_like(prompt_counts)]
-                        )[None]
-                        self._counts = self._counts_insert(
-                            self._counts, row_counts, np.int32(b)
-                        )
+                        # the first sample sees the row's fresh counts
                         sample_args += [
-                            row_counts,
+                            self._seed_counts(req, b),
                             np.asarray([req.repetition_penalty], np.float32),
                             np.asarray([req.presence_penalty], np.float32),
                             np.asarray([req.frequency_penalty], np.float32),
                         ]
                     first = self._sample_first(*sample_args)
-            except _PoolExhausted as err:
+            except PoolExhausted as err:
                 # backpressure, not failure: _paged_prefill released the
                 # row's blocks before raising. With work in flight (or a
                 # burst just placed) blocks WILL free — requeue at the
@@ -1727,23 +1353,12 @@ class BatchScheduler:
                 # request can never fit the configured pool: fail it.
                 # either way this admission attempt is over: return the
                 # adapter refcount (a requeued retry re-acquires)
-                self._release_adapter(req)
                 if self.active > 0 or placed:
-                    with self._cond:
-                        # front requeue refunds the WDRR cost charged at
-                        # the pop, so the retry isn't double-billed
-                        self._queue.appendleft(
-                            req, tenant=req.tenant,
-                            cost=max(1.0, float(req.max_new_tokens)),
-                        )
+                    self._requeue_front(req)
                     self.stats.paged_alloc_waits += 1
                     break
-                req.finish = "error"
-                req.events.put({
-                    "done": True, "result": None,
-                    "error": f"admission failed: {err} "
-                             "(kv_pool_blocks too small for this request)",
-                })
+                self._fail(req, f"admission failed: {err} "
+                                "(kv_pool_blocks too small for this request)")
                 # TERMINAL exhaustion (nothing in flight to free blocks) is
                 # an incident, unlike the backpressure requeue above — a
                 # pool sized under the workload is an operator problem the
@@ -1758,11 +1373,7 @@ class BatchScheduler:
                 # the popped request is in neither _queue nor _rows: fail it
                 # here or its caller hangs; then let _loop's handler recover
                 # (which errors the rest of this burst — they sit in _rows)
-                self._release_adapter(req)
-                req.finish = "error"
-                req.events.put(
-                    {"done": True, "result": None, "error": f"admission failed: {err!r}"}
-                )
+                self._fail(req, f"admission failed: {err!r}")
                 raise
             # reserve the row now (cur gets the real token after readback)
             self._rows[b] = req
@@ -1798,7 +1409,6 @@ class BatchScheduler:
                 # for streaming consumers; generate() reads the done event
                 req.emit([tok])
             if req.done:  # instant stop/zero-budget: free the row again
-                self._rows[b] = None
                 self._release_row(b)
                 self._retire(req)
                 continue
@@ -1832,10 +1442,8 @@ class BatchScheduler:
                     logger.exception("prefill handoff failed")
                     continue
                 if accepted:
-                    self._rows[b] = None
                     self._release_row(b)
                     self._release_adapter(req)
-                    self._row_params_dirty = True
                     self.stats.migrated_out += 1
                     self.stats.prefill_handoffs += 1
         self._compact_and_shrink()
@@ -1891,6 +1499,13 @@ class BatchScheduler:
             "ascales": scales,
         }
 
+    def _tightest_budget(self) -> int:
+        """Tokens the live row nearest its budget may still accept."""
+        return min(
+            r.max_new_tokens - len(r.out_ids)
+            for r in self._rows if r is not None
+        )
+
     def _window_size(self, pending: int = 0) -> int:
         """Chunks to dispatch before the next host sync (see
         EngineConfig.max_inflight_chunks). Streaming requests pin the
@@ -1911,37 +1526,12 @@ class BatchScheduler:
         K = e.engine_cfg.decode_chunk
         if any(r is not None and r.stream for r in self._rows):
             return 1
-        if (
-            self._spec is not None
-            and self._spec_possible()
-            and any(
-                r is not None and self._spec_eligible(b, r)
-                for b, r in enumerate(self._rows)
-            )
-        ):
+        if self._spec_wants_sync():
             return 1
-        min_left = min(
-            r.max_new_tokens - len(r.out_ids)
-            for r in self._rows
-            if r is not None
-        ) - pending
-        w = -(-min_left // K)  # ceil
+        w = -(-(self._tightest_budget() - pending) // K)  # ceil
         if self._queue:  # queued work wants a row soon: keep syncs frequent
             w = min(w, 2)
         return max(1, min(w, e.engine_cfg.max_inflight_chunks))
-
-    def _count_pages_written(self, rows: int, chunk: int, calls: int = 1):
-        """engine.kv_pages_written for one dispatch of ``calls`` forwards
-        over [rows, chunk] tokens — only where core.forward writes through
-        the page-write kernel."""
-        e = self.engine
-        if e.kv_in_place:
-            from ..ops.ragged import chunk_pages  # loaded with the attn_fn
-
-            _C_KV_PAGES_WRITTEN.inc(
-                rows * chunk_pages(chunk, e.engine_cfg.kv_block_size)
-                * calls * 2 * e.model_cfg.n_layers
-            )
 
     def _prepare_window_tables(self, extra: int, calls: int):
         """Paged: grow every active row's block table to cover the next
@@ -1957,8 +1547,8 @@ class BatchScheduler:
             if req is None:
                 continue
             try:
-                self._ensure_blocks(b, int(self._offsets[b]) + extra)
-            except _PoolExhausted as err:
+                self.cache.cover(b, int(self._offsets[b]) + extra)
+            except PoolExhausted as err:
                 # migration-based failover: a row the pool can no longer
                 # grow is fully recoverable state — offer it to the
                 # migration hook (a peer with headroom resumes it KV-
@@ -1976,30 +1566,24 @@ class BatchScheduler:
                         logger.exception("pool-pressure migration failed")
                     if migrated:
                         self.stats.migrated_out += 1
-                self._rows[b] = None
                 self._release_row(b)
-                self._row_params_dirty = True
                 if not migrated:
                     self._retire_error(req, str(err))
                 else:
                     self._release_adapter(req)
-        live = [
-            len(self._row_blocks[b])
-            for b, r in enumerate(self._rows) if r is not None
-        ]
-        if not live:
+        live_rows = [b for b, r in enumerate(self._rows) if r is not None]
+        if not live_rows:
             return None
-        tw = self._table_width(max(live))
+        tables, live = self.cache.window_table(live_rows, self._bsz)
         # the two proportionality counters: what the gather reads vs what
         # is actually mapped (tests + bench assert they track each other)
-        self.stats.paged_live_blocks = sum(live)
-        self.stats.paged_blocks_read_last_step = self._bsz * tw
-        self.stats.paged_blocks_in_use = self._alloc.used_count
-        _C_KV_PAGES_VISITED.inc(self._bsz * tw * calls)
-        _C_KV_PAGES_LIVE.inc(sum(live) * calls)
+        self.stats.paged_live_blocks = live
+        self.stats.paged_blocks_read_last_step = tables.size
+        _C_KV_PAGES_VISITED.inc(tables.size * calls)
+        _C_KV_PAGES_LIVE.inc(live * calls)
         # extra / calls = the tokens a call writes: 1 a decode step, K+1
-        self._count_pages_written(self._bsz, extra // calls, calls)
-        return np.ascontiguousarray(self._tables[:self._bsz, :tw])
+        self.cache.count_pages_written(self._bsz, extra // calls, calls)
+        return tables
 
     def _spec_eligible(self, b: int, req: Request) -> bool:
         """Row-level speculation gate: greedy, not penalized, some tier
@@ -2046,6 +1630,19 @@ class BatchScheduler:
             if int(self._offsets[b]) + K + 1 > e.max_seq_len:
                 return False
         return True
+
+    def _spec_wants_sync(self) -> bool:
+        """Does some live row want a draft look at the NEXT readback?
+        (_window_size pins the window to one chunk then, _overlap_ready
+        stacks nothing ahead of it: both must agree.)"""
+        return (
+            self._spec is not None
+            and self._spec_possible()
+            and any(
+                r is not None and self._spec_eligible(b, r)
+                for b, r in enumerate(self._rows)
+            )
+        )
 
     def _spec_transition(self, req: Request, failed_tier: str):
         """Move a row whose CURRENT tier just failed (probe miss budget
@@ -2206,21 +1803,18 @@ class BatchScheduler:
             with get_tracer().span(
                 "engine.spec_verify", active=self.active, drafted=int(lens.sum())
             ):
+                pen_args = dict(
+                    counts=self._counts, reps=self._reps,
+                    press=self._press, freqs=self._freqs,
+                ) if pen else {}
+                nxt_d, self.cache.pool, acc_d, *cnts = e._spec_verify(
+                    e.params, self._cur, drafts, lens, self.cache.pool,
+                    self._offsets, temps, topks, topps, minps,
+                    e._next_key(), tables, **self._lora_args(), **pen_args,
+                )
                 if pen:
-                    nxt_d, self._cache, acc_d, self._counts = e._spec_verify(
-                        e.params, self._cur, drafts, lens, self._cache,
-                        self._offsets, temps, topks, topps, minps,
-                        e._next_key(), tables, **self._lora_args(),
-                        counts=self._counts, reps=self._reps,
-                        press=self._press, freqs=self._freqs,
-                    )
+                    (self._counts,) = cnts
                     self.stats.counts_windows += 1
-                else:
-                    nxt_d, self._cache, acc_d = e._spec_verify(
-                        e.params, self._cur, drafts, lens, self._cache,
-                        self._offsets, temps, topks, topps, minps,
-                        e._next_key(), tables, **self._lora_args(),
-                    )
                 # a spec step is always a serialized sync: the drafter needs
                 # the verdict before it can propose again
                 _C_HOST_SYNCS.inc()
@@ -2284,9 +1878,8 @@ class BatchScheduler:
         # pool-growth forecast (engine/introspect.py): sampled on the
         # dispatch cadence so the pool_exhaust_eta gauge the admission
         # shed reads tracks the live allocation trend
-        self.engine.introspect.forecast.feed(
-            self._alloc.used_count, self._alloc.free_count
-        )
+        alloc = self.cache.alloc
+        self.engine.introspect.forecast.feed(alloc.used_count, alloc.free_count)
 
     def _mean_active_ctx(self) -> float:
         """Mean cache depth of the active rows — the attention-term input
@@ -2320,9 +1913,7 @@ class BatchScheduler:
         if emitted and req.stream:
             req.emit(emitted)
         if req.done:
-            self._rows[b] = None
             self._release_row(b)
-            self._row_params_dirty = True
             self._retire(req)
             return True
         return False
@@ -2397,7 +1988,7 @@ class BatchScheduler:
         AT DISPATCH — every pending-window consumer (_prepare_window_
         tables, _spec_eligible, _overlap_ready) sees the post-in-flight
         positions. Returns False when no active rows survive table prep."""
-        e = self.engine
+        e, c = self.engine, self.cache
         K = e.engine_cfg.decode_chunk
         W = self._window_size(pending)
         tables = self._prepare_window_tables(W * K, W * K)
@@ -2441,18 +2032,18 @@ class BatchScheduler:
                 cur_d = jax.device_put(cur_d, self._chain_sharding[0])
                 off_d = jax.device_put(off_d, self._chain_sharding[1])
         lora = dict(self._lora_args())
-        if self._recurrent:
+        if c.recurrent:
             steps = W * K * e.model_cfg.n_layers
             _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
             _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
         toks_parts = []
         for _ in range(W):
-            if self._recurrent:
+            if c.recurrent:
                 # the state chains through the windows like the pool does
-                lora["state"] = self._state
+                lora["state"] = c.state
             if self._fused:
-                cur_d, self._cache, off_d, cnts, toks, self._state = self._decode(
-                    e.params, cur_d, self._cache, off_d,
+                cur_d, c.pool, off_d, cnts, toks, c.state = self._decode(
+                    e.params, cur_d, c.pool, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     counts=self._counts if pen else None,
                     reps=self._reps if pen else None,
@@ -2463,9 +2054,9 @@ class BatchScheduler:
                 if pen:
                     self._counts = cnts
             elif pen:
-                cur_d, self._cache, off_d, self._counts, toks, self._state = (
+                cur_d, c.pool, off_d, self._counts, toks, c.state = (
                     self._decode_pen(
-                        e.params, cur_d, self._cache, off_d, self._counts,
+                        e.params, cur_d, c.pool, off_d, self._counts,
                         temps, topks, topps, minps,
                         self._reps, self._press, self._freqs,
                         e._next_key(), tables, **lora,
@@ -2476,8 +2067,8 @@ class BatchScheduler:
                 # left None it lowers to the counts-free graph, so the
                 # unfused setting differs only in routing pen windows to
                 # the split _decode_pen root above
-                cur_d, self._cache, off_d, _, toks, self._state = self._decode(
-                    e.params, cur_d, self._cache, off_d,
+                cur_d, c.pool, off_d, _, toks, c.state = self._decode(
+                    e.params, cur_d, c.pool, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     **lora,
                 )
@@ -2524,42 +2115,26 @@ class BatchScheduler:
         # a spec-eligible row wants a draft look at the NEXT readback —
         # stacking plain windows ahead of it would decode past the
         # repetition the drafter feeds on
-        if (
-            self._spec is not None
-            and self._spec_possible()
-            and any(
-                r is not None and self._spec_eligible(b, r)
-                for b, r in enumerate(self._rows)
-            )
-        ):
+        if self._spec_wants_sync():
             return False
         e = self.engine
         K = e.engine_cfg.decode_chunk
-        min_left = min(
-            r.max_new_tokens - len(r.out_ids)
-            for r in self._rows
-            if r is not None
-        )
         # some row must still need tokens BEYOND what is already in
         # flight, or the whole window would be budget overshoot
-        if min_left <= pending:
+        if self._tightest_budget() <= pending:
             return False
         W = self._window_size(pending)
-        need = 0
-        for b, r in enumerate(self._rows):
-            if r is None:
-                continue
-            upto = int(self._offsets[b]) + W * K
-            # hard capacity: the non-overlap path may overshoot into the
-            # decode_chunk margin once; stacked look-ahead may not
-            if upto > e.max_seq_len:
-                return False
-            need += max(
-                0, ceil_div(upto, self._block_size) - len(self._row_blocks[b])
-            )
+        growth = [
+            (b, int(self._offsets[b]) + W * K)
+            for b, r in enumerate(self._rows) if r is not None
+        ]
+        # hard capacity: the non-overlap path may overshoot into the
+        # decode_chunk margin once; stacked look-ahead may not
+        if any(upto > e.max_seq_len for _, upto in growth):
+            return False
         # the free list must cover the window outright: look-ahead never
         # reclaims prefix pins and never migrates/retires a row
-        return need <= self._alloc.free_count
+        return self.cache.growth_fits(growth)
 
     @_phase("fetch")
     def _fetch_window(self, rec) -> np.ndarray:
@@ -2617,10 +2192,8 @@ class BatchScheduler:
         """Free blocks whose rows retired while windows were in flight —
         only once the ring is empty (until then, in-flight windows still
         dead-row-scatter into them)."""
-        if self._deferred_blocks and not self._inflight:
-            self._alloc.deref(self._deferred_blocks)
-            self._deferred_blocks = []
-            self.stats.paged_blocks_in_use = self._alloc.used_count
+        if not self._inflight:
+            self.cache.flush_deferred()
 
     def _retire(self, req: Request):
         self._release_adapter(req)
@@ -2637,14 +2210,12 @@ class BatchScheduler:
         """Error-terminate an ADMITTED row with full retirement accounting
         (retired/history/t_done) — `admitted - retired` must not drift for
         rows the pool failed mid-decode."""
-        self._release_adapter(req)
         if self._spec is not None:
             self._spec.forget(req)
-        req.finish = "error"
         req.timing.t_done = time.perf_counter()
         self.stats.retired += 1
         self.stats.history.append(
             {"new_tokens": len(req.out_ids), "chunks": req.chunks_decoded,
              "error": True}
         )
-        req.events.put({"done": True, "result": None, "error": reason})
+        self._fail(req, reason)

@@ -103,7 +103,7 @@ class ChaosMigration:
             mgr._send_chunk = wrapped
             self._restores.append(lambda: setattr(mgr, "_send_chunk", orig))
         else:  # exhaust_target
-            from ..engine.scheduler import _PoolExhausted
+            from ..engine.paged import PoolExhausted
 
             # the wrapper below runs on the ENGINE SCHEDULER THREAD;
             # asyncio.Event.set is not thread-safe, so the trigger hops
@@ -125,7 +125,7 @@ class ChaosMigration:
                         loop.call_soon_threadsafe(self.triggered.set)
                     else:
                         self.triggered.set()
-                    raise _PoolExhausted("chaos: import pool exhausted")
+                    raise PoolExhausted("chaos: import pool exhausted")
 
                 sch._paged_import = failing
                 self._restores.append(

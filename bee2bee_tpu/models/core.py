@@ -1212,8 +1212,24 @@ def forward(
             return out
 
         def kv_hook(k, v):
-            # write this chunk's K/V at [offset, offset+T) per batch row,
-            # then attend over the whole cache row
+            """Write this chunk's K/V at [offset, offset+T) of every row and
+            return what attention reads. Over the paged pool (block tables
+            given) three variants remain, selected by ``attn_fn.ragged``
+            and ``"k_scale" in cache``:
+
+            - ragged reader, float pool: the page-write kernel stores the
+              chunk into the STACKED pool, which is returned whole (the
+              kernel reads layer ``layer_idx`` of it in place);
+            - int8 pool (``k_scale`` present), either reader: XLA's
+              requantising page write on the layer's slice; the ragged
+              reader gets (pages, scales), the dense one the dequantised
+              gathered view;
+            - dense / sp reader, float pool: XLA's scatter on the layer's
+              slice, then the gathered [B, S, Hkv, hd] view.
+
+            Without tables (core.init_cache's rectangular cache: the model
+            drafter) the chunk is a dynamic-update-
+            slice into the row, and attention reads that row."""
             nonlocal lcache
 
             if page_write is not None:
@@ -1298,10 +1314,6 @@ def forward(
                     k=lcache["k"].at[layer_idx].set(ck),
                     v=lcache["v"].at[layer_idx].set(cv),
                 )
-                if ragged:
-                    # the kernel gathers straight from the pool — no
-                    # [B, S, Hkv, hd] view, no [T, S] scores
-                    return ck, cv
                 k_eff = jnp.transpose(ck[:, bt], (1, 2, 3, 0, 4)).reshape(
                     B, S, Hkv, hd
                 )

@@ -181,7 +181,7 @@ def bench_paged(msl: int, new_tokens: int) -> dict:
 
     eng = InferenceEngine(
         "distilgpt2",
-        engine_config=EngineConfig(max_seq_len=msl, max_batch=8, paged=True),
+        engine_config=EngineConfig(max_seq_len=msl, max_batch=8),
     )
     try:
         prompt = [1 + j % 500 for j in range(PROMPT_LEN)]

@@ -745,7 +745,7 @@ def child_tp_compare() -> None:
         sch = four.scheduler
         B = 8
         text = jax.jit(sch._decode_fn, donate_argnums=(2,)).lower(
-            four.params, np.zeros(B, np.int32), sch._cache, np.zeros(B, np.int32),
+            four.params, np.zeros(B, np.int32), sch.cache.pool, np.zeros(B, np.int32),
             np.zeros(B, np.float32), np.zeros(B, np.int32), np.ones(B, np.float32),
             None, jax.random.key(0), np.zeros((B, MB), np.int32),
         ).compile().as_text()

@@ -228,15 +228,40 @@ def test_overlap_parity_under_retirement_and_admission(fused_engine,
     assert on == off, "overlap changed tokens under retirement/admission"
 
 
+def _run_burst(eng, budgets):
+    """Every row admitted in ONE burst: the requests are queued while the
+    scheduler's condition is held (an RLock: submit() re-enters it), so its
+    loop cannot pop the first before the last is in. What the ring then
+    does is a function of the budgets alone, not of thread arrival."""
+    sch = eng.scheduler
+    with sch._cond:
+        reqs = [
+            sch.submit(eng._make_request(
+                PROMPTS[i % ROWS], budget, 0.0, 0, 1.0, None
+            ))
+            for i, budget in enumerate(budgets)
+        ]
+    for req in reqs:
+        while not (ev := req.events.get(timeout=120)).get("done"):
+            pass
+        assert ev.get("result") is not None, ev
+
+
 def test_overlap_removes_host_sync_stalls(fused_engine, unfused_engine):
     """The overlap steady state (uniform budgets, no queue/stream/spec):
     with the ring on, some readback windows must find another window
     already in flight (stalls < syncs). With overlap off, EVERY sync is
-    a stall by construction — the serialized loop's 1.0 ratio."""
+    a stall by construction — the serialized loop's 1.0 ratio.
+
+    The overlap side is driven as one admission burst (_run_burst): four
+    rows of 48 tokens at decode_chunk 4 are a first window of 8 chunks with
+    a second of 4 dispatched behind it, so the first readback finds the
+    ring occupied and only the last one stalls. Arriving one by one, as
+    threads under a loaded machine do, each row can run alone on windows
+    that cover its whole budget, and every sync is then rightly a stall."""
     budgets = [48] * ROWS
-    _run_batch(fused_engine, budgets)  # warm: admission skew, compiles
     s0, t0 = _C_HOST_SYNCS.value(), _C_SYNC_STALLS.value()
-    _run_batch(fused_engine, budgets)
+    _run_burst(fused_engine, budgets)
     syncs, stalls = _C_HOST_SYNCS.value() - s0, _C_SYNC_STALLS.value() - t0
     assert syncs > 0
     assert stalls < syncs, (

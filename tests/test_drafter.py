@@ -69,7 +69,7 @@ def model_engine():
     tables (the rectangular path is covered by the bad-seed engine)."""
     eng = InferenceEngine(
         "tiny-llama",
-        engine_config=EngineConfig(**SPEC_KW, drafter="tiny-llama", paged=True),
+        engine_config=EngineConfig(**SPEC_KW, drafter="tiny-llama"),
     )
     yield eng
     eng.close()

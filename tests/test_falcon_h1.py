@@ -284,7 +284,7 @@ def test_rows_admitted_retired_compacted_resized_equal_their_solo_runs(solo, ove
             t.join()
         assert got == {s: solo[s] for s in spec}
         st = eng.scheduler
-        assert st._state["ssm"].shape[1] == st._bsz  # the state follows the bucket
+        assert st.cache.state["ssm"].shape[1] == st._bsz  # the state follows the bucket
     finally:
         eng.close()
 
@@ -308,7 +308,7 @@ def test_state_counters_and_gauges():
         assert step.value(kind="live") - was["real"][1] == windows * 4 * 2
         assert step.value(kind="dead") - was["pad"][1] == 0  # a bucket of one row
         assert reg.get("engine.state_rows").value() == 1
-        state_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.scheduler._state))
+        state_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.scheduler.cache.state))
         assert reg.get("engine.state_bytes").value() == state_bytes == 2 * (4 * 8 * 16 + 3 * 96) * 4
         eng.introspect.ledger.snapshot()  # the ledger's gauges refresh on a read
         assert reg.get("engine.hbm_bytes").value(component="state") == state_bytes

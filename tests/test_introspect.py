@@ -219,11 +219,11 @@ def test_engine_seeded_steady_state_retrace_fires_typed_incident(tmp_path):
         # this config) but is block-aligned, so the trace compiles fine
         tokens = np.zeros((1, 32), np.int32)
         tokens[0, :4] = [1, 2, 3, 4]
-        tbl = np.ascontiguousarray(sch._tables[0:1, : eng.blocks_per_row])
+        tbl = np.ascontiguousarray(sch.cache.tables[0:1, : eng.blocks_per_row])
         # write_ceil=0 nulls every KV write: the call is a pure compile
         # probe, no pool block is touched
-        sch._cache, _ = eng._prefill(
-            eng.params, tokens, sch._cache,
+        sch.cache.pool, _ = eng._prefill(
+            eng.params, tokens, sch.cache.pool,
             np.asarray([4], np.int32), np.int32(0), tbl,
             np.int32(0), np.int32(0),
         )

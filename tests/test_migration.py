@@ -120,12 +120,12 @@ def test_lane_aligned_pool_exports_at_the_models_head_size():
     def aligned_engine():
         eng = _engine(attention="flash")
         sch = eng.scheduler
-        sch._cache = jax.jit(functools.partial(
+        sch.cache.pool = jax.jit(functools.partial(
             core.init_paged_pool, eng.model_cfg, eng.pool_blocks,
-            eng.engine_cfg.kv_block_size, sch._cache["k"].dtype,
+            eng.engine_cfg.kv_block_size, sch.cache.pool["k"].dtype,
             lane_aligned=True,
         ))()
-        assert sch._cache["k"].shape[-1] == 128 != eng.model_cfg.head_dim
+        assert sch.cache.pool["k"].shape[-1] == 128 != eng.model_cfg.head_dim
         return eng
 
     plain, a, b = _engine(), aligned_engine(), aligned_engine()
@@ -138,7 +138,7 @@ def test_lane_aligned_pool_exports_at_the_models_head_size():
             out, result = _drain_events(dst.import_generation(snap, kv), snap["out"])
             assert out == base.token_ids
             assert dst.scheduler.stats.import_reprefills == 0
-        assert not np.asarray(b.scheduler._cache["k"][..., 16:]).any()
+        assert not np.asarray(b.scheduler.cache.pool["k"][..., 16:]).any()
     finally:
         for eng in (plain, a, b):
             eng.close()
@@ -231,17 +231,17 @@ def test_cow_shared_prefix_refcounts_across_migration():
 
         base = a.generate(PROMPT, max_new_tokens=24)  # pins the prompt
         sch_a = a.scheduler
-        pinned_a = sch_a._alloc.used_count
-        assert len(sch_a._prefix_cache) >= 1
+        pinned_a = sch_a.cache.alloc.used_count
+        assert len(sch_a.cache.prefix) >= 1
 
         snap, kv, _req = _checkpoint_mid_decode(a)  # prefix HIT on admit
         assert sch_a.stats.prefix_hits >= 1, "second admission missed CoW"
         # source: the released row dropped every ref it took; only cache
         # pins (and nothing of the migrated row) remain
-        assert sch_a._alloc.used_count == pinned_a
-        for blocks in sch_a._prefix_cache._entries.values():
+        assert sch_a.cache.alloc.used_count == pinned_a
+        for blocks in sch_a.cache.prefix._entries.values():
             for blk in blocks:
-                assert sch_a._alloc.refcount(blk) == 1
+                assert sch_a.cache.alloc.refcount(blk) == 1
 
         req2 = b.import_generation(snap, kv)
         out, _result = _drain_events(req2, snap["out"])
@@ -250,16 +250,16 @@ def test_cow_shared_prefix_refcounts_across_migration():
         n_prompt_blocks = ceil_div(len(snap["ids"]), b.engine_cfg.kv_block_size)
         # target after retirement: the import pinned the prompt's blocks
         # (so repeat prompts CoW-share there too) and released the rest
-        assert len(sch_b._prefix_cache) == 1
-        assert sch_b._alloc.used_count == n_prompt_blocks
-        for blocks in sch_b._prefix_cache._entries.values():
+        assert len(sch_b.cache.prefix) == 1
+        assert sch_b.cache.alloc.used_count == n_prompt_blocks
+        for blocks in sch_b.cache.prefix._entries.values():
             for blk in blocks:
-                assert sch_b._alloc.refcount(blk) == 1
+                assert sch_b.cache.alloc.refcount(blk) == 1
         # retiring the pins returns the pool to empty on both ends
-        sch_a._prefix_cache.clear()
-        sch_b._prefix_cache.clear()
-        assert sch_a._alloc.used_count == 0
-        assert sch_b._alloc.used_count == 0
+        sch_a.cache.prefix.clear()
+        sch_b.cache.prefix.clear()
+        assert sch_a.cache.alloc.used_count == 0
+        assert sch_b.cache.alloc.used_count == 0
     finally:
         a.close()
         b.close()

@@ -483,7 +483,7 @@ def test_scheduler_counts_visited_and_live_pages():
         tables = orig(extra, calls)
         if tables is not None:
             live = sum(
-                len(sched._row_blocks[b])
+                len(sched.cache.row_blocks[b])
                 for b, r in enumerate(sched._rows) if r is not None
             )
             seen.append((counters()[0] - before[0], counters()[1] - before[1],
@@ -771,7 +771,7 @@ def test_single_batch_mixes_prefill_decode_and_spec_verify():
         # de-duplicates on its exact key instead of re-pinning)
         pinned = sum(
             len(blocks)
-            for blocks in eng.scheduler._prefix_cache._entries.values()
+            for blocks in eng.scheduler.cache.prefix._entries.values()
         )
         assert st.paged_blocks_in_use == pinned
     finally:
@@ -841,7 +841,7 @@ def test_int8_batch_mixes_prefill_decode_and_spec_verify():
         # every row retired: only prefix pins (scales included) remain
         pinned = sum(
             len(blocks)
-            for blocks in eng.scheduler._prefix_cache._entries.values()
+            for blocks in eng.scheduler.cache.prefix._entries.values()
         )
         assert st.paged_blocks_in_use == pinned
     finally:

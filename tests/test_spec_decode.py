@@ -121,7 +121,7 @@ def test_greedy_parity_paged(ref_engine):
     block tables instead of the rectangular rows — same tokens out."""
     eng = InferenceEngine(
         "tiny-llama",
-        engine_config=EngineConfig(**KW, spec_tokens=6, paged=True),
+        engine_config=EngineConfig(**KW, spec_tokens=6),
     )
     try:
         r0 = ref_engine.generate(REP_PROMPT, max_new_tokens=40, temperature=0.0)
@@ -306,11 +306,11 @@ def test_paged_pool_releases_draft_blocks_after_rejection_and_retire():
     free list at retirement, and a follow-up request must reuse them."""
     eng = InferenceEngine(
         "tiny-llama",
-        engine_config=EngineConfig(**KW, spec_tokens=6, paged=True),
+        engine_config=EngineConfig(**KW, spec_tokens=6),
     )
     try:
         sch = eng.scheduler
-        free0 = sch._alloc.free_count
+        free0 = sch.cache.alloc.free_count
         r1 = eng.generate(REP_PROMPT, max_new_tokens=40, temperature=0.0)
         st = sch.stats
         assert st.spec_steps > 0
@@ -320,11 +320,11 @@ def test_paged_pool_releases_draft_blocks_after_rejection_and_retire():
         )
         # no prefix cache configured: every block the row ever claimed
         # (draft tail included) must be free again
-        assert sch._alloc.free_count == free0
+        assert sch.cache.alloc.free_count == free0
         r2 = eng.generate(REP_PROMPT, max_new_tokens=40, temperature=0.0)
-        assert sch._alloc.free_count == free0
+        assert sch.cache.alloc.free_count == free0
         assert r2.token_ids == r1.token_ids  # reused blocks, same tokens
-        assert sch._alloc.hwm <= sch._alloc.num_blocks - 1
+        assert sch.cache.alloc.hwm <= sch.cache.alloc.num_blocks - 1
     finally:
         eng.close()
 
@@ -338,19 +338,19 @@ def test_paged_spec_with_prefix_cache_pins_survive():
     eng = InferenceEngine(
         "tiny-llama",
         engine_config=EngineConfig(
-            **KW, spec_tokens=6, paged=True, prefix_cache_entries=2
+            **KW, spec_tokens=6, prefix_cache_entries=2
         ),
     )
     try:
         sch = eng.scheduler
-        free0 = sch._alloc.free_count
+        free0 = sch.cache.alloc.free_count
         eng.generate(REP_PROMPT, max_new_tokens=32, temperature=0.0)
         pinned = ceil_div(len(REP_PROMPT), eng.engine_cfg.kv_block_size)
-        assert sch._alloc.free_count == free0 - pinned
+        assert sch.cache.alloc.free_count == free0 - pinned
         # the repeat admits from the pinned prefix and still retires clean
         eng.generate(REP_PROMPT, max_new_tokens=32, temperature=0.0)
         assert sch.stats.prefix_hits >= 1
-        assert sch._alloc.free_count == free0 - pinned
+        assert sch.cache.alloc.free_count == free0 - pinned
     finally:
         eng.close()
 
