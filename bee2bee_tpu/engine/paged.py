@@ -68,6 +68,12 @@ _C_KV_PAGES_WRITTEN = get_registry().counter(
     "pages a chunk can touch x the write calls (K and V, every layer, "
     "every attention call of the dispatch); 0 on the scatter paths",
 )
+_C_KV_TILES = get_registry().counter(
+    "engine.kv_tiles",
+    "grid steps of the ragged read: one layer's call x the dispatched "
+    "window's attention calls (kind label: live = steps with a work item "
+    "| stepped = all; live / stepped = the share of the grid that does work)",
+)
 _G_STATE_ROWS = get_registry().gauge(
     "engine.state_rows",
     "row slots of recurrent state allocated (= the batch bucket; recurrent "
@@ -571,6 +577,32 @@ class RowCache:
                 rows * chunk_pages(chunk, self.block_size)
                 * calls * 2 * e.model_cfg.n_layers
             )
+
+    def count_tiles(self, tables, offsets, chunk: int, calls: int = 1):
+        """engine.kv_tiles for one dispatch of ``calls`` attention calls a
+        layer over ``tables`` [rows, tw], ``chunk`` tokens a row from
+        ``offsets``: the grid steps of ONE layer's ragged read, and those
+        with a work item — ops/ragged.work_counts, the call's own tile plan
+        and live-tile arithmetic on host integers, at the shapes one shard
+        of the pool sees (a model whose layers alternate local and global
+        attention counts a global layer). Only where the ragged kernel
+        reads."""
+        e, cfg = self.engine, self.engine.model_cfg
+        if e.engine_cfg.attention != "flash":
+            return
+        from ..ops.ragged import work_counts  # loaded with the attn_fn
+
+        k = self.pool["k"]
+        heads, _, block, head_dim = k.sharding.shard_shape(k.shape)[1:]
+        live, stepped = work_counts(
+            tables, offsets[: len(tables)],
+            0 if cfg.sliding_window_every > 1 else int(cfg.sliding_window or 0),
+            heads=heads, group=cfg.n_heads // cfg.n_kv_heads, chunk=chunk,
+            head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
+            quantized=e.kv_quantized,
+        )
+        _C_KV_TILES.inc(live * calls, kind="live")
+        _C_KV_TILES.inc(stepped * calls, kind="stepped")
 
     # ---- prefix sharing
 

@@ -125,6 +125,7 @@ _C_KV_PAGES_LIVE = _REG.counter(
 )
 
 
+
 def _phase(name: str):
     """Run a BatchScheduler method as loop phase `name` (tracing.PhaseClock:
     a `sched.<name>` annotation on the profiler's host plane + exclusive
@@ -1583,6 +1584,7 @@ class BatchScheduler:
         _C_KV_PAGES_LIVE.inc(live * calls)
         # extra / calls = the tokens a call writes: 1 a decode step, K+1
         self.cache.count_pages_written(self._bsz, extra // calls, calls)
+        self.cache.count_tiles(tables, self._offsets, extra // calls, calls)
         return tables
 
     def _spec_eligible(self, b: int, req: Request) -> bool:

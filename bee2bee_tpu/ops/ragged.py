@@ -14,21 +14,39 @@ the pool:
   a prefetched scalar — "Layouts" below). One grid step carries ``Th`` KV heads x
   ``Tp`` consecutive table entries of one row: the pool is passed as
   ``Tp`` K and ``Tp`` V operands, operand p blocked ``(Th, 1, BS, hd)``
-  with the scalar-prefetched block-table lookup in its index_map —
-  ``(h, tables[b, tile*Tp + p], 0, 0)`` — so each is one strided copy of
+  with a scalar-prefetched pool block id in its index_map —
+  ``(h, pages[step*Tp + p], 0, 0)`` — so each is one strided copy of
   Th heads of one pool block, the pipeline double-buffers all of them
-  and fetches the next step's tile while this one computes. The grid is
-  ``(B, Hkv/Th, q blocks, table width/Tp)``: phi-3-mini's decode step is
-  16 x 1 x 1 x 4 steps a layer where one page of one head a step made it
-  16 x 32 x 1 x 32. No gathered view, no [T, S] score materialization.
+  and fetches the next step's tile while this one computes. No gathered
+  view, no [T, S] score materialization.
   Every tensor operand's trailing block dims are ``(rows, hd)`` —
   Mosaic-tileable (the [NB, BS, Hkv, hd] layout would put a 1-blocked
   head axis second-to-last and fail to lower, and a bool-mask operand
   blocked per 16-lane page would violate the same rule — the constraint
   that shaped ops/flash.py's head-major layout). The copies stay
-  BlockSpec copies, not hand-started DMAs from an ``ANY`` operand: Mosaic
-  refuses to slice an HBM ref whose head size is off the 128-lane tiling
-  (phi-3's 96, gpt2's 64), and takes those as block shapes.
+  BlockSpec copies, not hand-started DMAs from an ``ANY`` operand: one
+  kernel then serves every pool (Mosaic refuses to slice an HBM ref whose
+  head size is off the 128-lane tiling — phi-3's 96, gpt2's 64 as the
+  dense readers' and the int8 pool's slices keep them — and takes those
+  as block shapes).
+- **The grid walks a compacted work list** (PR 31), not the table: the
+  grid is ``(Hkv/Th, B x q blocks x table width/Tp)`` — head groups,
+  then ONE sequential axis of the table's static length (so the compile
+  space stays one program per (T, table width)). Before the call,
+  _work_list builds from the scalars the kernel prefetches anyway (the
+  tables, the offsets, the window; a handful of XLA ops on [B]- and
+  [steps]-sized int32, which the compiler hoists out of the layer loop
+  where the window is one constant) the list of LIVE items ``(row, q
+  block, tile)`` in row order — phi-3-mini's short decode step has ~21
+  of 64, falcon-h1's wide one ~80 of 128 — with each item's ``Tp`` pool
+  block ids and a flag word (work, first / last item of its (row, q
+  block): where the f32 softmax state is reset and the output block
+  written). Step s < n_live does item s; the steps past the end repeat the
+  last item's indices with no flag and all sit at the END of the grid. So
+  consecutive steps are live items: the next row's first tile is in
+  flight while this row's last computes (on the rectangular (row, tile)
+  grid the dead steps TRAILED every row, and a row's first tile was
+  waited for in full behind them), and an index map is one SMEM load.
 - **The tile follows from the shapes** (_tile_plan: heads a shard holds,
   group size, chunk length, head size, page size, table width, dtypes,
   against a fixed VMEM budget), never from an argument, a flag or a
@@ -51,15 +69,17 @@ the pool:
 - **Live tiles only**: a row's live extent is known from the same
   scalars (_live_tiles: the tiles between the window's start and the
   causal frontier of THIS q block). A tile outside it — the pow2,
-  batch-wide table's padding, a retired row, the pages a window has
-  left behind — computes nothing and is not copied either: its step's
-  index maps name the tile the neighbouring step named (_fetched_tile),
-  and the pipeline copies a block only when its index changes. So both
-  the compute and the cache traffic of a step follow the row's live
-  pages rounded up to a tile, while the table (and with it the compile
-  space, one program per (T, table width)) stays as it was. Dead
-  entries INSIDE a live tile still copy the null block. ALiBi stays
-  dense-only (the bias needs absolute key positions per head; the
+  batch-wide table's padding, the pages a window has left behind, the
+  upper triangle of a prefill chunk's q blocks — is no item of the work
+  list, and neither is any tile of a row that maps no page (a retired row
+  of the sticky batch keeps its stale offset; its table is the null
+  block): no grid step between live ones, no copy, no compute. A (row, q
+  block) without an item is never visited, so its output block is zeroed
+  after the call (fused into the cut of the pad rows). Both the compute
+  and the cache traffic follow the row's live pages rounded up to a
+  tile, while the table (and with it the compile space) stays as it
+  was. Dead entries INSIDE a live tile still copy the null block. ALiBi
+  stays dense-only (the bias needs absolute key positions per head; the
   engine validates).
 - **Online softmax** over the tile iterations with f32 m/l/acc VMEM
   scratch (a leading Th), f32 MXU accumulation, storage dtype out —
@@ -203,40 +223,137 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
     return Th, Tp, bq
 
 
-def _live_tiles(off, win, i, *, chunk, block_q, tile_tokens, n_tiles):
+def _live_tiles(off, win, i, *, chunk, block_q, tile_tokens, n_tiles, xp=jnp):
     """[lo, hi): the page tiles of one row that hold a key some query row
     of q block ``i`` can see. Visible keys are the positions
     (qlo - win, qhi] (from 0 when no window binds), qlo/qhi the block's
     first/last query position: a q block that is a run of one chunk
     (block_q divides T) has its own, any other spans the chunk. A tile
     outside [lo, hi) is wholly past the causal frontier or wholly below
-    the window."""
+    the window. ``xp`` is the array module: jnp for the call's traced
+    scalars, numpy for the scheduler's host integers (work_counts)."""
     if chunk % block_q == 0:
         qlo = off + (i * block_q) % chunk
         qhi = qlo + block_q - 1
     else:
         qlo, qhi = off, off + chunk - 1
-    hi = jnp.minimum(qhi // tile_tokens + 1, n_tiles)
-    lo = jnp.where(win > 0, jnp.maximum(qlo - win + 1, 0) // tile_tokens, 0)
+    hi = xp.minimum(qhi // tile_tokens + 1, n_tiles)
+    lo = xp.where(win > 0, xp.maximum(qlo - win + 1, 0) // tile_tokens, 0)
     return lo, hi
 
 
-def _fetched_tile(j, lo, hi, n_tiles):
-    """The tile whose pages grid step ``j`` of a row names in its K/V
-    index maps: ``j`` itself while live, else the nearest live tile — the
-    one the previous step (or the next, below a window) names too. The
-    pipeline copies a block only when its index changes between
-    consecutive steps, so a dead tile starts NO copy."""
-    return jnp.clip(jnp.clip(j, lo, hi - 1), 0, n_tiles - 1)
+def _item_counts(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
+                 block_size, xp=jnp):
+    """(lo, n), both [B, q blocks]: the first live tile of every (row, q
+    block) and how many work items it contributes — its live tiles
+    (_live_tiles), or none at all for a row that maps no page: the table
+    entry of its first visible key is the null block (a retired row of the
+    sticky batch keeps its offset, its table is nulled)."""
+    width = tables.shape[1]
+    lo, hi = _live_tiles(
+        off[:, None], win, xp.arange(n_qblocks, dtype=xp.int32)[None, :],
+        chunk=chunk, block_q=block_q, tile_tokens=tile_pages * block_size,
+        n_tiles=width // tile_pages, xp=xp,
+    )
+    first = xp.where(win > 0, xp.maximum(off - win + 1, 0), 0) // block_size
+    mapped = xp.take_along_axis(
+        tables, xp.minimum(first, width - 1)[:, None], axis=1
+    ) != 0
+    # (a q block that spans the chunk has no `i` in its range: broadcast)
+    shape = (tables.shape[0], n_qblocks)
+    n = xp.where(mapped, xp.maximum(hi - lo, 0), 0)
+    return xp.broadcast_to(lo, shape), xp.broadcast_to(n, shape)
+
+
+_WORK, _FIRST, _LAST = 1, 2, 4  # bits of a work item's flags
+
+
+def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
+               block_size):
+    """((seg, tile, flags, pages), visited): the call's compacted work
+    list, one entry a step of the sequential grid axis — three [S] int32
+    with S = B x q blocks x tiles and ``pages`` [S * Tp], the Tp pool blocks
+    of the step's tile, in step order; ``seg`` is ``row * q blocks + q
+    block`` — and ``visited`` [B, q blocks] bool: the (row, q block)s that
+    have an item at all. The live items (row, q block, tile) come first,
+    rows ascending, then q blocks, then tiles ascending — the online
+    softmax's order; ``flags`` marks an item as work and as the first / last
+    of its (row, q block). The steps past the last item repeat ITS seg, tile
+    and pages with no flag: no block index changes there, so the pipeline
+    starts no copy, and the body computes nothing. Built from what the
+    kernel prefetches anyway (tables, offsets, the window).
+
+    Inside a layer loop the list is loop-invariant wherever the window is
+    one constant, and the TPU compiler hoists it — all but an operand of
+    the kernel whose LAST op is a cheap elementwise one, which it
+    recomputes in every layer (~2 us each, my chip runs, PR 31). So ``seg``
+    and ``visited`` come straight out of reductions."""
+    B, width = tables.shape
+    Tp, n_tiles = tile_pages, width // tile_pages
+    lo, n = _item_counts(
+        tables, off, win, chunk=chunk, block_q=block_q, n_qblocks=n_qblocks,
+        tile_pages=Tp, block_size=block_size,
+    )
+    lo, n = lo.reshape(-1), n.reshape(-1)  # (row, q block) major
+    ends = jnp.cumsum(n)
+    n_live = ends[-1]
+    step = jnp.arange(B * n_qblocks * n_tiles, dtype=jnp.int32)
+    item = jnp.minimum(step, n_live - 1)  # the tail repeats the last item
+    # the (row, q block) an item belongs to: the first whose end is past
+    # it (item < n_live = ends[-1], so there is one)
+    seg = jnp.sum(ends[None, :] <= item[:, None], axis=1, dtype=jnp.int32)
+    lo_, n_, end_ = (x[seg] for x in (lo, n, ends))
+    k = item - (end_ - n_)  # the item's place among its (row, q block)'s
+    tile = jnp.clip(lo_ + k, 0, n_tiles - 1).astype(jnp.int32)
+    flags = jnp.where(
+        step < n_live, _WORK + _FIRST * (k == 0) + _LAST * (k == n_ - 1), 0
+    ).astype(jnp.int32)
+    pages = tables.reshape(B, n_tiles, Tp)[seg // n_qblocks, tile].reshape(-1)
+    visited = jnp.any(
+        (seg[None, :] == jnp.arange(B * n_qblocks)[:, None]) & (flags[None, :] != 0),
+        axis=1,
+    )
+    return (seg, tile, flags, pages), visited.reshape(B, n_qblocks)
+
+
+def work_counts(tables, offsets, window, *, heads, group, chunk, head_dim,
+                block_size, itemsize, quantized=False):
+    """(live, stepped) of ONE layer's call on host integers: the work items
+    the read's grid does and the grid steps it takes, head groups included —
+    the same _tile_plan and _live_tiles arithmetic the call runs on the
+    device, on numpy ``tables`` [B, MB] and ``offsets`` [B]. ``heads`` is
+    the KV heads a shard holds, ``head_dim`` the pool's. For the
+    scheduler's engine.kv_tiles counter."""
+    import numpy as np
+
+    tables = np.asarray(tables, np.int32)
+    B, MB = tables.shape
+    Th, Tp, bq = _tile_plan(
+        heads, group, chunk, head_dim, block_size, MB, itemsize, quantized
+    )
+    if MB % Tp:
+        tables = np.pad(tables, ((0, 0), (0, -MB % Tp)))
+    n_qblocks = _round_up(group * chunk, bq) // bq
+    _, n = _item_counts(
+        tables, np.asarray(offsets, np.int32), np.int32(window), chunk=chunk,
+        block_q=bq, n_qblocks=n_qblocks, tile_pages=Tp, block_size=block_size,
+        xp=np,
+    )
+    groups = heads // Th
+    return groups * int(n.sum()), groups * B * n_qblocks * (tables.shape[1] // Tp)
 
 
 def _ragged_kernel(
-    tables_ref,  # SMEM [B, MBp] int32 (scalar-prefetch): per-row block tables
-    #              (the K/V index maps read them; the body does not)
-    off_ref,  # SMEM [B] int32 (scalar-prefetch): position of q[:, 0]
-    win_ref,  # SMEM [1] int32 (scalar-prefetch): sliding window (0 = none)
-    lay_ref,  # SMEM [1] int32 (scalar-prefetch): the stacked pool's layer
-    #           (the K/V index maps read it; 0 and unread for a 4-D slice)
+    # scalar-prefetch (SMEM int32). The work list, one entry a grid step
+    # (_work_list); the index maps read pages and seg, the body the rest:
+    seg_ref,  # [S] the step's batch row * q blocks + its q block
+    tile_ref,  # [S] its page tile
+    flag_ref,  # [S] _WORK | _FIRST | _LAST (0: a step past the last item)
+    pages_ref,  # [S * Tp] the pool block of entry p of step s's tile
+    off_ref,  # [B] position of q[:, 0]
+    win_ref,  # [1] sliding window (0 = none)
+    lay_ref,  # [1] the stacked pool's layer (the K/V index maps read it;
+    #           0 and unread for a 4-D slice)
     *refs,
     # quantized=True prepends two more scalar-prefetch refs:
     #   kscale_ref, vscale_ref  SMEM [Hkv, B, MBp] f32 scales, pre-gathered
@@ -256,6 +373,7 @@ def _ragged_kernel(
     block_size: int,
     block_q: int,
     chunk: int,  # T: query positions per row (row r is chunk position r % T)
+    n_qblocks: int,
     tile_heads: int,
     tile_pages: int,
     quantized: bool = False,
@@ -267,33 +385,29 @@ def _ragged_kernel(
     k_refs, v_refs = refs[:Tp], refs[Tp:2 * Tp]
     o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[2 * Tp:]
     tile_tokens = Tp * BS
-    b = pl.program_id(0)
-    h0 = pl.program_id(1) * Th
-    i = pl.program_id(2)
-    j = pl.program_id(3)
+    h0 = pl.program_id(0) * Th
+    step = pl.program_id(1)
+    flags = flag_ref[step]
 
-    @pl.when(j == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    off = off_ref[b]
-    win = win_ref[0]
-    lo, hi = _live_tiles(
-        off, win, i, chunk=chunk, block_q=block_q, tile_tokens=tile_tokens,
-        n_tiles=pl.num_programs(3),
-    )
-
-    # a dead tile was not copied (_fetched_tile) and computes nothing
-    @pl.when((lo <= j) & (j < hi))
+    # a step past the last item copied nothing and computes nothing
+    @pl.when(flags & _WORK != 0)
     def _attend():
+        seg, j = seg_ref[step], tile_ref[step]
+        b, i = seg // n_qblocks, seg % n_qblocks
+        off = off_ref[b]
+        win = win_ref[0]
         q = q_ref[0]  # [Th, BQ, hd]
         if quantized:
             # every key/value row of a page shares ONE scale per kv head:
             # the wrapper pre-gathered the per-page scales through the
-            # block tables to [Hkv, B, MBp], so the grid coordinates index
-            # them directly, and nothing wider than the tile dequantizes
+            # block tables to [Hkv, B, MBp], so the item's row and tile
+            # index them directly, and nothing wider than the tile dequantizes
             kdq_ref, vdq_ref = dq_refs
 
             def dequant(h, _):
@@ -347,11 +461,11 @@ def _ragged_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(flags & _LAST != 0)
     def _finalize():
-        # l == 0 only for rows with nothing visible (no live tile — can't
-        # happen for live rows, but a dead batch row's stale offset may
-        # land there): emit 0, not 0/0 = NaN
+        # l == 0 only for q rows with nothing visible (the pad rows of a q
+        # block past G*T never are: they alias chunk positions): emit 0,
+        # not 0/0 = NaN
         l = l_ref[...][:, :, :1]
         o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
@@ -411,6 +525,7 @@ def ragged_paged_attention(
         Hkv, G, T, hd, BS, MB, q.dtype.itemsize, quantized, block_q
     )
     nqp = _round_up(nq, bq)
+    n_qblocks = nqp // bq
     # [B, T, H, hd] -> [B, Hkv, G*T, hd]: head h = kvh*G + g attends kv
     # head kvh = h // G, so heads of one group are contiguous rows
     qT = q.reshape(B, T, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(B, Hkv, nq, hd)
@@ -430,6 +545,10 @@ def ragged_paged_attention(
     )
     win = jnp.asarray(window if window is not None else 0, jnp.int32).reshape(-1)[:1]
     lay = jnp.asarray(layer if stacked else 0, jnp.int32).reshape(-1)[:1]
+    work, visited = _work_list(
+        tables, off, win[0], chunk=T, block_q=bq, n_qblocks=n_qblocks,
+        tile_pages=Tp, block_size=BS,
+    )
 
     kernel = functools.partial(
         _ragged_kernel,
@@ -438,35 +557,35 @@ def ragged_paged_attention(
         block_size=BS,
         block_q=bq,
         chunk=T,
+        n_qblocks=n_qblocks,
         tile_heads=Th,
         tile_pages=Tp,
         quantized=quantized,
     )
 
-    # index maps take the scalar-prefetch refs as trailing args (4 of
-    # them, or 6 with the quantization scales — the variadic tail keeps
-    # one lambda serving both). The K/V maps ARE the gather: entry p of
-    # the step's tile reads Th heads of pool block tables[b, tile*Tp + p]
-    # (of layer lay[0] when the pool is stacked), and a dead step names
-    # the tile its neighbour named (_fetched_tile), which the pipeline
-    # then does not copy again
+    # index maps take the grid indices (head group, step) and the
+    # scalar-prefetch refs as trailing args (7 of them, or 9 with the
+    # quantization scales — the variadic tail keeps one lambda serving
+    # both). The K/V maps ARE the gather: entry p of the step's tile reads
+    # Th heads of pool block pages[s*Tp + p] (of layer lay[0] when the pool
+    # is stacked) — one SMEM load a map; a step whose pages are the step
+    # before's (the tail) starts no copy
     def page_map(p):
-        def index(b, h, i, j, tb, off_, win_, lay_, *_):
-            lo, hi = _live_tiles(
-                off_[b], win_[0], i, chunk=T, block_q=bq,
-                tile_tokens=Tp * BS, n_tiles=n_tiles,
-            )
-            page = (h, tb[b, _fetched_tile(j, lo, hi, n_tiles) * Tp + p], 0, 0)
+        def index(h, s, seg_, tile_, flag_, pages_, off_, win_, lay_, *_):
+            page = (h, pages_[s * Tp + p], 0, 0)
             return (lay_[0], *page) if stacked else page
 
         return index
 
-    qo_spec = pl.BlockSpec((1, Th, bq, hd), lambda b, h, i, j, *_: (b, h, i, 0))
+    qo_spec = pl.BlockSpec(
+        (1, Th, bq, hd),
+        lambda h, s, seg_, *_: (seg_[s] // n_qblocks, h, seg_[s] % n_qblocks, 0),
+    )
     page_block = (None,) * stacked + (Th, 1, BS, hd)
     page_specs = [pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6 if quantized else 4,
-        grid=(B, Hkv // Th, nqp // bq, n_tiles),
+        num_scalar_prefetch=9 if quantized else 7,
+        grid=(Hkv // Th, B * n_qblocks * n_tiles),
         in_specs=[qo_spec] + page_specs + page_specs,
         out_specs=qo_spec,
         scratch_shapes=[
@@ -495,11 +614,18 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, nqp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            # the steps walk the work list in order (a row's softmax state
+            # lives across them); the head groups are independent
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(tables, off, win, lay, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
+    )(*work, off, win, lay, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
+    # a (row, q block) with no item was never visited: its block of `out`
+    # holds whatever the buffer held. Zero it (fused into the cut below)
+    out = jnp.where(
+        jnp.repeat(visited, bq, axis=1)[:, None, :, None], out, 0
+    )
     # [B, Hkv, nqp, hd] -> [B, T, H*hd]
     out = out[:, :, :nq, :hd_q].reshape(B, Hkv, G, T, hd_q).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, T, H * hd_q)

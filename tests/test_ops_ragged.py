@@ -312,6 +312,43 @@ TILE_CASES = {
     "gqa2x4-hd128-mb128-window-under-jit": dict(
         Hkv=2, G=4, hd=128, MB=128, T=1, offs=[1900, 1500, 255], window=300,
         jit=True),
+    # the work list's edges (PR 31): the grid walks a compacted list of live
+    # (row, q block, tile) items, so what matters is where a row's items
+    # start and end in it. Retired rows of the sticky batch (table nulled,
+    # offset stale) between live ones contribute nothing
+    "mha32-hd96-mb32-retired-rows-stale-offsets": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 300, 40, 9, 500, 130],
+        dead=[1, 4]),
+    "gqa2x4-hd128-mb32-first-and-last-row-retired": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=1, offs=[400, 300, 17, 511], dead=[0, 3]),
+    # every row's init, work and finalize fall on ONE step (tiles of 256 / 128)
+    "gqa2x4-hd128-mb32-rows-of-exactly-one-tile": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=1, offs=[255, 0, 100, 17]),
+    "mha32-hd96-mb32-rows-of-exactly-one-tile": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[127, 0, 64]),
+    "mha32-hd96-mb32-every-row-dead": dict(
+        Hkv=32, G=1, hd=96, MB=32, T=1, offs=[200, 127, 40], dead=[0, 1, 2]),
+    # the list of a windowed row starts at its first live tile, not at 0
+    "gqa2x4-hd128-mb128-window-retired-row-t5": dict(
+        Hkv=2, G=4, hd=128, MB=128, T=5, offs=[1900, 1200, 700, 300],
+        window=260, dead=[1]),
+    # several q blocks a row, each with its own live range (the skipped
+    # upper triangle), a row starting mid-table and a retired one
+    "mha8-hd96-prefill-t512-two-q-blocks": dict(
+        Hkv=8, G=1, hd=96, MB=64, T=512, offs=[0, 300, 77], dead=[2]),
+    "gqa2x4-hd128-prefill-t256-four-q-blocks-window": dict(
+        Hkv=2, G=4, hd=128, MB=64, T=256, offs=[700, 0], window=300),
+    "gqa8x4-hd96-spec-verify-t5-retired-rows": dict(
+        Hkv=8, G=4, hd=96, MB=32, T=5, offs=[123, 124, 250, 300, 11],
+        dead=[0, 3]),
+    "gqa2x4-hd128-int8-retired-and-one-tile-rows": dict(
+        Hkv=2, G=4, hd=128, MB=32, T=1, offs=[255, 300, 17, 500], dead=[1],
+        int8=True),
+    # a table the tile does not divide: the wrapper pads it with null entries
+    "gqa2x4-hd128-mb20-tile-does-not-divide": dict(
+        Hkv=2, G=4, hd=128, MB=20, T=1, offs=[300, 17, 250], dead=[1]),
+    "gqa2x4-hd128-mb20-tile-does-not-divide-t5-int8": dict(
+        Hkv=2, G=4, hd=128, MB=20, T=5, offs=[300, 17, 250], int8=True),
 }
 
 
@@ -360,6 +397,8 @@ def test_ragged_tiles_match_dense(case):
     out = run(q, kp, vp, tb, off, jnp.full((1,), window, jnp.int32))
     assert out.shape == (len(offs), T, Hkv * G * hd) and out.dtype == dtype
     assert np.isfinite(np.asarray(out, np.float32)).all()
+    # a retired row contributes no work item: its output block is zeroed
+    assert not np.asarray(out, np.float32)[list(c.get("dead", ()))].any()
     want = _dense_ref(
         q.astype(jnp.float32), kg.astype(jnp.float32), vg.astype(jnp.float32),
         mask, cfg,
@@ -416,43 +455,69 @@ def test_tile_plan_follows_shapes_within_vmem_budget():
 
 
 def test_dead_tile_starts_no_copy():
-    """The K/V index maps name, for a dead grid step, the tile a
-    neighbouring step names (the pipeline copies a block only when its
-    index changes): over every step of a row the distinct tiles fetched
-    are exactly the tiles with a visible key, brute-forced from the
-    visibility rule itself."""
-    from bee2bee_tpu.ops.ragged import _fetched_tile, _live_tiles
+    """The grid walks a compacted work list (_work_list): the steps flagged
+    as work are exactly the live tiles - the tiles with a key some query of
+    the q block can see, brute-forced from the visibility rule itself - of
+    the rows that map a page, in (row, q block, tile) order, the first and
+    the last of every (row, q block) flagged; every later step repeats the
+    last item's row, q block, tile AND pages with no flag, so no block index
+    changes there and the pipeline starts no copy."""
+    from bee2bee_tpu.ops.ragged import _FIRST, _LAST, _WORK, _work_list
 
     BS, Tp, n_tiles = 16, 8, 16
-    tt = BS * Tp
+    tt, width = BS * Tp, Tp * n_tiles
+    rng = np.random.default_rng(0)
+    offs_all = (0, 1, 127, 128, 129, 1000, 1500, 5000)
     for chunk, block_q in ((1, 8), (5, 20), (256, 256), (512, 256), (64, 256)):
+        n_qblocks = max(1, chunk // block_q)
         for win in (0, 1, 100, 300, 5000):
-            for off in (0, 1, 127, 128, 129, 1000, 1500, 2047 - chunk, 5000):
-                for i in range(max(1, chunk // block_q)):
-                    lo, hi = (int(x) for x in _live_tiles(
-                        off, win, i, chunk=chunk, block_q=block_q,
-                        tile_tokens=tt, n_tiles=n_tiles,
-                    ))
+            offs = np.array(offs_all + (2047 - chunk,), np.int32)
+            B = len(offs)
+            tables = rng.integers(1, 900, size=(B, width)).astype(np.int32)
+            retired = [2, B - 1] if win != 100 else list(range(B))
+            tables[retired] = 0  # nulled table, stale offset
+            work, visited = _work_list(
+                jnp.asarray(tables), jnp.asarray(offs), jnp.int32(win),
+                chunk=chunk, block_q=block_q, n_qblocks=n_qblocks,
+                tile_pages=Tp, block_size=BS,
+            )
+            seg, tile, flags, pages = (np.asarray(x) for x in work)
+            row, qblk = seg // n_qblocks, seg % n_qblocks
+            assert len(row) == B * n_qblocks * n_tiles  # the grid's static length
+            want = []  # (row, q block, tile, flags) of every item, in order
+            kv = np.arange(n_tiles * tt)
+            for b in range(B):
+                if b in retired:
+                    continue
+                for i in range(n_qblocks):
                     rows = np.arange(i * block_q, (i + 1) * block_q)
-                    qpos = off + rows % chunk
-                    kv = np.arange(n_tiles * tt)
+                    qpos = offs[b] + rows % chunk
                     vis = kv[None, :] <= qpos[:, None]
                     if win > 0:
                         vis &= kv[None, :] > qpos[:, None] - win
-                    want = sorted(set((kv[vis.any(axis=0)] // tt).tolist()))
-                    assert list(range(lo, hi)) == want, (chunk, win, off, i)
-                    steps = [
-                        int(_fetched_tile(j, lo, hi, n_tiles))
-                        for j in range(n_tiles)
+                    tiles = sorted(set((kv[vis.any(axis=0)] // tt).tolist()))
+                    want += [
+                        (b, i, t, _WORK | _FIRST * (t == tiles[0])
+                         | _LAST * (t == tiles[-1]))
+                        for t in tiles
                     ]
-                    assert all(0 <= t < n_tiles for t in steps)
-                    # changes of index = copies started (the first step's
-                    # block is always fetched: one tile even for a row
-                    # with nothing visible)
-                    copies = 1 + sum(a != b for a, b in zip(steps, steps[1:]))
-                    assert copies == max(len(want), 1), (chunk, win, off, i, steps)
-                    if want:
-                        assert sorted(set(steps)) == want
+            n = len(want)
+            got = list(zip(row.tolist(), qblk.tolist(), tile.tolist(), flags.tolist()))
+            assert got[:n] == want, (chunk, win)
+            last = want[-1][:3] if want else got[0][:3]
+            assert all(g == (*last, 0) for g in got[n:]), (chunk, win)
+            assert (0 <= tile).all() and (tile < n_tiles).all()
+            # the pages a step names are its tile's table entries; in the
+            # tail they are the last item's, so no index changes
+            pages = pages.reshape(-1, Tp)
+            np.testing.assert_array_equal(
+                pages, tables.reshape(B, n_tiles, Tp)[row, tile])
+            assert (pages[max(n, 1):] == pages[max(n, 1) - 1]).all()
+            # and the (row, q block)s with an item are the ones written
+            seen = np.zeros((B, n_qblocks), bool)
+            for b, i, _, _ in want:
+                seen[b, i] = True
+            np.testing.assert_array_equal(np.asarray(visited), seen)
 
 
 def test_scheduler_counts_visited_and_live_pages():
@@ -503,6 +568,69 @@ def test_scheduler_counts_visited_and_live_pages():
     v1, l1 = counters()
     assert v1 - v0 == sum(s[2] for s in seen)
     assert l1 - l0 == sum(s[3] for s in seen)
+
+
+def test_scheduler_counts_live_and_stepped_tiles():
+    """engine.kv_tiles{kind}: once a dispatched window, the read's grid
+    steps of one layer's call x the window's calls - all of them
+    (``stepped``: head groups x rows x q blocks x tiles of the tile plan)
+    and those with a work item (``live``: the rows' live tiles), by the
+    call's own arithmetic (ops/ragged.work_counts)."""
+    from bee2bee_tpu.engine import EngineConfig, InferenceEngine
+    from bee2bee_tpu.metrics import get_registry
+    from bee2bee_tpu.ops.ragged import _tile_plan, work_counts
+
+    tiles = get_registry().counter("engine.kv_tiles")
+
+    def counters():
+        return tiles.value(kind="live"), tiles.value(kind="stepped")
+
+    BS = 8
+    eng = InferenceEngine("tiny-llama", engine_config=EngineConfig(
+        max_seq_len=128, max_batch=4, decode_chunk=4, kv_block_size=BS,
+        attention="flash",
+    ))
+    cfg, sched = eng.model_cfg, eng.scheduler
+    seen = []
+    orig = sched._prepare_window_tables
+
+    def spy(extra, calls):
+        before = counters()
+        tables = orig(extra, calls)
+        if tables is not None:
+            B, MB = tables.shape
+            Th, Tp, _ = _tile_plan(
+                cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, 1, cfg.head_dim,
+                BS, MB, eng.dtype.itemsize, False)
+            tt = Tp * BS
+            # a decode row at offset o sees keys 0..o: tiles 0..o // tt
+            live = sum(
+                min(int(sched._offsets[b]) // tt + 1, -(-MB // Tp))
+                for b, r in enumerate(sched._rows) if r is not None
+            )
+            groups = cfg.n_kv_heads // Th
+            after = counters()
+            seen.append((after[0] - before[0], after[1] - before[1],
+                         groups * live * calls, groups * B * -(-MB // Tp) * calls))
+        return tables
+
+    sched._prepare_window_tables = spy
+    try:
+        eng.generate(list(range(3, 40)), max_new_tokens=12, temperature=0.0)
+    finally:
+        eng.close()
+    assert seen, "no decode window was dispatched"
+    for d_live, d_stepped, want_live, want_stepped in seen:
+        assert (d_live, d_stepped) == (want_live, want_stepped)
+        assert 0 < d_live <= d_stepped
+    # the same arithmetic, where rows are retired or windowed
+    tables = np.zeros((4, 32), np.int32)
+    tables[0, :13], tables[2, :3] = 1, 2  # rows 1 and 3 map no page
+    kw = dict(heads=32, group=1, chunk=1, head_dim=128, block_size=16, itemsize=2)
+    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (2 + 1, 4 * 4)
+    assert work_counts(tables, [200, 999, 40, 9], 64, **kw) == (1 + 1, 4 * 4)
+    tables[:] = 0
+    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (0, 4 * 4)
 
 
 # ------------------------------------- the pool written and read in place

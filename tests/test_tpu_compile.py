@@ -75,20 +75,28 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _ragged_args(sharding, *, B, T, H, Hkv, hd, MB, pool_dtype=jnp.bfloat16):
+def _ragged_args(sharding, *, B, T, H, Hkv, hd, MB, pool_dtype=jnp.bfloat16,
+                 layers=0):
+    """(q, K pool, V pool, tables, offsets): the pool one layer's 4-D slice,
+    or with ``layers`` the stacked pool as the engine stores it on a TPU
+    (lane-aligned: core.init_paged_pool)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    pool = (Hkv, NB, BS, hd) if not layers else (layers, Hkv, NB, BS, -(-hd // 128) * 128)
     return (
         sds((B, T, H, hd), jnp.bfloat16),
-        sds((Hkv, NB, BS, hd), pool_dtype),
-        sds((Hkv, NB, BS, hd), pool_dtype),
+        sds(pool, pool_dtype),
+        sds(pool, pool_dtype),
         sds((B, MB), jnp.int32),
         sds((B,), jnp.int32),
     )
 
 
-def _heads(model: str) -> dict:
+def _heads(model) -> dict:
+    """A model's head layout, or the dict itself (one shard's share)."""
+    if isinstance(model, dict):
+        return model
     cfg = get_config(model)
     return {"H": cfg.n_heads, "Hkv": cfg.n_kv_heads, "hd": cfg.head_dim}
 
@@ -111,25 +119,43 @@ RAGGED_CASES = {
     "phi-3-mini-decode": ("phi-3-mini", dict(B=16, T=1, MB=32)),
     "phi-3-mini-decode-long": ("phi-3-mini", dict(B=4, T=1, MB=128)),
     "phi-3-mini-prefill-2048": ("phi-3-mini", dict(B=1, T=2048, MB=128)),
+    # the rest of chip_smoke.KERNEL_CASES (PR 31: every shape the chip times
+    # compiles here first): one shard of mistral-7b under model:4 behind its
+    # sliding window, falcon-h1's GQA 20/4 at 64 rows; then phi-3's spec
+    # verify, and the benchmark's decode shapes on the STACKED lane-aligned
+    # pool with a traced layer, as core.forward issues them on a TPU
+    "mistral-7b-shard-decode-window": (
+        dict(H=8, Hkv=2, hd=128), dict(B=32, T=1, MB=64, window=4096)),
+    "falcon-h1-decode": ("falcon-h1-34b", dict(B=64, T=1, MB=32)),
+    "phi-3-mini-spec-verify-k6": ("phi-3-mini", dict(B=16, T=7, MB=32)),
+    "phi-3-mini-decode-stacked": ("phi-3-mini", dict(B=16, T=1, MB=32, layers=2)),
+    "phi-3-mini-prefill-2048-stacked": (
+        "phi-3-mini", dict(B=1, T=2048, MB=128, layers=2)),
+    "falcon-h1-decode-stacked": ("falcon-h1-34b", dict(B=64, T=1, MB=32, layers=2)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(RAGGED_CASES))
 def test_ragged_kernel_compiles_for_v5e(one_chip, case):
     model, shape = RAGGED_CASES[case]
+    shape = dict(shape)
+    window = shape.pop("window", None)
+    layer = jnp.int32(1) if shape.get("layers") else None
     text = _compiled_text(
-        lambda q, k, v, t, o: ragged_paged_attention(q, k, v, t, o, interpret=False),
+        lambda q, k, v, t, o: ragged_paged_attention(
+            q, k, v, t, o, window=window, interpret=False, layer=layer),
         *_ragged_args(one_chip, **_heads(model), **shape),
     )
     assert "tpu_custom_call" in text
 
 
-def test_ragged_kernel_int8_pool_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("model,B,MB", [("gemma-2b", 8, 8), ("phi-3-mini", 16, 32)])
+def test_ragged_kernel_int8_pool_compiles_for_v5e(one_chip, model, B, MB):
     """The quantized pool variant: int8 pages + per-page-per-head f32
     scales riding the scalar-prefetch channel."""
-    h = _heads("gemma-2b")
+    h = _heads(model)
     q, k, v, t, o = _ragged_args(
-        one_chip, **h, B=8, T=1, MB=8, pool_dtype=jnp.int8
+        one_chip, **h, B=B, T=1, MB=MB, pool_dtype=jnp.int8
     )
     scale = jax.ShapeDtypeStruct((h["Hkv"], NB), jnp.float32, sharding=one_chip)
     text = _compiled_text(
@@ -157,14 +183,18 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo):
+@pytest.mark.parametrize(
+    "shape", [(1, 1, 1, 4), (2, 1, 1, 2)], ids=["model-4", "data-2-model-2"])
+def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo, shape):
     """Tensor-parallel serving: the attn_fn the engine builds for a
     model:4 mesh runs the kernel per shard (q heads and the pool's kv
-    heads over `model`: zephyr-7b's 8 kv heads are 2 a chip). The mesh is
-    the four DESCRIBED devices, so interpret mode resolves off from the
-    mesh itself — the same rule the engine follows on the chip."""
+    heads over `model`: zephyr-7b's 8 kv heads are 2 a chip); on 2x2 the
+    rows split over `data` too, and each shard builds its work list from
+    its own rows. The mesh is the four DESCRIBED devices, so interpret mode
+    resolves off from the mesh itself — the same rule the engine follows
+    on the chip."""
     cfg = get_config("zephyr-7b")
-    mesh = Mesh(np.array(topo.devices, dtype=object).reshape(1, 1, 1, 4), AXES)
+    mesh = Mesh(np.array(topo.devices, dtype=object).reshape(shape), AXES)
     attn = make_ragged_attn_fn(mesh)
 
     def sds(shape, dtype, spec):
