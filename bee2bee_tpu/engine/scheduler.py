@@ -145,6 +145,11 @@ _C_SSM_STEP_ROWS = _REG.counter(
     "one-step recurrent updates dispatched: rows x decode steps x layers "
     "(kind label: live | dead rows of the batch bucket)",
 )
+_C_SSM_STEP_KERNEL_CALLS = _REG.counter(
+    "engine.ssm_step_kernel_calls",
+    "calls of the one-step state kernel (ops/ssm_step.py) dispatched: "
+    "decode steps x layers of every decode window of a recurrent model",
+)
 _C_SSM_SCAN_TOKENS = _REG.counter(
     "engine.ssm_scan_tokens",
     "positions a prefill's chunked scan ran over, a layer counted once "
@@ -2038,6 +2043,7 @@ class BatchScheduler:
             steps = W * K * e.model_cfg.n_layers
             _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
             _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
+            _C_SSM_STEP_KERNEL_CALLS.inc(steps)
         toks_parts = []
         for _ in range(W):
             if c.recurrent:

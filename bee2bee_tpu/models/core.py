@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.ssm_step import ssm_state_step, ssm_state_step_xla
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -714,15 +715,21 @@ def _ssm_chunked_scan(x, dt, A, Bm, Cm, h0, chunk: int):
     return y.reshape(B, nc * Q, G, Hg, P)[:, :T], h_last
 
 
-def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None):
+def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None,
+              layer=None):
     """falcon-h1's Mamba-2 mixer on ``u`` [B, T, D] (ln1's output).
     Returns (out [B, T, D], new_state or None).
 
     ``state`` is ONE layer's slice of init_ssm_state ({"ssm": [B, heads,
     head_dim, state] f32, "conv": [B, K-1, C]}) or None for a stateless
     full-sequence pass from zero (training / scoring / the cache-less
-    forward). T == 1 runs the one-step recurrence (decode); longer chunks
-    run the chunked scan from the carried state (prefill, chunked prefill).
+    forward). With ``layer`` (a traced scalar: core.forward's layer scan)
+    ``state["ssm"]`` is the STACKED [L, B, heads, head_dim, state] and
+    comes back whole with that layer's slice replaced. T == 1 over a
+    carried state is one Mosaic call that steps the state in place and
+    forms ``y`` in the same pass (ops/ssm_step.py: decode); longer chunks
+    run the chunked scan from the carried state (prefill, chunked
+    prefill); a stateless T == 1 keeps the recurrence as XLA ops.
 
     ``valid_len`` [B]: only each row's first ``valid_len`` positions are
     real (a prefill bucket's padded tail). Pads get dt = 0 and are left
@@ -766,18 +773,41 @@ def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None):
         real = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
         dt = jnp.where(real[..., None, None], dt, 0.0)
     A = -jnp.exp(p["A_log"].astype(f32)).reshape(G, Hg)
-    h0 = (state["ssm"].reshape(B, G, Hg, P, N) if state is not None
-          else jnp.zeros((B, G, Hg, P, N), f32))
+    stacked = layer is not None
 
-    if T == 1:
+    if T == 1 and state is not None:
+        # inside the scope: the benchmark books the call's device time by it
         with jax.named_scope("ssm.step"):
-            dt1, x1 = dt[:, 0], x[:, 0]  # [B,G,Hg], [B,G,Hg,P]
-            dBx = (dt1[..., None] * x1)[..., None] * Bm[:, 0, :, None, None, :]
-            h = h0 * jnp.exp(dt1 * A)[..., None, None] + dBx
-            y = jnp.sum(h * Cm[:, 0, :, None, None, :], axis=-1)[:, None]
+            hs, y = ssm_state_step(
+                state["ssm"] if stacked else state["ssm"][None],
+                layer if stacked else 0,
+                dt.reshape(B, Hs), x.reshape(B, Hs, P), Bm[:, 0], Cm[:, 0],
+                A.reshape(Hs))
+            new_ssm = hs if stacked else hs[0]
+            y = y.reshape(B, 1, G, Hg, P)
     else:
-        with jax.named_scope("ssm.scan"):
-            y, h = _ssm_chunked_scan(x, dt, A, Bm, Cm, h0, cfg.ssm_chunk)
+        if state is None:
+            h0 = jnp.zeros((B, G, Hg, P, N), f32)
+        else:
+            h0 = (state["ssm"][layer] if stacked else state["ssm"]).reshape(
+                B, G, Hg, P, N)
+        if T == 1:
+            with jax.named_scope("ssm.step"):
+                h, y = ssm_state_step_xla(
+                    h0.reshape(B, Hs, P, N), dt.reshape(B, Hs),
+                    x.reshape(B, Hs, P), Bm[:, 0], Cm[:, 0], A.reshape(Hs))
+                y = y.reshape(B, 1, G, Hg, P)
+        else:
+            with jax.named_scope("ssm.scan"):
+                y, h = _ssm_chunked_scan(x, dt, A, Bm, Cm, h0, cfg.ssm_chunk)
+        if state is not None:
+            new_ssm = h.reshape(B, Hs, P, N)
+            if stacked:
+                # where the compiler puts a chunk's last state update (one
+                # in-place dynamic-update-slice fusion a layer): a scope of
+                # its own, or a device trace books it to no part of the mixer
+                with jax.named_scope("ssm.state_write"):
+                    new_ssm = state["ssm"].at[layer].set(new_ssm)
 
     with jax.named_scope("ssm.out_proj"):
         y = y + p["D"].astype(f32).reshape(G, Hg)[..., None] * x
@@ -791,7 +821,7 @@ def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None):
 
     if state is None:
         return out, None
-    return out, {"ssm": h.reshape(B, Hs, P, N), "conv": new_conv.astype(state["conv"].dtype)}
+    return out, {"ssm": new_ssm, "conv": new_conv.astype(state["conv"].dtype)}
 
 
 # ------------------------------------------------------- reusable blocks
@@ -1194,19 +1224,16 @@ def forward(
         def ssm_hook(h):
             # this layer's recurrent state in, the state after the chunk out
             nonlocal lcache
+            # (the mixer takes the STACKED state: a decode step updates
+            # it in place, a longer chunk writes its slice back)
             out, new = ssm_mixer(
                 lp["ssm"], cfg, h,
-                {"ssm": lcache["ssm"][layer_idx],
-                 "conv": lcache["conv"][layer_idx]},
-                valid_len,
+                {"ssm": lcache["ssm"], "conv": lcache["conv"][layer_idx]},
+                valid_len, layer=layer_idx,
             )
-            # the write-back is where the compiler puts the state's update
-            # (one in-place dynamic-update-slice fusion a layer): a scope of
-            # its own, or a device trace books it to no part of the mixer
-            with jax.named_scope("ssm.state_write"):
+            with jax.named_scope("ssm.state_write"):  # the conv's tail
                 lcache = dict(
-                    lcache,
-                    ssm=lcache["ssm"].at[layer_idx].set(new["ssm"]),
+                    lcache, ssm=new["ssm"],
                     conv=lcache["conv"].at[layer_idx].set(new["conv"]),
                 )
             return out

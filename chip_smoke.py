@@ -654,9 +654,104 @@ def _in_place_case(args, window) -> dict:
     }
 
 
+def _state_step_case(device_kind: str, rows: int = 64, layers: int = 6) -> dict:
+    """falcon-h1's one-step state kernel (ops/ssm_step.py) at the wide cell's
+    shape: ``rows`` x 32 heads x [128, 256] float32 a layer, a stack of
+    ``layers``. One compiled, donated call on layer 3 against the XLA
+    recurrence (the new slice bit for bit - the same float32 products in
+    the same order; y within the bound any two orders of a 256-term sum
+    keep; every other layer untouched), then KERNEL_TIMED_CALLS passes of a
+    scan over all the layers with the state as its carry, as core.forward
+    calls it: microseconds a call and the GB/s of its one read + one write
+    of a layer's slice against the chip's peak (benchmark/peaks.json: 819 on
+    a v5e), beside the XLA lines in the
+    same scan (there the compiler fuses the update into the write-back and
+    reads the slice a second time for y; outside a scan it does not)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.models.config import get_config
+    from bee2bee_tpu.ops.ssm_step import _head_tile, ssm_state_step, ssm_state_step_xla
+
+    peaks = json.loads((ROOT / "benchmark" / "peaks.json").read_text())
+    hbm_gbs = peaks[device_kind]["hbm_bytes_per_s"] / 1e9  # an unlisted device is an error
+    cfg = get_config("falcon-h1-34b")
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    B, layer = rows, jnp.int32(3)
+    ks = jax.random.split(jax.random.key(SEED), 6)
+    state = jax.random.normal(ks[0], (layers, B, H, P, N), jnp.float32)
+    dt = jnp.abs(jax.random.normal(ks[1], (B, H))) * 0.3
+    x = jax.random.normal(ks[2], (B, H, P))
+    Bm, Cm = (jax.random.normal(k, (B, G, N)) for k in ks[3:5])
+    A = -jnp.exp(jax.random.normal(ks[5], (H,)))
+
+    def xla(state, layer, *inputs):  # what every decode step ran before the kernel
+        h, y = ssm_state_step_xla(state[layer], *inputs)
+        return state.at[layer].set(h), y
+
+    args = (layer, dt, x, Bm, Cm, A)
+    want_state, want_y = jax.jit(xla)(state, *args)
+    mag = jnp.sum(jnp.abs(  # sum_n |h C|: what bounds two orders of y's sum
+        want_state[3].reshape(B, G, H // G, P, N) * Cm[:, :, None, None, :]), -1)
+    lowered = jax.jit(
+        lambda st, *a: ssm_state_step(st, *a, interpret=False), donate_argnums=(0,)
+    ).lower(state, *args)
+    has_kernel = "tpu_custom_call" in lowered.as_text()
+    kernel = lowered.compile()
+    got_state, got_y = kernel(state + 0, *args)
+    state_diff = float(jnp.max(jnp.abs(got_state[3] - want_state[3])))
+    others_same = bool(jnp.array_equal(got_state[:3], state[:3])
+                       and jnp.array_equal(got_state[4:], state[4:]))
+    y_diff = np.abs(np.asarray(got_y) - np.asarray(want_y))
+    y_bound = 2 * (N - 1) * float(np.finfo(np.float32).eps) * np.asarray(mag).reshape(B, H, P)
+    del want_state
+
+    def timed(step, st):
+        def every_layer(st):
+            def one(carry, li):
+                st, acc = carry
+                st, y = step(st, li, dt, x + 0 * acc[:1, :1, :1], Bm, Cm, A)
+                return (st, acc + y), None
+            return jax.lax.scan(
+                one, (st, jnp.zeros((B, H, P), jnp.float32)),
+                jnp.arange(layers, dtype=jnp.int32))[0]
+
+        fn = jax.jit(every_layer, donate_argnums=(0,))
+        st, _ = fn(st)  # compiles; outside the clock
+        jax.block_until_ready(st)
+        t0 = time.perf_counter()
+        for _ in range(KERNEL_TIMED_CALLS):
+            st, acc = fn(st)
+        jax.block_until_ready((st, acc))
+        return (time.perf_counter() - t0) / (KERNEL_TIMED_CALLS * layers) * 1e6
+
+    us = timed(lambda *a: ssm_state_step(*a, interpret=False), got_state)
+    xla_us = timed(xla, state)
+    moved = 2 * B * H * P * N * 4
+    gbs = moved / us / 1e3
+    return {
+        "B": B, "layers": layers, "H": H, "P": P, "N": N, "groups": G,
+        "head_tile": _head_tile(H, P, N)[0],
+        "tpu_custom_call_in_lowered_text": has_kernel,
+        "state_max_abs_diff_vs_xla": state_diff,
+        "other_layers_untouched": others_same,
+        "y_max_abs_diff_vs_xla": float(y_diff.max()),
+        "y_worst_diff_over_bound": float(np.max(y_diff / (y_bound + 1e-30))),
+        "us_per_call": round(us, 1),
+        "xla_us_per_call": round(xla_us, 1),
+        "bytes_moved_per_call": moved,
+        "GBs": round(gbs, 1),
+        "share_of_hbm_peak": round(gbs / hbm_gbs, 4),
+        "ok": bool(has_kernel and state_diff <= 1e-5 and others_same
+                   and np.all(y_diff <= y_bound)),
+    }
+
+
 def child_kernel() -> None:
     """The ragged kernel, compiled, against the dense path on the chip
-    (16-slot pages, bf16), and its microseconds a call."""
+    (16-slot pages, bf16), and its microseconds a call; then the state-step
+    kernel at falcon-h1's shape against the XLA recurrence."""
     from bee2bee_tpu.utils import enable_compile_cache
 
     enable_compile_cache()
@@ -669,6 +764,7 @@ def child_kernel() -> None:
                   "block": KERNEL_BLOCK, "dtype": "bfloat16",
                   "tolerance": f"|d| <= {KERNEL_ATOL} + {KERNEL_RTOL}*|dense|",
                   "cases": {n: _kernel_case(n, c, rng) for n, c in KERNEL_CASES.items()}}
+    line["cases"]["h1_state_step"] = _state_step_case(dev.device_kind)
     line["ok"] = all(c["ok"] for c in line["cases"].values())
     line["elapsed_s"] = elapsed()
     emit(line)

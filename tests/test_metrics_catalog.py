@@ -39,6 +39,7 @@ ALLOWED_ABSENT = {
     "engine.state_rows": "no recurrent state: the boot's model has no mixer",
     "engine.state_bytes": "no recurrent state: the boot's model has no mixer",
     "engine.ssm_step_rows": "no recurrent state: the boot's model has no mixer",
+    "engine.ssm_step_kernel_calls": "no recurrent state: the boot's model has no mixer",
     "engine.ssm_scan_tokens": "no recurrent state: the boot's model has no mixer",
     # CPU test backend: device.memory_stats() is None and no
     # BEE2BEE_HBM_BYTES budget is set, so headroom cannot compute

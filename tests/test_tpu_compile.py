@@ -34,6 +34,7 @@ from bee2bee_tpu.ops.flash import flash_attention
 from bee2bee_tpu.ops.ragged import (
     make_ragged_attn_fn, paged_kv_write, ragged_paged_attention,
 )
+from bee2bee_tpu.ops.ssm_step import ssm_state_step
 from bee2bee_tpu.parallel.mesh import AXES
 
 BS = 16  # EngineConfig.kv_block_size default
@@ -69,6 +70,15 @@ def _no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic_state_step(monkeypatch):
+    """core.ssm_mixer's state step asks the DEFAULT backend whether to run
+    interpreted, and that is the CPU here: steer it from the test, so the
+    program compiled for the described chip holds the kernel the chip runs."""
+    monkeypatch.setattr(
+        "bee2bee_tpu.ops.ssm_step.interpret_off_tpu", lambda mesh=None: False)
 
 
 def _compiled_text(fn, *args) -> str:
@@ -346,7 +356,7 @@ IN_PLACE_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
-def test_forward_keeps_the_pool_in_place(one_chip, case):
+def test_forward_keeps_the_pool_in_place(one_chip, mosaic_state_step, case):
     """No instruction of the program but the two kernels' aliased calls
     produces an array as large as one layer's pool slice: no slice, no
     relayout, no select, no write-back in the layer loop, and no relayout
@@ -379,3 +389,66 @@ def test_forward_keeps_the_pool_in_place_under_model_4(topo):
     assert "while(" in text, "no layer loop in the compiled text"
     assert text.count("tpu_custom_call") >= 3
     assert _pool_sized_ops(text, slice_elems, 2) == []
+
+
+# ------------------ the recurrent state stepped in place (PR 34, falcon-h1)
+
+STATE_STEP_CASES = {
+    # (rows, heads, head size, state size, groups)
+    "falcon-h1-decode": (64, 32, 128, 256, 2),
+    "falcon-h1-decode-one-row": (1, 32, 128, 256, 2),
+    "tiny-falcon-h1-decode": (4, 4, 8, 16, 2),  # blocks off the (8, 128) tiling
+    "mamba2-64x128-decode": (8, 24, 64, 128, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STATE_STEP_CASES))
+def test_state_step_kernel_compiles_for_v5e(one_chip, case):
+    """The one-step state kernel alone, on a stacked state of two layers with
+    a traced layer, donated: one Mosaic call, the state aliased in place and
+    no temporary of a head tile's size beside it."""
+    B, H, P, N, G = STATE_STEP_CASES[case]
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda st, lay, dt, x, b, c, a: ssm_state_step(
+            st, lay, dt, x, b, c, a, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        sds(2, B, H, P, N), sds(dtype=jnp.int32), sds(B, H), sds(B, H, P),
+        sds(B, G, N), sds(B, G, N), sds(H),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= 2 * B * H * P * N * 4  # (more: the lane pad)
+    assert analysis.temp_size_in_bytes < B * H * P * 4 * 8  # dt x, exp(dt A): no state
+
+
+# the temporaries of the program below at the parent of PR 34 (tree d549c9f,
+# where XLA's fused dynamic-update-slice stepped the state; my compile for
+# the described v5e, PR 34): the kernel may not cost the program more
+H1_DECODE_TEMP_BYTES_BEFORE = 2_670_592
+
+
+def test_forward_steps_the_state_in_place(one_chip, mosaic_state_step):
+    """falcon-h1's decode forward (64 rows, two layers): the layer loop holds
+    the state-step kernel under the scope the benchmark books it by, no
+    instruction but that aliased call produces an array as large as one
+    layer's state or the stacked state (no slice, no dynamic-update-slice,
+    no copy: 1.62 GB at six layers would not fit the chip), and the
+    temporaries are no larger than before the kernel."""
+    B = 64
+    cfg = dataclasses.replace(get_config("falcon-h1-34b"), n_layers=2)
+    lowered, _ = _forward_program(cfg, B, 1, 32, 3201, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 4  # K write, V write, the read, the state step
+    assert sum("ssm.step/pallas_call" in ln for ln in calls) == 1
+    state_elems = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+    assert _pool_sized_ops(text, state_elems, 2) == []
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= 2 * state_elems * 4
+    assert analysis.temp_size_in_bytes <= H1_DECODE_TEMP_BYTES_BEFORE
