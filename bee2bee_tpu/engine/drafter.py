@@ -140,6 +140,12 @@ class DraftModel(Drafter):
             raise RecurrentStateUnsupported(
                 "spec_model_drafter", self.cfg.name,
                 "a rejected draft cannot be rolled back out of the state")
+        if self.cfg.has_mla:
+            from .paged import LatentPoolUnsupported
+
+            raise LatentPoolUnsupported(
+                "spec_model_drafter", self.cfg.name,
+                "the drafter's rectangular cache holds K/V")
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

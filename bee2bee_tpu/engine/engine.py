@@ -50,7 +50,7 @@ from ..models import core, partition
 from ..parallel.mesh import local_mesh
 from ..tracing import current_timing
 from ..utils import MetricsAggregator
-from .paged import RecurrentStateUnsupported
+from .paged import LatentPoolUnsupported, RecurrentStateUnsupported
 from .tokenizer import load_tokenizer
 
 logger = logging.getLogger("bee2bee_tpu.engine")
@@ -313,6 +313,7 @@ class InferenceEngine:
         self.max_seq_len = min(self.engine_cfg.max_seq_len, self.model_cfg.max_seq_len)
         partition.validate_divisibility(self.model_cfg, self.mesh)
         self._validate_recurrent_features()
+        self._validate_latent_features()
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -717,6 +718,46 @@ class InferenceEngine:
                    f"divide max_seq_len {self.max_seq_len}, so the last "
                    "window would re-feed tokens the state already absorbed")
 
+    def _validate_latent_features(self):
+        """Refuse, by name, every configured feature that is not proven over
+        a latent pool (LatentPoolUnsupported; cfg.has_mla). Pipeline stages
+        refuse in stage_runner, a latent-attention DRAFTER in drafter.py."""
+        cfg, ec = self.model_cfg, self.engine_cfg
+        if not cfg.has_mla:
+            return
+
+        def refuse(feature, why):
+            raise LatentPoolUnsupported(feature, cfg.name, why)
+
+        if jnp.dtype(ec.cache_dtype) == jnp.int8:
+            refuse("kv_int8", "the requantising page write keeps a scale a "
+                   "K/V head: a latent row has none")
+        if ec.drafter == "mesh":
+            refuse("spec_mesh_drafter", "the verify forward over latent rows "
+                   "is not tested")
+        if ec.drafter:
+            refuse("spec_model_drafter", "the verify forward over latent rows "
+                   "is not tested")
+        if ec.spec_tokens > 0:
+            refuse("spec_ngram", "the verify forward over latent rows is not "
+                   "tested")
+        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
+            refuse("seq_attention", "the sp partials read per-head K/V")
+        if self.mesh.shape.get("model", 1) > 1:
+            refuse("mesh_model", "the one latent row a token is read by every "
+                   "head: the read is not partitioned over a model axis "
+                   "(--mesh-shape model:N)")
+        if self.mesh.shape.get("expert", 1) > 1:
+            refuse("mesh_expert", "the dropless expert layer's grouped product "
+                   "is not partitioned over an expert axis")
+        if ec.max_adapters > 0:
+            refuse("multi_lora", "the latent projections have no adapter path")
+        if ec.quantize == "int8":
+            refuse("weight_int8", "the absorbed products read W_kvb unquantised")
+        if ec.prefix_cache_entries > 0:
+            refuse("prefix_cache", "a shared latent block under a resumed "
+                   "prefill is not tested")
+
     @property
     def state_info(self) -> dict | None:
         """The recurrent state's identity for the boot record (/providers,
@@ -761,10 +802,19 @@ class InferenceEngine:
         share the base model's prefix cache (scheduler guard).
         ``state`` (recurrent models): the prefilling ROW's state slot
         ([L, 1, ...], zero for a fresh row, the previous chunk's output
-        otherwise), donated; returned third. The bucket's padded tail
+        otherwise), donated; returned third, in a dict that also holds an
+        expert model's ``moe_stats``. The bucket's padded tail
         leaves it untouched (``valid_len``), and only the last real
         position's logits are computed."""
         recurrent = state is not None
+        moe = self.model_cfg.moe_dropless
+        # the head for ONE position a row (recurrent models since PR 28, and
+        # latent-attention ones: at a 129,280-token vocabulary the full
+        # [1, 512, V] logits are 0.27 GB); phi-3's programs stay as they were
+        one_logit = recurrent or self.model_cfg.has_mla
+        if moe:  # the forward's expert-layer counters: extras["moe_stats"]
+            cache = dict(cache, moe_stats=jnp.zeros(
+                (len(core.MOE_STATS),), jnp.int32))
         logits, cache = core.forward(
             params, self.model_cfg, tokens,
             dict(cache, **state) if recurrent else cache, offset,
@@ -772,13 +822,19 @@ class InferenceEngine:
             paged_write_floor=write_floor, paged_write_ceil=write_ceil,
             adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
             valid_len=true_len if recurrent else None,
-            last_index=true_len - 1 if recurrent else None,
+            last_index=true_len - 1 if one_logit else None,
         )
-        if recurrent:
-            state = {k: cache.pop(k) for k in tuple(state)}
-            return cache, logits[:, 0, :], state
-        idx = (true_len - 1).reshape(-1, 1, 1)  # [B,1,1]
-        last = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
+        # what the chunk hands back beside the pool, by NAME: the row's
+        # recurrent state and / or the expert layers' counters
+        extras = {k: cache.pop(k) for k in
+                  tuple(state or ()) + (("moe_stats",) if moe else ())}
+        if one_logit:
+            last = logits
+        else:
+            idx = (true_len - 1).reshape(-1, 1, 1)  # [B,1,1]
+            last = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
+        if extras:
+            return cache, last[:, 0, :], extras
         return cache, last[:, 0, :]
 
     def _spec_verify_fn(self, params, cur, drafts, draft_lens, cache, offsets,
@@ -906,6 +962,13 @@ class InferenceEngine:
             "capacity_tokens": int(
                 (self.pool_blocks - 1) * self.engine_cfg.kv_block_size
             ),
+            # what a token stores (core.pool_layout), as published: a
+            # lane-aligned pool's arrays hold more (engine.hbm_bytes)
+            "layout": {n: list(hw) for n, hw in
+                       core.pool_layout(self.model_cfg).items()},
+            "bytes_per_token": core.pool_bytes_per_token(
+                self.model_cfg,
+                jnp.dtype(self.engine_cfg.cache_dtype).itemsize),
         }
 
     @property
@@ -1267,6 +1330,8 @@ class InferenceEngine:
             "n_layers": cfg.n_layers,
             "n_kv_heads": cfg.n_kv_heads,
             "head_dim": cfg.head_dim,
+            "pool_layout": {n: list(hw) for n, hw in
+                            core.pool_layout(cfg).items()},
             "block_size": self.engine_cfg.kv_block_size,
             "cache_dtype": str(jnp.dtype(self.engine_cfg.cache_dtype)),
         }
@@ -1353,11 +1418,11 @@ class InferenceEngine:
             cfg = self.model_cfg
             nb = ceil_div(offset, self.engine_cfg.kv_block_size)
             cache_dt = jnp.dtype(self.engine_cfg.cache_dtype)
-            pool_shape = (
-                cfg.n_layers, cfg.n_kv_heads, nb,
-                self.engine_cfg.kv_block_size, cfg.head_dim,
-            )
-            want = {"k": (pool_shape, cache_dt), "v": (pool_shape, cache_dt)}
+            want = {
+                name: ((cfg.n_layers, heads, nb,
+                        self.engine_cfg.kv_block_size, width), cache_dt)
+                for name, (heads, width) in core.pool_layout(cfg).items()
+            }
             if self.kv_quantized:
                 sshape = (cfg.n_layers, cfg.n_kv_heads, nb)
                 want["k_scale"] = (sshape, jnp.dtype(jnp.float32))

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..metrics import get_registry
+from ..models import core
 
 # block-pool occupancy for /metrics (one engine per serving node, so
 # unlabeled gauges suffice; the last-constructed allocator owns them)
@@ -137,6 +138,21 @@ class RecurrentStateUnsupported(ValueError):
         super().__init__(
             f"{feature} is not supported for {model!r}: its rows carry "
             f"recurrent state beside their K/V pages, and {why}"
+        )
+
+
+class LatentPoolUnsupported(ValueError):
+    """An engine feature that is not proven over a LATENT pool (a model with
+    latent attention caches one [c_kv | k_rope] row a token, no per-head K/V:
+    core.pool_layout) was asked for with such a model. ``feature`` names it.
+    Raised when the engine is built, as RecurrentStateUnsupported is: none of
+    these may be silently wrong."""
+
+    def __init__(self, feature: str, model: str, why: str):
+        self.feature = feature
+        super().__init__(
+            f"{feature} is not supported for {model!r}: its rows cache "
+            f"latent rows (no per-head K/V), and {why}"
         )
 
 
@@ -385,13 +401,18 @@ class RowCache:
         self.block_size = engine.engine_cfg.kv_block_size
         self.blocks_per_row = engine.blocks_per_row
         self.recurrent = engine.model_cfg.has_ssm
+        # what a token stores, a layer (core.pool_layout): the page
+        # counters, the export format and the bytes a token follow from it
+        self.layout = core.pool_layout(engine.model_cfg)
         ic = engine.introspect
         # the CoW copy is scalar-arg'd (one trace ever): un-predicated,
         # repeats storm
         self._copy_block = ic.sentinel.watch(
             "cow_copy", _copy_slot, key_fn=lambda axis, pool, src, dst: ()
         )
-        ic.ledger.register("kv_pool", lambda: self.pool)
+        # engine.hbm_bytes{component}: a latent pool goes under its own name
+        ic.ledger.register(
+            "latent" if "latent" in self.layout else "kv_pool", lambda: self.pool)
         if self.recurrent:
             ic.ledger.register("state", lambda: self.state)
         self.rebuild()
@@ -575,7 +596,7 @@ class RowCache:
 
             _C_KV_PAGES_WRITTEN.inc(
                 rows * chunk_pages(chunk, self.block_size)
-                * calls * 2 * e.model_cfg.n_layers
+                * calls * len(self.layout) * e.model_cfg.n_layers
             )
 
     def count_tiles(self, tables, offsets, chunk: int, calls: int = 1):
@@ -592,12 +613,15 @@ class RowCache:
             return
         from ..ops.ragged import work_counts  # loaded with the attn_fn
 
-        k = self.pool["k"]
+        k = next(iter(self.pool.values()))  # K, or the latent rows
         heads, _, block, head_dim = k.sharding.shard_shape(k.shape)[1:]
         live, stepped = work_counts(
             tables, offsets[: len(tables)],
             0 if cfg.sliding_window_every > 1 else int(cfg.sliding_window or 0),
-            heads=heads, group=cfg.n_heads // cfg.n_kv_heads, chunk=chunk,
+            heads=heads,
+            # query heads a stored head: all of them read a latent row
+            group=cfg.n_heads // next(iter(self.layout.values()))[0],
+            chunk=chunk,
             head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
             quantized=e.kv_quantized,
         )
@@ -693,7 +717,7 @@ class RowCache:
         if not nb:
             return 0, None
         idx = self._padded_index(self.row_blocks[b][:nb])
-        hd = self.engine.model_cfg.head_dim
+        hd = next(iter(self.layout.values()))[1]  # the published width
         got = jax.device_get(_gather_blocks(hd, self.pool, idx))
         return nb, {
             name: np.asarray(arr[:, :, :nb]) for name, arr in got.items()

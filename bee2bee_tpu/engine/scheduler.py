@@ -155,6 +155,36 @@ _C_SSM_SCAN_TOKENS = _REG.counter(
     "positions a prefill's chunked scan ran over, a layer counted once "
     "(kind label: real | pad of the prefill bucket)",
 )
+_C_MOE_ASSIGNMENTS = _REG.counter(
+    "engine.moe_assignments",
+    "(token, expert) assignments of the dropless expert layer dispatched: "
+    "positions x experts a token x expert layers of every forward (kind "
+    "label: live | dead = dead rows of the batch bucket and a prefill "
+    "bucket's padded tail, which reach no expert)",
+)
+_C_MOE_LAYER_CALLS = _REG.counter(
+    "engine.moe_layer_calls",
+    "expert-layer calls dispatched: forwards x expert layers (x the "
+    "experts a layer = the denominator of engine.moe_experts_hit)",
+)
+_C_MOE_EXPERTS_HIT = _REG.counter(
+    "engine.moe_experts_hit",
+    "distinct experts with at least one live assignment, summed over "
+    "expert-layer calls: counted on the device, fetched with the window's "
+    "tokens (x an expert's bytes = the weights the grouped products read)",
+)
+_G_MOE_LOAD = _REG.gauge(
+    "engine.moe_expert_load_max",
+    "the busiest expert's assignments over the mean assignments an expert, "
+    "averaged over the expert-layer calls of the last fetched decode window "
+    "(1 = perfectly balanced)",
+)
+_C_LATENT_TOKENS_READ = _REG.counter(
+    "engine.latent_tokens_read",
+    "latent rows a dispatched decode window's reads cover: the live rows' "
+    "context lengths summed over its steps, x layers (x a row's bytes = "
+    "what the latent read must fetch; prefill chunks are not in it)",
+)
 
 
 class Request:
@@ -467,6 +497,9 @@ class BatchScheduler:
         # buffers, and its own (row, request) map — row bookkeeping may
         # drift (retirement nulls _rows[b]) between dispatch and fetch.
         self._inflight: deque = deque()
+        # an expert model's prefill counters (device arrays) waiting for the
+        # next window's fetch
+        self._moe_pending: list = []
         # (cur, offsets) shardings of the decode root's outputs, captured
         # at the first dispatch. Ring-empty dispatches re-enter the chain
         # from the numpy host mirrors, which must be committed to these
@@ -674,6 +707,7 @@ class BatchScheduler:
         B = cur.shape[0]
         if state is not None:
             cache = dict(cache, **state)
+        cache = self._with_moe_stats(cache)
 
         def step(carry, key_t):
             cur, cache, off, cnt = carry
@@ -694,9 +728,24 @@ class BatchScheduler:
         (cur, cache, offsets, counts), toks = jax.lax.scan(
             step, (cur, cache, offsets, counts), keys
         )
-        if state is not None:
-            state = {k: cache.pop(k) for k in tuple(state)}
-        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1), state
+        return (cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1),
+                self._chunk_extras(cache, state))
+
+    @staticmethod
+    def _chunk_extras(cache, state):
+        """What a decode chunk hands back beside the pool, popped from the
+        cache dict by NAME: the recurrent state's leaves and / or an expert
+        model's ``moe_stats`` in one dict (None for a model with neither)."""
+        names = tuple(state or ()) + (("moe_stats",) if "moe_stats" in cache else ())
+        return {k: cache.pop(k) for k in names} or None
+
+    def _with_moe_stats(self, cache):
+        """The chunk's expert-layer counters ride the cache dict through
+        core.forward (its ``moe_stats``): zeroed here, popped on the way
+        out. Models without a dropless expert layer keep their trace."""
+        if not self.engine.model_cfg.moe_dropless:
+            return cache
+        return dict(cache, moe_stats=jnp.zeros((len(core.MOE_STATS),), jnp.int32))
 
     def _decode_pen_fn(
         self, params, cur, cache, offsets, counts,
@@ -713,6 +762,7 @@ class BatchScheduler:
         B = cur.shape[0]
         if state is not None:
             cache = dict(cache, **state)
+        cache = self._with_moe_stats(cache)
 
         def step(carry, key_t):
             cur, cache, off, counts = carry
@@ -732,9 +782,8 @@ class BatchScheduler:
         (cur, cache, offsets, counts), toks = jax.lax.scan(
             step, (cur, cache, offsets, counts), keys
         )
-        if state is not None:
-            state = {k: cache.pop(k) for k in tuple(state)}
-        return cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1), state
+        return (cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1),
+                self._chunk_extras(cache, state))
 
     # ------------------------------------------------------------ loop
 
@@ -786,6 +835,7 @@ class BatchScheduler:
         # abandon the readback ring outright: its device futures may be
         # poisoned, and with every row released below nobody needs them
         self._inflight.clear()
+        self._moe_pending = []
         _G_OVERLAP.set(0)
         self.cache.flush_deferred()
         for req in list(self._queue) + [r for r in self._rows if r is not None]:
@@ -1183,12 +1233,18 @@ class BatchScheduler:
                        else self._lora_args_row(req)),
                 )
                 self.cache.count_pages_written(1, bucket)
+                self.cache.pool, last_logits, *extras = out
+                extras = dict(extras[0]) if extras else {}
+                if "moe_stats" in extras:  # an expert model's counters
+                    self._moe_pending.append(extras.pop("moe_stats"))
+                    # positions past the prompt's end are pad even where a
+                    # re-anchored window re-feeds real ones
+                    real = min(len(chunk), n - pos)
+                    self._count_moe(real, bucket - real, 1)
                 if row_state is not None:
-                    self.cache.pool, last_logits, row_state = out
+                    row_state = extras
                     _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
                     _C_SSM_SCAN_TOKENS.inc(bucket - len(chunk), kind="pad")
-                else:
-                    self.cache.pool, last_logits = out
                 # economics: the bucket's padded width is what the chip
                 # ran; only the real prompt tokens were useful (and none
                 # on the re-prefill rung)
@@ -2044,13 +2100,21 @@ class BatchScheduler:
             _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
             _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
             _C_SSM_STEP_KERNEL_CALLS.inc(steps)
-        toks_parts = []
+        self._count_moe(self.active * W * K, (self._bsz - self.active) * W * K,
+                        W * K)
+        if e.model_cfg.has_mla:
+            # step s of a row at offset o reads its o + s + 1 cached rows
+            n, ctx = W * K, sum(int(self._offsets[b])
+                                for b, r in enumerate(self._rows) if r is not None)
+            _C_LATENT_TOKENS_READ.inc(
+                (ctx * n + self.active * n * (n + 1) // 2) * e.model_cfg.n_layers)
+        toks_parts, moe_parts = [], []
         for _ in range(W):
             if c.recurrent:
                 # the state chains through the windows like the pool does
                 lora["state"] = c.state
             if self._fused:
-                cur_d, c.pool, off_d, cnts, toks, c.state = self._decode(
+                cur_d, c.pool, off_d, cnts, toks, st = self._decode(
                     e.params, cur_d, c.pool, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     counts=self._counts if pen else None,
@@ -2062,7 +2126,7 @@ class BatchScheduler:
                 if pen:
                     self._counts = cnts
             elif pen:
-                cur_d, c.pool, off_d, self._counts, toks, c.state = (
+                cur_d, c.pool, off_d, self._counts, toks, st = (
                     self._decode_pen(
                         e.params, cur_d, c.pool, off_d, self._counts,
                         temps, topks, topps, minps,
@@ -2075,11 +2139,16 @@ class BatchScheduler:
                 # left None it lowers to the counts-free graph, so the
                 # unfused setting differs only in routing pen windows to
                 # the split _decode_pen root above
-                cur_d, c.pool, off_d, _, toks, c.state = self._decode(
+                cur_d, c.pool, off_d, _, toks, st = self._decode(
                     e.params, cur_d, c.pool, off_d,
                     temps, topks, topps, minps, e._next_key(), tables,
                     **lora,
                 )
+            if st is not None and "moe_stats" in st:  # an expert model's counters
+                st = dict(st)
+                moe_parts.append(st.pop("moe_stats"))
+            if c.recurrent:
+                c.state = st
             toks_parts.append(toks)
         if self._chain_sharding is None:
             # metadata-only read (no sync): adopt the root's own output
@@ -2087,6 +2156,9 @@ class BatchScheduler:
             self._chain_sharding = (cur_d.sharding, off_d.sharding)
         self._inflight.append({
             "cur": cur_d, "off": off_d, "toks": toks_parts, "W": W,
+            # fetched with the tokens: the prefills' counters since the last
+            # window, then this window's
+            "moe": self._moe_pending + moe_parts,
             # each record carries its own (row, request) map: retirement
             # nulls _rows[b] between dispatch and fetch, and the fetch
             # must still route row b's tokens to the request that was
@@ -2096,6 +2168,7 @@ class BatchScheduler:
             ],
             "t0": time.perf_counter(),
         })
+        self._moe_pending = []
         self._offsets = self._offsets + np.int32(W * K)
         self.stats.chunks += W
         if pen:
@@ -2156,7 +2229,11 @@ class BatchScheduler:
             active=len(rec["rows"]), chunks=rec["W"],
             inflight=len(self._inflight),
         ):
-            parts = [np.asarray(x) for x in jax.device_get(rec["toks"])]  # meshlint: ignore[ML-J003] -- the one sanctioned sync per readback window (docs/PERF.md)
+            # (an expert model's counters ride the same fetch: rec["moe"])
+            parts = [np.asarray(x) for x in jax.device_get(rec["toks"] + rec.get("moe", []))]  # meshlint: ignore[ML-J003] -- the one sanctioned sync per readback window (docs/PERF.md)
+        parts, moe = parts[:len(rec["toks"])], parts[len(rec["toks"]):]
+        if moe:
+            self._note_moe(moe, windows=rec["W"])
         toks_host = (
             np.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
         )  # [B, W*K]
@@ -2167,6 +2244,31 @@ class BatchScheduler:
             self._cur = toks_host[:, -1].astype(np.int32).copy()
         _H_STEP.observe((time.perf_counter() - rec["t0"]) * 1000.0)
         return toks_host
+
+    def _count_moe(self, live: int, dead: int, forwards: int):
+        """The host's half of the expert layer's counters for one dispatch
+        of ``forwards`` forwards over ``live`` real and ``dead`` padded
+        positions in all: assignments and layer calls follow from shapes
+        (what the device's live mask will do: core.forward's token_live)."""
+        cfg = self.engine.model_cfg
+        if not cfg.moe_dropless:
+            return
+        per = cfg.n_experts_per_tok * cfg.n_expert_layers
+        _C_MOE_ASSIGNMENTS.inc(live * per, kind="live")
+        _C_MOE_ASSIGNMENTS.inc(dead * per, kind="dead")
+        _C_MOE_LAYER_CALLS.inc(forwards * cfg.n_expert_layers)
+
+    def _note_moe(self, stats: list, windows: int):
+        """The device's half, fetched with a window's tokens: ``stats`` are
+        the [hit, max_load, live] vectors (core.MOE_STATS) of the
+        prefills since the last window, then of this window's ``windows``
+        chunks (the gauge reads those alone)."""
+        got = np.asarray(stats, np.int64)
+        _C_MOE_EXPERTS_HIT.inc(int(got[:, 0].sum()))
+        _, max_load, live = got[-windows:].sum(axis=0)
+        if live:
+            _G_MOE_LOAD.set(
+                float(max_load) * self.engine.model_cfg.n_experts / float(live))
 
     @_phase("process")
     def _process_window(self, rec, toks_host: np.ndarray) -> bool:

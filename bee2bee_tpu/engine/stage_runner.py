@@ -81,6 +81,12 @@ class StageRunner:
             raise RecurrentStateUnsupported(
                 "pipeline_stages", self.model_cfg.name,
                 "a stage's per-microbatch cache holds K/V only")
+        if self.model_cfg.has_mla:
+            from .paged import LatentPoolUnsupported
+
+            raise LatentPoolUnsupported(
+                "pipeline_stages", self.model_cfg.name,
+                "a stage's per-microbatch cache is rectangular K/V")
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

@@ -131,6 +131,33 @@ class ModelConfig:
     mlp_multipliers: tuple = (1.0, 1.0)
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
 
+    # JoyAI-LLM-Flash / DeepSeek-V3 style latent attention (MLA), under the
+    # published config.json names: q_lora_rank, kv_lora_rank,
+    # qk_nope_head_dim, qk_rope_head_dim, v_head_dim. mla_kv_rank == 0
+    # means "plain attention". A token caches ONE row of mla_kv_rank +
+    # mla_rope_dim numbers a layer (the normed c_kv and the rotated k_rope
+    # shared by every head): no per-head K, no V (core._mla_attention)
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
+    # the same family's expert layer: moe_router "sigmoid" scores each
+    # expert with sigmoid(x W_r), SELECTS the top k of score + a learned
+    # per-expert bias (e_score_correction_bias), weighs them by the score
+    # WITHOUT the bias, normalised over the k and times moe_scale
+    # (routed_scaling_factor); n_shared_experts always-on experts of the
+    # routed width join the sum; d_ff_expert is the experts' width apart
+    # from the dense layers' d_ff (0 = d_ff); the first first_k_dense layers
+    # are dense MLPs (first_k_dense_replace). A sigmoid router always takes
+    # the DROPLESS expert layer (core._moe_dropless): moe_impl is not
+    # consulted for it
+    moe_router: str = "softmax"  # "softmax" | "sigmoid"
+    moe_scale: float = 1.0
+    n_shared_experts: int = 0
+    d_ff_expert: int = 0
+    first_k_dense: int = 0
+
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
         # back to hashable tuples: cfg is a static jit argument
@@ -190,6 +217,30 @@ class ModelConfig:
             )
         if self.moe_group_size < 1:
             raise ValueError(f"moe_group_size={self.moe_group_size} must be >= 1")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router={self.moe_router!r} must be 'softmax' or 'sigmoid'"
+            )
+        if self.mla_kv_rank and min(self.mla_q_rank, self.mla_nope_dim,
+                                    self.mla_rope_dim, self.mla_v_dim) < 1:
+            raise ValueError(
+                "latent attention needs positive mla_q_rank / mla_nope_dim / "
+                f"mla_rope_dim / mla_v_dim beside mla_kv_rank, got "
+                f"{self.mla_q_rank}/{self.mla_nope_dim}/{self.mla_rope_dim}/"
+                f"{self.mla_v_dim}"
+            )
+        if self.mla_kv_rank and self.mla_rope_dim % 2:
+            raise ValueError(f"mla_rope_dim={self.mla_rope_dim} must be even")
+        if not 0 <= self.first_k_dense <= self.n_layers or (
+            (self.first_k_dense or self.n_shared_experts or self.d_ff_expert)
+            and not self.n_experts
+        ):
+            raise ValueError(
+                f"first_k_dense={self.first_k_dense} / n_shared_experts="
+                f"{self.n_shared_experts} / d_ff_expert={self.d_ff_expert} "
+                f"need an expert model of at least first_k_dense layers "
+                f"(n_experts={self.n_experts}, n_layers={self.n_layers})"
+            )
 
     # families where attention width != d_model (gemma-7b: 16 heads of 256
     # over d_model 3072) set this; None derives d_model // n_heads
@@ -213,6 +264,31 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def has_mla(self) -> bool:
+        """Latent attention: a token caches one [c_kv | k_rope] row a layer
+        (core.init_paged_pool's ``latent`` leaf) instead of per-head K/V."""
+        return self.mla_kv_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token caches a layer under latent attention."""
+        return self.mla_kv_rank + self.mla_rope_dim
+
+    @property
+    def moe_dropless(self) -> bool:
+        """The sigmoid-routed expert layer computes every chosen assignment
+        (core._moe_dropless); the softmax presets keep moe_impl's two."""
+        return self.n_experts > 0 and self.moe_router == "sigmoid"
+
+    @property
+    def expert_ff(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.first_k_dense if self.n_experts else 0
 
     @property
     def has_ssm(self) -> bool:
@@ -612,6 +688,44 @@ CONFIGS["tiny-falcon-h1"] = ModelConfig(
 )
 
 
+_JOYAI_LLM_FLASH = dict(
+    # jdopensource/JoyAI-LLM-Flash config.json (model_type joyai_llm_flash,
+    # 48B-A2.7B): MLA with 32 heads (q rank 1536, latent 512 + a 64-wide
+    # roped key shared by the heads, 128 nope / 128 value a head), one
+    # leading dense layer 7168 wide, then expert layers of 256 experts 768
+    # wide, top-8 behind a sigmoid router with a selection bias, weights
+    # normalised and scaled by 2.5, one shared expert; untied head over
+    # 129,280 tokens. The next-n (multi-token prediction) layer is NOT built
+    vocab_size=129280, d_model=2048, n_heads=32, n_kv_heads=32, d_ff=7168,
+    head_dim_override=64, max_seq_len=131072, rope_theta=32000000.0,
+    rope_style="interleaved", norm_eps=1e-6, tie_embeddings=False,
+    mla_q_rank=1536, mla_kv_rank=512, mla_nope_dim=128, mla_rope_dim=64,
+    mla_v_dim=128,
+    n_experts=256, n_experts_per_tok=8, moe_router="sigmoid", moe_scale=2.5,
+    n_shared_experts=1, d_ff_expert=768, first_k_dense=1,
+)
+CONFIGS["joyai-llm-flash"] = ModelConfig(
+    name="joyai-llm-flash", n_layers=40, **_JOYAI_LLM_FLASH)
+CONFIGS["joyai-llm-flash-5l"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/joyai-llm-flash-5l
+    # .json): the leading dense layer and four expert layers with ALL 256
+    # experts, every width and the whole vocabulary: the first of ten
+    # pipeline stages of four layers, with the final norm and head added
+    name="joyai-llm-flash-5l", n_layers=5, **_JOYAI_LLM_FLASH)
+CONFIGS["tiny-joyai"] = ModelConfig(
+    # every mechanism at CPU-test size, no width a multiple of 128: 3
+    # layers of which 1 dense, 16 experts top-4, 1 shared, latent 24 + 8
+    name="tiny-joyai", vocab_size=512, d_model=48, n_layers=3, n_heads=4,
+    n_kv_heads=4, d_ff=96, head_dim_override=8, max_seq_len=256,
+    rope_theta=10000.0, rope_style="interleaved", norm_eps=1e-6,
+    tie_embeddings=False,
+    mla_q_rank=40, mla_kv_rank=24, mla_nope_dim=12, mla_rope_dim=8,
+    mla_v_dim=20,
+    n_experts=16, n_experts_per_tok=4, moe_router="sigmoid", moe_scale=2.5,
+    n_shared_experts=1, d_ff_expert=36, first_k_dense=1,
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -738,6 +852,63 @@ def _falcon_h1_from_hf(d: dict, nm: str) -> ModelConfig:
         ssm_out_multiplier=float(d.get("ssm_out_multiplier", 1.0)),
         mlp_multipliers=tuple(d.get("mlp_multipliers") or (1.0, 1.0)),
         ssm_multipliers=tuple(d.get("ssm_multipliers") or (1.0,) * 5),
+    )
+
+
+def _joyai_from_hf(d: dict, nm: str) -> ModelConfig:
+    """joyai_llm_flash (jdopensource/JoyAI-LLM-Flash; the DeepSeek-V3
+    layout): latent attention, a sigmoid router with a selection bias, a
+    shared expert, leading dense layers. What core does not implement is
+    refused BY NAME. ``num_nextn_predict_layers`` is read past: the
+    next-token logits do not depend on the next-n layer, which is not
+    built (the loader skips its tensors)."""
+    published = {  # key -> the one value the implementation covers
+        "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc",
+        "scoring_func": "sigmoid", "rope_scaling": None, "moe_layer_freq": 1,
+        "attention_bias": False, "hidden_act": "silu",
+    }
+    for key, want in published.items():
+        got = d.get(key, want)
+        if got != want:
+            raise ValueError(
+                f"joyai_llm_flash config with {key}={got!r} is not "
+                f"implemented (only {key}={want!r}, the published setting)"
+            )
+    if d.get("q_lora_rank") is None:
+        raise ValueError(
+            "joyai_llm_flash config with q_lora_rank=None is not implemented "
+            "(the query goes through its low-rank pair)"
+        )
+    if not d.get("norm_topk_prob", True):
+        raise ValueError(
+            "joyai_llm_flash config with norm_topk_prob=False is not "
+            "implemented (the chosen weights are normalised)"
+        )
+    if not d.get("rope_interleave", True):
+        raise ValueError(
+            "joyai_llm_flash config with rope_interleave=False is not "
+            "implemented (RoPE pairs are (2i, 2i+1))"
+        )
+    H = d["num_attention_heads"]
+    return ModelConfig(
+        name=nm, vocab_size=d["vocab_size"], d_model=d["hidden_size"],
+        n_layers=d["num_hidden_layers"], n_heads=H,
+        n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],
+        head_dim_override=d.get("head_dim") or d["qk_rope_head_dim"],
+        max_seq_len=d.get("max_position_embeddings", 4096),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rope_style="interleaved", norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        mla_q_rank=d["q_lora_rank"], mla_kv_rank=d["kv_lora_rank"],
+        mla_nope_dim=d["qk_nope_head_dim"], mla_rope_dim=d["qk_rope_head_dim"],
+        mla_v_dim=d["v_head_dim"],
+        n_experts=d["n_routed_experts"],
+        n_experts_per_tok=d["num_experts_per_tok"], moe_router="sigmoid",
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=d.get("n_shared_experts") or 0,
+        d_ff_expert=d["moe_intermediate_size"],
+        first_k_dense=d.get("first_k_dense_replace", 0),
     )
 
 
@@ -1032,6 +1203,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         )
     if mt == "falcon_h1":
         return _falcon_h1_from_hf(d, nm)
+    if mt == "joyai_llm_flash":
+        return _joyai_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1204,7 +1377,7 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
-        f"falcon_h1; "
+        f"falcon_h1/joyai_llm_flash; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

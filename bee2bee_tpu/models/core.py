@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.grouped import grouped_matmul
 from ..ops.ssm_step import ssm_state_step, ssm_state_step_xla
 from .config import ModelConfig
 
@@ -66,17 +67,30 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     W_out."""
     D, F, L = cfg.d_model, cfg.d_ff, cfg.n_layers
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
+    if cfg.has_mla:  # the five matrices of core._mla_attention
+        attn = (
+            D * cfg.mla_q_rank
+            + cfg.mla_q_rank * H * (cfg.mla_nope_dim + cfg.mla_rope_dim)
+            + D * cfg.latent_width
+            + cfg.mla_kv_rank * H * (cfg.mla_nope_dim + cfg.mla_v_dim)
+            + H * cfg.mla_v_dim * D
+        )
+    else:
+        attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
     gated = cfg.activation in ("silu", "geglu")
     mlp_one = (3 if gated else 2) * D * F
     if cfg.is_moe:
-        mlp = D * cfg.n_experts + cfg.n_experts_per_tok * mlp_one
+        one = (3 if gated else 2) * D * cfg.expert_ff
+        moe = D * cfg.n_experts + (
+            cfg.n_experts_per_tok + cfg.n_shared_experts) * one
+        # leading dense layers (first_k_dense) pay the dense MLP instead
+        mlps = cfg.first_k_dense * mlp_one + cfg.n_expert_layers * moe
     else:
-        mlp = mlp_one
+        mlps = L * mlp_one
     # falcon-h1: the mixer's in- and out-projection (the scan itself is
     # O(inner * state) a token — under 1 % of the block — and not counted)
     ssm = D * cfg.ssm_proj_dim + cfg.ssm_inner * D if cfg.has_ssm else 0
-    return L * (attn + mlp + ssm) + D * cfg.vocab_size
+    return L * (attn + ssm) + mlps + D * cfg.vocab_size
 
 
 def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
@@ -106,13 +120,38 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
              (proj = inner z + [x; B; C] + heads dt), conv_w [L, C, K],
              conv_b [L, C], norm [L, inner], w_out [L, inner, D] in
              ``dtype``; dt_bias, A_log, D [L, heads] ALWAYS float32
+        attn under latent attention (cfg.has_mla) instead: wq_a [L, D, qr],
+             q_a_norm [L, qr], wq_b [L, qr, H*(nope+rope)], wkv_a
+             [L, D, kvr+rope], kv_a_norm [L, kvr], wkv_b [L, kvr,
+             H*(nope+v)], wo [L, H*v, D]
+        moe under the sigmoid router (cfg.moe_dropless) also: router_bias
+             [L, E] ALWAYS float32 (balanced on seeded traffic as training
+             balances it, balance_router_bias: nonzero, so selection and
+             weight differ), shared {w_gate, w_up [L, D, Fs], w_down
+             [L, Fs, D]}; expert matrices are cfg.expert_ff wide
+      dense_layers/ (cfg.first_k_dense > 0 only): the LEADING dense
+        layers, the same schema with a dense mlp, stacked [k, ...];
+        ``layers`` then holds the n_layers - k expert layers. Layers of
+        unlike trees go through forward's loop as groups of like layers.
     """
     # cfg and dtype are static arguments of the one module-level function,
     # so repeated inits of a config reuse its trace (a fresh partial or
     # lambda per call would retrace every time)
-    return jax.jit(
+    params = jax.jit(
         _init_params, static_argnums=(0, 2), out_shardings=out_shardings
     )(cfg, key, jnp.dtype(dtype))
+    if cfg.moe_dropless:
+        # a noaux_tc router is TRAINED to an even load by its selection
+        # bias; seeded weights get theirs the same way (balance_router_bias)
+        moe = params["layers"]["moe"]
+        bias = jax.jit(
+            balance_router_bias, static_argnums=1,
+            out_shardings=(None if out_shardings is None
+                           else out_shardings["layers"]["moe"]["router_bias"]),
+        )(params, cfg)
+        params = dict(params, layers=dict(
+            params["layers"], moe=dict(moe, router_bias=bias)))
+    return params
 
 
 def _init_params(cfg: ModelConfig, key, dtype) -> Params:
@@ -133,85 +172,114 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         if cfg.norm == "layernorm" and cfg.norm_bias:
             params["embed_norm"]["bias"] = jnp.zeros((D,), dtype)
 
-    layers: Params = {
-        "attn": {
-            "wq": dense((L, D, H * hd)),
-            "wk": dense((L, D, Hkv * hd)),
-            "wv": dense((L, D, Hkv * hd)),
-            "wo": dense((L, H * hd, D), scale=1.0 / math.sqrt(H * hd)),
-        },
-    }
-    if not cfg.no_pre_norms:  # olmo2 blocks norm only their OUTPUTS
-        layers["ln1"] = {"scale": jnp.ones((L, D), dtype)}
-        if not cfg.parallel_block or cfg.parallel_norms == 2:
-            # sequential blocks AND neox-style dual-norm parallel blocks
-            # have ln2; only phi's shared-norm parallel blocks drop it
-            layers["ln2"] = {"scale": jnp.ones((L, D), dtype)}
-    if cfg.post_norms:  # gemma-2: norms on the attn/mlp outputs too
-        layers["ln1_post"] = {"scale": jnp.ones((L, D), dtype)}
-        layers["ln2_post"] = {"scale": jnp.ones((L, D), dtype)}
-    if cfg.norm == "layernorm" and cfg.norm_bias:
-        for ln in ("ln1", "ln2", "ln1_post", "ln2_post"):
-            if ln in layers:
-                layers[ln]["bias"] = jnp.zeros((L, D), dtype)
-    if cfg.use_bias or cfg.qkv_bias:
-        layers["attn"]["bq"] = jnp.zeros((L, H * hd), dtype)
-        layers["attn"]["bk"] = jnp.zeros((L, Hkv * hd), dtype)
-        layers["attn"]["bv"] = jnp.zeros((L, Hkv * hd), dtype)
-    if cfg.qk_norm:  # qwen3: per-head scales; olmo2: full-width scales
-        qn = (H * hd, Hkv * hd) if cfg.qk_norm_full else (hd, hd)
-        layers["attn"]["q_norm"] = jnp.ones((L, qn[0]), dtype)
-        layers["attn"]["k_norm"] = jnp.ones((L, qn[1]), dtype)
-    if cfg.use_bias:  # qwen2 (qkv_bias) has NO output-projection bias
-        layers["attn"]["bo"] = jnp.zeros((L, D), dtype)
+    def layer_group(L, moe_layers):
+        """One group of ``L`` like layers (dense MLP or expert layers)."""
+        if cfg.has_mla:
+            qk = cfg.mla_nope_dim + cfg.mla_rope_dim
+            attn = {
+                "wq_a": dense((L, D, cfg.mla_q_rank)),
+                "q_a_norm": jnp.ones((L, cfg.mla_q_rank), dtype),
+                "wq_b": dense((L, cfg.mla_q_rank, H * qk)),
+                "wkv_a": dense((L, D, cfg.latent_width)),
+                "kv_a_norm": jnp.ones((L, cfg.mla_kv_rank), dtype),
+                "wkv_b": dense((L, cfg.mla_kv_rank,
+                                H * (cfg.mla_nope_dim + cfg.mla_v_dim))),
+                "wo": dense((L, H * cfg.mla_v_dim, D)),
+            }
+        else:
+            attn = {
+                "wq": dense((L, D, H * hd)),
+                "wk": dense((L, D, Hkv * hd)),
+                "wv": dense((L, D, Hkv * hd)),
+                "wo": dense((L, H * hd, D), scale=1.0 / math.sqrt(H * hd)),
+            }
+        layers: Params = {"attn": attn}
+        if not cfg.no_pre_norms:  # olmo2 blocks norm only their OUTPUTS
+            layers["ln1"] = {"scale": jnp.ones((L, D), dtype)}
+            if not cfg.parallel_block or cfg.parallel_norms == 2:
+                # sequential blocks AND neox-style dual-norm parallel blocks
+                # have ln2; only phi's shared-norm parallel blocks drop it
+                layers["ln2"] = {"scale": jnp.ones((L, D), dtype)}
+        if cfg.post_norms:  # gemma-2: norms on the attn/mlp outputs too
+            layers["ln1_post"] = {"scale": jnp.ones((L, D), dtype)}
+            layers["ln2_post"] = {"scale": jnp.ones((L, D), dtype)}
+        if cfg.norm == "layernorm" and cfg.norm_bias:
+            for ln in ("ln1", "ln2", "ln1_post", "ln2_post"):
+                if ln in layers:
+                    layers[ln]["bias"] = jnp.zeros((L, D), dtype)
+        if cfg.use_bias or cfg.qkv_bias:
+            layers["attn"]["bq"] = jnp.zeros((L, H * hd), dtype)
+            layers["attn"]["bk"] = jnp.zeros((L, Hkv * hd), dtype)
+            layers["attn"]["bv"] = jnp.zeros((L, Hkv * hd), dtype)
+        if cfg.qk_norm:  # qwen3: per-head scales; olmo2: full-width scales
+            qn = (H * hd, Hkv * hd) if cfg.qk_norm_full else (hd, hd)
+            layers["attn"]["q_norm"] = jnp.ones((L, qn[0]), dtype)
+            layers["attn"]["k_norm"] = jnp.ones((L, qn[1]), dtype)
+        if cfg.use_bias:  # qwen2 (qkv_bias) has NO output-projection bias
+            layers["attn"]["bo"] = jnp.zeros((L, D), dtype)
 
-    gated = cfg.activation in ("silu", "geglu")
-    if cfg.is_moe:
-        E = cfg.n_experts
-        moe = {
-            "router": dense((L, D, E)),
-            "w_up": dense((L, E, D, F)),
-            "w_down": dense((L, E, F, D), scale=1.0 / math.sqrt(F)),
-        }
-        if gated:
-            moe["w_gate"] = dense((L, E, D, F))
-        layers["moe"] = moe
-    else:
-        mlp = {
-            "w_up": dense((L, D, F)),
-            "w_down": dense((L, F, D), scale=1.0 / math.sqrt(F)),
-        }
-        if gated:
-            mlp["w_gate"] = dense((L, D, F))
-        if cfg.use_bias or cfg.mlp_bias:
-            mlp["b_up"] = jnp.zeros((L, F), dtype)
-            mlp["b_down"] = jnp.zeros((L, D), dtype)
-        layers["mlp"] = mlp
+        gated = cfg.activation in ("silu", "geglu")
+        if moe_layers:
+            E, Fe = cfg.n_experts, cfg.expert_ff
+            moe = {
+                "router": dense((L, D, E)),
+                "w_up": dense((L, E, D, Fe)),
+                "w_down": dense((L, E, Fe, D), scale=1.0 / math.sqrt(Fe)),
+            }
+            if gated:
+                moe["w_gate"] = dense((L, E, D, Fe))
+            if cfg.moe_dropless:
+                # float32 always; init_params sets it (balance_router_bias)
+                moe["router_bias"] = jnp.zeros((L, E), jnp.float32)
+            if cfg.n_shared_experts:
+                Fs = cfg.n_shared_experts * Fe
+                moe["shared"] = {
+                    "w_gate": dense((L, D, Fs)),
+                    "w_up": dense((L, D, Fs)),
+                    "w_down": dense((L, Fs, D), scale=1.0 / math.sqrt(Fs)),
+                }
+            layers["moe"] = moe
+        else:
+            mlp = {
+                "w_up": dense((L, D, F)),
+                "w_down": dense((L, F, D), scale=1.0 / math.sqrt(F)),
+            }
+            if gated:
+                mlp["w_gate"] = dense((L, D, F))
+            if cfg.use_bias or cfg.mlp_bias:
+                mlp["b_up"] = jnp.zeros((L, F), dtype)
+                mlp["b_down"] = jnp.zeros((L, D), dtype)
+            layers["mlp"] = mlp
 
-    if cfg.has_ssm:
-        Hs, K, C = cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
-        # what random-normal does not suit, by the Mamba-2 convention:
-        # A_log = log(1..heads) (S4D-real), D = 1, dt_bias = the inverse
-        # softplus of dt drawn log-uniform in [1e-3, 1e-1], norm scale 1.
-        # The three per-head vectors stay float32 whatever the dtype:
-        # exp(A_log) and softplus(dt + dt_bias) set every step's decay
-        dt = jnp.exp(
-            jax.random.uniform(next(keys), (L, Hs), jnp.float32)
-            * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
-        )
-        layers["ssm"] = {
-            "w_in": dense((L, D, cfg.ssm_proj_dim)),
-            "conv_w": dense((L, C, K), scale=1.0 / math.sqrt(K)),
-            "conv_b": jnp.zeros((L, C), dtype),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-            "A_log": jnp.broadcast_to(
-                jnp.log(jnp.arange(1, Hs + 1, dtype=jnp.float32)), (L, Hs)),
-            "D": jnp.ones((L, Hs), jnp.float32),
-            "norm": jnp.ones((L, cfg.ssm_inner), dtype),
-            "w_out": dense((L, cfg.ssm_inner, D)),
-        }
+        if cfg.has_ssm:
+            Hs, K, C = cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_conv_dim
+            # what random-normal does not suit, by the Mamba-2 convention:
+            # A_log = log(1..heads) (S4D-real), D = 1, dt_bias = the inverse
+            # softplus of dt drawn log-uniform in [1e-3, 1e-1], norm scale 1.
+            # The three per-head vectors stay float32 whatever the dtype:
+            # exp(A_log) and softplus(dt + dt_bias) set every step's decay
+            dt = jnp.exp(
+                jax.random.uniform(next(keys), (L, Hs), jnp.float32)
+                * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
+            )
+            layers["ssm"] = {
+                "w_in": dense((L, D, cfg.ssm_proj_dim)),
+                "conv_w": dense((L, C, K), scale=1.0 / math.sqrt(K)),
+                "conv_b": jnp.zeros((L, C), dtype),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "A_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, Hs + 1, dtype=jnp.float32)), (L, Hs)),
+                "D": jnp.ones((L, Hs), jnp.float32),
+                "norm": jnp.ones((L, cfg.ssm_inner), dtype),
+                "w_out": dense((L, cfg.ssm_inner, D)),
+            }
 
-    params["layers"] = layers
+        return layers
+
+    k_dense = cfg.first_k_dense
+    params["layers"] = layer_group(L - k_dense, cfg.is_moe)
+    if k_dense:
+        params["dense_layers"] = layer_group(k_dense, False)
     params["final_norm"] = {"scale": jnp.ones((D,), dtype)}
     if cfg.norm == "layernorm" and cfg.norm_bias:
         params["final_norm"]["bias"] = jnp.zeros((D,), dtype)
@@ -533,6 +601,11 @@ def _mlp(x, p, cfg: ModelConfig, lora=None):
     return out
 
 
+_HI = lax.Precision.HIGHEST  # float32 products that must stay float32 on
+# the TPU's MXU (its default would round both operands to bf16): the mixer's
+# scan, the expert router
+
+
 def _moe_routed(x, p, cfg: ModelConfig):
     """Top-k expert MLP, GShard-style routed dispatch (static shapes).
 
@@ -630,10 +703,305 @@ def _moe(x, p, cfg: ModelConfig):
     return jnp.einsum("bted,bte->btd", out, weights.astype(out.dtype))
 
 
-# ------------------------------------------- recurrent mixer (falcon-h1)
+MOE_STATS = ("hit", "max_load", "live")  # the entries of a forward's
+# ``moe_stats`` int32 [3], each summed over its expert layers: experts with at
+# least one live assignment, the busiest expert's assignments, live assignments
 
-_HI = lax.Precision.HIGHEST  # the scan's float32 products stay float32 on
-# the TPU's MXU (its default would round both operands to bf16)
+
+def _router_scores(xf, p):
+    """``sigmoid(x W_r)`` [N, E] in float32 from a float32 product."""
+    return jax.nn.sigmoid(jnp.dot(
+        xf.astype(jnp.float32), p["router"].astype(jnp.float32), precision=_HI))
+
+
+def _split_expert_stack(moe: Params):
+    """A stacked expert group's tree as (the rest, {w_gate, w_up, w_down}):
+    the three [L, E, ...] stacks stay out of a layer scan's xs and are read
+    in place (_moe_dropless's ``experts`` / ``layer``)."""
+    names = ("w_gate", "w_up", "w_down")
+    return ({n: a for n, a in moe.items() if n not in names},
+            {n: moe[n] for n in names})
+
+
+def _moe_router(xf, p, cfg: ModelConfig):
+    """The sigmoid router on ``xf`` [N, D], float32 throughout: scores
+    ``s = sigmoid(x W_r)``; the k experts with the largest ``s + bias``
+    are chosen; their weights are ``s`` WITHOUT the bias, divided by their
+    sum and times cfg.moe_scale. Returns (topi [N, k] int32, w [N, k] f32)."""
+    s = _router_scores(xf, p)
+    _, topi = lax.top_k(s + p["router_bias"], cfg.n_experts_per_tok)
+    w = jnp.take_along_axis(s, topi, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.moe_scale
+    return topi.astype(jnp.int32), w
+
+
+# The words of the repo's synthetic traffic (chip_smoke.py, the benchmark's
+# load generator): what seeded weights are balanced on. A router's even load
+# holds on the text it was balanced on, as a trained one's does.
+BALANCE_WORDS = ("mesh", "node", "token", "cache", "block", "shard", "queue",
+                 "route", "draft", "batch", "page", "head", "layer", "chip",
+                 "ring", "tile")
+_BALANCE_ROWS, _BALANCE_WIDTH = 32, 256  # the balancing batch: rows x tokens
+_BALANCE_PASSES, _BALANCE_STEPS = 4, 64
+
+
+def _balance_tokens(cfg: ModelConfig):
+    """The balancing batch (tokens [R, T] int32, prompt lengths [R]): a row
+    is BOS + seeded BALANCE_WORDS text through the byte tokenizer's map
+    (token = byte + 3), 32 to 192 tokens of it, then a continuation, seeded
+    random to begin with. The same for every key: a constant of the program."""
+    import numpy as np
+
+    rng = np.random.RandomState(0)
+    R, T, V = _BALANCE_ROWS, _BALANCE_WIDTH, cfg.vocab_size
+    plen = 32 + (np.arange(R) * 160) // R
+    tokens = rng.randint(3, V, (R, T))
+    for r in range(R):
+        text = " ".join(rng.choice(BALANCE_WORDS, T // 4)).encode()
+        tokens[r, 0] = 1
+        tokens[r, 1:plen[r]] = 3 + np.frombuffer(
+            text[:plen[r] - 1], np.uint8).astype(np.int64) % max(V - 3, 1)
+    return tokens.astype(np.int32), plen.astype(np.int32)
+
+
+def _balanced_bias(s, k: int):
+    """The selection bias [E] under which the top ``k`` of ``s + bias``
+    load every expert alike over the rows of ``s`` [N, E] (scores in (0,
+    1), float32): noaux_tc's own rule — the bias of an expert over the mean
+    load goes down, under it up — run until it rests."""
+    N, E = s.shape
+
+    def step(i, b):
+        _, topi = lax.top_k(s + b, k)
+        # counted in integers: the sum's order cannot reach the bias
+        load = jnp.zeros((E,), jnp.int32).at[topi.reshape(-1)].add(1) * (
+            E / (N * k))  # 1 = the mean load
+        rate = 0.03 * jnp.minimum(1.0, 4.0 * (1.0 - i / _BALANCE_STEPS))
+        return b - rate * jnp.clip(load - 1.0, -2.0, 2.0)
+
+    return lax.fori_loop(0, _BALANCE_STEPS, step, jnp.zeros((E,), jnp.float32))
+
+
+def balance_router_bias(params: Params, cfg: ModelConfig):
+    """``router_bias`` [L, E] float32 for seeded weights: every expert
+    layer's selection bias balanced, layer after layer, on the balancing
+    batch — what a noaux_tc router's training does to it, and what makes
+    64 decoding rows touch 1 - (1 - k/E)^64 of a layer's experts. Seeded
+    hidden states share a large common part across tokens (the mean of the
+    context's values), which a zero or random bias lets pick the same few
+    experts for every row (measured: 30 % of 256 touched a step, not 87).
+
+    A layer's bias is solved from its own router scores (_balanced_bias)
+    and used for the layers after it. The batch's continuations are the
+    model's OWN greedy tokens, as served contexts hold: each of the
+    _BALANCE_PASSES passes takes the argmax at every position as the next
+    position's token (a Jacobi step of greedy decoding) and balances anew;
+    the last pass's bias is returned."""
+    tokens, plen = _balance_tokens(cfg)
+    R, T = tokens.shape
+    prompt = jnp.arange(T)[None, :] < jnp.asarray(plen)[:, None]
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (R, T))
+    mask = attn_mask(cfg, positions, T)
+    moe = params["layers"]["moe"]
+    rest, stack = _split_expert_stack(moe)
+    layers = dict(params["layers"], moe=rest)
+
+    def layer(x, xs):
+        lp, i = xs
+        x = x + _mla_attention(
+            lp["attn"], cfg, _norm(x, lp["ln1"], cfg), positions, mask)
+        h2 = _norm(x, lp["ln2"], cfg)
+        s = _router_scores(h2.reshape(R * T, -1), lp["moe"])
+        bias = _balanced_bias(s, cfg.n_experts_per_tok)
+        out, _ = _moe_dropless(h2, dict(lp["moe"], router_bias=bias), cfg,
+                               experts=stack, layer=i)
+        return x + out, bias
+
+    def one_pass(_, carry):
+        tokens = carry[1]
+        x = embed_tokens(params, cfg, tokens, positions)
+        for i in range(cfg.first_k_dense):
+            x = transformer_block(
+                jax.tree.map(lambda a: a[i], params["dense_layers"]), cfg,  # noqa: B023
+                x, positions, mask)
+        x, bias = lax.scan(
+            layer, x, (layers, jnp.arange(cfg.n_layers - cfg.first_k_dense)))
+        # a row's logits at a time: [T, V] float32, never [R, T, V]
+        greedy = lax.map(
+            lambda xr: jnp.argmax(final_logits(params, cfg, xr[None])[0], -1), x)
+        shifted = jnp.concatenate([tokens[:, :1], greedy[:, :-1]], axis=1)
+        return bias, jnp.where(prompt, tokens, shifted.astype(tokens.dtype))
+
+    bias, _ = lax.fori_loop(
+        0, _BALANCE_PASSES, one_pass,
+        (jnp.zeros_like(moe["router_bias"]), jnp.asarray(tokens)))
+    return bias
+
+
+def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None):
+    """The sigmoid-routed expert layer, DROPLESS: every chosen assignment
+    is computed, whatever the imbalance. Returns (out [B, T, D], stats
+    int32 [3] as MOE_STATS names them).
+
+    The N x k assignments are sorted by expert (``moe.dispatch``: one
+    argsort, a bincount for the group sizes, a row gather), the three
+    expert matrices are grouped products over the sorted rows
+    (``moe.experts``: ops/grouped.py, which visits only experts that got a
+    row and reads each visited expert's matrix from where it lies), the
+    outputs go back by the inverse permutation, weighted, summed over a
+    token's k in float32 (``moe.combine``), and the shared expert is added
+    (``moe.shared``).
+
+    ``live`` [B, T] bool: positions that are real. A dead row of a batch
+    bucket or a prefill bucket's padded tail is assigned to a pad group
+    past the last expert: it sorts last, belongs to no group of the
+    product and gets weight zero, so it neither touches an expert nor
+    counts as load. ``experts`` (default ``p``) holds w_gate / w_up /
+    w_down; with ``layer`` (a traced scalar: forward's layer scan) they are
+    the STACKED [L, E, ...] arrays, read in place: the stack is viewed as
+    L*E groups of which only this layer's E get rows, so no layer's
+    experts are sliced out of the stack (a 0.8 GB copy a matrix a layer
+    otherwise)."""
+    B, T, D = x.shape
+    E, k = cfg.n_experts, cfg.n_experts_per_tok
+    N, M = B * T, B * T * k
+    xf = x.reshape(N, D)
+    experts = p if experts is None else experts
+
+    with jax.named_scope("moe.router"):
+        topi, w = _moe_router(xf, p, cfg)
+
+    with jax.named_scope("moe.dispatch"):
+        flat = topi.reshape(M)
+        if live is not None:
+            flat = jnp.where(jnp.repeat(live.reshape(N), k), flat, E)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [M]
+        gs = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+        n_live = jnp.sum(gs)
+        row_live = jnp.arange(M, dtype=jnp.int32) < n_live  # of SORTED rows
+        xs = jnp.take(xf, order // k, axis=0)  # [M, D]
+        if layer is not None:
+            L = experts["w_up"].shape[0]
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), gs, (layer * E,))
+            stack = lambda a: a.reshape(L * E, *a.shape[2:])  # noqa: E731
+        else:
+            sizes, stack = gs, lambda a: a  # noqa: E731
+
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(xs, stack(experts["w_gate"]), sizes)
+        up = grouped_matmul(xs, stack(experts["w_up"]), sizes)
+        # (rows past the last group hold whatever the buffer held, through
+        # all three products: a row's product reads no other row)
+        y = grouped_matmul(
+            jax.nn.silu(gate) * up, stack(experts["w_down"]), sizes)
+
+    with jax.named_scope("moe.combine"):
+        y = jnp.where(row_live[:, None], y.astype(jnp.float32), 0.0)
+        y = y * jnp.take(w.reshape(M), order)[:, None]
+        inv = jnp.zeros((M,), jnp.int32).at[order].set(
+            jnp.arange(M, dtype=jnp.int32))
+        out = jnp.sum(jnp.take(y, inv, axis=0).reshape(N, k, D), axis=1)
+
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + _mlp(xf, p["shared"], cfg).astype(jnp.float32)
+
+    stats = jnp.stack([jnp.sum(gs > 0), jnp.max(gs), n_live]).astype(jnp.int32)
+    return out.astype(x.dtype).reshape(B, T, D), stats
+
+
+# ------------------------------------------- latent attention (MLA)
+
+
+def _latent_attention(q_lat, lat, mask, v_width: int, sm_scale: float):
+    """Dense attention over latent rows: ``q_lat`` [B, T, H, W] (the
+    absorbed query beside its rotated part), ``lat`` [B, S, W] the cached
+    [c_kv | k_rope] rows — keys as they are, values their first
+    ``v_width`` columns; every head reads the same rows. mask [B|1, 1, T,
+    S]. Returns [B, T, H, v_width]."""
+    logits = jnp.einsum("bthw,bsw->bhts", q_lat, lat).astype(jnp.float32)
+    logits = jnp.where(mask, logits * sm_scale, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1).astype(lat.dtype)
+    return jnp.einsum("bhts,bsr->bthr", probs, lat[..., :v_width])
+
+
+def _mla_attention(p, cfg: ModelConfig, h, positions, mask, kv_hook=None,
+                   attn_fn=None):
+    """JoyAI-LLM-Flash / DeepSeek-V3 latent attention on ``h`` [B, T, D]
+    (ln1's output). Returns the block's attention output [B, T, D].
+
+    ``c_q = RMS(h W_qa)``; per head ``[q_nope | q_rope] = c_q W_qb``;
+    ``[c_kv | k_r] = h W_kva``; the token's cached row is ``[RMS(c_kv) |
+    RoPE(k_r)]`` (ONE roped key shared by the heads; pairs (2i, 2i+1)).
+    Per head ``[k_nope | v] = c_kv W_kvb``. The score denominator is
+    sqrt(nope + rope). Two paths, the same mathematics (tests hold them to
+    each other), chosen by whether a cache exists:
+
+    - a cache (``kv_hook``), the served path: ABSORBED. ``q_nope . k_nope =
+      (q_nope W_kvb[k]^T) . c_kv``, so the query is taken into the latent
+      space once (under ``mla.q_proj``), attention runs over the cached
+      latent rows themselves — keys 576 wide, values their first 512
+      columns — and the output comes back through ``W_kvb[v]`` (under
+      ``mla.out``). Nothing per head is ever cached or expanded.
+      ``kv_hook(latent [B, T, W]) -> what attention reads``: the paged
+      paths write the chunk's rows into the pool and return the gathered
+      view (dense) or the stacked pool itself (ragged: ``attn_fn`` is then
+      the ragged reader's ``latent`` form, core._attention's ABI with no
+      V, and reads it in place).
+    - no cache (training, scoring, a whole-sequence pass): EXPANDED.
+      ``k_nope`` and ``v`` are built from the chunk's own ``c_kv`` and
+      plain causal attention runs per head (320 multiply-adds a (query,
+      key) pair a head against the absorbed form's 1,088)."""
+    B, T, _ = h.shape
+    H = cfg.n_heads
+    R, r, dn, dv = (cfg.mla_kv_rank, cfg.mla_rope_dim, cfg.mla_nope_dim,
+                    cfg.mla_v_dim)
+    rope = functools.partial(
+        _rope, positions=positions, theta=cfg.rope_theta, style=cfg.rope_style)
+    wkv_b = p["wkv_b"].reshape(R, H, dn + dv)
+    sm_scale = 1.0 / math.sqrt(dn + r)
+
+    with jax.named_scope("mla.q_proj"):
+        c_q = _norm(matmul(h, p["wq_a"]), {"scale": p["q_a_norm"]}, cfg)
+        q = matmul(c_q, p["wq_b"]).reshape(B, T, H, dn + r)
+        q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
+    with jax.named_scope("mla.kv_proj"):
+        kv = matmul(h, p["wkv_a"])  # [B, T, R + r]
+        c_kv = _norm(kv[..., :R], {"scale": p["kv_a_norm"]}, cfg)
+        k_rope = rope(kv[..., R:][:, :, None, :])[:, :, 0]
+    if kv_hook is None:  # expanded: per-head keys and values of the chunk
+        with jax.named_scope("mla.kv_proj"):
+            kvb = jnp.einsum("bsr,rhn->bshn", c_kv, wkv_b)
+            k_nope, v = kvb[..., :dn], kvb[..., dn:]
+        with jax.named_scope("mla.read"):
+            logits = (
+                jnp.einsum("bthn,bshn->bhts", q_nope, k_nope)
+                + jnp.einsum("bthr,bsr->bhts", q_rope, k_rope)
+            ).astype(jnp.float32)
+            logits = jnp.where(mask, logits * sm_scale, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+            o = jnp.einsum("bhts,bshv->bthv", probs, v)
+    else:  # absorbed: the query into the latent space, the output back out
+        with jax.named_scope("mla.q_proj"):
+            q_abs = jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :dn])
+            q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)  # [B, T, H, W]
+        with jax.named_scope("mla.write"):
+            cached = kv_hook(jnp.concatenate([c_kv, k_rope], axis=-1))
+        with jax.named_scope("mla.read"):
+            if attn_fn is not None:  # the ragged reader's latent form
+                o_lat = attn_fn(
+                    q_lat, cached, None, mask, cfg, positions=positions
+                ).reshape(B, T, H, R)
+            else:
+                o_lat = _latent_attention(q_lat, cached, mask, R, sm_scale)
+        with jax.named_scope("mla.out"):
+            o = jnp.einsum("bthr,rhv->bthv", o_lat, wkv_b[..., dn:])
+    with jax.named_scope("mla.out"):
+        return matmul(o.reshape(B, T, H * dv), p["wo"])
+
+
+# ------------------------------------------- recurrent mixer (falcon-h1)
 
 
 def init_ssm_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
@@ -844,6 +1212,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, input_ids, positions):
 def transformer_block(
     lp: Params, cfg: ModelConfig, x, positions, mask, kv_hook=None,
     attn_fn=None, rope_local=None, lora=None, ssm_hook=None,
+    moe_kw=None, moe_sink=None,
 ):
     """One block. lp: a single layer's params (no leading L dim). x [B,T,D].
 
@@ -867,11 +1236,22 @@ def transformer_block(
     joins the attention's before the one residual add. The cached paths
     pass a hook that reads and writes the layer's recurrent state; no
     hook = a stateless pass from zero state (ssm_mixer).
+
+    Latent attention (cfg.has_mla): ``kv_hook(latent)`` takes the chunk's
+    one cached row a token and ``attn_fn`` is the ragged reader's latent
+    form (_mla_attention). A sigmoid-routed expert layer (``"moe"`` in
+    ``lp``, cfg.moe_dropless) takes ``moe_kw`` (_moe_dropless's ``live``,
+    ``experts``, ``layer``) and hands its stats to ``moe_sink``.
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     h = x if cfg.no_pre_norms else _norm(x, lp["ln1"], cfg)
+    if cfg.has_mla:
+        x = x + _mla_attention(
+            lp["attn"], cfg, h, positions, mask, kv_hook, attn_fn)
+        return x + _ffn(_norm(x, lp["ln2"], cfg), lp, cfg, lora, moe_kw,
+                        moe_sink)
     mix_out = None
     if cfg.has_ssm:
         mix_out = (ssm_hook(h) if ssm_hook is not None
@@ -941,14 +1321,27 @@ def transformer_block(
     x = x + attn_out
 
     h2 = x if cfg.no_pre_norms else _norm(x, lp["ln2"], cfg)
-    # MoE keeps base experts (lora MLP targets are rejected per-model by
-    # train/lora.validate_targets — expert weights carry an [L, E, ...] dim)
-    mlp_out = (
-        _moe(h2, lp["moe"], cfg) if cfg.is_moe else _mlp(h2, lp["mlp"], cfg, lora)
-    )
+    mlp_out = _ffn(h2, lp, cfg, lora, moe_kw, moe_sink)
     if cfg.post_norms:
         mlp_out = _norm(mlp_out, lp["ln2_post"], cfg)
     return x + mlp_out
+
+
+def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
+         moe_sink=None):
+    """A block's feed-forward half on the normed ``h2``: the layer's dense
+    MLP, or its expert layer where the layer's tree has one (a model's
+    leading dense layers, cfg.first_k_dense, have none). MoE keeps base
+    experts (lora MLP targets are rejected per-model by
+    train/lora.validate_targets — expert weights carry an [L, E, ...] dim)."""
+    if "moe" not in lp:
+        return _mlp(h2, lp["mlp"], cfg, lora)
+    if not cfg.moe_dropless:
+        return _moe(h2, lp["moe"], cfg)
+    out, stats = _moe_dropless(h2, lp["moe"], cfg, **(moe_kw or {}))
+    if moe_sink is not None:
+        moe_sink(stats)
+    return out
 
 
 def final_logits(params: Params, cfg: ModelConfig, x):
@@ -1136,6 +1529,15 @@ def forward(
         )
     if valid_len is not None:
         valid_len = jnp.asarray(valid_len, jnp.int32)
+    # the pool leaf whose shape gives the block size: the latent rows of a
+    # model with latent attention, else K
+    pool_key = next(iter(pool_layout(cfg)))
+    if cfg.has_mla and cache is not None and block_tables is None:
+        raise ValueError(
+            f"{cfg.name!r} has latent attention: its cache is the paged "
+            "latent pool (core.init_paged_pool + block_tables); the "
+            "rectangular cache has no latent form"
+        )
 
     off = jnp.asarray(offset, jnp.int32)
     off_b = jnp.broadcast_to(off.reshape(-1), (B,))  # [B]
@@ -1145,7 +1547,7 @@ def forward(
 
     if block_tables is not None:
         bt = jnp.asarray(block_tables, jnp.int32)
-        BS = cache["k"].shape[3]  # pool block size
+        BS = cache[pool_key].shape[3]  # pool block size
         S = bt.shape[1] * BS  # gathered view width = logical positions
         wfloor = (
             jnp.asarray(paged_write_floor, jnp.int32)
@@ -1165,7 +1567,7 @@ def forward(
     quantized = bt is not None and cache is not None and "k_scale" in cache
     if (
         cache is not None
-        and cache["k"].dtype == jnp.int8
+        and cache[pool_key].dtype == jnp.int8
         and not quantized
     ):
         raise ValueError(
@@ -1184,8 +1586,15 @@ def forward(
     # (ops/ragged.py "Layouts"). The int8 pool's requantising write is
     # XLA's and keeps the per-layer slices.
     page_write = attn_fn.write if ragged and not quantized else None
+    if cfg.has_mla and attn_fn is not None and page_write is None:
+        raise ValueError(
+            f"{cfg.name!r} has latent attention: only the ragged reader over "
+            "a float paged pool (attention='flash') or the dense path reads "
+            "latent rows"
+        )
     if ragged:
-        attn_fn = functools.partial(attn_fn, block_tables=bt)
+        attn_fn = functools.partial(
+            attn_fn.latent if cfg.has_mla else attn_fn, block_tables=bt)
         layer_mask = make_layer_window(cfg)
     else:
         layer_mask = make_layer_mask(cfg, positions, T, S)
@@ -1208,18 +1617,74 @@ def forward(
             return None
         return is_sliding_layer(cfg, layer_idx)
 
+    # the positions an expert layer may count as load: not a prefill
+    # bucket's padded tail (past valid_len or the write ceil), not a row of
+    # the batch bucket that maps no page (the ragged read's own rule for a
+    # dead row: its table is the null block)
+    token_live = None
+    if cfg.moe_dropless:
+        if valid_len is not None:
+            token_live = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+        if bt is not None:
+            mapped = jnp.broadcast_to(bt[:, :1] != 0, (B, T))
+            token_live = mapped if token_live is None else token_live & mapped
+            if wceil is not None:
+                token_live = token_live & (positions < wceil)
+    # the expert matrices of a stacked group stay out of the layer scan's
+    # xs and are read in place (_moe_dropless): set per group below
+    expert_stack = None
+
     def layer(carry, xs):
         x, lcache = carry
         lp, layer_idx = xs[0], xs[1]
         lora = lora_for(xs[2]) if len(xs) > 2 else None
+        moe_kw = None
+        if cfg.moe_dropless and "moe" in lp:
+            moe_kw = {"live": token_live}
+            if expert_stack is not None:
+                moe_kw.update(experts=expert_stack,
+                              layer=layer_idx - cfg.first_k_dense)
 
         if lcache is None:  # training/scoring path: plain block
             return (
                 transformer_block(lp, cfg, x, positions,
                                   layer_mask(layer_idx), attn_fn=attn_fn,
-                                  rope_local=rope_flag(layer_idx), lora=lora),
+                                  rope_local=rope_flag(layer_idx), lora=lora,
+                                  moe_kw=moe_kw),
                 None,
             ), None
+
+        def moe_sink(stats):
+            # the forward's expert-layer counters ride the cache dict when
+            # the caller put them there (the engine's roots do)
+            nonlocal lcache
+            if "moe_stats" in lcache:
+                lcache = dict(lcache, moe_stats=lcache["moe_stats"] + stats)
+
+        def latent_hook(latent):
+            """kv_hook under latent attention: write the chunk's rows
+            [B, T, W] and return what attention reads — the stacked pool
+            (ragged reader: the page-write kernel stores in place) or the
+            gathered [B, S, W] view (dense: XLA's scatter on the layer's
+            slice). The pool is [L, 1, NB, BS, W]: the unit axis stands
+            where K/V pools have their heads."""
+            nonlocal lcache
+            if page_write is not None:
+                lcache = dict(lcache, latent=page_write(
+                    lcache["latent"], latent[:, :, None, :], bt, off_b,
+                    layer_idx, wfloor, wceil))
+                return lcache["latent"]
+            # (the floor / ceil redirects to the null block: kv_hook below)
+            blk = jnp.take_along_axis(bt, positions // BS, axis=1)
+            if wfloor is not None:
+                blk = jnp.where(positions >= wfloor, blk, 0)
+            if wceil is not None:
+                blk = jnp.where(positions < wceil, blk, 0)
+            pool_l = lcache["latent"][layer_idx, 0].at[blk, positions % BS].set(
+                latent.astype(lcache["latent"].dtype))
+            lcache = dict(
+                lcache, latent=lcache["latent"].at[layer_idx, 0].set(pool_l))
+            return pool_l[bt].reshape(B, S, -1).astype(latent.dtype)
 
         def ssm_hook(h):
             # this layer's recurrent state in, the state after the chunk out
@@ -1365,13 +1830,14 @@ def forward(
 
         x = transformer_block(
             lp, cfg, x, positions, layer_mask(layer_idx),
-            kv_hook=kv_hook,
+            kv_hook=latent_hook if cfg.has_mla else kv_hook,
             attn_fn=(
                 attn_fn if page_write is None
                 else functools.partial(attn_fn, layer=layer_idx)
             ),
             rope_local=rope_flag(layer_idx), lora=lora,
             ssm_hook=ssm_hook if cfg.has_ssm else None,
+            moe_kw=moe_kw, moe_sink=moe_sink,
         )
         return (x, lcache), None
 
@@ -1399,6 +1865,18 @@ def forward(
             else:
                 carry, _ = layer_body(carry, (lp, i))
         x, new_cache = carry
+    elif "dense_layers" in params:
+        # layers of unlike trees (cfg.first_k_dense): one scan a group of
+        # like layers, the pool indexed by a layer's absolute place
+        k_dense = cfg.first_k_dense
+        carry, _ = lax.scan(
+            layer_body, (x, cache),
+            (params["dense_layers"], jnp.arange(k_dense)))
+        if cfg.moe_dropless:
+            rest, expert_stack = _split_expert_stack(layer_params["moe"])
+            layer_params = dict(layer_params, moe=rest)
+        (x, new_cache), _ = lax.scan(
+            layer_body, carry, (layer_params, jnp.arange(k_dense, n_layers)))
     else:
         xs = (layer_params, jnp.arange(n_layers))
         if adapters is not None:
@@ -1425,11 +1903,14 @@ def unstack_layers(params: Params) -> Params:
     stacked = params["layers"]
     if isinstance(stacked, (list, tuple)):
         return params  # already unstacked: slicing again would shred weights
-    n = len(jax.tree.leaves(stacked)[0])
     out = dict(params)
+    # a model's leading dense layers (their own stacked group) come first:
+    # the list is in the layers' absolute order, trees unlike
     out["layers"] = [
-        jax.tree.map(lambda a: np.ascontiguousarray(np.asarray(a[i])), stacked)
-        for i in range(n)
+        jax.tree.map(lambda a: np.ascontiguousarray(np.asarray(a[i])), group)
+        for group in (out.pop("dense_layers", None), stacked)
+        if group is not None
+        for i in range(len(jax.tree.leaves(group)[0]))
     ]
     return out
 
@@ -1446,9 +1927,19 @@ def restack_layers(params: Params) -> Params:
     if not isinstance(layers, (list, tuple)):
         return params
     out = dict(params)
-    out["layers"] = jax.tree.map(
-        lambda *leaves: np.stack([np.asarray(a) for a in leaves]), *layers
-    )
+
+    def stack(group):
+        return jax.tree.map(
+            lambda *leaves: np.stack([np.asarray(a) for a in leaves]), *group)
+
+    # leading dense layers of an expert model go back to their own group
+    k = 0
+    if any("moe" in lp for lp in layers):
+        while "moe" not in layers[k]:
+            k += 1
+    if k:
+        out["dense_layers"] = stack(layers[:k])
+    out["layers"] = stack(layers[k:])
     return out
 
 
@@ -1464,12 +1955,35 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int | None = None, dtype=j
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def pool_layout(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
+    """What a token stores in the paged pool, a layer: {leaf: (heads,
+    width)}. K and V of every KV head for plain attention; under latent
+    attention ONE row of cfg.latent_width numbers ([c_kv | k_rope], keys
+    as it is and values by its first mla_kv_rank columns), its ``heads`` a
+    unit axis that only keeps the block axis where every pool leaf has it.
+    The engine's byte arithmetic, export format and kernels' shapes follow
+    from this, never from (n_kv_heads, head_dim) directly."""
+    if cfg.has_mla:
+        return {"latent": (1, cfg.latent_width)}
+    return {"k": (cfg.n_kv_heads, cfg.head_dim),
+            "v": (cfg.n_kv_heads, cfg.head_dim)}
+
+
+def pool_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
+    """Bytes a cached token takes over all layers, as published (a
+    lane-aligned pool stores more: its arrays' own nbytes say)."""
+    return cfg.n_layers * itemsize * sum(
+        h * w for h, w in pool_layout(cfg).values())
+
+
 def init_paged_pool(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     lane_aligned: bool = False,
 ):
     """Preallocate the paged KV block pool:
-    {"k","v"}: [L, Hkv, num_blocks, block_size, hd]. Block 0 is the
+    {"k","v"}: [L, Hkv, num_blocks, block_size, hd] — or, under latent
+    attention, {"latent": [L, 1, num_blocks, block_size, W]} (pool_layout:
+    one row a token, no per-head K, no V). Block 0 is the
     engine's reserved null block (padding target for table entries past a
     row's live extent); rows map logical positions onto blocks via the
     block tables forward() takes.
@@ -1507,9 +2021,17 @@ def init_paged_pool(
     (the ragged kernels over a float pool, on a TPU) asks for it; nothing
     but ops/ragged.py and the scheduler's block export / import ever
     looks at the pad."""
-    hd = -(-cfg.head_dim // 128) * 128 if lane_aligned else cfg.head_dim
-    shape = (cfg.n_layers, cfg.n_kv_heads, num_blocks, block_size, hd)
-    pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    layout = pool_layout(cfg)
+    if cfg.has_mla and jnp.dtype(dtype) == jnp.int8:
+        raise ValueError(
+            f"{cfg.name!r}: the latent pool has no int8 form (the "
+            "requantising page write is per K/V head)")
+    pool = {}
+    for name, (heads, width) in layout.items():
+        if lane_aligned:
+            width = -(-width // 128) * 128
+        pool[name] = jnp.zeros(
+            (cfg.n_layers, heads, num_blocks, block_size, width), dtype)
     if jnp.dtype(dtype) == jnp.int8:
         sshape = (cfg.n_layers, cfg.n_kv_heads, num_blocks)
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)

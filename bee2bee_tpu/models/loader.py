@@ -514,6 +514,71 @@ def _convert_falcon_h1(state, cfg: ModelConfig) -> dict:
     return params
 
 
+def _convert_joyai(state, cfg: ModelConfig) -> dict:
+    """HF joyai_llm_flash (DeepSeek-V3 layout) names -> our layout: the latent
+    attention's seven tensors, the leading dense layers (``dense_layers``),
+    then the expert layers (``layers``) with mlp.gate.{weight,
+    e_score_correction_bias}, mlp.experts.N.* stacked over N, and
+    mlp.shared_experts.*. Tensors of layers past cfg.n_layers — the next-n
+    (multi-token prediction) layer, which is not built — are ignored."""
+    pre = "model." if any(k.startswith("model.") for k in state) else ""
+    t = lambda a: np.ascontiguousarray(a.T)  # noqa: E731  HF linear is [out, in]
+    w = lambda i, k: state[f"{pre}layers.{i}.{k}"]  # noqa: E731
+    proj = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+
+    def group(idx, moe: bool) -> dict:
+        attn = {
+            "wq_a": _stack([t(w(i, "self_attn.q_a_proj.weight")) for i in idx]),
+            "q_a_norm": _stack([w(i, "self_attn.q_a_layernorm.weight") for i in idx]),
+            "wq_b": _stack([t(w(i, "self_attn.q_b_proj.weight")) for i in idx]),
+            "wkv_a": _stack([t(w(i, "self_attn.kv_a_proj_with_mqa.weight")) for i in idx]),
+            "kv_a_norm": _stack([w(i, "self_attn.kv_a_layernorm.weight") for i in idx]),
+            "wkv_b": _stack([t(w(i, "self_attn.kv_b_proj.weight")) for i in idx]),
+            "wo": _stack([t(w(i, "self_attn.o_proj.weight")) for i in idx]),
+        }
+        out = {
+            "attn": attn,
+            "ln1": {"scale": _stack([w(i, "input_layernorm.weight") for i in idx])},
+            "ln2": {"scale": _stack(
+                [w(i, "post_attention_layernorm.weight") for i in idx])},
+        }
+        swiglu = lambda at: {  # noqa: E731
+            ours: _stack([t(w(i, f"{at}.{theirs}.weight")) for i in idx])
+            for ours, theirs in proj
+        }
+        if not moe:
+            out["mlp"] = swiglu("mlp")
+            return out
+        E = range(cfg.n_experts)
+        out["moe"] = {
+            "router": _stack([t(w(i, "mlp.gate.weight")) for i in idx]),
+            "router_bias": _stack(
+                [w(i, "mlp.gate.e_score_correction_bias") for i in idx]
+            ).astype(np.float32),
+            **{
+                ours: _stack([
+                    _stack([t(w(i, f"mlp.experts.{e}.{theirs}.weight")) for e in E])
+                    for i in idx])
+                for ours, theirs in proj
+            },
+        }
+        if cfg.n_shared_experts:
+            out["moe"]["shared"] = swiglu("mlp.shared_experts")
+        return out
+
+    k = cfg.first_k_dense
+    params = {
+        "tok_embed": state[f"{pre}embed_tokens.weight"],
+        "layers": group(range(k, cfg.n_layers), True),
+        "final_norm": {"scale": state[f"{pre}norm.weight"]},
+    }
+    if k:
+        params["dense_layers"] = group(range(k), False)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = t(state["lm_head.weight"])
+    return params
+
+
 def _convert_llama(state, cfg: ModelConfig) -> dict:
     """HF Llama/Mistral names → our layout (weights transpose: HF linear is
     [out, in]; ours is [in, out])."""
@@ -622,9 +687,12 @@ def _materialize(params, dtype, host: bool):
     dense model in HBM first would double the load-time peak)."""
     # the mixer's per-head decay vectors stay float32 whatever the dtype
     # (core.init_params keeps them so too)
+    # (and so does the sigmoid router's selection bias)
     keep = lambda path: (  # noqa: E731
-        len(path) >= 2 and getattr(path[-2], "key", None) == "ssm"
-        and getattr(path[-1], "key", None) in ("A_log", "D", "dt_bias"))
+        len(path) >= 2 and (
+            getattr(path[-2], "key", None) == "ssm"
+            and getattr(path[-1], "key", None) in ("A_log", "D", "dt_bias")
+            or getattr(path[-1], "key", None) == "router_bias"))
     if host:
         return jax.tree_util.tree_map_with_path(
             lambda path, a: np.asarray(a).astype(
@@ -674,6 +742,8 @@ def load_checkpoint(
         params = _convert_gptj(state, cfg)
     elif any(".mamba.in_proj." in k for k in state):  # falcon_h1's mixer
         params = _convert_falcon_h1(state, cfg)
+    elif any(".self_attn.kv_a_proj_with_mqa." in k for k in state):
+        params = _convert_joyai(state, cfg)  # latent attention's unique name
     else:
         params = _convert_llama(state, cfg)
     return _materialize(params, dtype, host)

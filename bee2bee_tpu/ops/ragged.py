@@ -377,13 +377,17 @@ def _ragged_kernel(
     tile_heads: int,
     tile_pages: int,
     quantized: bool = False,
+    v_width: int = 0,  # latent rows (MLA): no V operands, a fetched tile is
+    #                    the keys as it is and the values by its first
+    #                    v_width columns; o_ref / acc_ref are v_width wide
 ):
     Th, Tp, BS = tile_heads, tile_pages, block_size
     if quantized:
         kscale_ref, vscale_ref, *refs = refs
     q_ref, *refs = refs
-    k_refs, v_refs = refs[:Tp], refs[Tp:2 * Tp]
-    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[2 * Tp:]
+    n_kv = Tp if v_width else 2 * Tp
+    k_refs, v_refs = refs[:Tp], refs[Tp:n_kv]
+    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[n_kv:]
     tile_tokens = Tp * BS
     h0 = pl.program_id(0) * Th
     step = pl.program_id(1)
@@ -426,7 +430,8 @@ def _ragged_kernel(
             k, v = kdq_ref[...], vdq_ref[...]
         else:
             k = jnp.concatenate([r[:, 0] for r in k_refs], axis=1)
-            v = jnp.concatenate([r[:, 0] for r in v_refs], axis=1)
+            v = (k[:, :, :v_width] if v_width else
+                 jnp.concatenate([r[:, 0] for r in v_refs], axis=1))
         # all Th heads in one batched dot: their dot -> softmax -> dot
         # chains are independent, and the compiler interleaves them
         s = (
@@ -486,6 +491,8 @@ def ragged_paged_attention(
     v_scale=None,  # both present = quantized pool, dequant in-kernel
     layer=None,  # [] or [1] int32 (traced ok): the pool is STACKED and
     #              this is the layer to read, in place (module docstring)
+    v_width: int | None = None,  # latent rows (MLA): ``v_pool`` is None and
+    #              the values are the first v_width columns of the key rows
 ):
     """Causal attention for a [B, T] chunk over the paged pool; returns
     [B, T, H*hd] (core._attention ABI). T=1 is decode, T=K+1 spec verify,
@@ -497,8 +504,18 @@ def ragged_paged_attention(
     its dot — same tiles, same softmax math, half the pool HBM traffic.
     With ``layer`` the pool operands are the stacked 5-D pool and the page
     index maps lead with the prefetched layer: the same copies from the
-    same bytes, and no slice of the pool exists outside the kernel."""
+    same bytes, and no slice of the pool exists outside the kernel.
+
+    With ``v_width`` the pool holds LATENT rows (core.pool_layout: one row
+    a token, a unit axis for heads): every query head reads the same rows,
+    a page tile is fetched ONCE and serves as keys (the whole row) and as
+    values (its first ``v_width`` columns), and the result is
+    [B, T, H*v_width]. ``q`` is the absorbed query beside its rotated part,
+    as wide as a row."""
     B, T, H, hd = q.shape
+    latent = v_width is not None
+    if latent and (v_pool is not None or k_scale is not None):
+        raise ValueError("latent rows take no v_pool and no int8 scales")
     stacked = layer is not None
     if k_pool.ndim != 4 + stacked:
         raise ValueError(
@@ -550,6 +567,7 @@ def ragged_paged_attention(
         tile_pages=Tp, block_size=BS,
     )
 
+    hd_o = v_width if latent else hd  # the output block's width
     kernel = functools.partial(
         _ragged_kernel,
         sm_scale=sm_scale,
@@ -561,6 +579,7 @@ def ragged_paged_attention(
         tile_heads=Th,
         tile_pages=Tp,
         quantized=quantized,
+        v_width=v_width or 0,
     )
 
     # index maps take the grid indices (head group, step) and the
@@ -577,21 +596,25 @@ def ragged_paged_attention(
 
         return index
 
-    qo_spec = pl.BlockSpec(
-        (1, Th, bq, hd),
-        lambda h, s, seg_, *_: (seg_[s] // n_qblocks, h, seg_[s] % n_qblocks, 0),
-    )
+    def qo_spec(width):
+        return pl.BlockSpec(
+            (1, Th, bq, width),
+            lambda h, s, seg_, *_: (
+                seg_[s] // n_qblocks, h, seg_[s] % n_qblocks, 0),
+        )
+
     page_block = (None,) * stacked + (Th, 1, BS, hd)
     page_specs = [pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)]
+    pools = [k_pool] * Tp + ([] if latent else [v_pool] * Tp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=9 if quantized else 7,
         grid=(Hkv // Th, B * n_qblocks * n_tiles),
-        in_specs=[qo_spec] + page_specs + page_specs,
-        out_specs=qo_spec,
+        in_specs=[qo_spec(hd)] + page_specs * (1 if latent else 2),
+        out_specs=qo_spec(hd_o),
         scratch_shapes=[
             pltpu.VMEM((Th, bq, _LANES), jnp.float32),
             pltpu.VMEM((Th, bq, _LANES), jnp.float32),
-            pltpu.VMEM((Th, bq, hd), jnp.float32),
+            pltpu.VMEM((Th, bq, hd_o), jnp.float32),
         ] + [pltpu.VMEM((Th, Tp * BS, hd), q.dtype)] * (2 * quantized),
     )
     # pre-gather the per-page scales through the block tables OUTSIDE the
@@ -612,7 +635,7 @@ def ragged_paged_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, nqp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, nqp, hd_o), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # the steps walk the work list in order (a row's softmax state
             # lives across them); the head groups are independent
@@ -620,13 +643,14 @@ def ragged_paged_attention(
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(*work, off, win, lay, *scales, qT, *[k_pool] * Tp, *[v_pool] * Tp)
+    )(*work, off, win, lay, *scales, qT, *pools)
     # a (row, q block) with no item was never visited: its block of `out`
     # holds whatever the buffer held. Zero it (fused into the cut below)
     out = jnp.where(
         jnp.repeat(visited, bq, axis=1)[:, None, :, None], out, 0
     )
     # [B, Hkv, nqp, hd] -> [B, T, H*hd]
+    hd_q = v_width if latent else hd_q
     out = out[:, :, :nq, :hd_q].reshape(B, Hkv, G, T, hd_q).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, T, H * hd_q)
 
@@ -915,7 +939,24 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
             pool, new, jnp.asarray(block_tables, jnp.int32), off, lay, lim
         )
 
+    def latent(q, pool, _v, mask, cfg, positions=None, block_tables=None,
+               layer=None):
+        """attn's ABI over LATENT rows (core._mla_attention): ``q`` [B, T,
+        H, W] the absorbed query beside its rotated part, ``pool`` the
+        stacked [L, 1, NB, BS, W] latent pool, read in place at ``layer``;
+        no V. Every head reads the one row a token; -> [B, T, H*kv_rank].
+        A single device only: the engine refuses a mesh for such a model."""
+        if mesh_axes(q.shape[0], 1) is not None:
+            raise ValueError("the latent read is not partitioned over a mesh")
+        return ragged_paged_attention(
+            q, pool, None, block_tables,
+            positions[:, 0] if positions is not None else None, mask,
+            sm_scale=1.0 / math.sqrt(cfg.mla_nope_dim + cfg.mla_rope_dim),
+            interpret=interpret, layer=layer, v_width=cfg.mla_kv_rank,
+        )
+
     attn.write = write
+    attn.latent = latent
     attn.ragged = True
     return attn
 

@@ -654,6 +654,158 @@ def _in_place_case(args, window) -> dict:
     }
 
 
+def _latent_case(B: int, T: int, MB: int, ctx: int) -> dict:
+    """JoyAI-LLM-Flash's latent page-write + read at the published shapes
+    (32 heads over ONE 576-wide row a token, stored in 640 lanes, values its
+    first 512 columns) on a stacked pool of two layers, compiled: the write
+    against XLA's scatter (bit for bit), the read against the dense path over
+    the gathered rows (core._latent_attention), microseconds a call each."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.models import core
+    from bee2bee_tpu.models.config import get_config
+    from bee2bee_tpu.ops.ragged import paged_kv_write, ragged_paged_attention
+
+    cfg = get_config("joyai-llm-flash-5l")
+    W, R, H, BS = cfg.latent_width, cfg.mla_kv_rank, cfg.n_heads, KERNEL_BLOCK
+    rng = np.random.default_rng(SEED)
+    lens = np.minimum(rng.integers(ctx // 2, ctx + 1, size=B), MB * BS - T).astype(np.int32)
+    off = jnp.asarray(lens)
+    need = -(-(lens + T) // BS)
+    tables = np.zeros((B, MB), np.int32)
+    nxt = 1
+    for b in range(B):
+        tables[b, :need[b]] = np.arange(nxt, nxt + need[b])
+        nxt += need[b]
+    NB = nxt + 1
+    tables = jnp.asarray(tables)
+    ks = jax.random.split(jax.random.key(SEED), 3)
+    rows = jax.random.normal(ks[0], (1, NB, BS, W), jnp.bfloat16)
+    q = (jax.random.normal(ks[1], (B, T, H, W), jnp.float32) * 0.2).astype(jnp.bfloat16)
+    new = jax.random.normal(ks[2], (B, T, 1, W), jnp.bfloat16)
+    sm = 1.0 / (cfg.mla_nope_dim + cfg.mla_rope_dim) ** 0.5
+    positions = off[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    blk = jnp.take_along_axis(tables, positions // BS, axis=1)
+    want_rows = rows.at[:, blk, positions % BS].set(jnp.transpose(new, (2, 0, 1, 3)))
+    stacked = jnp.pad(jnp.stack([rows * 0, rows]), ((0, 0),) * 4 + ((0, -W % 128),))
+    write = jax.jit(
+        lambda pool, new: paged_kv_write(
+            pool, new, tables, off, jnp.int32(1), interpret=False),
+        donate_argnums=(0,)).lower(stacked, new).compile()
+    stacked = write(stacked, new)
+    got_rows = np.asarray(stacked[1, ..., :W], np.float32)
+    wrote = bool(np.array_equal(got_rows[:, 1:], np.asarray(want_rows, np.float32)[:, 1:])
+                 and not np.asarray(stacked[0], np.float32).any()
+                 and not np.asarray(stacked[1, ..., W:], np.float32).any())
+
+    def clock(fn, *args):
+        out = fn(*args)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(KERNEL_TIMED_CALLS):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
+
+    t0 = time.perf_counter()
+    for _ in range(KERNEL_TIMED_CALLS):
+        stacked = write(stacked, new)
+    stacked.block_until_ready()
+    write_us = (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
+    lowered = jax.jit(lambda q, pool: ragged_paged_attention(
+        q, pool, None, tables, off, sm_scale=sm, interpret=False,
+        layer=jnp.int32(1), v_width=R)).lower(q, stacked)
+    has_kernel = "tpu_custom_call" in lowered.as_text()
+    got, read_us = clock(lowered.compile(), q, stacked)
+
+    def dense(q, pool):
+        lat = pool[1, 0][tables].reshape(B, MB * BS, -1)[..., :W]
+        mask = jnp.arange(MB * BS)[None, None, :] <= positions[:, :, None]
+        return core._latent_attention(q, lat, mask[:, None], R, sm).reshape(B, T, H * R)
+
+    want, dense_us = clock(jax.jit(dense), q, stacked)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    diff = np.abs(got - want)
+    worst = float(np.max(diff / (KERNEL_ATOL + KERNEL_RTOL * np.abs(want))))
+    live_rows = int((lens + T).sum())
+    return {
+        "B": B, "T": T, "H": H, "row": W, "values": R, "table_width": MB,
+        "live_rows": live_rows, "tpu_custom_call_in_lowered_text": has_kernel,
+        "write_matches_scatter": wrote, "write_us_per_call": round(write_us, 1),
+        "worst_diff_over_tolerance": worst, "max_abs_diff_vs_dense": float(diff.max()),
+        "us_per_call": round(read_us, 1), "dense_us_per_call": round(dense_us, 1),
+        "published_row_GBs": round(live_rows * W * 2 / read_us / 1e3, 1),
+        "ok": bool(has_kernel and wrote and np.isfinite(got).all() and worst <= 1.0),
+    }
+
+
+def _grouped_case(tokens: int, device_kind: str, layers: int = 4) -> dict:
+    """The dropless expert layer's grouped product (ops/grouped.py) at the
+    published shapes: ``tokens`` x 8 sorted assignments over 256 experts of
+    2048 x 768, the experts a STACK of ``layers`` layers read in place at layer
+    2, compiled, against a per-row dense product of the gathered matrices;
+    then a scan over the layers as core.forward runs it, microseconds a matrix
+    a layer and the share of reading the touched experts once at the chip's
+    peak."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.ops.grouped import grouped_matmul
+
+    peaks = json.loads((ROOT / "benchmark" / "peaks.json").read_text())
+    hbm = peaks[device_kind]["hbm_bytes_per_s"]
+    E, D, F, K = 256, 2048, 768, 8
+    rng = np.random.default_rng(SEED + tokens)
+    flat = np.concatenate([rng.choice(E, K, replace=False) for _ in range(tokens)])
+    gs = np.bincount(flat, minlength=E).astype(np.int32)
+    M, touched = tokens * K, int((gs > 0).sum())
+    w = jax.random.normal(jax.random.key(SEED), (layers, E, D, F), jnp.bfloat16) * 0.02
+    x = jax.random.normal(jax.random.key(SEED + 1), (M, D), jnp.bfloat16)
+    gsd = jnp.asarray(gs)
+
+    def product(x, w, layer):
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((layers * E,), jnp.int32), gsd, (layer * E,))
+        return grouped_matmul(x, w.reshape(layers * E, D, F), sizes, interpret=False)
+
+    lowered = jax.jit(product).lower(x, w, jnp.int32(2))
+    has_kernel = "tpu_custom_call" in lowered.as_text()
+    got = np.asarray(lowered.compile()(x, w, jnp.int32(2)), np.float32)
+    eid = np.repeat(np.arange(E), gs)
+    worst, step = 0.0, 256  # the dense side in blocks: a gathered matrix a row
+    for s in range(0, min(M, 1024), step):
+        want = np.asarray(jnp.einsum(
+            "md,mdf->mf", x[s:s + step], w[2][eid[s:s + step]],
+            preferred_element_type=jnp.float32))
+        d = np.abs(got[s:s + step] - want)
+        worst = max(worst, float(np.max(d / (KERNEL_ATOL + KERNEL_RTOL * np.abs(want)))))
+
+    def every_layer(x, w):
+        def one(acc, layer):
+            return acc + product(x, w, layer)[:, :8].astype(jnp.float32).sum(), None
+        return jax.lax.scan(one, jnp.float32(0), jnp.arange(layers, dtype=jnp.int32))[0]
+
+    fn = jax.jit(every_layer)
+    jax.block_until_ready(fn(x, w))
+    t0 = time.perf_counter()
+    for _ in range(KERNEL_TIMED_CALLS):
+        out = fn(x, w)
+    jax.block_until_ready(out)
+    us = (time.perf_counter() - t0) / (KERNEL_TIMED_CALLS * layers) * 1e6
+    floor_us = touched * D * F * 2 / hbm * 1e6
+    return {
+        "assignments": M, "experts": E, "touched": touched, "K": D, "N": F,
+        "tpu_custom_call_in_lowered_text": has_kernel,
+        "worst_diff_over_tolerance": worst, "us_per_matrix_per_layer": round(us, 1),
+        "touched_once_at_peak_us": round(floor_us, 1),
+        "share_of_hbm_peak": round(floor_us / us, 4),
+        "ok": bool(has_kernel and np.isfinite(got).all() and worst <= 1.0),
+    }
+
+
 def _state_step_case(device_kind: str, rows: int = 64, layers: int = 6) -> dict:
     """falcon-h1's one-step state kernel (ops/ssm_step.py) at the wide cell's
     shape: ``rows`` x 32 heads x [128, 256] float32 a layer, a stack of
@@ -765,6 +917,12 @@ def child_kernel() -> None:
                   "tolerance": f"|d| <= {KERNEL_ATOL} + {KERNEL_RTOL}*|dense|",
                   "cases": {n: _kernel_case(n, c, rng) for n, c in KERNEL_CASES.items()}}
     line["cases"]["h1_state_step"] = _state_step_case(dev.device_kind)
+    # JoyAI-LLM-Flash (PR 39): the latent pool's kernels, the grouped product
+    line["cases"]["joyai_latent_decode"] = _latent_case(64, 1, 64, 700)
+    line["cases"]["joyai_latent_decode_table8"] = _latent_case(64, 1, 8, 120)
+    line["cases"]["joyai_latent_prefill512"] = _latent_case(1, 512, 32, 0)
+    line["cases"]["joyai_grouped_512"] = _grouped_case(64, dev.device_kind)
+    line["cases"]["joyai_grouped_4096"] = _grouped_case(512, dev.device_kind)
     line["ok"] = all(c["ok"] for c in line["cases"].values())
     line["elapsed_s"] = elapsed()
     emit(line)

@@ -213,3 +213,62 @@ def test_recurrent_model_serves_with_int8_kv_and_int8_weights():
     r = _rollout(InferenceEngine("tiny-falcon-h1", engine_config=EngineConfig(
         **{**KW, "cache_dtype": "int8"})))
     assert len(r) == 8
+
+
+# ---- a latent-attention model (JoyAI-LLM-Flash): what is not proven over a
+# LATENT pool (one [c_kv | k_rope] row a token, no per-head K/V) is refused by
+# name when the engine (stage runner, drafter) is BUILT. The config-only
+# refusals live in tests/test_joyai.py::REFUSED.
+
+
+def _latent_refused(feature, build):
+    from bee2bee_tpu.engine import LatentPoolUnsupported
+
+    with pytest.raises(LatentPoolUnsupported) as err:
+        build()
+    assert err.value.feature == feature and "tiny-joyai" in str(err.value)
+
+
+@pytest.mark.parametrize("feature,mesh,over", [
+    ("mesh_model", MeshSpec(model=2), {}),
+    ("seq_attention", MeshSpec(seq=2), {"attention": "sp"}),
+    ("seq_attention", MeshSpec(seq=2), {}),
+    ("mesh_expert", MeshSpec(expert=2), {}),
+])
+def test_latent_model_refuses_mesh_axes_it_is_not_partitioned_over(feature, mesh, over):
+    _latent_refused(feature, lambda: InferenceEngine(
+        "tiny-joyai", mesh=build_mesh(mesh), engine_config=EngineConfig(**over, **KW)))
+
+
+@pytest.mark.parametrize("feature,over", [
+    ("kv_int8", {"cache_dtype": "int8"}),
+    ("weight_int8", {"quantize": "int8"}),
+    ("spec_ngram", {"spec_tokens": 4}),
+    ("spec_model_drafter", {"spec_tokens": 4, "drafter": "tiny-llama"}),
+    ("multi_lora", {"max_adapters": 2}),
+    ("prefix_cache", {"prefix_cache_entries": 4}),
+])
+def test_latent_model_refuses_config_features_by_name(feature, over):
+    _latent_refused(feature, lambda: InferenceEngine(
+        "tiny-joyai", engine_config=EngineConfig(**{**KW, **over})))
+
+
+def test_latent_model_refuses_pipeline_stages_and_the_drafter_seat():
+    from bee2bee_tpu.engine.drafter import DraftModel
+    from bee2bee_tpu.engine.stage_runner import StageRunner
+
+    _latent_refused("pipeline_stages", lambda: StageRunner(
+        "tiny-joyai", n_stages=3, stage=0, max_seq_len=64, dtype="float32"))
+    _latent_refused("spec_model_drafter", lambda: DraftModel(
+        "tiny-joyai", spec_tokens=4, batch=2, target_max_seq_len=64, dtype="float32"))
+
+
+def test_latent_model_serves_with_chunked_prefill_penalties_and_the_ragged_reader():
+    """What does not look inside a page keeps working over the latent pool."""
+    want = _rollout(InferenceEngine("tiny-joyai", engine_config=EngineConfig(**KW)))
+    got = _rollout(InferenceEngine("tiny-joyai", engine_config=EngineConfig(
+        prefill_chunk=16, **KW)))
+    assert got == want and len(want) == 8
+    flash = _rollout(InferenceEngine("tiny-joyai", engine_config=EngineConfig(
+        **{**KW, "attention": "flash"})))
+    assert len(flash) == 8

@@ -41,6 +41,13 @@ ALLOWED_ABSENT = {
     "engine.ssm_step_rows": "no recurrent state: the boot's model has no mixer",
     "engine.ssm_step_kernel_calls": "no recurrent state: the boot's model has no mixer",
     "engine.ssm_scan_tokens": "no recurrent state: the boot's model has no mixer",
+    # sigmoid-routed expert models only (JoyAI-LLM-Flash: tests/test_joyai.py
+    # reads them); tiny-llama has no expert layer
+    "engine.moe_assignments": "no dropless expert layer in the boot's model",
+    "engine.moe_layer_calls": "no dropless expert layer in the boot's model",
+    "engine.moe_experts_hit": "no dropless expert layer in the boot's model",
+    "engine.moe_expert_load_max": "no dropless expert layer in the boot's model",
+    "engine.latent_tokens_read": "no latent attention in the boot's model",
     # CPU test backend: device.memory_stats() is None and no
     # BEE2BEE_HBM_BYTES budget is set, so headroom cannot compute
     "engine.hbm_headroom_frac": "no device memory stats on CPU",
@@ -257,3 +264,18 @@ async def test_full_surface_scrape_matches_catalog():
     assert not missing, (
         f"economics-plane families absent after a generation: {missing}"
     )
+
+
+def test_every_device_trace_scope_the_model_opens_is_documented():
+    """The ``jax.named_scope``s of models/core.py (``ssm.*``, ``kv.write``,
+    ``mla.*``, ``moe.*``) are what the benchmark's scope readers book device
+    time by: each one opened in the source is named in the document."""
+    import re
+
+    src = (DOC.parent.parent / "bee2bee_tpu" / "models" / "core.py").read_text()
+    opened = set(re.findall(r'named_scope\("([a-z_]+\.[a-z_]+)"\)', src))
+    assert {"mla.q_proj", "mla.kv_proj", "mla.write", "mla.read", "mla.out",
+            "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+            "ssm.step", "kv.write"} <= opened
+    doc = DOC.read_text()
+    assert not sorted(s for s in opened if f"`{s}`" not in doc)

@@ -305,7 +305,9 @@ def test_int8_import_validation_and_signature_gate():
     a = _engine(cache_dtype="int8")
     b = _engine()  # the full-precision pool (float32 on the CPU suite)
     try:
-        snap, kv, _req = _checkpoint_mid_decode(a)
+        # 64 tokens, not the helper's 24: nothing here compares a rollout, and a
+        # 24-token request can retire before checkpoint() on a loaded machine
+        snap, kv, _req = _checkpoint_mid_decode(a, max_new_tokens=64)
         no_scales = {name: kv[name] for name in ("k", "v")}
         with pytest.raises(ValueError, match="kv tensors"):
             a.import_generation(dict(snap), no_scales)
@@ -317,7 +319,7 @@ def test_int8_import_validation_and_signature_gate():
         # withheld → b re-prefills prompt+accepted at ITS precision and
         # decodes on (the continuation may legitimately differ from a's
         # int8-pool rollout — the accepted prefix is what must survive)
-        snap2, _kv2, _ = _checkpoint_mid_decode(a)
+        snap2, _kv2, _ = _checkpoint_mid_decode(a, max_new_tokens=64)
         req2 = b.import_generation(dict(snap2))
         out, _result = _drain_events(req2, snap2["out"])
         assert out[:len(snap2["out"])] == snap2["out"]

@@ -669,6 +669,12 @@ PAGE_WRITE_CASES = {
     # a lane-aligned pool (core.init_paged_pool): 96 stored in 128 lanes
     "mha-x96-in-128-lanes": dict(offs=[3, 47], T=7, Hkv=8, hd=96, lanes=128),
     "decode-x64-in-128-lanes": dict(offs=[5, 16, 31, 100], T=1, hd=64, lanes=128),
+    # the latent pool (MLA): ONE 576-wide row a token on a unit head axis,
+    # stored in 640 lanes; every other layer's slice keeps its bits
+    "latent-x576-in-640-lanes": dict(
+        offs=[5, 16, 31, 100], T=1, Hkv=1, hd=576, lanes=640, dtype=jnp.bfloat16),
+    "latent-x576-prefill-chunk": dict(
+        offs=[37], T=64, ceil=37 + 50, Hkv=1, hd=576, lanes=640, dtype=jnp.bfloat16),
 }
 
 
@@ -1000,3 +1006,39 @@ def test_flash_engine_spec_parity_sequential():
         assert st.spec_drafted > 0 and st.spec_steps > 0
     finally:
         eng.close()
+
+
+# ---------------- latent rows (PR 39, MLA): one fetched tile, keys AND values
+
+
+@pytest.mark.parametrize("T,offs,stacked,dims", [
+    (1, [0, 7, 8, 30], False, (40, 24, 5)), (1, [5, 0, 21], True, (40, 24, 5)),
+    (13, [0, 9], True, (40, 24, 5)), (7, [16, 3], False, (40, 24, 5)),
+    (1, [5, 41], True, (576, 512, 32)),
+], ids=["decode", "decode-stacked", "prefill-chunk-stacked", "spec-shape",
+        "decode-stacked-576-wide-32-heads"])
+def test_latent_read_matches_the_dense_path(T, offs, stacked, dims):
+    """The latent read against core._latent_attention over the gathered rows,
+    at widths off the 128 lanes (row 40 = 24 + 16, values its first 24
+    columns), 5 heads sharing each row, rows of unequal length, null-block
+    table tails; on one layer's slice and on the stacked pool; and at the
+    published widths (576-wide rows, values the first 512, 32 heads)."""
+    (W, R, H), BS = dims, 8
+    q, kp, _, tables, offs_, mask, kg, _ = _pool_case(
+        offs, T, H, 1, W, BS=BS, extra_tables=2, seed=11)
+    scale = 0.21
+    want = core._latent_attention(q, kg[:, :, 0], mask[:, None], R, scale)
+    if stacked:
+        pool = jnp.stack([jnp.zeros_like(kp), kp, jnp.ones_like(kp)])
+        got = ragged_paged_attention(q, pool, None, tables, offs_, sm_scale=scale,
+                                     v_width=R, layer=jnp.int32(1))
+    else:
+        got = ragged_paged_attention(q, kp, None, tables, offs_, sm_scale=scale, v_width=R)
+    assert got.shape == (len(offs), T, H * R)
+    _assert_close(got.reshape(want.shape), want)
+
+
+def test_latent_read_takes_no_v_pool_and_no_scales():
+    q, kp, vp, tables, offs_, *_ = _pool_case([3], 1, 2, 1, 16)
+    with pytest.raises(ValueError, match="latent rows"):
+        ragged_paged_attention(q, kp, vp, tables, offs_, v_width=8)

@@ -325,7 +325,7 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
     stored = whole if mesh is None else NamedSharding(
         mesh, partition.paged_cache_spec(cfg, mesh))
     params = place(params, mesh and partition.param_shardings(params, mesh, cfg))
-    pool = place(pool, {"k": stored, "v": stored})
+    pool = place(pool, {name: stored for name in pool})  # K and V, or latent rows
     if cfg.has_ssm:  # the rows' recurrent state rides the same carry
         pool = dict(pool, **place(
             jax.eval_shape(lambda: core.init_ssm_state(cfg, B, jnp.float32))))
@@ -342,7 +342,8 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
     lowered = jax.jit(step, donate_argnums=(2,)).lower(
         params, ints(B, T), pool, ints(B), ints(B, MB), ints())
     shard = mesh.shape["model"] if mesh is not None else 1
-    return lowered, int(np.prod(pool["k"].shape[1:])) // shard
+    leaf = pool[next(iter(core.pool_layout(cfg)))]
+    return lowered, int(np.prod(leaf.shape[1:])) // shard
 
 
 # the cells' shapes, two layers deep (B, T, table width, pool blocks: one
@@ -452,3 +453,152 @@ def test_forward_steps_the_state_in_place(one_chip, mosaic_state_step):
     analysis = compiled.memory_analysis()
     assert analysis.alias_size_in_bytes >= 2 * state_elems * 4
     assert analysis.temp_size_in_bytes <= H1_DECODE_TEMP_BYTES_BEFORE
+
+
+# ------- JoyAI-LLM-Flash (PR 39): the latent pool's two kernels and the
+# dropless expert layer's grouped products, at the published shapes
+
+
+@pytest.fixture
+def mosaic_grouped(monkeypatch):
+    """ops/grouped.py asks the DEFAULT backend whether to run interpreted (the
+    CPU here): steer it, so the compiled program holds the chip's kernel."""
+    monkeypatch.setattr(
+        "bee2bee_tpu.ops.grouped.interpret_off_tpu", lambda mesh=None: False)
+
+
+JOYAI = get_config("joyai-llm-flash-5l")
+LATENT_CASES = {
+    # (B, T, table width): the cell's decode step at its widest table, a
+    # narrow one, and the largest prefill bucket its prompts reach
+    "decode-64-rows": (64, 1, 64),
+    "decode-table-8": (64, 1, 8),
+    "prefill-512": (1, 512, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_write_and_read_compile_for_v5e(one_chip, case):
+    """One latent row a token (576 stored in 640 lanes), a unit axis for heads:
+    the page-write stores it in place (aliased), the read fetches a page tile
+    ONCE — one pool operand a table entry, no V operands — and returns the 32
+    heads' 512-wide latent outputs."""
+    B, T, MB = LATENT_CASES[case]
+    W, R, H = JOYAI.latent_width, JOYAI.mla_kv_rank, JOYAI.n_heads
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((2, 1, 3201, BS, 640), jnp.bfloat16)
+    tables, offs, lay = sds((B, MB), jnp.int32), sds((B,), jnp.int32), sds((), jnp.int32)
+    wrote = jax.jit(
+        lambda pool, new, t, o, lay: paged_kv_write(
+            pool, new, t, o, lay, 0, 2000, interpret=False),
+        donate_argnums=(0,),
+    ).lower(pool, sds((B, T, 1, W), jnp.bfloat16), tables, offs, lay).compile()
+    assert "tpu_custom_call" in wrote.as_text()
+    assert wrote.memory_analysis().alias_size_in_bytes > 0
+    read = jax.jit(
+        lambda q, pool, t, o, lay: ragged_paged_attention(
+            q, pool, None, t, o, interpret=False, layer=lay, v_width=R,
+            sm_scale=192 ** -0.5)
+    ).lower(sds((B, T, H, W), jnp.bfloat16), pool, tables, offs, lay).compile()
+    text = read.as_text()
+    assert "tpu_custom_call" in text
+    assert f"bf16[{B},{T},{H * R}]" in text.replace(" ", "")  # no second copy for values
+    assert read.memory_analysis().temp_size_in_bytes < 2 * 3201 * BS * 640 * 2
+
+
+@pytest.mark.parametrize("tokens", [64, 512])
+def test_dropless_expert_layer_compiles_for_v5e(one_chip, mosaic_grouped, tokens):
+    """512 and 4,096 assignments over 256 experts of 2048 x 768: three Mosaic
+    grouped products over the STACKED experts of four layers, read in place
+    (no instruction produces an array of a layer's expert matrix's size: no
+    slice of the stack, no re-laid copy)."""
+    cfg = JOYAI
+    shapes = jax.eval_shape(
+        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))["layers"]["moe"]
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    names = ("w_gate", "w_up", "w_down")
+    experts = place({n: shapes[n] for n in names})
+    rest = place(jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+        {n: a for n, a in shapes.items() if n not in names}))
+    x = jax.ShapeDtypeStruct((tokens, 1, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    lay = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x, rest, experts, lay: core._moe_dropless(
+            x, rest, cfg, experts=experts, layer=lay)
+    ).lower(x, rest, experts, lay).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_expert_layers) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix * 2 // 4
+
+
+def test_the_balancing_pass_compiles_for_v5e_beside_the_weights(one_chip, mosaic_grouped):
+    """core.balance_router_bias at the published widths (32 rows x 256 tokens,
+    four passes): its temporaries fit beside the 11.1 GB of weights on a
+    15.75 GB chip, the expert stacks are read in place by the three grouped
+    products, and all that leaves is the [4, 256] float32 bias."""
+    cfg = JOYAI
+    shapes = jax.eval_shape(
+        lambda: jax.jit(core._init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(jnp.bfloat16)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(core.balance_router_bias, static_argnums=1).lower(args, cfg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_expert_layers) == []
+    analysis = compiled.memory_analysis()
+    assert analysis.output_size_in_bytes == cfg.n_expert_layers * cfg.n_experts * 4
+    assert analysis.argument_size_in_bytes + analysis.temp_size_in_bytes < 13.0e9
+
+
+@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (1, 128, 8)])
+def test_forward_keeps_the_latent_pool_and_the_expert_stacks_in_place(
+        one_chip, mosaic_grouped, B, T, MB):
+    """joyai-llm-flash cut to the dense layer + two expert layers: the pool is
+    touched by the page-write and the read alone (one call each a group of
+    like layers), the expert stacks by the three grouped products alone; no
+    instruction produces a pool slice, the pool, or an expert matrix stack."""
+    cfg = dataclasses.replace(JOYAI, n_layers=3)
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, 3201, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("while(") >= 2, "one layer loop a group of like layers"
+    assert text.count("tpu_custom_call") >= 7  # 2 writes, 2 reads, 3 grouped products
+    assert _pool_sized_ops(text, slice_elems, 3) == []
+    one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, 2) == []
+    analysis = compiled.memory_analysis()
+    assert analysis.alias_size_in_bytes >= 3 * slice_elems * 2  # the pool in place
+    assert analysis.temp_size_in_bytes < one_matrix * 2 // 4
+
+
+@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (1, 512, 32)],
+                         ids=["joyai-decode", "joyai-prefill-512"])
+def test_the_cell_programs_fit_one_chip_at_full_depth(one_chip, mosaic_grouped, B, T, MB):
+    """joyai-llm-flash-5l as the cell serves it (5 layers, 256 experts, 3,200
+    pool blocks): the decode step at 64 rows and the largest prefill bucket
+    hold both latent custom calls under their scopes, alias the pool in
+    place, make no expert-stack-sized array and stay under the chip's 15.75
+    GB (arguments + temporaries + what the outputs add beyond the alias)."""
+    lowered, slice_elems = _forward_program(JOYAI, B, T, MB, 3200, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert re.search(r"mla\.write[\w.]* = .*tpu_custom_call", text), "the page-write under its scope"
+    assert re.search(r"mla\.read[\w.]* = .*tpu_custom_call", text), "the read under its scope"
+    one_matrix = JOYAI.n_experts * JOYAI.d_model * JOYAI.expert_ff
+    assert _pool_sized_ops(text, one_matrix, JOYAI.n_expert_layers) == []
+    assert _pool_sized_ops(text, slice_elems, JOYAI.n_layers) == []
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= JOYAI.n_layers * slice_elems * 2  # the pool in place
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 11.0e9 < total < 15.75e9, total
+    assert m.temp_size_in_bytes < one_matrix * 2 // 4
