@@ -62,6 +62,10 @@ _HOT_LOOP_FNS = {
     "_process_window",
     "_drain_inflight",
     "_process_row_tokens",
+    "_settle_window",
+    "_settle_row",
+    "_deliver_pending",
+    "_deliver_row",
 }
 
 

@@ -1202,7 +1202,8 @@ async def _stream_service(
     record = current_timing()
     with get_tracer().span("gen.local", service=svc.name, stream=True) as span:
         ctx = contextvars.copy_context()
-        task = loop.run_in_executor(svc.pump_executor(), ctx.run, pump)
+        task = loop.run_in_executor(
+            svc.pump_executor(node.admission.config.max_concurrent), ctx.run, pump)
         chunks = 0
         text_chars = 0
         t0 = time.perf_counter()

@@ -118,6 +118,12 @@ _C_KV_PAGES_VISITED = _REG.counter(
     "block-table entries handed to attention: batch rows x table width x "
     "the dispatched window's attention calls a layer",
 )
+_C_WINDOW_DELIVERIES = _REG.counter(
+    "engine.window_deliveries",
+    "settled windows whose tokens were delivered to their requests (kind "
+    "label: under_device_work = an admission burst or a decode window was "
+    "in flight meanwhile | exposed = the chip had nothing to run)",
+)
 _C_KV_PAGES_LIVE = _REG.counter(
     "engine.kv_pages_live",
     "of engine.kv_pages_visited, the entries that map a row's own block "
@@ -497,6 +503,12 @@ class BatchScheduler:
         # buffers, and its own (row, request) map — row bookkeeping may
         # drift (retirement nulls _rows[b]) between dispatch and fetch.
         self._inflight: deque = deque()
+        # settled, not yet delivered: one deque of (request, accepted
+        # tokens, ended) a fetched window, oldest first. _settle_row has
+        # already done what the SCHEDULER needs of those tokens (out_ids,
+        # finish, the row freed); what the CALLERS need (stream events, the
+        # done event) waits here until the chip has its next work
+        self._undelivered: deque = deque()
         # an expert model's prefill counters (device arrays) waiting for the
         # next window's fetch
         self._moe_pending: list = []
@@ -791,7 +803,8 @@ class BatchScheduler:
         while True:
             with self._cond:
                 while (not self._queue and self.active == 0
-                       and not self._checkpoints and not self._shutdown):
+                       and not self._checkpoints and not self._undelivered
+                       and not self._shutdown):
                     self._cond.wait()
                 if self._shutdown:
                     self._fail_all("engine shut down")
@@ -803,7 +816,15 @@ class BatchScheduler:
                     if self._drain_inflight():
                         self._compact_and_shrink()
                 self._service_checkpoints()
-                self._admit()
+                # _admit delivers the last settled window itself, whenever
+                # it has nobody to admit; what it left (it may stop early)
+                # is delivered here, before the next dispatch. Again while
+                # it places a burst: who arrived while the firsts were
+                # gathered (the callers of the rows just delivered) gets a
+                # free row now, not a window later
+                while self._admit():
+                    pass
+                self._deliver_pending()
                 if self.active or self._inflight:
                     self._step()
             except Exception as e:  # noqa: BLE001 — the thread must survive:
@@ -838,6 +859,14 @@ class BatchScheduler:
         self._moe_pending = []
         _G_OVERLAP.set(0)
         self.cache.flush_deferred()
+        # a row that settle ended is in neither _queue nor _rows: its tokens
+        # and its done event are still owed (a delivery that raises fails
+        # its own request, so every pass makes progress)
+        while self._undelivered:
+            try:
+                self._deliver_pending()
+            except Exception:  # noqa: BLE001
+                logger.exception("delivery failed while failing all requests")
         for req in list(self._queue) + [r for r in self._rows if r is not None]:
             self._fail(req, reason)
         self._queue.clear()
@@ -912,6 +941,7 @@ class BatchScheduler:
             if not self._checkpoints:
                 return
             pending, self._checkpoints = self._checkpoints, []
+        self._deliver_pending()  # a snapshot reads delivered state
         for req, done in pending:
             snap = None
             try:
@@ -1286,20 +1316,32 @@ class BatchScheduler:
             )
 
     @_phase("admit")
-    def _admit(self):
+    def _admit(self) -> bool:
         """Prefill queued requests into free rows, growing the batch bucket
-        up to max_batch. All prefills/inserts of an admission burst are
+        up to max_batch; True when a burst was placed. All prefills/inserts of an admission burst are
         dispatched asynchronously; the first tokens come back in ONE device
         sync (its cost is not measured on the current machine — a burst of
-        8 must not pay it 8 times while active streams sit undecoded)."""
+        8 must not pay it 8 times while active streams sit undecoded).
+        Whenever the queue has nobody for a free row, the last settled
+        window is delivered (_deliver_next): under the prefills already
+        dispatched, and bringing the requests that follow the ended ones."""
         e = self.engine
         placed: list[tuple] = []  # (req, row, firsts_index)
         firsts: list = []
         while True:
             with self._cond:
-                if not self._queue or self.active >= self.max_batch:
-                    break
-                req = self._queue.popleft()
+                req = (self._queue.popleft()
+                       if self._queue and self.active < self.max_batch
+                       else None)
+            if req is None:
+                # nobody to admit right now. A settled window's rows are
+                # delivered one at a time meanwhile, the queue looked at
+                # again after each: a caller in a closed loop sends its
+                # next request once its done event is out, and that
+                # request's prefill is then the next thing the chip gets
+                if self._deliver_next(burst=bool(placed)):
+                    continue
+                break
             if req.cancelled:
                 req.finish = "cancelled"
                 req.timing.t_first = req.timing.t_done = time.perf_counter()
@@ -1445,7 +1487,8 @@ class BatchScheduler:
             firsts.append(first)
 
         if not placed:
-            return
+            return False
+        self._deliver_pending(burst=True)
         # ONE blocking gather for the whole burst (device_get on the list
         # fetches all; no eager concatenate op on device)
         toks = np.concatenate([np.asarray(x) for x in jax.device_get(firsts)])
@@ -1471,7 +1514,7 @@ class BatchScheduler:
                 # for streaming consumers; generate() reads the done event
                 req.emit([tok])
             if req.done:  # instant stop/zero-budget: free the row again
-                self._release_row(b)
+                self._vacate(b, req)
                 self._retire(req)
                 continue
             if req.penalized and self._counts is not None:
@@ -1509,6 +1552,7 @@ class BatchScheduler:
                     self.stats.migrated_out += 1
                     self.stats.prefill_handoffs += 1
         self._compact_and_shrink()
+        return True
 
     def _row_sampling_arrays(self):
         if self._row_params_dirty or self._temps is None:
@@ -1915,7 +1959,7 @@ class BatchScheduler:
                 self._meter.note_spec(tier, drafted_here, a)
             # accepted draft prefix, then the verify's own next token
             retired = self._process_row_tokens(
-                b, req, list(drafts[b, :a]) + [nxt[b]]
+                b, req, np.append(drafts[b, :a], nxt[b])
             )
             retired_any |= retired
             if drafted_here and not retired:
@@ -1928,6 +1972,8 @@ class BatchScheduler:
                 if drafter is not None:
                     drafter.observe(req, a)
                 self._spec_tier_check(req)
+        # a spec step is serialized: nothing ran while its rows were delivered
+        _C_WINDOW_DELIVERIES.inc(kind="exposed")
         if retired_any:
             self._compact_and_shrink()
         return True
@@ -1953,41 +1999,102 @@ class BatchScheduler:
         ]
         return sum(depths) / len(depths) if depths else 0.0
 
-    @_phase("process")
     def _process_row_tokens(self, b: int, req: Request, tokens) -> bool:
         """THE per-row token-intake protocol, shared by the decode-window
         and spec-step paths (a retirement/streaming semantics change must
-        hit both identically): mark cancellation, accept tokens until the
-        request finishes, emit the stream event, retire a done row.
+        hit both identically), in its two halves: _settle_row is what the
+        SCHEDULER needs of the tokens, _deliver_row what the CALLER needs.
+        Here they run back to back (a spec step is serialized); a decode
+        window settles all its rows first (_settle_window) and is delivered
+        once the chip has its next work (_deliver_pending).
         Returns True when the row retired."""
+        with self._phases.phase("settle"):
+            entry = self._settle_row(b, req, tokens)
+        with self._phases.phase("process"):
+            self._deliver_row(entry)
+        return entry[2]
+
+    def _settle_row(self, b: int, req: Request, tokens: np.ndarray) -> tuple:
+        """The scheduler's half of the intake: which of row b's sampled
+        ``tokens`` (host, [n]) its request keeps and whether it ended —
+        Request.accept's rule over the whole row at once, no per-token
+        Python: a stop token is not kept, a budget reached on a token keeps
+        that token, what follows the cut is waste, a cancelled row keeps
+        nothing. out_ids and finish are final on return and an ended row is
+        free for the next admission.
+        -> (req, kept tokens, ended): what _deliver_row is owed."""
         if req.cancelled and not req.done:
             req.finish = "cancelled"
-        emitted: list[int] = []
-        for t in tokens:
-            if not req.accept(int(t)):
-                break
-            emitted.append(int(t))
-            if req.done:  # budget exhausted exactly on this token
-                break
+        kept: list[int] = []
+        if req.finish is None:
+            budget = req.max_new_tokens - len(req.out_ids)
+            head = tokens[:max(budget, 0)]
+            stops = np.fromiter(req.stop, np.int64, len(req.stop))
+            hits = np.flatnonzero((head[:, None] == stops).any(axis=1))
+            if hits.size:
+                cut = int(hits[0])
+                req.finish = "eos" if int(head[cut]) == req.eos else "stop"
+                head = head[:cut]
+            elif len(head) >= budget:
+                req.finish = "length"  # exhausted by head's last token
+            kept = head.tolist()  # meshlint: ignore[ML-J003] -- a host array: the window's one fetch brought it
+            req.out_ids.extend(kept)
+        if req.done:
+            self._vacate(b, req)
+        return req, kept, req.done
+
+    def _deliver_row(self, entry: tuple):
+        """The caller's half: the stream event for the tokens its request
+        kept, then, for an ended one, the done event."""
+        req, kept, ended = entry
         # goodput accounting: only tokens ACCEPTED into an output are
         # useful — post-EOS overshoot, rejected draft positions and
         # cancelled-row tokens all stay scheduled-only
-        self._meter.note_useful(len(emitted))
-        if emitted and req.stream:
-            req.emit(emitted)
-        if req.done:
-            self._release_row(b)
+        self._meter.note_useful(len(kept))
+        if kept and req.stream:
+            req.emit(kept)
+        if ended:
             self._retire(req)
-            return True
-        return False
+
+    def _deliver_next(self, burst: bool = False) -> bool:
+        """Deliver the oldest settled row still owed (False: none is).
+        Oldest first, so a request's token events keep their order and
+        precede its done event. ``burst``: an admission burst is in flight.
+        A delivery that raises fails its own request (it is in neither
+        _queue nor _rows by now) before the error reaches _loop's recovery."""
+        if not self._undelivered:
+            return False
+        window = self._undelivered[0]
+        with self._phases.phase("process"):
+            if window:
+                entry = window.popleft()
+                try:
+                    self._deliver_row(entry)
+                except Exception as err:
+                    if entry[2]:
+                        self._fail(entry[0], f"delivery failed: {err!r}")
+                    raise
+            if not window:
+                self._undelivered.popleft()
+                _C_WINDOW_DELIVERIES.inc(
+                    kind="under_device_work" if burst or self._inflight
+                    else "exposed")
+        return True
+
+    def _deliver_pending(self, burst: bool = False):
+        """Deliver every settled row still owed."""
+        while self._deliver_next(burst):
+            pass
 
     def _step(self):
         """One hot-loop turn (docs/PERF.md "Decode hot loop"): keep the
         readback ring full, fetch the OLDEST in-flight window (the only
-        host sync), refill the ring BEFORE processing its tokens — so
-        token emission/stop handling/accounting overlap the next window's
-        device time — then process. With overlap off the ring depth is 1
-        and this collapses to the classic dispatch→sync→process loop.
+        host sync), refill the ring BEFORE touching its tokens, then
+        SETTLE it (_settle_window: which rows ended). Its delivery to the
+        callers is left pending for _loop's next turn, which puts the
+        freed rows' prefills in flight first (_admit) and delivers under
+        them. With overlap off the ring depth is 1 and this collapses to
+        the classic dispatch→sync→settle loop.
         With speculation enabled, a turn where some greedy row drafted
         becomes ONE serialized [B, K+1] verify call instead (_spec_step
         — the drafter needs each verdict before proposing again, so spec
@@ -2028,7 +2135,10 @@ class BatchScheduler:
             # the device goes idle while the host processes this window —
             # the stall the overlap machinery exists to remove
             _C_SYNC_STALLS.inc()
-        retired_any = self._process_window(rec, toks_host)
+        # settle only: which rows ended is all the next dispatch needs. The
+        # tokens reach their streams once the chip has work again: under
+        # the coming turn's admission burst, else before its dispatch
+        retired_any = self._settle_window(rec, toks_host)
         self._release_deferred()
         if self.active == 0 and self._inflight:
             # every row retired mid-ring: the remaining windows are pure
@@ -2270,18 +2380,29 @@ class BatchScheduler:
             _G_MOE_LOAD.set(
                 float(max_load) * self.engine.model_cfg.n_experts / float(live))
 
-    @_phase("process")
-    def _process_window(self, rec, toks_host: np.ndarray) -> bool:
-        """Route one fetched window's tokens through the shared per-row
-        intake (_process_row_tokens). Rows that retired or moved since
+    @_phase("settle")
+    def _settle_window(self, rec, toks_host: np.ndarray) -> bool:
+        """Route one fetched window's tokens through the scheduler's half
+        of the shared per-row intake (_settle_row) and queue the callers'
+        half for _deliver_pending. Rows that retired or moved since
         dispatch are skipped — their overshoot tokens are scheduled-only
         work the goodput meter already books as waste."""
-        retired_any = False
+        window = []
         for b, req in rec["rows"]:
             if self._rows[b] is not req or req.done:
                 continue
             req.chunks_decoded += rec["W"]
-            retired_any |= self._process_row_tokens(b, req, toks_host[b])
+            window.append(self._settle_row(b, req, toks_host[b]))
+        # the ended rows' callers first: what they send next fills the rows
+        window.sort(key=lambda entry: not entry[2])
+        self._undelivered.append(deque(window))
+        return bool(window) and window[0][2]
+
+    def _process_window(self, rec, toks_host: np.ndarray) -> bool:
+        """Settle and deliver one fetched window back to back (with any
+        window settled before it, in order)."""
+        retired_any = self._settle_window(rec, toks_host)
+        self._deliver_pending()
         return retired_any
 
     def _drain_inflight(self) -> bool:
@@ -2305,10 +2426,16 @@ class BatchScheduler:
         if not self._inflight:
             self.cache.flush_deferred()
 
-    def _retire(self, req: Request):
+    def _vacate(self, b: int, req: Request):
+        """Row b's request ended: the row, its adapter refcount and its
+        drafter state (KV slot / mesh server row) are free for the next
+        admission. The caller's done event is _retire's."""
+        self._release_row(b)
         self._release_adapter(req)
         if self._spec is not None:
-            self._spec.forget(req)  # drafter KV slot / mesh server row
+            self._spec.forget(req)
+
+    def _retire(self, req: Request):
         req.timing.t_done = time.perf_counter()
         self.stats.retired += 1
         self.stats.history.append(

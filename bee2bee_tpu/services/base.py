@@ -46,16 +46,20 @@ class BaseService:
     # max_batch); 0 = unknown. A stream's pump holds one thread for life
     stream_rows: int = 0
 
-    def pump_executor(self):
+    def pump_executor(self, streams: int = 0):
         """The executor this service's stream pumps run on (api.py's
         _stream_service, _stream_via_thread): ``None`` — the loop's default
         of min(32, cores + 4) threads — while that feeds every batch row
-        plus one queued stream, else a pool of ``stream_rows + 1`` threads
-        made once. A wide batch (a state-space model's reason to exist)
-        cannot be fed through fewer threads than it has rows."""
+        plus one queued stream AND the ``streams`` the caller may have open
+        at once (the gateway: admission's ``max_concurrent``), else a pool
+        that wide made once. A wide batch (a state-space model's reason to
+        exist) cannot be fed through fewer threads than it has rows; and a
+        stream that admission let in but that waits here for a thread is a
+        request the scheduler's queue cannot see: a freed row then stands
+        empty until a done event has travelled to its caller and back."""
         import os
 
-        need = int(self.stream_rows) + 1
+        need = max(int(self.stream_rows) + 1, int(streams))
         if need <= min(32, (os.cpu_count() or 1) + 4):
             return None
         pool = getattr(self, "_pump_pool", None)

@@ -226,6 +226,36 @@ def test_overlap_parity_under_retirement_and_admission(fused_engine,
     on = _run_batch(fused_engine, budgets)
     off = _run_batch(unfused_engine, budgets)
     assert on == off, "overlap changed tokens under retirement/admission"
+    # STREAMED rows (no look-ahead: a window's delivery waits for the burst
+    # that its retirements admit, ISSUE 40) send the same tokens, event by
+    # event, on both engines
+    assert _run_streams(fused_engine, budgets) == off
+    assert _run_streams(unfused_engine, budgets) == off
+
+
+def _run_streams(eng, budgets):
+    """The budgets as concurrent streams; per row, the tokens of its
+    content events in order (checked against its done line's)."""
+    sent: list = [None] * len(budgets)
+
+    def run(i):
+        got = []
+        for ev in eng.generate_stream(
+            PROMPTS[i % ROWS], max_new_tokens=budgets[i], temperature=0.0
+        ):
+            if ev.get("done"):
+                assert got == ev["result"].token_ids
+                sent[i] = got
+            else:
+                got.extend(ev["tokens"])
+
+    threads = [threading.Thread(target=run, args=(i,))
+               for i in range(len(budgets))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return sent
 
 
 def _run_burst(eng, budgets):

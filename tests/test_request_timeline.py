@@ -286,7 +286,7 @@ def test_phase_clock_flush_credits_the_open_phase_from_another_thread():
 
 def test_scheduler_phases_account_for_the_loops_wall_time(engine):
     counter = get_registry().get("engine.phase_seconds")
-    phases = ("admit", "dispatch", "fetch", "process", "compact")
+    phases = ("admit", "dispatch", "fetch", "settle", "process", "compact")
     before = {p: counter.value(phase=p) for p in phases}
     t0 = time.perf_counter()
     out = engine.generate("phases of one generation", max_new_tokens=48, temperature=0.0)

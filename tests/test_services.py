@@ -193,6 +193,31 @@ def test_ollama_exposes_async_wrappers():
     assert callable(getattr(svc, "execute_stream_async"))
 
 
+@pytest.mark.parametrize("rows,streams,width", [
+    (0, 0, None),      # a service that batches nothing: the loop's default executor
+    (2, 4, None),      # both fit the default executor
+    (64, 0, 65),       # every batch row plus one queued stream
+    (64, 96, 96),      # every stream admission lets in: the scheduler's queue sees them
+    (16, 1000, 1000),
+])
+def test_pump_pool_is_as_wide_as_the_streams_admission_lets_in(monkeypatch, rows, streams, width):
+    """A stream that was admitted but waits for a pump thread is invisible to
+    the scheduler's queue: a freed row would stand empty until a done event
+    has travelled to its caller and back (PERF.md, PR 40)."""
+    import os
+
+    monkeypatch.setattr(os, "cpu_count", lambda: 13)  # default executor: 17 threads
+    svc = BaseService("m")
+    svc.stream_rows = rows
+    pool = svc.pump_executor(streams)
+    try:
+        assert (pool._max_workers if pool is not None else None) == width
+        assert svc.pump_executor(streams) is pool  # made once
+    finally:
+        if pool is not None:
+            pool.shutdown()
+
+
 async def test_stream_via_thread_stops_pump_when_consumer_abandons():
     """A consumer that stops iterating (client hung up, error raised at
     the node layer) must stop the backend pull at the next line — the
