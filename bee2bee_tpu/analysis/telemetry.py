@@ -10,6 +10,7 @@ metric LABELS (which are themselves chosen from bounded sets), never in
 the name.
 
 - ML-T001 — the name argument of a ``span(...)`` / ``phase(...)`` /
+  ``part(...)`` / ``annotate(...)`` / ``prog_scope(...)`` /
   ``counter(...)`` / ``gauge(...)`` / ``histogram(...)`` call is built
   dynamically: an f-string, a ``%`` / ``+`` expression, or ``.format()``.
   Plain variables pass (a forwarding helper like the scheduler's
@@ -26,7 +27,8 @@ import ast
 
 # call targets whose first argument is a span/metric/phase NAME
 _NAME_CALLS = frozenset(
-    {"span", "phase", "_phase", "counter", "gauge", "histogram"}
+    {"span", "phase", "_phase", "part", "annotate", "prog_scope",
+     "counter", "gauge", "histogram"}
 )
 
 

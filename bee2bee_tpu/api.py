@@ -39,7 +39,13 @@ from .metrics import PROMETHEUS_CONTENT_TYPE, get_registry
 from .obs import SERIES_BY_NAME, SERIES_NAMES
 from .protocol import copy_sampling
 from .router import DEFAULT_TENANT, AdmissionReject
-from .tracing import current_timing, get_tracer, request_timing, stitch_trace
+from .tracing import (
+    annotate,
+    current_timing,
+    get_tracer,
+    request_timing,
+    stitch_trace,
+)
 
 logger = logging.getLogger("bee2bee_tpu.api")
 
@@ -862,7 +868,9 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
         """On-demand device profiling (docs/OBSERVABILITY.md "Engine
         economics"): POST starts a duration-bounded ``jax.profiler``
         capture (body ``{"duration_s": 2.0}``, clamped to the profiler's
-        max) and blocks until the zipped artifact lands under
+        max; ``"python_tracer": true`` brings the Python frames back, at
+        the price of stretching every host phase it then times) and blocks
+        until the zipped artifact lands under
         ``$BEE2BEE_INCIDENT_DIR/profiles``; a concurrent capture is the
         typed 409 ``profile_in_progress`` (jax.profiler is a process
         singleton — two captures would corrupt each other). GET lists
@@ -920,7 +928,9 @@ def build_app(node: P2PNode, api_key: str | None = None) -> web.Application:
         try:
             # capture blocks ~duration_s: off the event loop, bounded by
             # the profiler's own MAX_DURATION_S clamp
-            header = await asyncio.to_thread(profiler.capture, duration)
+            header = await asyncio.to_thread(
+                profiler.capture, duration,
+                python_tracer=body.get("python_tracer") is True)
         except ProfileInProgress as e:
             return web.json_response(
                 {"detail": str(e), "error_kind": "profile_in_progress"},
@@ -1240,7 +1250,10 @@ async def _stream_service(
                     # metrics must never kill a stream: non-object lines or
                     # non-string "text" from custom services pass through
                     pass
-                await resp.write(frame(item))
+                # on a /debug/profile capture's host plane: an idle gap of
+                # the device that waits for a frame's write gets its name
+                with annotate("gateway.write"):
+                    await resp.write(frame(item))
                 if first:
                     record.t_first_write = time.perf_counter()
                     _observe_segments(record, _TTFT_SEGMENTS)

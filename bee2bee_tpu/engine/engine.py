@@ -48,7 +48,7 @@ from ..metrics import get_registry
 from ..models import config as model_config
 from ..models import core, partition
 from ..parallel.mesh import local_mesh
-from ..tracing import current_timing
+from ..tracing import current_timing, prog_scope
 from ..utils import MetricsAggregator
 from .paged import LatentPoolUnsupported, RecurrentStateUnsupported
 from .tokenizer import load_tokenizer
@@ -428,15 +428,16 @@ class InferenceEngine:
             ),
         )
         self._state_zeros = jax.jit(
-            functools.partial(core.init_ssm_state, self.model_cfg,
-                              dtype=self.dtype),
+            functools.partial(prog_scope("prog.pool")(core.init_ssm_state),
+                              self.model_cfg, dtype=self.dtype),
             static_argnums=0,
         )
         self._rng = jax.random.key(self.engine_cfg.rng_seed)
         # jitted split: an eager jax.random.split is a dispatch of its
         # own, and _next_key runs on every admission/window (the cost of
         # an eager op is not measured on the current machine)
-        self._split_key = jax.jit(lambda k: tuple(jax.random.split(k)))
+        self._split_key = jax.jit(
+            prog_scope("prog.sample")(lambda k: tuple(jax.random.split(k))))
         # gateways run execute() on a thread pool: guard the rng stream and
         # lazy scheduler creation (jax itself is safe for concurrent dispatch)
         self._mutex = threading.Lock()
@@ -784,6 +785,7 @@ class InferenceEngine:
             return None
         return self._state_zeros(rows)
 
+    @prog_scope("prog.prefill")
     def _prefill_fn(self, params, tokens, cache, true_len, offset,
                     block_tables=None, write_floor=None, write_ceil=None,
                     adapters=None, aids=None, ascales=None, state=None):
@@ -837,6 +839,7 @@ class InferenceEngine:
             return cache, last[:, 0, :], extras
         return cache, last[:, 0, :]
 
+    @prog_scope("prog.verify")
     def _spec_verify_fn(self, params, cur, drafts, draft_lens, cache, offsets,
                         temps, topks, topps, minps, key, tables=None,
                         adapters=None, aids=None, ascales=None,

@@ -134,7 +134,26 @@ _C_SYNC_STALLS = _REG.counter(
 _C_PHASE_SECONDS = _REG.counter(
     "engine.phase_seconds",
     "scheduler-thread wall seconds by loop phase (phase label: admit, "
-    "dispatch, fetch = blocked on the device, process, compact)",
+    "dispatch, fetch = blocked on the device, settle, process, compact, "
+    "turn = the loop's own lines between them)",
+)
+_C_ADMIT_SECONDS = _REG.counter(
+    "engine.admit_seconds",
+    "the admit phase's seconds once more, by part of an admission call "
+    "(part label: dispatch = popping, planning and dispatching the burst's "
+    "prefills | wait = blocked in the burst's one first-token gather, the "
+    "chip running prefills and no decode window | emit = handing out the "
+    "first tokens | none = a call that found nobody to admit); the parts "
+    "sum to engine.phase_seconds{phase=\"admit\"}",
+)
+_C_DECODE_SLOTS = _REG.counter(
+    "engine.decode_slots",
+    "token slots of every fetched decode window (batch-bucket rows x "
+    "steps) and spec-verify step (rows x K+1), by what became of them "
+    "(kind label: kept = a token that entered an output | after_end = a "
+    "live row's slots past its stop or budget, a rejected draft, a row "
+    "that retired or moved since dispatch | dead_row = rows of the bucket "
+    "with no request)",
 )
 _G_OVERLAP = _REG.gauge(
     "engine.overlap_inflight",
@@ -728,6 +747,17 @@ class GoodputMeter:
         except Exception:  # noqa: BLE001 — telemetry never throws
             pass
 
+    @staticmethod
+    def note_slots(rows: int, live_rows: int, steps: int, kept: int) -> None:
+        """Book one fetched decode window (or verify step) of ``rows`` x
+        ``steps`` token slots, ``live_rows`` of them a request's at
+        dispatch, ``kept`` tokens accepted into outputs: the cumulative
+        engine.decode_slots{kind}, which a reader can take over any
+        stretch (the gauges above trail 60 s and mix prefill pad in)."""
+        _C_DECODE_SLOTS.inc(kept, kind="kept")
+        _C_DECODE_SLOTS.inc(live_rows * steps - kept, kind="after_end")
+        _C_DECODE_SLOTS.inc((rows - live_rows) * steps, kind="dead_row")
+
     def note_spec(self, tier: str, drafted: int, accepted: int) -> None:
         """Book one row's verify outcome against its drafter tier.
 
@@ -868,10 +898,19 @@ class DeviceProfiler:
             return dict(self._active) if self._active else None
 
     def capture(self, duration_s: float = 2.0,
-                workload: Callable | None = None) -> dict:
+                workload: Callable | None = None,
+                python_tracer: bool = False) -> dict:
         """Blocking capture: start jax.profiler, run ``workload()`` (or
         sleep) for ``duration_s``, stop, zip. Returns the artifact header.
-        Raises ProfileInProgress when a capture is already running."""
+        Raises ProfileInProgress when a capture is already running.
+
+        The Python tracer is OFF unless ``python_tracer``: jax's default
+        (python_tracer_level=1) makes every Python call of every thread an
+        event and so stretches the host phases the capture then times
+        (`sched.process` 38-48 ms a window against 11-12 untraced on PR 39's
+        tree, 1.3-1.6x on PR 40's: PERF.md sections 5-6). The program's own
+        TraceAnnotations and the runtime's host events (host_tracer_level
+        keeps its default) stay on the one clock with the device ops."""
         import jax
 
         duration_s = max(0.05, min(float(duration_s), self.MAX_DURATION_S))
@@ -887,7 +926,9 @@ class DeviceProfiler:
         try:
             raw_dir.mkdir(parents=True, exist_ok=True)
             t0 = time.time()
-            jax.profiler.start_trace(str(raw_dir))
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python_tracer else 0
+            jax.profiler.start_trace(str(raw_dir), profiler_options=options)
             try:
                 if workload is not None:
                     while time.time() - t0 < duration_s:
@@ -1075,7 +1116,8 @@ class EngineIntrospection:
         self.forecast = PoolForecast()
         # the scheduler loop's phases; refresh() credits the open one, so
         # a scrape reads engine.phase_seconds up to that instant
-        self.phases = PhaseClock("sched", _C_PHASE_SECONDS)
+        self.phases = PhaseClock(
+            "sched", _C_PHASE_SECONDS, parts={"admit": _C_ADMIT_SECONDS})
         with _INSTANCES_LOCK:
             _INSTANCES[id(self)] = self
         _wire_provider()

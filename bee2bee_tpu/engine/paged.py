@@ -51,6 +51,7 @@ import numpy as np
 
 from ..metrics import get_registry
 from ..models import core
+from ..tracing import prog_scope
 
 # block-pool occupancy for /metrics (one engine per serving node, so
 # unlabeled gauges suffice; the last-constructed allocator owns them)
@@ -316,11 +317,13 @@ class PagedPrefixCache:
 # pool's [L, Hkv, NB] scales line up, so one program moves pages and
 # their scales together. State leaves are [L, B, ...], row dim 1. All but
 # the gather (a pure read: the pool keeps serving) donate what they update.
+# Each body runs under the root scope "prog.pool" (tracing.prog_scope).
 
 _donating = functools.partial(jax.jit, donate_argnums=(0,))
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+@prog_scope("prog.pool")
 def _copy_slot(axis, tree, src, dst):
     """Slot ``src`` of every leaf copied over slot ``dst`` along ``axis``: a
     pool block (axis 2, the CoW copy) or a state row (axis 1, compaction).
@@ -332,6 +335,7 @@ def _copy_slot(axis, tree, src, dst):
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
+@prog_scope("prog.pool")
 def _gather_blocks(hd, pool, idx):
     """Blocks ``idx`` of every leaf, pages cut to the model's head size
     ``hd``: a lane-aligned pool's pad lanes (core.init_paged_pool) do not
@@ -343,6 +347,7 @@ def _gather_blocks(hd, pool, idx):
 
 
 @_donating
+@prog_scope("prog.pool")
 def _scatter_blocks(pool, new, idx):
     """Write blocks ``new`` at ``idx``; pages narrower than the pool's
     (head size vs lane-aligned) get their pad lanes zeroed."""
@@ -359,6 +364,7 @@ def _scatter_blocks(pool, new, idx):
 
 
 @_donating
+@prog_scope("prog.pool")
 def _reset_scales(pool, idx):
     return dict(
         pool,
@@ -368,6 +374,7 @@ def _reset_scales(pool, idx):
 
 
 @_donating
+@prog_scope("prog.pool")
 def _state_insert(st, row, b):
     return jax.tree.map(
         lambda big, r: jax.lax.dynamic_update_slice_in_dim(
@@ -375,6 +382,7 @@ def _state_insert(st, row, b):
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
+@prog_scope("prog.pool")
 def _state_shrink(st, n):
     return jax.tree.map(lambda a: a[:, :n], st)
 

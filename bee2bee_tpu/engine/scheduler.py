@@ -66,7 +66,7 @@ from ..metrics import get_registry
 from ..models import core
 from ..router.fairness import WdrrQueue
 from ..router.tenants import load_tenant_config
-from ..tracing import RequestTiming, get_tracer
+from ..tracing import RequestTiming, annotate, get_tracer, prog_scope
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
 from .paged import (
     PoolExhausted,
@@ -98,6 +98,23 @@ _H_PREFILL = _REG.histogram(
 )
 _H_STEP = _REG.histogram(
     "engine.step_ms", "one decode window / spec verify step wall time (ms)"
+)
+_H_BURST = _REG.histogram(
+    "engine.admit_burst_requests",
+    "requests placed by one admission call that placed any: the burst whose "
+    "first tokens come back in ONE gather (observed once a burst)",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
+)
+_C_PREFILL_CALLS = _REG.counter(
+    "engine.prefill_calls",
+    "prefill PROGRAMS dispatched: one a chunk of an admission's walk, the "
+    "re-prefill import rung included (bucket label: the chunk's padded "
+    "width in tokens)",
+)
+_C_PREFILL_TOKENS = _REG.counter(
+    "engine.prefill_tokens",
+    "positions those programs ran over (kind label: real prompt tokens | "
+    "pad of the bucket), every model",
 )
 _G_BATCH_FILL = _REG.gauge(
     "engine.batch_fill", "active rows / current batch bucket (0..1)"
@@ -455,6 +472,10 @@ class BatchScheduler:
         # occurrences, 1 = generated), so they get their own row helpers
         V = self._vocab
 
+        # (each of these small programs, like every jit root of the serving
+        # path, runs its body under one root scope: tracing.prog_scope)
+        pool_prog = prog_scope("prog.pool")
+
         def c_insert(c, row, b):
             return jax.lax.dynamic_update_slice(c, row, (b, 0, 0))
 
@@ -463,19 +484,20 @@ class BatchScheduler:
             return jax.lax.dynamic_update_slice(c, row, (dst, 0, 0))
 
         self._counts_zeros = jax.jit(
-            lambda b: jnp.zeros((b, 2, V), jnp.int32), static_argnums=0
+            pool_prog(lambda b: jnp.zeros((b, 2, V), jnp.int32)),
+            static_argnums=0,
         )
         self._counts_grow = jax.jit(
-            lambda d, s: jax.lax.dynamic_update_slice(d, s, (0, 0, 0)),
+            pool_prog(lambda d, s: jax.lax.dynamic_update_slice(d, s, (0, 0, 0))),
             donate_argnums=(0,),
         )
-        self._counts_insert = jax.jit(c_insert, donate_argnums=(0,))
-        self._counts_move = jax.jit(c_move, donate_argnums=(0,))
+        self._counts_insert = jax.jit(pool_prog(c_insert), donate_argnums=(0,))
+        self._counts_move = jax.jit(pool_prog(c_move), donate_argnums=(0,))
         self._counts_bump = jax.jit(
-            lambda c, b, t: c.at[b, 1, t].add(1), donate_argnums=(0,)
+            pool_prog(lambda c, b, t: c.at[b, 1, t].add(1)), donate_argnums=(0,)
         )
         self._counts_shrink = jax.jit(
-            lambda c, n: c[:n], static_argnums=(1,)
+            pool_prog(lambda c, n: c[:n]), static_argnums=(1,)
         )
         # engine economics plane (engine/introspect.py): the decode roots
         # register with the engine's retrace sentinel under the declared
@@ -538,7 +560,7 @@ class BatchScheduler:
             )
         # jitted: sample_batched run eagerly is ~15 tiny ops = ~15
         # dispatches per admission
-        self._sample_first = jax.jit(sample_batched)
+        self._sample_first = jax.jit(prog_scope("prog.sample")(sample_batched))
 
         # self-speculative decoding (engine/spec.py): greedy rows draft
         # from their own prompt+output and one [B, K+1] verify call
@@ -695,6 +717,7 @@ class BatchScheduler:
             minps is not None, adapters is not None,
         )
 
+    @prog_scope("prog.decode")
     def _decode_fn(self, params, cur, cache, offsets, temps, topks, topps,
                    minps, key, tables=None, adapters=None, aids=None,
                    ascales=None, counts=None, reps=None, press=None,
@@ -759,6 +782,7 @@ class BatchScheduler:
             return cache
         return dict(cache, moe_stats=jnp.zeros((len(core.MOE_STATS),), jnp.int32))
 
+    @prog_scope("prog.decode")
     def _decode_pen_fn(
         self, params, cur, cache, offsets, counts,
         temps, topks, topps, minps, reps, press, freqs, key, tables=None,
@@ -805,28 +829,16 @@ class BatchScheduler:
                 while (not self._queue and self.active == 0
                        and not self._checkpoints and not self._undelivered
                        and not self._shutdown):
-                    self._cond.wait()
+                    # nothing to do, so no phase; named on a capture all the
+                    # same: a device gap that waits for the callers' next
+                    # requests (a closed loop whose rows end together)
+                    with annotate("sched.idle"):
+                        self._cond.wait()
                 if self._shutdown:
                     self._fail_all("engine shut down")
                     return
             try:
-                if self._inflight and (self._checkpoints or self._queue):
-                    # admission and checkpoints need settled row state —
-                    # drain the readback ring before touching either
-                    if self._drain_inflight():
-                        self._compact_and_shrink()
-                self._service_checkpoints()
-                # _admit delivers the last settled window itself, whenever
-                # it has nobody to admit; what it left (it may stop early)
-                # is delivered here, before the next dispatch. Again while
-                # it places a burst: who arrived while the firsts were
-                # gathered (the callers of the rows just delivered) gets a
-                # free row now, not a window later
-                while self._admit():
-                    pass
-                self._deliver_pending()
-                if self.active or self._inflight:
-                    self._step()
+                self._turn()
             except Exception as e:  # noqa: BLE001 — the thread must survive:
                 # a dead scheduler thread would hang every blocked caller
                 logger.exception("scheduler step failed; failing active requests")
@@ -848,6 +860,29 @@ class BatchScheduler:
                         except Exception:
                             pass
                     return
+
+    @_phase("turn")
+    def _turn(self):
+        """One turn of the loop. Its own lines (and the checkpoints' and
+        _step's between their phases) are the phase `turn`, so that the
+        phases sum to the loop's busy time with nothing left over."""
+        if self._inflight and (self._checkpoints or self._queue):
+            # admission and checkpoints need settled row state —
+            # drain the readback ring before touching either
+            if self._drain_inflight():
+                self._compact_and_shrink()
+        self._service_checkpoints()
+        # _admit delivers the last settled window itself, whenever
+        # it has nobody to admit; what it left (it may stop early)
+        # is delivered here, before the next dispatch. Again while
+        # it places a burst: who arrived while the firsts were
+        # gathered (the callers of the rows just delivered) gets a
+        # free row now, not a window later
+        while self._admit():
+            pass
+        self._deliver_pending()
+        if self.active or self._inflight:
+            self._step()
 
     def _fail_all(self, reason: str):
         """Error-terminate every queued AND admitted request (callers are
@@ -1271,6 +1306,9 @@ class BatchScheduler:
                     # re-anchored window re-feeds real ones
                     real = min(len(chunk), n - pos)
                     self._count_moe(real, bucket - real, 1)
+                _C_PREFILL_CALLS.inc(bucket=str(bucket))
+                _C_PREFILL_TOKENS.inc(len(chunk), kind="real")
+                _C_PREFILL_TOKENS.inc(bucket - len(chunk), kind="pad")
                 if row_state is not None:
                     row_state = extras
                     _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
@@ -1324,7 +1362,12 @@ class BatchScheduler:
         8 must not pay it 8 times while active streams sit undecoded).
         Whenever the queue has nobody for a free row, the last settled
         window is delivered (_deliver_next): under the prefills already
-        dispatched, and bringing the requests that follow the ended ones."""
+        dispatched, and bringing the requests that follow the ended ones.
+        A call's own seconds are booked once more by PART
+        (engine.admit_seconds, annotation sched.admit.<part>): `dispatch`
+        from the first popped request on, `wait` in the gather, `emit`
+        from the gather to the end; what runs under another phase
+        (deliveries = process, resize and compaction = compact) is theirs."""
         e = self.engine
         placed: list[tuple] = []  # (req, row, firsts_index)
         firsts: list = []
@@ -1342,6 +1385,9 @@ class BatchScheduler:
                 if self._deliver_next(burst=bool(placed)):
                     continue
                 break
+            # someone to admit: from here the call runs as admit's part
+            # `dispatch` (engine.admit_seconds{part}; until then as "none")
+            self._phases.part("dispatch")
             if req.cancelled:
                 req.finish = "cancelled"
                 req.timing.t_first = req.timing.t_done = time.perf_counter()
@@ -1490,8 +1536,12 @@ class BatchScheduler:
             return False
         self._deliver_pending(burst=True)
         # ONE blocking gather for the whole burst (device_get on the list
-        # fetches all; no eager concatenate op on device)
+        # fetches all; no eager concatenate op on device). The part `wait`:
+        # the chip runs the burst's prefills and no decode window meanwhile
+        self._phases.part("wait")
         toks = np.concatenate([np.asarray(x) for x in jax.device_get(firsts)])
+        self._phases.part("emit")
+        _H_BURST.observe(len(placed))
         now = time.perf_counter()
         for req, b, i in placed:
             tok = int(toks[i])
@@ -1935,9 +1985,12 @@ class BatchScheduler:
         self.stats.spec_steps += 1
 
         retired_any = False
+        live_rows = kept = 0  # the verify's [bsz, K+1] slots (engine.decode_slots)
         for b, req in enumerate(self._rows):
             if req is None:
                 continue
+            live_rows += 1
+            had = len(req.out_ids)
             req.chunks_decoded += 1
             a = int(acc[b])
             drafted_here = int(lens[b])
@@ -1962,6 +2015,7 @@ class BatchScheduler:
                 b, req, np.append(drafts[b, :a], nxt[b])
             )
             retired_any |= retired
+            kept += len(req.out_ids) - had
             if drafted_here and not retired:
                 # the verdict rolls the drafter's state forward (model:
                 # KV frontier; mesh: pipeline the next draft_request NOW
@@ -1972,6 +2026,8 @@ class BatchScheduler:
                 if drafter is not None:
                     drafter.observe(req, a)
                 self._spec_tier_check(req)
+        self._meter.note_slots(self._bsz, live_rows,
+                               e.engine_cfg.spec_tokens + 1, kept)
         # a spec step is serialized: nothing ran while its rows were delivered
         _C_WINDOW_DELIVERIES.inc(kind="exposed")
         if retired_any:
@@ -2393,6 +2449,9 @@ class BatchScheduler:
                 continue
             req.chunks_decoded += rec["W"]
             window.append(self._settle_row(b, req, toks_host[b]))
+        rows, steps = toks_host.shape
+        self._meter.note_slots(rows, len(rec["rows"]), steps,
+                               sum(len(entry[1]) for entry in window))
         # the ended rows' callers first: what they send next fills the rows
         window.sort(key=lambda entry: not entry[2])
         self._undelivered.append(deque(window))

@@ -14,6 +14,7 @@ import time
 from typing import Any, Iterator
 
 from ..metrics import get_registry
+from ..tracing import annotate
 
 # every backend's execute() funnels through result_dict, so this one
 # histogram covers service execute latency for tpu/ollama/remote/fake
@@ -25,6 +26,17 @@ _H_EXECUTE = get_registry().histogram(
 
 class ServiceError(Exception):
     pass
+
+
+def pump_turn():
+    """Context of ONE turn of a stream's pump thread: an event taken off
+    its request's queue, framed into lines and handed to the gateway's
+    loop — `svc.pump` on the host plane of a `/debug/profile` capture, so
+    an idle gap of the device that waits for a caller's next request gets
+    a name (no counter: `service.holdback_ms` and `gateway.write_ms` time
+    the request's side). A backend opens it AFTER its blocking wait for the
+    event: an annotation over the wait would name every gap."""
+    return annotate("svc.pump")
 
 
 class BaseService:

@@ -14,6 +14,7 @@ from .base import (
     ServiceError,
     normalize_stops,
     parse_transcript,
+    pump_turn,
     role_cut,
     scrub_stop_words,
     scrub_stream_delta,
@@ -200,23 +201,26 @@ class TPUService(BaseService):
                 return self.stream_line({"text": text})
 
             for ev in self.engine.generate_stream(**args):
-                if ev.get("done"):  # flush the held-back tail
-                    res = ev.get("result")
-                    if res is not None:
-                        n_new = res.new_tokens
-                        timing = dict(res.timings)
-                    tail = scrub_stop_words(acc, stops)
-                    if tail[emitted:]:
-                        yield content_line(tail[emitted:])
-                    break
-                acc += ev.get("text", "")
-                n_seen += len(ev.get("tokens") or ([1] if ev.get("token") is not None else []))
-                delta, emitted, hit = scrub_stream_delta(acc, emitted, stops)
-                if delta:
-                    yield content_line(delta)
-                if hit:
-                    n_new = n_seen
-                    break
+                # the event is here (the wait for it is over): this turn of
+                # the pump, its line's hand-over to the gateway included
+                with pump_turn():
+                    if ev.get("done"):  # flush the held-back tail
+                        res = ev.get("result")
+                        if res is not None:
+                            n_new = res.new_tokens
+                            timing = dict(res.timings)
+                        tail = scrub_stop_words(acc, stops)
+                        if tail[emitted:]:
+                            yield content_line(tail[emitted:])
+                        break
+                    acc += ev.get("text", "")
+                    n_seen += len(ev.get("tokens") or ([1] if ev.get("token") is not None else []))
+                    delta, emitted, hit = scrub_stream_delta(acc, emitted, stops)
+                    if delta:
+                        yield content_line(delta)
+                    if hit:
+                        n_new = n_seen
+                        break
             # the done line carries the node's REAL accounting so mesh
             # peers / the web gateway don't fall back to len/4 estimates
             done: dict[str, Any] = {"done": True}

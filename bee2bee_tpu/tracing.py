@@ -28,6 +28,9 @@ per-request latency_ms (reference services.py:97-105) and ping RTTs
   capture, beside the device ops) and exclusive seconds on a counter.
   Device traces themselves come from `POST /debug/profile`
   (engine/introspect.DeviceProfiler).
+- `annotate` / `prog_scope`: a name on the capture's host plane for a
+  turn of another thread, and ONE `jax.named_scope` around the body of a
+  jit root, so the capture's device ops say which program they belong to.
 
 Spans are cheap (monotonic clock + dict append) and bounded (ring
 buffer), so they stay on in production; mesh nodes surface them at the
@@ -39,10 +42,12 @@ would defeat the per-name aggregation and explode cardinality).
 from __future__ import annotations
 
 import contextvars
+import functools
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
@@ -204,6 +209,39 @@ def current_timing() -> RequestTiming | None:
     return _current_timing.get()
 
 
+def annotate(name: str):
+    """``name`` over a block on the host plane of a `/debug/profile`
+    capture, beside the device ops: a plain `jax.profiler.TraceAnnotation`
+    (no span, no counter), for a turn of a thread that an idle gap of the
+    device may be waiting for. A process that has not loaded jax can have
+    no capture running and gets a no-op. Names are literals (ML-T001)."""
+    if "jax" not in sys.modules:
+        return nullcontext()
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
+def prog_scope(name: str):
+    """Decorate the function a jit root traces: its body runs under ONE
+    `jax.named_scope(name)` ("prog.decode", "prog.prefill", ...), so every
+    device op of the program carries `jit(f)/<name>/...` in the op_name
+    metadata a capture records. Metadata only: the compiled code, its
+    instruction names and the compile-cache key stay what they were."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return run
+
+    return deco
+
+
 class PhaseClock:
     """The named phases of ONE thread's loop. `phase(name)` opens a
     `jax.profiler.TraceAnnotation("<prefix>.<name>")` — it lands on the
@@ -215,38 +253,74 @@ class PhaseClock:
     makes the counter's growth between two scrapes exact (a phase can
     last seconds — the scheduler blocked on the device — and would
     otherwise count only when it ends). No Tracer span: the span ring is
-    for requests. Names are literals (meshlint ML-T001)."""
+    for requests. Names are literals (meshlint ML-T001).
 
-    def __init__(self, prefix: str, counter):
+    A phase listed in ``parts`` (phase name -> counter) is told apart once
+    more: `part(name)` labels the innermost open phase from there on (to
+    its end, or the next `part`) with an annotation
+    "<prefix>.<phase>.<name>" NESTED in the phase's own, and every second
+    the clock credits to `counter{phase=...}` it credits to
+    `parts[phase]{part=name}` too ("none" while no part is set) — one
+    reading of one clock booked twice, so a phase's parts sum to the
+    phase by construction."""
+
+    def __init__(self, prefix: str, counter, parts: dict | None = None):
         from jax.profiler import TraceAnnotation
 
         self._annotation = TraceAnnotation
         self._prefix = prefix
         self._counter = counter
+        self._parts = dict(parts or {})
         self._lock = threading.Lock()
         self._open: list[str] = []  # phases entered and not yet left
+        # beside it, each open phase's part: [name | None, its annotation]
+        self._part: list[list] = []
         self._since = 0.0  # up to when the innermost open phase is credited
 
     def flush(self) -> None:
         with self._lock:
             if self._open:
                 now = time.perf_counter()
-                self._counter.inc(now - self._since, phase=self._open[-1])
+                name = self._open[-1]
+                self._counter.inc(now - self._since, phase=name)
+                if name in self._parts:
+                    self._parts[name].inc(
+                        now - self._since, part=self._part[-1][0] or "none")
                 self._since = now
+
+    def part(self, name: str | None) -> None:
+        """From here on the innermost open phase runs as its part ``name``
+        (None: as no part). Only the loop's own thread calls this."""
+        if not self._open or self._part[-1][0] == name:
+            return
+        self.flush()  # what ran so far belongs to the part before
+        if self._part[-1][1] is not None:
+            self._part[-1][1].__exit__(None, None, None)
+        note = None
+        if name is not None:
+            note = self._annotation(f"{self._prefix}.{self._open[-1]}.{name}")
+            note.__enter__()
+        with self._lock:
+            self._part[-1] = [name, note]
 
     @contextmanager
     def phase(self, name: str):
         self.flush()  # the parent pauses here
         with self._lock:
             self._open.append(name)
+            self._part.append([None, None])
             self._since = time.perf_counter()
         try:
             with self._annotation(f"{self._prefix}.{name}"):
-                yield
+                try:
+                    yield
+                finally:
+                    self.part(None)  # a part ends with its phase, inside it
         finally:
             self.flush()
             with self._lock:
                 self._open.pop()
+                self._part.pop()
                 self._since = time.perf_counter()
 
 

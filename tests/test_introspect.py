@@ -672,6 +672,54 @@ async def test_debug_profile_route_round_trip(tmp_path, monkeypatch):
         await node.stop()
 
 
+def _traced_levels(monkeypatch) -> list[tuple[int, int]]:
+    """(python_tracer_level, host_tracer_level) of every capture started."""
+    seen: list[tuple[int, int]] = []
+    start = jax.profiler.start_trace
+
+    def starting(log_dir, *args, profiler_options=None, **kwargs):
+        seen.append((profiler_options.python_tracer_level,
+                     profiler_options.host_tracer_level))
+        return start(log_dir, *args, profiler_options=profiler_options, **kwargs)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", starting)
+    return seen
+
+
+def test_a_capture_leaves_the_python_tracer_off_unless_asked(tmp_path, monkeypatch):
+    """jax's default (python_tracer_level=1) makes every Python call an event
+    and stretches the host phases it then times; the host tracer, which
+    records the program's TraceAnnotations, keeps jax's default."""
+    seen = _traced_levels(monkeypatch)
+    default_host = jax.profiler.ProfileOptions().host_tracer_level
+    prof = DeviceProfiler(profile_dir=tmp_path)
+    prof.capture(duration_s=0.05)
+    prof.capture(duration_s=0.05, python_tracer=True)
+    assert seen == [(0, default_host), (1, default_host)] and default_host > 0
+
+
+@pytest.mark.parametrize("body,level", [
+    ({"duration_s": 0.05}, 0),  # what the benchmark posts
+    ({"duration_s": 0.05, "python_tracer": True}, 1),
+    ({"duration_s": 0.05, "python_tracer": "yes"}, 0),  # only the JSON true asks
+    ({"duration_s": 0.05, "python_tracer": False}, 0),
+], ids=["default", "asked", "not_a_bool", "refused"])
+async def test_debug_profile_route_python_tracer_field(tmp_path, monkeypatch, body, level):
+    monkeypatch.setattr(intro_mod, "_PROFILER", DeviceProfiler(tmp_path))
+    seen = _traced_levels(monkeypatch)
+    node = P2PNode(host="127.0.0.1", port=0)
+    await node.start()
+    client = TestClient(TestServer(build_app(node)))
+    await client.start_server()
+    try:
+        r = await client.post("/debug/profile", json=body)
+        assert r.status == 200 and (await r.json())["id"].startswith("prof-")
+        assert [lv for lv, _ in seen] == [level]
+    finally:
+        await client.close()
+        await node.stop()
+
+
 async def test_debug_profile_route_concurrent_capture_409(tmp_path, monkeypatch):
     prof = DeviceProfiler(tmp_path)
     monkeypatch.setattr(intro_mod, "_PROFILER", prof)

@@ -785,6 +785,57 @@ class S:
     assert [f.line for f in findings] == [11, 13]
 
 
+def test_telemetry_pass_covers_parts_annotations_and_program_scopes():
+    """ISSUE 41's names: a phase's part (PhaseClock.part: an annotation name
+    and a label value), a plain host annotation (tracing.annotate) and a jit
+    root's scope (tracing.prog_scope) are literals too."""
+    src = '''
+from ..tracing import annotate, prog_scope
+
+class S:
+    @prog_scope("prog.decode")
+    def _decode_fn(self, x):
+        return x
+
+    @prog_scope("prog.%s" % "decode")
+    def _other_fn(self, x):
+        return x
+
+    def _admit(self, bucket, part):
+        self._phases.part("dispatch")
+        self._phases.part(None)
+        self._phases.part(part)
+        self._phases.part(f"bucket_{bucket}")
+        with annotate("svc.pump"):
+            pass
+        with annotate("svc." + part):
+            pass
+'''
+    findings = analyze_source(src, "engine/fixture.py")
+    assert _rules(findings) == ["ML-T001"] * 3
+    assert [f.line for f in findings] == [9, 17, 20]
+
+
+def test_the_loops_turn_is_in_the_hot_loop_region():
+    """ML-J003 covers `_turn` (ISSUE 41: the loop's body, phase `turn`): a
+    host sync written there is one the readback ring did not schedule."""
+    src = '''
+import jax
+import numpy as np
+
+class S:
+    def _turn(self):
+        self._admit()
+        np.asarray(self._inflight[0]["toks"])
+        jax.device_get(self._cur)
+
+    def _admit(self):  # not the region: the burst's one gather lives here
+        return jax.device_get(self._firsts)
+'''
+    findings = analyze_source(src, "engine/fixture.py")
+    assert _rules(findings) == ["ML-J003"] * 2 and [f.line for f in findings] == [8, 9]
+
+
 def test_telemetry_pass_scans_whole_package():
     """Telemetry calls live in engine/, meshnet/, services/, web/ and
     api.py alike — the pass must not scope itself out of any of them."""

@@ -97,6 +97,13 @@ REQUIRED_PRESENT = {
     "engine.scheduled_tokens_per_s",
     "engine.hbm_bytes",
     "engine.tokens_generated",
+    # ISSUE 41: one generation places a burst, dispatches a prefill program
+    # and settles decode windows
+    "engine.admit_seconds",
+    "engine.admit_burst_requests",
+    "engine.prefill_calls",
+    "engine.prefill_tokens",
+    "engine.decode_slots",
     "engine.paged_blocks_in_use",
     "adapter.pool_resident",
     "gen.requests",

@@ -100,6 +100,69 @@ async def test_streamed_chat_stamps_one_timeline_and_observes_each_segment_once(
         delta["gateway.ttft_ms"][1], abs=1.0)
 
 
+async def test_a_streams_pump_turns_and_frame_writes_are_annotated(engine, monkeypatch):
+    """What the capture's Python tracer used to be the only witness of
+    (ISSUE 41): a turn of the stream's pump thread is `svc.pump`, opened once
+    the event is off the request's queue (never over the wait for it), and
+    the gateway's write of a frame is `gateway.write` on the loop's thread."""
+    from contextlib import contextmanager
+
+    import bee2bee_tpu.api as api_mod
+    import bee2bee_tpu.services.base as base_mod
+
+    spans: list[tuple[str, str, float]] = []
+
+    @contextmanager
+    def recording(name):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            spans.append((name, threading.current_thread().name, time.perf_counter() - t0))
+
+    monkeypatch.setattr(api_mod, "annotate", recording)
+    monkeypatch.setattr(base_mod, "annotate", recording)
+    stream = engine.generate_stream
+
+    def slow_events(**kwargs):
+        for ev in stream(**kwargs):
+            time.sleep(0.05)  # the pump waits for its event: no turn yet
+            yield ev
+
+    monkeypatch.setattr(engine, "generate_stream", slow_events)
+    node, client = await gateway([TPUService("tiny-llama", engine=engine)])
+    try:
+        lines = await stream_lines(client, "/chat", {
+            "prompt": "the mesh hums", "model": "tiny-llama", "stream": True,
+            "max_new_tokens": 10, "temperature": 0.0,
+        })
+    finally:
+        await client.close()
+        await node.stop()
+    pumps = [s for s in spans if s[0] == "svc.pump"]
+    writes = [s for s in spans if s[0] == "gateway.write"]
+    assert {s[0] for s in spans} == {"svc.pump", "gateway.write"}
+    assert len(writes) == len(lines) and len(pumps) >= len(lines) - 1
+    assert all(seconds < 0.04 for _, _, seconds in pumps), pumps
+    assert {t for _, t, _ in writes} == {threading.current_thread().name}
+    assert not {t for _, t, _ in pumps} & {t for _, t, _ in writes}
+
+
+def test_annotate_is_a_trace_annotation_where_jax_is_loaded_and_nothing_elsewhere(monkeypatch):
+    import sys
+    from contextlib import nullcontext
+
+    from jax.profiler import TraceAnnotation
+
+    from bee2bee_tpu.tracing import annotate
+
+    assert isinstance(annotate("test.annotate"), TraceAnnotation)
+    with annotate("test.annotate"):
+        pass
+    monkeypatch.delitem(sys.modules, "jax")  # a process that never loaded it
+    assert isinstance(annotate("test.annotate"), nullcontext)
+
+
 async def test_streamed_v1_sse_observes_the_same_histograms(engine):
     node, client = await gateway([TPUService("tiny-llama", engine=engine)])
     try:

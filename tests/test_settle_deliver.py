@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from bee2bee_tpu.engine import EngineConfig, InferenceEngine
+from bee2bee_tpu.engine.introspect import GoodputMeter
 from bee2bee_tpu.engine.scheduler import (
     _C_WINDOW_DELIVERIES,
     BatchScheduler,
@@ -62,6 +63,7 @@ class _Rows:
     def __init__(self, rows):
         self._rows = list(rows)
         self._undelivered: deque = deque()
+        self._meter = GoodputMeter(None, 1.0)  # books the window's slots
         self.vacated: list[Request] = []
 
     def _vacate(self, b, req):
