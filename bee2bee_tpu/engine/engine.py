@@ -51,6 +51,7 @@ from ..parallel.mesh import local_mesh
 from ..tracing import current_timing, prog_scope
 from ..utils import MetricsAggregator
 from .paged import LatentPoolUnsupported, RecurrentStateUnsupported
+from .programs import StoredPrograms
 from .tokenizer import load_tokenizer
 
 logger = logging.getLogger("bee2bee_tpu.engine")
@@ -72,6 +73,14 @@ _C_TOKENS_OUT = get_registry().counter(
 )
 
 DEFAULT_BUCKETS = (64, 128, 256, 512, 1024, 2048, 4096, 8192)
+# the prefill root's rows: an admission burst's requests of one bucket run
+# as ONE [n, bucket] program (scheduler._admit), n from this ladder, while
+# the call stays inside the token budget — which bounds the call's
+# temporaries and the compile space ([2|4|8, 64], [2|4|8, 128], [2|4, 256],
+# [2, 512] beside the [1, bucket] programs). Derived from nothing a user sets.
+PREFILL_GROUP_ROWS = (1, 2, 4, 8)
+PREFILL_GROUP_TOKENS = 1024
+PREFILL_GROUP_MAX_BUCKET = 512
 
 
 def _env_flag(name: str, default: bool) -> bool:
@@ -390,7 +399,11 @@ class InferenceEngine:
         } | {self.max_seq_len}
         if self.engine_cfg.prefill_chunk:
             prefill_widths.add(self.engine_cfg.prefill_chunk)
-        self._declared_prefill_widths = frozenset(prefill_widths)
+        # ... by the rows of a grouped prefill: [1, width] for every width,
+        # [n, bucket] on the group ladder
+        self._declared_prefill_shapes = frozenset(
+            (n, w) for w in prefill_widths for n in self.prefill_group_rows(w)
+        )
         # batch buckets: the CLOSURE of {1} under the scheduler's actual
         # resize ops — grow min(2b, max_batch), shrink max(1, b//2) — so
         # a non-pow2 max_batch's shrink ladder (6 -> 3 -> 1) is declared
@@ -406,15 +419,27 @@ class InferenceEngine:
             frontier.add(min(2 * b, mb))
             frontier.add(max(1, b // 2))
         self._declared_batch_sizes = frozenset(sizes)
-        # one jit object; it specializes per tokens shape (= per bucket)
-        self._prefill = self.introspect.sentinel.watch(
+        # stored programs of this engine's roots (engine/programs.py): what
+        # their text depends on beside the call's own signature and the
+        # build, and the devices they run on
+        self.stored_programs = functools.partial(
+            StoredPrograms,
+            salt=repr((self.model_cfg, self.engine_cfg, dict(self.mesh.shape))),
+            devices=list(self.mesh.devices.flat),
+        )
+        # one jit object; it specializes per tokens shape (= rows x bucket).
+        # The programs the boot warm-up makes resident are loaded executables
+        # (scheduler.warm_prefill); every other shape compiles on first use
+        self._prefill = self.stored_programs(
             "prefill",
-            jax.jit(self._prefill_fn, donate_argnums=(2,),
-                    donate_argnames=("state",)),
-            key_fn=self._prefill_key,
-            allowed=lambda key: (
-                key[0] == 1 and key[1] in self._declared_prefill_widths
+            self.introspect.sentinel.watch(
+                "prefill",
+                jax.jit(self._prefill_fn, donate_argnums=(2,),
+                        donate_argnames=("state",)),
+                key_fn=self._prefill_key,
+                allowed=lambda key: key[:2] in self._declared_prefill_shapes,
             ),
+            self._prefill_key,
         )
         # speculative-decode verify step: [B, K+1] forward through the
         # same cache write paths, donated like the decode cache
@@ -515,12 +540,13 @@ class InferenceEngine:
         """Sentinel shape key for the prefill root: the dims that select
         a compiled variant — batch rows, the padded token width (the
         bucket), the block-table width bucket, and the None-flags of the
-        optional operands (each flag is a distinct legitimate trace)."""
+        optional operands (each flag is a distinct legitimate trace: a
+        recurrent row's carried state among them)."""
         return (
             int(tokens.shape[0]), int(tokens.shape[1]),
             None if block_tables is None else int(block_tables.shape[1]),
             write_floor is not None, write_ceil is not None,
-            adapters is not None,
+            adapters is not None, state is not None,
         )
 
     @staticmethod
@@ -789,7 +815,12 @@ class InferenceEngine:
     def _prefill_fn(self, params, tokens, cache, true_len, offset,
                     block_tables=None, write_floor=None, write_ceil=None,
                     adapters=None, aids=None, ascales=None, state=None):
-        """tokens [B, Tb] padded; returns (cache, last_logits [B, V]).
+        """tokens [B, Tb] padded; returns (cache, last_logits [B, V]). The
+        rows are the requests of one admission group (scheduler._admit:
+        B on PREFILL_GROUP_ROWS), each with its own `true_len`, `offset`,
+        table row, `write_floor` and `write_ceil` ([B]; a scalar holds
+        for every row); a DEAD row (true_len 0, write_ceil 0, a null table
+        row) writes nothing and leaves its state slot as it was.
         `offset` is the global cache position of tokens[:, 0] — 0 for a
         whole-prompt prefill, the running position for chunked prefill.
         `true_len` is the valid length WITHIN this chunk. With
@@ -802,18 +833,23 @@ class InferenceEngine:
         factors apply to the PROMPT too — an adapted wk/wv writes
         adapter-specific K/V, which is exactly why adapter rows never
         share the base model's prefix cache (scheduler guard).
-        ``state`` (recurrent models): the prefilling ROW's state slot
-        ([L, 1, ...], zero for a fresh row, the previous chunk's output
-        otherwise), donated; returned third, in a dict that also holds an
+        ``state`` (recurrent models): the prefilling ROWS' state slots
+        ([L, B, ...]: the previous chunk's output, donated; None for fresh
+        rows, whose zero state — "no token seen" — is made here); the state
+        after the chunk is returned third, in a dict that also holds an
         expert model's ``moe_stats``. The bucket's padded tail
         leaves it untouched (``valid_len``), and only the last real
         position's logits are computed."""
-        recurrent = state is not None
+        recurrent = self.model_cfg.has_ssm
+        if recurrent and state is None:
+            state = core.init_ssm_state(
+                self.model_cfg, tokens.shape[0], dtype=self.dtype)
         moe = self.model_cfg.moe_dropless
         # the head for ONE position a row (recurrent models since PR 28, and
         # latent-attention ones: at a 129,280-token vocabulary the full
         # [1, 512, V] logits are 0.27 GB); phi-3's programs stay as they were
         one_logit = recurrent or self.model_cfg.has_mla
+        last = jnp.maximum(jnp.asarray(true_len, jnp.int32) - 1, 0)  # dead row: 0
         if moe:  # the forward's expert-layer counters: extras["moe_stats"]
             cache = dict(cache, moe_stats=jnp.zeros(
                 (len(core.MOE_STATS),), jnp.int32))
@@ -824,20 +860,18 @@ class InferenceEngine:
             paged_write_floor=write_floor, paged_write_ceil=write_ceil,
             adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
             valid_len=true_len if recurrent else None,
-            last_index=true_len - 1 if one_logit else None,
+            last_index=last if one_logit else None,
         )
         # what the chunk hands back beside the pool, by NAME: the row's
         # recurrent state and / or the expert layers' counters
         extras = {k: cache.pop(k) for k in
                   tuple(state or ()) + (("moe_stats",) if moe else ())}
-        if one_logit:
-            last = logits
-        else:
-            idx = (true_len - 1).reshape(-1, 1, 1)  # [B,1,1]
-            last = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
+        if not one_logit:
+            idx = last.reshape(-1, 1, 1)  # [B,1,1]
+            logits = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
         if extras:
-            return cache, last[:, 0, :], extras
-        return cache, last[:, 0, :]
+            return cache, logits[:, 0, :], extras
+        return cache, logits[:, 0, :]
 
     @prog_scope("prog.verify")
     def _spec_verify_fn(self, params, cur, drafts, draft_lens, cache, offsets,
@@ -901,6 +935,15 @@ class InferenceEngine:
         return nxt, cache, accepted, counts
 
     # ------------------------------------------------------------ helpers
+
+    def prefill_group_rows(self, bucket: int) -> tuple[int, ...]:
+        """The row counts a prefill program of this padded width may have,
+        ascending: the group ladder inside the token budget, 1 alone for a
+        width over the ladder's widest bucket."""
+        if bucket > PREFILL_GROUP_MAX_BUCKET:
+            return (1,)
+        return tuple(n for n in PREFILL_GROUP_ROWS
+                     if n == 1 or n * bucket <= PREFILL_GROUP_TOKENS)
 
     def _bucket_for(self, n: int) -> int:
         for b in self.engine_cfg.prefill_buckets:

@@ -381,6 +381,16 @@ def _state_insert(st, row, b):
             big, r.astype(big.dtype), b, axis=1), st, row)
 
 
+@_donating
+@prog_scope("prog.pool")
+def _state_scatter(st, rows, slots):
+    """``rows`` [L, n, ...] into the slots ``slots`` [n] of every leaf; a
+    slot past the bucket (a dead row of the group) is dropped."""
+    return jax.tree.map(
+        lambda big, r: big.at[:, slots].set(r.astype(big.dtype), mode="drop"),
+        st, rows)
+
+
 @functools.partial(jax.jit, static_argnums=(1,))
 @prog_scope("prog.pool")
 def _state_shrink(st, n):
@@ -574,16 +584,30 @@ class RowCache:
             self.state = _state_shrink(self.state, bsz)
         self._set_state_gauges(bsz)
 
-    def put_state(self, b: int, row_state):
-        """A prefilled row's state ([L, 1, ...]) into slot b."""
-        self.state = _state_insert(self.state, row_state, np.int32(b))
+    def put_state(self, rows, group_state):
+        """A prefilled group's states ([L, n, ...]) into the slots ``rows``
+        ([n]; -1 = a dead row of the group, whose state goes nowhere): one
+        program a group."""
+        slots = np.asarray(rows, np.int32)
+        self.state = _state_scatter(
+            self.state, group_state,
+            np.where(slots < 0, np.int32(self.state_rows), slots))
 
     # ---- tables for a device call
 
-    def row_table(self, b: int) -> np.ndarray:
-        """[1, tw] — row b's table for its own prefill chunk."""
-        tw = self._width(len(self.row_blocks[b]))
-        return np.ascontiguousarray(self.tables[b:b + 1, :tw])
+    def rows_table(self, rows, chunk: int = 0) -> np.ndarray:
+        """[n, tw] — the tables of the rows ``rows`` of one prefill group
+        (-1 = a dead row: the null block throughout). tw covers the longest
+        of them, and never less than a fresh prompt filling ``chunk``
+        positions would need, so that the groups of one bucket share ONE
+        width whatever their prompts' lengths."""
+        rows = np.asarray(rows, np.int64)
+        tw = self._width(max(
+            [ceil_div(chunk, self.block_size)]
+            + [len(self.row_blocks[b]) for b in rows if b >= 0]))
+        table = self.tables[np.maximum(rows, 0), :tw]  # a copy
+        table[rows < 0] = 0
+        return table
 
     def window_table(self, live_rows: list[int], bsz: int):
         """-> ([bsz, tw] table of the batch bucket, blocks the live rows

@@ -55,6 +55,7 @@ import queue
 import threading
 import time
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import jax
@@ -68,10 +69,12 @@ from ..router.fairness import WdrrQueue
 from ..router.tenants import load_tenant_config
 from ..tracing import RequestTiming, annotate, get_tracer, prog_scope
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
+from .engine import PREFILL_GROUP_MAX_BUCKET
 from .paged import (
     PoolExhausted,
     RecurrentStateUnsupported,
     RowCache,
+    best_prefix_key,
     prefill_chunk_positions,
 )
 from .sampling import sample_batched
@@ -114,7 +117,13 @@ _C_PREFILL_CALLS = _REG.counter(
 _C_PREFILL_TOKENS = _REG.counter(
     "engine.prefill_tokens",
     "positions those programs ran over (kind label: real prompt tokens | "
-    "pad of the bucket), every model",
+    "pad of the bucket and a group's dead rows), every model",
+)
+_C_PREFILL_ROWS = _REG.counter(
+    "engine.prefill_rows",
+    "rows of those programs (kind label: live = a request's | dead = a row "
+    "that only fills the group's declared shape); live / engine.prefill_calls "
+    "= requests a program",
 )
 _G_BATCH_FILL = _REG.gauge(
     "engine.batch_fill", "active rows / current batch bucket (0..1)"
@@ -419,6 +428,19 @@ class SchedulerStats:
         return self.spec_accepted / self.spec_drafted if self.spec_drafted else 0.0
 
 
+@dataclass
+class _Admission:
+    """A popped request with its batch row and its pages (RowCache.adopt /
+    cover): planned, not prefilled yet."""
+
+    req: Request
+    row: int
+    seq: list  # the tokens to prefill: the prompt (+ accepted, re-prefill rung)
+    start: int  # [0, start) came from the prefix cache: the write floor
+    windows: list  # where each chunk of its walk starts
+    recompute: bool = False  # the re-prefill rung: no useful token in it
+
+
 class BatchScheduler:
     """Decides when rows are admitted, stepped, moved and retired; where a
     row's cache lives is engine/paged.RowCache's. See module docstring."""
@@ -560,7 +582,11 @@ class BatchScheduler:
             )
         # jitted: sample_batched run eagerly is ~15 tiny ops = ~15
         # dispatches per admission
-        self._sample_first = jax.jit(prog_scope("prog.sample")(sample_batched))
+        self._sample_first = e.stored_programs(
+            "sample", jax.jit(prog_scope("prog.sample")(sample_batched)),
+            lambda logits, key, temps, topks, topps, minps=None, counts=None, *_:
+                (logits.shape[0], minps is None, counts is None),
+        )
 
         # self-speculative decoding (engine/spec.py): greedy rows draft
         # from their own prompt+output and one [B, K+1] verify call
@@ -1079,10 +1105,10 @@ class BatchScheduler:
                     self.cache.publish_prefix(b, req.ids)
             else:
                 seq = [int(t) for t in st["seq"]]
-                start, cached = self._plan_prefill(req, seq)
-                # last_logits discarded: the next token is already known
-                # (cur = out[-1]); decode resumes from it
-                self._paged_prefill(req, b, start, cached, seq=seq)
+                # a group of one; its last_logits discarded: the next token
+                # is already known (cur = out[-1]); decode resumes from it
+                planned = self._plan_row(req, b, seq, recompute=True)
+                self._prefill_group(req.bucket, [planned])
                 self._offsets[b] = len(seq)
                 self.stats.import_reprefills += 1
             self._cur[b] = int(st["cur"])
@@ -1229,110 +1255,264 @@ class BatchScheduler:
             req.bucket = e._bucket_for(remaining)
         return start, cached
 
-    def _paged_prefill(self, req: Request, b: int, start: int, cached,
-                       seq: list | None = None) -> object:
-        """Admit one request onto the paged pool: row b adopts the matched
-        prefix (RowCache.adopt: shared full blocks, at most one CoW copy),
-        the remainder chunk-prefills straight into the pool, and the
-        prompt's blocks are pinned in the prefix cache.
-        Returns last_logits [1, V]. On PoolExhausted every reference this
-        call took is released and the table row is nulled, so the caller
-        can requeue the request cleanly — and the raise happens BEFORE any
-        device work (block sufficiency is prechecked), so a requeue-retry
-        cycle under pool pressure never redoes CoW copies or prefill
-        chunks, and never double-counts prefix stats.
-
-        ``seq`` overrides the token sequence prefilled (default: the
-        prompt). The re-prefill import rung (_paged_import) passes
-        prompt + accepted-so-far — one chunk walk, two consumers."""
-        e, bucket = self.engine, req.bucket  # _plan_prefill chose it
-        # goodput accounting: a re-prefill (migration/failover import —
-        # `seq` passed) recomputes K/V the fleet already paid for once;
-        # its positions are scheduled work that produces zero USEFUL
-        # tokens, which is exactly how the meter is told to book it
-        recompute = seq is not None
-        if seq is None:
-            seq = req.ids
+    def _plan_row(self, req: Request, b: int, seq: list,
+                  recompute: bool = False) -> _Admission:
+        """Give one request its pages on row b, host work but for a prefix
+        hit's one CoW copy: row b adopts the matched prefix (RowCache.adopt:
+        shared full blocks, at most one copied) and is covered to the end of
+        ``seq`` (the prompt; prompt + accepted-so-far on the re-prefill
+        import rung, ``recompute``). On PoolExhausted every reference taken
+        is released and the table row nulled, so the caller can requeue the
+        request cleanly — and the raise comes BEFORE any prefill (block
+        sufficiency is prechecked), so a requeue-retry cycle under pool
+        pressure never redoes prefill chunks nor double-counts prefix
+        stats."""
+        start, cached = self._plan_prefill(req, seq)
         n = len(seq)
         try:
             copied = self.cache.adopt(b, n, start, cached)
-            if cached is not None:
-                self.stats.paged_blocks_copied += copied
-                self.stats.prefix_hits += 1
-                self.stats.prefix_tokens_saved += start
-            # the chunk walk (paged.prefill_chunk_positions — adopt's
-            # precheck simulated exactly these windows). The
-            # capacity re-anchor can re-feed tokens BELOW `start`;
-            # recomputed K/V under a different chunk geometry is not
-            # guaranteed bit-identical, so the write floor keeps shared
-            # donor blocks read-only (attention still reads the donor's
-            # values there)
-            # a recurrent row's state is carried from chunk to chunk in a
-            # slot of its own ([L, 1, ...], zero = "no token seen") and
-            # joins the batch's state once the walk is over
-            row_state = e.new_state(1)
-            fed = start
-            for pos in prefill_chunk_positions(n, start, bucket, e.max_seq_len):
-                # the write ceil (n) turns the bucket's padded-tail
-                # scatters into null-block writes, so the row only ever
-                # claims blocks covering real prompt positions
-                self.cache.cover(b, min(pos + bucket, n))
-                chunk = seq[pos:pos + bucket]
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, :len(chunk)] = chunk
-                tbl = self.cache.row_table(b)
-                if row_state is not None and pos != fed:
-                    # engine._validate_recurrent_features makes the walk
-                    # monotone; a re-fed token would be absorbed twice,
-                    # so never run past this
-                    raise RuntimeError(
-                        f"recurrent prefill walk re-anchored: window at "
-                        f"{pos}, state holds {fed} tokens"
-                    )
-                fed = pos + len(chunk)
-                out = e._prefill(
-                    e.params, tokens, self.cache.pool,
-                    np.asarray([len(chunk)], np.int32),
-                    np.int32(pos), tbl, np.int32(start), np.int32(n),
-                    **({"state": row_state} if row_state is not None
-                       else self._lora_args_row(req)),
+            # the write ceil (n) turns the bucket's padded-tail scatters
+            # into null-block writes, so the row only ever claims blocks
+            # covering real positions (adopt's precheck counted these)
+            self.cache.cover(b, n)
+        except PoolExhausted:
+            self._release_row(b)
+            raise
+        if cached is not None:
+            self.stats.paged_blocks_copied += copied
+            self.stats.prefix_hits += 1
+            self.stats.prefix_tokens_saved += start
+        return _Admission(
+            req, b, seq, start,
+            prefill_chunk_positions(n, start, req.bucket, self.engine.max_seq_len),
+            recompute,
+        )
+
+    def _waits_for_a_planned_prefix(self, burst: list, req: Request) -> bool:
+        """Would ``req`` share at least a block more with a request of this
+        burst, planned and not prefilled yet, than the prefix cache gives it
+        now? A prompt is published with its prefill's dispatch, so such a
+        request is planned a round later and adopts those blocks, as it did
+        when the burst ran one request at a time."""
+        if self.cache.prefix is None or req.adapter or not burst:
+            return False
+        _, m = best_prefix_key(
+            (a.seq for a in burst if not a.req.adapter), req.ids)
+        return (m >= self.cache.block_size
+                and m > self.cache.match_prefix(req.ids)[0])
+
+    def _cut_groups(self, burst: list) -> list:
+        """An admission burst as the prefill programs that run it: the
+        requests of one bucket share calls, each the widest the engine
+        declares ([n, bucket] on its group ladder) that their count fills —
+        five are 4 + 1 — so a group has no dead row and no request rides in
+        a wider bucket: the positions of one call a request, and fewer
+        reads of the weights. Alone goes who cannot share a call: a prompt
+        that walks more than one chunk, an adapter's row (its factors are a
+        row's argument), a penalized row (its counts seed its own sample).
+        The largest group first: the chip is fed soonest with the most work."""
+        groups: list[list[_Admission]] = []
+        by_bucket: dict[int, list[_Admission]] = {}
+        for a in burst:
+            if len(a.windows) > 1 or a.req.adapter or a.req.penalized:
+                groups.append([a])
+            else:
+                by_bucket.setdefault(a.req.bucket, []).append(a)
+        for bucket, rows in by_bucket.items():
+            sizes = self.engine.prefill_group_rows(bucket)
+            while rows:
+                n = max(k for k in sizes if k <= len(rows))
+                groups.append(rows[:n])
+                rows = rows[n:]
+        groups.sort(key=len, reverse=True)
+        return groups
+
+    def _prefill_group(self, bucket: int, group: list, dead: int = 0):
+        """Prefill one group straight into the pool: ONE program a chunk
+        over [n, bucket] tokens, row i of it the request ``group[i]`` at its
+        own offset, length, table row, write floor and write ceil; ``dead``
+        more rows fill the shape and write nothing (the boot warm-up's).
+        A group of several is one chunk each (_cut_groups); a group of one
+        walks its windows (paged.prefill_chunk_positions — adopt's precheck
+        simulated exactly these). Then the rows' recurrent state joins the
+        batch's in one insert and the prompts are pinned in the prefix
+        cache. Returns last_logits [n, V].
+
+        The capacity re-anchor can re-feed tokens BELOW a row's ``start``;
+        recomputed K/V under a different chunk geometry is not guaranteed
+        bit-identical, so the write floor keeps shared donor blocks
+        read-only (attention still reads the donor's values there)."""
+        e = self.engine
+        n = len(group) + dead
+        rows = [a.row for a in group] + [-1] * dead
+        floors = np.asarray([a.start for a in group] + [0] * dead, np.int32)
+        ceils = np.asarray([len(a.seq) for a in group] + [0] * dead, np.int32)
+        # a recurrent row's state is carried from chunk to chunk in a slot
+        # of the group's own ([L, n, ...]; the first chunk's program starts
+        # it at zero, "no token seen") and joins the batch's state once the
+        # walk is over
+        recurrent, state = e.model_cfg.has_ssm, None
+        fed = floors.copy()
+        # goodput accounting: a re-prefill (migration/failover import)
+        # recomputes K/V the fleet already paid for once; its positions are
+        # scheduled work that produces zero USEFUL tokens
+        useful = [i for i, a in enumerate(group) if not a.recompute]
+        last_logits = None
+        for w in range(len(group[0].windows) if group else 1):
+            tokens = np.zeros((n, bucket), np.int32)
+            true_len = np.zeros((n,), np.int32)
+            offset = np.zeros((n,), np.int32)
+            for i, a in enumerate(group):
+                pos = a.windows[w]
+                chunk = a.seq[pos:pos + bucket]
+                tokens[i, :len(chunk)] = chunk
+                true_len[i], offset[i] = len(chunk), pos
+            if recurrent and (offset != fed).any():
+                # engine._validate_recurrent_features makes the walk
+                # monotone; a re-fed token would be absorbed twice, so
+                # never run past this
+                raise RuntimeError(
+                    f"recurrent prefill walk re-anchored: windows at "
+                    f"{offset.tolist()}, states hold {fed.tolist()} tokens"
                 )
-                self.cache.count_pages_written(1, bucket)
-                self.cache.pool, last_logits, *extras = out
-                extras = dict(extras[0]) if extras else {}
-                if "moe_stats" in extras:  # an expert model's counters
-                    self._moe_pending.append(extras.pop("moe_stats"))
-                    # positions past the prompt's end are pad even where a
-                    # re-anchored window re-feeds real ones
-                    real = min(len(chunk), n - pos)
-                    self._count_moe(real, bucket - real, 1)
-                _C_PREFILL_CALLS.inc(bucket=str(bucket))
-                _C_PREFILL_TOKENS.inc(len(chunk), kind="real")
-                _C_PREFILL_TOKENS.inc(bucket - len(chunk), kind="pad")
-                if row_state is not None:
-                    row_state = extras
-                    _C_SSM_SCAN_TOKENS.inc(len(chunk), kind="real")
-                    _C_SSM_SCAN_TOKENS.inc(bucket - len(chunk), kind="pad")
-                # economics: the bucket's padded width is what the chip
-                # ran; only the real prompt tokens were useful (and none
-                # on the re-prefill rung)
-                self._meter.record_dispatch(
-                    bucket, pos + bucket / 2.0, scheduled=bucket
-                )
-                if not recompute:
-                    self._meter.note_useful(len(chunk))
-            if row_state is not None:
-                self.cache.put_state(b, row_state)
+            fed = offset + true_len
+            out = e._prefill(
+                e.params, tokens, self.cache.pool, true_len, offset,
+                self.cache.rows_table(rows, bucket), floors, ceils,
+                **({"state": state} if state is not None
+                   else self._lora_args_row(group[0].req) if group else {}),
+            )
+            self.cache.count_pages_written(n, bucket)
+            self.cache.pool, last_logits, *extras = out
+            extras = dict(extras[0]) if extras else {}
+            real = int(true_len.sum())
+            pad = n * bucket - real  # the buckets' tails and the dead rows
+            if "moe_stats" in extras:  # an expert model's counters
+                self._moe_pending.append(extras.pop("moe_stats"))
+                self._count_moe(real, pad, 1)
+            _C_PREFILL_CALLS.inc(bucket=str(bucket))
+            _C_PREFILL_ROWS.inc(len(group), kind="live")
+            _C_PREFILL_ROWS.inc(dead, kind="dead")
+            _C_PREFILL_TOKENS.inc(real, kind="real")
+            _C_PREFILL_TOKENS.inc(pad, kind="pad")
+            if recurrent:
+                state = extras
+                _C_SSM_SCAN_TOKENS.inc(real, kind="real")
+                _C_SSM_SCAN_TOKENS.inc(pad, kind="pad")
+            # economics: the padded width is what the chip ran; only the
+            # real prompt tokens were useful (none on the re-prefill rung)
+            self._meter.record_dispatch(
+                n * bucket, float(offset.mean()) + bucket / 2.0,
+                scheduled=n * bucket,
+            )
+            self._meter.note_useful(int(true_len[useful].sum()))
+        if recurrent:
+            self.cache.put_state(rows, state)
+        for a in group:
             # adapter rows NEVER enter the prefix cache: an adapted wk/wv
             # writes adapter-specific K/V, so sharing those blocks with a
             # base-model (or other-adapter) prompt would serve silently
             # wrong attention — sharing stays base-model-only
-            if not req.adapter:
-                self.cache.publish_prefix(b, seq)
-            return last_logits
-        except PoolExhausted:
-            self._release_row(b)
-            raise
+            if not a.req.adapter:
+                self.cache.publish_prefix(a.row, a.seq)
+        return last_logits
+
+    def _first_tokens(self, group: list):
+        """One group of a burst on the chip: its prefill (_prefill_group)
+        and ONE sample of its rows' first tokens -> [n] int32, not fetched."""
+        e, reqs = self.engine, [a.req for a in group]
+        with get_tracer().span(
+            "engine.admit", rows=len(group), bucket=reqs[0].bucket,
+            prompt_tokens=sum(len(a.seq) for a in group),
+            prefix=sum(a.start for a in group),
+        ):
+            # np arguments throughout: jit converts them on entry (one
+            # small transfer), no eager ops, no blocking
+            last_logits = self._prefill_group(reqs[0].bucket, group)
+            # one arg tuple for plain and penalized rows: a marshalling
+            # change must hit both identically
+            sample_args = [
+                last_logits,
+                e._next_key(),
+                np.asarray([r.temperature for r in reqs], np.float32),
+                np.asarray([r.top_k for r in reqs], np.int32),
+                np.asarray([r.top_p for r in reqs], np.float32),
+                (np.asarray([r.min_p for r in reqs], np.float32)
+                 if any(r.min_p > 0 for r in reqs) else None),
+            ]
+            if reqs[0].penalized:  # alone in its group (_cut_groups)
+                (req,) = reqs
+                # the first sample sees the row's fresh counts
+                sample_args += [
+                    self._seed_counts(req, group[0].row),
+                    np.asarray([req.repetition_penalty], np.float32),
+                    np.asarray([req.presence_penalty], np.float32),
+                    np.asarray([req.frequency_penalty], np.float32),
+                ]
+            return self._sample_first(*sample_args)
+
+    def warm_prefill(self):
+        """Make every prefill program a burst can ask for resident, before
+        the node says that it serves: which [n, bucket] an admission meets
+        depends on who arrives together, so no warm-up traffic can promise
+        to meet them all, and a shape first met under load stalls every row
+        for its trace and compile. The declared shapes up to the group
+        ladder's widest bucket are loaded from the program store — or, on a
+        build's first boot, compiled side by side (XLA releases the GIL) and
+        stored (engine/programs.py) — then each runs once on dead rows
+        (nothing is written outside the null block), with its group's
+        sample program (stored too) and state insert, through the calls
+        that serve.
+        The caller's thread runs it, under the lock: the loop is asleep and
+        nothing is queued."""
+        e, t0 = self.engine, time.perf_counter()
+        with self._cond:
+            if self._queue or self.active or self._inflight:
+                raise RuntimeError("warm_prefill needs an idle scheduler")
+            shapes = sorted(
+                (w, n) for n, w in e._declared_prefill_shapes
+                if w <= PREFILL_GROUP_MAX_BUCKET
+            )
+
+            def like(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+            def ints(*shape):
+                return jax.ShapeDtypeStruct(shape, np.int32)
+
+            params, pool = jax.tree.map(like, (e.params, self.cache.pool))
+
+            def resident(shape) -> bool:  # the served call's arguments, as shapes
+                bucket, n = shape
+                table = self.cache.rows_table([-1] * n, bucket)
+                return e._prefill.warm(
+                    params, ints(n, bucket), pool, ints(n), ints(n),
+                    ints(*table.shape), ints(n), ints(n))
+
+            with ThreadPoolExecutor(max_workers=4) as side_by_side:
+                loaded = list(side_by_side.map(resident, shapes))
+            # whatever this boot has traced so far is forgotten. A kernel's
+            # text (the Mosaic call inside a program) holds where each of its
+            # parts was FIRST traced in the process, and the compile cache
+            # keys on it: a boot that compiled these programs has traced the
+            # model, one that loaded them has not, and the decode programs
+            # traced next would differ between the two and compile again
+            jax.clear_caches()
+            if self.cache.recurrent:
+                # at the batch bucket a loaded node holds (sticky widths):
+                # the state insert is a jit program a bucket and group size
+                self.cache.resize(self.max_batch)
+            for bucket, n in shapes:
+                logits = self._prefill_group(bucket, [], dead=n)
+                sample_args = (
+                    logits, e._next_key(), np.zeros((n,), np.float32),
+                    np.zeros((n,), np.int32), np.ones((n,), np.float32), None)
+                self._sample_first.warm(*sample_args)  # a group size's first: stored too
+                self._sample_first(*sample_args).block_until_ready()
+            if self.cache.recurrent:
+                self.cache.resize(1)
+        logger.info("prefill programs resident: %d of %d from the store, %.1f s",
+                    sum(loaded), len(shapes), time.perf_counter() - t0)
 
     def _refund(self, req: Request):
         """A popped request that will never decode: the pop charged its
@@ -1353,38 +1533,24 @@ class BatchScheduler:
                 cost=max(1.0, float(req.max_new_tokens)),
             )
 
-    @_phase("admit")
-    def _admit(self) -> bool:
-        """Prefill queued requests into free rows, growing the batch bucket
-        up to max_batch; True when a burst was placed. All prefills/inserts of an admission burst are
-        dispatched asynchronously; the first tokens come back in ONE device
-        sync (its cost is not measured on the current machine — a burst of
-        8 must not pay it 8 times while active streams sit undecoded).
-        Whenever the queue has nobody for a free row, the last settled
-        window is delivered (_deliver_next): under the prefills already
-        dispatched, and bringing the requests that follow the ended ones.
-        A call's own seconds are booked once more by PART
-        (engine.admit_seconds, annotation sched.admit.<part>): `dispatch`
-        from the first popped request on, `wait` in the gather, `emit`
-        from the gather to the end; what runs under another phase
-        (deliveries = process, resize and compaction = compact) is theirs."""
+    def _plan_burst(self, placed: bool) -> tuple[list, bool]:
+        """Pop every request the free rows allow and give each its row and
+        its pages (_plan_row) — host work, so that the whole burst is known
+        before its first program is cut. -> (the planned admissions, may
+        the call go on admitting). ``placed``: this call dispatched a group
+        already. A cancelled request, an unknown adapter and a prompt the
+        pool can never hold cost that request alone; an import is placed
+        here, whole; pool backpressure and a denied growth requeue their
+        request and end the call's admissions behind what is planned."""
         e = self.engine
-        placed: list[tuple] = []  # (req, row, firsts_index)
-        firsts: list = []
+        burst: list[_Admission] = []
         while True:
             with self._cond:
                 req = (self._queue.popleft()
                        if self._queue and self.active < self.max_batch
                        else None)
             if req is None:
-                # nobody to admit right now. A settled window's rows are
-                # delivered one at a time meanwhile, the queue looked at
-                # again after each: a caller in a closed loop sends its
-                # next request once its done event is out, and that
-                # request's prefill is then the next thing the chip gets
-                if self._deliver_next(burst=bool(placed)):
-                    continue
-                break
+                return burst, True
             # someone to admit: from here the call runs as admit's part
             # `dispatch` (engine.admit_seconds{part}; until then as "none")
             self._phases.part("dispatch")
@@ -1394,6 +1560,9 @@ class BatchScheduler:
                 req.events.put({"done": True, "result": e._build_result(req)})
                 self._refund(req)
                 continue
+            if self._waits_for_a_planned_prefix(burst, req):
+                self._requeue_front(req)
+                return burst, True
             req.timing.t_admit = time.perf_counter()
             if req.adapter:
                 # slot resolution happens at ADMISSION, not submit — the
@@ -1420,7 +1589,7 @@ class BatchScheduler:
                     # and the retry admits into a hole without growing
                     self._requeue_front(req)
                     self.stats.width_grow_denials += 1
-                    break
+                    return burst, False
                 self._resize(min(self._bsz * 2, self.max_batch))
             b = next(i for i, r in enumerate(self._rows) if r is None)
 
@@ -1460,44 +1629,12 @@ class BatchScheduler:
                 req.events.put({"imported": True})
                 continue
 
-            n = len(req.ids)
-            start, cached = self._plan_prefill(req, req.ids)
-            bucket = req.bucket
             try:
-                with get_tracer().span(
-                    "engine.admit", row=b, prompt_tokens=n, bucket=bucket,
-                    prefix=start,
-                ):
-                    # np arguments throughout: jit converts them on entry
-                    # (one small transfer), no eager ops, no blocking.
-                    # Prefill straight into the shared pool through the
-                    # row's block table; prefix hits share the donor's
-                    # full blocks CoW (engine/paged.py)
-                    last_logits = self._paged_prefill(req, b, start, cached)
-                    # one arg tuple for plain and penalized rows: a
-                    # marshalling change must hit both identically
-                    sample_args = [
-                        last_logits,
-                        e._next_key(),
-                        np.asarray([req.temperature], np.float32),
-                        np.asarray([req.top_k], np.int32),
-                        np.asarray([req.top_p], np.float32),
-                        (np.asarray([req.min_p], np.float32)
-                         if req.min_p > 0 else None),
-                    ]
-                    if req.penalized:
-                        # the first sample sees the row's fresh counts
-                        sample_args += [
-                            self._seed_counts(req, b),
-                            np.asarray([req.repetition_penalty], np.float32),
-                            np.asarray([req.presence_penalty], np.float32),
-                            np.asarray([req.frequency_penalty], np.float32),
-                        ]
-                    first = self._sample_first(*sample_args)
+                planned = self._plan_row(req, b, req.ids)
             except PoolExhausted as err:
-                # backpressure, not failure: _paged_prefill released the
-                # row's blocks before raising. With work in flight (or a
-                # burst just placed) blocks WILL free — requeue at the
+                # backpressure, not failure: _plan_row released the row's
+                # blocks before raising. With work in flight (or a burst
+                # planned or placed) blocks WILL free — requeue at the
                 # front and admit again after the next window. With
                 # nothing in flight and nothing left to evict, this
                 # request can never fit the configured pool: fail it.
@@ -1506,7 +1643,7 @@ class BatchScheduler:
                 if self.active > 0 or placed:
                     self._requeue_front(req)
                     self.stats.paged_alloc_waits += 1
-                    break
+                    return burst, False
                 self._fail(req, f"admission failed: {err} "
                                 "(kv_pool_blocks too small for this request)")
                 # TERMINAL exhaustion (nothing in flight to free blocks) is
@@ -1525,12 +1662,49 @@ class BatchScheduler:
                 # (which errors the rest of this burst — they sit in _rows)
                 self._fail(req, f"admission failed: {err!r}")
                 raise
-            # reserve the row now (cur gets the real token after readback)
+            # the row is the request's from here (cur gets the real token
+            # after readback): the next free row is another, and a failure
+            # of its group's programs finds it in _rows (_fail_all)
             self._rows[b] = req
-            self._offsets[b] = n
+            self._offsets[b] = len(req.ids)
             self._aids[b] = req.adapter_slot
-            placed.append((req, b, len(firsts)))
-            firsts.append(first)
+            burst.append(planned)
+
+    @_phase("admit")
+    def _admit(self) -> bool:
+        """Prefill queued requests into free rows, growing the batch bucket
+        up to max_batch; True when a burst was placed. Everyone queued NOW
+        is planned first (_plan_burst: rows and pages, host work), then the
+        burst is cut into groups (_cut_groups) and each group runs as ONE
+        prefill program and ONE sample (_first_tokens), all dispatched
+        asynchronously; the first tokens come back in ONE device sync (a
+        burst of 8 must not pay it 8 times while active streams sit
+        undecoded). Whenever the queue has nobody for a free row, the last
+        settled window is delivered (_deliver_next), a row at a time: under
+        the prefills already dispatched, and bringing the requests that
+        follow the ended ones, which form later, smaller groups.
+        A call's own seconds are booked once more by PART
+        (engine.admit_seconds, annotation sched.admit.<part>): `dispatch`
+        from the first popped request on, `wait` in the gather, `emit`
+        from the gather to the end; what runs under another phase
+        (deliveries = process, resize and compaction = compact) is theirs."""
+        placed: list[tuple] = []  # (req, row, its group in firsts, its row there)
+        firsts: list = []  # a group's first tokens [n], on the device
+        while True:
+            burst, go_on = self._plan_burst(bool(placed))
+            for group in self._cut_groups(burst):
+                placed += [(a.req, a.row, len(firsts), j)
+                           for j, a in enumerate(group)]
+                firsts.append(self._first_tokens(group))
+            if not go_on:
+                break
+            # nobody to admit right now. A settled window's rows are
+            # delivered one at a time meanwhile, the queue looked at
+            # again after each: a caller in a closed loop sends its
+            # next request once its done event is out, and that
+            # request's prefill is then the next thing the chip gets
+            if not burst and not self._deliver_next(burst=bool(placed)):
+                break
 
         if not placed:
             return False
@@ -1539,12 +1713,12 @@ class BatchScheduler:
         # fetches all; no eager concatenate op on device). The part `wait`:
         # the chip runs the burst's prefills and no decode window meanwhile
         self._phases.part("wait")
-        toks = np.concatenate([np.asarray(x) for x in jax.device_get(firsts)])
+        toks = jax.device_get(firsts)
         self._phases.part("emit")
         _H_BURST.observe(len(placed))
         now = time.perf_counter()
-        for req, b, i in placed:
-            tok = int(toks[i])
+        for req, b, i, j in placed:
+            tok = int(toks[i][j])
             req.timing.t_first = now
             t = req.timing
             _H_QUEUE_WAIT.observe((t.t_admit - t.t_submit) * 1000.0)
@@ -1583,7 +1757,7 @@ class BatchScheduler:
         # the first token was sampled above — so the existing histograms
         # measure the handoff regime unchanged.
         if self.handoff_after_prefill and self.migrate_cb is not None:
-            for req, b, _i in placed:
+            for req, b, _i, _j in placed:
                 if self._rows[b] is not req or req.done or req.cancelled:
                     continue
                 if req.max_new_tokens - len(req.out_ids) < 2:

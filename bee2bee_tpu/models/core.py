@@ -1442,8 +1442,8 @@ def forward(
     remat: bool = False,  # jax.checkpoint each layer (training: HBM for FLOPs)
     attn_fn=None,  # custom attention (ops.flash / parallel.ring); None = dense
     block_tables=None,  # [B, MB] int32: paged cache — see below
-    paged_write_floor=None,  # [] int32: drop paged WRITES below this position
-    paged_write_ceil=None,  # [] int32: drop paged WRITES at/after this position
+    paged_write_floor=None,  # [] or [B] int32: drop a row's paged WRITES below this position
+    paged_write_ceil=None,  # [] or [B] int32: drop a row's paged WRITES at/after this position
     adapters=None,  # multi-LoRA serving (adapters/pool.py): stacked pool
     # factors {target: {"a": [L, N, din, r], "b": [L, N, r, dout]}}
     adapter_ids=None,  # [B] int32: each row's pool slot (0 = no adapter)
@@ -1484,7 +1484,10 @@ def forward(
 
     ``paged_write_floor`` / ``paged_write_ceil`` (paged only): scatter
     writes outside [floor, ceil) are redirected to the null block — reads
-    still see the existing pool content. The floor protects copy-on-write
+    still see the existing pool content. Each is one position for the
+    whole chunk or one A ROW ([B]: the rows of a grouped prefill have
+    their own share points and prompt ends; a row whose ceil is 0 writes
+    nothing). The floor protects copy-on-write
     shares (the engine's chunked-prefill capacity re-anchor can re-feed
     tokens BELOW a share point, and recomputed K/V under a different
     chunk geometry is not guaranteed bit-identical, so shared donor
@@ -1549,14 +1552,15 @@ def forward(
         bt = jnp.asarray(block_tables, jnp.int32)
         BS = cache[pool_key].shape[3]  # pool block size
         S = bt.shape[1] * BS  # gathered view width = logical positions
-        wfloor = (
-            jnp.asarray(paged_write_floor, jnp.int32)
-            if paged_write_floor is not None else None
-        )
-        wceil = (
-            jnp.asarray(paged_write_ceil, jnp.int32)
-            if paged_write_ceil is not None else None
-        )
+
+        def row_limit(x):  # [] or [B] -> [B, 1], beside positions [B, T]
+            if x is None:
+                return None
+            x = jnp.asarray(x, jnp.int32).reshape(-1)
+            return jnp.broadcast_to(x, (B,))[:, None]
+
+        wfloor = row_limit(paged_write_floor)
+        wceil = row_limit(paged_write_ceil)
     else:
         bt = None
         S = cache["k"].shape[2] if cache is not None else None
@@ -1672,7 +1676,7 @@ def forward(
             if page_write is not None:
                 lcache = dict(lcache, latent=page_write(
                     lcache["latent"], latent[:, :, None, :], bt, off_b,
-                    layer_idx, wfloor, wceil))
+                    layer_idx, paged_write_floor, paged_write_ceil))
                 return lcache["latent"]
             # (the floor / ceil redirects to the null block: kv_hook below)
             blk = jnp.take_along_axis(bt, positions // BS, axis=1)
@@ -1729,7 +1733,7 @@ def forward(
                     lcache = dict(lcache, **{
                         name: page_write(
                             lcache[name], new, bt, off_b, layer_idx,
-                            wfloor, wceil,
+                            paged_write_floor, paged_write_ceil,
                         )
                         for name, new in (("k", k), ("v", v))
                     })

@@ -663,27 +663,28 @@ def chunk_pages(T: int, BS: int) -> int:
     return (T + BS - 2) // BS + 1
 
 
-def _write_limits(floor, ceil):
-    """[2] int32 (floor, ceil) of the written positions; None = no limit."""
+def _write_limits(floor, ceil, rows: int):
+    """[2, rows] int32 (floor, ceil) of each row's written positions; a
+    scalar holds for every row, None = no limit."""
     return jnp.stack([
-        jnp.asarray(0 if floor is None else floor, jnp.int32).reshape(()),
-        jnp.asarray(
-            jnp.iinfo(jnp.int32).max if ceil is None else ceil, jnp.int32
-        ).reshape(()),
+        jnp.broadcast_to(jnp.asarray(x, jnp.int32).reshape(-1), (rows,))
+        for x in (0 if floor is None else floor,
+                  jnp.iinfo(jnp.int32).max if ceil is None else ceil)
     ])
 
 
-def _written_span(off, lim_ref, chunk):
-    """[lo, hi): the positions of a row's chunk that may be written — the
-    chunk itself, cut by the write floor and ceil."""
-    return jnp.maximum(off, lim_ref[0]), jnp.minimum(off + chunk, lim_ref[1])
+def _written_span(off, lim_ref, b, chunk):
+    """[lo, hi): the positions of row b's chunk that may be written — the
+    chunk itself, cut by the row's write floor and ceil."""
+    return (jnp.maximum(off, lim_ref[0, b]),
+            jnp.minimum(off + chunk, lim_ref[1, b]))
 
 
 def _page_write_kernel(
     tables_ref,  # SMEM [B, MB] int32 (the pool's index map reads it)
     off_ref,  # SMEM [B] int32: position of the chunk's first token
     lay_ref,  # SMEM [1] int32: layer of the stacked pool (index map)
-    lim_ref,  # SMEM [2] int32: write floor, write ceil
+    lim_ref,  # SMEM [2, B] int32: each row's write floor, write ceil
     new_ref,  # [Hkv, BS, hd] the chunk's rows laid out as THIS page's slots
     #           ([Hkv, 1, hd] for a one-token chunk: its one row)
     page_ref,  # [Hkv, BS, hd] the page as the pool holds it
@@ -692,8 +693,9 @@ def _page_write_kernel(
     block_size: int,
     chunk: int,
 ):
-    off = off_ref[pl.program_id(0)]
-    lo, hi = _written_span(off, lim_ref, chunk)
+    b = pl.program_id(0)
+    off = off_ref[b]
+    lo, hi = _written_span(off, lim_ref, b, chunk)
     page = page_ref[...]
     pos = (off // block_size + pl.program_id(1)) * block_size + (
         jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
@@ -709,14 +711,14 @@ def paged_kv_write(
     block_tables,  # [B, MB] int32
     offset,  # [] or [B] int32: position of new[:, 0]
     layer,  # [] or [1] int32 (traced ok)
-    floor=None,  # [] int32: positions below it are not written
-    ceil=None,  # [] int32: positions at / over it are not written
+    floor=None,  # [] or [B] int32: a row's positions below it are not written
+    ceil=None,  # [] or [B] int32: a row's positions at / over it are not written
     interpret: bool | None = None,
 ):
     """Store a chunk's K (or V) into the pages its positions map to, IN
     PLACE in the stacked pool; returns the pool (the same buffer: the
     operand is aliased to the result). Row b's position ``p`` in
-    ``[offset_b, offset_b + T)`` and in ``[floor, ceil)`` goes to slot
+    ``[offset_b, offset_b + T)`` and in ``[floor_b, ceil_b)`` goes to slot
     ``p % BS`` of block ``tables[b, p // BS]`` of every KV head of
     ``layer``; every other byte of every block a row owns keeps its value.
 
@@ -740,7 +742,7 @@ def paged_kv_write(
     tables = jnp.asarray(block_tables, jnp.int32)
     off = jnp.broadcast_to(jnp.asarray(offset, jnp.int32).reshape(-1), (B,))
     lay = jnp.asarray(layer, jnp.int32).reshape(-1)[:1]
-    lim = _write_limits(floor, ceil)
+    lim = _write_limits(floor, ceil, B)
     n_pages = chunk_pages(T, BS)
     new = new.astype(pool.dtype)
     if new.shape[-1] != hd:  # a lane-aligned pool: its pad lanes hold zeros
@@ -756,7 +758,7 @@ def paged_kv_write(
 
     def page_index(b, p, tb, off_, lay_, lim_):
         page = off_[b] // BS + p
-        lo, hi = _written_span(off_[b], lim_, T)
+        lo, hi = _written_span(off_[b], lim_, b, T)
         live = (page * BS < hi) & (page * BS + BS > lo) & (page < MB)
         return (
             lay_[0], 0,
@@ -923,7 +925,7 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
             )
         kv_ax = axes[2]
         off, lay = scalars(new.shape[0], offset, layer)
-        lim = _write_limits(floor, ceil)
+        lim = _write_limits(floor, ceil, new.shape[0])
         mapped = shard_map(
             lambda p_, n_, t_, o_, l_, m_: paged_kv_write(
                 p_, n_, t_, o_, l_, m_[0], m_[1], interpret=interpret

@@ -64,6 +64,9 @@ class TPUService(BaseService):
                 engine_config=self._engine_config,
                 lora_path=self._lora_path,
             )
+            # every prefill program an admission burst can ask for, before
+            # the node announces the model (scheduler.warm_prefill)
+            self.engine.scheduler.warm_prefill()
         if self.model_name in (None, "", "auto"):
             # `--model auto`: advertise the name the checkpoint's config
             # resolved to, not the sentinel

@@ -102,6 +102,7 @@ REQUIRED_PRESENT = {
     "engine.admit_seconds",
     "engine.admit_burst_requests",
     "engine.prefill_calls",
+    "engine.prefill_rows",  # ISSUE 42: the rows of those programs
     "engine.prefill_tokens",
     "engine.decode_slots",
     "engine.paged_blocks_in_use",

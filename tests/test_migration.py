@@ -336,7 +336,9 @@ def test_import_pool_exhausted_is_typed_and_immediate():
     a = _engine()
     tiny = _engine(kv_pool_blocks=3)  # null block + 2: can't host 3 blocks
     try:
-        snap, kv, _req = _checkpoint_mid_decode(a, min_tokens=16)
+        # a budget well past the 16 tokens waited for: the row decodes on
+        # while this thread reads, and must still be live at the checkpoint
+        snap, kv, _req = _checkpoint_mid_decode(a, min_tokens=16, max_new_tokens=64)
         assert snap["kv_blocks"] >= 3
         req2 = tiny.import_generation(snap, kv)
         ev = req2.events.get(timeout=60)

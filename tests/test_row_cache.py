@@ -93,7 +93,7 @@ def test_compaction_move_and_bucket_resize(model):
         moved = list(rc.row_blocks[3])
         if rc.recurrent:
             marked = jax.tree.map(lambda a: a + 3, eng.new_state(1))
-            rc.put_state(3, marked)
+            rc.put_state([3], marked)
         rc.move(3, 1)
         assert rc.row_blocks[1] == moved and rc.row_blocks[3] == []
         assert rc.tables[1, :3].tolist() == moved and not rc.tables[3].any()
@@ -169,7 +169,7 @@ def test_adopt_refuses_before_any_copy_when_the_prompt_cannot_fit():
         with pytest.raises(PoolExhausted):
             rc.adopt(1, len(longer), start, cached)
         assert rc.pool is pool_before  # no device work happened
-        rc.release(1)  # the caller's move (scheduler._paged_prefill)
+        rc.release(1)  # the caller's move (scheduler._plan_row)
         # the pins were given up trying (they could not cover it); the
         # donor ROW's blocks, and the refs adopt took for row 1, are intact
         d0, d1 = rc.row_blocks[0]
@@ -302,7 +302,14 @@ def test_window_tables_are_bucketed_and_count_what_is_mapped():
         table, live = rc.window_table([0, 2], 4)
         assert table.shape == (4, 4) and live == 4 and table.flags.c_contiguous
         assert table[0, :3].tolist() == rc.row_blocks[0] and not table[1].any()
-        assert rc.row_table(2).shape == (1, 1)
+        assert rc.rows_table([2]).shape == (1, 1)
+        # a prefill group: its rows' tables at ONE width, never under a fresh
+        # prompt's that fills the chunk; a dead row (-1) maps the null block
+        group = rc.rows_table([2, -1, 0], chunk=2 * BS)
+        assert group.shape == (3, 4) and not group[1].any()
+        assert group[2, :3].tolist() == rc.row_blocks[0] and group[0, 0] == rc.row_blocks[2][0]
+        assert rc.rows_table([2, -1], chunk=2 * BS).shape == (2, 2)
+        assert rc.tables[0, :3].tolist() == rc.row_blocks[0]  # a copy: the table itself is whole
         rc.cover(0, eng.max_seq_len + 4)  # the whole row: the physical width
         table, live = rc.window_table([0, 2], 4)
         assert table.shape[1] == eng.blocks_per_row == 9 and live == 10

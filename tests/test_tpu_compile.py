@@ -330,9 +330,11 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
         pool = dict(pool, **place(
             jax.eval_shape(lambda: core.init_ssm_state(cfg, B, jnp.float32))))
 
-    def step(params, ids, pool, off, tables, ceil):
+    def step(params, ids, pool, off, tables, floor, ceil):
+        # a prefill chunk's rows each have a write floor and a write ceil
         return core.forward(
             params, cfg, ids, pool, off, attn_fn=attn, block_tables=tables,
+            paged_write_floor=floor if T > 1 else None,
             paged_write_ceil=ceil if T > 1 else None,
         )
 
@@ -340,7 +342,7 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=whole)
 
     lowered = jax.jit(step, donate_argnums=(2,)).lower(
-        params, ints(B, T), pool, ints(B), ints(B, MB), ints())
+        params, ints(B, T), pool, ints(B), ints(B, MB), ints(B), ints(B))
     shard = mesh.shape["model"] if mesh is not None else 1
     leaf = pool[next(iter(core.pool_layout(cfg)))]
     return lowered, int(np.prod(leaf.shape[1:])) // shard
@@ -352,6 +354,8 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
 IN_PLACE_CASES = {
     "phi-3-mini-decode": ("phi-3-mini", 16, 1, 32, 385),
     "phi-3-mini-prefill-128": ("phi-3-mini", 1, 128, 8, 385),
+    "phi-3-mini-prefill-4x128": ("phi-3-mini", 4, 128, 8, 385),  # one group of a burst
+    "falcon-h1-prefill-8x128": ("falcon-h1-34b", 8, 128, 8, 3201),
     "falcon-h1-decode": ("falcon-h1-34b", 64, 1, 32, 3201),
 }
 
@@ -379,13 +383,15 @@ def test_forward_keeps_the_pool_in_place(one_chip, mosaic_state_step, case):
         assert analysis.temp_size_in_bytes < slice_elems * 2
 
 
-def test_forward_keeps_the_pool_in_place_under_model_4(topo):
+@pytest.mark.parametrize("B,T", [(8, 1), (4, 128)], ids=["decode", "prefill-4x128"])
+def test_forward_keeps_the_pool_in_place_under_model_4(topo, B, T):
     """`model:4`: both kernels run per shard of the pool's kv heads inside
     shard_maps over the same pool spec; the program still holds no op of a
-    (per-device) layer slice's size."""
+    (per-device) layer slice's size. A decode step, and one grouped prefill
+    whose rows have a write floor and a write ceil each."""
     cfg = dataclasses.replace(get_config("zephyr-7b"), n_layers=2)
     mesh = Mesh(np.array(topo.devices, dtype=object).reshape(1, 1, 1, 4), AXES)
-    lowered, slice_elems = _forward_program(cfg, 8, 1, 8, NB, mesh=mesh)
+    lowered, slice_elems = _forward_program(cfg, B, T, 8, NB, mesh=mesh)
     text = lowered.compile().as_text()
     assert "while(" in text, "no layer loop in the compiled text"
     assert text.count("tpu_custom_call") >= 3
@@ -580,12 +586,12 @@ def test_forward_keeps_the_latent_pool_and_the_expert_stacks_in_place(
     assert analysis.temp_size_in_bytes < one_matrix * 2 // 4
 
 
-@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (1, 512, 32)],
-                         ids=["joyai-decode", "joyai-prefill-512"])
+@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (1, 512, 32), (8, 128, 8)],
+                         ids=["joyai-decode", "joyai-prefill-512", "joyai-prefill-8x128"])
 def test_the_cell_programs_fit_one_chip_at_full_depth(one_chip, mosaic_grouped, B, T, MB):
     """joyai-llm-flash-5l as the cell serves it (5 layers, 256 experts, 3,200
-    pool blocks): the decode step at 64 rows and the largest prefill bucket
-    hold both latent custom calls under their scopes, alias the pool in
+    pool blocks): the decode step at 64 rows, the largest prefill bucket and
+    the widest group of a burst hold both latent custom calls under their scopes, alias the pool in
     place, make no expert-stack-sized array and stay under the chip's 15.75
     GB (arguments + temporaries + what the outputs add beyond the alias)."""
     lowered, slice_elems = _forward_program(JOYAI, B, T, MB, 3200, sharding=one_chip)
