@@ -146,6 +146,12 @@ class DraftModel(Drafter):
             raise LatentPoolUnsupported(
                 "spec_model_drafter", self.cfg.name,
                 "the drafter's rectangular cache holds K/V")
+        if self.cfg.moe_dropless:
+            from .paged import DroplessExpertsUnsupported
+
+            raise DroplessExpertsUnsupported(
+                "spec_model_drafter", self.cfg.name,
+                "the drafter's loop over layers of two kinds is not tested")
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

@@ -50,7 +50,11 @@ from ..models import core, partition
 from ..parallel.mesh import local_mesh
 from ..tracing import current_timing, prog_scope
 from ..utils import MetricsAggregator
-from .paged import LatentPoolUnsupported, RecurrentStateUnsupported
+from .paged import (
+    DroplessExpertsUnsupported,
+    LatentPoolUnsupported,
+    RecurrentStateUnsupported,
+)
 from .programs import StoredPrograms
 from .tokenizer import load_tokenizer
 
@@ -323,6 +327,7 @@ class InferenceEngine:
         partition.validate_divisibility(self.model_cfg, self.mesh)
         self._validate_recurrent_features()
         self._validate_latent_features()
+        self._validate_dropless_features()
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -785,6 +790,48 @@ class InferenceEngine:
             refuse("prefix_cache", "a shared latent block under a resumed "
                    "prefill is not tested")
 
+    def _validate_dropless_features(self):
+        """Refuse, by name, every configured feature that is not proven for
+        a model of dropless expert layers over a K/V pool
+        (DroplessExpertsUnsupported; cfg.moe_dropless without latent
+        attention: smallthinker). Pipeline stages refuse in stage_runner,
+        such a DRAFTER in drafter.py. Chunked prefill, the ragged reader and
+        the prefix cache are tested (tests/test_feature_matrix.py)."""
+        cfg, ec = self.model_cfg, self.engine_cfg
+        if not cfg.moe_dropless or cfg.has_mla:
+            return
+
+        def refuse(feature, why):
+            raise DroplessExpertsUnsupported(feature, cfg.name, why)
+
+        if jnp.dtype(ec.cache_dtype) == jnp.int8:
+            refuse("kv_int8", "the int8 pool's per-layer slices under a "
+                   "window that binds are not tested")
+        if ec.quantize == "int8":
+            refuse("weight_int8", "the grouped product reads the expert "
+                   "stacks unquantised")
+        if ec.drafter == "mesh":
+            refuse("spec_mesh_drafter", "the verify forward over layers of "
+                   "two kinds is not tested")
+        if ec.drafter:
+            refuse("spec_model_drafter", "the verify forward over layers of "
+                   "two kinds is not tested")
+        if ec.spec_tokens > 0:
+            refuse("spec_ngram", "the verify forward over layers of two "
+                   "kinds is not tested")
+        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
+            refuse("seq_attention", "the sp partials know no window")
+        if self.mesh.shape.get("model", 1) > 1:
+            refuse("mesh_model", "the dropless expert layer's grouped product "
+                   "is not partitioned over a model axis (--mesh-shape "
+                   "model:N)")
+        if self.mesh.shape.get("expert", 1) > 1:
+            refuse("mesh_expert", "the dropless expert layer's grouped product "
+                   "is not partitioned over an expert axis")
+        if ec.max_adapters > 0:
+            refuse("multi_lora", "adapters under a router that reads the "
+                   "pre-attention norm are not tested")
+
     @property
     def state_info(self) -> dict | None:
         """The recurrent state's identity for the boot record (/providers,
@@ -847,8 +894,9 @@ class InferenceEngine:
         moe = self.model_cfg.moe_dropless
         # the head for ONE position a row (recurrent models since PR 28, and
         # latent-attention ones: at a 129,280-token vocabulary the full
-        # [1, 512, V] logits are 0.27 GB); phi-3's programs stay as they were
-        one_logit = recurrent or self.model_cfg.has_mla
+        # [1, 512, V] logits are 0.27 GB; smallthinker's [1, 2048, 151,936]
+        # would be 1.24 GB); phi-3's programs stay as they were
+        one_logit = recurrent or self.model_cfg.has_mla or moe
         last = jnp.maximum(jnp.asarray(true_len, jnp.int32) - 1, 0)  # dead row: 0
         if moe:  # the forward's expert-layer counters: extras["moe_stats"]
             cache = dict(cache, moe_stats=jnp.zeros(

@@ -42,6 +42,7 @@ bit-identical, and a rewrite would perturb co-borrowers mid-decode.
 
 from __future__ import annotations
 
+import collections
 import functools
 from collections.abc import Iterable
 
@@ -75,6 +76,18 @@ _C_KV_TILES = get_registry().counter(
     "grid steps of the ragged read: one layer's call x the dispatched "
     "window's attention calls (kind label: live = steps with a work item "
     "| stepped = all; live / stepped = the share of the grid that does work)",
+)
+_G_KV_TOKENS_HELD = get_registry().gauge(
+    "engine.kv_tokens_held",
+    "layer-tokens the live rows hold in the pool: their contexts x all "
+    "layers (set once a dispatched decode window or verify step)",
+)
+_G_KV_TOKENS_BEHIND_WINDOW = get_registry().gauge(
+    "engine.kv_tokens_behind_window",
+    "of those, the layer-tokens a sliding-window layer holds and no later "
+    "read can see (positions at or below context - window, x the window "
+    "layers): what per-layer-kind block tables with below-window release "
+    "would free; 0 for a model whose window never binds",
 )
 _G_STATE_ROWS = get_registry().gauge(
     "engine.state_rows",
@@ -154,6 +167,21 @@ class LatentPoolUnsupported(ValueError):
         super().__init__(
             f"{feature} is not supported for {model!r}: its rows cache "
             f"latent rows (no per-head K/V), and {why}"
+        )
+
+
+class DroplessExpertsUnsupported(ValueError):
+    """An engine feature that is not proven for a model whose every layer is
+    a DROPLESS expert layer over a plain K/V pool (smallthinker: full layers
+    without positions beside roped window layers, the router fed the
+    pre-attention norm) was asked for with such a model. ``feature`` names
+    it. Raised when the engine is built, as RecurrentStateUnsupported is."""
+
+    def __init__(self, feature: str, model: str, why: str):
+        self.feature = feature
+        super().__init__(
+            f"{feature} is not supported for {model!r}: its layers are "
+            f"dropless expert layers of two attention kinds, and {why}"
         )
 
 
@@ -637,9 +665,10 @@ class RowCache:
         ``offsets``: the grid steps of ONE layer's ragged read, and those
         with a work item — ops/ragged.work_counts, the call's own tile plan
         and live-tile arithmetic on host integers, at the shapes one shard
-        of the pool sees (a model whose layers alternate local and global
-        attention counts a global layer). Only where the ragged kernel
-        reads."""
+        of the pool sees. A model whose layers are of several kinds (full
+        beside windowed) counts each kind with its own window, weighted by
+        its share of the layers: the MEAN layer's call. Only where the
+        ragged kernel reads."""
         e, cfg = self.engine, self.engine.model_cfg
         if e.engine_cfg.attention != "flash":
             return
@@ -647,18 +676,30 @@ class RowCache:
 
         k = next(iter(self.pool.values()))  # K, or the latent rows
         heads, _, block, head_dim = k.sharding.shard_shape(k.shape)[1:]
-        live, stepped = work_counts(
-            tables, offsets[: len(tables)],
-            0 if cfg.sliding_window_every > 1 else int(cfg.sliding_window or 0),
-            heads=heads,
-            # query heads a stored head: all of them read a latent row
-            group=cfg.n_heads // next(iter(self.layout.values()))[0],
-            chunk=chunk,
-            head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
-            quantized=e.kv_quantized,
-        )
-        _C_KV_TILES.inc(live * calls, kind="live")
-        _C_KV_TILES.inc(stepped * calls, kind="stepped")
+        for window, n in collections.Counter(cfg.layer_windows).items():
+            live, stepped = work_counts(
+                tables, offsets[: len(tables)], window, heads=heads,
+                # query heads a stored head: all of them read a latent row
+                group=cfg.n_heads // next(iter(self.layout.values()))[0],
+                chunk=chunk,
+                head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
+                quantized=e.kv_quantized,
+            )
+            share = n / cfg.n_layers
+            _C_KV_TILES.inc(live * calls * share, kind="live")
+            _C_KV_TILES.inc(stepped * calls * share, kind="stepped")
+
+    def note_tokens_held(self, contexts):
+        """The gauges engine.kv_tokens_held / engine.kv_tokens_behind_window
+        from the live rows' ``contexts`` (tokens cached a row): a window
+        layer's read at the next position p sees the keys above p - window,
+        so a row holds max(0, context - window + 1) tokens a window layer
+        that nothing will read again (one table a row: they stay mapped)."""
+        windows = self.engine.model_cfg.layer_windows
+        ctx = np.asarray(contexts, np.int64)
+        _G_KV_TOKENS_HELD.set(int(ctx.sum()) * len(windows))
+        _G_KV_TOKENS_BEHIND_WINDOW.set(sum(
+            int(np.maximum(ctx - w + 1, 0).sum()) for w in windows if w))
 
     # ---- prefix sharing
 

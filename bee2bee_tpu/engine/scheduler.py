@@ -114,6 +114,11 @@ _C_PREFILL_CALLS = _REG.counter(
     "re-prefill import rung included (bucket label: the chunk's padded "
     "width in tokens)",
 )
+_C_PREFILL_CHUNKS = _REG.counter(
+    "engine.prefill_chunks",
+    "of those programs, the chunks of a CHUNKED prompt (one that walks more "
+    "than one window: EngineConfig.prefill_chunk)",
+)
 _C_PREFILL_TOKENS = _REG.counter(
     "engine.prefill_tokens",
     "positions those programs ran over (kind label: real prompt tokens | "
@@ -1391,6 +1396,8 @@ class BatchScheduler:
                 self._moe_pending.append(extras.pop("moe_stats"))
                 self._count_moe(real, pad, 1)
             _C_PREFILL_CALLS.inc(bucket=str(bucket))
+            if group and len(group[0].windows) > 1:
+                _C_PREFILL_CHUNKS.inc()
             _C_PREFILL_ROWS.inc(len(group), kind="live")
             _C_PREFILL_ROWS.inc(dead, kind="dead")
             _C_PREFILL_TOKENS.inc(real, kind="real")
@@ -2210,10 +2217,13 @@ class BatchScheduler:
 
     def _set_fill_gauges(self):
         """Batch utilization snapshot before a device step: how full the
-        bucket is and the absolute active-row count."""
+        bucket is, the absolute active-row count, and what the live rows
+        hold in the pool (RowCache.note_tokens_held)."""
         a = self.active
         _G_ACTIVE_ROWS.set(a)
         _G_BATCH_FILL.set(a / self._bsz if self._bsz else 0.0)
+        self.cache.note_tokens_held(
+            [self._offsets[b] for b, r in enumerate(self._rows) if r is not None])
         # pool-growth forecast (engine/introspect.py): sampled on the
         # dispatch cadence so the pool_exhaust_eta gauge the admission
         # shed reads tracks the live allocation trend

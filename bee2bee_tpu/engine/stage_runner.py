@@ -87,6 +87,13 @@ class StageRunner:
             raise LatentPoolUnsupported(
                 "pipeline_stages", self.model_cfg.name,
                 "a stage's per-microbatch cache is rectangular K/V")
+        if self.model_cfg.moe_dropless:
+            from .paged import DroplessExpertsUnsupported
+
+            raise DroplessExpertsUnsupported(
+                "pipeline_stages", self.model_cfg.name,
+                "a stage's loop reads a layer's experts sliced out of the "
+                "stack and is not tested")
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

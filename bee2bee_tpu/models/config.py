@@ -32,7 +32,8 @@ class ModelConfig:
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     norm_bias: bool = True  # layernorm only: mpt ships weight-only norms
     activation: str = "silu"  # "silu" (gated) | "gelu" (tanh approx, gpt2/
-    # phi) | "gelu_exact" (erf — gpt-neox) | "geglu"
+    # phi) | "gelu_exact" (erf — gpt-neox) | "geglu" | "reglu" (gated by a
+    # ReLU: smallthinker's sparse experts)
     use_bias: bool = False  # attn/mlp biases (gpt2 style)
     qkv_bias: bool = False  # bias on q/k/v ONLY (qwen2 style; no bo/mlp bias)
     qk_norm: bool = False  # per-head RMSNorm on q and k before rope
@@ -75,6 +76,10 @@ class ModelConfig:
     # gemma-3: SLIDING layers rotate with this theta and NO rope_scaling;
     # global layers use rope_theta + rope_scaling. None = one rope for all
     local_rope_theta: float | None = None
+    # smallthinker: ONLY the sliding layers rotate (rope_theta, no scaling);
+    # the full layers carry no positional encoding at all (NoPE). The same
+    # is_sliding_layer rule decides a layer's window and its rotation
+    rope_sliding_only: bool = False
     # gemma-2 attention extras
     attn_logit_softcap: float | None = None  # tanh cap on attention scores
     attn_scale: float | None = None  # score denominator becomes
@@ -151,12 +156,25 @@ class ModelConfig:
     # from the dense layers' d_ff (0 = d_ff); the first first_k_dense layers
     # are dense MLPs (first_k_dense_replace). A sigmoid router always takes
     # the DROPLESS expert layer (core._moe_dropless): moe_impl is not
-    # consulted for it
-    moe_router: str = "softmax"  # "softmax" | "sigmoid"
+    # consulted for it. "softmax_topk" (smallthinker) takes the same
+    # dropless layer with no bias: the top k of the LOGITS x W_r, weighed by
+    # a softmax over those k alone (= softmax over all, then renormalised)
+    moe_router: str = "softmax"  # "softmax" | "sigmoid" | "softmax_topk"
     moe_scale: float = 1.0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
     first_k_dense: int = 0
+    # SEEDED weights only (core.init_params; a checkpoint's own values load
+    # over it): the std of the token embedding's rows. At the default every
+    # token's own part of the residual stream is ~1/11 of what attention adds
+    # from a long context's MEAN value, so behind 4k-8k tokens every row of a
+    # batch holds nearly the same state, emits the same greedy token and picks
+    # the same experts; at 1.0 a token's own part leads, as a trained model's
+    embed_init_std: float = 0.02
+    # smallthinker: the router reads the block's PRE-ATTENTION norm output
+    # ("attn_norm": the router sits before attention), not the pre-FFN one
+    # the experts read ("ffn_norm"). Dropless expert layers only
+    moe_router_input: str = "ffn_norm"  # "ffn_norm" | "attn_norm"
 
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
@@ -217,9 +235,37 @@ class ModelConfig:
             )
         if self.moe_group_size < 1:
             raise ValueError(f"moe_group_size={self.moe_group_size} must be >= 1")
-        if self.moe_router not in ("softmax", "sigmoid"):
+        if self.moe_router not in ("softmax", "sigmoid", "softmax_topk"):
             raise ValueError(
-                f"moe_router={self.moe_router!r} must be 'softmax' or 'sigmoid'"
+                f"moe_router={self.moe_router!r} must be 'softmax', 'sigmoid' "
+                "or 'softmax_topk'"
+            )
+        if self.moe_router_input not in ("ffn_norm", "attn_norm") or (
+            self.moe_router_input == "attn_norm"
+            and (self.moe_router == "softmax" or self.no_pre_norms
+                 or self.parallel_block)
+        ):
+            raise ValueError(
+                f"moe_router_input={self.moe_router_input!r} must be "
+                "'ffn_norm', or 'attn_norm' on a dropless expert layer "
+                "(moe_router 'sigmoid' / 'softmax_topk') of a sequential "
+                "pre-norm block"
+            )
+        if (self.moe_router == "softmax_topk" and self.n_experts
+                and self.moe_router_input != "attn_norm"):
+            raise ValueError(
+                "moe_router='softmax_topk' is built with moe_router_input="
+                "'attn_norm' only (core.center_router evens a seeded router "
+                "on the pre-attention norm's output)"
+            )
+        if self.rope_sliding_only and not (
+            self.sliding_window and self.sliding_window_every > 1
+            and self.pos_embedding == "rope"
+            and self.local_rope_theta is None
+        ):
+            raise ValueError(
+                "rope_sliding_only needs a rope model whose layers alternate "
+                "(sliding_window with sliding_window_every > 1) and one theta"
             )
         if self.mla_kv_rank and min(self.mla_q_rank, self.mla_nope_dim,
                                     self.mla_rope_dim, self.mla_v_dim) < 1:
@@ -262,6 +308,16 @@ class ModelConfig:
         return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)
 
     @property
+    def layer_windows(self) -> tuple:
+        """Every layer's sliding window on host integers (0 = it attends
+        fully): core.is_sliding_layer's rule, for what is counted per layer
+        KIND (the ragged read's tiles, the tokens behind a window)."""
+        w = int(self.sliding_window or 0)
+        return tuple(
+            w if i % self.sliding_window_every in self.sliding_window_residues
+            else 0 for i in range(self.n_layers))
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
@@ -278,9 +334,15 @@ class ModelConfig:
 
     @property
     def moe_dropless(self) -> bool:
-        """The sigmoid-routed expert layer computes every chosen assignment
-        (core._moe_dropless); the softmax presets keep moe_impl's two."""
-        return self.n_experts > 0 and self.moe_router == "sigmoid"
+        """The sigmoid- and softmax-top-k-routed expert layers compute every
+        chosen assignment (core._moe_dropless); the softmax presets keep
+        moe_impl's two."""
+        return self.n_experts > 0 and self.moe_router != "softmax"
+
+    @property
+    def gated_mlp(self) -> bool:
+        """The MLP / an expert has a gate matrix beside up and down."""
+        return self.activation in ("silu", "geglu", "reglu")
 
     @property
     def expert_ff(self) -> int:
@@ -726,6 +788,43 @@ CONFIGS["tiny-joyai"] = ModelConfig(
 )
 
 
+_SMALLTHINKER_21B = dict(
+    # PowerInfer/SmallThinker-21BA3B-Instruct config.json (model_name
+    # smallthinker_21b_instruct, 21B-A3B): GQA 28/4 x 128; layers l % 4 == 0
+    # attend fully with NO positional encoding, the other three of four
+    # through a 4,096 window with RoPE at theta 1.5e6; every layer 64 ReGLU
+    # experts 768 wide, top-6, the router fed the PRE-ATTENTION norm's
+    # output, weights a softmax over the chosen six; no bias anywhere, no
+    # shared expert; untied head over 151,936 tokens
+    vocab_size=151936, d_model=2560, n_heads=28, n_kv_heads=4, d_ff=768,
+    head_dim_override=128, max_seq_len=16384, rope_theta=1500000.0,
+    norm_eps=1e-6, tie_embeddings=False, activation="reglu",
+    sliding_window=4096, sliding_window_every=4,
+    sliding_window_residues=(1, 2, 3), rope_sliding_only=True,
+    n_experts=64, n_experts_per_tok=6, moe_router="softmax_topk",
+    moe_router_input="attn_norm", embed_init_std=1.0,
+)
+CONFIGS["smallthinker-21b-a3b"] = ModelConfig(
+    name="smallthinker-21b-a3b", n_layers=52, **_SMALLTHINKER_21B)
+CONFIGS["smallthinker-21b-a3b-8l"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/smallthinker-21b-
+    # a3b-8l.json): two whole periods (F W W W F W W W) with all 64 experts,
+    # every width and the whole vocabulary: the first of seven pipeline
+    # stages, with the final norm and head added
+    name="smallthinker-21b-a3b-8l", n_layers=8, **_SMALLTHINKER_21B)
+CONFIGS["tiny-smallthinker"] = ModelConfig(
+    # every mechanism at CPU-test size: 4 layers = one period, a window of
+    # 24 that contexts of the tests pass, 8 experts top-3
+    name="tiny-smallthinker", vocab_size=512, d_model=48, n_layers=4,
+    n_heads=4, n_kv_heads=2, d_ff=36, head_dim_override=16, max_seq_len=256,
+    rope_theta=10000.0, norm_eps=1e-6, tie_embeddings=False,
+    activation="reglu", sliding_window=24, sliding_window_every=4,
+    sliding_window_residues=(1, 2, 3), rope_sliding_only=True,
+    n_experts=8, n_experts_per_tok=3, moe_router="softmax_topk",
+    moe_router_input="attn_norm", embed_init_std=1.0,
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -909,6 +1008,77 @@ def _joyai_from_hf(d: dict, nm: str) -> ModelConfig:
         n_shared_experts=d.get("n_shared_experts") or 0,
         d_ff_expert=d["moe_intermediate_size"],
         first_k_dense=d.get("first_k_dense_replace", 0),
+    )
+
+
+def _layer_period(sliding: set, n_layers: int, longest: int):
+    """(every, residues) of the shortest period, at most ``longest`` layers,
+    under which the layers of ``sliding`` are those whose index modulo
+    ``every`` is in ``residues`` (sliding_window_every / _residues), or None."""
+    for p in range(1, longest + 1):
+        residues = tuple(sorted({i % p for i in sliding}))
+        if all((i % p in residues) == (i in sliding) for i in range(n_layers)):
+            return p, residues
+    return None
+
+
+def _smallthinker_from_hf(d: dict, nm: str) -> ModelConfig:
+    """smallthinker (PowerInfer/SmallThinker-*; ``model_name
+    smallthinker_*``): full layers with no positional encoding beside
+    windowed layers with RoPE, ReGLU experts behind a softmax-top-k router
+    that reads the pre-attention norm. What core does not implement is
+    refused BY NAME: a layout that is not periodic, a ``rope_layout`` that
+    is not the ``sliding_window_layout``'s twin (one is_sliding_layer rule
+    decides both), sigmoid routing, unnormalised weights, rope scaling."""
+    L = d["num_hidden_layers"]
+    layout = list(d.get("sliding_window_layout") or [0] * L)
+    if len(layout) != L or list(d.get("rope_layout") or layout) != layout:
+        raise ValueError(
+            f"smallthinker config with rope_layout={d.get('rope_layout')!r} "
+            f"beside sliding_window_layout={layout!r} is not implemented "
+            f"(both must list {L} layers and be alike: a layer rotates iff "
+            "it windows)"
+        )
+    sliding = {i for i, w in enumerate(layout) if w}
+    found = _layer_period(sliding, L, min(L // 2, 12))  # at least two periods
+    if found is None:
+        raise ValueError(
+            f"smallthinker config with sliding_window_layout={layout!r} is "
+            "not implemented (no period of at most 12 layers, repeated at "
+            "least twice, describes it)"
+        )
+    p, residues = found
+    if not sliding or len(sliding) == L:
+        raise ValueError(
+            f"smallthinker config with sliding_window_layout={layout!r} is "
+            "not implemented (it mixes no full and windowed layers)"
+        )
+    for key, want in {"moe_primary_router_apply_softmax": True,
+                      "norm_topk_prob": True, "rope_scaling": None,
+                      "moe_enable_secondary_experts": False}.items():
+        got = d.get(key, want)
+        if got != want:
+            raise ValueError(
+                f"smallthinker config with {key}={got!r} is not implemented "
+                f"(only {key}={want!r}, the published setting)"
+            )
+    H = d["num_attention_heads"]
+    return ModelConfig(
+        name=nm, vocab_size=d["vocab_size"], d_model=d["hidden_size"],
+        n_layers=L, n_heads=H, n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["moe_ffn_hidden_size"],
+        head_dim_override=d.get("head_dim") or d["hidden_size"] // H,
+        max_seq_len=d.get("max_position_embeddings", 16384),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        activation="reglu", sliding_window=d["sliding_window_size"],
+        sliding_window_every=p, sliding_window_residues=residues,
+        rope_sliding_only=True,
+        n_experts=d["moe_num_primary_experts"],
+        n_experts_per_tok=d["moe_num_active_primary_experts"],
+        moe_router="softmax_topk", moe_router_input="attn_norm",
+        embed_init_std=1.0,
     )
 
 
@@ -1205,6 +1375,10 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         return _falcon_h1_from_hf(d, nm)
     if mt == "joyai_llm_flash":
         return _joyai_from_hf(d, nm)
+    if mt == "smallthinker" or str(d.get("model_name", "")).startswith(
+            "smallthinker_"):
+        return _smallthinker_from_hf(d, name or d.get("_name_or_path")
+                                     or d.get("model_name") or nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1219,17 +1393,13 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
                        if t == "sliding_attention"}
             # recover a periodic (every, residues) description; gemma-3
             # ships 5-local-1-global (period 6)
-            for p in range(1, min(len(types), 12) + 1):
-                residues = tuple(sorted({i % p for i in sliding}))
-                if all((i % p in residues) == (i in sliding)
-                       for i in range(len(types))):
-                    every, res = p, residues
-                    break
-            else:
+            found = _layer_period(sliding, len(types), min(len(types), 12))
+            if found is None:
                 raise ValueError(
                     "gemma3 layer_types pattern is not periodic; cannot "
                     "represent it"
                 )
+            every, res = found
         else:
             # no layer_types (older transformers writers): the pattern key
             # is sliding_window_pattern (Gemma3TextConfig default 6),
@@ -1377,7 +1547,7 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
-        f"falcon_h1/joyai_llm_flash; "
+        f"falcon_h1/joyai_llm_flash/smallthinker; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

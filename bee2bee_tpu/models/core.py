@@ -21,6 +21,7 @@ Partition rules over the same paths live in partition.py.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any
@@ -77,7 +78,7 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
         )
     else:
         attn = D * (H * hd) + 2 * D * (Hkv * hd) + (H * hd) * D
-    gated = cfg.activation in ("silu", "geglu")
+    gated = cfg.gated_mlp
     mlp_one = (3 if gated else 2) * D * F
     if cfg.is_moe:
         one = (3 if gated else 2) * D * cfg.expert_ff
@@ -124,7 +125,7 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
              q_a_norm [L, qr], wq_b [L, qr, H*(nope+rope)], wkv_a
              [L, D, kvr+rope], kv_a_norm [L, kvr], wkv_b [L, kvr,
              H*(nope+v)], wo [L, H*v, D]
-        moe under the sigmoid router (cfg.moe_dropless) also: router_bias
+        moe under the sigmoid router (cfg.moe_router "sigmoid") also: router_bias
              [L, E] ALWAYS float32 (balanced on seeded traffic as training
              balances it, balance_router_bias: nonzero, so selection and
              weight differ), shared {w_gate, w_up [L, D, Fs], w_down
@@ -142,15 +143,20 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
     )(cfg, key, jnp.dtype(dtype))
     if cfg.moe_dropless:
         # a noaux_tc router is TRAINED to an even load by its selection
-        # bias; seeded weights get theirs the same way (balance_router_bias)
+        # bias; seeded weights get theirs the same way (balance_router_bias).
+        # A router with no bias gets the same even load by a rule on its
+        # weights (center_router): no parameter is added
+        name, rule = (("router_bias", balance_router_bias)
+                      if cfg.moe_router == "sigmoid"
+                      else ("router", center_router))
         moe = params["layers"]["moe"]
-        bias = jax.jit(
-            balance_router_bias, static_argnums=1,
+        new = jax.jit(
+            rule, static_argnums=1,
             out_shardings=(None if out_shardings is None
-                           else out_shardings["layers"]["moe"]["router_bias"]),
+                           else out_shardings["layers"]["moe"][name]),
         )(params, cfg)
         params = dict(params, layers=dict(
-            params["layers"], moe=dict(moe, router_bias=bias)))
+            params["layers"], moe=dict(moe, **{name: new})))
     return params
 
 
@@ -163,7 +169,8 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         return _dense_init(next(keys), shape, scale, dtype)
 
     params: Params = {
-        "tok_embed": _dense_init(next(keys), (V, D), scale=0.02, dtype=dtype),
+        "tok_embed": _dense_init(
+            next(keys), (V, D), scale=cfg.embed_init_std, dtype=dtype),
     }
     if cfg.pos_embedding == "learned":
         params["pos_embed"] = _dense_init(next(keys), (cfg.max_seq_len, D), 0.02, dtype)
@@ -218,7 +225,7 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         if cfg.use_bias:  # qwen2 (qkv_bias) has NO output-projection bias
             layers["attn"]["bo"] = jnp.zeros((L, D), dtype)
 
-        gated = cfg.activation in ("silu", "geglu")
+        gated = cfg.gated_mlp
         if moe_layers:
             E, Fe = cfg.n_experts, cfg.expert_ff
             moe = {
@@ -228,7 +235,7 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             }
             if gated:
                 moe["w_gate"] = dense((L, E, D, Fe))
-            if cfg.moe_dropless:
+            if cfg.moe_router == "sigmoid":
                 # float32 always; init_params sets it (balance_router_bias)
                 moe["router_bias"] = jnp.zeros((L, E), jnp.float32)
             if cfg.n_shared_experts:
@@ -412,6 +419,8 @@ def _activate(up, gate, cfg: ModelConfig):
         return jax.nn.silu(gate) * up
     if cfg.activation == "geglu":
         return jax.nn.gelu(gate, approximate=True) * up
+    if cfg.activation == "reglu":  # smallthinker's sparse experts
+        return jax.nn.relu(gate) * up
     if cfg.activation == "gelu_exact":  # gpt-neox: erf, not tanh approx
         return jax.nn.gelu(up, approximate=False)
     return jax.nn.gelu(up, approximate=True)
@@ -708,10 +717,15 @@ MOE_STATS = ("hit", "max_load", "live")  # the entries of a forward's
 # least one live assignment, the busiest expert's assignments, live assignments
 
 
+def _router_logits(xf, p):
+    """``x W_r`` [N, E] in float32 from a float32 product."""
+    return jnp.dot(
+        xf.astype(jnp.float32), p["router"].astype(jnp.float32), precision=_HI)
+
+
 def _router_scores(xf, p):
     """``sigmoid(x W_r)`` [N, E] in float32 from a float32 product."""
-    return jax.nn.sigmoid(jnp.dot(
-        xf.astype(jnp.float32), p["router"].astype(jnp.float32), precision=_HI))
+    return jax.nn.sigmoid(_router_logits(xf, p))
 
 
 def _split_expert_stack(moe: Params):
@@ -724,10 +738,18 @@ def _split_expert_stack(moe: Params):
 
 
 def _moe_router(xf, p, cfg: ModelConfig):
-    """The sigmoid router on ``xf`` [N, D], float32 throughout: scores
-    ``s = sigmoid(x W_r)``; the k experts with the largest ``s + bias``
-    are chosen; their weights are ``s`` WITHOUT the bias, divided by their
-    sum and times cfg.moe_scale. Returns (topi [N, k] int32, w [N, k] f32)."""
+    """The dropless layer's router on ``xf`` [N, D], float32 throughout.
+    Returns (topi [N, k] int32, w [N, k] f32).
+
+    "sigmoid": scores ``s = sigmoid(x W_r)``; the k experts with the
+    largest ``s + bias`` are chosen; their weights are ``s`` WITHOUT the
+    bias, divided by their sum and times cfg.moe_scale.
+    "softmax_topk" (no bias): the k largest LOGITS ``z = x W_r`` are chosen
+    and weighed by a softmax over those k alone — what a softmax over all
+    the experts gives once the chosen weights are renormalised."""
+    if cfg.moe_router == "softmax_topk":
+        z, topi = lax.top_k(_router_logits(xf, p), cfg.n_experts_per_tok)
+        return topi.astype(jnp.int32), jax.nn.softmax(z, axis=-1) * cfg.moe_scale
     s = _router_scores(xf, p)
     _, topi = lax.top_k(s + p["router_bias"], cfg.n_experts_per_tok)
     w = jnp.take_along_axis(s, topi, axis=-1)
@@ -745,16 +767,18 @@ _BALANCE_ROWS, _BALANCE_WIDTH = 32, 256  # the balancing batch: rows x tokens
 _BALANCE_PASSES, _BALANCE_STEPS = 4, 64
 
 
-def _balance_tokens(cfg: ModelConfig):
+def _balance_tokens(cfg: ModelConfig, rows: int = _BALANCE_ROWS,
+                    width: int = _BALANCE_WIDTH, prompt_only: bool = False):
     """The balancing batch (tokens [R, T] int32, prompt lengths [R]): a row
     is BOS + seeded BALANCE_WORDS text through the byte tokenizer's map
     (token = byte + 3), 32 to 192 tokens of it, then a continuation, seeded
-    random to begin with. The same for every key: a constant of the program."""
+    random to begin with; ``prompt_only``: the text fills the row. The same
+    for every key: a constant of the program."""
     import numpy as np
 
     rng = np.random.RandomState(0)
-    R, T, V = _BALANCE_ROWS, _BALANCE_WIDTH, cfg.vocab_size
-    plen = 32 + (np.arange(R) * 160) // R
+    R, T, V = rows, width, cfg.vocab_size
+    plen = np.full((R,), T) if prompt_only else 32 + (np.arange(R) * 160) // R
     tokens = rng.randint(3, V, (R, T))
     for r in range(R):
         text = " ".join(rng.choice(BALANCE_WORDS, T // 4)).encode()
@@ -838,10 +862,67 @@ def balance_router_bias(params: Params, cfg: ModelConfig):
     return bias
 
 
-def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None):
-    """The sigmoid-routed expert layer, DROPLESS: every chosen assignment
-    is computed, whatever the imbalance. Returns (out [B, T, D], stats
-    int32 [3] as MOE_STATS names them).
+# center_router's balancing batch: rows x tokens of TEXT (no random
+# continuation), of which the deeper half of every row is averaged
+_CENTER_ROWS, _CENTER_WIDTH = 16, 1024
+
+
+def center_router(params: Params, cfg: ModelConfig):
+    """``router`` [L, D, E] for seeded weights of a router WITHOUT a bias
+    (cfg.moe_router "softmax_topk"): from every layer's ``W_r`` its response
+    to the MEAN router input of the balancing batch is removed,
+    ``W_r - m (m^T W_r) / (m^T m)``, layer after layer (a layer's mean is
+    taken behind the layers already centred). Seeded hidden states share a
+    large common part across tokens (balance_router_bias), growing with
+    depth, which a plain random router turns into the same few experts for
+    every row; with the mean's response gone the logits are what a token has
+    of its own, and 32 decoding rows touch about 1 - (1 - k/E)^32 of a
+    layer's experts, as a trained router's even load does. No parameter is
+    added: the served weights and a reference's are the same arrays.
+
+    The batch must show the common part as SERVED contexts hold it, to a
+    degree: the mean is taken over the deeper half of 16 rows of 1,024 tokens
+    of the load generator's words alone. Measured on the chip at the
+    published widths, the experts 32 rows hit 4,096 tokens deep (PR 43): no
+    rule 44.6 %, joyai's batch (32 x 256, prompts of 32-192 tokens then random
+    tokens, every position) 69.0 %, text only 69.0 %, its deeper half 75.9 %,
+    this batch 87.7 %; removing the top 2-16 principal directions of the
+    router input's second moment instead of the mean 86.9-88.7 % (not worth
+    an eigendecomposition a layer)."""
+    tokens, _ = _balance_tokens(
+        cfg, _CENTER_ROWS, min(_CENTER_WIDTH, cfg.max_seq_len), prompt_only=True)
+    R, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (R, T))
+    layer_mask = make_layer_mask(cfg, positions, T)
+    rest, stack = _split_expert_stack(params["layers"]["moe"])
+
+    def layer(x, xs):
+        lp, i = xs
+        m = jnp.mean(_norm(x, lp["ln1"], cfg).astype(jnp.float32)[
+            :, T // 2:].reshape(R * (T - T // 2), -1), axis=0)  # [D]
+        w = lp["moe"]["router"].astype(jnp.float32)
+        w = (w - jnp.outer(m, jnp.dot(m, w, precision=_HI)) / jnp.dot(m, m)
+             ).astype(lp["moe"]["router"].dtype)
+        x = transformer_block(
+            dict(lp, moe=dict(lp["moe"], router=w)), cfg, x, positions,
+            layer_mask(i), rope_local=layer_rope_flag(cfg, i),
+            moe_kw={"experts": stack, "layer": i})
+        return x, w
+
+    _, router = lax.scan(
+        layer, embed_tokens(params, cfg, jnp.asarray(tokens), positions),
+        (dict(params["layers"], moe=rest), jnp.arange(cfg.n_layers)))
+    return router
+
+
+def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
+                  router_x=None):
+    """The expert layer behind a sigmoid or softmax-top-k router (_moe_router),
+    DROPLESS: every chosen assignment is computed, whatever the imbalance.
+    Returns (out [B, T, D], stats int32 [3] as MOE_STATS names them).
+    ``router_x`` [B, T, D] is what the ROUTER reads where that is not ``x``
+    (cfg.moe_router_input "attn_norm": the block's pre-attention norm); the
+    experts read ``x``.
 
     The N x k assignments are sorted by expert (``moe.dispatch``: one
     argsort, a bincount for the group sizes, a row gather), the three
@@ -869,7 +950,8 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None):
     experts = p if experts is None else experts
 
     with jax.named_scope("moe.router"):
-        topi, w = _moe_router(xf, p, cfg)
+        topi, w = _moe_router(
+            xf if router_x is None else router_x.reshape(N, D), p, cfg)
 
     with jax.named_scope("moe.dispatch"):
         flat = topi.reshape(M)
@@ -894,7 +976,7 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None):
         # (rows past the last group hold whatever the buffer held, through
         # all three products: a row's product reads no other row)
         y = grouped_matmul(
-            jax.nn.silu(gate) * up, stack(experts["w_down"]), sizes)
+            _activate(up, gate, cfg), stack(experts["w_down"]), sizes)
 
     with jax.named_scope("moe.combine"):
         y = jnp.where(row_live[:, None], y.astype(jnp.float32), 0.0)
@@ -1241,10 +1323,19 @@ def transformer_block(
     one cached row a token and ``attn_fn`` is the ragged reader's latent
     form (_mla_attention). A sigmoid-routed expert layer (``"moe"`` in
     ``lp``, cfg.moe_dropless) takes ``moe_kw`` (_moe_dropless's ``live``,
-    ``experts``, ``layer``) and hands its stats to ``moe_sink``.
+    ``experts``, ``layer``) and hands its stats to ``moe_sink``; its router
+    reads ``h``, the pre-attention norm, under cfg.moe_router_input
+    "attn_norm". ``rope_local`` (the traced is-sliding flag of this layer,
+    layer_rope_flag) also decides WHETHER a layer rotates under
+    cfg.rope_sliding_only: the full layers carry no positional encoding.
+    The parts of a dropless-expert model's plain attention run under the
+    scopes ``attn.qkv`` / ``attn.rope`` / ``attn.write`` / ``attn.read`` /
+    ``attn.out`` (the dense block's carry none).
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    scope = (jax.named_scope if cfg.moe_dropless
+             else lambda _: contextlib.nullcontext())
 
     h = x if cfg.no_pre_norms else _norm(x, lp["ln1"], cfg)
     if cfg.has_mla:
@@ -1259,9 +1350,10 @@ def transformer_block(
         mix_out = mix_out * jnp.asarray(cfg.ssm_out_multiplier, mix_out.dtype)
         if cfg.attention_in_multiplier != 1.0:
             h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
-    q = lora_matmul(h, lp["attn"]["wq"], "wq", lora)
-    k = lora_matmul(h, lp["attn"]["wk"], "wk", lora)
-    v = lora_matmul(h, lp["attn"]["wv"], "wv", lora)
+    with scope("attn.qkv"):
+        q = lora_matmul(h, lp["attn"]["wq"], "wq", lora)
+        k = lora_matmul(h, lp["attn"]["wk"], "wk", lora)
+        v = lora_matmul(h, lp["attn"]["wv"], "wv", lora)
     if "bq" in lp["attn"]:
         q = q + lp["attn"]["bq"]
         k = k + lp["attn"]["bk"]
@@ -1279,7 +1371,18 @@ def transformer_block(
         k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
     if cfg.key_multiplier != 1.0:  # falcon-h1: k scaled BEFORE the rotation
         k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
-    if cfg.pos_embedding == "rope":
+    if cfg.rope_sliding_only:
+        if rope_local is None:
+            raise ValueError(
+                f"{cfg.name!r} rotates its sliding layers only: this path "
+                "hands transformer_block no per-layer flag (layer_rope_flag), "
+                "so its full layers would be rotated too"
+            )
+        with scope("attn.rope"):
+            q, k = (jnp.where(rope_local, _rope(
+                a, positions, cfg.rope_theta, cfg.rotary_dim, cfg.rope_style,
+                None), a) for a in (q, k))
+    elif cfg.pos_embedding == "rope":
         if cfg.local_rope_theta is not None and rope_local is not None:
             # gemma-3: SLIDING layers rotate with the local theta and no
             # scaling; global layers use rope_theta + rope_scaling.
@@ -1298,12 +1401,15 @@ def transformer_block(
             k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim,
                       cfg.rope_style, cfg.rope_scaling)
     if kv_hook is not None:
-        k, v = kv_hook(k, v)
-    if attn_fn is None:
-        attn_out = _attention(q, k, v, mask, cfg)
-    else:
-        attn_out = attn_fn(q, k, v, mask, cfg, positions=positions)
-    attn_out = lora_matmul(attn_out, lp["attn"]["wo"], "wo", lora)
+        with scope("attn.write"):
+            k, v = kv_hook(k, v)
+    with scope("attn.read"):
+        if attn_fn is None:
+            attn_out = _attention(q, k, v, mask, cfg)
+        else:
+            attn_out = attn_fn(q, k, v, mask, cfg, positions=positions)
+    with scope("attn.out"):
+        attn_out = lora_matmul(attn_out, lp["attn"]["wo"], "wo", lora)
     if "bo" in lp["attn"]:
         attn_out = attn_out + lp["attn"]["bo"]
     if mix_out is not None:
@@ -1321,6 +1427,8 @@ def transformer_block(
     x = x + attn_out
 
     h2 = x if cfg.no_pre_norms else _norm(x, lp["ln2"], cfg)
+    if cfg.moe_router_input == "attn_norm":
+        moe_kw = dict(moe_kw or {}, router_x=h)
     mlp_out = _ffn(h2, lp, cfg, lora, moe_kw, moe_sink)
     if cfg.post_norms:
         mlp_out = _norm(mlp_out, lp["ln2_post"], cfg)
@@ -1391,6 +1499,16 @@ def attn_mask(cfg: ModelConfig, positions, T: int, S: int | None = None,
         ki = jnp.arange(T, dtype=jnp.int32)[None, :]
         causal = causal & (qi - ki < w)
     return causal[None, None, :, :]
+
+
+def layer_rope_flag(cfg: ModelConfig, global_idx):
+    """transformer_block's ``rope_local`` for the layer at GLOBAL index: the
+    traced is-sliding flag where a layer's rotation follows its kind
+    (gemma-3's local theta, smallthinker's unrotated full layers), else
+    None."""
+    if cfg.local_rope_theta is None and not cfg.rope_sliding_only:
+        return None
+    return is_sliding_layer(cfg, global_idx)
 
 
 def is_sliding_layer(cfg: ModelConfig, global_idx):
@@ -1616,10 +1734,7 @@ def forward(
     else:
         lora_for = None
 
-    def rope_flag(layer_idx):
-        if cfg.local_rope_theta is None:
-            return None
-        return is_sliding_layer(cfg, layer_idx)
+    rope_flag = functools.partial(layer_rope_flag, cfg)
 
     # the positions an expert layer may count as load: not a prefill
     # bucket's padded tail (past valid_len or the write ceil), not a row of
@@ -1882,6 +1997,9 @@ def forward(
         (x, new_cache), _ = lax.scan(
             layer_body, carry, (layer_params, jnp.arange(k_dense, n_layers)))
     else:
+        if cfg.moe_dropless:  # every layer an expert layer (smallthinker)
+            rest, expert_stack = _split_expert_stack(layer_params["moe"])
+            layer_params = dict(layer_params, moe=rest)
         xs = (layer_params, jnp.arange(n_layers))
         if adapters is not None:
             # the [L, N, ...] factor stacks join the scan xs, so each

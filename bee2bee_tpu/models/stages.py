@@ -151,9 +151,7 @@ def stage_forward(
     layer_mask = core.make_layer_mask(cfg, positions, T, S, start=spec.start)
 
     def rope_flag(idx):
-        if cfg.local_rope_theta is None:
-            return None
-        return core.is_sliding_layer(cfg, spec.start + idx)
+        return core.layer_rope_flag(cfg, spec.start + idx)
 
     def layer(carry, xs):
         h, ck, cv = carry

@@ -89,7 +89,7 @@ def validate_targets(cfg: ModelConfig, lcfg: LoraConfig) -> None:
             f"{cfg.name!r} (expert weights are [L, E, ...]); use attention "
             f"targets {ATTN_TARGETS}"
         )
-    if "w_gate" in lcfg.targets and cfg.activation not in ("silu", "geglu"):
+    if "w_gate" in lcfg.targets and not cfg.gated_mlp:
         raise ValueError(
             f"target 'w_gate' does not exist on {cfg.name!r} "
             f"(activation={cfg.activation!r} is not gated)"

@@ -272,3 +272,54 @@ def test_latent_model_serves_with_chunked_prefill_penalties_and_the_ragged_reade
     flash = _rollout(InferenceEngine("tiny-joyai", engine_config=EngineConfig(
         **{**KW, "attention": "flash"})))
     assert len(flash) == 8
+
+
+# ---- a model of dropless expert layers over a K/V pool whose layers are of
+# two attention kinds (SmallThinker): what is not proven for it is refused by
+# name when the engine (stage runner, drafter) is BUILT. The config-only
+# refusals live in tests/test_smallthinker.py::REFUSED.
+
+
+def _dropless_refused(feature, build):
+    from bee2bee_tpu.engine import DroplessExpertsUnsupported
+
+    with pytest.raises(DroplessExpertsUnsupported) as err:
+        build()
+    assert err.value.feature == feature and "tiny-smallthinker" in str(err.value)
+
+
+@pytest.mark.parametrize("feature,mesh,over", [
+    ("mesh_model", MeshSpec(model=2), {}),
+    ("seq_attention", MeshSpec(seq=2), {"attention": "sp"}),
+    ("seq_attention", MeshSpec(seq=2), {}),
+    ("mesh_expert", MeshSpec(expert=2), {}),
+])
+def test_dropless_model_refuses_mesh_axes_it_is_not_partitioned_over(feature, mesh, over):
+    _dropless_refused(feature, lambda: InferenceEngine(
+        "tiny-smallthinker", mesh=build_mesh(mesh), engine_config=EngineConfig(**over, **KW)))
+
+
+def test_dropless_model_refuses_pipeline_stages_and_the_drafter_seat():
+    from bee2bee_tpu.engine.drafter import DraftModel
+    from bee2bee_tpu.engine.stage_runner import StageRunner
+
+    _dropless_refused("pipeline_stages", lambda: StageRunner(
+        "tiny-smallthinker", n_stages=2, stage=0, max_seq_len=64, dtype="float32"))
+    _dropless_refused("spec_model_drafter", lambda: DraftModel(
+        "tiny-smallthinker", spec_tokens=4, batch=2, target_max_seq_len=64, dtype="float32"))
+
+
+def test_dropless_model_serves_with_chunked_prefill_the_prefix_cache_and_the_ragged_reader():
+    """What does not look inside an expert keeps working: contexts of 48 tokens,
+    two windows of 24, in chunks, from shared blocks, through the kernel."""
+    want = _rollout(InferenceEngine("tiny-smallthinker", engine_config=EngineConfig(**KW)))
+    eng = InferenceEngine("tiny-smallthinker", engine_config=EngineConfig(
+        prefix_cache_entries=4, prefill_chunk=16, **KW))
+    first = eng.generate(PROMPT, max_new_tokens=8, temperature=0.0).token_ids
+    second = eng.generate(PROMPT, max_new_tokens=8, temperature=0.0).token_ids
+    assert eng.scheduler.stats.prefix_hits == 1
+    eng.close()
+    assert first == want and second == want and len(want) == 8
+    flash = _rollout(InferenceEngine("tiny-smallthinker", engine_config=EngineConfig(
+        **{**KW, "attention": "flash", "prefill_chunk": 16})))
+    assert flash == want

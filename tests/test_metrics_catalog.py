@@ -48,6 +48,7 @@ ALLOWED_ABSENT = {
     "engine.moe_experts_hit": "no dropless expert layer in the boot's model",
     "engine.moe_expert_load_max": "no dropless expert layer in the boot's model",
     "engine.latent_tokens_read": "no latent attention in the boot's model",
+    "engine.prefill_chunks": "no prompt walks more than one prefill window in this boot",
     # CPU test backend: device.memory_stats() is None and no
     # BEE2BEE_HBM_BYTES budget is set, so headroom cannot compute
     "engine.hbm_headroom_frac": "no device memory stats on CPU",
@@ -285,5 +286,9 @@ def test_every_device_trace_scope_the_model_opens_is_documented():
     assert {"mla.q_proj", "mla.kv_proj", "mla.write", "mla.read", "mla.out",
             "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
             "ssm.step", "kv.write"} <= opened
+    # the plain attention's parts, opened for a dropless-expert model only
+    # (transformer_block's ``scope``: smallthinker)
+    attn = set(re.findall(r'scope\("(attn\.[a-z_]+)"\)', src))
+    assert attn == {"attn.qkv", "attn.rope", "attn.write", "attn.read", "attn.out"}
     doc = DOC.read_text()
-    assert not sorted(s for s in opened if f"`{s}`" not in doc)
+    assert not sorted(s for s in opened | attn if f"`{s}`" not in doc)

@@ -338,6 +338,12 @@ TILE_CASES = {
         Hkv=8, G=1, hd=96, MB=64, T=512, offs=[0, 300, 77], dead=[2]),
     "gqa2x4-hd128-prefill-t256-four-q-blocks-window": dict(
         Hkv=2, G=4, hd=128, MB=64, T=256, offs=[700, 0], window=300),
+    # smallthinker's shapes (PR 43): a table of 1,024 pages, a window of 4,096
+    # that BINDS — the list starts 8-19 tiles into a row — beside a row inside it
+    "gqa4x7-hd128-mb1024-window-binds": dict(
+        Hkv=4, G=7, hd=128, MB=1024, T=1, offs=[6300, 4100, 9000, 700], window=4096),
+    "gqa4x7-hd128-mb1024-prefill-chunk-t256-window-binds": dict(
+        Hkv=4, G=7, hd=128, MB=1024, T=256, offs=[6144], window=4096),
     "gqa8x4-hd96-spec-verify-t5-retired-rows": dict(
         Hkv=8, G=4, hd=96, MB=32, T=5, offs=[123, 124, 250, 300, 11],
         dead=[0, 3]),

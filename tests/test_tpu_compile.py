@@ -608,3 +608,61 @@ def test_the_cell_programs_fit_one_chip_at_full_depth(one_chip, mosaic_grouped, 
              + m.output_size_in_bytes - m.alias_size_in_bytes)
     assert 11.0e9 < total < 15.75e9, total
     assert m.temp_size_in_bytes < one_matrix * 2 // 4
+
+
+# ---- smallthinker-21b-a3b-8l (PR 43): full NoPE layers beside roped window
+# layers, every layer 64 dropless ReGLU experts; a 1,024-page table, a binding
+# window, 2,048-token prefill chunks
+
+SMALLTHINKER = get_config("smallthinker-21b-a3b-8l")
+
+
+@pytest.mark.parametrize("B,T,MB", [(32, 1, 1024), (32, 1, 512), (1, 2048, 512)],
+                         ids=["st-decode-1024", "st-decode-512", "st-prefill-chunk-2048"])
+def test_the_smallthinker_cell_programs_fit_one_chip_at_full_depth(
+        one_chip, mosaic_grouped, B, T, MB):
+    """smallthinker-21b-a3b-8l as the cell serves it (8 layers, 64 experts,
+    19,200 pool blocks): the 32-row decode step at both table widths and a
+    2,048-token prefill chunk hold the page-write and the read under their
+    ``attn.*`` scopes, alias the pool in place, make no array the size of the
+    pool, a layer's slice of it or an expert matrix stack, and stay under the
+    chip's 15.75 GB."""
+    cfg = SMALLTHINKER
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, 19200, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("while(") >= 1, "one layer loop: every layer is an expert layer"
+    # (an instruction is named by its innermost scope: kv.write inside attn.write)
+    assert re.search(r"kv\.write[\w.]* = .*tpu_custom_call.*attn\.write/kv\.write", text), \
+        "the page-write under its scope"
+    assert re.search(r"attn\.read[\w.]* = .*tpu_custom_call", text), "the read under its scope"
+    one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_layers) == []
+    assert _pool_sized_ops(text, slice_elems, cfg.n_layers) == []
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= cfg.n_layers * slice_elems * 2  # the pool in place
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.5e9 < total < 15.75e9, total
+
+
+def test_the_router_centring_pass_compiles_for_v5e_beside_the_weights(one_chip, mosaic_grouped):
+    """core.center_router at the published widths (32 rows x 256 tokens, one
+    pass): its temporaries fit beside the 7.9 GB of weights, the expert stacks
+    are read in place, and all that leaves is the [8, 2560, 64] router."""
+    cfg = SMALLTHINKER
+    shapes = jax.eval_shape(
+        lambda: jax.jit(core._init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(jnp.bfloat16)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(core.center_router, static_argnums=1).lower(args, cfg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # (the stack's size only: the batch's 32 x 256 x 6 sorted rows of 2,560
+    # happen to hold as many elements as ONE layer's 64 x 2,560 x 768 matrix)
+    stack = cfg.n_layers * cfg.n_experts * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, stack, 1) == []
+    analysis = compiled.memory_analysis()
+    assert analysis.output_size_in_bytes == cfg.n_layers * cfg.d_model * cfg.n_experts * 2
+    assert analysis.argument_size_in_bytes + analysis.temp_size_in_bytes < 13.0e9
