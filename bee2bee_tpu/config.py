@@ -29,7 +29,6 @@ _ENV_MAP = {
     "BEE2BEE_MAX_BATCH": "max_batch_size",
     "BEE2BEE_ATTENTION": "attention",
     "BEE2BEE_PREFILL_CHUNK": "prefill_chunk",
-    "BEE2BEE_PREFILL_BUCKETS": "prefill_buckets",
     "BEE2BEE_PREFIX_CACHE": "prefix_cache_entries",
     "BEE2BEE_KV_BLOCK_SIZE": "kv_block_size",
     "BEE2BEE_KV_POOL_BLOCKS": "kv_pool_blocks",
@@ -79,12 +78,6 @@ class NodeConfig:
     # chunked prefill size (0 = whole-prompt buckets); bounds dense
     # prefill score memory for long prompts (EngineConfig.prefill_chunk)
     prefill_chunk: int = 0
-    # padded prompt widths the prefill root compiles for, comma-separated
-    # ("" = EngineConfig's default ladder 64 .. 8192). A node that serves
-    # documents declares e.g. "2048" beside prefill_chunk 2048: ONE prefill
-    # shape, and a boot that compiles none of the short-prompt group programs
-    # (scheduler.warm_prefill) no request of its will ask for
-    prefill_buckets: str = ""
     # prompt prefix cache entries (0 = off): chat turns resend the whole
     # transcript; cached prompt K/V makes turn N+1 prefill only the delta
     prefix_cache_entries: int = 0
@@ -144,9 +137,6 @@ class NodeConfig:
             max_batch=self.max_batch_size,
             attention=self.attention,
             prefill_chunk=self.prefill_chunk or None,
-            **({"prefill_buckets": tuple(
-                int(w) for w in str(self.prefill_buckets).split(",") if w.strip())}
-               if self.prefill_buckets else {}),
             prefix_cache_entries=self.prefix_cache_entries,
             quantize=self.quantize,
             cache_dtype="int8" if self.kv_quant else "bfloat16",

@@ -55,6 +55,7 @@ from jax import lax
 
 from ..models import config as model_config
 from ..models import core
+from .paged import DROPLESS_ROUTED, LATENT_POOL, RECURRENT_STATE, FeatureUnsupported
 from .spec import Drafter
 
 
@@ -135,23 +136,18 @@ class DraftModel(Drafter):
         except KeyError as e:
             raise DrafterLoadError(f"unknown drafter model {model!r}") from e
         if self.cfg.has_ssm:
-            from .paged import RecurrentStateUnsupported
-
-            raise RecurrentStateUnsupported(
+            raise FeatureUnsupported(
                 "spec_model_drafter", self.cfg.name,
-                "a rejected draft cannot be rolled back out of the state")
+                "a rejected draft cannot be rolled back out of the state",
+                RECURRENT_STATE)
         if self.cfg.has_mla:
-            from .paged import LatentPoolUnsupported
-
-            raise LatentPoolUnsupported(
+            raise FeatureUnsupported(
                 "spec_model_drafter", self.cfg.name,
-                "the drafter's rectangular cache holds K/V")
+                "the drafter's rectangular cache holds K/V", LATENT_POOL)
         if self.cfg.moe_dropless:
-            from .paged import DroplessExpertsUnsupported
-
-            raise DroplessExpertsUnsupported(
+            raise FeatureUnsupported(
                 "spec_model_drafter", self.cfg.name,
-                "the drafter's loop over layers of two kinds is not tested")
+                "the drafter's loop is not tested with it", DROPLESS_ROUTED)
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

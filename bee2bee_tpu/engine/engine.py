@@ -51,9 +51,10 @@ from ..parallel.mesh import local_mesh
 from ..tracing import current_timing, prog_scope
 from ..utils import MetricsAggregator
 from .paged import (
-    DroplessExpertsUnsupported,
-    LatentPoolUnsupported,
-    RecurrentStateUnsupported,
+    DROPLESS_ROUTED,
+    LATENT_POOL,
+    RECURRENT_STATE,
+    FeatureUnsupported,
 )
 from .programs import StoredPrograms
 from .tokenizer import load_tokenizer
@@ -714,7 +715,7 @@ class InferenceEngine:
 
     def _validate_recurrent_features(self):
         """Refuse, by name, every configured feature that cannot carry a
-        recurrent row state yet (RecurrentStateUnsupported). Pipeline
+        recurrent row state yet (FeatureUnsupported, RECURRENT_STATE). Pipeline
         stages refuse in stage_runner, a recurrent DRAFTER in drafter.py;
         block-level migration snapshots are not refused but never made
         (scheduler._snapshot_row ships metadata only, so the importer
@@ -724,7 +725,7 @@ class InferenceEngine:
             return
 
         def refuse(feature, why):
-            raise RecurrentStateUnsupported(feature, cfg.name, why)
+            raise FeatureUnsupported(feature, cfg.name, why, RECURRENT_STATE)
 
         if ec.prefix_cache_entries > 0:
             refuse("prefix_cache", "a pinned block holds K/V only — the "
@@ -752,14 +753,14 @@ class InferenceEngine:
 
     def _validate_latent_features(self):
         """Refuse, by name, every configured feature that is not proven over
-        a latent pool (LatentPoolUnsupported; cfg.has_mla). Pipeline stages
+        a latent pool (FeatureUnsupported, LATENT_POOL; cfg.has_mla). Pipeline stages
         refuse in stage_runner, a latent-attention DRAFTER in drafter.py."""
         cfg, ec = self.model_cfg, self.engine_cfg
         if not cfg.has_mla:
             return
 
         def refuse(feature, why):
-            raise LatentPoolUnsupported(feature, cfg.name, why)
+            raise FeatureUnsupported(feature, cfg.name, why, LATENT_POOL)
 
         if jnp.dtype(ec.cache_dtype) == jnp.int8:
             refuse("kv_int8", "the requantising page write keeps a scale a "
@@ -793,7 +794,7 @@ class InferenceEngine:
     def _validate_dropless_features(self):
         """Refuse, by name, every configured feature that is not proven for
         a model of dropless expert layers over a K/V pool
-        (DroplessExpertsUnsupported; cfg.moe_dropless without latent
+        (FeatureUnsupported, DROPLESS_ROUTED; cfg.moe_dropless without latent
         attention: smallthinker). Pipeline stages refuse in stage_runner,
         such a DRAFTER in drafter.py. Chunked prefill, the ragged reader and
         the prefix cache are tested (tests/test_feature_matrix.py)."""
@@ -802,7 +803,7 @@ class InferenceEngine:
             return
 
         def refuse(feature, why):
-            raise DroplessExpertsUnsupported(feature, cfg.name, why)
+            raise FeatureUnsupported(feature, cfg.name, why, DROPLESS_ROUTED)
 
         if jnp.dtype(ec.cache_dtype) == jnp.int8:
             refuse("kv_int8", "the int8 pool's per-layer slices under a "
@@ -811,14 +812,13 @@ class InferenceEngine:
             refuse("weight_int8", "the grouped product reads the expert "
                    "stacks unquantised")
         if ec.drafter == "mesh":
-            refuse("spec_mesh_drafter", "the verify forward over layers of "
-                   "two kinds is not tested")
+            refuse("spec_mesh_drafter", "the verify forward is not tested "
+                   "with it")
         if ec.drafter:
-            refuse("spec_model_drafter", "the verify forward over layers of "
-                   "two kinds is not tested")
+            refuse("spec_model_drafter", "the verify forward is not tested "
+                   "with it")
         if ec.spec_tokens > 0:
-            refuse("spec_ngram", "the verify forward over layers of two "
-                   "kinds is not tested")
+            refuse("spec_ngram", "the verify forward is not tested with it")
         if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
             refuse("seq_attention", "the sp partials know no window")
         if self.mesh.shape.get("model", 1) > 1:
@@ -829,8 +829,7 @@ class InferenceEngine:
             refuse("mesh_expert", "the dropless expert layer's grouped product "
                    "is not partitioned over an expert axis")
         if ec.max_adapters > 0:
-            refuse("multi_lora", "adapters under a router that reads the "
-                   "pre-attention norm are not tested")
+            refuse("multi_lora", "adapters are not tested with it")
 
     @property
     def state_info(self) -> dict | None:

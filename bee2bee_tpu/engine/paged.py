@@ -80,7 +80,8 @@ _C_KV_TILES = get_registry().counter(
 _G_KV_TOKENS_HELD = get_registry().gauge(
     "engine.kv_tokens_held",
     "layer-tokens the live rows hold in the pool: their contexts x all "
-    "layers (set once a dispatched decode window or verify step)",
+    "layers (set once a dispatched decode window or verify step; a model "
+    "with a sliding-window layer only)",
 )
 _G_KV_TOKENS_BEHIND_WINDOW = get_registry().gauge(
     "engine.kv_tokens_behind_window",
@@ -139,50 +140,29 @@ def best_prefix_key(keys, ids) -> tuple[tuple | None, int]:
     return best_key, best_m
 
 
-class RecurrentStateUnsupported(ValueError):
-    """An engine feature that assumes "a row's cache is its K/V pages" was
-    asked for with a model whose rows also own recurrent state (falcon-h1's
-    Mamba-2 mixer). ``feature`` names it. Raised when the engine is built:
-    rollback is not free for a recurrence (spec verify), pinned blocks do
-    not hold the state at a prefix's end (prefix cache), and the state is
-    not sharded over a mesh yet — none of these may be silently wrong."""
+class FeatureUnsupported(ValueError):
+    """An engine feature that is not proven for a kind of model was asked for
+    with such a model: ``feature`` names it, ``ground`` is the model's
+    property it stumbles on (one of the three below). Raised when the engine,
+    a stage runner or a drafter is built: none of these may be silently
+    wrong. RECURRENT_STATE (falcon-h1's Mamba-2 mixer): rollback is not free
+    for a recurrence (spec verify), pinned blocks do not hold the state at a
+    prefix's end (prefix cache), and the state is not sharded over a mesh
+    yet. LATENT_POOL (latent attention caches one [c_kv | k_rope] row a token,
+    no per-head K/V: core.pool_layout). DROPLESS_ROUTED (smallthinker: every
+    layer a dropless expert layer over a plain K/V pool, its router fed the
+    pre-attention norm)."""
 
-    def __init__(self, feature: str, model: str, why: str):
-        self.feature = feature
+    def __init__(self, feature: str, model: str, why: str, ground: str):
+        self.feature, self.ground = feature, ground
         super().__init__(
-            f"{feature} is not supported for {model!r}: its rows carry "
-            f"recurrent state beside their K/V pages, and {why}"
-        )
+            f"{feature} is not supported for {model!r}: {ground}, and {why}")
 
 
-class LatentPoolUnsupported(ValueError):
-    """An engine feature that is not proven over a LATENT pool (a model with
-    latent attention caches one [c_kv | k_rope] row a token, no per-head K/V:
-    core.pool_layout) was asked for with such a model. ``feature`` names it.
-    Raised when the engine is built, as RecurrentStateUnsupported is: none of
-    these may be silently wrong."""
-
-    def __init__(self, feature: str, model: str, why: str):
-        self.feature = feature
-        super().__init__(
-            f"{feature} is not supported for {model!r}: its rows cache "
-            f"latent rows (no per-head K/V), and {why}"
-        )
-
-
-class DroplessExpertsUnsupported(ValueError):
-    """An engine feature that is not proven for a model whose every layer is
-    a DROPLESS expert layer over a plain K/V pool (smallthinker: full layers
-    without positions beside roped window layers, the router fed the
-    pre-attention norm) was asked for with such a model. ``feature`` names
-    it. Raised when the engine is built, as RecurrentStateUnsupported is."""
-
-    def __init__(self, feature: str, model: str, why: str):
-        self.feature = feature
-        super().__init__(
-            f"{feature} is not supported for {model!r}: its layers are "
-            f"dropless expert layers of two attention kinds, and {why}"
-        )
+RECURRENT_STATE = "its rows carry recurrent state beside their K/V pages"
+LATENT_POOL = "its rows cache latent rows (no per-head K/V)"
+DROPLESS_ROUTED = ("its every layer is a dropless expert layer routed from "
+                   "the pre-attention norm")
 
 
 class PoolExhausted(RuntimeError):
@@ -447,6 +427,7 @@ class RowCache:
         self.block_size = engine.engine_cfg.kv_block_size
         self.blocks_per_row = engine.blocks_per_row
         self.recurrent = engine.model_cfg.has_ssm
+        self.windowed = any(engine.model_cfg.layer_windows)  # a layer reads behind a window
         # what a token stores, a layer (core.pool_layout): the page
         # counters, the export format and the bytes a token follow from it
         self.layout = core.pool_layout(engine.model_cfg)
@@ -666,9 +647,9 @@ class RowCache:
         with a work item — ops/ragged.work_counts, the call's own tile plan
         and live-tile arithmetic on host integers, at the shapes one shard
         of the pool sees. A model whose layers are of several kinds (full
-        beside windowed) counts each kind with its own window, weighted by
-        its share of the layers: the MEAN layer's call. Only where the
-        ragged kernel reads."""
+        beside windowed) counts each kind with its own window and adds the
+        mean over its layers, rounded down: the MEAN layer's call. Only
+        where the ragged kernel reads."""
         e, cfg = self.engine, self.engine.model_cfg
         if e.engine_cfg.attention != "flash":
             return
@@ -676,8 +657,9 @@ class RowCache:
 
         k = next(iter(self.pool.values()))  # K, or the latent rows
         heads, _, block, head_dim = k.sharding.shard_shape(k.shape)[1:]
+        live = stepped = 0
         for window, n in collections.Counter(cfg.layer_windows).items():
-            live, stepped = work_counts(
+            one = work_counts(
                 tables, offsets[: len(tables)], window, heads=heads,
                 # query heads a stored head: all of them read a latent row
                 group=cfg.n_heads // next(iter(self.layout.values()))[0],
@@ -685,9 +667,9 @@ class RowCache:
                 head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
                 quantized=e.kv_quantized,
             )
-            share = n / cfg.n_layers
-            _C_KV_TILES.inc(live * calls * share, kind="live")
-            _C_KV_TILES.inc(stepped * calls * share, kind="stepped")
+            live, stepped = live + n * one[0], stepped + n * one[1]
+        _C_KV_TILES.inc(live * calls // cfg.n_layers, kind="live")
+        _C_KV_TILES.inc(stepped * calls // cfg.n_layers, kind="stepped")
 
     def note_tokens_held(self, contexts):
         """The gauges engine.kv_tokens_held / engine.kv_tokens_behind_window
@@ -699,7 +681,8 @@ class RowCache:
         ctx = np.asarray(contexts, np.int64)
         _G_KV_TOKENS_HELD.set(int(ctx.sum()) * len(windows))
         _G_KV_TOKENS_BEHIND_WINDOW.set(sum(
-            int(np.maximum(ctx - w + 1, 0).sum()) for w in windows if w))
+            n * int(np.maximum(ctx - w + 1, 0).sum())
+            for w, n in collections.Counter(windows).items() if w))
 
     # ---- prefix sharing
 
@@ -782,9 +765,9 @@ class RowCache:
         int8 pool's scales under their own keys. Pure read."""
         if self.recurrent:
             # a row's blocks are NOT its complete state here
-            raise RecurrentStateUnsupported(
+            raise FeatureUnsupported(
                 "kv_export", self.engine.model_cfg.name,
-                "the state has no export format yet",
+                "the state has no export format yet", RECURRENT_STATE,
             )
         nb = ceil_div(upto, self.block_size)
         if not nb:

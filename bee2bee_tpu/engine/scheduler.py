@@ -71,8 +71,8 @@ from ..tracing import RequestTiming, annotate, get_tracer, prog_scope
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
 from .engine import PREFILL_GROUP_MAX_BUCKET
 from .paged import (
+    FeatureUnsupported,
     PoolExhausted,
-    RecurrentStateUnsupported,
     RowCache,
     best_prefix_key,
     prefill_chunk_positions,
@@ -1077,7 +1077,7 @@ class BatchScheduler:
         offset = int(self._offsets[b])
         try:
             nb, kv = self.cache.export_row(b, offset)
-        except RecurrentStateUnsupported:
+        except FeatureUnsupported:
             # the recurrent state has no export format yet: ship the
             # metadata alone, so the importer takes the re-prefill rung
             # (prompt + accepted tokens rebuild K/V AND state; next
@@ -1469,10 +1469,16 @@ class BatchScheduler:
         stored (engine/programs.py) — then each runs once on dead rows
         (nothing is written outside the null block), with its group's
         sample program (stored too) and state insert, through the calls
-        that serve.
+        that serve. A node that CHUNKS its prefill (EngineConfig.prefill_chunk)
+        states that long prompts are its traffic: each walks the one
+        [1, chunk] program, which its first prompt compiles, and the group
+        programs of short prompts are left to their first meeting (13 of them,
+        106 s of a first boot at smallthinker-21b-a3b-8l's widths, PR 43).
         The caller's thread runs it, under the lock: the loop is asleep and
         nothing is queued."""
         e, t0 = self.engine, time.perf_counter()
+        if e.engine_cfg.prefill_chunk:
+            return
         with self._cond:
             if self._queue or self.active or self._inflight:
                 raise RuntimeError("warm_prefill needs an idle scheduler")
@@ -2222,8 +2228,9 @@ class BatchScheduler:
         a = self.active
         _G_ACTIVE_ROWS.set(a)
         _G_BATCH_FILL.set(a / self._bsz if self._bsz else 0.0)
-        self.cache.note_tokens_held(
-            [self._offsets[b] for b, r in enumerate(self._rows) if r is not None])
+        if self.cache.windowed:
+            self.cache.note_tokens_held(self._offsets[
+                [b for b, r in enumerate(self._rows) if r is not None]])
         # pool-growth forecast (engine/introspect.py): sampled on the
         # dispatch cadence so the pool_exhaust_eta gauge the admission
         # shed reads tracks the live allocation trend

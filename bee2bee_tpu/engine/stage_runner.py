@@ -30,6 +30,7 @@ import numpy as np
 from ..metrics import get_registry
 from ..models import config as model_config
 from ..models import core, stages
+from .paged import DROPLESS_ROUTED, LATENT_POOL, RECURRENT_STATE, FeatureUnsupported
 
 STALE_CACHE_S = 600.0  # drop request caches untouched this long
 
@@ -76,24 +77,18 @@ class StageRunner:
         # (`serve-stage --model auto --checkpoint <dir>`)
         self.model_cfg = model_config.resolve_model_config(model, checkpoint_path)
         if self.model_cfg.has_ssm:
-            from .paged import RecurrentStateUnsupported
-
-            raise RecurrentStateUnsupported(
+            raise FeatureUnsupported(
                 "pipeline_stages", self.model_cfg.name,
-                "a stage's per-microbatch cache holds K/V only")
+                "a stage's per-microbatch cache holds K/V only", RECURRENT_STATE)
         if self.model_cfg.has_mla:
-            from .paged import LatentPoolUnsupported
-
-            raise LatentPoolUnsupported(
+            raise FeatureUnsupported(
                 "pipeline_stages", self.model_cfg.name,
-                "a stage's per-microbatch cache is rectangular K/V")
+                "a stage's per-microbatch cache is rectangular K/V", LATENT_POOL)
         if self.model_cfg.moe_dropless:
-            from .paged import DroplessExpertsUnsupported
-
-            raise DroplessExpertsUnsupported(
+            raise FeatureUnsupported(
                 "pipeline_stages", self.model_cfg.name,
                 "a stage's loop reads a layer's experts sliced out of the "
-                "stack and is not tested")
+                "stack and is not tested", DROPLESS_ROUTED)
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

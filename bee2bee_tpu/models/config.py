@@ -164,13 +164,6 @@ class ModelConfig:
     n_shared_experts: int = 0
     d_ff_expert: int = 0
     first_k_dense: int = 0
-    # SEEDED weights only (core.init_params; a checkpoint's own values load
-    # over it): the std of the token embedding's rows. At the default every
-    # token's own part of the residual stream is ~1/11 of what attention adds
-    # from a long context's MEAN value, so behind 4k-8k tokens every row of a
-    # batch holds nearly the same state, emits the same greedy token and picks
-    # the same experts; at 1.0 a token's own part leads, as a trained model's
-    embed_init_std: float = 0.02
     # smallthinker: the router reads the block's PRE-ATTENTION norm output
     # ("attn_norm": the router sits before attention), not the pre-FFN one
     # the experts read ("ffn_norm"). Dropless expert layers only
@@ -802,7 +795,7 @@ _SMALLTHINKER_21B = dict(
     sliding_window=4096, sliding_window_every=4,
     sliding_window_residues=(1, 2, 3), rope_sliding_only=True,
     n_experts=64, n_experts_per_tok=6, moe_router="softmax_topk",
-    moe_router_input="attn_norm", embed_init_std=1.0,
+    moe_router_input="attn_norm",
 )
 CONFIGS["smallthinker-21b-a3b"] = ModelConfig(
     name="smallthinker-21b-a3b", n_layers=52, **_SMALLTHINKER_21B)
@@ -821,7 +814,7 @@ CONFIGS["tiny-smallthinker"] = ModelConfig(
     activation="reglu", sliding_window=24, sliding_window_every=4,
     sliding_window_residues=(1, 2, 3), rope_sliding_only=True,
     n_experts=8, n_experts_per_tok=3, moe_router="softmax_topk",
-    moe_router_input="attn_norm", embed_init_std=1.0,
+    moe_router_input="attn_norm",
 )
 
 
@@ -1078,7 +1071,6 @@ def _smallthinker_from_hf(d: dict, nm: str) -> ModelConfig:
         n_experts=d["moe_num_primary_experts"],
         n_experts_per_tok=d["moe_num_active_primary_experts"],
         moe_router="softmax_topk", moe_router_input="attn_norm",
-        embed_init_std=1.0,
     )
 
 

@@ -39,16 +39,22 @@ the plain pass too: recomputing 6,144 positions through 64 experts for each of
 What is compared: ``reference_falcon_h1.py``'s forking walk (served text ->
 bytes -> the best reference logit among the tokens of the served byte must lie
 within ``tolerance`` of the reference's maximum; every same-byte candidate
-within the tolerance extends a context of its own), with
-``reference_joyai.py``'s ROUTING rule: a bf16 rounding upstream can swap a
-token's 6th and 7th expert, so the compared position is ALSO computed with the
+within the tolerance extends a context of its own), walked THROUGH the bytes
+the text lost (``served_bytes``, ``walk``: half of a seeded model's greedy
+bytes at this vocabulary, so 8 probes of 8 tokens cut at the first loss show
+1-14 positions), with ``reference_joyai.py``'s ROUTING rule: a bf16 rounding
+upstream can swap a token's 6th and 7th expert, so the compared position is
+ALSO computed with the
 6th <-> 7th choice swapped in every layer whose gap (6th minus 7th logit), in
 the pass that leads to it, is under ``near_tie``: a tree of passes that forks
 at each such layer (at most ``MAX_PASSES`` leaves), and the position's margin
 is its best under any of them. What decides ``correct`` is ``mean_margin``, the
 mean over the compared positions of the best margin, against
 ``mean_margin_limit`` (``tolerance_why`` in the configuration file has the
-readings); the walk's own verdict is reported as ``walk_ok``.
+readings); the walk's own verdict is reported as ``walk_ok``. AND the program's
+ragged read must equal this file's dense mask at the cell's shapes
+(``window_read``, against ``window_read_limit``): served text cannot show
+whether a window binds.
 
 ``job["perturb"]`` (the builder's proof that the limit discriminates, never
 set by ``run.py``), each ONE thing wrong: ``{"no_window": true}`` (window layers
@@ -70,13 +76,13 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from reference import BOS, OFFSET, byte_class, known_bytes  # noqa: E402
-from reference_falcon_h1 import SPARE_ROWS, walk  # noqa: E402
+from reference import BOS, MIN_CHECKED, MIN_DECODE_CHECKED, OFFSET, byte_class  # noqa: E402
 
 HEAD_BLOCK = 37984  # columns of the head a call (151,936 = 4 blocks)
 EXPERT_BLOCK = 8  # experts a step of the dense expert sum
 Q_BLOCK = 256  # query positions a call of the prompt pass
 MAX_PASSES = 32  # leaves of a compared position's tree of routing passes
+LOST = -1  # a served position whose byte the text no longer shows (one U+FFFD)
 
 
 def build_forward(dims: dict, perturb: dict | None = None):
@@ -310,6 +316,134 @@ def context_logits(dims: dict, params: dict, tokens, positions, pieces=None,
     return out
 
 
+def served_bytes(text: str, n_new: int) -> list[int]:
+    """The served bytes a probe's text shows, a position each, ``LOST`` where
+    it shows U+FFFD. The byte tokenizer decodes with ``errors="replace"``: a
+    U+FFFD stands for ONE byte of 0x80-0xFF, or for a truncated sequence of two
+    or three. Every other character gives its own bytes, so the positions add
+    up to ``n_new`` exactly when every U+FFFD is one byte (a longer loss, a
+    special token or an early end leaves them short: nothing can make them
+    long). Then the whole text is walked; else only up to the first loss, as
+    ``reference.known_bytes`` has it. At this vocabulary a seeded model's
+    greedy byte is lost every other position, and 8 probes of 8 tokens cut at
+    the first loss showed 1-14 positions (PR 43)."""
+    out: list[int] = []
+    for ch in text:
+        out.extend([LOST] if ch == "\ufffd" else ch.encode("utf-8"))
+    if len(out) != n_new and LOST in out:
+        out = out[:out.index(LOST)]
+    return out[:n_new]
+
+
+def token_class(byte: int, V: int):
+    """The tokens a served position may have been: those of its byte, or of
+    any byte that cannot stand alone (0x80-0xFF) where the byte is lost."""
+    import numpy as np
+
+    if byte != LOST:
+        return byte_class(byte, V)
+    ids = np.arange(OFFSET, V)
+    return ids[(ids - OFFSET) % 256 >= 0x80]
+
+
+LOST_ROWS = 32  # contexts beyond one a probe that the lost bytes' runners-up may open
+
+
+def walk(logits_at, tokens, owner, served, P: int, n_new: int, V: int, tol: float) -> dict:
+    """``reference_falcon_h1.walk`` with LOST positions walked through, not
+    ended at. ``logits_at(step, rows)`` gives [R, V] with the ``rows`` filled.
+
+    At a known byte: as there. The best reference logit of the byte's tokens
+    must lie within ``tol`` of the maximum, else the context ends, wrong; every
+    further candidate within ``tol`` is a context of its own, and a probe is as
+    good as its best context. At a lost byte nothing is compared: the context
+    goes on with the lost class's best token, and every further one within
+    ``tol / 4`` of the maximum opens a context (the served path rounds to bf16
+    and may route a near-tie the other way: it picked a runner-up 0.06-0.17
+    off at 1 position in 100, PR 43). A context that followed the wrong token
+    reads far out at its next known byte. That end says nothing of the served
+    path, so it is a GUESS's end: not counted as wrong, not compared. A margin
+    within ``tol`` there is compared like any other, so a fault that moves
+    every position still moves the mean. ``margins`` holds, a compared
+    position, the best margin any of its contexts gave; ``checked`` counts
+    those."""
+    import numpy as np
+
+    R = tokens.shape[0]
+    row_worst = np.zeros((R,), np.float64)  # a live context's worst margin so far
+    guessed = np.zeros((R,), bool)  # lost bytes since the context's last compared one
+    alive = owner >= 0
+    ended: dict[int, list[float]] = {}  # probe -> worst margins of its ended contexts
+    margins_at: dict = {}  # (probe, step) -> the best margin of a compared position
+    events, stds, forks, forks_dropped, lost, guesses_ended = [], [], 0, 0, 0, 0
+    for step in range(n_new):
+        live = [r for r in range(R) if alive[r] and len(served[owner[r]]) > step]
+        for r in range(R):  # a context whose probe's text ends here has ended well
+            if alive[r] and r not in live:
+                ended.setdefault(int(owner[r]), []).append(float(row_worst[r]))
+                alive[r] = False
+        if not live:
+            break
+        logits = logits_at(step, live)
+        stds.append(float(np.std(logits[live])))
+        for r in live:
+            i = int(owner[r])
+            known = served[i][step] != LOST
+            cls = token_class(served[i][step], V)
+            margins = float(np.max(logits[r])) - logits[r, cls]
+            order = np.argsort(margins)
+            best = float(margins[order[0]])
+            if known:
+                if best > tol and guessed[r]:  # a wrong guess before it: this context ends here
+                    guesses_ended += 1
+                    alive[r] = False
+                    continue
+                if best > tol / 8.0:
+                    events.append((i, step, best))
+                margins_at[(i, step)] = min(margins_at.get((i, step), math.inf), best)
+                if best > tol:  # this context ends here, wrong
+                    ended.setdefault(i, []).append(max(float(row_worst[r]), best))
+                    alive[r] = False
+                    continue
+                row_worst[r] = max(row_worst[r], best)
+                guessed[r] = False
+            else:
+                lost += 1
+                guessed[r] = True
+            tokens[r, P + step] = cls[order[0]]
+            for j in order[1:]:  # every further candidate of the class within the width
+                if margins[j] > (tol if known else tol / 4.0):
+                    break
+                free = np.flatnonzero(owner < 0)
+                if not len(free):
+                    forks_dropped += 1
+                    continue
+                f = int(free[0])
+                tokens[f] = tokens[r]
+                tokens[f, P + step] = cls[j]
+                owner[f], alive[f], guessed[f] = i, True, guessed[r]
+                row_worst[f] = max(row_worst[r], float(margins[j])) if known else row_worst[r]
+                forks += 1
+    for r in range(R):
+        if alive[r]:
+            ended.setdefault(int(owner[r]), []).append(float(row_worst[r]))
+    per_probe = {i: min(ws) for i, ws in ended.items()}  # a probe is as good as its best context
+    worst = max(per_probe.values(), default=None)
+    checked = len(margins_at)
+    decode_checked = sum(step > 0 for _, step in margins_at)
+    enough = checked >= MIN_CHECKED and decode_checked >= MIN_DECODE_CHECKED
+    ok = bool(enough and worst is not None and math.isfinite(worst) and worst <= tol)
+    return {
+        "ok": ok, "checked": checked, "decode_checked": decode_checked,
+        "lost_walked": lost, "guesses_ended": guesses_ended, "enough_positions": enough,
+        "worst_margin": worst, "tolerance": tol, "forks": forks, "forks_dropped": forks_dropped,
+        "margins_over_tol_8th": sorted(events, key=lambda e: -e[2])[:20],
+        "logits_std": stds[0] if stds else None,
+        "tolerance_share_of_std": tol / stds[0] if stds and stds[0] else None,
+        "margins": margins_at,
+    }
+
+
 def compare(job: dict, conf: dict, params: dict) -> dict:
     """The comparison on ``job``'s served text with the program's seeded
     ``params``: the result line's fields (``ok`` decides ``correct``)."""
@@ -321,7 +455,7 @@ def compare(job: dict, conf: dict, params: dict) -> dict:
     probes = job["probes"]
     P = max(len(p["prompt"].encode()) for p in probes) + 1
     n_new = int(job["output_tokens"])
-    R = len(probes) + SPARE_ROWS
+    R = len(probes) + LOST_ROWS
     tokens = np.zeros((R, P + n_new), np.int32)
     owner = np.full((R,), -1, np.int64)
     for i, p in enumerate(probes):
@@ -331,13 +465,12 @@ def compare(job: dict, conf: dict, params: dict) -> dict:
         tokens[i, 0] = BOS
         tokens[i, 1:P] = np.frombuffer(raw, np.uint8).astype(np.int32) + OFFSET
         owner[i] = i
-    served = [known_bytes(p["text"])[:n_new] for p in probes]
+    served = [served_bytes(p["text"], n_new) for p in probes]
     near_tie = float(conf["reference"]["near_tie"])
     tol = float(job["tolerance"])
     T = _padded(P + n_new)
     states: dict[int, list] = {}  # row -> its context's layer inputs
     seen = {"min_gap": math.inf, "near": 0, "rescued": 0}
-    position_margin: dict = {}  # (probe, step) -> the best margin any of its contexts gave
 
     def state_of(r: int, pos: int) -> list:
         """Row r's state up to ``pos``: its own, a copy of the context it was
@@ -353,28 +486,25 @@ def compare(job: dict, conf: dict, params: dict) -> dict:
                 states[r] = prompt_pass(dims, params, padded, P - 1, pieces)
         return states[r]
 
-    def logits_at(step: int):
+    def logits_at(step: int, rows: list):
         """[R, V] for the walk: a live row's logits under the routing pass
         (plain, or its near-tie layers swapped at the compared position) that
         serves its probe's byte best. The walk then applies its tolerance."""
         pos = P - 1 + step
         folded = np.zeros((R, V), np.float32)
-        for r in np.flatnonzero(owner >= 0):
+        for r in set(states) - set(rows):  # an ended context's state: half a GB of host memory
+            del states[r]
+        for r in rows:
             text = served[owner[r]]
-            if len(text) <= step:
-                states.pop(int(r), None)
-                continue
             outs, gaps = position_logits(
                 dims, params, state_of(int(r), pos), int(tokens[r, pos]), pos, near_tie, pieces)
-            cls = byte_class(text[step], V)
+            cls = token_class(text[step], V)
             margins = [float(o.max() - o[cls].max()) for o in outs]
             best = int(np.argmin(margins))
             folded[r] = outs[best]
             seen["min_gap"] = min(seen["min_gap"], min(gaps))
             seen["near"] += min(gaps) < near_tie
             seen["rescued"] += margins[0] > tol >= margins[best]
-            at = (int(owner[r]), step)
-            position_margin[at] = min(position_margin.get(at, math.inf), margins[best])
         return folded
 
     res = walk(logits_at, tokens, owner, served, P, n_new, V, tol)
@@ -382,6 +512,7 @@ def compare(job: dict, conf: dict, params: dict) -> dict:
     # routing swap at an EARLIER token throws one position far out and leaves
     # the others where they were; a fault of the model moves every position)
     mean_limit = float(conf["reference"]["mean_margin_limit"])
+    position_margin = res.pop("margins")
     mean_margin = (sum(position_margin.values()) / len(position_margin)
                    if position_margin else math.inf)
     return {
@@ -393,6 +524,95 @@ def compare(job: dict, conf: dict, params: dict) -> dict:
         "near_tie_rescued": int(seen["rescued"]),
         "min_gap": None if math.isinf(seen["min_gap"]) else seen["min_gap"],
     }
+
+
+def window_read(conf: dict, prompt_tokens: int, output_tokens: int,
+                perturb: dict | None = None) -> dict:
+    """The program's ragged read against this file's dense mask, layer by
+    layer, at the cell's own shapes: the window that ``core.make_layer_window``
+    gives each layer of the program's preset, the page-table width of
+    ``max_seq_len`` (1,024 pages), decode rows at the probes' depths and just
+    past and just inside one window, and one prefill chunk of ``prefill_chunk``
+    queries that ends at the probes' prompt. Served text cannot show a window:
+    seeded weights spread attention over 6k keys nearly evenly, so the keys
+    behind a window change a logit by less than bf16 does (``tolerance_why``).
+    Here q is drawn four times as wide as k, so a score's std is 4 and a query
+    leans on a handful of keys, a third of them behind the window of a row 6k
+    deep: a read that sees one of them, or skips a page it should see, is off
+    by a whole value row. ``window_read_err`` is the largest |read - dense| of
+    any output, in units of the values' rms, against ``window_read_limit``.
+    Shares with the served path: ``ops/ragged.make_ragged_attn_fn`` and the
+    preset's per-layer window; nothing of the model's weights."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.models import core
+    from bee2bee_tpu.models.config import get_config
+    from bee2bee_tpu.ops.ragged import make_ragged_attn_fn
+
+    srv = conf["server"]["config_json"]
+    mcfg = get_config(conf["server"]["model"])
+    S, BS, C = int(srv["max_seq_len"]), int(srv.get("kv_block_size", 16)), int(srv["prefill_chunk"])
+    W, L = int(conf["sliding_window_size"]), int(conf["layers"])
+    H, Hkv, hd = conf["num_attention_heads"], conf["num_key_value_heads"], conf["head_dim"]
+    layout = conf["sliding_window_layout"][:L]
+    dtype = jnp.dtype(srv.get("dtype", "bfloat16"))
+    P = int(prompt_tokens)
+    decode = [min(n, S) for n in (P + int(output_tokens), P, W + 1, W - 1)]  # tokens cached a row
+    chunk_at = (P - 1) // C * C  # the prompt's last chunk
+    rows = [(n - 1, 1) for n in decode] + [(chunk_at, C)]  # (the first query's position, queries)
+    pages = [-(-(at + T) // BS) for at, T in rows]
+    rng = np.random.default_rng(0)
+    ids = rng.permutation(sum(pages)) + 1  # block 0 is the null block
+    tables = np.zeros((len(rows), S // BS), np.int32)
+    for r, n in enumerate(pages):
+        tables[r, :n] = ids[sum(pages[:r]):sum(pages[:r]) + n]
+    kq, kk, kv = jax.random.split(jax.random.key(0), 3)
+    pool_shape = (L, Hkv, len(ids) + 1, BS, hd)
+    k_pool = jax.random.normal(kk, pool_shape, jnp.float32).astype(dtype)
+    v_pool = jax.random.normal(kv, pool_shape, jnp.float32).astype(dtype)
+    attn = make_ragged_attn_fn(None)
+    layer_window = core.make_layer_window(mcfg)
+
+    @jax.jit
+    def read(k_pool, v_pool, q, table, positions, layer):
+        return attn(q, k_pool, v_pool, layer_window(layer), mcfg, positions=positions,
+                    block_tables=table, layer=layer)
+
+    @jax.jit
+    def dense(k_pool, v_pool, q, table, positions, layer):  # q [T, H, hd] of ONE row, its table [MB]
+        with jax.default_matmul_precision("highest"):
+            def keys(pool):  # [S, Hkv, hd] as the row's table maps them
+                return jnp.take(pool[layer], table, axis=1).transpose(1, 2, 0, 3).reshape(
+                    S, Hkv, hd).astype(jnp.float32)
+
+            pos = jnp.arange(S)
+            seen = pos[None, :] <= positions[:, None]
+            if not (perturb or {}).get("no_window"):
+                seen &= (jnp.asarray(layout)[layer] == 0) | (pos[None, :] > positions[:, None] - W)
+            s = jnp.einsum("tgjd,sgd->gjts", q.astype(jnp.float32).reshape(-1, Hkv, H // Hkv, hd),
+                           keys(k_pool)) / math.sqrt(hd)
+            p = jax.nn.softmax(jnp.where(seen[None, None], s, -1e30), axis=-1)
+            return jnp.einsum("gjts,sgd->tgjd", p, keys(v_pool)).reshape(-1, H * hd)
+
+    worst, Tq = 0.0, min(C, 512)  # the dense side in blocks of Tq queries
+    for T, which in ((1, range(len(decode))), (C, [len(decode)])):
+        at = np.asarray([rows[r][0] for r in which], np.int32)
+        positions = at[:, None] + np.arange(T, dtype=np.int32)[None]
+        q = (4.0 * jax.random.normal(jax.random.fold_in(kq, T), (len(at), T, H, hd),
+                                     jnp.float32)).astype(dtype)
+        for layer in range(L):
+            got = np.asarray(read(k_pool, v_pool, q, tables[list(which)], positions,
+                                  np.int32(layer)), np.float32)
+            for n, r in enumerate(which):
+                for t in range(0, T, Tq):
+                    want = np.asarray(dense(k_pool, v_pool, q[n, t:t + Tq], tables[r],
+                                            positions[n, t:t + Tq], np.int32(layer)))
+                    worst = max(worst, float(np.abs(got[n, t:t + Tq] - want).max()))
+    limit = float(conf["reference"]["window_read_limit"])
+    return {"window_read_err": worst, "window_read_limit": limit, "window_read_ok": worst <= limit,
+            "window_read_rows": [list(r) for r in rows]}
 
 
 def main(job_path: str) -> int:
@@ -427,6 +647,9 @@ def main(job_path: str) -> int:
         print(json.dumps({"ok": False, "error": f"the program's preset {srv['model']!r} "
                           f"differs from the configuration file: {differs}"}))
         return 1
+    # before the weights are made: the dense side of a 2,048-query chunk wants the room
+    seen = window_read(conf, len(job["probes"][0]["prompt"].encode()) + 1, job["output_tokens"],
+                       job.get("perturb"))
     mesh = local_mesh()
     dtype = jnp.dtype(srv.get("config_json", {}).get("dtype", "bfloat16"))
     key = jax.random.key(0)  # EngineConfig.rng_seed: the node config cannot set it
@@ -436,8 +659,9 @@ def main(job_path: str) -> int:
         out_shardings=partition.param_shardings(shapes, mesh, mcfg))
 
     res = compare(job, conf, params)
+    res["ok"] = bool(res["ok"] and seen["window_read_ok"])
     dev0 = devs[0]
-    print(json.dumps({**res, "device": {"platform": dev0.platform, "kind": dev0.device_kind,
+    print(json.dumps({**res, **seen, "device": {"platform": dev0.platform, "kind": dev0.device_kind,
                                         "count": len(devs)}}))
     return 0 if res["ok"] else 1
 

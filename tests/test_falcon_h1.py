@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bee2bee_tpu.engine import EngineConfig, InferenceEngine, RecurrentStateUnsupported
+from bee2bee_tpu.engine import EngineConfig, InferenceEngine, FeatureUnsupported
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import CONFIGS, config_from_hf, get_config
 
@@ -362,6 +362,6 @@ REFUSED = {
 
 @pytest.mark.parametrize("feature", sorted(REFUSED))
 def test_config_features_that_cannot_carry_the_state_are_refused(feature):
-    with pytest.raises(RecurrentStateUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         _engine(**REFUSED[feature])
     assert err.value.feature == feature and feature in str(err.value)

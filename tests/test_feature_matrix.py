@@ -175,9 +175,9 @@ def test_lora_with_quantize_int8():
 
 
 def _refused(feature, build):
-    from bee2bee_tpu.engine import RecurrentStateUnsupported
+    from bee2bee_tpu.engine import FeatureUnsupported
 
-    with pytest.raises(RecurrentStateUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         build()
     assert err.value.feature == feature and "tiny-falcon-h1" in str(err.value)
 
@@ -222,9 +222,9 @@ def test_recurrent_model_serves_with_int8_kv_and_int8_weights():
 
 
 def _latent_refused(feature, build):
-    from bee2bee_tpu.engine import LatentPoolUnsupported
+    from bee2bee_tpu.engine import FeatureUnsupported
 
-    with pytest.raises(LatentPoolUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         build()
     assert err.value.feature == feature and "tiny-joyai" in str(err.value)
 
@@ -281,9 +281,9 @@ def test_latent_model_serves_with_chunked_prefill_penalties_and_the_ragged_reade
 
 
 def _dropless_refused(feature, build):
-    from bee2bee_tpu.engine import DroplessExpertsUnsupported
+    from bee2bee_tpu.engine import FeatureUnsupported
 
-    with pytest.raises(DroplessExpertsUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         build()
     assert err.value.feature == feature and "tiny-smallthinker" in str(err.value)
 

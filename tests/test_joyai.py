@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bee2bee_tpu.engine import EngineConfig, InferenceEngine, LatentPoolUnsupported
+from bee2bee_tpu.engine import EngineConfig, InferenceEngine, FeatureUnsupported
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import config_from_hf, get_config
 from bee2bee_tpu.ops.ragged import make_ragged_attn_fn
@@ -700,7 +700,7 @@ REFUSED = {
 
 @pytest.mark.parametrize("feature", sorted(REFUSED))
 def test_features_not_proven_over_a_latent_pool_are_refused(feature):
-    with pytest.raises(LatentPoolUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         _engine(**REFUSED[feature])
     assert err.value.feature == feature and feature in str(err.value)
 

@@ -49,6 +49,9 @@ ALLOWED_ABSENT = {
     "engine.moe_expert_load_max": "no dropless expert layer in the boot's model",
     "engine.latent_tokens_read": "no latent attention in the boot's model",
     "engine.prefill_chunks": "no prompt walks more than one prefill window in this boot",
+    # models with a sliding-window layer only (tests/test_smallthinker.py reads them)
+    "engine.kv_tokens_held": "no layer of the boot's model reads behind a window",
+    "engine.kv_tokens_behind_window": "no layer of the boot's model reads behind a window",
     # CPU test backend: device.memory_stats() is None and no
     # BEE2BEE_HBM_BYTES budget is set, so headroom cannot compute
     "engine.hbm_headroom_frac": "no device memory stats on CPU",
@@ -286,7 +289,7 @@ def test_every_device_trace_scope_the_model_opens_is_documented():
     assert {"mla.q_proj", "mla.kv_proj", "mla.write", "mla.read", "mla.out",
             "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
             "ssm.step", "kv.write"} <= opened
-    # the plain attention's parts, opened for a dropless-expert model only
+    # the plain attention's parts, opened where core._attn_scoped says
     # (transformer_block's ``scope``: smallthinker)
     attn = set(re.findall(r'scope\("(attn\.[a-z_]+)"\)', src))
     assert attn == {"attn.qkv", "attn.rope", "attn.write", "attn.read", "attn.out"}

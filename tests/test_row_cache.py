@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bee2bee_tpu.engine import EngineConfig, InferenceEngine, RecurrentStateUnsupported
+from bee2bee_tpu.engine import EngineConfig, InferenceEngine, FeatureUnsupported
 from bee2bee_tpu.engine.paged import PoolExhausted, RowCache
 from bee2bee_tpu.models import core
 
@@ -285,7 +285,7 @@ def test_import_refuses_with_nothing_taken_and_recurrent_export_is_typed():
     eng, rc = _cache("tiny-falcon-h1")
     try:
         rc.cover(0, BS)
-        with pytest.raises(RecurrentStateUnsupported) as err:
+        with pytest.raises(FeatureUnsupported) as err:
             rc.export_row(0, BS)  # its pages are not its whole state
         assert err.value.feature == "kv_export"
         rc.release(0)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bee2bee_tpu.engine import DroplessExpertsUnsupported, EngineConfig, InferenceEngine
+from bee2bee_tpu.engine import EngineConfig, FeatureUnsupported, InferenceEngine
 from bee2bee_tpu.metrics import get_registry
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import config_from_hf, get_config
@@ -387,7 +387,7 @@ REFUSED = {
 
 @pytest.mark.parametrize("feature", sorted(REFUSED))
 def test_features_not_proven_for_dropless_layers_of_two_kinds_are_refused(feature):
-    with pytest.raises(DroplessExpertsUnsupported) as err:
+    with pytest.raises(FeatureUnsupported) as err:
         InferenceEngine("tiny-smallthinker",
                         engine_config=EngineConfig(**{**ENGINE_KW, **REFUSED[feature]}))
     assert err.value.feature == feature and "tiny-smallthinker" in str(err.value)
@@ -462,8 +462,8 @@ def test_tiles_are_counted_a_layer_kind_and_the_window_gauges_follow_the_rows():
     tiles = get_registry().counter("engine.kv_tiles")
     live0, step0 = tiles.value(kind="live"), tiles.value(kind="stepped")
     cache.count_tiles(tables, offsets, 1, calls=4)
-    assert tiles.value(kind="live") - live0 == pytest.approx(4 * (full[0] + 3 * bound[0]) / 4)
-    assert tiles.value(kind="stepped") - step0 == pytest.approx(4 * full[1])
+    assert tiles.value(kind="live") - live0 == 4 * (full[0] + 3 * bound[0]) // 4  # whole tiles
+    assert tiles.value(kind="stepped") - step0 == 4 * full[1]
     cache.note_tokens_held([410, 33, 10])
     held = get_registry().gauge("engine.kv_tokens_held").value()
     behind = get_registry().gauge("engine.kv_tokens_behind_window").value()
@@ -474,7 +474,93 @@ def test_tiles_are_counted_a_layer_kind_and_the_window_gauges_follow_the_rows():
 
 def test_a_model_whose_window_never_binds_holds_nothing_behind_it():
     eng = InferenceEngine("tiny-llama", engine_config=EngineConfig(**ENGINE_KW))
+    assert not eng.scheduler.cache.windowed  # so the scheduler never calls it for this model
     eng.scheduler.cache.note_tokens_held([50, 60])
     assert get_registry().gauge("engine.kv_tokens_behind_window").value() == 0
     assert get_registry().gauge("engine.kv_tokens_held").value() == 110 * eng.model_cfg.n_layers
     eng.close()
+
+
+# ------------------------------------------ the cell's check: lost bytes, the window
+
+
+@pytest.mark.parametrize("text,want", [
+    ("abcdefgh", list(b"abcdefgh")),
+    # every U+FFFD one byte (the positions add up to 8): the whole text is walked
+    ("a�b��cde", [97, plain.LOST, 98, plain.LOST, plain.LOST, 99, 100, 101]),
+    ("é�abcde", [0xC3, 0xA9, plain.LOST, 97, 98, 99, 100, 101]),
+    # a U+FFFD that stands for a truncated sequence (7 positions of 8): cut at the first loss
+    ("ab�cdef", [97, 98]),
+    ("�abcdef", []),
+    ("abc", [97, 98, 99]),  # an early end without a loss stays whole
+])
+def test_served_bytes_walks_through_a_loss_only_where_every_loss_is_one_byte(text, want):
+    assert plain.served_bytes(text, 8) == want
+
+
+def test_the_lost_class_is_every_token_of_a_byte_that_cannot_stand_alone():
+    cls = plain.token_class(plain.LOST, 1000)
+    assert len(cls) == sum((t - 3) % 256 >= 0x80 for t in range(3, 1000))
+    assert cls.min() == 3 + 0x80 and not set(cls) & set(plain.token_class(97, 1000))
+
+
+def test_the_walk_goes_on_through_a_lost_byte_and_its_runner_up_opens_a_context():
+    """Probe 0's text: 'a', a lost byte, 'b'. At the lost step the reference's
+    best lost-class token is 3 + 0x80 and its runner-up 3 + 0x81 lies within
+    tol / 4: the SERVED token was the runner-up, and only the context that
+    follows it finds 'b' at the top next. The context behind the best token
+    ends there as a guess: not wrong, not compared. Probe 1 ('c' behind a
+    lost byte whose served token was no candidate at all) ends the same way
+    and leaves nothing compared."""
+    V, P, tol = 600, 4, 0.5
+    tokens = np.zeros((6, P + 3), np.int32)
+    owner = np.asarray([0, 1, -1, -1, -1, -1], np.int64)
+    a, b, best, second = 3 + 97, 3 + 98, 3 + 0x80, 3 + 0x81
+
+    def logits_at(step, rows):
+        out = np.zeros((6, V), np.float32)
+        for r in rows:
+            if owner[r] == 1:  # lost, then 'c' far down whatever was guessed
+                out[r, best if step == 0 else a] = 2.0
+            elif step == 0:
+                out[r, a] = 2.0
+            elif step == 1:
+                out[r, best], out[r, second], out[r, 3 + 0x90] = 2.0, 1.9, 1.5
+            else:  # 'b' leads only behind the runner-up; behind the best token it is far down
+                out[r, b] = 2.0 if tokens[r, P + 1] == second else -3.0
+                out[r, 3 + 99] = 1.0
+        return out
+
+    served = [[97, plain.LOST, 98], [plain.LOST, 99]]
+    res = plain.walk(logits_at, tokens, owner, served, P, 3, V, tol)
+    assert res["margins"] == {(0, 0): 0.0, (0, 2): 0.0} and res["checked"] == 2
+    assert res["decode_checked"] == 1 and res["lost_walked"] == 2 and res["forks"] == 1
+    assert res["guesses_ended"] == 2 and res["worst_margin"] == 0.0
+    assert sorted(tokens[owner == 0, P + 1]) == [best, second]
+
+
+def test_a_known_byte_far_down_behind_a_known_one_ends_the_probe_wrong():
+    V, P = 600, 4
+    tokens, owner = np.zeros((2, P + 2), np.int32), np.asarray([0, -1], np.int64)
+
+    def logits_at(step, rows):
+        out = np.zeros((2, V), np.float32)
+        out[rows, 3 + 97 if step == 0 else 3 + 120] = 2.0
+        return out
+
+    res = plain.walk(logits_at, tokens, owner, [[97, 98]], P, 2, V, 0.5)
+    assert res["margins"] == {(0, 0): 0.0, (0, 1): 2.0} and res["worst_margin"] == 2.0
+    assert res["guesses_ended"] == 0 and not res["ok"]
+
+
+def test_the_window_read_check_passes_the_served_read_and_fails_an_ignored_window():
+    """benchmark/reference_smallthinker.window_read at the tiny fixture: the
+    program's ragged read under the preset's per-layer window equals the dense
+    mask; a reference that ignores the window reads a whole value row off."""
+    conf = json.loads((ROOT / "benchmark/tests/fixtures/tiny-smallthinker.json").read_text())
+    good = plain.window_read(conf, 60, 8)
+    assert good["window_read_ok"] and good["window_read_err"] < 1e-4, good
+    # decode rows at the probes' depth, one token past and one inside the window; a chunk
+    assert good["window_read_rows"] == [[67, 1], [59, 1], [24, 1], [22, 1], [32, 32]]
+    bad = plain.window_read(conf, 60, 8, {"no_window": True})
+    assert not bad["window_read_ok"] and bad["window_read_err"] > 0.5, bad

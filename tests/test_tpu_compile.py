@@ -142,6 +142,13 @@ RAGGED_CASES = {
     "phi-3-mini-prefill-2048-stacked": (
         "phi-3-mini", dict(B=1, T=2048, MB=128, layers=2)),
     "falcon-h1-decode-stacked": ("falcon-h1-34b", dict(B=64, T=1, MB=32, layers=2)),
+    # the smallthinker cell's on-chip check of the read (benchmark/
+    # reference_smallthinker.window_read): 4 decode rows and one 2,048-query
+    # chunk at the 1,024-page table, behind the 4,096 window
+    "smallthinker-window-read-decode": (
+        "smallthinker-21b-a3b-8l", dict(B=4, T=1, MB=1024, layers=2, window=4096)),
+    "smallthinker-window-read-chunk": (
+        "smallthinker-21b-a3b-8l", dict(B=1, T=2048, MB=1024, layers=2, window=4096)),
 }
 
 
