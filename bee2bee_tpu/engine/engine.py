@@ -1082,7 +1082,7 @@ class InferenceEngine:
         (partition.paged_cache_spec) so TP serving gathers stay local;
         under attention='sp' the slot dim additionally shards over `seq`
         (per-device pool memory 1/seq — the long-context scaling). An
-        int8 pool (cache_dtype='int8') carries k_scale/v_scale arrays,
+        int8 pool (cache_dtype='int8') carries its kv_scale array,
         sharded like the pool's kv-head dim (partition.paged_scale_spec).
         The in-place path's pool is lane-aligned (below)."""
         from ..ops.flash import interpret_off_tpu
@@ -1105,7 +1105,8 @@ class InferenceEngine:
         shardings = {
             name: NamedSharding(
                 self.mesh,
-                self._fit_spec(spec if arr.ndim == 5 else sspec, arr.shape),
+                self._fit_spec(
+                    sspec if name.endswith("_scale") else spec, arr.shape),
             )
             for name, arr in jax.eval_shape(make).items()
         }

@@ -6,8 +6,8 @@ decode step (the scheduler measured 4x decode cost at bsz=8 with one
 active row). This module replaces the row-owns-capacity model with the
 vLLM/"Ragged Paged Attention" (PAPERS.md, arxiv 2604.15464) pool model:
 
-- **One pool** ``[L, num_blocks, block_size, Hkv, hd]`` holds every
-  row's K/V. Block 0 is the reserved null block (padding target; never
+- **One pool** ``[L, num_blocks, 2, Hkv, block_size, hd]`` holds every
+  row's K beside its V (core.init_paged_pool). Block 0 is the reserved null block (padding target; never
   allocated).
 - **Per-row block tables** map logical position ``p`` to pool slot
   ``(table[p // block_size], p % block_size)``. The map is
@@ -68,8 +68,8 @@ _G_BLOCKS_TOTAL = get_registry().gauge(
 _C_KV_PAGES_WRITTEN = get_registry().counter(
     "engine.kv_pages_written",
     "pool pages the page-write kernel copied in and out: batch rows x the "
-    "pages a chunk can touch x the write calls (K and V, every layer, "
-    "every attention call of the dispatch); 0 on the scatter paths",
+    "pages a chunk can touch x the write calls (one a layer: a page holds K "
+    "beside V; every attention call of the dispatch); 0 on the scatter paths",
 )
 _C_KV_TILES = get_registry().counter(
     "engine.kv_tiles",
@@ -320,53 +320,74 @@ class PagedPrefixCache:
             pass
 
 
-# ---- the jitted programs over the pool and the state. Pool leaves carry
-# their block dim on axis 2: the [L, Hkv, NB, BS, hd] pages and the int8
-# pool's [L, Hkv, NB] scales line up, so one program moves pages and
-# their scales together. State leaves are [L, B, ...], row dim 1. All but
-# the gather (a pure read: the pool keeps serving) donate what they update.
-# Each body runs under the root scope "prog.pool" (tracing.prog_scope).
+# ---- the jitted programs over the pool and the state. Every leaf's slot
+# dim is axis 1: the pool's block axis (the [L, NB, 2, Hkv, BS, hd] pages,
+# the int8 pool's [L, NB, 2, Hkv] scales and a latent pool's
+# [L, NB, 1, BS, W] rows line up, so one program moves pages and their
+# scales together), the state's row ([L, B, ...]). All but the gather (a
+# pure read: the pool keeps serving) donate what they update. Each body
+# runs under the root scope "prog.pool" (tracing.prog_scope).
 
 _donating = functools.partial(jax.jit, donate_argnums=(0,))
 
+# the block export format (RowCache.export_row, meshnet/migrate.py) is
+# HEAD-major with the block axis at 2, K and V (and their scales) apart:
+# {"k", "v"} [L, Hkv, nb, BS, hd] (+ {"k_scale", "v_scale"} [L, Hkv, nb]),
+# or {"latent"} [L, 1, nb, BS, W]. It is older than the stored layout and
+# did not change with it (a peer may run either): the two halves of a
+# stored leaf's pages split and join at this edge
+_WIRE_HALVES = {"kv": ("k", "v"), "kv_scale": ("k_scale", "v_scale")}
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+
+@_donating
 @prog_scope("prog.pool")
-def _copy_slot(axis, tree, src, dst):
-    """Slot ``src`` of every leaf copied over slot ``dst`` along ``axis``: a
-    pool block (axis 2, the CoW copy) or a state row (axis 1, compaction).
-    Scalar ids: one trace a tree, ever."""
+def _copy_slot(tree, src, dst):
+    """Slot ``src`` of every leaf copied over slot ``dst``: a pool block
+    (the CoW copy) or a state row (compaction). Scalar ids: one trace a
+    tree, ever."""
     return jax.tree.map(
         lambda big: jax.lax.dynamic_update_slice_in_dim(
-            big, jax.lax.dynamic_slice_in_dim(big, src, 1, axis=axis),
-            dst, axis=axis), tree)
+            big, jax.lax.dynamic_slice_in_dim(big, src, 1, axis=1),
+            dst, axis=1), tree)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 @prog_scope("prog.pool")
 def _gather_blocks(hd, pool, idx):
-    """Blocks ``idx`` of every leaf, pages cut to the model's head size
-    ``hd``: a lane-aligned pool's pad lanes (core.init_paged_pool) do not
-    travel, so a peer's pool may be laid out either way."""
-    return {
-        name: arr[:, :, idx][..., :hd] if arr.ndim == 5 else arr[:, :, idx]
-        for name, arr in pool.items()
-    }
+    """Blocks ``idx`` of every leaf in the export format, pages cut to the
+    model's head size ``hd``: a lane-aligned pool's pad lanes
+    (core.init_paged_pool) do not travel."""
+    out = {}
+    for name, arr in pool.items():
+        got = arr[:, idx]
+        halves = _WIRE_HALVES.get(name)
+        parts = {name: got} if halves is None else {
+            wire: got[:, :, i] for i, wire in enumerate(halves)}
+        for wire, part in parts.items():
+            part = jnp.swapaxes(part, 1, 2)  # blocks behind the heads
+            out[wire] = part[..., :hd] if part.ndim == 5 else part
+    return out
 
 
 @_donating
 @prog_scope("prog.pool")
 def _scatter_blocks(pool, new, idx):
-    """Write blocks ``new`` at ``idx``; pages narrower than the pool's
-    (head size vs lane-aligned) get their pad lanes zeroed."""
-    def aligned(blocks, arr):
-        if arr.ndim != 5 or blocks.shape[-1] == arr.shape[-1]:
-            return blocks
-        pad = arr.shape[-1] - blocks.shape[-1]
-        return jnp.pad(blocks, ((0, 0),) * 4 + ((0, pad),))
+    """Write blocks ``new`` (the export format) at ``idx``; pages narrower
+    than the pool's (head size vs lane-aligned) get their pad lanes
+    zeroed."""
+    def stored(name, arr):
+        def part(wire):
+            blocks = jnp.swapaxes(new[wire], 1, 2)  # blocks in front again
+            pad = arr.shape[-1] - blocks.shape[-1] if blocks.ndim == 5 else 0
+            return jnp.pad(blocks, ((0, 0),) * (blocks.ndim - 1) + ((0, pad),))
+
+        halves = _WIRE_HALVES.get(name)
+        if halves is None:
+            return part(name)
+        return jnp.stack([part(wire) for wire in halves], axis=2)
 
     return {
-        name: arr.at[:, :, idx].set(aligned(new[name], arr))
+        name: arr.at[:, idx].set(stored(name, arr))
         for name, arr in pool.items()
     }
 
@@ -374,11 +395,7 @@ def _scatter_blocks(pool, new, idx):
 @_donating
 @prog_scope("prog.pool")
 def _reset_scales(pool, idx):
-    return dict(
-        pool,
-        k_scale=pool["k_scale"].at[:, :, idx].set(0.0),
-        v_scale=pool["v_scale"].at[:, :, idx].set(0.0),
-    )
+    return dict(pool, kv_scale=pool["kv_scale"].at[:, idx].set(0.0))
 
 
 @_donating
@@ -435,7 +452,7 @@ class RowCache:
         # the CoW copy is scalar-arg'd (one trace ever): un-predicated,
         # repeats storm
         self._copy_block = ic.sentinel.watch(
-            "cow_copy", _copy_slot, key_fn=lambda axis, pool, src, dst: ()
+            "cow_copy", _copy_slot, key_fn=lambda pool, src, dst: ()
         )
         # engine.hbm_bytes{component}: a latent pool goes under its own name
         ic.ledger.register(
@@ -575,9 +592,7 @@ class RowCache:
         self.row_blocks[dst] = self.row_blocks[src]
         self.row_blocks[src] = []
         if self.recurrent:
-            self.state = _copy_slot(
-                1, self.state, np.int32(src), np.int32(dst)
-            )
+            self.state = _copy_slot(self.state, np.int32(src), np.int32(dst))
 
     def resize(self, bsz: int):
         """Follow the batch bucket to ``bsz`` rows: the state is re-shaped,
@@ -637,7 +652,7 @@ class RowCache:
 
             _C_KV_PAGES_WRITTEN.inc(
                 rows * chunk_pages(chunk, self.block_size)
-                * calls * len(self.layout) * e.model_cfg.n_layers
+                * calls * e.model_cfg.n_layers
             )
 
     def count_tiles(self, tables, offsets, chunk: int, calls: int = 1):
@@ -655,8 +670,8 @@ class RowCache:
             return
         from ..ops.ragged import work_counts  # loaded with the attn_fn
 
-        k = next(iter(self.pool.values()))  # K, or the latent rows
-        heads, _, block, head_dim = k.sharding.shard_shape(k.shape)[1:]
+        pages = next(iter(self.pool.values()))  # K beside V, or latent rows
+        *_, heads, block, head_dim = pages.sharding.shard_shape(pages.shape)
         live = stepped = 0
         for window, n in collections.Counter(cfg.layer_windows).items():
             one = work_counts(
@@ -739,7 +754,7 @@ class RowCache:
             fresh = self._alloc_fresh(1)
             # the ONE CoW device copy
             self.pool = self._copy_block(
-                2, self.pool, np.int32(partial), np.int32(fresh[0])
+                self.pool, np.int32(partial), np.int32(fresh[0])
             )
             row.append(fresh[0])
             self.tables[b, full] = fresh[0]
@@ -781,8 +796,8 @@ class RowCache:
 
     def import_row(self, b: int, upto: int, kv: dict):
         """Row b from shipped pages covering [0, upto): fresh blocks, the
-        table, one scatter (``kv``'s leaves match the pool's —
-        engine.import_generation validated them). Raises PoolExhausted
+        table, one scatter (``kv`` is the export format of this pool —
+        engine.import_generation validated it). Raises PoolExhausted
         with nothing taken."""
         self.cover(b, upto)  # b is a free row: all of it is fresh
         idx = self._padded_index(self.row_blocks[b])
@@ -790,8 +805,8 @@ class RowCache:
         # aimed at the null block
         pad = len(idx) - len(self.row_blocks[b])
         new = {
-            name: np.pad(kv[name], [(0, 0), (0, 0), (0, pad)]
-                         + [(0, 0)] * (np.ndim(kv[name]) - 3))
-            for name in self.pool
+            name: np.pad(arr, [(0, 0), (0, 0), (0, pad)]
+                         + [(0, 0)] * (np.ndim(arr) - 3))
+            for name, arr in kv.items()
         }
         self.pool = _scatter_blocks(self.pool, new, idx)

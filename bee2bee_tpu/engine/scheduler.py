@@ -12,7 +12,7 @@ no batching at all — reference hf.py:84-108):
   chunk; on TPU, decode is HBM-bandwidth-bound on the weights, so batched
   rows ride along nearly free — this is the route to the BASELINE
   throughput ladder, not bigger single streams. The ONE cache layout is
-  the block pool ``[L, Hkv, num_blocks, block_size, hd]`` + per-row block
+  the block pool ``[L, num_blocks, 2, Hkv, block_size, hd]`` + per-row block
   tables (engine/paged.py): blocks are allocated lazily, attention
   touches only live blocks — per-step cache HBM traffic scales with live
   tokens instead of ``bsz * max_seq`` (the deleted rectangular layout's

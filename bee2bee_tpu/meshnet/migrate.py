@@ -525,7 +525,9 @@ class MigrationManager:
         """Pool blocks → binary tensor frames, <= MAX_CHUNK_BYTES each,
         with per-buffer sha256 in the header (the pieces.py discipline).
         Generic over the pool's leaves: an int8 pool ships k/v pages AND
-        their k_scale/v_scale tensors (block dim = axis 2 on every leaf),
+        their k_scale/v_scale tensors (the export format of
+        RowCache.export_row: block dim = axis 2 on every tensor, whatever
+        layout either pool stores),
         each hashed separately — a corrupt SCALE is as fatal to the
         import as a corrupt page and takes the same typed refusal."""
         arrs = {name: np.asarray(a) for name, a in kv.items()}

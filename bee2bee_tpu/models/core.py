@@ -471,18 +471,18 @@ def _attention(q, k, v, mask, cfg: ModelConfig):
     return out.reshape(B, T, H * hd)
 
 
-def _quantized_page_write(pool, scale, blk, slot, wslot, xT):
+def _quantized_page_write(pool, scale, blk, slot, wslot, x):
     """Quantize-on-write scatter for ONE layer of an int8 paged pool —
-    the models/quant.py symmetric amax recipe at (page, kv-head)
-    granularity.
+    the models/quant.py symmetric amax recipe at (page, K or V, kv-head)
+    granularity, K and V in one pass.
 
-    ``pool`` [Hkv, NB, BS, hd] int8; ``scale`` [Hkv, NB] f32;
+    ``pool`` [NB, 2, Hkv, BS, hd] int8; ``scale`` [NB, 2, Hkv] f32;
     ``blk``/``slot`` [B, T] the position→(page, slot) map WITH the
     write-floor/ceil null redirects already applied (so CoW donor pages
     are never touched — redirected positions land in the null block 0);
     ``wslot`` [B, T] each position's index into the chunk's page window
-    (positions // BS - offset // BS); ``xT`` [Hkv, B, T, hd] the chunk's
-    freshly projected K or V, head-major like the pool.
+    (positions // BS - offset // BS); ``x`` [B, T, 2, Hkv, hd] the chunk's
+    freshly projected K beside its V, as a page holds them.
 
     A page's scale is a RUNNING MAX over its tenancy: a write that
     raises the page's amax requantizes the page's existing int8 content
@@ -494,17 +494,17 @@ def _quantized_page_write(pool, scale, blk, slot, wslot, xT):
     before the gather/rescatter, so per-step requantization traffic is
     O(pages written) — one page per row on decode — not O(T) full-page
     copies. Returns (new_pool, new_scale)."""
-    Hkv, NB, BS, hd = pool.shape
+    NB, _, _, BS, _ = pool.shape
     B, T = blk.shape
     # a T-position chunk at an arbitrary slot offset straddles at most
     # this many pages — the window the touched-page dedup scatters into
     # (wslot values are < P by construction: (off+T-1)//BS - off//BS)
     P = (T + BS - 2) // BS + 1
-    xf = xT.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1) * (1.0 / 127.0)  # [Hkv, B, T]
-    # scatter-max per (head, page): redirected positions only ever grow
-    # the null block's scale (garbage page by design)
-    cand = jnp.zeros((Hkv, NB), jnp.float32).at[:, blk].max(amax)
+    xf = x.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(xf), axis=-1) * (1.0 / 127.0)  # [B, T, 2, Hkv]
+    # scatter-max per (page, half, head): redirected positions only ever
+    # grow the null block's scale (garbage page by design)
+    cand = jnp.zeros(scale.shape, jnp.float32).at[blk].max(amax)
     new_scale = jnp.maximum(scale, cand)
     safe = jnp.where(new_scale > 0.0, new_scale, 1.0)
     # dedup touched pages: window slot w holds ONE page id (rows own
@@ -522,20 +522,20 @@ def _quantized_page_write(pool, scale, blk, slot, wslot, xT):
     # 1.0) is exact), < 1 where grown, 0 for a freshly reset page
     # (scale 0 → stale bytes zeroed before the new tenant's first read)
     def _requant(p):
-        ratio = scale / safe  # [Hkv, NB]
-        pages = p[:, pg_blk].astype(jnp.float32)  # [Hkv, B, P, BS, hd]
+        ratio = scale / safe  # [NB, 2, Hkv]
+        pages = p[pg_blk].astype(jnp.float32)  # [B, P, 2, Hkv, BS, hd]
         rq = jnp.clip(
-            jnp.rint(pages * ratio[:, pg_blk][..., None, None]), -127, 127
+            jnp.rint(pages * ratio[pg_blk][..., None, None]), -127, 127
         ).astype(jnp.int8)
-        return p.at[:, pg_blk].set(rq)
+        return p.at[pg_blk].set(rq)
 
     out = lax.cond(jnp.any(cand > scale), _requant, lambda p: p, pool)
     # quantize the chunk's values under the new page scales and scatter
     # into their slots (distinct (page, slot) pairs except the null block)
     q = jnp.clip(
-        jnp.rint(xf / safe[:, blk][..., None]), -127, 127
+        jnp.rint(xf / safe[blk][..., None]), -127, 127
     ).astype(jnp.int8)
-    return out.at[:, blk, slot].set(q), new_scale
+    return out.at[blk, :, :, slot].set(q), new_scale
 
 
 def matmul(x, w):
@@ -1304,7 +1304,9 @@ def transformer_block(
 
     kv_hook(k, v) -> (k_eff, v_eff), when given, intercepts the freshly
     projected K/V — the cached decode path uses it to write the chunk into
-    the KV cache and attend over the cache instead. No hook = plain causal
+    the KV cache and attend over the cache instead (over the paged pool
+    with the ragged reader, k_eff is the pool's ``kv`` leaf, K beside V,
+    and v_eff None: forward's kv_hook). No hook = plain causal
     self-attention over the chunk (training/scoring/pipeline-stage path).
 
     attn_fn(q, k, v, mask, cfg, positions=positions) -> [B,T,H*hd] replaces
@@ -1590,19 +1592,20 @@ def forward(
     the chunk — the training/scoring path.
 
     With ``block_tables`` [B, MB], the cache is a PAGED pool
-    {"k","v"}: [L, Hkv, num_blocks, block_size, hd] (init_paged_pool) and
-    row b's logical cache position p lives at pool slot
-    (block_tables[b, p // block_size], p % block_size) of every kv head.
+    {"kv": [L, num_blocks, 2, Hkv, block_size, hd]} (init_paged_pool: K
+    beside V, page-major) and row b's logical cache position p lives at
+    pool slot (block_tables[b, p // block_size], p % block_size) of both
+    halves of every kv head.
     Writes scatter the chunk into the mapped blocks. Attention depends on
     the attn_fn: a RAGGED attn_fn (ops/ragged.make_ragged_attn_fn, marked
     by its ``ragged`` attribute) reads the pool directly — the kernel
     gathers a tile of blocks per grid step, so neither the [B, S, Hkv, hd]
     view nor the [T, S] scores ever materialize — and over a float pool
-    it WRITES it too: the kv_hook stores the chunk with the page-write
-    kernel and hands the stacked pool and the layer index through, so the
-    pool is the layer loop's carry, in place, and no layer slices it or
-    writes a slice back (the int8 pool's requantising write is XLA's and
-    keeps its per-layer slices). The dense path (attn_fn None)
+    it WRITES it too: the kv_hook stores the chunk's K and V with ONE
+    page-write call and hands the stacked leaf and the layer index
+    through, so the pool is the layer loop's carry, in place, and no layer
+    slices it or writes a slice back (the int8 pool's requantising write
+    is XLA's and keeps its per-layer slices). The dense path (attn_fn None)
     gathers the MB mapped blocks per row into that view; either way cache
     traffic per step scales with the table width the caller passes (live
     blocks, bucketed) instead of the pool capacity. The position→slot map
@@ -1628,11 +1631,12 @@ def forward(
     decode overwrites its own positions before reading them.
 
     **Quantized pool** (EngineConfig.cache_dtype="int8"): the pool dict
-    additionally carries ``k_scale``/``v_scale`` [L, Hkv, NB] f32
+    additionally carries ``kv_scale`` [L, NB, 2, Hkv] f32
     per-page-per-head scales (init_paged_pool). The paged scatter becomes
-    quantize-on-write (_quantized_page_write: amax per (page, head) →
-    int8 + running-max scale, requantizing a page whose scale grew), the
-    ragged attn_fn receives (pool_slice, scale_slice) tuples and
+    quantize-on-write (_quantized_page_write: amax per (page, K or V,
+    head) → int8 + running-max scale, requantizing a page whose scale
+    grew; K and V in one pass), the
+    ragged attn_fn receives the (pool_slice, scale_slice) pair and
     dequantizes INSIDE its page loop, and the dense/sp fallback
     dequantizes the gathered view — K/V never materialize wider than one
     block (kernel) or the existing gathered view (fallback) anywhere.
@@ -1663,9 +1667,10 @@ def forward(
         )
     if valid_len is not None:
         valid_len = jnp.asarray(valid_len, jnp.int32)
-    # the pool leaf whose shape gives the block size: the latent rows of a
-    # model with latent attention, else K
-    pool_key = next(iter(pool_layout(cfg)))
+    # the leaf that holds the keys: a paged pool's latent rows or its K
+    # beside V (init_paged_pool), the rectangular cache's K (init_cache)
+    pool_key = ("latent" if cfg.has_mla
+                else "kv" if block_tables is not None else "k")
     if cfg.has_mla and cache is not None and block_tables is None:
         raise ValueError(
             f"{cfg.name!r} has latent attention: its cache is the paged "
@@ -1681,7 +1686,7 @@ def forward(
 
     if block_tables is not None:
         bt = jnp.asarray(block_tables, jnp.int32)
-        BS = cache[pool_key].shape[3]  # pool block size
+        BS = cache[pool_key].shape[-2]  # pool block size
         S = bt.shape[1] * BS  # gathered view width = logical positions
 
         def row_limit(x):  # [] or [B] -> [B, 1], beside positions [B, T]
@@ -1699,7 +1704,7 @@ def forward(
     # astype-truncate K/V into garbage bit patterns — and only the PAGED
     # pool implements quantize-on-write, so an int8 rectangular cache is
     # rejected outright (static trace-time check, not a traced branch)
-    quantized = bt is not None and cache is not None and "k_scale" in cache
+    quantized = bt is not None and cache is not None and "kv_scale" in cache
     if (
         cache is not None
         and cache[pool_key].dtype == jnp.int8
@@ -1707,7 +1712,7 @@ def forward(
     ):
         raise ValueError(
             "int8 KV cache requires the paged pool with its "
-            "k_scale/v_scale scale arrays (init_paged_pool dtype=int8 "
+            "kv_scale scale array (init_paged_pool dtype=int8 "
             "+ block_tables); the rectangular cache has no quantized path"
         )
     # pool-direct attention: the ragged kernel gathers blocks itself, so
@@ -1798,8 +1803,8 @@ def forward(
             [B, T, W] and return what attention reads — the stacked pool
             (ragged reader: the page-write kernel stores in place) or the
             gathered [B, S, W] view (dense: XLA's scatter on the layer's
-            slice). The pool is [L, 1, NB, BS, W]: the unit axis stands
-            where K/V pools have their heads."""
+            slice). The pool is [L, NB, 1, BS, W]: the unit axis stands
+            where a K/V page has its two halves of heads."""
             nonlocal lcache
             if page_write is not None:
                 lcache = dict(lcache, latent=page_write(
@@ -1812,10 +1817,10 @@ def forward(
                 blk = jnp.where(positions >= wfloor, blk, 0)
             if wceil is not None:
                 blk = jnp.where(positions < wceil, blk, 0)
-            pool_l = lcache["latent"][layer_idx, 0].at[blk, positions % BS].set(
+            pool_l = lcache["latent"][layer_idx].at[blk, 0, positions % BS].set(
                 latent.astype(lcache["latent"].dtype))
             lcache = dict(
-                lcache, latent=lcache["latent"].at[layer_idx, 0].set(pool_l))
+                lcache, latent=lcache["latent"].at[layer_idx].set(pool_l))
             return pool_l[bt].reshape(B, S, -1).astype(latent.dtype)
 
         def ssm_hook(h):
@@ -1839,34 +1844,34 @@ def forward(
             """Write this chunk's K/V at [offset, offset+T) of every row and
             return what attention reads. Over the paged pool (block tables
             given) three variants remain, selected by ``attn_fn.ragged``
-            and ``"k_scale" in cache``:
+            and ``"kv_scale" in cache``; each stores K beside V in one
+            pass over the ``kv`` leaf:
 
             - ragged reader, float pool: the page-write kernel stores the
-              chunk into the STACKED pool, which is returned whole (the
-              kernel reads layer ``layer_idx`` of it in place);
-            - int8 pool (``k_scale`` present), either reader: XLA's
+              chunk into the STACKED leaf, which is returned whole, with
+              no V beside it (the kernel reads layer ``layer_idx`` of it
+              in place, a page's K and V in one copy);
+            - int8 pool (``kv_scale`` present), either reader: XLA's
               requantising page write on the layer's slice; the ragged
               reader gets (pages, scales), the dense one the dequantised
               gathered view;
             - dense / sp reader, float pool: XLA's scatter on the layer's
-              slice, then the gathered [B, S, Hkv, hd] view.
+              slice, then the gathered [B, S, Hkv, hd] views.
 
             Without tables (core.init_cache's rectangular cache: the model
             drafter) the chunk is a dynamic-update-
             slice into the row, and attention reads that row."""
             nonlocal lcache
 
-            if page_write is not None:
-                with jax.named_scope("kv.write"):
-                    lcache = dict(lcache, **{
-                        name: page_write(
-                            lcache[name], new, bt, off_b, layer_idx,
-                            paged_write_floor, paged_write_ceil,
-                        )
-                        for name, new in (("k", k), ("v", v))
-                    })
-                return lcache["k"], lcache["v"]
             if bt is not None:
+                kv = jnp.stack([k, v], axis=2)  # [B, T, 2, Hkv, hd], as a page lies
+                if page_write is not None:
+                    with jax.named_scope("kv.write"):
+                        lcache = dict(lcache, kv=page_write(
+                            lcache["kv"], kv, bt, off_b, layer_idx,
+                            paged_write_floor, paged_write_ceil,
+                        ))
+                    return lcache["kv"], None
                 # paged: scatter each position into its mapped (block, slot)
                 # of every kv head. Rows own disjoint blocks (the engine's
                 # allocator invariant), so the scatter indices never
@@ -1885,66 +1890,45 @@ def forward(
                     # (an out-of-table lookup above may have produced a
                     # fill value; this rewrites it to the real null block)
                     blk = jnp.where(positions < wceil, blk, 0)
-                # pool layer [Hkv, NB, BS, hd]: the leading slice before
-                # the (blk, slot) index arrays keeps the head dim in
-                # place, so the update operand is k as [Hkv, B, T, hd]
-                kT = jnp.transpose(k, (2, 0, 1, 3))
-                vT = jnp.transpose(v, (2, 0, 1, 3))
+
+                def views(pages):
+                    # [B, MB, 2, Hkv, BS, hd] gathered pages -> K's and V's
+                    # [B, S, Hkv, hd]
+                    g = jnp.transpose(pages, (2, 0, 1, 4, 3, 5)).reshape(
+                        2, B, S, Hkv, hd)
+                    return g[0], g[1]
+
                 if quantized:
                     # chunk-position → page-window slot for the touched-
                     # page dedup (positions[:, 0] == off_b)
                     wslot = positions // BS - (off_b // BS)[:, None]
-                    ck, ks = _quantized_page_write(
-                        lcache["k"][layer_idx],
-                        lcache["k_scale"][layer_idx], blk, slot, wslot, kT,
-                    )
-                    cv, vs = _quantized_page_write(
-                        lcache["v"][layer_idx],
-                        lcache["v_scale"][layer_idx], blk, slot, wslot, vT,
+                    ckv, sc = _quantized_page_write(
+                        lcache["kv"][layer_idx],
+                        lcache["kv_scale"][layer_idx], blk, slot, wslot, kv,
                     )
                     lcache = dict(
                         lcache,
-                        k=lcache["k"].at[layer_idx].set(ck),
-                        v=lcache["v"].at[layer_idx].set(cv),
-                        k_scale=lcache["k_scale"].at[layer_idx].set(ks),
-                        v_scale=lcache["v_scale"].at[layer_idx].set(vs),
+                        kv=lcache["kv"].at[layer_idx].set(ckv),
+                        kv_scale=lcache["kv_scale"].at[layer_idx].set(sc),
                     )
                     if ragged:
                         # (pool slice, scale slice): the kernel dequants
                         # inside its page loop — int8 is all that crosses
                         # HBM, one block's dequant lives in VMEM
-                        return (ck, ks), (cv, vs)
+                        return (ckv, sc), None
                     # dense/sp fallback: dequantize the gathered view —
                     # the same [B, S, Hkv, hd] width the bf16 path builds
-                    k_eff = jnp.transpose(
-                        ck[:, bt].astype(jnp.float32)
-                        * ks[:, bt][..., None, None],
-                        (1, 2, 3, 0, 4),
-                    ).reshape(B, S, Hkv, hd).astype(k.dtype)
-                    v_eff = jnp.transpose(
-                        cv[:, bt].astype(jnp.float32)
-                        * vs[:, bt][..., None, None],
-                        (1, 2, 3, 0, 4),
-                    ).reshape(B, S, Hkv, hd).astype(v.dtype)
-                    return k_eff, v_eff
-                ck = lcache["k"][layer_idx].at[:, blk, slot].set(
-                    kT.astype(lcache["k"].dtype)
+                    return views((
+                        ckv[bt].astype(jnp.float32) * sc[bt][..., None, None]
+                    ).astype(k.dtype))
+                # the layer's slice [NB, 2, Hkv, BS, hd]: the (blk, slot)
+                # index arrays around the two sliced axes put [B, T] in
+                # front, so the update operand is kv as it stands
+                ckv = lcache["kv"][layer_idx].at[blk, :, :, slot].set(
+                    kv.astype(lcache["kv"].dtype)
                 )
-                cv = lcache["v"][layer_idx].at[:, blk, slot].set(
-                    vT.astype(lcache["v"].dtype)
-                )
-                lcache = dict(
-                    lcache,
-                    k=lcache["k"].at[layer_idx].set(ck),
-                    v=lcache["v"].at[layer_idx].set(cv),
-                )
-                k_eff = jnp.transpose(ck[:, bt], (1, 2, 3, 0, 4)).reshape(
-                    B, S, Hkv, hd
-                )
-                v_eff = jnp.transpose(cv[:, bt], (1, 2, 3, 0, 4)).reshape(
-                    B, S, Hkv, hd
-                )
-                return k_eff, v_eff
+                lcache = dict(lcache, kv=lcache["kv"].at[layer_idx].set(ckv))
+                return views(ckv[bt])
 
             def write(cache_row, new_row, start):
                 return lax.dynamic_update_slice(
@@ -2091,12 +2075,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int | None = None, dtype=j
 
 
 def pool_layout(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
-    """What a token stores in the paged pool, a layer: {leaf: (heads,
-    width)}. K and V of every KV head for plain attention; under latent
-    attention ONE row of cfg.latent_width numbers ([c_kv | k_rope], keys
-    as it is and values by its first mla_kv_rank columns), its ``heads`` a
-    unit axis that only keeps the block axis where every pool leaf has it.
-    The engine's byte arithmetic, export format and kernels' shapes follow
+    """What a token stores in the paged pool, a layer: {part: (heads,
+    width)}. K and V of every KV head for plain attention (the two halves
+    of the stored ``kv`` leaf's pages, and two tensors of the block export
+    format); under latent attention ONE row of cfg.latent_width numbers
+    ([c_kv | k_rope], keys as it is and values by its first mla_kv_rank
+    columns), its ``heads`` a unit axis. The engine's byte arithmetic,
+    export format and the stored leaves' shapes (init_paged_pool) follow
     from this, never from (n_kv_heads, head_dim) directly."""
     if cfg.has_mla:
         return {"latent": (1, cfg.latent_width)}
@@ -2115,30 +2100,33 @@ def init_paged_pool(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     lane_aligned: bool = False,
 ):
-    """Preallocate the paged KV block pool:
-    {"k","v"}: [L, Hkv, num_blocks, block_size, hd] — or, under latent
-    attention, {"latent": [L, 1, num_blocks, block_size, W]} (pool_layout:
-    one row a token, no per-head K, no V). Block 0 is the
-    engine's reserved null block (padding target for table entries past a
-    row's live extent); rows map logical positions onto blocks via the
-    block tables forward() takes.
+    """Preallocate the paged KV block pool, ONE leaf, page-major:
+    {"kv": [L, num_blocks, 2, Hkv, block_size, hd]} — K beside V and every
+    KV head of a block adjacent, so a page of a layer is one contiguous
+    run of 2 x Hkv x block_size x hd numbers — or, under latent attention,
+    {"latent": [L, num_blocks, 1, block_size, W]} (pool_layout: one row a
+    token, no per-head K, no V; the unit axis stands where a K/V page has
+    its two halves of heads). The block axis is 1 on every leaf. Block 0
+    is the engine's reserved null block (padding target for table entries
+    past a row's live extent); rows map logical positions onto blocks via
+    the block tables forward() takes.
 
-    Head-major layout: the ragged kernel (ops/ragged.py) gathers one
-    (kv_head, block) tile per grid step, and Mosaic needs the trailing
-    two dims of that tile to be (block_size, hd) — a head axis blocked
-    at 1 in trailing position fails to lower, the same constraint that
-    shaped ops/flash.py's head-major transpose. On the ragged path a
+    The trailing dims stay ``(block_size, hd)``: the ragged kernel
+    (ops/ragged.py) fetches K and V of a tile of heads of one block as ONE
+    operand a grid step, and Mosaic needs the trailing two dims of that
+    block to be (block_size, hd) — a head axis blocked at 1 in trailing
+    position fails to lower, the same constraint that shaped
+    ops/flash.py's head-major transpose. On the ragged path a
     float pool is written and read by Mosaic calls alone, in place, as
     the layer loop's carry (forward's kv_hook): inside that loop it must
     never be sliced, scattered into or selected by XLA, or the compiler
-    gives the carry XLA's layout (head size minor then KV heads for a
-    scatter; the block axis minor-most by default when hd pads to 128
-    lanes) and re-lays it for the kernel in every layer (ops/ragged.py,
-    "Layouts").
+    gives the carry XLA's layout and re-lays it for the kernel in every
+    layer (ops/ragged.py, "Layouts").
 
     With ``dtype=int8`` (EngineConfig.cache_dtype="int8") the pool pages
-    store quantized K/V and the dict grows ``k_scale``/``v_scale``
-    [L, Hkv, num_blocks] f32 per-page-per-head symmetric scales —
+    store quantized K/V and the dict grows ``kv_scale``
+    [L, num_blocks, 2, Hkv] f32 per-page-per-head symmetric scales, K's
+    beside V's as the pages lie —
     initialized to ZERO (= "page holds nothing"; forward's running-max
     quantize-on-write takes it from there, and the scheduler re-zeroes a
     block's entry when the allocator recycles it). Pool HBM halves vs
@@ -2150,25 +2138,25 @@ def init_paged_pool(
     what they store and cut what they return). The device's default layout
     for the stored array is then the kernels' own row-major one, so
     entering and leaving a program re-lays nothing: at head size 96 the
-    default puts the BLOCK axis minor-most ("it pads nothing"), every
+    default puts another axis minor-most ("it pads nothing"), every
     prefill call and decode window re-laid the whole pool in and out and
     held the padded copy as a temporary anyway. Only the in-place path
     (the ragged kernels over a float pool, on a TPU) asks for it; nothing
     but ops/ragged.py and the scheduler's block export / import ever
     looks at the pad."""
-    layout = pool_layout(cfg)
     if cfg.has_mla and jnp.dtype(dtype) == jnp.int8:
         raise ValueError(
             f"{cfg.name!r}: the latent pool has no int8 form (the "
             "requantising page write is per K/V head)")
-    pool = {}
-    for name, (heads, width) in layout.items():
-        if lane_aligned:
-            width = -(-width // 128) * 128
-        pool[name] = jnp.zeros(
-            (cfg.n_layers, heads, num_blocks, block_size, width), dtype)
+    heads, width = next(iter(pool_layout(cfg).values()))  # K's are V's
+    if lane_aligned:
+        width = -(-width // 128) * 128
+    # what stands in front of a page's (block_size, width): K and V of
+    # every head, or a latent row's unit axis
+    parts = (1,) if cfg.has_mla else (2, heads)
+    pool = {"latent" if cfg.has_mla else "kv": jnp.zeros(
+        (cfg.n_layers, num_blocks, *parts, block_size, width), dtype)}
     if jnp.dtype(dtype) == jnp.int8:
-        sshape = (cfg.n_layers, cfg.n_kv_heads, num_blocks)
-        pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
-        pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
+        pool["kv_scale"] = jnp.zeros(
+            (cfg.n_layers, num_blocks, *parts), jnp.float32)
     return pool

@@ -191,26 +191,32 @@ def paged_cache_spec(
     mesh: Mesh | None = None,
     seq_sharded: bool = False,
 ) -> P:
-    """Paged KV pool [L, Hkv, num_blocks, block_size, hd]: kv heads on
-    `model` — attention over the pool (ragged kernel) or its gathered
-    view (dense) stays collective-free per shard. The BLOCK dim is never
+    """Paged KV pool [L, num_blocks, 2, Hkv, block_size, hd] (the ``kv``
+    leaf of core.init_paged_pool: K beside V, page-major): kv heads, axis
+    3, on `model` — attention over the pool (ragged kernel) or its
+    gathered view (dense) stays collective-free per shard. The BLOCK dim
+    is never
     sharded: any row gathers arbitrary pool blocks, so splitting it would
     turn every gather into a cross-device reshard. With ``seq_sharded``
-    (the engine sets it iff attention='sp') the SLOT dim shards over
-    `seq`: per-device pool memory is 1/seq — the long-context capacity
+    (the engine sets it iff attention='sp') the SLOT dim, axis 4, shards
+    over `seq`: per-device pool memory is 1/seq — the long-context capacity
     scaling of parallel/sp_serving — and the block gather stays local
     (it indexes only the block dim); XLA reshards the gathered view into
     the sp shard_map's contiguous [B, S/seq] layout per step, which is
     the collective sp attention pays anyway. MQA meshes (kv_replicated)
-    replicate the kv-head dim to match wk/wv."""
+    replicate the kv-head dim to match wk/wv. A latent pool
+    ([L, num_blocks, 1, block_size, W]) is replicated: the engine refuses
+    a mesh for such a model."""
+    if cfg is not None and cfg.has_mla:
+        return P()
     seq = "seq" if seq_sharded and mesh is not None and mesh.shape.get("seq", 1) > 1 else None
     if cfg is not None and mesh is not None and kv_replicated(cfg, mesh):
-        return P(None, None, None, seq, None)
-    return P(None, "model", None, seq, None)
+        return P(None, None, None, None, seq, None)
+    return P(None, None, None, "model", seq, None)
 
 
 def paged_scale_spec(cfg: ModelConfig | None = None, mesh: Mesh | None = None) -> P:
-    """Int8-pool quantization scales [L, Hkv, num_blocks] f32: the
+    """Int8-pool quantization scales [L, num_blocks, 2, Hkv] f32: the
     kv-head dim shards exactly like the pool's (MQA replication
     included), the block dim never shards (same any-row-any-block
     argument as paged_cache_spec), and there is no slot dim — under
@@ -218,8 +224,8 @@ def paged_scale_spec(cfg: ModelConfig | None = None, mesh: Mesh | None = None) -
     dequant broadcasts each page's scale across its (seq-sharded) slots
     locally."""
     if cfg is not None and mesh is not None and kv_replicated(cfg, mesh):
-        return P(None, None, None)
-    return P(None, "model", None)
+        return P(None, None, None, None)
+    return P(None, None, None, "model")
 
 
 def flat_partition_specs(

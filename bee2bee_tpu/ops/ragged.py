@@ -8,27 +8,36 @@ attention still paid the dense rectangle. This kernel (after "Ragged
 Paged Attention" — PAPERS.md, arxiv 2604.15464) reads K/V straight from
 the pool:
 
-- **Pool-direct gather, a tile a step**: the pool is head-major
-  ``[Hkv, NB, BS, hd]`` (one layer of core.init_paged_pool's
-  ``[L, Hkv, NB, BS, hd]``: a slice, or the stacked pool with the layer as
-  a prefetched scalar — "Layouts" below). One grid step carries ``Th`` KV heads x
-  ``Tp`` consecutive table entries of one row: the pool is passed as
-  ``Tp`` K and ``Tp`` V operands, operand p blocked ``(Th, 1, BS, hd)``
-  with a scalar-prefetched pool block id in its index_map —
-  ``(h, pages[step*Tp + p], 0, 0)`` — so each is one strided copy of
-  Th heads of one pool block, the pipeline double-buffers all of them
-  and fetches the next step's tile while this one computes. No gathered
-  view, no [T, S] score materialization.
+- **Pool-direct gather, a tile a step, a page ONE copy** (PR 44): the
+  pool is page-major with K beside V, ``[NB, 2, Hkv, BS, hd]`` (one layer
+  of core.init_paged_pool's ``kv`` leaf ``[L, NB, 2, Hkv, BS, hd]``: a
+  slice, or the stacked leaf with the layer as a prefetched scalar —
+  "Layouts" below), so everything a block holds of a layer is adjacent.
+  One grid step carries ``Th`` KV heads x ``Tp`` consecutive table entries
+  of one row: the pool is passed as ``Tp`` operands, operand p blocked
+  ``(None, 2, Th, BS, hd)`` with a scalar-prefetched pool block id in its
+  index_map — ``(pages[step*Tp + p], 0, h, 0, 0)`` — so each is one copy
+  of K AND V of Th heads of one pool block (with ``Th == Hkv``, every
+  decode shape the engine issues, one contiguous run of
+  2 x Hkv x BS x hd numbers); the body takes ``k = r[0]``, ``v = r[1]``.
+  The pipeline double-buffers all of them and fetches the next step's
+  tile while this one computes. No gathered view, no [T, S] score
+  materialization. What a step pays is copies ISSUED before bytes moved
+  (~0.1 us a copy; PERF.md Findings, PR 44): with K and V two head-major
+  arrays a page of 4 GQA heads was 2 operands of 4 strided 4 KB pieces,
+  and the read ran at 12 % of its roofline. A latent pool
+  (``[NB, 1, BS, W]``, MLA) is the same operand with a unit axis where the
+  two halves of heads stand, and no V.
   Every tensor operand's trailing block dims are ``(rows, hd)`` —
-  Mosaic-tileable (the [NB, BS, Hkv, hd] layout would put a 1-blocked
-  head axis second-to-last and fail to lower, and a bool-mask operand
-  blocked per 16-lane page would violate the same rule — the constraint
-  that shaped ops/flash.py's head-major layout). The copies stay
-  BlockSpec copies, not hand-started DMAs from an ``ANY`` operand: one
-  kernel then serves every pool (Mosaic refuses to slice an HBM ref whose
-  head size is off the 128-lane tiling — phi-3's 96, gpt2's 64 as the
-  dense readers' and the int8 pool's slices keep them — and takes those
-  as block shapes).
+  Mosaic-tileable (a layout with the head axis between BS and hd would
+  put a 1-blocked head axis second-to-last and fail to lower, and a
+  bool-mask operand blocked per 16-lane page would violate the same rule
+  — the constraint that shaped ops/flash.py's head-major layout). The
+  copies stay BlockSpec copies, not hand-started DMAs from an ``ANY``
+  operand: one kernel then serves every pool (Mosaic refuses to slice an
+  HBM ref whose head size is off the 128-lane tiling — phi-3's 96,
+  gpt2's 64 as the dense readers' and the int8 pool's slices keep them —
+  and takes those as block shapes).
 - **The grid walks a compacted work list** (PR 31), not the table: the
   grid is ``(Hkv/Th, B x q blocks x table width/Tp)`` — head groups,
   then ONE sequential axis of the table's static length (so the compile
@@ -50,8 +59,9 @@ the pool:
 - **The tile follows from the shapes** (_tile_plan: heads a shard holds,
   group size, chunk length, head size, page size, table width, dtypes,
   against a fixed VMEM budget), never from an argument, a flag or a
-  model's name: MHA-32 x 96 takes all heads and 8 pages, a 2-head GQA
-  shard 16 pages, a 2048-row prefill chunk 4 heads and 256 q rows.
+  model's name: MHA-32 x 96 takes all heads and 4 pages (1 MB of K and
+  V), a 4-head GQA group 32 pages, a 2048-row prefill chunk 4 heads, 256
+  q rows and 32 pages.
 - **One kernel, every chunk shape**: queries fold to ``[B, Hkv, G*T,
   hd]`` rows (GQA group g major, chunk position t minor), so [B, 1]
   decode, [B, K+1] spec verify and ragged prefill chunks are all just
@@ -87,53 +97,59 @@ the pool:
   holds token-for-token; the Th heads of a step go through one batched
   dot, their chains interleaved by the compiler.
 
-- **Int8 pool dequant in the tile**: with ``k_scale``/``v_scale``
-  [Hkv, NB] f32 (the per-layer slice of core.init_paged_pool's
-  per-page-per-head quantization scales), the pool blocks arrive int8
-  and each grid step dequantizes ITS tile in VMEM — every page with its
-  own scale, into a [Th, Tp*BS, hd] scratch the two dots then read — so
+- **Int8 pool dequant in the tile**: with ``scale`` [NB, 2, Hkv] f32
+  (the per-layer slice of core.init_paged_pool's per-page-per-head
+  quantization scales, K's beside V's as the pages lie), the pool blocks
+  arrive int8 and each grid step dequantizes ITS tile in VMEM — every
+  page's K and V each with its own scale, into two [Th, Tp*BS, hd]
+  scratches the two dots then read — so
   the precision change rides the existing gather: HBM cache traffic
   halves and nothing wider than one tile ever materializes (a tile of
   pages is also what makes int8's (32, 128) minimum tile meet a 16-slot
   page). The scales ride the SAME scalar-prefetch channel as the block
-  tables — pre-gathered through the tables to ``[Hkv, B, MB]`` outside
-  the kernel, so the kernel reads Th x Tp f32 a step at ``[h, b, j]``
-  from SMEM (a (1, 1)-blocked VMEM operand would violate the
-  trailing-dims tiling rule above) and the SMEM footprint is table-sized
-  — 2 * Hkv/shard * B * MB * 4 bytes, bounded by the pow2-bucketed LIVE
-  width like every per-step operand, never by pool capacity. The f32
-  m/l/acc scratch already isolates accumulation from storage precision,
-  so the quantized path changes no softmax math.
+  tables — pre-gathered through the tables to ``[2, Hkv, B, MB]`` outside
+  the kernel, so the kernel reads 2 x Th x Tp f32 a step at
+  ``[half, h, b, j]`` from SMEM (a (1, 1)-blocked VMEM operand would
+  violate the trailing-dims tiling rule above) and the SMEM footprint is
+  table-sized — 2 * Hkv/shard * B * MB * 4 bytes, bounded by the
+  pow2-bucketed LIVE width like every per-step operand, never by pool
+  capacity. The f32 m/l/acc scratch already isolates accumulation from
+  storage precision, so the quantized path changes no softmax math.
 
 - **Layouts: the float pool is written and read IN PLACE, by Mosaic only**
-  (PR 29). On the serving path the stacked pool ``[L, Hkv, NB, BS, hd]`` is
-  the layer loop's carry, and core.forward touches it with two calls of
-  this module alone: ``paged_kv_write`` (aliased pool -> pool, one page of
-  all the shard's KV heads a grid step) and ``ragged_paged_attention`` with
-  a ``layer`` (the page index maps lead with the prefetched layer). Both
-  address it row-major, ``(BS, hd)`` minor, so the carry stays put and a
-  decode step moves B pages a layer instead of the pool. The pool must
-  never meet, inside the layer loop, the two other layouts the TPU compiler
-  would pick for it: XLA's scatter wants head size minor, then the KV-head
-  axis (``{3,0,2,1}``), and the device's default for the stored array puts
-  the BLOCK axis minor-most when the head size pads to the 128 lanes
-  (``{2,4,3,1,0}`` at phi-3's 96). Whenever XLA writes, slices or selects
-  what Mosaic reads, layout assignment gives the carry XLA's layout and
-  re-lays a layer's slice (or, with an XLA write straight into the stacked
-  pool, the WHOLE pool) for the kernel in every layer: eight passes over
-  38-50 MB a layer, 40-52 % of phi-3's device time before this
-  (tests/test_tpu_compile.py keeps that shut). So that the STORED array's
-  default layout is row-major too, and entering and leaving a program
-  re-lays nothing either, the engine allocates this path's pool with the
-  head at the lane width (core.init_paged_pool ``lane_aligned``): both
-  calls take a pool wider than the head, pad what they store and the
+  (PR 29). On the serving path the stacked leaf ``[L, NB, 2, Hkv, BS, hd]``
+  is the layer loop's carry, and core.forward touches it with two calls of
+  this module alone: ``paged_kv_write`` (aliased pool -> pool, ONE call a
+  layer: a whole page — K beside V of all the shard's KV heads — a grid
+  step) and ``ragged_paged_attention`` with a ``layer`` (the page index
+  maps lead with the prefetched layer). Both address it row-major,
+  ``(BS, hd)`` minor, so the carry stays put and a decode step moves B
+  pages a layer instead of the pool. The pool must never meet, inside the
+  layer loop, the other layouts the TPU compiler would pick for it: XLA's
+  scatter wants head size minor, then the axes it scatters along, and the
+  device's default for the stored array puts another axis minor-most when
+  the head size pads to the 128 lanes (at phi-3's 96). Whenever XLA
+  writes, slices, transposes or selects what Mosaic reads, layout
+  assignment gives the carry XLA's layout and re-lays a layer's slice (or,
+  with an XLA write straight into the stacked pool, the WHOLE pool) for
+  the kernel in every layer: eight passes over 38-50 MB a layer, 40-52 %
+  of phi-3's device time before PR 29 (tests/test_tpu_compile.py keeps
+  that shut, at phi-3's and smallthinker's shapes). So that the STORED
+  array's default layout is row-major too, and entering and leaving a
+  program re-lays nothing either, the engine allocates this path's pool
+  with the head at the lane width (core.init_paged_pool ``lane_aligned``):
+  both calls take a pool wider than the head, pad what they store and the
   queries with zeros, and cut the output back. (Pinning the layout of the
   96-wide array with ``jax.experimental.layout.Format`` compiles to the
   same program, but an executable with a pinned parameter layout comes
   back from the persistent compilation cache expecting the default one:
   PERF.md, Findings PR 29.) The int8 pool's requantising write is XLA's
-  and stays on the per-layer slices (4-D operands, no ``layer``), as do
-  the dense readers, whose reads are XLA's too.
+  and stays on the per-layer slices (5-D operands, no ``layer``), as do
+  the dense readers, whose reads are XLA's too. A caller that still holds
+  K and V as two head-major pools (make_ragged_attn_fn's attn with a
+  ``v``: checks and tests) pays a stack and a transpose to this layout
+  inside its own jit and reads through the same kernel; the served hook
+  hands the leaf through and never meets that copy.
 
 On devices that are not TPUs the kernel runs in pallas interpret mode
 (ops/flash.interpret_off_tpu), so the CPU test suite exercises the exact
@@ -155,16 +171,20 @@ from .flash import NEG_INF, _LANES, interpret_off_tpu, validate_flash_mesh
 
 
 # what the tile choice plans VMEM for (the q and o blocks, the f32
-# softmax state, the pipeline's two buffers a K/V page operand, one
+# softmax state, the pipeline's two buffers a page operand, one
 # head's score temporaries) and the limit handed to Mosaic: the plan is
 # an estimate, the limit leaves room for what the compiler adds (v5e's
-# scoped default is 16 MiB of 128)
-_VMEM_BUDGET = 12 * 2**20
+# scoped default is 16 MiB of 128). The constants are the chip's (my chip
+# runs, PR 44: PERF.md Findings): a page is ONE copy, and what a step pays
+# is copies issued before bytes, so a step takes as many pages as 1 MB,
+# 512 keys and the score temporaries allow — 32 pages of 4 GQA heads
+# (32 KB each), 4 of 32 MHA heads (256 KB each)
+_VMEM_BUDGET = 14 * 2**20
 _VMEM_LIMIT = 32 * 2**20
-_TILE_BYTES = 2 * 2**20  # K+V bytes one page tile aims to move
+_TILE_BYTES = 2**20  # K+V bytes one page tile aims to move
 _TILE_TOKENS = 512  # most key positions a tile may span
-_TILE_PAGES = 16  # most table entries a step: each is a K and a V operand
-_SCORE_ELEMS = 64 * 1024  # most [bq, Tp*BS] f32 score elements a head
+_TILE_PAGES = 32  # most table entries a step: each is one page operand
+_SCORE_ELEMS = 128 * 1024  # most [bq, Tp*BS] f32 score elements a head
 
 
 def _round_up(n: int, m: int) -> int:
@@ -178,13 +198,15 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
     ``quantized``). ``Th`` is the largest divisor of Hkv whose per-head
     VMEM (double-buffered q and o blocks, f32 m/l/acc, the f32 score
     temporaries of a 128-key tile) fits half the budget. ``Tp`` is the
-    largest power of two, at most 16, that keeps a tile's K+V at ~2 MB,
-    its span at 512 positions, one head's scores at 64 K elements and the
+    largest power of two, at most 32, that keeps a tile's K+V at 1 MB,
+    its span at 512 positions, one head's scores at 128 K elements and the
     whole plan inside the budget, and does not pass the table (the pow2
     ceiling of MB when MB is smaller; the wrapper pads a table whose
-    width ``Tp`` does not divide). So 32 MHA heads of 96 take the whole
-    head axis and 8 pages a step, a 2-head GQA shard 16 pages, a 2048-row
-    prefill chunk 4 heads."""
+    width ``Tp`` does not divide). A page operand is K beside V of the
+    step's ``Th`` heads (a latent row is reckoned as if it had a V: its
+    plan is what it was). So 32 MHA heads of 96 take the whole head axis
+    and 4 pages a step, a 4-head GQA group 32 pages, a 2048-row prefill
+    chunk 4 heads, 256 q rows and 32 pages."""
     nq = G * T
     bq = min(block_q, max(nq, 8))
     lanes = _round_up(hd, _LANES)
@@ -202,7 +224,7 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
         if Hkv % d == 0
         and (d == 1 or d * (state + scores(_LANES)) <= _VMEM_BUDGET // 2)
     )
-    # one table entry of one head as VMEM holds it: K and V, the
+    # one head's share of a page operand as VMEM holds it: K and V, the
     # pipeline's two buffers each (+ an int8 page's dequantized copy)
     moved = 4 * _round_up(BS, 32 // pool_item) * lanes * pool_item
     page = moved + (2 * BS * lanes * itemsize if quantized else 0)
@@ -355,17 +377,18 @@ def _ragged_kernel(
     lay_ref,  # [1] the stacked pool's layer (the K/V index maps read it;
     #           0 and unread for a 4-D slice)
     *refs,
-    # quantized=True prepends two more scalar-prefetch refs:
-    #   kscale_ref, vscale_ref  SMEM [Hkv, B, MBp] f32 scales, pre-gathered
-    #                           through the block tables per row
+    # quantized=True prepends one more scalar-prefetch ref:
+    #   scale_ref   SMEM [2, Hkv, B, MBp] f32: K's and V's page scales,
+    #               pre-gathered through the block tables per row
     # then the tensor operands either way:
-    #   q_ref       [1, Th, BQ, hd]  q rows: GQA group g major, chunk pos t minor
-    #   k_refs[p]   [Th, 1, BS, hd]  Tp operands: Th heads of the pool block
-    #   v_refs[p]   [Th, 1, BS, hd]  at entry p of the step's table tile
-    #   o_ref       [1, Th, BQ, hd]
-    #   m_ref       VMEM [Th, BQ, 128] f32 running max
-    #   l_ref       VMEM [Th, BQ, 128] f32 running sum
-    #   acc_ref     VMEM [Th, BQ, hd] f32
+    #   q_ref        [1, Th, BQ, hd]  q rows: GQA group g major, chunk pos t minor
+    #   page_refs[p] [2, Th, BS, hd]  Tp operands: K beside V of Th heads of the
+    #                pool block at entry p of the step's table tile ([Th, BS, W]
+    #                of a latent pool: Th is its unit axis)
+    #   o_ref        [1, Th, BQ, hd]
+    #   m_ref        VMEM [Th, BQ, 128] f32 running max
+    #   l_ref        VMEM [Th, BQ, 128] f32 running sum
+    #   acc_ref      VMEM [Th, BQ, hd] f32
     # and, quantized, the tile's dequantized keys and values:
     #   kdq_ref, vdq_ref  VMEM [Th, Tp*BS, hd] compute dtype
     sm_scale: float,
@@ -377,17 +400,16 @@ def _ragged_kernel(
     tile_heads: int,
     tile_pages: int,
     quantized: bool = False,
-    v_width: int = 0,  # latent rows (MLA): no V operands, a fetched tile is
-    #                    the keys as it is and the values by its first
+    v_width: int = 0,  # latent rows (MLA): a page holds no V, a fetched tile
+    #                    is the keys as it is and the values by its first
     #                    v_width columns; o_ref / acc_ref are v_width wide
 ):
     Th, Tp, BS = tile_heads, tile_pages, block_size
     if quantized:
-        kscale_ref, vscale_ref, *refs = refs
+        scale_ref, *refs = refs
     q_ref, *refs = refs
-    n_kv = Tp if v_width else 2 * Tp
-    k_refs, v_refs = refs[:Tp], refs[Tp:n_kv]
-    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[n_kv:]
+    page_refs = refs[:Tp]
+    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[Tp:]
     tile_tokens = Tp * BS
     h0 = pl.program_id(0) * Th
     step = pl.program_id(1)
@@ -408,30 +430,27 @@ def _ragged_kernel(
         win = win_ref[0]
         q = q_ref[0]  # [Th, BQ, hd]
         if quantized:
-            # every key/value row of a page shares ONE scale per kv head:
+            # every key (value) row of a page shares ONE scale per kv head:
             # the wrapper pre-gathered the per-page scales through the
-            # block tables to [Hkv, B, MBp], so the item's row and tile
+            # block tables to [2, Hkv, B, MBp], so the item's row and tile
             # index them directly, and nothing wider than the tile dequantizes
-            kdq_ref, vdq_ref = dq_refs
-
             def dequant(h, _):
                 for p in range(Tp):
                     rows = pl.ds(p * BS, BS)
-                    for page_ref, scale_ref, out_ref in (
-                        (k_refs[p], kscale_ref, kdq_ref),
-                        (v_refs[p], vscale_ref, vdq_ref),
-                    ):
+                    for half, out_ref in enumerate(dq_refs):
                         out_ref[h, rows] = (
-                            page_ref[h, 0].astype(jnp.float32)
-                            * scale_ref[h0 + h, b, j * Tp + p]
+                            page_refs[p][half, h].astype(jnp.float32)
+                            * scale_ref[half, h0 + h, b, j * Tp + p]
                         ).astype(out_ref.dtype)
 
             jax.lax.fori_loop(0, Th, dequant, None)
-            k, v = kdq_ref[...], vdq_ref[...]
+            k, v = (r[...] for r in dq_refs)
+        elif v_width:
+            k = jnp.concatenate([r[...] for r in page_refs], axis=1)
+            v = k[:, :, :v_width]
         else:
-            k = jnp.concatenate([r[:, 0] for r in k_refs], axis=1)
-            v = (k[:, :, :v_width] if v_width else
-                 jnp.concatenate([r[:, 0] for r in v_refs], axis=1))
+            k, v = (jnp.concatenate([r[half] for r in page_refs], axis=1)
+                    for half in (0, 1))
         # all Th heads in one batched dot: their dot -> softmax -> dot
         # chains are independent, and the compiler interleaves them
         s = (
@@ -477,8 +496,9 @@ def _ragged_kernel(
 
 def ragged_paged_attention(
     q,  # [B, T, H, hd]
-    k_pool,  # [Hkv, NB, BS, hd], one layer's slice of the paged pool — or,
-    v_pool,  # with ``layer``, the stacked pool [L, Hkv, NB, BS, hd] itself
+    pool,  # [NB, 2, Hkv, BS, hd], one layer's slice of the paged pool's ``kv``
+    #        leaf (K beside V) — or, with ``layer``, the stacked leaf
+    #        [L, NB, 2, Hkv, BS, hd] itself; a latent pool's [(L,) NB, 1, BS, W]
     block_tables,  # [B, MB] int32: pool block ids per row (0 = null block)
     offset,  # [] or [B] int32: global position of q[:, 0]
     window=None,  # [] or [1] int32 (traced ok) or python int: sliding
@@ -487,55 +507,58 @@ def ragged_paged_attention(
     logit_softcap: float = 0.0,
     block_q: int = 256,
     interpret: bool | None = None,
-    k_scale=None,  # [Hkv, NB] f32: int8-pool per-page-per-head scales;
-    v_scale=None,  # both present = quantized pool, dequant in-kernel
+    scale=None,  # [NB, 2, Hkv] f32: an int8 pool's per-page-per-head scales
+    #              (K's beside V's, as the pages lie); dequant in-kernel
     layer=None,  # [] or [1] int32 (traced ok): the pool is STACKED and
     #              this is the layer to read, in place (module docstring)
-    v_width: int | None = None,  # latent rows (MLA): ``v_pool`` is None and
+    v_width: int | None = None,  # latent rows (MLA): the pool holds no V and
     #              the values are the first v_width columns of the key rows
 ):
     """Causal attention for a [B, T] chunk over the paged pool; returns
     [B, T, H*hd] (core._attention ABI). T=1 is decode, T=K+1 spec verify,
     T=bucket a ragged prefill chunk — one compiled program per (T, table
     width) pair, both already bucketed by the engine; the tile a grid
-    step carries follows from the shapes (_tile_plan). With
-    ``k_scale``/``v_scale`` the pool is int8 (core.init_paged_pool's
-    quantized layout) and each fetched page dequantizes in VMEM before
-    its dot — same tiles, same softmax math, half the pool HBM traffic.
-    With ``layer`` the pool operands are the stacked 5-D pool and the page
-    index maps lead with the prefetched layer: the same copies from the
-    same bytes, and no slice of the pool exists outside the kernel.
+    step carries follows from the shapes (_tile_plan). With ``scale`` the
+    pool is int8 (core.init_paged_pool's quantized layout) and each
+    fetched page dequantizes in VMEM before its dot — same tiles, same
+    softmax math, half the pool HBM traffic. With ``layer`` the pool
+    operands are the stacked leaf and the page index maps lead with the
+    prefetched layer: the same copies from the same bytes, and no slice
+    of the pool exists outside the kernel.
 
     With ``v_width`` the pool holds LATENT rows (core.pool_layout: one row
-    a token, a unit axis for heads): every query head reads the same rows,
-    a page tile is fetched ONCE and serves as keys (the whole row) and as
-    values (its first ``v_width`` columns), and the result is
-    [B, T, H*v_width]. ``q`` is the absorbed query beside its rotated part,
-    as wide as a row."""
+    a token, a unit axis where K/V pages have their two halves of heads):
+    every query head reads the same rows, a page tile serves as keys (the
+    whole row) and as values (its first ``v_width`` columns), and the
+    result is [B, T, H*v_width]. ``q`` is the absorbed query beside its
+    rotated part, as wide as a row."""
     B, T, H, hd = q.shape
     latent = v_width is not None
-    if latent and (v_pool is not None or k_scale is not None):
-        raise ValueError("latent rows take no v_pool and no int8 scales")
+    if latent and scale is not None:
+        raise ValueError("latent rows take no int8 scales")
     stacked = layer is not None
-    if k_pool.ndim != 4 + stacked:
+    # the axes in front of a page's (BS, width): (2, Hkv), or a latent
+    # row's unit axis
+    rank = (4 if latent else 5) + stacked
+    if pool.ndim != rank or pool.shape[stacked + 1] != (1 if latent else 2):
         raise ValueError(
-            f"pool of rank {k_pool.ndim}: a layer's slice [Hkv, NB, BS, hd] "
-            "takes no `layer`, the stacked pool [L, Hkv, NB, BS, hd] needs one"
+            f"pool of shape {pool.shape}: a layer's slice is [NB, 2, Hkv, BS, "
+            "hd] (latent rows [NB, 1, BS, W]) and takes no `layer`, the "
+            "stacked leaf leads with L and needs one"
         )
-    Hkv, NB, BS, _ = k_pool.shape[-4:]
+    NB, *parts, BS, _ = pool.shape[stacked:]
+    Hkv = parts[-1]
     MB = block_tables.shape[1]
     G = H // Hkv
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    hd_q, hd = hd, k_pool.shape[-1]
+    hd_q, hd = hd, pool.shape[-1]
     if hd != hd_q:
         # a lane-aligned pool (core.init_paged_pool): its pad lanes hold
         # zeros, so zero lanes on q leave every score as it was, and the
         # output's pad lanes, cut off below, are zero
         q = jnp.pad(q, ((0, 0),) * 3 + ((0, hd - hd_q),))
     interpret = interpret_off_tpu() if interpret is None else interpret
-    quantized = k_scale is not None
-    if quantized and v_scale is None:
-        raise ValueError("quantized pool needs BOTH k_scale and v_scale")
+    quantized = scale is not None
 
     nq = G * T
     Th, Tp, bq = _tile_plan(
@@ -583,15 +606,15 @@ def ragged_paged_attention(
     )
 
     # index maps take the grid indices (head group, step) and the
-    # scalar-prefetch refs as trailing args (7 of them, or 9 with the
+    # scalar-prefetch refs as trailing args (7 of them, or 8 with the
     # quantization scales — the variadic tail keeps one lambda serving
-    # both). The K/V maps ARE the gather: entry p of the step's tile reads
-    # Th heads of pool block pages[s*Tp + p] (of layer lay[0] when the pool
-    # is stacked) — one SMEM load a map; a step whose pages are the step
-    # before's (the tail) starts no copy
+    # both). The page maps ARE the gather: entry p of the step's tile reads
+    # K and V of Th heads of pool block pages[s*Tp + p] (of layer lay[0]
+    # when the pool is stacked) in ONE copy — one SMEM load a map; a step
+    # whose pages are the step before's (the tail) starts no copy
     def page_map(p):
         def index(h, s, seg_, tile_, flag_, pages_, off_, win_, lay_, *_):
-            page = (h, pages_[s * Tp + p], 0, 0)
+            page = (pages_[s * Tp + p], *(0,) * (len(parts) - 1), h, 0, 0)
             return (lay_[0], *page) if stacked else page
 
         return index
@@ -603,13 +626,12 @@ def ragged_paged_attention(
                 seg_[s] // n_qblocks, h, seg_[s] % n_qblocks, 0),
         )
 
-    page_block = (None,) * stacked + (Th, 1, BS, hd)
-    page_specs = [pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)]
-    pools = [k_pool] * Tp + ([] if latent else [v_pool] * Tp)
+    page_block = (None,) * (stacked + 1) + (*parts[:-1], Th, BS, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=9 if quantized else 7,
+        num_scalar_prefetch=7 + quantized,
         grid=(Hkv // Th, B * n_qblocks * n_tiles),
-        in_specs=[qo_spec(hd)] + page_specs * (1 if latent else 2),
+        in_specs=[qo_spec(hd)] + [
+            pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)],
         out_specs=qo_spec(hd_o),
         scratch_shapes=[
             pltpu.VMEM((Th, bq, _LANES), jnp.float32),
@@ -618,17 +640,14 @@ def ragged_paged_attention(
         ] + [pltpu.VMEM((Th, Tp * BS, hd), q.dtype)] * (2 * quantized),
     )
     # pre-gather the per-page scales through the block tables OUTSIDE the
-    # kernel: the SMEM operand is then [Hkv, B, MBp] — bounded by the
+    # kernel: the SMEM operand is then [2, Hkv, B, MBp] — bounded by the
     # pow2-bucketed LIVE table width like every other per-step operand —
-    # instead of the pool-sized [Hkv, NB], which scales with total
+    # instead of the pool-sized [NB, 2, Hkv], which scales with total
     # capacity and would overflow SMEM on production-sized pools. The
-    # gather itself is B*MB*Hkv f32 per call — noise next to one tile's
-    # page traffic — and the kernel then indexes (h, b, j) directly.
+    # gather itself is B*MB*2*Hkv f32 per call — noise next to one tile's
+    # page traffic — and the kernel then indexes (half, h, b, j) directly.
     scales = (
-        (
-            jnp.asarray(k_scale, jnp.float32)[:, tables],
-            jnp.asarray(v_scale, jnp.float32)[:, tables],
-        )
+        (jnp.asarray(scale, jnp.float32)[tables].transpose(2, 3, 0, 1),)
         if quantized
         else ()
     )
@@ -643,7 +662,7 @@ def ragged_paged_attention(
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(*work, off, win, lay, *scales, qT, *pools)
+    )(*work, off, win, lay, *scales, qT, *[pool] * Tp)
     # a (row, q block) with no item was never visited: its block of `out`
     # holds whatever the buffer held. Zero it (fused into the cut below)
     out = jnp.where(
@@ -685,9 +704,10 @@ def _page_write_kernel(
     off_ref,  # SMEM [B] int32: position of the chunk's first token
     lay_ref,  # SMEM [1] int32: layer of the stacked pool (index map)
     lim_ref,  # SMEM [2, B] int32: each row's write floor, write ceil
-    new_ref,  # [Hkv, BS, hd] the chunk's rows laid out as THIS page's slots
-    #           ([Hkv, 1, hd] for a one-token chunk: its one row)
-    page_ref,  # [Hkv, BS, hd] the page as the pool holds it
+    new_ref,  # [2, Hkv, BS, hd] the chunk's rows laid out as THIS page's
+    #           slots ([2, Hkv, 1, hd] for a one-token chunk: its one row);
+    #           a latent pool's page is [1, BS, W]
+    page_ref,  # the page as the pool holds it, K beside V
     out_ref,  # the same block of the same buffer (aliased)
     *,
     block_size: int,
@@ -698,7 +718,7 @@ def _page_write_kernel(
     lo, hi = _written_span(off, lim_ref, b, chunk)
     page = page_ref[...]
     pos = (off // block_size + pl.program_id(1)) * block_size + (
-        jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+        jax.lax.broadcasted_iota(jnp.int32, page.shape, page.ndim - 2)
     )
     out_ref[...] = jnp.where(
         (pos >= lo) & (pos < hi), jnp.broadcast_to(new_ref[...], page.shape), page
@@ -706,8 +726,10 @@ def _page_write_kernel(
 
 
 def paged_kv_write(
-    pool,  # [L, Hkv, NB, BS, hd]: the stacked K (or V) pool, written in place
-    new,  # [B, T, Hkv, hd]: the chunk's K (or V), any float dtype
+    pool,  # [L, NB, 2, Hkv, BS, hd]: the stacked ``kv`` leaf, written in place
+    #        (a latent pool's [L, NB, 1, BS, W] likewise)
+    new,  # [B, T, 2, Hkv, hd]: the chunk's K beside its V, any float dtype
+    #       ([B, T, 1, W] latent rows)
     block_tables,  # [B, MB] int32
     offset,  # [] or [B] int32: position of new[:, 0]
     layer,  # [] or [1] int32 (traced ok)
@@ -715,27 +737,31 @@ def paged_kv_write(
     ceil=None,  # [] or [B] int32: a row's positions at / over it are not written
     interpret: bool | None = None,
 ):
-    """Store a chunk's K (or V) into the pages its positions map to, IN
-    PLACE in the stacked pool; returns the pool (the same buffer: the
-    operand is aliased to the result). Row b's position ``p`` in
+    """Store a chunk's K and V into the pages its positions map to, IN
+    PLACE in the stacked pool, in ONE call; returns the pool (the same
+    buffer: the operand is aliased to the result). Row b's position ``p`` in
     ``[offset_b, offset_b + T)`` and in ``[floor_b, ceil_b)`` goes to slot
-    ``p % BS`` of block ``tables[b, p // BS]`` of every KV head of
-    ``layer``; every other byte of every block a row owns keeps its value.
+    ``p % BS`` of block ``tables[b, p // BS]`` of ``layer``, K and V of
+    every KV head; every other byte of every block a row owns keeps its
+    value.
 
-    Grid ``(row, page of the chunk)``, a page of ALL the shard's KV heads a
-    step: the step copies the page in, replaces the slots the chunk owns,
-    copies it out (2 x Hkv x BS x hd a page; a decode step of B rows moves
-    B pages a layer). A chunk need not start on a page edge, so it may
-    touch ``(T + BS - 2) // BS + 1`` pages; the chunk is laid out in page
-    coordinates beforehand (a chunk-sized XLA gather, nothing pool-sized).
-    A grid page that holds no position to write — past the chunk, past the
-    table, wholly under the floor or over the ceil, a dead row's — names
-    the null block 0 and rewrites it unchanged. Rows own disjoint blocks
-    (the allocator's invariant), so no two steps write one live page.
+    Grid ``(row, page of the chunk)``, a whole page a step — the axes in
+    front of a page's ``(BS, width)`` are read off the pool's shape: K and
+    V of ALL the shard's KV heads, or a latent row's unit axis — as one
+    contiguous block: the step copies the page in, replaces the slots the
+    chunk owns, copies it out (2 x 2 x Hkv x BS x hd a page; a decode step
+    of B rows moves B pages a layer). A chunk need not start on a page
+    edge, so it may touch ``(T + BS - 2) // BS + 1`` pages; the chunk is
+    laid out in page coordinates beforehand (a chunk-sized XLA gather,
+    nothing pool-sized). A grid page that holds no position to write —
+    past the chunk, past the table, wholly under the floor or over the
+    ceil, a dead row's — names the null block 0 and rewrites it unchanged.
+    Rows own disjoint blocks (the allocator's invariant), so no two steps
+    write one live page.
 
     This is the write half of the pool's in-place contract (module
     docstring, "Layouts"): it must be a Mosaic call, like the read."""
-    _, Hkv, _, BS, hd = pool.shape
+    *parts, BS, hd = pool.shape[2:]  # (2, Hkv), or a latent row's (1,)
     B, T = new.shape[:2]
     MB = block_tables.shape[1]
     interpret = interpret_off_tpu() if interpret is None else interpret
@@ -746,26 +772,32 @@ def paged_kv_write(
     n_pages = chunk_pages(T, BS)
     new = new.astype(pool.dtype)
     if new.shape[-1] != hd:  # a lane-aligned pool: its pad lanes hold zeros
-        new = jnp.pad(new, ((0, 0),) * 3 + ((0, hd - new.shape[-1]),))
+        new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, hd - new.shape[-1]),))
     if T == 1:
-        rows = new[:, :, :, None, :]  # [B, 1, Hkv, 1, hd]: every slot's candidate
+        # [B, 1, 2, Hkv, 1, hd]: the candidate of every slot of both halves
+        rows = new[..., None, :]
     else:
         # slot s of the chunk's page p holds chunk position p*BS + s - off % BS
         src = jnp.arange(n_pages * BS, dtype=jnp.int32)[None] - (off % BS)[:, None]
-        rows = jnp.take_along_axis(
-            new, jnp.clip(src, 0, T - 1)[:, :, None, None], axis=1
-        ).reshape(B, n_pages, BS, Hkv, hd).transpose(0, 1, 3, 2, 4)
+        rows = jnp.moveaxis(
+            jnp.take_along_axis(
+                new, jnp.clip(src, 0, T - 1).reshape(B, -1, *(1,) * (new.ndim - 2)),
+                axis=1,
+            ).reshape(B, n_pages, BS, *parts, hd),
+            2, -2,
+        )
+
+    zeros = (0,) * (len(parts) + 2)
 
     def page_index(b, p, tb, off_, lay_, lim_):
         page = off_[b] // BS + p
         lo, hi = _written_span(off_[b], lim_, b, T)
         live = (page * BS < hi) & (page * BS + BS > lo) & (page < MB)
         return (
-            lay_[0], 0,
-            jnp.where(live, tb[b, jnp.minimum(page, MB - 1)], 0), 0, 0,
+            lay_[0], jnp.where(live, tb[b, jnp.minimum(page, MB - 1)], 0), *zeros,
         )
 
-    page_spec = pl.BlockSpec((None, Hkv, None, BS, hd), page_index)
+    page_spec = pl.BlockSpec((None, None, *parts, BS, hd), page_index)
     return pl.pallas_call(
         functools.partial(_page_write_kernel, block_size=BS, chunk=T),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -773,8 +805,8 @@ def paged_kv_write(
             grid=(B, n_pages),
             in_specs=[
                 pl.BlockSpec(
-                    (None, None, Hkv, rows.shape[3], hd),
-                    lambda b, p, *_: (b, p, 0, 0, 0),
+                    (None, None, *parts, rows.shape[-2], hd),
+                    lambda b, p, *_: (b, p, *zeros),
                 ),
                 page_spec,
             ],
@@ -800,12 +832,16 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
     the block-tables path forward partials in the block tables, and the
     per-layer mask argument becomes the compact [1] int32 window selector
     (core.make_layer_window) instead of a bool mask — nothing S-wide is
-    ever built. Over a float pool the kv_hook stores the chunk through
-    ``attn.write`` (paged_kv_write under this mesh) and hands the STACKED
-    pool through as (k, v) with ``layer=`` partialled in: written and read
-    in place (module docstring, "Layouts"). On an int8 pool the hook hands
-    (pool slice, [Hkv, NB] scale slice) TUPLES through and the kernel
-    dequantizes per gathered block.
+    ever built. Over a float pool the kv_hook stores the chunk's K and V
+    through ONE ``attn.write`` (paged_kv_write under this mesh) and hands
+    the STACKED ``kv`` leaf through as ``k`` (``v`` None) with ``layer=``
+    partialled in: written and read in place (module docstring,
+    "Layouts"). On an int8 pool the hook hands the (pool slice,
+    [NB, 2, Hkv] scale slice) pair through and the kernel dequantizes per
+    gathered block. Handed a ``v`` as well, ``k`` and ``v`` are head-major
+    pools ``[(L,) Hkv, NB, BS, hd]`` (the form the tree stored before
+    PR 44; checks and tests build them by hand): attn lays them page-major
+    itself and reads them through the same kernel.
 
     Under a non-trivial mesh the kernel runs per-shard via shard_map
     (pallas_call has no SPMD partitioning rule): q heads and the pool's
@@ -813,7 +849,8 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
     kernel's head-layout rules, enforced by validate_flash_mesh),
     batch/tables/offsets over `data` when it divides; the window scalar
     replicates. The pool's block/slot dims never shard here — any row
-    gathers arbitrary blocks (partition.paged_cache_spec).
+    gathers arbitrary blocks (partition.paged_cache_spec: kv heads are
+    axis 3 of the stacked leaf).
 
     ``interpret=None`` resolves from the MESH's devices
     (interpret_off_tpu), once, here. Called WITHOUT block tables it
@@ -856,39 +893,41 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
                 "(a paged pool); pass attn_fn=None for a cache-less or "
                 "rectangular-cache forward"
             )
-        # int8 pool: the kv_hook hands (pool slice, scale slice) pairs
-        # through — unpack them here so the kernel dequants in-loop
-        k_scale = v_scale = None
-        if isinstance(k, tuple):
-            k, k_scale = k
-            v, v_scale = v
-        stacked = layer is not None  # k, v: the stacked pool, read in place
+        # what the kv_hook hands through as ``k`` (``v`` is None): the
+        # stacked ``kv`` leaf, or an int8 pool's (layer slice, scale slice)
+        # pair — unpack it here so the kernel dequants in-loop
+        pool, scale = k if isinstance(k, tuple) else (k, None)
+        if v is not None:
+            # the old form at the door: a head-major K pool and V pool
+            # ([(L,) Hkv, NB, BS, hd] each; checks and tests build them by
+            # hand). Laid page-major here, inside the caller's jit, and read
+            # by the same kernel; the served hook never takes this branch
+            pool = jnp.moveaxis(jnp.stack([pool, v], axis=-5), -3, -5)
+        stacked = layer is not None  # the stacked leaf, read in place
         window = mask  # the ragged path's per-layer [1] int32 selector
         offset = positions[:, 0] if positions is not None else None
         sm_scale = 1.0 / math.sqrt(cfg.attn_scale or cfg.head_dim)
         softcap = float(cfg.attn_logit_softcap or 0.0)
-        axes = mesh_axes(q.shape[0], k.shape[-4])
+        axes = mesh_axes(q.shape[0], pool.shape[-3])
         if axes is None:
             return ragged_paged_attention(
-                q, k, v, block_tables, offset, window,
+                q, pool, block_tables, offset, window,
                 sm_scale=sm_scale, logit_softcap=softcap, interpret=interpret,
-                k_scale=k_scale, v_scale=v_scale, layer=layer,
+                scale=scale, layer=layer,
             )
         batch_ax, head_ax, kv_ax = axes
         off, win, lay = scalars(q.shape[0], offset, window, layer)
         # ONE shard_map for both pool precisions: the int8 scales shard
         # exactly like the pool's kv-head dim (their block dim, like the
         # pool's, never shards) and simply extend the operand tuple
-        quant = k_scale is not None
-        scale_args = (k_scale, v_scale) if quant else ()
-        pool_spec = P(None, kv_ax) if stacked else P(kv_ax)
+        scale_args = () if scale is None else (scale,)
+        pool_spec = P(*(None,) * (stacked + 2), kv_ax)
 
-        def body(q_, k_, v_, t_, o_, w_, l_, *sc):
+        def body(q_, p_, t_, o_, w_, l_, *sc):
             return ragged_paged_attention(
-                q_, k_, v_, t_, o_, w_,
+                q_, p_, t_, o_, w_,
                 sm_scale=sm_scale, logit_softcap=softcap, interpret=interpret,
-                k_scale=sc[0] if sc else None,
-                v_scale=sc[1] if sc else None,
+                scale=sc[0] if sc else None,
                 layer=l_ if stacked else None,
             )
 
@@ -898,17 +937,16 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
             in_specs=(
                 P(batch_ax, None, head_ax, None),
                 pool_spec,
-                pool_spec,
                 P(batch_ax),
                 P(batch_ax),
                 P(),
                 P(),
-            ) + (P(kv_ax),) * len(scale_args),
+            ) + (P(None, None, kv_ax),) * len(scale_args),
             out_specs=P(batch_ax, None, head_ax),
             check_vma=False,
         )
         return mapped(
-            q, k, v, jnp.asarray(block_tables, jnp.int32), off, win, lay,
+            q, pool, jnp.asarray(block_tables, jnp.int32), off, win, lay,
             *scale_args,
         )
 
@@ -917,7 +955,7 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
         pool's kv heads, like the read. The batch is NOT split over
         `data`: the pool is replicated there (partition.paged_cache_spec),
         so every replica must store every row."""
-        axes = mesh_axes(new.shape[0], pool.shape[1])
+        axes = mesh_axes(new.shape[0], pool.shape[-3])
         if axes is None:
             return paged_kv_write(
                 pool, new, block_tables, offset, layer, floor, ceil,
@@ -932,9 +970,10 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
             ),
             mesh=mesh,
             in_specs=(
-                P(None, kv_ax), P(None, None, kv_ax), P(), P(), P(), P(),
+                P(None, None, None, kv_ax), P(None, None, None, kv_ax),
+                P(), P(), P(), P(),
             ),
-            out_specs=P(None, kv_ax),
+            out_specs=P(None, None, None, kv_ax),
             check_vma=False,
         )
         return mapped(
@@ -945,13 +984,13 @@ def make_ragged_attn_fn(mesh=None, interpret: bool | None = None):
                layer=None):
         """attn's ABI over LATENT rows (core._mla_attention): ``q`` [B, T,
         H, W] the absorbed query beside its rotated part, ``pool`` the
-        stacked [L, 1, NB, BS, W] latent pool, read in place at ``layer``;
+        stacked [L, NB, 1, BS, W] latent pool, read in place at ``layer``;
         no V. Every head reads the one row a token; -> [B, T, H*kv_rank].
         A single device only: the engine refuses a mesh for such a model."""
         if mesh_axes(q.shape[0], 1) is not None:
             raise ValueError("the latent read is not partitioned over a mesh")
         return ragged_paged_attention(
-            q, pool, None, block_tables,
+            q, pool, block_tables,
             positions[:, 0] if positions is not None else None, mask,
             sm_scale=1.0 / math.sqrt(cfg.mla_nope_dim + cfg.mla_rope_dim),
             interpret=interpret, layer=layer, v_width=cfg.mla_kv_rank,

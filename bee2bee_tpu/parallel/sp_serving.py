@@ -5,7 +5,7 @@ parallel/ring.py gives training its ring attention; this module gives the
 *serving* engine the same first-class long-context story (the reference
 has nothing here — SURVEY §5 "Long-context: absent"). Design:
 
-- The paged KV pool [L, Hkv, NB, BS, hd] is sharded over the `seq` mesh
+- The paged KV pool [L, NB, 2, Hkv, BS, hd] is sharded over the `seq` mesh
   axis on its SLOT dim BS (models/partition.paged_cache_spec with
   seq_sharded=True — the engine sets it iff attention='sp'), so
   per-device pool HBM is 1/n — max context scales linearly with

@@ -498,6 +498,10 @@ KERNEL_CASES = {
                               lengths=(2048, 2048)),
     # falcon-h1's attention, the wide cell's decode: GQA 20/4 x 128, 64 rows
     "h1_decode": dict(heads=(20, 4, 128), B=64, T=1, MB=32, lengths=(64, 500)),
+    # smallthinker's, the document cell's decode (PR 44): GQA 28/4 x 128, 32
+    # rows 4k-8k deep in 1,024-page tables behind the 4,096 window
+    "st_decode": dict(heads=(28, 4, 128), B=32, T=1, MB=1024, window=4096,
+                      lengths=(4300, 8400)),
 }
 # microseconds a call of the one-page-one-head kernel this one replaced, on
 # the same inputs (tree ee64104, TPU v5 lite, my chip run, PR 27; the two
@@ -531,8 +535,8 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
     pages = -(-lengths // BS)
     NB = int(pages.sum()) + 1
     q = jnp.asarray(rng.standard_normal((B, T, H, hd)), jnp.bfloat16)
-    k_pool = jnp.asarray(rng.standard_normal((Hkv, NB, BS, hd)), jnp.bfloat16)
-    v_pool = jnp.asarray(rng.standard_normal((Hkv, NB, BS, hd)), jnp.bfloat16)
+    # one layer of core.init_paged_pool's ``kv`` leaf: K beside V, page-major
+    kv_pool = jnp.asarray(rng.standard_normal((NB, 2, Hkv, BS, hd)), jnp.bfloat16)
     # every row maps its own distinct pool blocks; past its live extent
     # the table holds the null block 0
     ids = iter(rng.permutation(np.arange(1, NB)))
@@ -542,17 +546,17 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
     tables = jnp.asarray(tables)
     off = jnp.asarray(lengths - T, jnp.int32)
 
-    def kernel(q, k_pool, v_pool, tables, off):
+    def kernel(q, kv_pool, tables, off):
         return ragged_paged_attention(
-            q, k_pool, v_pool, tables, off, window=window, interpret=False
+            q, kv_pool, tables, off, window=window, interpret=False
         )
 
-    def dense(q, k_pool, v_pool, tables, off):
+    def dense(q, kv_pool, tables, off):
         # the engine's dense path: gather the mapped blocks into the
-        # [B, S, Hkv, hd] view, mask by position, core._attention
+        # [B, S, Hkv, hd] views, mask by position, core._attention
         S = MB * BS
-        k = jnp.transpose(k_pool[:, tables], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
-        v = jnp.transpose(v_pool[:, tables], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
+        k, v = jnp.transpose(kv_pool[tables], (2, 0, 1, 4, 3, 5)).reshape(
+            2, B, S, Hkv, hd)
         qpos = (off[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :])[:, :, None]
         kvpos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
         mask = kvpos <= qpos
@@ -560,7 +564,7 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
             mask = mask & (kvpos > qpos - window)
         return core._attention(q, k, v, mask[:, None], cfg)
 
-    args = (q, k_pool, v_pool, tables, off)
+    args = (q, kv_pool, tables, off)
     lowered = jax.jit(kernel).lower(*args)
     has_kernel = "tpu_custom_call" in lowered.as_text()
     compiled = lowered.compile()
@@ -596,28 +600,27 @@ def _kernel_case(name: str, case: dict, rng) -> dict:
 
 def _in_place_case(args, window) -> dict:
     """The same case on a STACKED, lane-aligned pool of two layers, as
-    core.forward runs it on a TPU since PR 29: the page-write of the chunk's K
-    into layer 1, compiled and donated, against XLA's scatter (bit for bit in
-    every block a row owns), then the kernel reading layer 1 in place against
-    its read of the unaligned slice."""
+    core.forward runs it on a TPU since PR 29: ONE page-write of the chunk's K
+    and V into layer 1, compiled and donated, against XLA's scatter (bit for
+    bit in every block a row owns), then the kernel reading layer 1 in place
+    against its read of the unaligned slice."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from bee2bee_tpu.ops.ragged import paged_kv_write, ragged_paged_attention
 
-    q, k_pool, v_pool, tables, off = args
+    q, kv_pool, tables, off = args
     BS, T = KERNEL_BLOCK, q.shape[1]
-    new = (q[:, :, : k_pool.shape[0]] * 0.5).astype(k_pool.dtype)  # [B,T,Hkv,hd]
+    Hkv, hd = kv_pool.shape[2], kv_pool.shape[-1]
+    half = (q[:, :, :Hkv] * 0.5).astype(kv_pool.dtype)  # [B, T, Hkv, hd]
+    new = jnp.stack([half, half * 0.5], axis=2)  # K beside V, as a page lies
     positions = off[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     blk = jnp.take_along_axis(tables, positions // BS, axis=1)
-    want = np.asarray(
-        k_pool.at[:, blk, positions % BS].set(jnp.transpose(new, (2, 0, 1, 3))),
-        np.float32,
-    )
-    hd = k_pool.shape[-1]
-    lanes = ((0, 0),) * 4 + ((0, -hd % 128),)
-    stacked = jnp.pad(jnp.stack([v_pool, k_pool]), lanes)
+    want = np.asarray(kv_pool.at[blk, :, :, positions % BS].set(new), np.float32)
+    lanes = ((0, 0),) * 5 + ((0, -hd % 128),)
+    other = jnp.flip(kv_pool, axis=1)  # layer 0: must come back untouched
+    stacked = jnp.pad(jnp.stack([other, kv_pool]), lanes)
     write = jax.jit(
         lambda pool, new: paged_kv_write(
             pool, new, tables, off, jnp.int32(1), interpret=False),
@@ -633,16 +636,16 @@ def _in_place_case(args, window) -> dict:
     stacked.block_until_ready()
     us = (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
 
-    def read(k, v, layer=None):
+    def read(pool, layer=None):
         return ragged_paged_attention(
-            q, k, v, tables, off, window=window, interpret=False, layer=layer)
+            q, pool, tables, off, window=window, interpret=False, layer=layer)
 
-    sliced = jax.jit(read)(stacked[1, ..., :hd], stacked[1, ..., :hd])
-    in_place = jax.jit(read)(stacked, stacked, jnp.int32(1))
+    sliced = jax.jit(read)(stacked[1, ..., :hd])
+    in_place = jax.jit(read)(stacked, jnp.int32(1))
     return {
         "write_matches_scatter": bool(
-            np.array_equal(got[1][:, 1:], want[:, 1:]) and pad_is_zero
-            and np.array_equal(got[0], np.asarray(v_pool, np.float32))
+            np.array_equal(got[1][1:], want[1:]) and pad_is_zero
+            and np.array_equal(got[0], np.asarray(other, np.float32))
         ),
         "write_us_per_call": round(us, 1),
         # the same pages through 128 lanes instead of hd: the kernel-vs-dense
@@ -682,13 +685,13 @@ def _latent_case(B: int, T: int, MB: int, ctx: int) -> dict:
     NB = nxt + 1
     tables = jnp.asarray(tables)
     ks = jax.random.split(jax.random.key(SEED), 3)
-    rows = jax.random.normal(ks[0], (1, NB, BS, W), jnp.bfloat16)
+    rows = jax.random.normal(ks[0], (NB, 1, BS, W), jnp.bfloat16)
     q = (jax.random.normal(ks[1], (B, T, H, W), jnp.float32) * 0.2).astype(jnp.bfloat16)
     new = jax.random.normal(ks[2], (B, T, 1, W), jnp.bfloat16)
     sm = 1.0 / (cfg.mla_nope_dim + cfg.mla_rope_dim) ** 0.5
     positions = off[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     blk = jnp.take_along_axis(tables, positions // BS, axis=1)
-    want_rows = rows.at[:, blk, positions % BS].set(jnp.transpose(new, (2, 0, 1, 3)))
+    want_rows = rows.at[blk, 0, positions % BS].set(new[:, :, 0])
     stacked = jnp.pad(jnp.stack([rows * 0, rows]), ((0, 0),) * 4 + ((0, -W % 128),))
     write = jax.jit(
         lambda pool, new: paged_kv_write(
@@ -696,7 +699,7 @@ def _latent_case(B: int, T: int, MB: int, ctx: int) -> dict:
         donate_argnums=(0,)).lower(stacked, new).compile()
     stacked = write(stacked, new)
     got_rows = np.asarray(stacked[1, ..., :W], np.float32)
-    wrote = bool(np.array_equal(got_rows[:, 1:], np.asarray(want_rows, np.float32)[:, 1:])
+    wrote = bool(np.array_equal(got_rows[1:], np.asarray(want_rows, np.float32)[1:])
                  and not np.asarray(stacked[0], np.float32).any()
                  and not np.asarray(stacked[1, ..., W:], np.float32).any())
 
@@ -715,13 +718,13 @@ def _latent_case(B: int, T: int, MB: int, ctx: int) -> dict:
     stacked.block_until_ready()
     write_us = (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6
     lowered = jax.jit(lambda q, pool: ragged_paged_attention(
-        q, pool, None, tables, off, sm_scale=sm, interpret=False,
+        q, pool, tables, off, sm_scale=sm, interpret=False,
         layer=jnp.int32(1), v_width=R)).lower(q, stacked)
     has_kernel = "tpu_custom_call" in lowered.as_text()
     got, read_us = clock(lowered.compile(), q, stacked)
 
     def dense(q, pool):
-        lat = pool[1, 0][tables].reshape(B, MB * BS, -1)[..., :W]
+        lat = pool[1][tables].reshape(B, MB * BS, -1)[..., :W]
         mask = jnp.arange(MB * BS)[None, None, :] <= positions[:, :, None]
         return core._latent_attention(q, lat, mask[:, None], R, sm).reshape(B, T, H * R)
 

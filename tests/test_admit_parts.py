@@ -605,17 +605,17 @@ def test_the_inner_scopes_still_match_their_readers_under_a_root(monkeypatch, mo
 
 def test_the_pool_and_count_helpers_lower_under_prog_pool(engine):
     sch = engine.scheduler
-    pool = {"k": jnp.zeros((1, 1, 4, 2, 2))}
+    pool = {"kv": jnp.zeros((1, 4, 2, 1, 2, 2))}  # [L, NB, 2, Hkv, BS, hd]
     state = {"ssm": jnp.zeros((1, 2, 3))}
     i32 = np.int32
     counts = jnp.zeros((2, 2, sch._vocab), jnp.int32)
     lowered = {
-        "copy_slot": paged._copy_slot.lower(2, pool, i32(0), i32(1)),
+        "copy_slot": paged._copy_slot.lower(pool, i32(0), i32(1)),
         "gather_blocks": paged._gather_blocks.lower(2, pool, np.zeros((2,), i32)),
         "scatter_blocks": paged._scatter_blocks.lower(
-            pool, {"k": jnp.zeros((1, 1, 2, 2, 2))}, np.zeros((2,), i32)),
+            pool, {n: jnp.zeros((1, 1, 2, 2, 2)) for n in "kv"}, np.zeros((2,), i32)),
         "reset_scales": paged._reset_scales.lower(
-            {"k_scale": jnp.zeros((1, 1, 4)), "v_scale": jnp.zeros((1, 1, 4))}, np.zeros((2,), i32)),
+            {"kv_scale": jnp.zeros((1, 4, 2, 1))}, np.zeros((2,), i32)),
         "state_insert": paged._state_insert.lower(state, {"ssm": jnp.zeros((1, 1, 3))}, i32(1)),
         "state_shrink": paged._state_shrink.lower(state, 1),
         "counts_zeros": sch._counts_zeros.lower(2),

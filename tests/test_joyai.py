@@ -153,7 +153,7 @@ def test_prefill_in_chunks_then_decode_through_the_latent_pool(params, pieces, r
     attn = make_ragged_attn_fn() if reader == "ragged" else None
     BS, lens = 4, [13, 21, 0, 9]
     pool = core.init_paged_pool(CFG, 40, BS, jnp.float32)
-    assert set(pool) == {"latent"} and pool["latent"].shape == (3, 1, 40, BS, 32)
+    assert set(pool) == {"latent"} and pool["latent"].shape == (3, 40, 1, BS, 32)
     tables, nxt = np.zeros((4, 8), np.int32), 1
     for b, n in enumerate(lens):
         if n:
@@ -556,7 +556,7 @@ def test_engine_decode_matches_full_forward(solo):
         kv = eng.info["kv"]
         assert kv["layout"] == {"latent": [1, 32]} and kv["bytes_per_token"] == 3 * 32 * 4
         pool = eng.scheduler.cache.pool
-        assert set(pool) == {"latent"} and pool["latent"].shape[1] == 1  # stored once, no V
+        assert set(pool) == {"latent"} and pool["latent"].shape[2] == 1  # stored once, no V
         eng.introspect.ledger.snapshot()
         from bee2bee_tpu.metrics import get_registry
 
@@ -674,7 +674,8 @@ def test_latent_rows_export_and_import_between_plain_and_lane_aligned_pools():
         assert nb == 3 and set(sent) == {"latent"}
         assert sent["latent"].shape == (CFG.n_layers, 1, 3, BS, W)
         aligned.import_row(2, n, sent)
-        got = np.asarray(aligned.pool["latent"])[:, :, aligned.row_blocks[2]]
+        # stored [L, NB, 1, BS, W]; the wire keeps its block axis at 2
+        got = np.asarray(aligned.pool["latent"])[:, aligned.row_blocks[2]].swapaxes(1, 2)
         np.testing.assert_array_equal(got[..., :W], sent["latent"])
         assert not got[..., W:].any()  # pad lanes stay zero
         nb2, back = aligned.export_row(2, n)

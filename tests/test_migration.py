@@ -122,10 +122,10 @@ def test_lane_aligned_pool_exports_at_the_models_head_size():
         sch = eng.scheduler
         sch.cache.pool = jax.jit(functools.partial(
             core.init_paged_pool, eng.model_cfg, eng.pool_blocks,
-            eng.engine_cfg.kv_block_size, sch.cache.pool["k"].dtype,
+            eng.engine_cfg.kv_block_size, sch.cache.pool["kv"].dtype,
             lane_aligned=True,
         ))()
-        assert sch.cache.pool["k"].shape[-1] == 128 != eng.model_cfg.head_dim
+        assert sch.cache.pool["kv"].shape[-1] == 128 != eng.model_cfg.head_dim
         return eng
 
     plain, a, b = _engine(), aligned_engine(), aligned_engine()
@@ -138,7 +138,7 @@ def test_lane_aligned_pool_exports_at_the_models_head_size():
             out, result = _drain_events(dst.import_generation(snap, kv), snap["out"])
             assert out == base.token_ids
             assert dst.scheduler.stats.import_reprefills == 0
-        assert not np.asarray(b.scheduler.cache.pool["k"][..., 16:]).any()
+        assert not np.asarray(b.scheduler.cache.pool["kv"][..., 16:]).any()
     finally:
         for eng in (plain, a, b):
             eng.close()

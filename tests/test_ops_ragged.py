@@ -48,17 +48,31 @@ def _pool_case(offs, T, H, Hkv, hd, BS=8, extra_tables=0, seed=0,
             tables[b, i] = nxt
             nxt += 1
     NB = nxt + 1
-    kp = jnp.asarray(rng.standard_normal((Hkv, NB, BS, hd)), dtype)
-    vp = jnp.asarray(rng.standard_normal((Hkv, NB, BS, hd)), dtype)
+    # one layer of core.init_paged_pool's ``kv`` leaf: K beside V, page-major
+    kv = jnp.asarray(rng.standard_normal((NB, 2, Hkv, BS, hd)), dtype)
     q = jnp.asarray(rng.standard_normal((B, T, H, hd)), dtype)
-    S = MB * BS
-    # gathered view [B, S, Hkv, hd] — what the dense path attends over
-    kg = jnp.transpose(kp[:, tables], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
-    vg = jnp.transpose(vp[:, tables], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
-    s_idx = np.arange(S)[None, None, :]
+    kg, vg = _gathered(kv, tables)
+    s_idx = np.arange(MB * BS)[None, None, :]
     q_pos = (offs[:, None] + np.arange(T)[None, :])[:, :, None]
     mask = jnp.asarray(s_idx <= q_pos)  # [B, T, S] — for the dense ref
-    return q, kp, vp, jnp.asarray(tables), jnp.asarray(offs), mask, kg, vg
+    return q, kv, jnp.asarray(tables), jnp.asarray(offs), mask, kg, vg
+
+
+def _gathered(kv, tables):
+    """The gathered views [B, S, Hkv, hd] of K and of V — what the dense
+    path attends over — of a page-major pool slice [NB, 2, Hkv, BS, hd]."""
+    B, MB = tables.shape
+    _, _, Hkv, BS, hd = kv.shape
+    g = jnp.transpose(kv[tables], (2, 0, 1, 4, 3, 5)).reshape(2, B, MB * BS, Hkv, hd)
+    return g[0], g[1]
+
+
+def _head_major(kv):
+    """A page-major leaf [(L,) NB, 2, Hkv, BS, hd] as the head-major K pool
+    and V pool [(L,) Hkv, NB, BS, hd] the tree stored before PR 44 (and
+    that make_ragged_attn_fn's attn still takes at the door)."""
+    both = jnp.moveaxis(kv, -5, -3)  # [(L,) 2, Hkv, NB, BS, hd]
+    return both[..., 0, :, :, :, :], both[..., 1, :, :, :, :]
 
 
 def _dense_ref(q, kg, vg, mask, cfg=CFG):
@@ -73,10 +87,10 @@ def test_ragged_decode_lengths_across_block_boundaries():
     """T=1 decode rows whose lengths sit just below, at, and past block
     boundaries (BS=8): the per-row page walk must mask the exact ragged
     extent."""
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[0, 7, 8, 21], T=1, H=4, Hkv=2, hd=16
     )
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
@@ -84,11 +98,11 @@ def test_ragged_null_block_tail_is_masked():
     """Table entries past the live extent map to null block 0 (the
     engine's pow2-bucketed width padding): they must contribute exactly
     nothing, matching the dense reference over the same padded view."""
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[3, 12], T=1, H=4, Hkv=2, hd=16, extra_tables=3, seed=1
     )
     assert int((np.asarray(tb) == 0).sum()) >= 6  # tails really padded
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     assert np.isfinite(np.asarray(out)).all()
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
@@ -96,11 +110,11 @@ def test_ragged_null_block_tail_is_masked():
 def test_ragged_dead_row_all_null_is_finite():
     """A dead batch row (retired mid-batch) has its whole table nulled:
     output is garbage-but-finite, and live rows are untouched."""
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[9, 4], T=1, H=4, Hkv=2, hd=16, seed=2
     )
     tb = tb.at[1].set(0)
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     assert np.isfinite(np.asarray(out)).all()
     want = _dense_ref(q[:1], kg[:1], vg[:1], mask[:1])
     _assert_close(out[:1], want)
@@ -109,10 +123,10 @@ def test_ragged_dead_row_all_null_is_finite():
 @pytest.mark.parametrize("H,Hkv", [(4, 4), (8, 2), (4, 1)],
                          ids=["mha", "gqa4", "mqa"])
 def test_ragged_gqa_ratios(H, Hkv):
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[5, 18], T=2, H=H, Hkv=Hkv, hd=8, seed=3
     )
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
@@ -123,7 +137,7 @@ def test_ragged_sliding_window_softcap_and_scale():
     override as scalar params — all must match the dense path, which is
     the ModelConfig-coverage contract."""
     cfg = replace(CFG, attn_logit_softcap=30.0, attn_scale=13)
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[6, 19, 33], T=2, H=4, Hkv=2, hd=16, seed=4
     )
     w = 9
@@ -137,12 +151,12 @@ def test_ragged_sliding_window_softcap_and_scale():
 
     for window in (w, jnp.full((1,), w, jnp.int32)):  # python int + traced
         out = ragged_paged_attention(
-            q, kp, vp, tb, off, window=window,
+            q, kv, tb, off, window=window,
             sm_scale=1.0 / math.sqrt(13), logit_softcap=30.0,
         )
         _assert_close(out, _dense_ref(q, kg, vg, maskw, cfg))
     # a window wider than any offset never masks: must equal full causal
-    out = ragged_paged_attention(q, kp, vp, tb, off, window=10_000)
+    out = ragged_paged_attention(q, kv, tb, off, window=10_000)
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
@@ -150,10 +164,10 @@ def test_ragged_spec_verify_shape():
     """[B, K+1] — the speculative-decode verify chunk: per-row offsets,
     rows at different depths, causality within the chunk."""
     K = 5
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[2, 15, 24], T=K + 1, H=4, Hkv=2, hd=16, seed=5
     )
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
@@ -161,58 +175,49 @@ def test_ragged_prefill_chunk_rows():
     """A bucket-wide chunk (T=16) at ragged per-row offsets — chunked
     prefill re-anchoring lands rows at arbitrary positions; q-row tiling
     (block_q below the row count) must not change the math."""
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[0, 11], T=16, H=4, Hkv=2, hd=16, seed=6
     )
-    out = ragged_paged_attention(q, kp, vp, tb, off, block_q=8)
+    out = ragged_paged_attention(q, kv, tb, off, block_q=8)
     _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
 def test_ragged_under_jit():
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[4, 9], T=1, H=4, Hkv=2, hd=16, seed=7
     )
     f = jax.jit(lambda *a: ragged_paged_attention(*a))
-    _assert_close(f(q, kp, vp, tb, off), _dense_ref(q, kg, vg, mask))
+    _assert_close(f(q, kv, tb, off), _dense_ref(q, kg, vg, mask))
 
 
-def _quantize_pool(kp, vp):
-    """f32 pool → (int8 pool, [Hkv, NB] scales), the per-page-per-head
-    symmetric amax recipe core._quantized_page_write applies on write."""
-    def one(p):
-        s = np.max(np.abs(np.asarray(p, np.float32)), axis=(2, 3)) / 127.0
-        safe = np.where(s > 0, s, 1.0)
-        q = np.clip(
-            np.rint(np.asarray(p, np.float32) / safe[:, :, None, None]),
-            -127, 127,
-        ).astype(np.int8)
-        return jnp.asarray(q), jnp.asarray(s.astype(np.float32))
-
-    kq, ks = one(kp)
-    vq, vs = one(vp)
-    return kq, ks, vq, vs
+def _quantize_pool(kv):
+    """f32 pool [NB, 2, Hkv, BS, hd] → (int8 pool, [NB, 2, Hkv] scales), the
+    per-page-per-head symmetric amax recipe core._quantized_page_write
+    applies on write."""
+    p = np.asarray(kv, np.float32)
+    s = np.max(np.abs(p), axis=(3, 4)) / 127.0
+    safe = np.where(s > 0, s, 1.0)
+    q = np.clip(np.rint(p / safe[..., None, None]), -127, 127).astype(np.int8)
+    return jnp.asarray(q), jnp.asarray(s.astype(np.float32))
 
 
 def test_ragged_int8_pool_dequant_matches_dense_on_dequantized_view():
-    """ISSUE 12 kernel contract: with an int8 pool + [Hkv, NB] scales the
+    """ISSUE 12 kernel contract: with an int8 pool + [NB, 2, Hkv] scales the
     kernel dequantizes K before QK^T and V before PV per gathered block —
     it must match the dense reference attending over the HOST-dequantized
     gathered view exactly (same values enter both softmaxes, so the only
     tolerance is the usual online-softmax reordering). Covers ragged
     decode lengths, null-block tails, and the [B, K+1] verify shape."""
     for offs, T, extra in ([0, 7, 8, 21], 1, 0), ([3, 12], 1, 3), ([2, 15, 24], 6, 0):
-        q, kp, vp, tb, off, mask, _kg, _vg = _pool_case(
+        q, kv, tb, off, mask, _kg, _vg = _pool_case(
             offs=offs, T=T, H=4, Hkv=2, hd=16, extra_tables=extra, seed=11
         )
-        kq, ks, vq, vs = _quantize_pool(kp, vp)
-        out = ragged_paged_attention(q, kq, vq, tb, off, k_scale=ks, v_scale=vs)
+        kvq, sc = _quantize_pool(kv)
+        out = ragged_paged_attention(q, kvq, tb, off, scale=sc)
         # dense view over the DEQUANTIZED pool (what the engine's int8
         # dense fallback builds), gathered exactly like _pool_case does
-        kdq = jnp.asarray(kq, jnp.float32) * ks[:, :, None, None]
-        vdq = jnp.asarray(vq, jnp.float32) * vs[:, :, None, None]
-        B, S = tb.shape[0], tb.shape[1] * kp.shape[2]
-        kg = jnp.transpose(kdq[:, tb], (1, 2, 3, 0, 4)).reshape(B, S, 2, 16)
-        vg = jnp.transpose(vdq[:, tb], (1, 2, 3, 0, 4)).reshape(B, S, 2, 16)
+        kg, vg = _gathered(
+            jnp.asarray(kvq, jnp.float32) * sc[..., None, None], np.asarray(tb))
         _assert_close(out, _dense_ref(q, kg, vg, mask))
 
 
@@ -241,18 +246,25 @@ def test_ragged_attn_fn_needs_block_tables_and_reads_interpret_from_mesh():
     assert interpret_off_tpu() is True  # the suite's default backend is the CPU
 
 
-def test_ragged_int8_requires_both_scales():
-    q, kp, vp, tb, off, *_ = _pool_case(offs=[4], T=1, H=4, Hkv=2, hd=16)
-    kq, ks, _vq, _vs = _quantize_pool(kp, vp)
-    with pytest.raises(ValueError, match="k_scale and v_scale"):
-        ragged_paged_attention(q, kq, vp, tb, off, k_scale=ks)
+def test_ragged_int8_scales_lie_as_the_pages_do():
+    """One scale array for K and V, [NB, 2, Hkv] beside the pages' leading
+    axes: the kernel reads K's half and V's half of it, each its own
+    (swapping the halves of the scales swaps what the two dots see)."""
+    q, kv, tb, off, mask, *_ = _pool_case(offs=[4, 19], T=1, H=4, Hkv=2, hd=16)
+    kvq, sc = _quantize_pool(kv.at[:, 1].multiply(3.0))  # V's scales are not K's
+    assert sc.shape == kvq.shape[:3]
+    want = ragged_paged_attention(q, kvq, tb, off, scale=sc)
+    kg, vg = _gathered(jnp.asarray(kvq, jnp.float32) * sc[..., None, None], np.asarray(tb))
+    _assert_close(want, _dense_ref(q, kg, vg, mask))
+    swapped = ragged_paged_attention(q, kvq, tb, off, scale=sc[:, ::-1])
+    assert not np.allclose(np.asarray(swapped), np.asarray(want), atol=1e-3)
 
 
 def test_ragged_bf16_storage_f32_accumulation():
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=[10], T=1, H=4, Hkv=2, hd=16, seed=8, dtype=jnp.bfloat16
     )
-    out = ragged_paged_attention(q, kp, vp, tb, off)
+    out = ragged_paged_attention(q, kv, tb, off)
     assert out.dtype == jnp.bfloat16
     want = _dense_ref(
         q.astype(jnp.float32), kg.astype(jnp.float32),
@@ -367,7 +379,7 @@ def test_ragged_tiles_match_dense(case):
     dtype = jnp.dtype(c.get("dtype", "float32"))
     need = max(-(-(o + T) // BS) for o in offs)
     assert need <= MB, "the case's rows must fit its table"
-    q, kp, vp, tb, off, mask, kg, vg = _pool_case(
+    q, kv, tb, off, mask, kg, vg = _pool_case(
         offs=offs, T=T, H=Hkv * G, Hkv=Hkv, hd=hd, BS=BS,
         extra_tables=MB - need, seed=len(case), dtype=dtype,
     )
@@ -385,22 +397,18 @@ def test_ragged_tiles_match_dense(case):
     cfg = replace(CFG, attn_logit_softcap=c.get("softcap", 0.0))
     kw = dict(logit_softcap=c.get("softcap", 0.0))
     if c.get("int8"):
-        kq, ks, vq, vs = _quantize_pool(kp, vp)
-        kw.update(k_scale=ks, v_scale=vs)
+        kv, sc = _quantize_pool(kv)
+        kw.update(scale=sc)
         # the dense view over the DEQUANTIZED pool, gathered like _pool_case
-        B, S = tb.shape[0], MB * BS
-        kdq = jnp.asarray(kq, jnp.float32) * ks[:, :, None, None]
-        vdq = jnp.asarray(vq, jnp.float32) * vs[:, :, None, None]
-        kg = jnp.transpose(kdq[:, mapped], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
-        vg = jnp.transpose(vdq[:, mapped], (1, 2, 3, 0, 4)).reshape(B, S, Hkv, hd)
-        kp, vp = kq, vq
+        kg, vg = _gathered(
+            jnp.asarray(kv, jnp.float32) * sc[..., None, None], mapped)
 
-    def run(q, kp, vp, tb, off, win):
-        return ragged_paged_attention(q, kp, vp, tb, off, window=win, **kw)
+    def run(q, kv, tb, off, win):
+        return ragged_paged_attention(q, kv, tb, off, window=win, **kw)
 
     if c.get("jit"):
         run = jax.jit(run)
-    out = run(q, kp, vp, tb, off, jnp.full((1,), window, jnp.int32))
+    out = run(q, kv, tb, off, jnp.full((1,), window, jnp.int32))
     assert out.shape == (len(offs), T, Hkv * G * hd) and out.dtype == dtype
     assert np.isfinite(np.asarray(out, np.float32)).all()
     # a retired row contributes no work item: its output block is zeroed
@@ -422,19 +430,28 @@ def test_tile_plan_follows_shapes_within_vmem_budget():
     from bee2bee_tpu.ops import ragged
 
     plan = ragged._tile_plan
-    # phi-3-mini decode: all 32 MHA heads, 8 pages a step (4 MB of K/V
-    # buffers), int8 pages the same; its 2048-row prefill chunk: fewer
-    # heads so that q, scores and accumulators fit, q rows 256
-    assert plan(32, 1, 1, 96, 16, 32, 2, False) == (32, 8, 8)
-    assert plan(32, 1, 1, 96, 16, 32, 2, True) == (32, 8, 8)
-    assert plan(32, 1, 2048, 96, 16, 128, 2, False) == (4, 16, 256)
+    # phi-3-mini decode: all 32 MHA heads, 4 pages a step (a page operand is
+    # K beside V of every head: 256 KB, 1 MB a step; my chip runs, PR 44), int8
+    # pages the same; its 2048-row prefill chunk: fewer heads so that q, scores
+    # and accumulators fit, q rows 256, and as many pages as 512 keys hold
+    assert plan(32, 1, 1, 96, 16, 32, 2, False) == (32, 4, 8)
+    assert plan(32, 1, 1, 96, 16, 32, 2, True) == (32, 4, 8)
+    assert plan(32, 1, 2048, 96, 16, 128, 2, False) == (4, 32, 256)
     # a mistral-7b shard under model:4 (2 KV heads, groups of 4, hd 128):
     # both heads and a LARGER page tile than the MHA plan's
-    assert plan(2, 4, 1, 128, 16, 64, 2, False) == (2, 16, 8)
+    assert plan(2, 4, 1, 128, 16, 64, 2, False) == (2, 32, 8)
+    # smallthinker (GQA 28/4 x 128, a 1,024-page table): 32 pages of 32 KB a
+    # step, decode and the 2,048 chunk alike; falcon-h1's 20/4 the same;
+    # joyai's latent row (reckoned as if it had a V) keeps 16
+    assert plan(4, 7, 1, 128, 16, 1024, 2, False) == (4, 32, 8)
+    assert plan(4, 7, 2048, 128, 16, 1024, 2, False) == (4, 32, 256)
+    assert plan(4, 5, 1, 128, 16, 32, 2, False) == (4, 32, 8)
+    assert plan(1, 32, 1, 640, 16, 32, 2, False) == (1, 16, 32)
     # a table narrower than a tile takes the table (pow2 ceiling)
     assert plan(32, 1, 1, 96, 16, 2, 2, False)[1] == 2
     assert plan(32, 1, 1, 96, 16, 4, 2, False)[1] == 4
     assert plan(2, 4, 1, 128, 16, 5, 2, False)[1] == 8
+    assert plan(2, 4, 1, 128, 16, 20, 2, False)[1] == 32
     assert plan(8, 1, 1, 256, 16, 1, 2, False)[1] == 1
     for Hkv, G, hd in [(32, 1, 96), (8, 4, 128), (2, 4, 128), (1, 8, 256),
                        (12, 1, 64), (16, 2, 256)]:
@@ -633,10 +650,56 @@ def test_scheduler_counts_live_and_stepped_tiles():
     tables = np.zeros((4, 32), np.int32)
     tables[0, :13], tables[2, :3] = 1, 2  # rows 1 and 3 map no page
     kw = dict(heads=32, group=1, chunk=1, head_dim=128, block_size=16, itemsize=2)
-    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (2 + 1, 4 * 4)
-    assert work_counts(tables, [200, 999, 40, 9], 64, **kw) == (1 + 1, 4 * 4)
+    # 32 MHA heads: tiles of 4 pages = 64 keys, 8 of them across the table
+    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (4 + 1, 4 * 8)
+    assert work_counts(tables, [200, 999, 40, 9], 64, **kw) == (2 + 1, 4 * 8)
     tables[:] = 0
-    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (0, 4 * 4)
+    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (0, 4 * 8)
+
+
+# (Hkv, G, T, hd, MB, offs, window): the cells' shapes at the plan PR 44's
+# chip runs chose (a page ONE operand: 32 pages a step of 4 GQA heads, 4 of
+# 32 MHA heads, a latent row's 16)
+WORK_COUNT_CASES = {
+    "st-decode-window-binds": (4, 7, 1, 128, 1024, [6300, 4100, 9000, 700, 4095], 4096),
+    "st-decode-full-layer": (4, 7, 1, 128, 1024, [6300, 4100, 9000, 700, 4095], 0),
+    "st-chunk-2048-crosses-the-window": (4, 7, 2048, 128, 1024, [4096], 4096),
+    "h1-decode": (4, 5, 1, 128, 32, [100, 499, 31, 250, 0, 64], 0),
+    "phi3-decode": (32, 1, 1, 128, 32, [200, 127, 40, 9, 63, 64], 0),
+    "phi3-long-decode": (32, 1, 1, 128, 128, [1800, 2047, 1023], 0),
+    "phi3-bucket-2048": (32, 1, 2048, 128, 128, [0], 0),
+    "joyai-latent-decode": (1, 32, 1, 640, 32, [100, 499, 31, 250], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORK_COUNT_CASES))
+def test_work_counts_equal_the_devices_item_count_at_the_new_plan(case):
+    """ops/ragged.work_counts (host integers, for engine.kv_tiles) against the
+    work list the call itself builds on the device, under the same tile plan:
+    the items flagged as work and the grid's steps, head groups included; one
+    row of every case is retired (table nulled, offset stale)."""
+    from bee2bee_tpu.ops.ragged import _WORK, _round_up, _tile_plan, _work_list, work_counts
+
+    Hkv, G, T, hd, MB, offs, window = WORK_COUNT_CASES[case]
+    BS, B = 16, len(offs)
+    rng = np.random.default_rng(len(case))
+    tables = np.zeros((B, MB), np.int32)
+    for b, o in enumerate(offs):
+        n = -(-(o + T) // BS)
+        tables[b, :n] = rng.integers(1, 5000, n)
+    if B > 1:
+        tables[1] = 0
+    Th, Tp, bq = _tile_plan(Hkv, G, T, hd, BS, MB, 2, False)
+    n_qblocks = _round_up(G * T, bq) // bq
+    work, _ = _work_list(
+        jnp.asarray(tables), jnp.asarray(offs, jnp.int32), jnp.int32(window),
+        chunk=T, block_q=bq, n_qblocks=n_qblocks, tile_pages=Tp, block_size=BS)
+    flags = np.asarray(work[2])
+    groups = Hkv // Th
+    got = work_counts(tables, offs, window, heads=Hkv, group=G, chunk=T,
+                      head_dim=hd, block_size=BS, itemsize=2)
+    assert got == (groups * int((flags & _WORK != 0).sum()), groups * len(flags))
+    assert 0 < got[0] < got[1]
 
 
 # ------------------------------------- the pool written and read in place
@@ -644,8 +707,10 @@ def test_scheduler_counts_live_and_stepped_tiles():
 
 def _scatter_write(pool, new, tables, off, layer, floor, ceil):
     """core.forward's kv_hook scatter (the dense readers' write, and the
-    page-write's specification), on one layer of a stacked pool."""
-    BS = pool.shape[3]
+    page-write's specification), on one layer of a stacked pool: ``pool``
+    [L, NB, *parts, BS, hd] and ``new`` [B, T, *parts, hd], parts (2, Hkv)
+    or a latent row's (1,)."""
+    BS = pool.shape[-2]
     positions = off[:, None] + jnp.arange(new.shape[1], dtype=jnp.int32)[None]
     blk = jnp.take_along_axis(tables, positions // BS, axis=1)
     slot = positions % BS
@@ -653,13 +718,14 @@ def _scatter_write(pool, new, tables, off, layer, floor, ceil):
         blk = jnp.where(positions >= floor, blk, 0)
     if ceil is not None:
         blk = jnp.where(positions < ceil, blk, 0)
-    newT = jnp.transpose(new, (2, 0, 1, 3))
+    parts = (slice(None),) * (pool.ndim - 4)
     return pool.at[layer].set(
-        pool[layer].at[:, blk, slot].set(newT.astype(pool.dtype))
+        pool[layer].at[(blk, *parts, slot)].set(new.astype(pool.dtype))
     )
 
 
-# offs, T (BS = 16, tables 8 wide); floor / ceil as core.forward takes them
+# offs, T (BS = 16, tables 8 wide); floor / ceil as core.forward takes them.
+# ONE call stores K and V (PR 44): `new` is the chunk's K beside its V
 PAGE_WRITE_CASES = {
     "decode-slot-mid-page": dict(offs=[5, 16, 31, 100], T=1),
     "spec-verify-straddles-a-page-edge": dict(offs=[5, 12, 31, 90], T=7),
@@ -671,53 +737,61 @@ PAGE_WRITE_CASES = {
     "dead-row": dict(offs=[5, 16, 31, 100], T=1, dead=(2,)),
     "chunk-runs-off-the-table": dict(offs=[125, 120], T=7),
     "gqa-20-4-x128": dict(offs=[3, 47, 64, 1], T=1, Hkv=4, hd=128),
+    "gqa-28-4-x128-chunk-off-a-page-edge": dict(offs=[37], T=64, Hkv=4, hd=128),
     "mha-x96": dict(offs=[3, 47], T=7, Hkv=32, hd=96, dtype=jnp.bfloat16),
     # a lane-aligned pool (core.init_paged_pool): 96 stored in 128 lanes
     "mha-x96-in-128-lanes": dict(offs=[3, 47], T=7, Hkv=8, hd=96, lanes=128),
     "decode-x64-in-128-lanes": dict(offs=[5, 16, 31, 100], T=1, hd=64, lanes=128),
-    # the latent pool (MLA): ONE 576-wide row a token on a unit head axis,
+    # the latent pool (MLA): ONE 576-wide row a token on a unit axis,
     # stored in 640 lanes; every other layer's slice keeps its bits
     "latent-x576-in-640-lanes": dict(
-        offs=[5, 16, 31, 100], T=1, Hkv=1, hd=576, lanes=640, dtype=jnp.bfloat16),
+        offs=[5, 16, 31, 100], T=1, latent=True, hd=576, lanes=640, dtype=jnp.bfloat16),
     "latent-x576-prefill-chunk": dict(
-        offs=[37], T=64, ceil=37 + 50, Hkv=1, hd=576, lanes=640, dtype=jnp.bfloat16),
+        offs=[37], T=64, ceil=37 + 50, latent=True, hd=576, lanes=640,
+        dtype=jnp.bfloat16),
 }
 
 
 @pytest.mark.parametrize("case", sorted(PAGE_WRITE_CASES))
 def test_page_write_matches_scatter_bit_for_bit(case):
-    """The Mosaic page-write stores exactly what kv_hook's scatter stores,
-    in every block a row owns, and touches no other layer. (The null block
-    0 is garbage by contract: the scatter dumps refused positions there,
-    the page-write only what a dead row's all-null table sends.)"""
+    """The ONE Mosaic page-write a layer stores exactly what kv_hook's
+    scatter stores, K's half and V's half of every block a row owns, and
+    touches no other layer. (The null block 0 is garbage by contract: the
+    scatter dumps refused positions there, the page-write only what a dead
+    row's all-null table sends.)"""
     c = dict(PAGE_WRITE_CASES[case])
     offs, T = c.pop("offs"), c.pop("T")
-    Hkv, hd = c.pop("Hkv", 4), c.pop("hd", 64)
+    hd = c.pop("hd", 64)
+    parts = (1,) if c.pop("latent", False) else (2, c.pop("Hkv", 4))
     dtype, dead = c.pop("dtype", jnp.float32), c.pop("dead", ())
     floor, ceil, lanes = c.get("floor"), c.get("ceil"), c.get("lanes", hd)
     B, MB, BS, L, layer = len(offs), 8, 16, 3, 1
     rng = np.random.default_rng(7)
-    pool = jnp.asarray(rng.standard_normal((L, Hkv, 1 + B * MB, BS, lanes)), dtype)
+    pool = jnp.asarray(rng.standard_normal((L, 1 + B * MB, *parts, BS, lanes)), dtype)
     pool = pool.at[..., hd:].set(0)  # pad lanes hold zeros, and keep them
-    new = jnp.asarray(rng.standard_normal((B, T, Hkv, hd)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((B, T, *parts, hd)), jnp.float32)
     tables = np.arange(1, 1 + B * MB, dtype=np.int32).reshape(B, MB)
     for b in dead:
         tables[b] = 0
     tables, off = jnp.asarray(tables), jnp.asarray(offs, jnp.int32)
 
     want = _scatter_write(
-        pool, jnp.pad(new, ((0, 0),) * 3 + ((0, lanes - hd),)),
+        pool, jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, lanes - hd),)),
         tables, off, layer, floor, ceil,
     )
     got = jax.jit(paged_kv_write)(
         pool, new, tables, off, jnp.int32(layer), floor, ceil
     )
     want, got, was = (np.asarray(x, np.float32) for x in (want, got, pool))
-    assert np.array_equal(got[:, :, 1:], want[:, :, 1:])
+    assert np.array_equal(got[:, 1:], want[:, 1:])
     if not dead:  # a dead row's table IS the null block: it writes there
-        assert np.array_equal(got[:, :, 0], was[:, :, 0])
+        assert np.array_equal(got[:, 0], was[:, 0])
     if not dead and (floor is None or floor < max(offs) + T):
         assert not np.array_equal(got[layer], was[layer]), "nothing was written"
+        if len(parts) == 2:  # K's half and V's half both took their own rows
+            for half in (0, 1):
+                assert not np.array_equal(got[layer][:, half], was[layer][:, half])
+            assert not np.array_equal(got[layer][:, 0], got[layer][:, 1])
     assert chunk_pages(T, BS) == max(
         (o % BS + T - 1) // BS + 1 for o in range(BS)
     )
@@ -727,27 +801,85 @@ def test_page_write_matches_scatter_bit_for_bit(case):
 def test_stacked_pool_read_matches_sliced_read(layer):
     """The kernel handed the stacked pool and a layer index makes the
     copies it makes from that layer's slice: same bits out."""
-    q, kp, vp, tb, off, *_ = _pool_case(
+    q, kv, tb, off, *_ = _pool_case(
         offs=[0, 7, 8, 21], T=2, H=4, Hkv=2, hd=16, extra_tables=2
     )
     rng = np.random.default_rng(11)
-    ks, vs = (
-        jnp.asarray(rng.standard_normal((3, *one.shape)), one.dtype).at[layer].set(one)
-        for one in (kp, vp)
-    )
-    want = ragged_paged_attention(q, kp, vp, tb, off)
-    got = jax.jit(ragged_paged_attention)(q, ks, vs, tb, off, layer=jnp.int32(layer))
+    stacked = jnp.asarray(
+        rng.standard_normal((3, *kv.shape)), kv.dtype).at[layer].set(kv)
+    want = ragged_paged_attention(q, kv, tb, off)
+    got = jax.jit(ragged_paged_attention)(q, stacked, tb, off, layer=jnp.int32(layer))
     assert np.array_equal(np.asarray(got), np.asarray(want))
-    with pytest.raises(ValueError, match="stacked pool"):
-        ragged_paged_attention(q, ks, vs, tb, off)
-    with pytest.raises(ValueError, match="stacked pool"):
-        ragged_paged_attention(q, kp, vp, tb, off, layer=0)
+    with pytest.raises(ValueError, match="stacked leaf"):
+        ragged_paged_attention(q, stacked, tb, off)
+    with pytest.raises(ValueError, match="stacked leaf"):
+        ragged_paged_attention(q, kv, tb, off, layer=0)
     # the same pool stored lane-aligned (16 -> 128 lanes, zeros beyond):
     # q is padded with zeros, the output cut back - the same numbers
-    aligned = [jnp.pad(x, ((0, 0),) * 4 + ((0, 112),)) for x in (ks, vs)]
-    got = jax.jit(ragged_paged_attention)(q, *aligned, tb, off, layer=jnp.int32(layer))
+    aligned = jnp.pad(stacked, ((0, 0),) * 5 + ((0, 112),))
+    got = jax.jit(ragged_paged_attention)(q, aligned, tb, off, layer=jnp.int32(layer))
     assert got.shape == want.shape
     _assert_close(got, want, atol=1e-6)
+
+
+# GQA 28/4 x 128 with a window of 24 keys (binding and not), MHA x 96 on a
+# lane-aligned pool; offs are q[:, 0]'s positions, pages of 8
+OLD_FORM_CASES = {
+    "gqa28x4-hd128-decode-window-binds-and-not": dict(
+        H=28, Hkv=4, hd=128, T=1, offs=[70, 9, 23, 24], window=24),
+    "gqa28x4-hd128-chunk-crosses-the-window": dict(
+        H=28, Hkv=4, hd=128, T=16, offs=[20, 0], window=24),
+    "mha8-hd96-in-128-lanes": dict(
+        H=8, Hkv=8, hd=96, T=3, offs=[40, 5], window=0, lanes=128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OLD_FORM_CASES))
+def test_head_major_pair_at_the_door_reads_what_the_kv_leaf_reads(case):
+    """make_ragged_attn_fn's attn still takes a stacked head-major K pool and
+    V pool with ``layer=`` (the benchmark's window_read and older callers
+    build them by hand): it lays them page-major inside the caller's jit and
+    reads them through the SAME kernel — bit for bit what the served form
+    (the ``kv`` leaf as ``k``, no ``v``) gives, and both equal the dense
+    float32 reference."""
+    c = OLD_FORM_CASES[case]
+    H, Hkv, hd, T, window = c["H"], c["Hkv"], c["hd"], c["T"], c["window"]
+    lanes = c.get("lanes", hd)
+    q, kv, tb, off, mask, kg, vg = _pool_case(
+        offs=c["offs"], T=T, H=H, Hkv=Hkv, hd=hd, extra_tables=3, seed=len(case))
+    L, layer = 3, 1
+    rng = np.random.default_rng(5)
+    stacked = jnp.asarray(rng.standard_normal((L, *kv.shape)), kv.dtype).at[layer].set(kv)
+    stacked = jnp.pad(stacked, ((0, 0),) * 5 + ((0, lanes - hd),))
+    cfg = replace(CFG, n_heads=H, n_kv_heads=Hkv, head_dim_override=hd)
+    attn = make_ragged_attn_fn(None)
+    positions = off[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+    win = jnp.full((1,), window, jnp.int32)
+
+    @jax.jit
+    def served(q, pool):
+        return attn(q, pool, None, win, cfg, positions=positions, block_tables=tb,
+                    layer=jnp.int32(layer))
+
+    @jax.jit
+    def door(q, k_pool, v_pool):
+        return attn(q, k_pool, v_pool, win, cfg, positions=positions,
+                    block_tables=tb, layer=jnp.int32(layer))
+
+    want = served(q, stacked)
+    k_pool, v_pool = _head_major(stacked)
+    assert k_pool.shape == (L, Hkv, kv.shape[0], 8, lanes)
+    got = door(q, k_pool, v_pool)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    if window:
+        S = mask.shape[-1]
+        q_pos = np.asarray(positions)
+        mask = mask & jnp.asarray(
+            np.arange(S)[None, None, :] > (q_pos[:, :, None] - window))
+        # the window binds for some queries (key 0 is behind it) and not
+        # for others
+        assert (q_pos >= window).any() and not (q_pos >= window).all()
+    _assert_close(want, _dense_ref(q, kg, vg, mask, cfg), atol=5e-5)
 
 
 def test_forward_writes_and_reads_the_stacked_pool_in_place():
@@ -763,6 +895,8 @@ def test_forward_writes_and_reads_the_stacked_pool_in_place():
             np.random.default_rng(3).standard_normal(a.shape), a.dtype),
         core.init_paged_pool(cfg, 2 * MB + 1, BS, jnp.float32),
     )
+    assert set(pool0) == {"kv"} and pool0["kv"].shape == (
+        3, 2 * MB + 1, 2, cfg.n_kv_heads, BS, cfg.head_dim)
     tables = jnp.arange(1, 2 * MB + 1, dtype=jnp.int32).reshape(2, MB)
     ids = jnp.asarray(np.random.default_rng(4).integers(3, 200, (2, T)), jnp.int32)
     off = jnp.asarray([11, 16], jnp.int32)
@@ -774,17 +908,34 @@ def test_forward_writes_and_reads_the_stacked_pool_in_place():
         ))(params, pool0)
 
     (lg_d, pool_d), (lg_r, pool_r) = run(None), run(make_ragged_attn_fn())
-    for name in ("k", "v"):
-        d, r = np.asarray(pool_d[name]), np.asarray(pool_r[name])
-        assert np.array_equal(r[0][:, 1:], d[0][:, 1:])
-        np.testing.assert_allclose(r[:, :, 1:], d[:, :, 1:], atol=1e-4)
+    d, r = np.asarray(pool_d["kv"]), np.asarray(pool_r["kv"])
+    assert np.array_equal(r[0][1:], d[0][1:])
+    np.testing.assert_allclose(r[:, 1:], d[:, 1:], atol=1e-4)
     np.testing.assert_allclose(np.asarray(lg_r), np.asarray(lg_d), atol=2e-4)
 
 
+def test_forward_issues_one_page_write_call_a_layer():
+    """The served program as traced: ONE aliased page-write and one read a
+    layer (the two Mosaic calls), where K and V were written by two."""
+    cfg = replace(CFG, n_layers=2)
+    params = jax.eval_shape(
+        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
+    pool = jax.eval_shape(lambda: core.init_paged_pool(cfg, 9, 8, jnp.float32))
+    tables = jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4)
+    ids = jnp.zeros((2, 1), jnp.int32)
+    text = str(jax.make_jaxpr(lambda p, c: core.forward(
+        p, cfg, ids, c, jnp.asarray([3, 9], jnp.int32),
+        attn_fn=make_ragged_attn_fn(), block_tables=tables,
+    ))(params, pool))
+    # the layer loop is one scan body: its two kernel calls are the program's
+    assert text.count("pallas_call[") == 2, text.count("pallas_call[")
+    assert text.count("input_output_aliases=((5, 0),)") == 1  # the write
+
+
 def test_scheduler_counts_written_pages():
-    """engine.kv_pages_written: rows x the pages a chunk can touch x the
-    K and V write calls of every layer of every forward a dispatch runs;
-    nothing on the dense reader's scatter path."""
+    """engine.kv_pages_written: rows x the pages a chunk can touch x the ONE
+    write call of every layer of every forward a dispatch runs (a page holds
+    K beside V); nothing on the dense reader's scatter path."""
     from bee2bee_tpu.engine import EngineConfig, InferenceEngine
     from bee2bee_tpu.metrics import get_registry
 
@@ -813,7 +964,7 @@ def test_scheduler_counts_written_pages():
             eng.generate(list(range(3, 23)), max_new_tokens=12, temperature=0.0)
         finally:
             eng.close()
-        return written.value() - before, sum(want) * 2 * eng.model_cfg.n_layers
+        return written.value() - before, sum(want) * eng.model_cfg.n_layers
 
     got, want = serve("flash")
     assert got == want > 0
@@ -831,15 +982,16 @@ def test_pool_is_lane_aligned_only_where_the_kernels_own_it_on_a_tpu():
         cfg = replace(get_config(model), n_layers=1)
         shapes = jax.eval_shape(
             lambda cfg=cfg: core.init_paged_pool(cfg, 4, 16, lane_aligned=True))
-        assert shapes["k"].shape == shapes["v"].shape == (1, cfg.n_kv_heads, 4, 16, lanes)
+        assert set(shapes) == {"kv"}  # ONE leaf: K beside V, page-major
+        assert shapes["kv"].shape == (1, 4, 2, cfg.n_kv_heads, 16, lanes)
         plain = jax.eval_shape(lambda cfg=cfg: core.init_paged_pool(cfg, 4, 16))
-        assert plain["k"].shape[-1] == cfg.head_dim
+        assert plain["kv"].shape[-1] == cfg.head_dim
     kw = dict(max_seq_len=128, max_batch=2, decode_chunk=4, kv_block_size=8)
     for attention in ("flash", "dense"):
         eng = InferenceEngine("tiny-llama", engine_config=EngineConfig(
             attention=attention, **kw))
         try:
-            assert eng.new_pool()["k"].shape[-1] == eng.model_cfg.head_dim
+            assert eng.new_pool()["kv"].shape[-1] == eng.model_cfg.head_dim
         finally:
             eng.close()
 
@@ -1030,21 +1182,25 @@ def test_latent_read_matches_the_dense_path(T, offs, stacked, dims):
     table tails; on one layer's slice and on the stacked pool; and at the
     published widths (576-wide rows, values the first 512, 32 heads)."""
     (W, R, H), BS = dims, 8
-    q, kp, _, tables, offs_, mask, kg, _ = _pool_case(
+    q, kv, tables, offs_, mask, kg, _ = _pool_case(
         offs, T, H, 1, W, BS=BS, extra_tables=2, seed=11)
+    rows = kv[:, :1, 0]  # [NB, 1, BS, W]: a layer of the latent leaf
     scale = 0.21
     want = core._latent_attention(q, kg[:, :, 0], mask[:, None], R, scale)
     if stacked:
-        pool = jnp.stack([jnp.zeros_like(kp), kp, jnp.ones_like(kp)])
-        got = ragged_paged_attention(q, pool, None, tables, offs_, sm_scale=scale,
+        pool = jnp.stack([jnp.zeros_like(rows), rows, jnp.ones_like(rows)])
+        got = ragged_paged_attention(q, pool, tables, offs_, sm_scale=scale,
                                      v_width=R, layer=jnp.int32(1))
     else:
-        got = ragged_paged_attention(q, kp, None, tables, offs_, sm_scale=scale, v_width=R)
+        got = ragged_paged_attention(q, rows, tables, offs_, sm_scale=scale, v_width=R)
     assert got.shape == (len(offs), T, H * R)
     _assert_close(got.reshape(want.shape), want)
 
 
-def test_latent_read_takes_no_v_pool_and_no_scales():
-    q, kp, vp, tables, offs_, *_ = _pool_case([3], 1, 2, 1, 16)
+def test_latent_read_takes_no_kv_leaf_and_no_scales():
+    q, kv, tables, offs_, *_ = _pool_case([3], 1, 2, 1, 16)
     with pytest.raises(ValueError, match="latent rows"):
-        ragged_paged_attention(q, kp, vp, tables, offs_, v_width=8)
+        ragged_paged_attention(q, kv, tables, offs_, v_width=8)
+    with pytest.raises(ValueError, match="latent rows"):
+        ragged_paged_attention(q, kv[:, :1, 0], tables, offs_, v_width=8,
+                               scale=jnp.ones(kv.shape[:1] + (1,)))

@@ -695,3 +695,38 @@ def test_paged_composes_with_flash_and_auto():
     )
     assert auto.engine_cfg.attention == "dense"
     auto.close()
+
+
+# ------------------------------------------- the stored layout (PR 44)
+
+
+@pytest.mark.parametrize("model,cache_dtype,attention", [
+    ("tiny-llama", "float32", "dense"), ("tiny-llama", "float32", "flash"),
+    ("tiny-llama", "int8", "flash"), ("tiny-gemma", "float32", "flash"),
+])
+def test_the_pool_is_one_page_major_leaf_and_the_boot_record_is_what_it_was(
+        model, cache_dtype, attention):
+    """Every engine's pool is ONE leaf, page-major, the block axis at 1:
+    ``kv`` [L, NB, 2, Hkv, BS, hd] (+ the int8 pool's ``kv_scale``
+    [L, NB, 2, Hkv]), whatever reads it; what the node publishes about it
+    (``engine.info["kv"]``, the migration signature, the ledger's component)
+    names K and V as before, so peers and dashboards see no change."""
+    eng = InferenceEngine(model, engine_config=EngineConfig(
+        **{**KW, "cache_dtype": cache_dtype}, attention=attention, kv_block_size=8))
+    try:
+        cfg, pool = eng.model_cfg, eng.new_pool()
+        L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        assert set(pool) == {"kv"} | ({"kv_scale"} if cache_dtype == "int8" else set())
+        assert pool["kv"].shape == (L, eng.pool_blocks, 2, Hkv, 8, hd)
+        assert str(pool["kv"].dtype) == cache_dtype
+        if cache_dtype == "int8":
+            assert pool["kv_scale"].shape == (L, eng.pool_blocks, 2, Hkv)
+        kv = eng.info["kv"]
+        assert kv["layout"] == {"k": [Hkv, hd], "v": [Hkv, hd]}
+        assert kv["bytes_per_token"] == 2 * L * Hkv * hd * pool["kv"].dtype.itemsize
+        assert eng.migration_signature()["pool_layout"] == kv["layout"]
+        # the same bytes as two head-major arrays took
+        assert pool["kv"].nbytes == 2 * L * Hkv * eng.pool_blocks * 8 * hd * (
+            pool["kv"].dtype.itemsize)
+    finally:
+        eng.close()

@@ -134,12 +134,12 @@ def test_a_group_is_row_for_row_its_single_calls(model, attention):
                     state_g[name][:, g], state[name][:, 0], rtol=2e-4, atol=2e-5)
         for name in pool_g:
             got, want = np.asarray(pool_g[name]), np.asarray(pool_s[name])
-            # block axis 2; block 0 is the null block, where every pad
+            # block axis 1; block 0 is the null block, where every pad
             # position's and the dead row's writes went
-            np.testing.assert_allclose(got[:, :, 1:], want[:, :, 1:], rtol=2e-4, atol=2e-5)
+            np.testing.assert_allclose(got[:, 1:], want[:, 1:], rtol=2e-4, atol=2e-5)
             owned = sorted(blk for b in range(3) for blk in rc.row_blocks[b])
-            free = np.setdiff1d(np.arange(1, got.shape[2]), owned)
-            assert not got[:, :, free].any(), "a write outside the rows' own blocks"
+            free = np.setdiff1d(np.arange(1, got.shape[1]), owned)
+            assert not got[:, free].any(), "a write outside the rows' own blocks"
         if state_g:  # the dead row's slot came back as it went in: zero
             assert not any(np.asarray(a)[:, 2].any() for a in state_g.values())
     finally:
@@ -172,9 +172,9 @@ def test_a_rows_floor_keeps_its_donors_blocks_inside_a_group(attention):
         for name, was in before.items():
             got = np.asarray(pool[name])
             shared = rc.row_blocks[0]
-            np.testing.assert_array_equal(got[:, :, shared], was[:, :, shared])
-            assert got[:, :, own].any() and not was[:, :, own].any()
-            assert got[:, :, rc.row_blocks[2]].any()
+            np.testing.assert_array_equal(got[:, shared], was[:, shared])
+            assert got[:, own].any() and not was[:, own].any()
+            assert got[:, rc.row_blocks[2]].any()
     finally:
         eng.close()
 
@@ -235,7 +235,7 @@ def test_the_boot_warm_up_calls_every_declared_shape_on_dead_rows():
         rc = eng.scheduler.cache
         assert rc.alloc.used_count == 0
         for leaf in rc.pool.values():  # nothing outside the null block
-            assert not np.asarray(leaf)[:, :, 1:].any()
+            assert not np.asarray(leaf)[:, 1:].any()
     finally:
         eng.close()
 

@@ -256,3 +256,48 @@ def test_int8_serving_new_architecture_classes(family):
         ids.append(t)
         want.append(t)
     assert r.token_ids == want
+
+
+def test_quantized_page_write_stores_k_beside_v_in_one_pass():
+    """core._quantized_page_write over the page-major int8 leaf
+    [NB, 2, Hkv, BS, hd] with scales [NB, 2, Hkv] (PR 44: once over 2 x Hkv
+    heads where K and V took a call each): K's half and V's half come out
+    bit for bit what a pass over each half alone gives — scales, requantised
+    pages and the chunk's slots — for a decode token, a chunk off a page
+    edge under a floor and a ceil, and a dead row."""
+    from bee2bee_tpu.models.core import _quantized_page_write
+
+    NB, Hkv, BS, hd = 12, 2, 8, 16
+    rng = np.random.default_rng(0)
+    pool = jnp.asarray(rng.integers(-127, 128, (NB, 2, Hkv, BS, hd)), jnp.int8)
+    scale = jnp.asarray(rng.uniform(0.0, 0.02, (NB, 2, Hkv)), jnp.float32)
+    scale = scale.at[5].set(0.0)  # a freshly recycled block
+    tables = np.asarray([[1, 2, 3, 0], [4, 5, 6, 0], [0, 0, 0, 0]], np.int32)
+    for T, offs, floor, ceil in ((1, [9, 17, 3], None, None),
+                                 (11, [5, 3, 0], [7, 0, 0], [14, 12, 0])):
+        B = len(offs)
+        positions = np.asarray(offs)[:, None] + np.arange(T)[None]
+        blk = np.take_along_axis(tables, positions // BS, axis=1)
+        if floor is not None:
+            blk = np.where(positions >= np.asarray(floor)[:, None], blk, 0)
+            blk = np.where(positions < np.asarray(ceil)[:, None], blk, 0)
+        slot = positions % BS
+        wslot = positions // BS - (np.asarray(offs) // BS)[:, None]
+        x = jnp.asarray(rng.standard_normal((B, T, 2, Hkv, hd)) * [[[1.0]], [[3.0]]],
+                        jnp.float32)  # V's amax is not K's
+        got_pool, got_scale = _quantized_page_write(pool, scale, blk, slot, wslot, x)
+        assert got_pool.shape == pool.shape and got_scale.shape == scale.shape
+        for half in (0, 1):
+            one_pool, one_scale = _quantized_page_write(
+                pool[:, half:half + 1], scale[:, half:half + 1], blk, slot, wslot,
+                x[:, :, half:half + 1])
+            np.testing.assert_array_equal(
+                np.asarray(got_pool[:, half]), np.asarray(one_pool[:, 0]))
+            np.testing.assert_array_equal(
+                np.asarray(got_scale[:, half]), np.asarray(one_scale[:, 0]))
+        live = np.unique(blk[blk > 0])
+        assert (np.asarray(got_scale)[live] >= np.asarray(scale)[live]).all()
+        assert not np.array_equal(np.asarray(got_pool)[live], np.asarray(pool)[live])
+        untouched = np.setdiff1d(np.arange(1, NB), live)
+        np.testing.assert_array_equal(
+            np.asarray(got_pool)[untouched], np.asarray(pool)[untouched])

@@ -49,7 +49,7 @@ def _fill(rc: RowCache, seed=0):
 
 
 def _block(rc: RowCache, b: int) -> dict:
-    return {name: np.asarray(arr[:, :, b]) for name, arr in rc.pool.items()}
+    return {name: np.asarray(arr[:, b]) for name, arr in rc.pool.items()}
 
 
 def test_cover_release_and_release_deferred_while_in_flight():
@@ -213,16 +213,15 @@ def test_int8_pool_zeroes_the_scale_of_a_recycled_block():
         (used,), (other,) = rc.row_blocks[0], rc.row_blocks[1]
         rc.pool = dict(
             rc.pool,
-            k_scale=rc.pool["k_scale"].at[:, :, used].set(2.0).at[:, :, other].set(3.0),
-            v_scale=rc.pool["v_scale"].at[:, :, used].set(2.0).at[:, :, other].set(3.0),
+            kv_scale=rc.pool["kv_scale"].at[:, used].set(2.0).at[:, other].set(3.0),
         )
         rc.release(0)
         rc.cover(2, BS)
         assert rc.row_blocks[2] == [used]  # the free list hands it straight back
-        for name in ("k_scale", "v_scale"):
-            scale = np.asarray(rc.pool[name])
-            assert not scale[:, :, used].any()
-            assert (scale[:, :, other] == 3.0).all()
+        scale = np.asarray(rc.pool["kv_scale"])  # [L, NB, 2, Hkv]: K's and V's
+        assert scale.shape[2] == 2
+        assert not scale[:, used].any()
+        assert (scale[:, other] == 3.0).all()
         rc.release(1)
         rc.release(2)
         _assert_all_free(rc)
@@ -241,7 +240,7 @@ def test_export_import_round_trip_between_lane_aligned_and_plain_pools():
             core.init_paged_pool, eng.model_cfg, eng.pool_blocks, BS,
             jnp.float32, lane_aligned=True,
         ))()
-        assert aligned.pool["k"].shape[-1] == 128 != hd
+        assert aligned.pool["kv"].shape[-1] == 128 != hd
         n = 2 * BS + 5  # 37 positions: three blocks, a 4-wide index
         plain.cover(0, n)
         _fill(plain)
@@ -251,15 +250,19 @@ def test_export_import_round_trip_between_lane_aligned_and_plain_pools():
         aligned.import_row(2, n, sent)
         assert len(aligned.row_blocks[2]) == 3
         assert aligned.tables[2, :3].tolist() == aligned.row_blocks[2]
-        got = np.asarray(aligned.pool["k"])[:, :, aligned.row_blocks[2]]
-        np.testing.assert_array_equal(got[..., :hd], sent["k"])
+        # the stored leaf is page-major, K beside V; the wire is head-major
+        got = np.asarray(aligned.pool["kv"])[:, aligned.row_blocks[2]]
+        for half, name in enumerate(("k", "v")):
+            np.testing.assert_array_equal(
+                got[:, :, half, :, :, :hd].swapaxes(1, 2), sent[name])
         assert not got[..., hd:].any()  # pad lanes stay zero
         nb2, back = aligned.export_row(2, n)  # and out again, cut to size
         assert nb2 == 3
         plain.import_row(1, n, back)
-        for name in ("k", "v"):
+        for half, name in enumerate(("k", "v")):
             np.testing.assert_array_equal(
-                np.asarray(plain.pool[name])[:, :, plain.row_blocks[1]], sent[name]
+                np.asarray(plain.pool["kv"])[:, plain.row_blocks[1], half]
+                .swapaxes(1, 2), sent[name]
             )
         assert plain.export_row(3, 0) == (0, None)  # nothing written yet
         for rc, rows in ((plain, (0, 1)), (aligned, (2,))):
@@ -351,5 +354,60 @@ def test_rebuild_after_a_device_failure_starts_from_nothing(model):
         rc.cover(0, BS)  # and it serves again
         rc.release(0)
         _assert_all_free(rc)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+def test_export_import_keeps_the_head_major_wire_format(cache_dtype):
+    """The stored leaf is page-major with K beside V (PR 44); the pages a row
+    exports are what they were: ``{"k", "v"}`` [L, Hkv, nb, BS, hd] (+
+    ``{"k_scale", "v_scale"}`` [L, Hkv, nb] of an int8 pool), block axis 2,
+    so a peer on either layout takes them. Out, into another cache, and out
+    again: the same tensors, and the importer's stored pages are the
+    exporter's."""
+    eng, src = _cache(cache_dtype=cache_dtype)
+    dst = RowCache(eng, 4)
+    cfg = eng.model_cfg
+    L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    try:
+        n = 2 * BS + 5
+        src.cover(1, n)
+        dst.cover(0, BS)  # the importer's blocks are not the exporter's
+        keys = jax.random.split(jax.random.key(3), len(src.pool))
+        src.pool = {
+            name: (jax.random.randint(k, arr.shape, -100, 100).astype(arr.dtype)
+                   if arr.dtype == jnp.int8
+                   else jax.random.uniform(k, arr.shape, jnp.float32, 0.5, 2.0))
+            for k, (name, arr) in zip(keys, src.pool.items())
+        }
+        assert src.pool["kv"].shape == (L, eng.pool_blocks, 2, Hkv, BS, hd)
+        nb, sent = src.export_row(1, n)
+        names = {"k", "v"} | ({"k_scale", "v_scale"} if cache_dtype == "int8" else set())
+        assert nb == 3 and set(sent) == names
+        assert sent["k"].shape == sent["v"].shape == (L, Hkv, 3, BS, hd)
+        if cache_dtype == "int8":
+            assert sent["k_scale"].shape == sent["v_scale"].shape == (L, Hkv, 3)
+            assert sent["k"].dtype == np.int8 and sent["k_scale"].dtype == np.float32
+        # the wire's K is the stored pages' first half, its V the second
+        stored = np.asarray(src.pool["kv"])[:, src.row_blocks[1]]
+        np.testing.assert_array_equal(sent["k"], stored[:, :, 0].swapaxes(1, 2))
+        np.testing.assert_array_equal(sent["v"], stored[:, :, 1].swapaxes(1, 2))
+        assert not np.array_equal(sent["k"], sent["v"])
+        dst.import_row(2, n, sent)
+        assert dst.row_blocks[2] != src.row_blocks[1]
+        for name in src.pool:
+            np.testing.assert_array_equal(
+                np.asarray(dst.pool[name])[:, dst.row_blocks[2]],
+                np.asarray(src.pool[name])[:, src.row_blocks[1]])
+        nb2, back = dst.export_row(2, n)
+        assert nb2 == 3 and set(back) == names
+        for name in names:
+            np.testing.assert_array_equal(back[name], sent[name])
+        src.release(1)
+        dst.release(0)
+        dst.release(2)
+        _assert_all_free(src)
+        _assert_all_free(dst)
     finally:
         eng.close()

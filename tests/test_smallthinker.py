@@ -103,7 +103,8 @@ def test_prefill_in_chunks_then_decode_through_the_paged_pool(params, pieces, re
     attn = make_ragged_attn_fn() if reader == "ragged" else None
     BS, lens = 8, [37, 61, 0, 29]
     pool = core.init_paged_pool(CFG, 40, BS, jnp.float32)
-    assert set(pool) == {"k", "v"}
+    assert set(pool) == {"kv"} and pool["kv"].shape == (
+        CFG.n_layers, 40, 2, CFG.n_kv_heads, BS, CFG.head_dim)
     tables, nxt = np.zeros((4, 16), np.int32), 1
     for b, n in enumerate(lens):
         if n:

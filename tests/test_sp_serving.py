@@ -104,8 +104,10 @@ def test_sp_pool_is_sharded_over_seq():
 
     mesh = _mesh(seq=4)
     cfg = get_config("tiny-llama")
-    assert paged_cache_spec(cfg, mesh, seq_sharded=True)[3] == "seq"
-    assert paged_cache_spec(cfg, mesh)[3] is None
+    # [L, NB, 2, Hkv, BS, hd]: kv heads axis 3, slots axis 4
+    assert tuple(paged_cache_spec(cfg, mesh, seq_sharded=True)) == (
+        None, None, None, "model", "seq", None)
+    assert paged_cache_spec(cfg, mesh)[4] is None
     assert cache_spec(cfg, mesh, seq_sharded=True)[2] == "seq"
     assert cache_spec(cfg, mesh)[2] is None
     eng = InferenceEngine(
@@ -116,9 +118,10 @@ def test_sp_pool_is_sharded_over_seq():
         ),
     )
     pool = eng.new_pool()
-    shard_shape = pool["k"].sharding.shard_shape(pool["k"].shape)
-    # [L, Hkv, NB, BS, hd]: the slot dim is BS/4 per device
-    assert shard_shape[3] == pool["k"].shape[3] // 4
+    shard_shape = pool["kv"].sharding.shard_shape(pool["kv"].shape)
+    # [L, NB, 2, Hkv, BS, hd]: the slot dim is BS/4 per device
+    assert shard_shape[4] == pool["kv"].shape[4] // 4
+    assert shard_shape[:4] == pool["kv"].shape[:4]
     eng.close()
 
 

@@ -87,16 +87,16 @@ def _compiled_text(fn, *args) -> str:
 
 def _ragged_args(sharding, *, B, T, H, Hkv, hd, MB, pool_dtype=jnp.bfloat16,
                  layers=0):
-    """(q, K pool, V pool, tables, offsets): the pool one layer's 4-D slice,
-    or with ``layers`` the stacked pool as the engine stores it on a TPU
-    (lane-aligned: core.init_paged_pool)."""
+    """(q, the ``kv`` pool, tables, offsets): the pool one layer's slice
+    [NB, 2, Hkv, BS, hd], or with ``layers`` the stacked 6-D leaf as the
+    engine stores it on a TPU (lane-aligned: core.init_paged_pool)."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    pool = (Hkv, NB, BS, hd) if not layers else (layers, Hkv, NB, BS, -(-hd // 128) * 128)
+    pool = ((NB, 2, Hkv, BS, hd) if not layers
+            else (layers, NB, 2, Hkv, BS, -(-hd // 128) * 128))
     return (
         sds((B, T, H, hd), jnp.bfloat16),
-        sds(pool, pool_dtype),
         sds(pool, pool_dtype),
         sds((B, MB), jnp.int32),
         sds((B,), jnp.int32),
@@ -159,8 +159,8 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, case):
     window = shape.pop("window", None)
     layer = jnp.int32(1) if shape.get("layers") else None
     text = _compiled_text(
-        lambda q, k, v, t, o: ragged_paged_attention(
-            q, k, v, t, o, window=window, interpret=False, layer=layer),
+        lambda q, kv, t, o: ragged_paged_attention(
+            q, kv, t, o, window=window, interpret=False, layer=layer),
         *_ragged_args(one_chip, **_heads(model), **shape),
     )
     assert "tpu_custom_call" in text
@@ -171,15 +171,15 @@ def test_ragged_kernel_int8_pool_compiles_for_v5e(one_chip, model, B, MB):
     """The quantized pool variant: int8 pages + per-page-per-head f32
     scales riding the scalar-prefetch channel."""
     h = _heads(model)
-    q, k, v, t, o = _ragged_args(
+    q, kv, t, o = _ragged_args(
         one_chip, **h, B=B, T=1, MB=MB, pool_dtype=jnp.int8
     )
-    scale = jax.ShapeDtypeStruct((h["Hkv"], NB), jnp.float32, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((NB, 2, h["Hkv"]), jnp.float32, sharding=one_chip)
     text = _compiled_text(
-        lambda q, k, v, t, o, ks, vs: ragged_paged_attention(
-            q, k, v, t, o, interpret=False, k_scale=ks, v_scale=vs
+        lambda q, kv, t, o, sc: ragged_paged_attention(
+            q, kv, t, o, interpret=False, scale=sc
         ),
-        q, k, v, t, o, scale, scale,
+        q, kv, t, o, scale,
     )
     assert "tpu_custom_call" in text
 
@@ -219,13 +219,13 @@ def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo, shape):
 
     B, T, MB = 8, 1, 8
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    pool = sds((Hkv, NB, BS, hd), jnp.bfloat16, P("model"))
+    pool = sds((NB, 2, Hkv, BS, hd), jnp.bfloat16, P(None, None, "model"))
     text = _compiled_text(
-        lambda q, k, v, tables, positions, window: attn(
-            q, k, v, window, cfg, positions=positions, block_tables=tables
+        lambda q, kv, tables, positions, window: attn(
+            q, kv, None, window, cfg, positions=positions, block_tables=tables
         ),
         sds((B, T, H, hd), jnp.bfloat16, P(None, None, "model", None)),
-        pool, pool,
+        pool,
         sds((B, MB), jnp.int32, P()),
         sds((B, T), jnp.int32, P()),
         sds((1,), jnp.int32, P()),
@@ -254,8 +254,8 @@ PAGE_WRITE_CASES = {
 
 @pytest.mark.parametrize("case", sorted(PAGE_WRITE_CASES))
 def test_page_write_kernel_compiles_for_v5e(one_chip, case):
-    """The write half alone, on a stacked pool of two layers, donated: one
-    Mosaic call, aliased in place."""
+    """The write half alone, on a stacked pool of two layers, donated: ONE
+    Mosaic call for K and V, aliased in place."""
     model, B, T = PAGE_WRITE_CASES[case]
     h = _heads(model)
 
@@ -267,12 +267,21 @@ def test_page_write_kernel_compiles_for_v5e(one_chip, case):
             pool, new, t, o, lay, 3, 2000, interpret=False),
         donate_argnums=(0,),
     ).lower(
-        sds((2, h["Hkv"], 384, BS, h["hd"]), jnp.bfloat16),
-        sds((B, T, h["Hkv"], h["hd"]), jnp.bfloat16),
+        sds((2, 384, 2, h["Hkv"], BS, h["hd"]), jnp.bfloat16),
+        sds((B, T, 2, h["Hkv"], h["hd"]), jnp.bfloat16),
         sds((B, 128), jnp.int32), sds((B,), jnp.int32), sds((), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _custom_calls(compiled.as_text()) == 1
     assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def _custom_calls(text: str, scope: str = "") -> int:
+    """The Mosaic calls of a compiled module (those traced under ``scope``:
+    the ``op_name`` of an instruction's metadata holds its scopes' path)."""
+    return sum(
+        "tpu_custom_call" in ln
+        and re.search(rf'op_name="[^"]*{re.escape(scope)}', ln) is not None
+        for ln in text.splitlines())
 
 
 _HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
@@ -311,7 +320,7 @@ def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
 
 def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
     """(lowered `core.forward` over a donated float pool, elements of one
-    layer's K slice a device): weights and pool are shapes placed by the
+    layer's slice of the pool a device: K and V): weights and pool are shapes placed by the
     engine's own partition rules on ``mesh``, or whole on ``sharding``; the
     pool is allocated as the engine allocates it for this path on a TPU,
     lane-aligned (core.init_paged_pool), and laid out by the device's
@@ -332,7 +341,7 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
     stored = whole if mesh is None else NamedSharding(
         mesh, partition.paged_cache_spec(cfg, mesh))
     params = place(params, mesh and partition.param_shardings(params, mesh, cfg))
-    pool = place(pool, {name: stored for name in pool})  # K and V, or latent rows
+    pool = place(pool, {name: stored for name in pool})  # K beside V, or latent rows
     if cfg.has_ssm:  # the rows' recurrent state rides the same carry
         pool = dict(pool, **place(
             jax.eval_shape(lambda: core.init_ssm_state(cfg, B, jnp.float32))))
@@ -351,7 +360,7 @@ def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
     lowered = jax.jit(step, donate_argnums=(2,)).lower(
         params, ints(B, T), pool, ints(B), ints(B, MB), ints(B), ints(B))
     shard = mesh.shape["model"] if mesh is not None else 1
-    leaf = pool[next(iter(core.pool_layout(cfg)))]
+    (leaf,) = (a for name, a in pool.items() if name in ("kv", "latent"))
     return lowered, int(np.prod(leaf.shape[1:])) // shard
 
 
@@ -362,6 +371,7 @@ IN_PLACE_CASES = {
     "phi-3-mini-decode": ("phi-3-mini", 16, 1, 32, 385),
     "phi-3-mini-prefill-128": ("phi-3-mini", 1, 128, 8, 385),
     "phi-3-mini-prefill-4x128": ("phi-3-mini", 4, 128, 8, 385),  # one group of a burst
+    "phi-3-mini-prefill-2048": ("phi-3-mini", 1, 2048, 128, 385),  # the long cell's bucket
     "falcon-h1-prefill-8x128": ("falcon-h1-34b", 8, 128, 8, 3201),
     "falcon-h1-decode": ("falcon-h1-34b", 64, 1, 32, 3201),
 }
@@ -370,8 +380,9 @@ IN_PLACE_CASES = {
 @pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
 def test_forward_keeps_the_pool_in_place(one_chip, mosaic_state_step, case):
     """No instruction of the program but the two kernels' aliased calls
-    produces an array as large as one layer's pool slice: no slice, no
-    relayout, no select, no write-back in the layer loop, and no relayout
+    produces an array as large as one layer's slice of the 6-D ``kv`` leaf,
+    as its K half or V half alone, or as the leaf: no slice, no copy, no
+    transpose, no select, no write-back in the layer loop, and no relayout
     of the pool where the program is entered and left (phi-3's 96 stored
     in 128 lanes: at 96 the device's default puts the BLOCK axis minor-most
     and every program re-laid the whole pool in and out). The temporaries
@@ -382,10 +393,13 @@ def test_forward_keeps_the_pool_in_place(one_chip, mosaic_state_step, case):
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "while(" in text, "no layer loop in the compiled text"
-    assert text.count("tpu_custom_call") >= 3  # K write, V write, the read
+    # ONE page-write (K beside V) and the read a layer (+ falcon-h1's state step)
+    assert _custom_calls(text) == 2 + (cfg.has_ssm and T == 1)
+    assert _custom_calls(text, "kv.write") == 1
     assert _pool_sized_ops(text, slice_elems, 2) == []
+    assert _pool_sized_ops(text, slice_elems // 2, 2) == []
     analysis = compiled.memory_analysis()
-    assert analysis.alias_size_in_bytes >= 2 * 2 * slice_elems * 2  # K, V in place
+    assert analysis.alias_size_in_bytes >= 2 * slice_elems * 2  # the leaf in place
     if not cfg.has_ssm:  # falcon-h1 re-lays weights of its own (PERF.md)
         assert analysis.temp_size_in_bytes < slice_elems * 2
 
@@ -401,8 +415,9 @@ def test_forward_keeps_the_pool_in_place_under_model_4(topo, B, T):
     lowered, slice_elems = _forward_program(cfg, B, T, 8, NB, mesh=mesh)
     text = lowered.compile().as_text()
     assert "while(" in text, "no layer loop in the compiled text"
-    assert text.count("tpu_custom_call") >= 3
+    assert _custom_calls(text) == 2 and _custom_calls(text, "kv.write") == 1
     assert _pool_sized_ops(text, slice_elems, 2) == []
+    assert _pool_sized_ops(text, slice_elems // 2, 2) == []
 
 
 # ------------------ the recurrent state stepped in place (PR 34, falcon-h1)
@@ -459,7 +474,7 @@ def test_forward_steps_the_state_in_place(one_chip, mosaic_state_step):
     compiled = lowered.compile()
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 4  # K write, V write, the read, the state step
+    assert len(calls) == 3  # the page-write (K beside V), the read, the state step
     assert sum("ssm.step/pallas_call" in ln for ln in calls) == 1
     state_elems = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
     assert _pool_sized_ops(text, state_elems, 2) == []
@@ -502,7 +517,7 @@ def test_latent_write_and_read_compile_for_v5e(one_chip, case):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((2, 1, 3201, BS, 640), jnp.bfloat16)
+    pool = sds((2, 3201, 1, BS, 640), jnp.bfloat16)
     tables, offs, lay = sds((B, MB), jnp.int32), sds((B,), jnp.int32), sds((), jnp.int32)
     wrote = jax.jit(
         lambda pool, new, t, o, lay: paged_kv_write(
@@ -513,7 +528,7 @@ def test_latent_write_and_read_compile_for_v5e(one_chip, case):
     assert wrote.memory_analysis().alias_size_in_bytes > 0
     read = jax.jit(
         lambda q, pool, t, o, lay: ragged_paged_attention(
-            q, pool, None, t, o, interpret=False, layer=lay, v_width=R,
+            q, pool, t, o, interpret=False, layer=lay, v_width=R,
             sm_scale=192 ** -0.5)
     ).lower(sds((B, T, H, W), jnp.bfloat16), pool, tables, offs, lay).compile()
     text = read.as_text()
@@ -584,7 +599,8 @@ def test_forward_keeps_the_latent_pool_and_the_expert_stacks_in_place(
     compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("while(") >= 2, "one layer loop a group of like layers"
-    assert text.count("tpu_custom_call") >= 7  # 2 writes, 2 reads, 3 grouped products
+    assert _custom_calls(text) >= 7  # 2 writes, 2 reads, 3 grouped products
+    assert _custom_calls(text, "mla.write") == 2  # one a group of like layers
     assert _pool_sized_ops(text, slice_elems, 3) == []
     one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
     assert _pool_sized_ops(text, one_matrix, 2) == []
@@ -643,9 +659,14 @@ def test_the_smallthinker_cell_programs_fit_one_chip_at_full_depth(
     assert re.search(r"kv\.write[\w.]* = .*tpu_custom_call.*attn\.write/kv\.write", text), \
         "the page-write under its scope"
     assert re.search(r"attn\.read[\w.]* = .*tpu_custom_call", text), "the read under its scope"
+    # ONE page-write a layer stores K beside V, and one read fetches them
+    assert _custom_calls(text, "kv.write") == 1 and _custom_calls(text, "attn.read") == 1
     one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
     assert _pool_sized_ops(text, one_matrix, cfg.n_layers) == []
+    # no copy, transpose or re-layout of the 6-D leaf, a layer's slice of it,
+    # or a slice's K half or V half, outside the two Mosaic calls
     assert _pool_sized_ops(text, slice_elems, cfg.n_layers) == []
+    assert _pool_sized_ops(text, slice_elems // 2, cfg.n_layers) == []
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= cfg.n_layers * slice_elems * 2  # the pool in place
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
