@@ -55,7 +55,13 @@ from jax import lax
 
 from ..models import config as model_config
 from ..models import core
-from .paged import DROPLESS_ROUTED, LATENT_POOL, RECURRENT_STATE, FeatureUnsupported
+from .paged import (
+    DROPLESS_ROUTED,
+    LATENT_POOL,
+    LOOPED_STACK,
+    RECURRENT_STATE,
+    FeatureUnsupported,
+)
 from .spec import Drafter
 
 
@@ -148,6 +154,10 @@ class DraftModel(Drafter):
             raise FeatureUnsupported(
                 "spec_model_drafter", self.cfg.name,
                 "the drafter's loop is not tested with it", DROPLESS_ROUTED)
+        if self.cfg.loop_steps > 1:
+            raise FeatureUnsupported(
+                "spec_model_drafter", self.cfg.name,
+                "the drafter builds a cut stack and runs it once", LOOPED_STACK)
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

@@ -53,6 +53,7 @@ from ..utils import MetricsAggregator
 from .paged import (
     DROPLESS_ROUTED,
     LATENT_POOL,
+    LOOPED_STACK,
     RECURRENT_STATE,
     FeatureUnsupported,
 )
@@ -329,6 +330,7 @@ class InferenceEngine:
         self._validate_recurrent_features()
         self._validate_latent_features()
         self._validate_dropless_features()
+        self._validate_looped_features()
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -831,6 +833,44 @@ class InferenceEngine:
         if ec.max_adapters > 0:
             refuse("multi_lora", "adapters are not tested with it")
 
+    def _validate_looped_features(self):
+        """Refuse, by name, every configured feature that is not proven for
+        a looped stack (FeatureUnsupported, LOOPED_STACK; cfg.loop_steps > 1:
+        ouro). Pipeline stages refuse in stage_runner, a looped DRAFTER in
+        drafter.py. Chunked prefill, both readers, the prefix cache and the
+        block export / import are tested (tests/test_ouro.py)."""
+        cfg, ec = self.model_cfg, self.engine_cfg
+        if cfg.loop_steps == 1:
+            return
+
+        def refuse(feature, why):
+            raise FeatureUnsupported(feature, cfg.name, why, LOOPED_STACK)
+
+        if jnp.dtype(ec.cache_dtype) == jnp.int8:
+            refuse("kv_int8", "the int8 pool's per-layer slices inside the "
+                   "pass loop are not tested")
+        if ec.quantize == "int8":
+            refuse("weight_int8", "quantised weights read once a pass are "
+                   "not tested")
+        if ec.drafter == "mesh":
+            refuse("spec_mesh_drafter", "the verify forward is not tested "
+                   "with it")
+        if ec.drafter:
+            refuse("spec_model_drafter", "the verify forward is not tested "
+                   "with it")
+        if ec.spec_tokens > 0:
+            refuse("spec_ngram", "the verify forward is not tested with it")
+        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
+            refuse("seq_attention", "the sp path's cache is not indexed by pass")
+        if self.mesh.shape.get("model", 1) > 1:
+            refuse("mesh_model", "the pass loop around a sharded pool is not "
+                   "tested (--mesh-shape model:N)")
+        if self.mesh.shape.get("expert", 1) > 1:
+            refuse("mesh_expert", "it has no experts to place on an expert axis")
+        if ec.max_adapters > 0:
+            refuse("multi_lora", "the adapter stacks are [n_layers, ...] and "
+                   "the pass loop does not hand them round again")
+
     @property
     def state_info(self) -> dict | None:
         """The recurrent state's identity for the boot record (/providers,
@@ -1059,6 +1099,8 @@ class InferenceEngine:
             # lane-aligned pool's arrays hold more (engine.hbm_bytes)
             "layout": {n: list(hw) for n, hw in
                        core.pool_layout(self.model_cfg).items()},
+            # layers of cache a token holds (a looped stack: passes x layers)
+            "cache_layers": int(self.model_cfg.cache_layers),
             "bytes_per_token": core.pool_bytes_per_token(
                 self.model_cfg,
                 jnp.dtype(self.engine_cfg.cache_dtype).itemsize),
@@ -1422,6 +1464,8 @@ class InferenceEngine:
         return {
             "model": cfg.name,
             "n_layers": cfg.n_layers,
+            # what a block's tensors are deep: a looped stack's passes too
+            "cache_layers": cfg.cache_layers,
             "n_kv_heads": cfg.n_kv_heads,
             "head_dim": cfg.head_dim,
             "pool_layout": {n: list(hw) for n, hw in
@@ -1513,12 +1557,12 @@ class InferenceEngine:
             nb = ceil_div(offset, self.engine_cfg.kv_block_size)
             cache_dt = jnp.dtype(self.engine_cfg.cache_dtype)
             want = {
-                name: ((cfg.n_layers, heads, nb,
+                name: ((cfg.cache_layers, heads, nb,
                         self.engine_cfg.kv_block_size, width), cache_dt)
                 for name, (heads, width) in core.pool_layout(cfg).items()
             }
             if self.kv_quantized:
-                sshape = (cfg.n_layers, cfg.n_kv_heads, nb)
+                sshape = (cfg.cache_layers, cfg.n_kv_heads, nb)
                 want["k_scale"] = (sshape, jnp.dtype(jnp.float32))
                 want["v_scale"] = (sshape, jnp.dtype(jnp.float32))
             got_names = set(kv) if isinstance(kv, dict) else set()

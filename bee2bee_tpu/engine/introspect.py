@@ -226,7 +226,7 @@ class FlopsModel:
 
         self.matmul_flops_per_pos = 2.0 * matmul_params_per_token(model_cfg)
         self.attn_flops_per_pos_per_ctx = (
-            4.0 * model_cfg.n_layers * model_cfg.n_heads * model_cfg.head_dim
+            4.0 * model_cfg.cache_layers * model_cfg.n_heads * model_cfg.head_dim
         )
 
     def flops(self, positions: float, ctx: float) -> float:

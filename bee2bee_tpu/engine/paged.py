@@ -143,7 +143,7 @@ def best_prefix_key(keys, ids) -> tuple[tuple | None, int]:
 class FeatureUnsupported(ValueError):
     """An engine feature that is not proven for a kind of model was asked for
     with such a model: ``feature`` names it, ``ground`` is the model's
-    property it stumbles on (one of the three below). Raised when the engine,
+    property it stumbles on (one of the four below). Raised when the engine,
     a stage runner or a drafter is built: none of these may be silently
     wrong. RECURRENT_STATE (falcon-h1's Mamba-2 mixer): rollback is not free
     for a recurrence (spec verify), pinned blocks do not hold the state at a
@@ -151,7 +151,8 @@ class FeatureUnsupported(ValueError):
     yet. LATENT_POOL (latent attention caches one [c_kv | k_rope] row a token,
     no per-head K/V: core.pool_layout). DROPLESS_ROUTED (smallthinker: every
     layer a dropless expert layer over a plain K/V pool, its router fed the
-    pre-attention norm)."""
+    pre-attention norm). LOOPED_STACK (ouro: the layers run loop_steps times a
+    token, a layer of cache a (pass, layer): cfg.cache_layers)."""
 
     def __init__(self, feature: str, model: str, why: str, ground: str):
         self.feature, self.ground = feature, ground
@@ -163,6 +164,10 @@ RECURRENT_STATE = "its rows carry recurrent state beside their K/V pages"
 LATENT_POOL = "its rows cache latent rows (no per-head K/V)"
 DROPLESS_ROUTED = ("its every layer is a dropless expert layer routed from "
                    "the pre-attention norm")
+
+
+LOOPED_STACK = ("its layers run several times a token with a cache of "
+                "their own in every pass")
 
 
 class PoolExhausted(RuntimeError):
@@ -652,7 +657,7 @@ class RowCache:
 
             _C_KV_PAGES_WRITTEN.inc(
                 rows * chunk_pages(chunk, self.block_size)
-                * calls * e.model_cfg.n_layers
+                * calls * e.model_cfg.cache_layers
             )
 
     def count_tiles(self, tables, offsets, chunk: int, calls: int = 1):
@@ -683,8 +688,8 @@ class RowCache:
                 quantized=e.kv_quantized,
             )
             live, stepped = live + n * one[0], stepped + n * one[1]
-        _C_KV_TILES.inc(live * calls // cfg.n_layers, kind="live")
-        _C_KV_TILES.inc(stepped * calls // cfg.n_layers, kind="stepped")
+        _C_KV_TILES.inc(live * calls // cfg.cache_layers, kind="live")
+        _C_KV_TILES.inc(stepped * calls // cfg.cache_layers, kind="stepped")
 
     def note_tokens_held(self, contexts):
         """The gauges engine.kv_tokens_held / engine.kv_tokens_behind_window

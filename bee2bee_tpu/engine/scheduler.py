@@ -114,6 +114,13 @@ _C_PREFILL_CALLS = _REG.counter(
     "re-prefill import rung included (bucket label: the chunk's padded "
     "width in tokens)",
 )
+_C_LOOP_PASSES = _REG.counter(
+    "engine.loop_passes",
+    "passes of the layer stack dispatched: device calls x steps a call x "
+    "the model's loop_steps (1 for a plain stack, ouro 4), counted on the "
+    "host at dispatch (kind label: prefill programs | decode and verify "
+    "steps)",
+)
 _C_PREFILL_CHUNKS = _REG.counter(
     "engine.prefill_chunks",
     "of those programs, the chunks of a CHUNKED prompt (one that walks more "
@@ -1396,6 +1403,7 @@ class BatchScheduler:
                 self._moe_pending.append(extras.pop("moe_stats"))
                 self._count_moe(real, pad, 1)
             _C_PREFILL_CALLS.inc(bucket=str(bucket))
+            _C_LOOP_PASSES.inc(e.model_cfg.loop_steps, kind="prefill")
             if group and len(group[0].windows) > 1:
                 _C_PREFILL_CHUNKS.inc()
             _C_PREFILL_ROWS.inc(len(group), kind="live")
@@ -1927,6 +1935,8 @@ class BatchScheduler:
         # extra / calls = the tokens a call writes: 1 a decode step, K+1
         self.cache.count_pages_written(self._bsz, extra // calls, calls)
         self.cache.count_tiles(tables, self._offsets, extra // calls, calls)
+        _C_LOOP_PASSES.inc(
+            calls * self.engine.model_cfg.loop_steps, kind="decode")
         return tables
 
     def _spec_eligible(self, b: int, req: Request) -> bool:

@@ -30,7 +30,13 @@ import numpy as np
 from ..metrics import get_registry
 from ..models import config as model_config
 from ..models import core, stages
-from .paged import DROPLESS_ROUTED, LATENT_POOL, RECURRENT_STATE, FeatureUnsupported
+from .paged import (
+    DROPLESS_ROUTED,
+    LATENT_POOL,
+    LOOPED_STACK,
+    RECURRENT_STATE,
+    FeatureUnsupported,
+)
 
 STALE_CACHE_S = 600.0  # drop request caches untouched this long
 
@@ -89,6 +95,11 @@ class StageRunner:
                 "pipeline_stages", self.model_cfg.name,
                 "a stage's loop reads a layer's experts sliced out of the "
                 "stack and is not tested", DROPLESS_ROUTED)
+        if self.model_cfg.loop_steps > 1:
+            raise FeatureUnsupported(
+                "pipeline_stages", self.model_cfg.name,
+                "a stage's layers would have to come round once a pass",
+                LOOPED_STACK)
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

@@ -168,6 +168,12 @@ class ModelConfig:
     # ("attn_norm": the router sits before attention), not the pre-FFN one
     # the experts read ("ffn_norm"). Dropless expert layers only
     moe_router_input: str = "ffn_norm"  # "ffn_norm" | "attn_norm"
+    # ouro (looped language models): the WHOLE stack of n_layers runs
+    # loop_steps times a token with the same weights (the published
+    # total_ut_steps), the model's one final norm after every pass, and
+    # every pass keeps K/V of its own: cache_layers below is what sizes a
+    # cache, n_layers stays the count of WEIGHT layers. 1 = a plain stack
+    loop_steps: int = 1
 
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
@@ -270,6 +276,16 @@ class ModelConfig:
             )
         if self.mla_kv_rank and self.mla_rope_dim % 2:
             raise ValueError(f"mla_rope_dim={self.mla_rope_dim} must be even")
+        if self.loop_steps < 1 or (self.loop_steps > 1 and (
+                self.ssm_heads or self.mla_kv_rank or self.n_experts
+                or self.sliding_window)):
+            raise ValueError(
+                f"loop_steps={self.loop_steps} must be >= 1, and a looped "
+                "stack (loop_steps > 1) is built for dense full-attention "
+                "blocks only: no recurrent mixer, latent attention, experts "
+                "or sliding window (core.forward's pass loop indexes a "
+                "plain K/V cache by pass and layer)"
+            )
         if not 0 <= self.first_k_dense <= self.n_layers or (
             (self.first_k_dense or self.n_shared_experts or self.d_ff_expert)
             and not self.n_experts
@@ -301,6 +317,15 @@ class ModelConfig:
         return max(2, int(self.head_dim * self.rotary_pct) // 2 * 2)
 
     @property
+    def cache_layers(self) -> int:
+        """Layers of CACHE a token holds: one a (pass, weight layer) of a
+        looped stack, pass ``t``'s layer ``l`` at index ``t * n_layers + l``
+        (core.forward). THE one number that sizes a pool, a rectangular
+        cache, a block's bytes and a cached token's attention work; equal to
+        n_layers for every plain stack."""
+        return self.n_layers * self.loop_steps
+
+    @property
     def layer_windows(self) -> tuple:
         """Every layer's sliding window on host integers (0 = it attends
         fully): core.is_sliding_layer's rule, for what is counted per layer
@@ -308,7 +333,7 @@ class ModelConfig:
         w = int(self.sliding_window or 0)
         return tuple(
             w if i % self.sliding_window_every in self.sliding_window_residues
-            else 0 for i in range(self.n_layers))
+            else 0 for i in range(self.n_layers)) * self.loop_steps
 
     @property
     def is_moe(self) -> bool:
@@ -1004,6 +1029,30 @@ def _joyai_from_hf(d: dict, nm: str) -> ModelConfig:
     )
 
 
+CONFIGS["ouro-2.6b"] = ModelConfig(
+    # ByteDance/Ouro-2.6B config.json (model_type ouro, arXiv:2510.25741):
+    # 48 dense layers, MHA 16 x 128 with RoPE at theta 1e6 over the whole
+    # head, a "sandwich" block (RMSNorm before each branch AND on its
+    # output), SwiGLU 5632 wide, untied head over 49,152 tokens — and the
+    # whole stack runs total_ut_steps = 4 times a token with the same
+    # weights, the final norm after every pass, K/V kept a (pass, layer):
+    # 192 cache layers. The exit gate is not built (early_exit_threshold 1:
+    # no token leaves before the last pass; docs/MODELS.md)
+    name="ouro-2.6b", vocab_size=49152, d_model=2048, n_layers=48,
+    n_heads=16, n_kv_heads=16, d_ff=5632, head_dim_override=128,
+    max_seq_len=65536, rope_theta=1000000.0, norm_eps=1e-6,
+    tie_embeddings=False, post_norms=True, loop_steps=4,
+)
+CONFIGS["tiny-ouro"] = ModelConfig(
+    # the loop at CPU-test size: 3 layers x 3 passes (unlike counts, so a
+    # swapped (pass, layer) index cannot pass by symmetry), 4 heads x 16
+    name="tiny-ouro", vocab_size=512, d_model=64, n_layers=3, n_heads=4,
+    n_kv_heads=4, d_ff=96, head_dim_override=16, max_seq_len=256,
+    rope_theta=10000.0, norm_eps=1e-6, tie_embeddings=False,
+    post_norms=True, loop_steps=3,
+)
+
+
 def _layer_period(sliding: set, n_layers: int, longest: int):
     """(every, residues) of the shortest period, at most ``longest`` layers,
     under which the layers of ``sliding`` are those whose index modulo
@@ -1071,6 +1120,56 @@ def _smallthinker_from_hf(d: dict, nm: str) -> ModelConfig:
         n_experts=d["moe_num_primary_experts"],
         n_experts_per_tok=d["moe_num_active_primary_experts"],
         moe_router="softmax_topk", moe_router_input="attn_norm",
+    )
+
+
+def _ouro_from_hf(d: dict, nm: str) -> ModelConfig:
+    """ouro (ByteDance/Ouro-*; looped language models): a dense sandwich-norm
+    stack run ``total_ut_steps`` times a token. What core does not build is
+    refused BY NAME: leaving the loop early (``early_exit_threshold`` < 1:
+    the exit gate is not built), a sliding window, rope scaling, a layer
+    kind other than full attention."""
+    if float(d.get("early_exit_threshold", 1.0)) < 1.0:
+        raise ValueError(
+            f"ouro config with early_exit_threshold="
+            f"{d['early_exit_threshold']!r} is not implemented (only 1, the "
+            "published setting: every token runs every pass; the exit gate "
+            "that lets a token leave the loop early is not built)"
+        )
+    if d.get("use_sliding_window"):
+        raise ValueError(
+            "ouro config with use_sliding_window=True is not implemented "
+            "(only False, the published setting: a looped stack indexes a "
+            "full-attention cache by pass and layer)"
+        )
+    if d.get("rope_scaling") is not None:
+        raise ValueError(
+            f"ouro config with rope_scaling={d['rope_scaling']!r} is not "
+            "implemented (only null, the published setting)"
+        )
+    odd = sorted({t for t in d.get("layer_types") or [] if t != "full_attention"})
+    if odd:
+        raise ValueError(
+            f"ouro config with layer_types entries {odd} is not implemented "
+            "(only 'full_attention', the published setting)"
+        )
+    if d.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"ouro config with hidden_act={d['hidden_act']!r} is not "
+            "implemented (only 'silu', the published setting)"
+        )
+    H = d["num_attention_heads"]
+    return ModelConfig(
+        name=nm, vocab_size=d["vocab_size"], d_model=d["hidden_size"],
+        n_layers=d["num_hidden_layers"], n_heads=H,
+        n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],
+        head_dim_override=d.get("head_dim") or d["hidden_size"] // H,
+        max_seq_len=d.get("max_position_embeddings", 65536),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        post_norms=True, loop_steps=int(d.get("total_ut_steps", 1)),
     )
 
 
@@ -1371,6 +1470,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
             "smallthinker_"):
         return _smallthinker_from_hf(d, name or d.get("_name_or_path")
                                      or d.get("model_name") or nm)
+    if mt == "ouro":
+        return _ouro_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1539,7 +1640,7 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
-        f"falcon_h1/joyai_llm_flash/smallthinker; "
+        f"falcon_h1/joyai_llm_flash/smallthinker/ouro; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

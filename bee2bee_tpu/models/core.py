@@ -91,7 +91,8 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     # falcon-h1: the mixer's in- and out-projection (the scan itself is
     # O(inner * state) a token — under 1 % of the block — and not counted)
     ssm = D * cfg.ssm_proj_dim + cfg.ssm_inner * D if cfg.has_ssm else 0
-    return L * (attn + ssm) + mlps + D * cfg.vocab_size
+    # a looped stack streams every layer loop_steps times a token, the head once
+    return cfg.loop_steps * (L * (attn + ssm) + mlps) + D * cfg.vocab_size
 
 
 def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
@@ -212,8 +213,17 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
                 # have ln2; only phi's shared-norm parallel blocks drop it
                 layers["ln2"] = {"scale": jnp.ones((L, D), dtype)}
         if cfg.post_norms:  # gemma-2: norms on the attn/mlp outputs too
-            layers["ln1_post"] = {"scale": jnp.ones((L, D), dtype)}
-            layers["ln2_post"] = {"scale": jnp.ones((L, D), dtype)}
+            # a looped stack's SEEDED output norms start at 1 / sqrt(the
+            # branches a token passes): at scale 1 every one of ouro's 384
+            # adds a unit vector, attention's near-uniform average over the
+            # context outweighs a token's own part at once and every row of a
+            # batch emits the SAME greedy token whatever its prompt (PR 46,
+            # on the chip: 4 distinct tokens in 16 rows x 24 steps; at this
+            # scale 84, as unlike prompts should give)
+            post = (1.0 / math.sqrt(2 * cfg.cache_layers)
+                    if cfg.loop_steps > 1 else 1.0)
+            layers["ln1_post"] = {"scale": jnp.full((L, D), post, dtype)}
+            layers["ln2_post"] = {"scale": jnp.full((L, D), post, dtype)}
         if cfg.norm == "layernorm" and cfg.norm_bias:
             for ln in ("ln1", "ln2", "ln1_post", "ln2_post"):
                 if ln in layers:
@@ -598,15 +608,18 @@ def expert_einsum(spec, x, w, s_expand):
 
 
 def _mlp(x, p, cfg: ModelConfig, lora=None):
-    up = lora_matmul(x, p["w_up"], "w_up", lora)
-    if "b_up" in p:
-        up = up + p["b_up"]
-    gate = lora_matmul(x, p["w_gate"], "w_gate", lora) if "w_gate" in p else None
-    gate_mult, down_mult = cfg.mlp_multipliers  # falcon-h1's muP pair
-    if gate is not None and gate_mult != 1.0:
-        gate = gate * jnp.asarray(gate_mult, gate.dtype)
-    h = _activate(up, gate, cfg)
-    out = lora_matmul(h, p["w_down"], "w_down", lora)
+    scope = _scope_if(_stack_scoped(cfg))  # mlp.gate_up / mlp.down
+    with scope("mlp.gate_up"):
+        up = lora_matmul(x, p["w_up"], "w_up", lora)
+        if "b_up" in p:
+            up = up + p["b_up"]
+        gate = lora_matmul(x, p["w_gate"], "w_gate", lora) if "w_gate" in p else None
+        gate_mult, down_mult = cfg.mlp_multipliers  # falcon-h1's muP pair
+        if gate is not None and gate_mult != 1.0:
+            gate = gate * jnp.asarray(gate_mult, gate.dtype)
+        h = _activate(up, gate, cfg)
+    with scope("mlp.down"):
+        out = lora_matmul(h, p["w_down"], "w_down", lora)
     if "b_down" in p:
         out = out + p["b_down"]
     if down_mult != 1.0:
@@ -1340,7 +1353,7 @@ def transformer_block(
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    scope = jax.named_scope if _attn_scoped(cfg) else lambda _: contextlib.nullcontext()
+    scope = _scope_if(_attn_scoped(cfg))
 
     h = x if cfg.no_pre_norms else _norm(x, lp["ln1"], cfg)
     if cfg.has_mla:
@@ -1446,8 +1459,22 @@ def _attn_scoped(cfg: ModelConfig) -> bool:
     the only dropless-expert model over a K/V pool). A scope renames every
     op under it, so the older programs (phi-3, falcon-h1: the benchmark's
     readers and the program store know their op names) stay bare until a
-    tracing PR opens the scopes for all and re-anchors those."""
-    return cfg.moe_dropless and not cfg.has_mla
+    tracing PR opens the scopes for all and re-anchors those. PR 46: a
+    looped stack's too (ouro: a new program)."""
+    return (cfg.moe_dropless and not cfg.has_mla) or _stack_scoped(cfg)
+
+
+def _stack_scoped(cfg: ModelConfig) -> bool:
+    """Do the REST of this model's dense stack run under scopes: the MLP's
+    ``mlp.gate_up`` / ``mlp.down``, the norm between a looped stack's passes
+    ``loop.norm`` and the head ``head.logits``? A looped stack alone (ouro,
+    first built with them): smallthinker's head was built bare and stays so."""
+    return cfg.loop_steps > 1
+
+
+def _scope_if(on: bool):
+    """jax.named_scope, or a context that names nothing."""
+    return jax.named_scope if on else lambda _: contextlib.nullcontext()
 
 
 def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
@@ -1469,7 +1496,12 @@ def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
 
 def final_logits(params: Params, cfg: ModelConfig, x):
     """Final norm + LM head (+softcap), f32 logits."""
-    x = _norm(x, params["final_norm"], cfg)
+    return head_logits(params, cfg, _norm(x, params["final_norm"], cfg))
+
+
+def head_logits(params: Params, cfg: ModelConfig, x):
+    """LM head (+softcap) on an ALREADY normed ``x``, f32 logits: a looped
+    stack norms inside its pass loop (forward) and must not norm twice."""
     if cfg.tie_embeddings:
         logits = x @ params["tok_embed"].T
     else:
@@ -1657,6 +1689,14 @@ def forward(
     ``last_index`` [B] computes the head for one position a row only
     (prefill needs nothing else; at a 261,120-token vocabulary the full
     [B, T, V] logits of a 512 bucket would be 0.5 GB).
+
+    **A looped stack** (cfg.loop_steps > 1, ouro): the layer loop runs
+    inside a ``lax.scan`` over passes with the same weights; pass ``t``'s
+    layer ``l`` writes and reads cache layer ``t * n_layers + l`` on every
+    cache path (every cache is cfg.cache_layers deep), the model's final
+    norm follows EVERY pass, and the head reads the last pass's normed
+    output with no second norm. With loop_steps == 1 there is no pass loop
+    at all: a plain stack's program is what it was.
     """
     B, T = input_ids.shape
     if cfg.has_ssm and cache is not None and "ssm" not in cache:
@@ -1770,10 +1810,17 @@ def forward(
     # the expert matrices of a stacked group stay out of the layer scan's
     # xs and are read in place (_moe_dropless): set per group below
     expert_stack = None
+    # a looped stack (cfg.loop_steps > 1): the cache index of the running
+    # pass's layer 0, set by the pass loop below (traced); None = a plain
+    # stack, whose layer l reads and writes cache layer l
+    cache_base = None
 
     def layer(carry, xs):
         x, lcache = carry
         lp, layer_idx = xs[0], xs[1]
+        # the weights' layer ``layer_idx`` (masks, rotation, experts) against
+        # the CACHE's ``cache_idx`` = pass * n_layers + layer (cfg.cache_layers)
+        cache_idx = layer_idx if cache_base is None else cache_base + layer_idx
         lora = lora_for(xs[2]) if len(xs) > 2 else None
         moe_kw = None
         if cfg.moe_dropless and "moe" in lp:
@@ -1849,7 +1896,7 @@ def forward(
 
             - ragged reader, float pool: the page-write kernel stores the
               chunk into the STACKED leaf, which is returned whole, with
-              no V beside it (the kernel reads layer ``layer_idx`` of it
+              no V beside it (the kernel reads layer ``cache_idx`` of it
               in place, a page's K and V in one copy);
             - int8 pool (``kv_scale`` present), either reader: XLA's
               requantising page write on the layer's slice; the ragged
@@ -1868,7 +1915,7 @@ def forward(
                 if page_write is not None:
                     with jax.named_scope("kv.write"):
                         lcache = dict(lcache, kv=page_write(
-                            lcache["kv"], kv, bt, off_b, layer_idx,
+                            lcache["kv"], kv, bt, off_b, cache_idx,
                             paged_write_floor, paged_write_ceil,
                         ))
                     return lcache["kv"], None
@@ -1903,13 +1950,13 @@ def forward(
                     # page dedup (positions[:, 0] == off_b)
                     wslot = positions // BS - (off_b // BS)[:, None]
                     ckv, sc = _quantized_page_write(
-                        lcache["kv"][layer_idx],
-                        lcache["kv_scale"][layer_idx], blk, slot, wslot, kv,
+                        lcache["kv"][cache_idx],
+                        lcache["kv_scale"][cache_idx], blk, slot, wslot, kv,
                     )
                     lcache = dict(
                         lcache,
-                        kv=lcache["kv"].at[layer_idx].set(ckv),
-                        kv_scale=lcache["kv_scale"].at[layer_idx].set(sc),
+                        kv=lcache["kv"].at[cache_idx].set(ckv),
+                        kv_scale=lcache["kv_scale"].at[cache_idx].set(sc),
                     )
                     if ragged:
                         # (pool slice, scale slice): the kernel dequants
@@ -1924,10 +1971,10 @@ def forward(
                 # the layer's slice [NB, 2, Hkv, BS, hd]: the (blk, slot)
                 # index arrays around the two sliced axes put [B, T] in
                 # front, so the update operand is kv as it stands
-                ckv = lcache["kv"][layer_idx].at[blk, :, :, slot].set(
+                ckv = lcache["kv"][cache_idx].at[blk, :, :, slot].set(
                     kv.astype(lcache["kv"].dtype)
                 )
-                lcache = dict(lcache, kv=lcache["kv"].at[layer_idx].set(ckv))
+                lcache = dict(lcache, kv=lcache["kv"].at[cache_idx].set(ckv))
                 return views(ckv[bt])
 
             def write(cache_row, new_row, start):
@@ -1935,12 +1982,12 @@ def forward(
                     cache_row, new_row.astype(cache_row.dtype), (start, 0, 0)
                 )
 
-            ck = jax.vmap(write)(lcache["k"][layer_idx], k, off_b)
-            cv = jax.vmap(write)(lcache["v"][layer_idx], v, off_b)
+            ck = jax.vmap(write)(lcache["k"][cache_idx], k, off_b)
+            cv = jax.vmap(write)(lcache["v"][cache_idx], v, off_b)
             lcache = dict(
                 lcache,
-                k=lcache["k"].at[layer_idx].set(ck),
-                v=lcache["v"].at[layer_idx].set(cv),
+                k=lcache["k"].at[cache_idx].set(ck),
+                v=lcache["v"].at[cache_idx].set(cv),
             )
             return ck, cv
 
@@ -1949,7 +1996,7 @@ def forward(
             kv_hook=latent_hook if cfg.has_mla else kv_hook,
             attn_fn=(
                 attn_fn if page_write is None
-                else functools.partial(attn_fn, layer=layer_idx)
+                else functools.partial(attn_fn, layer=cache_idx)
             ),
             rope_local=rope_flag(layer_idx), lora=lora,
             ssm_hook=ssm_hook if cfg.has_ssm else None,
@@ -1957,43 +2004,46 @@ def forward(
         )
         return (x, lcache), None
 
-    layer_params = params["layers"]
     n_layers = cfg.n_layers
     # prevent_cse=False: checkpoint inside lax.scan doesn't need the CSE
     # barrier (scan's loop structure already prevents it) and the barrier
     # blocks XLA fusion otherwise
     layer_body = jax.checkpoint(layer, prevent_cse=False) if remat else layer
-    if isinstance(layer_params, (list, tuple)):
-        # Unstacked layers (list of per-layer trees): unrolled loop. This
-        # is the CPU serving fast path — XLA:CPU cannot pre-pack a GEMM
-        # operand it first has to slice out of the stacked [L, ...] array,
-        # so every dot inside scan falls off the packed-GEMM path
-        # (measured: 24 ms vs 1.1 ms per distilgpt2 block at T=1).
-        # Per-layer arrays arrive as separate, contiguous jit arguments
-        # and GEMM packing works. TPU keeps the stacked scan below
-        # (compile-time scales O(1) in depth; Mosaic handles layouts).
-        # models.unstack_layers converts; engine does it when backend=cpu.
-        carry = (x, cache)
-        for i, lp in enumerate(layer_params):
-            if adapters is not None:
-                lad = jax.tree.map(lambda a: a[i], adapters)
-                carry, _ = layer_body(carry, (lp, i, lad))
-            else:
-                carry, _ = layer_body(carry, (lp, i))
-        x, new_cache = carry
-    elif "dense_layers" in params:
-        # layers of unlike trees (cfg.first_k_dense): one scan a group of
-        # like layers, the pool indexed by a layer's absolute place
-        k_dense = cfg.first_k_dense
-        carry, _ = lax.scan(
-            layer_body, (x, cache),
-            (params["dense_layers"], jnp.arange(k_dense)))
-        if cfg.moe_dropless:
-            rest, expert_stack = _split_expert_stack(layer_params["moe"])
-            layer_params = dict(layer_params, moe=rest)
-        (x, new_cache), _ = lax.scan(
-            layer_body, carry, (layer_params, jnp.arange(k_dense, n_layers)))
-    else:
+
+    def run_layers(carry):
+        """Every layer once, in order: (x, cache) in, (x, cache) out."""
+        nonlocal expert_stack
+        layer_params = params["layers"]
+        if isinstance(layer_params, (list, tuple)):
+            # Unstacked layers (list of per-layer trees): unrolled loop. This
+            # is the CPU serving fast path — XLA:CPU cannot pre-pack a GEMM
+            # operand it first has to slice out of the stacked [L, ...] array,
+            # so every dot inside scan falls off the packed-GEMM path
+            # (measured: 24 ms vs 1.1 ms per distilgpt2 block at T=1).
+            # Per-layer arrays arrive as separate, contiguous jit arguments
+            # and GEMM packing works. TPU keeps the stacked scan below
+            # (compile-time scales O(1) in depth; Mosaic handles layouts).
+            # models.unstack_layers converts; engine does it when backend=cpu.
+            for i, lp in enumerate(layer_params):
+                if adapters is not None:
+                    lad = jax.tree.map(lambda a: a[i], adapters)
+                    carry, _ = layer_body(carry, (lp, i, lad))
+                else:
+                    carry, _ = layer_body(carry, (lp, i))
+            return carry
+        if "dense_layers" in params:
+            # layers of unlike trees (cfg.first_k_dense): one scan a group of
+            # like layers, the pool indexed by a layer's absolute place
+            k_dense = cfg.first_k_dense
+            carry, _ = lax.scan(
+                layer_body, carry,
+                (params["dense_layers"], jnp.arange(k_dense)))
+            if cfg.moe_dropless:
+                rest, expert_stack = _split_expert_stack(layer_params["moe"])
+                layer_params = dict(layer_params, moe=rest)
+            return lax.scan(
+                layer_body, carry,
+                (layer_params, jnp.arange(k_dense, n_layers)))[0]
         if cfg.moe_dropless:  # every layer an expert layer (smallthinker)
             rest, expert_stack = _split_expert_stack(layer_params["moe"])
             layer_params = dict(layer_params, moe=rest)
@@ -2003,12 +2053,46 @@ def forward(
             # layer body sees only its own [N, ...] slice; adapters=None
             # keeps the 2-tuple — the pre-adapter trace is unchanged
             xs = xs + (adapters,)
-        (x, new_cache), _ = lax.scan(layer_body, (x, cache), xs)
+        return lax.scan(layer_body, carry, xs)[0]
+
+    if cfg.loop_steps == 1:
+        x, new_cache = run_layers((x, cache))
+    else:
+        # a looped stack (ouro): the SAME layers loop_steps times, a loop and
+        # not an unrolling (a program's text and compile time stay one
+        # pass's), the model's one final norm after EVERY pass, and pass t's
+        # layer l on cache layer t * n_layers + l. The cache stays the carry
+        # of both loops, in place (init_paged_pool)
+        def one_pass(carry, t):
+            nonlocal cache_base
+            cache_base = t * n_layers
+            x, pass_cache = run_layers(carry)
+            with jax.named_scope("loop.norm"):
+                x = _norm(x, params["final_norm"], cfg)
+            return (x, pass_cache), None
+
+        (x, new_cache), _ = lax.scan(
+            one_pass, (x, cache), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
 
     if last_index is not None:
         idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
         x = jnp.take_along_axis(x, jnp.broadcast_to(idx, (B, 1, x.shape[2])), axis=1)
+    if cfg.loop_steps > 1:  # the last pass's norm WAS the final norm
+        with jax.named_scope("head.logits"):
+            return head_logits(params, cfg, x), new_cache
     return final_logits(params, cfg, x), new_cache
+
+
+def require_plain_stack(cfg: ModelConfig, what: str):
+    """Refuse a looped stack BY NAME on a path that walks the layers itself
+    (pipeline stages, the ring trainer, the pipeline trunk): it would run
+    ONE pass and norm once, silently another model."""
+    if cfg.loop_steps > 1:
+        raise ValueError(
+            f"{what} is not built for {cfg.name!r}: its layers run "
+            f"{cfg.loop_steps} times a token with the final norm after every "
+            "pass (cfg.loop_steps), and this path walks them once; use "
+            "core.forward")
 
 
 def unstack_layers(params: Params) -> Params:
@@ -2063,14 +2147,15 @@ def restack_layers(params: Params) -> Params:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int | None = None, dtype=jnp.bfloat16):
-    """Preallocate a fixed-capacity KV cache: {"k","v"}: [L,B,S,Hkv,hd].
+    """Preallocate a fixed-capacity KV cache: {"k","v"}: [L,B,S,Hkv,hd],
+    L = cfg.cache_layers (a looped stack holds one a (pass, layer)).
 
     Model-level utility for forward()'s contiguous cache path (per-stage
     pipeline caches, scoring/offline use). The SERVING engine no longer
     allocates these — its one cache layout is the paged block pool
     (init_paged_pool; engine/scheduler.py)."""
     S = max_len or cfg.max_seq_len
-    shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.cache_layers, batch, S, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -2092,7 +2177,7 @@ def pool_layout(cfg: ModelConfig) -> dict[str, tuple[int, int]]:
 def pool_bytes_per_token(cfg: ModelConfig, itemsize: int = 2) -> int:
     """Bytes a cached token takes over all layers, as published (a
     lane-aligned pool stores more: its arrays' own nbytes say)."""
-    return cfg.n_layers * itemsize * sum(
+    return cfg.cache_layers * itemsize * sum(
         h * w for h, w in pool_layout(cfg).values())
 
 
@@ -2106,7 +2191,9 @@ def init_paged_pool(
     run of 2 x Hkv x block_size x hd numbers — or, under latent attention,
     {"latent": [L, num_blocks, 1, block_size, W]} (pool_layout: one row a
     token, no per-head K, no V; the unit axis stands where a K/V page has
-    its two halves of heads). The block axis is 1 on every leaf. Block 0
+    its two halves of heads). L is cfg.cache_layers: a looped stack (ouro)
+    holds a layer of cache a (pass, weight layer), pass t's layer l at
+    t * n_layers + l. The block axis is 1 on every leaf. Block 0
     is the engine's reserved null block (padding target for table entries
     past a row's live extent); rows map logical positions onto blocks via
     the block tables forward() takes.
@@ -2155,8 +2242,8 @@ def init_paged_pool(
     # every head, or a latent row's unit axis
     parts = (1,) if cfg.has_mla else (2, heads)
     pool = {"latent" if cfg.has_mla else "kv": jnp.zeros(
-        (cfg.n_layers, num_blocks, *parts, block_size, width), dtype)}
+        (cfg.cache_layers, num_blocks, *parts, block_size, width), dtype)}
     if jnp.dtype(dtype) == jnp.int8:
         pool["kv_scale"] = jnp.zeros(
-            (cfg.n_layers, num_blocks, *parts), jnp.float32)
+            (cfg.cache_layers, num_blocks, *parts), jnp.float32)
     return pool

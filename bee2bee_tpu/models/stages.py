@@ -72,6 +72,7 @@ class StageSpec:
     def build(cls, cfg: ModelConfig, n_stages: int, stage: int) -> "StageSpec":
         if not 0 <= stage < n_stages:
             raise ValueError(f"stage={stage} must be in [0, {n_stages})")
+        core.require_plain_stack(cfg, "a pipeline stage split")
         a, b = layer_ranges(cfg.n_layers, n_stages)[stage]
         return cls(n_stages=n_stages, stage=stage, start=a, end=b)
 

@@ -86,6 +86,7 @@ def pipeline_apply(staged_params, cfg: ModelConfig, mesh: Mesh, x_mbs):
     (replicated over `pipe`, batch dim shardable on `data`). Returns the
     trunk output with the same shape.
     """
+    core.require_plain_stack(cfg, "the pipeline trunk")
     S = mesh.shape[PIPE_AXIS]
     M = x_mbs.shape[0]
     T = x_mbs.shape[2]

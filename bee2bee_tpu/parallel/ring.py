@@ -133,6 +133,7 @@ def make_sp_forward(cfg: ModelConfig, mesh: Mesh, remat: bool = False):
             raise ValueError(
                 f"make_sp_forward needs {ax}=1 in the mesh (got {mesh.shape})"
             )
+    core.require_plain_stack(cfg, "the ring (seq) forward")
     n_seq = mesh.shape["seq"]
     attn = partial(ring_attention_local, axis_name="seq", axis_size=n_seq)
 

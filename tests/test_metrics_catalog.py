@@ -108,6 +108,7 @@ REQUIRED_PRESENT = {
     "engine.prefill_calls",
     "engine.prefill_rows",  # ISSUE 42: the rows of those programs
     "engine.prefill_tokens",
+    "engine.loop_passes",  # ISSUE 46: passes of the stack those calls ran
     "engine.decode_slots",
     "engine.paged_blocks_in_use",
     "adapter.pool_resident",
@@ -293,5 +294,9 @@ def test_every_device_trace_scope_the_model_opens_is_documented():
     # (transformer_block's ``scope``: smallthinker)
     attn = set(re.findall(r'scope\("(attn\.[a-z_]+)"\)', src))
     assert attn == {"attn.qkv", "attn.rope", "attn.write", "attn.read", "attn.out"}
+    # a looped stack's MLP, the norm between its passes and its head
+    # (core._stack_scoped: ouro)
+    mlp = set(re.findall(r'scope\("(mlp\.[a-z_]+)"\)', src))
+    assert mlp == {"mlp.gate_up", "mlp.down"} and {"loop.norm", "head.logits"} <= opened
     doc = DOC.read_text()
-    assert not sorted(s for s in opened | attn if f"`{s}`" not in doc)
+    assert not sorted(s for s in opened | attn | mlp if f"`{s}`" not in doc)

@@ -694,3 +694,42 @@ def test_the_router_centring_pass_compiles_for_v5e_beside_the_weights(one_chip, 
     analysis = compiled.memory_analysis()
     assert analysis.output_size_in_bytes == cfg.n_layers * cfg.d_model * cfg.n_experts * 2
     assert analysis.argument_size_in_bytes + analysis.temp_size_in_bytes < 13.0e9
+
+
+# ---- ouro-2.6b (PR 46): 48 dense layers run FOUR times a token, a layer of
+# cache a (pass, layer): 192 cache layers, a 336-block pool of 8.46 GB
+
+OURO = get_config("ouro-2.6b")
+
+
+@pytest.mark.parametrize("B,T,MB", [(16, 1, 32), (8, 128, 8)],
+                         ids=["ouro-decode-16", "ouro-prefill-8x128"])
+def test_the_ouro_cell_programs_keep_the_pool_in_place_through_both_loops(one_chip, B, T, MB):
+    """ouro-2.6b as the cell serves it (48 layers x 4 passes, 336 pool blocks):
+    the 16-row decode step at the cell's widest table and the widest prefill
+    group of a burst (``PREFILL_GROUP_ROWS`` tops at 8 rows of a 128 bucket)
+    run the layer loop INSIDE the pass loop (two ``while``s, not 4 x 48 unrolled
+    layers: ONE page-write and ONE read in the text), alias the 8.46 GB pool in
+    and out with no second pool-sized buffer, a layer's slice of it or a
+    pass's 48 layers of it, and fit the chip's 15.75 GB."""
+    cfg = OURO
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, 336, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("while(") == 2, "the layer loop inside the pass loop, neither unrolled"
+    assert re.search(r"kv\.write[\w.]* = .*tpu_custom_call.*attn\.write/kv\.write", text)
+    assert re.search(r"attn\.read[\w.]* = .*tpu_custom_call", text), "the read under its scope"
+    assert _custom_calls(text, "kv.write") == 1 and _custom_calls(text, "attn.read") == 1
+    for scope in ("mlp.gate_up", "mlp.down", "loop.norm", "head.logits"):
+        assert re.search(rf'op_name="[^"]*{re.escape(scope)}', text), scope
+    for layers in (cfg.cache_layers, cfg.n_layers):  # the pool, or one pass's share of it
+        assert _pool_sized_ops(text, slice_elems, layers) == []
+        assert _pool_sized_ops(text, slice_elems // 2, layers) == []
+    m = compiled.memory_analysis()
+    pool_bytes = cfg.cache_layers * slice_elems * 2
+    assert pool_bytes == 336 * 16 * 1_572_864 == 8_455_716_864
+    assert m.alias_size_in_bytes >= pool_bytes  # in and out, in place
+    assert m.temp_size_in_bytes < 0.2 * pool_bytes  # no second pool among the temporaries
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert 13.7e9 < total < 15.75e9, total
