@@ -360,4 +360,14 @@ async def run_p2p_node(
                     loop.run_in_executor(None, forwarder.cleanup), 10.0
                 )
         await node.stop()
+        # an engine's scheduler is a daemon thread that drives the device: left
+        # running, the interpreter's finalization unwinds it inside a C++ frame
+        # and the process ends by SIGABRT, as half the exits of a busy serve-tpu
+        # did, and a SIGKILL could not always reap it then (PERF.md section 7,
+        # PR 48): end the thread first
+        for svc in node.local_services.values():
+            engine = getattr(svc, "engine", None)
+            if engine is not None:
+                engine.close()
+                logger.info("engine of %s closed", svc.name)
     return node

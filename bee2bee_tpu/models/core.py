@@ -1308,6 +1308,18 @@ def embed_tokens(params: Params, cfg: ModelConfig, input_ids, positions):
     return x
 
 
+# A call of at most this many rows (B * T, a static shape) keeps its q / k / v
+# products plain [rows, D] x [D, N] products that read the layer's matrix out
+# of the stacked parameter where it lies, as the MLP's and wo's do: a barrier
+# keeps the compiler from folding the head split into them, for a product over
+# heads takes no (stack, layer index) operand and every layer's wq / wk / wv is
+# then copied out of the stack first. That copy is a fixed cost a layer; what
+# the barrier costs, the activations re-laid for the heads behind it, grows
+# with the rows and passes it between 2,048 and 4,096 of them on a v5e
+# (both sides measured by PR 47 and kept in PERF.md section 6).
+QKV_IN_PLACE_ROWS = 2048
+
+
 def transformer_block(
     lp: Params, cfg: ModelConfig, x, positions, mask, kv_hook=None,
     attn_fn=None, rope_local=None, lora=None, ssm_hook=None,
@@ -1372,6 +1384,8 @@ def transformer_block(
         q = lora_matmul(h, lp["attn"]["wq"], "wq", lora)
         k = lora_matmul(h, lp["attn"]["wk"], "wk", lora)
         v = lora_matmul(h, lp["attn"]["wv"], "wv", lora)
+        if B * T <= QKV_IN_PLACE_ROWS:
+            q, k, v = lax.optimization_barrier((q, k, v))
     if "bq" in lp["attn"]:
         q = q + lp["attn"]["bq"]
         k = k + lp["attn"]["bk"]

@@ -329,3 +329,90 @@ def test_large_family_configs_resolve_and_validate():
         params, cfg, jnp.asarray([[1, 5, 9]], jnp.int32), None, jnp.int32(0)
     )
     assert logits.shape == (1, 3, 512)
+
+
+# ---- q / k / v read where they lie (PR 48): in a call of at most
+# core.QKV_IN_PLACE_ROWS rows a barrier keeps the three products apart from
+# the head split; in a call of more the compiler folds the split into them,
+# as it did in every call before
+
+
+def _int8(params):
+    from bee2bee_tpu.models.quant import quantize_params
+
+    return jax.tree.map(jnp.asarray, quantize_params(jax.device_get(params)))
+
+
+def _one_lora_row(cfg, B):
+    """A pool of two slots (0 = the null adapter) on wq and wv; row 1 alone
+    carries the adapter."""
+    rng, L, D, r = np.random.default_rng(3), cfg.n_layers, cfg.d_model, 4
+    widths = {"wq": cfg.n_heads * cfg.head_dim, "wv": cfg.n_kv_heads * cfg.head_dim}
+    factors = {}
+    for name, n in widths.items():
+        a = rng.normal(0, 0.3, (L, 2, D, r)).astype(np.float32)
+        b = rng.normal(0, 0.3, (L, 2, r, n)).astype(np.float32)
+        a[:, 0], b[:, 0] = 0, 0
+        factors[name] = {"a": jnp.asarray(a), "b": jnp.asarray(b)}
+    ids = np.zeros(B, np.int32)
+    ids[1] = 1
+    return dict(adapters=factors, adapter_ids=ids,
+                adapter_scales=jnp.asarray([0.0, 2.0], jnp.float32))
+
+
+@pytest.mark.parametrize("name,variant", [
+    ("tiny-gpt2", "plain"),  # MHA, q / k / v biases after the barrier
+    ("tiny-llama", "plain"),  # GQA 4 / 2
+    ("tiny-llama", "int8"),  # the barrier follows the scale
+    ("tiny-llama", "lora"),  # the barrier follows the row's delta
+])
+def test_the_qkv_barrier_leaves_the_logits_as_they_were(monkeypatch, name, variant):
+    """The same call under the boundary (the barrier) and over it (the head
+    split folded into the products, the program of every call before PR 48):
+    the logits agree to the tolerance of the cached-against-full tests above."""
+    cfg = get_config(name)
+    params = core.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    if variant == "int8":
+        params = _int8(params)
+    B, T = 4, 16
+    ids = np.random.default_rng(1).integers(3, cfg.vocab_size, (B, T)).astype(np.int32)
+    kw = _one_lora_row(cfg, B) if variant == "lora" else {}
+
+    def run(barrier):
+        fn = jax.jit(lambda p, x: core.forward(p, cfg, x, None, jnp.int32(0), **kw)[0])
+        assert ("optimization_barrier" in fn.lower(params, ids).as_text()) == barrier
+        return np.asarray(fn(params, ids))
+
+    kept = run(True)
+    monkeypatch.setattr(core, "QKV_IN_PLACE_ROWS", B * T - 1)
+    np.testing.assert_allclose(kept, run(False), rtol=2e-4, atol=2e-4)
+    if variant == "lora":  # and the adapter row is not the base model's
+        base = np.asarray(core.forward(params, cfg, ids, None, jnp.int32(0))[0])
+        assert np.abs(kept[1] - base[1]).max() > 1e-2
+        np.testing.assert_allclose(kept[0], base[0], rtol=2e-4, atol=2e-4)
+
+
+def test_the_qkv_barrier_is_differentiable(monkeypatch):
+    """Training calls of few rows run through the barrier: it has a transpose
+    rule, and the gradient is that of the many-row program."""
+    cfg = get_config("tiny-llama")
+    params = core.init_params(cfg, jax.random.key(0), dtype=jnp.float32)
+    ids = jnp.asarray(np.random.default_rng(2).integers(3, cfg.vocab_size, (4, 8)), jnp.int32)
+
+    def grads(barrier):
+        def loss(p):  # a fresh function a form: a trace is cached by function
+            logits = core.forward(p, cfg, ids, None, jnp.int32(0), remat=True)[0]
+            return jnp.mean(jax.nn.logsumexp(logits, axis=-1))
+
+        fn = jax.jit(jax.grad(loss))
+        assert ("optimization_barrier" in fn.lower(params).as_text()) == barrier
+        return fn(params)
+
+    kept = grads(True)
+    monkeypatch.setattr(core, "QKV_IN_PLACE_ROWS", 0)
+    without = grads(False)
+    for leaf in ("wq", "wk", "wv"):
+        g = np.asarray(kept["layers"]["attn"][leaf])
+        assert np.abs(g).max() > 0
+        np.testing.assert_allclose(
+            g, np.asarray(without["layers"]["attn"][leaf]), rtol=2e-4, atol=1e-6)

@@ -288,6 +288,21 @@ _HLO_LINE = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*?) ([a-z][a-z0-9-]*)\(")
 _HLO_ARRAY = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]")
 
 
+def _top_level_ops(text: str):
+    """(name, result types, opcode, line) of every instruction of a compiled
+    module outside the bodies of its fusions (a fusion counts by its result)."""
+    fused = set(re.findall(r"calls=%?([\w.-]+)", text))
+    skip = True
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            skip = head[1] in fused
+            continue
+        m = None if skip else _HLO_LINE.match(line)
+        if m:
+            yield m[1], m[2], m[3], line
+
+
 def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
     """The instructions of a compiled module that PRODUCE an array with as
     many elements as one layer's pool slice, or as the stacked pool
@@ -297,25 +312,37 @@ def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
     the pool along (parameter, tuple, get-tuple-element, bitcast, while),
     the inside of fusions (a fusion counts by its result) and the Mosaic
     calls, which alias the pool."""
-    fused = set(re.findall(r"calls=%?([\w.-]+)", text))
-    found, skip = [], True
-    for line in text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
-        if head:
-            skip = head[1] in fused
-            continue
-        m = None if skip else _HLO_LINE.match(line)
-        if not m or m[3] in (
+    found = []
+    for name, result, op, line in _top_level_ops(text):
+        if op in (
             "parameter", "tuple", "get-tuple-element", "bitcast", "while"
         ) or "tpu_custom_call" in line:
             continue
         elems = {
             int(np.prod([int(d) for d in dims.split(",") if d]))
-            for _, dims in _HLO_ARRAY.findall(m[2])
+            for _, dims in _HLO_ARRAY.findall(result)
         }
         if elems & {slice_elems, slice_elems * layers}:
-            found.append(f"{m[1]} {m[3]}")
+            found.append(f"{name} {op}")
     return found
+
+
+def _products_on(text: str, shape: tuple[int, ...]) -> int:
+    """The fusions of a compiled module that hold a plain ``[rows, D] x [D, N]``
+    product (``bf_io->bf``) AND take an array of ``shape`` as a parameter: a
+    stacked weight read where it lies (the fusion slices its layer out of the
+    stack inside, beside the product)."""
+    operand = "bf16[" + ",".join(map(str, shape)) + "]"
+    n, takes = 0, False
+    for line in text.splitlines():
+        head = re.match(r"^%?fused_computation[\w.-]* \((.*)\) -> .*\{\s*$", line)
+        if head:
+            takes = operand in head[1]
+        elif line.startswith("}"):
+            takes = False
+        elif takes and " convolution(" in line and "dim_labels=bf_io->bf" in line:
+            n, takes = n + 1, False
+    return n
 
 
 def _forward_program(cfg, B, T, MB, nb, mesh=None, sharding=None):
@@ -729,7 +756,62 @@ def test_the_ouro_cell_programs_keep_the_pool_in_place_through_both_loops(one_ch
     pool_bytes = cfg.cache_layers * slice_elems * 2
     assert pool_bytes == 336 * 16 * 1_572_864 == 8_455_716_864
     assert m.alias_size_in_bytes >= pool_bytes  # in and out, in place
-    assert m.temp_size_in_bytes < 0.2 * pool_bytes  # no second pool among the temporaries
+    # no second pool among the temporaries, and since PR 48 no transposed copy
+    # of the wq / wk / wv stacks (1.21 GB) in either program
+    assert m.temp_size_in_bytes < 100e6
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
              + m.output_size_in_bytes - m.alias_size_in_bytes)
     assert 13.7e9 < total < 15.75e9, total
+
+
+# ---- q / k / v read where they lie (PR 48): to core.QKV_IN_PLACE_ROWS rows a
+# barrier keeps the three products plain, so each takes the stacked parameter
+# and the layer index; the parent's folded the head split into them and
+# copied every layer's matrix out of the stack first (and the whole stacks
+# once a call)
+
+QKV_IN_PLACE_CASES = {
+    # (model, B, T, table width, pool blocks) at the CELL's depth: a stack of
+    # two phi-3 layers is small enough for the compiler to prefetch it whole
+    "phi-3-mini-decode": IN_PLACE_CASES["phi-3-mini-decode"],
+    "falcon-h1-decode": ("falcon-h1-34b-6l", *IN_PLACE_CASES["falcon-h1-decode"][1:]),
+    "ouro-decode-16": ("ouro-2.6b", 16, 1, 32, 336),
+    "st-decode-32": ("smallthinker-21b-a3b-8l", 32, 1, 512, 19200),
+    "phi-3-mini-prefill-2048": IN_PLACE_CASES["phi-3-mini-prefill-2048"],  # the long cell's bucket
+    "st-prefill-chunk-2048": ("smallthinker-21b-a3b-8l", 1, 2048, 512, 19200),  # the st cell's chunk
+}
+
+
+@pytest.mark.parametrize("case", sorted(QKV_IN_PLACE_CASES))
+def test_a_call_reads_wq_wk_wv_where_they_lie(
+        one_chip, mosaic_state_step, mosaic_grouped, case):
+    """The cells' decode steps and their widest prefills: outside the Mosaic
+    calls no instruction produces an array as large as one layer's ``wq``,
+    ``wk`` or ``wv`` or as their stacks (no slice out of the stack, no
+    re-laid copy of it), and a product takes each stacked parameter as its
+    own operand (``wo`` too where it shares ``wq``'s shape). (ouro's
+    temporaries, 1.21 GB of transposed stacks before: the ouro test above.)"""
+    model, B, T, MB, nb = QKV_IN_PLACE_CASES[case]
+    cfg = get_config(model)
+    assert B * T <= core.QKV_IN_PLACE_ROWS
+    lowered, _ = _forward_program(cfg, B, T, MB, nb, sharding=one_chip)
+    assert "optimization_barrier" in lowered.as_text()
+    text = lowered.compile().as_text()
+    D, L = cfg.d_model, cfg.n_layers
+    widths = [cfg.n_heads * cfg.head_dim] + 2 * [cfg.n_kv_heads * cfg.head_dim]
+    for n in set(widths):
+        assert _pool_sized_ops(text, D * n, L) == [], n
+        same = widths.count(n) + (n == D)  # wo [L, H * hd, D]
+        assert _products_on(text, (L, D, n)) == same, n
+    if core._attn_scoped(cfg):
+        assert len(re.findall(
+            r'convolution\(.*op_name="[^"]*attn\.qkv/dot_general', text)) == 3
+
+
+def test_a_call_of_more_rows_keeps_the_head_split_in_its_products(one_chip):
+    """Above the boundary (4,096 rows: the folded form read 2.7 % better there
+    on the chip) the program is the parent's: no barrier in the lowered text."""
+    cfg = dataclasses.replace(get_config("phi-3-mini"), n_layers=2)
+    assert 2 * 2048 > core.QKV_IN_PLACE_ROWS
+    lowered, _ = _forward_program(cfg, 2, 2048, 128, 385, sharding=one_chip)
+    assert "optimization_barrier" not in lowered.as_text()
