@@ -115,12 +115,16 @@ class EngineConfig:
     cache_dtype: str = "bfloat16"
     prefill_buckets: tuple = DEFAULT_BUCKETS
     rng_seed: int = 0
-    # tokens decoded per jit call (lax.scan on device). Chunking
-    # amortizes each host<->device sync to sync/chunk_len per token.
-    # Streaming granularity == chunk_len, and so is the EOS early-exit
-    # granularity (a request stopping mid-chunk pays the rest of that
-    # chunk, never the rest of max_new_tokens). The value 32 was chosen
-    # on a set-up that is gone; not measured on the current machine.
+    # the MOST tokens one call of the decode program runs, and the width of
+    # its token buffer: the step count itself is an operand of that one
+    # program, chosen by the scheduler at every dispatch (scheduler
+    # ._window_size: up to this many while a row streams, fewer where a
+    # row's end with the queue waiting is worth a stop). So this is the
+    # coarsest a stream's cadence gets, the most a row can hold its slot
+    # past its end, and the cache's overshoot margin (blocks_per_row); it
+    # is no longer how long a window IS. The value 32 was chosen on a
+    # set-up that is gone; since PR 49 the scheduler shortens windows from
+    # what it observes, so the cap binds only where long windows pay.
     decode_chunk: int = 32
     # continuous-batching rows: concurrent requests share one [max_batch]
     # KV cache and decode together (engine/scheduler.py). Decode is
@@ -1390,8 +1394,9 @@ class InferenceEngine:
         adapter: str | None = None,
     ) -> Iterator[dict]:
         """Yield {"token": last_id, "tokens": ids, "text": piece} per decode
-        chunk, then {"done": True, "result": GenerationResult}. Streaming
-        granularity is engine_cfg.decode_chunk tokens. Requests from
+        window, then {"done": True, "result": GenerationResult}. Streaming
+        granularity is one decode window: at most engine_cfg.decode_chunk
+        tokens. Requests from
         concurrent callers share the scheduler's batch — submission order
         is admission order; rows decode together (including rows on
         DIFFERENT adapters: per-row selection inside one decode step)."""

@@ -35,8 +35,11 @@ no batching at all — reference hf.py:84-108):
   chunks; nothing waits for the batch to drain.
 - **EOS early-exit**: tokens are read back every chunk; a row whose request
   hit a stop token or its token budget retires immediately and frees the
-  row for the next queued request. Per-request decode cost is
-  ceil(tokens_actually_generated / decode_chunk) chunks.
+  row for the next queued request. A window's length is chosen at every
+  dispatch (_window_size: at most decode_chunk steps while a row streams,
+  fewer where a row's end with the queue waiting is worth the host's turn
+  and the admission that follow), so a request holds its row for about the
+  tokens it generated, not for ceil(tokens / decode_chunk) chunks.
 - **Per-row sampling** (sampling.sample_batched): temperature/top-k/top-p
   ride as [B] arrays inside the one compiled step, so mixed sampling
   settings never force a recompile.
@@ -101,6 +104,18 @@ _H_PREFILL = _REG.histogram(
 )
 _H_STEP = _REG.histogram(
     "engine.step_ms", "one decode window / spec verify step wall time (ms)"
+)
+_H_WINDOW_STEPS = _REG.histogram(
+    "engine.window_steps",
+    "decode steps of each dispatched window (chosen at dispatch: "
+    "choose_window_steps); engine.step_ms / this = ms a decode step",
+    buckets=(1, 2, 4, 8, 12, 16, 24, 32, 64, 128, 256),
+)
+_C_WINDOWS = _REG.counter(
+    "engine.windows",
+    "decode windows dispatched (cut label: full = ran to the cap | budget = "
+    "shortened for a row's end with the queue waiting | drain = nobody "
+    "queued and every row ends sooner | sync = pinned for a spec draft)",
 )
 _H_BURST = _REG.histogram(
     "engine.admit_burst_requests",
@@ -229,6 +244,107 @@ _C_LATENT_TOKENS_READ = _REG.counter(
     "context lengths summed over its steps, x layers (x a row's bytes = "
     "what the latent read must fetch; prefill chunks are not in it)",
 )
+
+
+def choose_window_steps(budgets, queued: bool, cap: int, step, turn,
+                        admit) -> tuple[int, str]:
+    """How many steps the next decode window runs, and why: (n, cut).
+
+    ``budgets``: every live row's remaining tokens (an upper bound on its
+    end); ``queued``: is anyone waiting for a row; ``cap``: the most steps
+    the window may run; ``step`` / ``turn`` / ``admit``: what a decode step,
+    the host's turn between two windows and the part of an admission burst
+    that does not grow with its requests have been observed to cost (one
+    unit; None = not observed yet).
+
+    Never past the longest budget: those steps make no token. With nobody
+    queued a dead slot costs nothing, so the window runs to there. With the
+    queue waiting, the steps to the cap are planned as the STOPS THAT LOSE
+    THE FEWEST TOKENS, and the window runs to the first of them:
+    - a row that has ended loses a token a step until the stop that hands
+      its slot to the queue;
+    - a stop loses what the live rows would have made in ``turn + admit``.
+      What a burst costs a REQUEST is not the stop's: a freed row's request
+      is prefilled sooner or later, and parks the batch as long either way.
+    A stop is worth making only where a row ends, so the candidates are the
+    rows' ends and the cap (every plan's last stop); ties go to the later
+    stop. Before the costs are observed the window runs to the cap."""
+    budgets = np.sort(np.maximum(np.asarray(budgets, np.int64), 1))
+    top = int(min(cap, budgets[-1]))
+    if not queued:
+        return top, "full" if top == cap else "drain"
+    if step is None or turn is None or admit is None:
+        return top, "full" if top == cap else "budget"
+    stop = (turn + admit) * len(budgets) / max(step, 1e-9)
+    ends = np.unique(np.minimum(budgets, top))  # ascending; top is its last
+    last = len(ends) - 1
+    done = budgets[budgets <= top]
+    upto = np.searchsorted(done, ends, side="right")  # rows ended by a stop
+    when = np.concatenate([[0], np.cumsum(done)])[upto]  # ... their ends, summed
+
+    def lost(i, j):
+        """Tokens lost between a stop at ends[i] (-1: now) and the next at
+        each of ends[j]: the rows that end between them wait for the
+        second, which (unless it is the last) costs a stop itself."""
+        rows, at = (upto[i], when[i]) if i >= 0 else (0, 0)
+        return (upto[j] - rows) * ends[j] - (when[j] - at) + stop * (j < last)
+
+    after = np.zeros(len(ends))  # least loss from a stop there to the last
+    for i in range(last - 1, -1, -1):
+        j = np.arange(i + 1, len(ends))
+        after[i] = np.min(lost(i, j) + after[j])
+    j = np.arange(len(ends))
+    first = lost(-1, j) + after
+    best = int(ends[last - int(np.argmin(first[::-1]))])  # the LAST minimum
+    return best, "full" if best == cap else "budget"
+
+
+# how many readings the observed costs keep. A step and a turn: odd, so the
+# median IS a reading, and a new batch width is the median after five
+# windows. Bursts: their sizes must DIFFER before the fixed part can be told
+# from the per-request part, and a closed loop's bursts repeat a few sizes
+_LAST_COSTS = 9
+_LAST_BURSTS = 16
+
+
+class _Observed:
+    """One cost as the scheduler has observed it: the median of its last
+    few readings, so that a window that compiled (seconds where the rest
+    read milliseconds) moves it by nothing and a new batch width by all
+    within a handful of windows."""
+
+    def __init__(self):
+        self._seen: deque = deque(maxlen=_LAST_COSTS)
+
+    def note(self, seconds: float) -> None:
+        self._seen.append(max(0.0, seconds))
+
+    @property
+    def value(self):
+        if not self._seen:
+            return None
+        return float(np.median(self._seen))
+
+
+class _ObservedBursts:
+    """What the last few admission bursts cost, told apart into what grows
+    with a burst's requests and what does not (choose_window_steps weighs
+    the second): a request costs what the CHEAPEST burst cost a request,
+    and the fixed part is the median of what the bursts took beyond that
+    (a burst that compiled moves neither)."""
+
+    def __init__(self):
+        self._seen: deque = deque(maxlen=_LAST_BURSTS)
+
+    def note(self, requests: int, seconds: float) -> None:
+        self._seen.append((max(1, requests), max(0.0, seconds)))
+
+    @property
+    def fixed(self):
+        if not self._seen:
+            return None
+        k, s = np.asarray(self._seen, np.float64).T
+        return float(np.median(s - (s / k).min() * k))
 
 
 class Request:
@@ -559,6 +675,17 @@ class BatchScheduler:
         # buffers, and its own (row, request) map — row bookkeeping may
         # drift (retirement nulls _rows[b]) between dispatch and fetch.
         self._inflight: deque = deque()
+        # what the window policy weighs (choose_window_steps), in seconds,
+        # as observed on THIS machine for THIS model: a decode step (a
+        # fetched window's time over its steps), the host's turn between a
+        # fetch and the next dispatch, the admission bursts (each _admit
+        # call that placed one: its requests and its seconds). _t_fetched:
+        # when the last fetch returned (None after an idle wait);
+        # _admit_since: the bursts' seconds since then
+        self._step_s, self._turn_s, self._bursts = (
+            _Observed(), _Observed(), _ObservedBursts())
+        self._t_fetched: float | None = None
+        self._admit_since = 0.0
         # settled, not yet delivered: one deque of (request, accepted
         # tokens, ended) a fetched window, oldest first. _settle_row has
         # already done what the SCHEDULER needs of those tokens (out_ids,
@@ -717,6 +844,10 @@ class BatchScheduler:
             self._shutdown = True
             self._cond.notify()
         self._thread.join(timeout=5)
+        ms = [None if v is None else round(v * 1000.0, 2) for v in (
+            self._step_s.value, self._turn_s.value, self._bursts.fixed)]
+        logger.info("window policy at close: a step %s ms, a turn %s ms, a "
+                    "burst's fixed part %s ms (as last observed)", *ms)
         if self.mesh_drafter is not None:
             # drop the transport; the resident model tier (if any) is
             # owned by the engine and closed there
@@ -732,11 +863,12 @@ class BatchScheduler:
     def _decode_key(params, cur, cache, offsets, temps, topks, topps,
                     minps, key, tables=None, adapters=None, aids=None,
                     ascales=None, counts=None, reps=None, press=None,
-                    freqs=None, state=None):
+                    freqs=None, state=None, steps=None):
         """Sentinel shape key for the decode root: batch bucket, table
         width bucket, and the optional-operand None-flags (min_p, the
         adapter factors, and the fused penalty counts each select a
-        distinct legitimate trace)."""
+        distinct legitimate trace). ``steps`` is an operand's VALUE, not a
+        shape: it is taken (every dispatch passes it) and not keyed."""
         return (
             int(cur.shape[0]),
             None if tables is None else int(tables.shape[1]),
@@ -748,7 +880,7 @@ class BatchScheduler:
     def _decode_pen_key(params, cur, cache, offsets, counts,
                         temps, topks, topps, minps, reps, press, freqs,
                         key, tables=None, adapters=None, aids=None,
-                        ascales=None, state=None):
+                        ascales=None, state=None, steps=None):
         return (
             int(cur.shape[0]),
             None if tables is None else int(tables.shape[1]),
@@ -759,10 +891,17 @@ class BatchScheduler:
     def _decode_fn(self, params, cur, cache, offsets, temps, topks, topps,
                    minps, key, tables=None, adapters=None, aids=None,
                    ascales=None, counts=None, reps=None, press=None,
-                   freqs=None, state=None):
-        """One chunk: decode K tokens for ALL rows. Returns
+                   freqs=None, state=None, *, steps):
+        """One chunk: decode ``steps`` tokens for ALL rows. Returns
         (cur', cache', offsets', counts', toks [B, K], state').
-        ``state`` (recurrent models; None otherwise) rides the scan carry
+        ``steps`` (an int32 scalar OPERAND, 1 <= steps <= K =
+        decode_chunk) is how many steps this call runs: the ONE
+        program of a (batch width, table width) stops where the host says
+        (_window_size), and only the first ``steps`` columns of ``toks``
+        hold tokens. Step i draws with key i of the K split from ``key``,
+        so a call of a + b steps samples what calls of a and of b would
+        have with the same keys at the same steps.
+        ``state`` (recurrent models; None otherwise) rides the loop carry
         INSIDE the cache dict — core.forward reads and writes each
         layer's slice in place — and is split off again on the way out. `tables` [B, MBb]
         selects the paged-pool path: attention gathers only the mapped
@@ -770,36 +909,39 @@ class BatchScheduler:
         per-row LoRA deltas inside the same step; None keeps the base
         trace. THE FUSED ROOT (docs/PERF.md "Decode hot loop"): when
         ``counts`` [B, 2, V] rides along, penalty application + the
-        per-token occurrence bump run inside this same scan — penalized
+        per-token occurrence bump run inside this same loop — penalized
         rows cost one extra trace (the counts None-flag in _decode_key),
         never a separate root, and rep=1/pres=0/freq=0 rows pass through
         apply_penalties unchanged, so mixed batches stay token-for-token
         identical to the split-root path. counts=None keeps the
-        counts-free graph (None is a valid scan-carry pytree leaf)."""
+        counts-free graph (None is a valid loop-carry pytree leaf)."""
         e = self.engine
         B = cur.shape[0]
+        K = e.engine_cfg.decode_chunk
         if state is not None:
             cache = dict(cache, **state)
         cache = self._with_moe_stats(cache)
+        keys = jax.random.split(key, K)
 
-        def step(carry, key_t):
-            cur, cache, off, cnt = carry
+        def step(i, carry):
+            cur, cache, off, cnt, toks = carry
             logits, cache = core.forward(
                 params, e.model_cfg, cur[:, None], cache, off,
                 attn_fn=e._attn_fn(), block_tables=tables,
                 adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
             )
             nxt = sample_batched(
-                logits[:, -1, :], key_t, temps, topks, topps, minps,
+                logits[:, -1, :], keys[i], temps, topks, topps, minps,
                 cnt, reps, press, freqs,
             )
             if cnt is not None:
                 cnt = cnt.at[jnp.arange(B), 1, nxt].add(1)
-            return (nxt, cache, off + 1, cnt), nxt
+            toks = jax.lax.dynamic_update_index_in_dim(toks, nxt, i, 0)
+            return nxt, cache, off + 1, cnt, toks
 
-        keys = jax.random.split(key, e.engine_cfg.decode_chunk)
-        (cur, cache, offsets, counts), toks = jax.lax.scan(
-            step, (cur, cache, offsets, counts), keys
+        cur, cache, offsets, counts, toks = jax.lax.fori_loop(
+            0, steps, step,
+            (cur, cache, offsets, counts, jnp.zeros((K, B), cur.dtype)),
         )
         return (cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1),
                 self._chunk_extras(cache, state))
@@ -820,44 +962,22 @@ class BatchScheduler:
             return cache
         return dict(cache, moe_stats=jnp.zeros((len(core.MOE_STATS),), jnp.int32))
 
-    @prog_scope("prog.decode")
     def _decode_pen_fn(
         self, params, cur, cache, offsets, counts,
         temps, topks, topps, minps, reps, press, freqs, key, tables=None,
-        adapters=None, aids=None, ascales=None, state=None,
+        adapters=None, aids=None, ascales=None, state=None, *, steps,
     ):
-        """Penalty-carrying decode chunk: counts ride the scan carry and
+        """Penalty-carrying decode chunk: counts ride the loop carry and
         every sampled token scatters into its row. The PRE-FUSION split
         root — registered only when fused_root is off (the parity
-        reference the fused path is tested against); the fused _decode_fn
-        carries counts in the same scan slot and samples with the same
-        key draws, so the two are token-for-token identical."""
-        e = self.engine
-        B = cur.shape[0]
-        if state is not None:
-            cache = dict(cache, **state)
-        cache = self._with_moe_stats(cache)
-
-        def step(carry, key_t):
-            cur, cache, off, counts = carry
-            logits, cache = core.forward(
-                params, e.model_cfg, cur[:, None], cache, off,
-                attn_fn=e._attn_fn(), block_tables=tables,
-                adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
-            )
-            nxt = sample_batched(
-                logits[:, -1, :], key_t, temps, topks, topps, minps,
-                counts, reps, press, freqs,
-            )
-            counts = counts.at[jnp.arange(B), 1, nxt].add(1)
-            return (nxt, cache, off + 1, counts), nxt
-
-        keys = jax.random.split(key, e.engine_cfg.decode_chunk)
-        (cur, cache, offsets, counts), toks = jax.lax.scan(
-            step, (cur, cache, offsets, counts), keys
+        reference the fused path is tested against): the fused root's own
+        body under the split root's calling convention, so the two are
+        token-for-token identical."""
+        return self._decode_fn(
+            params, cur, cache, offsets, temps, topks, topps, minps, key,
+            tables, adapters, aids, ascales, counts, reps, press, freqs,
+            state, steps=steps,
         )
-        return (cur, cache, offsets, counts, jnp.moveaxis(toks, 0, 1),
-                self._chunk_extras(cache, state))
 
     # ------------------------------------------------------------ loop
 
@@ -872,6 +992,7 @@ class BatchScheduler:
                     # requests (a closed loop whose rows end together)
                     with annotate("sched.idle"):
                         self._cond.wait()
+                    self._t_fetched = None  # a wait is no turn of the host
                 if self._shutdown:
                     self._fail_all("engine shut down")
                     return
@@ -916,8 +1037,12 @@ class BatchScheduler:
         # it places a burst: who arrived while the firsts were
         # gathered (the callers of the rows just delivered) gets a
         # free row now, not a window later
-        while self._admit():
-            pass
+        t = time.perf_counter()
+        while placed := self._admit():
+            now = time.perf_counter()
+            self._bursts.note(placed, now - t)
+            self._admit_since += now - t
+            t = now
         self._deliver_pending()
         if self.active or self._inflight:
             self._step()
@@ -1692,10 +1817,10 @@ class BatchScheduler:
             burst.append(planned)
 
     @_phase("admit")
-    def _admit(self) -> bool:
+    def _admit(self) -> int:
         """Prefill queued requests into free rows, growing the batch bucket
-        up to max_batch; True when a burst was placed. Everyone queued NOW
-        is planned first (_plan_burst: rows and pages, host work), then the
+        up to max_batch; -> the requests of the burst it placed (0: none).
+        Everyone queued NOW is planned first (_plan_burst: rows and pages, host work), then the
         burst is cut into groups (_cut_groups) and each group runs as ONE
         prefill program and ONE sample (_first_tokens), all dispatched
         asynchronously; the first tokens come back in ONE device sync (a
@@ -1728,7 +1853,7 @@ class BatchScheduler:
                 break
 
         if not placed:
-            return False
+            return 0
         self._deliver_pending(burst=True)
         # ONE blocking gather for the whole burst (device_get on the list
         # fetches all; no eager concatenate op on device). The part `wait`:
@@ -1797,7 +1922,7 @@ class BatchScheduler:
                     self.stats.migrated_out += 1
                     self.stats.prefill_handoffs += 1
         self._compact_and_shrink()
-        return True
+        return len(placed)
 
     def _row_sampling_arrays(self):
         if self._row_params_dirty or self._temps is None:
@@ -1850,47 +1975,60 @@ class BatchScheduler:
             "ascales": scales,
         }
 
-    def _tightest_budget(self) -> int:
-        """Tokens the live row nearest its budget may still accept."""
-        return min(
-            r.max_new_tokens - len(r.out_ids)
+    def _budgets(self, pending: int = 0) -> list[int]:
+        """Tokens each live row may still accept beyond the ``pending``
+        already in flight: an upper bound on where it ends."""
+        return [
+            r.max_new_tokens - len(r.out_ids) - pending
             for r in self._rows if r is not None
-        )
+        ]
 
-    def _window_size(self, pending: int = 0) -> int:
-        """Chunks to dispatch before the next host sync (see
-        EngineConfig.max_inflight_chunks). Streaming requests pin the
-        window to 1 chunk so tokens flush at chunk cadence; otherwise the
-        tightest active row budget bounds the window, so no row ever has
-        more than its own remaining tokens in flight. Speculation-
-        eligible rows also pin the window: a multi-chunk dispatch would
-        decode hundreds of tokens between draft opportunities, so while
-        such a row is live the drafter gets a look every chunk (rows
+    def _window_size(self, pending: int = 0) -> tuple[int, str]:
+        """THE WINDOW POLICY: the decode steps to dispatch before the next
+        host sync, and why (the `cut` label of engine.windows): (n, cut).
+
+        The CAP is one chunk (EngineConfig.decode_chunk steps) while a row
+        streams — its tokens flush at least at chunk cadence — and while
+        a speculation-eligible row is live (cut `sync`: a longer window
+        would decode hundreds of tokens between draft opportunities; rows
         whose content never repeats stop being eligible via the
-        miss-counting adaptive disable and full windows resume).
+        miss-counting adaptive disable). Otherwise it is the chunks the
+        tightest row's budget needs, at most max_inflight_chunks and at
+        most 2 with someone queued (queued work wants a row soon), so no
+        row waits for its done event behind more than a chunk of another's
+        tokens. Under the cap choose_window_steps picks the length from
+        every live row's remaining budget, whether anyone is queued and the
+        costs observed so far (a step, the host's turn, a burst's fixed
+        part): to the longest budget with nobody queued, else to the first
+        of the stops that lose the fewest tokens.
 
         ``pending`` is the token depth already in flight (overlap mode
-        dispatches ahead of the readback): it comes off the tightest
-        budget so look-ahead windows never stack past a row's remaining
-        tokens."""
+        dispatches ahead of the readback): it comes off every budget so
+        look-ahead windows never stack past a row's remaining tokens."""
         e = self.engine
         K = e.engine_cfg.decode_chunk
-        if any(r is not None and r.stream for r in self._rows):
-            return 1
+        budgets = self._budgets(pending)
         if self._spec_wants_sync():
-            return 1
-        w = -(-(self._tightest_budget() - pending) // K)  # ceil
-        if self._queue:  # queued work wants a row soon: keep syncs frequent
-            w = min(w, 2)
-        return max(1, min(w, e.engine_cfg.max_inflight_chunks))
+            return max(1, min(K, max(budgets))), "sync"
+        if any(r is not None and r.stream for r in self._rows):
+            cap = K
+        else:
+            w = -(-min(budgets) // K)  # ceil
+            if self._queue:
+                w = min(w, 2)
+            cap = K * max(1, min(w, e.engine_cfg.max_inflight_chunks))
+        return choose_window_steps(
+            budgets, bool(self._queue), cap,
+            self._step_s.value, self._turn_s.value, self._bursts.fixed,
+        )
 
     def _prepare_window_tables(self, extra: int, calls: int):
         """Paged: grow every active row's block table to cover the next
-        device call's writes (positions < offset + extra — W*K for a
+        device call's writes (positions < offset + extra — its steps for a
         decode window, K+1 for a spec verify), then build the [bsz, tw]
         device argument at the pow2-bucketed width; ``calls`` is how many
-        attention calls a layer will read it (W*K decode steps, 1 spec
-        verify), for the page counters. A row the pool
+        attention calls a layer will read it (a window's decode steps, 1
+        spec verify), for the page counters. A row the pool
         cannot cover even after reclaiming prefix pins fails alone
         (explicitly undersized kv_pool_blocks); returns None when no
         active rows survive."""
@@ -2176,6 +2314,7 @@ class BatchScheduler:
                 _G_OVERLAP.set(0)
                 nxt, acc = (np.asarray(x) for x in jax.device_get((nxt_d, acc_d)))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
         _H_STEP.observe((time.perf_counter() - t_step) * 1000.0)
+        self._t_fetched = None  # a verify step is no turn of the host
         self._last_dispatch_t = time.perf_counter()
         self._cur = nxt.astype(np.int32).copy()
         self._offsets = (self._offsets + acc + 1).astype(np.int32)
@@ -2356,7 +2495,6 @@ class BatchScheduler:
         becomes ONE serialized [B, K+1] verify call instead (_spec_step
         — the drafter needs each verdict before proposing again, so spec
         steps never ride the ring)."""
-        K = self.engine.engine_cfg.decode_chunk
         if (not self._inflight and self._spec is not None
                 and self._spec_step()):
             return
@@ -2364,10 +2502,11 @@ class BatchScheduler:
         # classic step); look-ahead windows pass the _overlap_ready gate
         depth = self._depth if self._overlap else 1
         while len(self._inflight) < depth:
-            pending = sum(r["W"] for r in self._inflight) * K
-            if self._inflight and not self._overlap_ready(pending):
+            pending = sum(r["n"] for r in self._inflight)
+            chosen = self._overlap_ready(pending) if self._inflight else None
+            if self._inflight and chosen is None:
                 break
-            if not self._dispatch_window(pending):
+            if not self._dispatch_window(pending, chosen):
                 break
         if not self._inflight:
             self._compact_and_shrink()
@@ -2382,11 +2521,9 @@ class BatchScheduler:
         # and this tops it back up.
         if self._overlap:
             while len(self._inflight) < self._depth:
-                pending = (sum(r["W"] for r in self._inflight)
-                           + rec["W"]) * K
-                if not self._overlap_ready(pending):
-                    break
-                if not self._dispatch_window(pending):
+                pending = sum(r["n"] for r in self._inflight) + rec["n"]
+                chosen = self._overlap_ready(pending)
+                if chosen is None or not self._dispatch_window(pending, chosen):
                     break
         if not self._inflight:
             # the device goes idle while the host processes this window —
@@ -2410,9 +2547,13 @@ class BatchScheduler:
             self._compact_and_shrink()
 
     @_phase("dispatch")
-    def _dispatch_window(self, pending: int = 0) -> bool:
-        """Dispatch one W-chunk decode window (async — no host sync) and
-        push its record onto the readback ring. Chains device state off
+    def _dispatch_window(self, pending: int = 0, chosen=None) -> bool:
+        """Dispatch one decode window of the steps _window_size chose
+        (``chosen``: its answer where _overlap_ready has asked already) —
+        async, no host sync; full chunks of decode_chunk steps and a last one of
+        the remainder, each a call of the ONE decode program with its step
+        count as an operand — and push its record onto the readback ring.
+        Chains device state off
         the ring tail (or the host mirrors when the ring is empty), so
         windows form one dependency chain on device. Host offsets advance
         AT DISPATCH — every pending-window consumer (_prepare_window_
@@ -2420,10 +2561,12 @@ class BatchScheduler:
         positions. Returns False when no active rows survive table prep."""
         e, c = self.engine, self.cache
         K = e.engine_cfg.decode_chunk
-        W = self._window_size(pending)
-        tables = self._prepare_window_tables(W * K, W * K)
+        n, cut = chosen or self._window_size(pending)
+        tables = self._prepare_window_tables(n, n)
         if tables is None:
             return False
+        _H_WINDOW_STEPS.observe(n)
+        _C_WINDOWS.inc(cut=cut)
         temps, topks, topps = self._row_sampling_arrays()
         pen = self._counts is not None and any(
             r is not None and r.penalized for r in self._rows
@@ -2434,12 +2577,12 @@ class BatchScheduler:
         # diverge from how _row_sampling_arrays builds _minps
         minps = self._minps if self._minps.any() else None
         self._set_fill_gauges()
-        # economics: bsz*W*K positions run (dead rows included — the
-        # hardware computes them); active*W*K token slots are scheduled
+        # economics: bsz*n positions run (dead rows included — the
+        # hardware computes them); active*n token slots are scheduled
         self._meter.record_dispatch(
-            self._bsz * W * K,
-            self._mean_active_ctx() + W * K / 2.0,
-            scheduled=self.active * W * K,
+            self._bsz * n,
+            self._mean_active_ctx() + n / 2.0,
+            scheduled=self.active * n,
         )
         # host mirrors go in as the first call's args; chunks chain on
         # the returned DEVICE arrays; the host mirrors then advance
@@ -2463,20 +2606,20 @@ class BatchScheduler:
                 off_d = jax.device_put(off_d, self._chain_sharding[1])
         lora = dict(self._lora_args())
         if c.recurrent:
-            steps = W * K * e.model_cfg.n_layers
+            steps = n * e.model_cfg.n_layers
             _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
             _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
             _C_SSM_STEP_KERNEL_CALLS.inc(steps)
-        self._count_moe(self.active * W * K, (self._bsz - self.active) * W * K,
-                        W * K)
+        self._count_moe(self.active * n, (self._bsz - self.active) * n, n)
         if e.model_cfg.has_mla:
             # step s of a row at offset o reads its o + s + 1 cached rows
-            n, ctx = W * K, sum(int(self._offsets[b])
-                                for b, r in enumerate(self._rows) if r is not None)
+            ctx = sum(int(self._offsets[b])
+                      for b, r in enumerate(self._rows) if r is not None)
             _C_LATENT_TOKENS_READ.inc(
                 (ctx * n + self.active * n * (n + 1) // 2) * e.model_cfg.n_layers)
         toks_parts, moe_parts = [], []
-        for _ in range(W):
+        for done in range(0, n, K):
+            lora["steps"] = np.int32(min(K, n - done))
             if c.recurrent:
                 # the state chains through the windows like the pool does
                 lora["state"] = c.state
@@ -2522,7 +2665,8 @@ class BatchScheduler:
             # shardings as the canonical chain-entry commitment
             self._chain_sharding = (cur_d.sharding, off_d.sharding)
         self._inflight.append({
-            "cur": cur_d, "off": off_d, "toks": toks_parts, "W": W,
+            # toks: a buffer a device call (a partial chunk counts as a chunk)
+            "cur": cur_d, "off": off_d, "toks": toks_parts, "n": n,
             # fetched with the tokens: the prefills' counters since the last
             # window, then this window's
             "moe": self._moe_pending + moe_parts,
@@ -2535,54 +2679,61 @@ class BatchScheduler:
             ],
             "t0": time.perf_counter(),
         })
+        if self._t_fetched is not None and len(self._inflight) == 1:
+            # the chip stood still from the last fetch to here: the bursts
+            # placed meanwhile, and the host's turn
+            self._turn_s.note(self._inflight[0]["t0"] - self._t_fetched
+                              - self._admit_since)
+        self._admit_since = 0.0
         self._moe_pending = []
-        self._offsets = self._offsets + np.int32(W * K)
-        self.stats.chunks += W
+        self._offsets = self._offsets + np.int32(n)
+        self.stats.chunks += len(toks_parts)
         if pen:
             self.stats.counts_windows += 1
         self._last_dispatch_t = time.perf_counter()
         return True
 
-    def _overlap_ready(self, pending: int) -> bool:
+    def _overlap_ready(self, pending: int) -> tuple[int, str] | None:
         """May a look-ahead window dispatch with ``pending`` tokens
-        already in flight? Look-ahead is strictly opportunistic — it must
+        already in flight? None, or what _window_size chose for it (for
+        _dispatch_window). Look-ahead is strictly opportunistic — it must
         never be DESTRUCTIVE (evict prefix pins, migrate or retire rows)
         and never steal the sync cadence from work that wants the host
         (queued admissions, checkpoints, streaming flushes, spec drafts).
         Everything here reads post-in-flight offsets (_dispatch_window
         advances them at dispatch)."""
         if not self._overlap or self.active == 0:
-            return False
+            return None
         # queued/checkpoint work needs settled rows at the next sync;
         # streaming rows need token flushes at chunk cadence, not
         # pending*K tokens late
         if self._queue or self._checkpoints:
-            return False
+            return None
         if any(r is not None and r.stream for r in self._rows):
-            return False
+            return None
         # a spec-eligible row wants a draft look at the NEXT readback —
         # stacking plain windows ahead of it would decode past the
         # repetition the drafter feeds on
         if self._spec_wants_sync():
-            return False
+            return None
         e = self.engine
-        K = e.engine_cfg.decode_chunk
         # some row must still need tokens BEYOND what is already in
         # flight, or the whole window would be budget overshoot
-        if self._tightest_budget() <= pending:
-            return False
-        W = self._window_size(pending)
+        if min(self._budgets(pending)) <= 0:
+            return None
+        chosen = self._window_size(pending)
+        n = chosen[0]
         growth = [
-            (b, int(self._offsets[b]) + W * K)
+            (b, int(self._offsets[b]) + n)
             for b, r in enumerate(self._rows) if r is not None
         ]
         # hard capacity: the non-overlap path may overshoot into the
         # decode_chunk margin once; stacked look-ahead may not
         if any(upto > e.max_seq_len for _, upto in growth):
-            return False
+            return None
         # the free list must cover the window outright: look-ahead never
         # reclaims prefix pins and never migrates/retires a row
-        return self.cache.growth_fits(growth)
+        return chosen if self.cache.growth_fits(growth) else None
 
     @_phase("fetch")
     def _fetch_window(self, rec) -> np.ndarray:
@@ -2593,23 +2744,28 @@ class BatchScheduler:
         _C_HOST_SYNCS.inc()
         with get_tracer().span(
             "engine.decode_window",
-            active=len(rec["rows"]), chunks=rec["W"],
+            active=len(rec["rows"]), chunks=len(rec["toks"]), steps=rec["n"],
             inflight=len(self._inflight),
         ):
             # (an expert model's counters ride the same fetch: rec["moe"])
             parts = [np.asarray(x) for x in jax.device_get(rec["toks"] + rec.get("moe", []))]  # meshlint: ignore[ML-J003] -- the one sanctioned sync per readback window (docs/PERF.md)
         parts, moe = parts[:len(rec["toks"])], parts[len(rec["toks"]):]
         if moe:
-            self._note_moe(moe, windows=rec["W"])
-        toks_host = (
-            np.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
-        )  # [B, W*K]
+            self._note_moe(moe, windows=len(rec["toks"]))
+        # [B, n]: a chunk's buffer is decode_chunk wide, its steps come first
+        # (a window's chunks are full but the last)
+        toks_host = np.concatenate(parts, axis=1)[:, :rec["n"]]
         if not self._inflight:
             # ring drained: the host mirror of the latest sampled token
             # is this window's last column (mid-ring fetches skip this —
             # a NEWER window is already chained off the device value)
             self._cur = toks_host[:, -1].astype(np.int32).copy()
-        _H_STEP.observe((time.perf_counter() - rec["t0"]) * 1000.0)
+        now = time.perf_counter()
+        _H_STEP.observe((now - rec["t0"]) * 1000.0)
+        # a window queued behind another started when that one was fetched
+        self._step_s.note(
+            (now - max(rec["t0"], self._t_fetched or 0.0)) / rec["n"])
+        self._t_fetched = now
         return toks_host
 
     def _count_moe(self, live: int, dead: int, forwards: int):
@@ -2648,7 +2804,7 @@ class BatchScheduler:
         for b, req in rec["rows"]:
             if self._rows[b] is not req or req.done:
                 continue
-            req.chunks_decoded += rec["W"]
+            req.chunks_decoded += len(rec["toks"])
             window.append(self._settle_row(b, req, toks_host[b]))
         rows, steps = toks_host.shape
         self._meter.note_slots(rows, len(rec["rows"]), steps,

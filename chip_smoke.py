@@ -1005,6 +1005,7 @@ def child_tp_compare() -> None:
             four.params, np.zeros(B, np.int32), sch.cache.pool, np.zeros(B, np.int32),
             np.zeros(B, np.float32), np.zeros(B, np.int32), np.ones(B, np.float32),
             None, jax.random.key(0), np.zeros((B, MB), np.int32),
+            steps=np.int32(ecfg.decode_chunk),
         ).compile().as_text()
         n_kernel = text.count("tpu_custom_call")
         n_allreduce = text.count("all-reduce(") + text.count("all-reduce-start(")

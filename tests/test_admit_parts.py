@@ -202,7 +202,7 @@ def test_a_call_that_pops_a_request_but_places_none_never_waits(engine):
     before = _parts()
     with sch._cond:  # queued without waking the loop: this thread runs the call
         sch._queue.append(req, tenant=req.tenant, cost=4.0)
-    assert sch._admit() is False
+    assert not sch._admit()
     grown = _grew(_parts(), before)
     assert grown["dispatch"] > 0 and grown["wait"] == 0 and grown["emit"] == 0
     assert _drain(req)[-1]["result"].finish_reason == "cancelled"
@@ -475,7 +475,7 @@ def test_a_row_that_moved_since_dispatch_is_after_end_and_an_empty_one_dead():
     toks[1, 2] = 7
     before = _slots()
     Rows([a, b, newcomer, None])._settle_window(
-        {"rows": [(0, a), (1, b), (2, moved)], "W": 2}, toks)
+        {"rows": [(0, a), (1, b), (2, moved)], "toks": [None] * 2}, toks)
     assert _grew(_slots(), before) == {"kept": 8 + 2, "after_end": 6 + 8, "dead_row": 8}
     assert len(a.out_ids) == 8 and len(b.out_ids) == 2 and not moved.out_ids
 

@@ -306,10 +306,12 @@ def test_state_counters_and_gauges():
         eng.generate(_prompt(0, 21), max_new_tokens=6)  # bucket 32: 21 real + 11 pad
         assert scan.value(kind="real") - was["real"][0] == 21
         assert scan.value(kind="pad") - was["pad"][0] == 11
-        windows = eng.scheduler.stats.chunks  # of decode_chunk 4 steps, 2 layers, 1 row
-        assert step.value(kind="live") - was["real"][1] == windows * 4 * 2
+        # the 5 steps the budget leaves after the prefill's first token (chunks
+        # of decode_chunk 4 + 1: the window ends with its row), 2 layers, 1 row
+        assert eng.scheduler.stats.chunks == 2
+        assert step.value(kind="live") - was["real"][1] == 5 * 2
         assert step.value(kind="dead") - was["pad"][1] == 0  # a bucket of one row
-        assert calls.value() - calls_was == windows * 4 * 2  # one kernel call a layer a step
+        assert calls.value() - calls_was == 5 * 2  # one kernel call a layer a step
         assert reg.get("engine.state_rows").value() == 1
         state_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.scheduler.cache.state))
         assert reg.get("engine.state_bytes").value() == state_bytes == 2 * (4 * 8 * 16 + 3 * 96) * 4

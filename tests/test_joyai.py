@@ -613,7 +613,10 @@ def test_expert_counters_read_what_the_imbalanced_case_implies(params):
         was = (assign.value(kind="live"), assign.value(kind="dead"), hit.value(),
                calls.value(), rows.value())
         eng.generate(_prompt(0, 21), max_new_tokens=6)
-        steps = eng.scheduler.stats.chunks * 4  # decode_chunk 4, a bucket of one row
+        # a bucket of one row; the window runs the 5 steps the row's budget
+        # leaves after the prefill's first token, in chunks of 4 + 1
+        steps = 5
+        assert eng.scheduler.stats.chunks == 2
         assert assign.value(kind="live") - was[0] == (21 + steps) * 4 * 2
         assert assign.value(kind="dead") - was[1] == 11 * 4 * 2
         assert hit.value() - was[2] == (1 + steps) * 4 * 2

@@ -165,7 +165,7 @@ def test_settle_decides_what_the_accept_loop_decided(name, seed):
     expected = [_accept_loop(r, toks[b]) for b, r in enumerate(want[:-1])]
 
     sch = _Rows(reqs)
-    rec = {"rows": list(enumerate(reqs)), "W": 2}
+    rec = {"rows": list(enumerate(reqs)), "toks": [None] * 2}
     retired_any = sch._settle_window(rec, toks)
 
     [window] = sch._undelivered
@@ -190,7 +190,7 @@ def test_settle_queues_the_ended_rows_first():
     pairs = [_case(n, rng) for n in names]
     reqs = [r for r, _ in pairs]
     sch = _Rows(reqs)
-    assert sch._settle_window({"rows": list(enumerate(reqs)), "W": 1},
+    assert sch._settle_window({"rows": list(enumerate(reqs)), "toks": [None]},
                               np.stack([row for _, row in pairs]))
     [window] = sch._undelivered
     assert [(reqs.index(r), ended) for r, _, ended in window] == [
@@ -201,7 +201,7 @@ def test_settle_skips_a_row_that_moved_since_dispatch():
     rng = np.random.default_rng(3)
     a, b = _request(100, 0, rng), _request(100, 0, rng)
     sch = _Rows([b, None])  # a's row was handed to b since the window launched
-    sch._settle_window({"rows": [(0, a), (1, b)], "W": 1}, np.stack([_plain(rng)] * 2))
+    sch._settle_window({"rows": [(0, a), (1, b)], "toks": [None]}, np.stack([_plain(rng)] * 2))
     assert a.out_ids == [] and b.out_ids == [] and not sch._undelivered[0]
 
 
