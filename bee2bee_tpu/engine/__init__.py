@@ -4,5 +4,5 @@ TPU-native replacement for the reference's torch `model.generate` thread
 (reference hf.py:84-108)."""
 
 from .engine import EngineConfig, GenerationResult, InferenceEngine  # noqa: F401
-from .paged import FeatureUnsupported  # noqa: F401
+from ..models.support import FeatureUnsupported  # noqa: F401
 from .sampling import sample  # noqa: F401

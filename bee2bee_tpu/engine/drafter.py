@@ -54,14 +54,7 @@ import numpy as np
 from jax import lax
 
 from ..models import config as model_config
-from ..models import core
-from .paged import (
-    DROPLESS_ROUTED,
-    LATENT_POOL,
-    LOOPED_STACK,
-    RECURRENT_STATE,
-    FeatureUnsupported,
-)
+from ..models import core, support
 from .spec import Drafter
 
 
@@ -141,23 +134,7 @@ class DraftModel(Drafter):
             self.cfg = model_config.resolve_model_config(model, checkpoint_path)
         except KeyError as e:
             raise DrafterLoadError(f"unknown drafter model {model!r}") from e
-        if self.cfg.has_ssm:
-            raise FeatureUnsupported(
-                "spec_model_drafter", self.cfg.name,
-                "a rejected draft cannot be rolled back out of the state",
-                RECURRENT_STATE)
-        if self.cfg.has_mla:
-            raise FeatureUnsupported(
-                "spec_model_drafter", self.cfg.name,
-                "the drafter's rectangular cache holds K/V", LATENT_POOL)
-        if self.cfg.moe_dropless:
-            raise FeatureUnsupported(
-                "spec_model_drafter", self.cfg.name,
-                "the drafter's loop is not tested with it", DROPLESS_ROUTED)
-        if self.cfg.loop_steps > 1:
-            raise FeatureUnsupported(
-                "spec_model_drafter", self.cfg.name,
-                "the drafter builds a cut stack and runs it once", LOOPED_STACK)
+        support.require(self.cfg, "spec_model_drafter")
         self.spec_tokens = K = spec_tokens
         self.batch = batch
         self.dtype = jnp.dtype(dtype)

@@ -46,17 +46,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..metrics import get_registry
 from ..models import config as model_config
-from ..models import core, partition
+from ..models import core, partition, support
 from ..parallel.mesh import local_mesh
 from ..tracing import current_timing, prog_scope
 from ..utils import MetricsAggregator
-from .paged import (
-    DROPLESS_ROUTED,
-    LATENT_POOL,
-    LOOPED_STACK,
-    RECURRENT_STATE,
-    FeatureUnsupported,
-)
 from .programs import StoredPrograms
 from .tokenizer import load_tokenizer
 
@@ -89,25 +82,6 @@ PREFILL_GROUP_TOKENS = 1024
 PREFILL_GROUP_MAX_BUCKET = 512
 
 
-def _env_flag(name: str, default: bool) -> bool:
-    """Bool knob: unset -> default; "0"/"false"/"off"/"no" -> False."""
-    raw = os.environ.get(name)
-    if raw is None or raw.strip() == "":
-        return default
-    return raw.strip().lower() not in ("0", "false", "off", "no")
-
-
-def _env_int(name: str, default: int) -> int:
-    raw = os.environ.get(name)
-    if raw is None or raw.strip() == "":
-        return default
-    try:
-        return int(raw)
-    except ValueError:
-        logger.warning("%s=%r is not an int; using %d", name, raw, default)
-        return default
-
-
 @dataclass
 class EngineConfig:
     max_seq_len: int = 2048
@@ -134,8 +108,7 @@ class EngineConfig:
     # when no active request is streaming (the cost of a sync is not
     # measured on the current machine). The window is also
     # capped by the tightest active row budget, so worst-case post-EOS
-    # waste is max_inflight_chunks * decode_chunk tokens, never the rest
-    # of max_new_tokens like the round-1 engine.
+    # waste is max_inflight_chunks * decode_chunk tokens.
     max_inflight_chunks: int = 8
     # "dense": einsum attention (models/core._attention) over the
     # gathered block view — covers every score variant incl. ALiBi;
@@ -233,27 +206,6 @@ class EngineConfig:
     # lora arguments entirely). 0 = off. Adapters page in/out at runtime
     # (engine.load_adapter / the mesh's DHT fetch) without a restart.
     max_adapters: int = 0
-    # ---- decode hot-loop mechanisms (docs/PERF.md "Decode hot loop").
-    # None = resolve from env at construction so node configs and tests
-    # can flip them without plumbing; the resolved value is always a
-    # plain bool/int after __post_init__.
-    # async dispatch overlap: dispatch window N+1 while window N's token
-    # readback is still in flight (BEE2BEE_OVERLAP, default on).
-    decode_overlap: bool | None = None
-    # depth of the in-flight readback ring. 2 = double-buffered: token
-    # emission / stop handling on window W never blocks W+1's dispatch
-    # (BEE2BEE_READBACK_DEPTH, default 2; clamped to >= 1).
-    readback_depth: int | None = None
-    # fused decode root: sampling + penalty-counts application live
-    # inside the ONE decode jit root, so a penalized row no longer parks
-    # the whole batch on the counts window (BEE2BEE_FUSED_ROOT, default
-    # on; off restores the split decode/decode_penalized roots).
-    fused_root: bool | None = None
-    # persistent-width batches: hold the batch at a sticky width
-    # (grow-only; idle-timeout release) instead of riding the pow2
-    # resize ladder, with HBM-ledger headroom gating growth
-    # (BEE2BEE_BATCH_STICKY, default on).
-    batch_sticky: bool | None = None
 
     def __post_init__(self):
         # <= 0 means "disabled" (NodeConfig uses 0 as its sentinel); a raw
@@ -274,15 +226,6 @@ class EngineConfig:
                 f"need 1 <= spec_min_match <= spec_max_match, got "
                 f"{self.spec_min_match}..{self.spec_max_match}"
             )
-        if self.decode_overlap is None:
-            self.decode_overlap = _env_flag("BEE2BEE_OVERLAP", True)
-        if self.fused_root is None:
-            self.fused_root = _env_flag("BEE2BEE_FUSED_ROOT", True)
-        if self.batch_sticky is None:
-            self.batch_sticky = _env_flag("BEE2BEE_BATCH_STICKY", True)
-        if self.readback_depth is None:
-            self.readback_depth = _env_int("BEE2BEE_READBACK_DEPTH", 2)
-        self.readback_depth = max(1, int(self.readback_depth))
         if self.drafter is None:
             self.drafter = (os.environ.get("BEE2BEE_DRAFTER") or "").strip()
         if self.drafter_seed is None:
@@ -331,10 +274,13 @@ class InferenceEngine:
         # and the validation error message both read it
         self.max_seq_len = min(self.engine_cfg.max_seq_len, self.model_cfg.max_seq_len)
         partition.validate_divisibility(self.model_cfg, self.mesh)
-        self._validate_recurrent_features()
-        self._validate_latent_features()
-        self._validate_dropless_features()
-        self._validate_looped_features()
+        # what this kind of model cannot run yet (models/support.py)
+        support.require(
+            self.model_cfg,
+            *self._features_in_use(self.engine_cfg, self.mesh, self.max_seq_len),
+            prefill_chunk=self.engine_cfg.prefill_chunk,
+            max_seq_len=self.max_seq_len,
+        )
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -719,161 +665,30 @@ class InferenceEngine:
 
             validate_sp_mesh(self.model_cfg, self.engine_cfg, self.mesh)
 
-    def _validate_recurrent_features(self):
-        """Refuse, by name, every configured feature that cannot carry a
-        recurrent row state yet (FeatureUnsupported, RECURRENT_STATE). Pipeline
-        stages refuse in stage_runner, a recurrent DRAFTER in drafter.py;
-        block-level migration snapshots are not refused but never made
-        (scheduler._snapshot_row ships metadata only, so the importer
-        takes the re-prefill rung)."""
-        cfg, ec = self.model_cfg, self.engine_cfg
-        if not cfg.has_ssm:
-            return
-
-        def refuse(feature, why):
-            raise FeatureUnsupported(feature, cfg.name, why, RECURRENT_STATE)
-
-        if ec.prefix_cache_entries > 0:
-            refuse("prefix_cache", "a pinned block holds K/V only — the "
-                   "state at the prefix's end would have to be snapshotted")
-        if ec.drafter == "mesh":
-            refuse("spec_mesh_drafter", "a rejected draft cannot be rolled "
-                   "back out of the state")
-        if ec.drafter:
-            refuse("spec_model_drafter", "a rejected draft cannot be rolled "
-                   "back out of the state")
-        if ec.spec_tokens > 0:
-            refuse("spec_ngram", "a rejected draft cannot be rolled back "
-                   "out of the state")
-        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
-            refuse("seq_attention", "the state is not sharded over a seq axis")
-        if self.mesh.shape.get("model", 1) > 1:
-            refuse("mesh_model", "the mixer's heads and state are not "
-                   "sharded over a model axis (--mesh-shape model:N)")
-        if ec.max_adapters > 0:
-            refuse("multi_lora", "the mixer's projections have no adapter path")
-        if ec.prefill_chunk and self.max_seq_len % ec.prefill_chunk:
-            refuse("prefill_chunk", f"a chunk of {ec.prefill_chunk} does not "
-                   f"divide max_seq_len {self.max_seq_len}, so the last "
-                   "window would re-feed tokens the state already absorbed")
-
-    def _validate_latent_features(self):
-        """Refuse, by name, every configured feature that is not proven over
-        a latent pool (FeatureUnsupported, LATENT_POOL; cfg.has_mla). Pipeline stages
-        refuse in stage_runner, a latent-attention DRAFTER in drafter.py."""
-        cfg, ec = self.model_cfg, self.engine_cfg
-        if not cfg.has_mla:
-            return
-
-        def refuse(feature, why):
-            raise FeatureUnsupported(feature, cfg.name, why, LATENT_POOL)
-
-        if jnp.dtype(ec.cache_dtype) == jnp.int8:
-            refuse("kv_int8", "the requantising page write keeps a scale a "
-                   "K/V head: a latent row has none")
-        if ec.drafter == "mesh":
-            refuse("spec_mesh_drafter", "the verify forward over latent rows "
-                   "is not tested")
-        if ec.drafter:
-            refuse("spec_model_drafter", "the verify forward over latent rows "
-                   "is not tested")
-        if ec.spec_tokens > 0:
-            refuse("spec_ngram", "the verify forward over latent rows is not "
-                   "tested")
-        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
-            refuse("seq_attention", "the sp partials read per-head K/V")
-        if self.mesh.shape.get("model", 1) > 1:
-            refuse("mesh_model", "the one latent row a token is read by every "
-                   "head: the read is not partitioned over a model axis "
-                   "(--mesh-shape model:N)")
-        if self.mesh.shape.get("expert", 1) > 1:
-            refuse("mesh_expert", "the dropless expert layer's grouped product "
-                   "is not partitioned over an expert axis")
-        if ec.max_adapters > 0:
-            refuse("multi_lora", "the latent projections have no adapter path")
-        if ec.quantize == "int8":
-            refuse("weight_int8", "the absorbed products read W_kvb unquantised")
-        if ec.prefix_cache_entries > 0:
-            refuse("prefix_cache", "a shared latent block under a resumed "
-                   "prefill is not tested")
-
-    def _validate_dropless_features(self):
-        """Refuse, by name, every configured feature that is not proven for
-        a model of dropless expert layers over a K/V pool
-        (FeatureUnsupported, DROPLESS_ROUTED; cfg.moe_dropless without latent
-        attention: smallthinker). Pipeline stages refuse in stage_runner,
-        such a DRAFTER in drafter.py. Chunked prefill, the ragged reader and
-        the prefix cache are tested (tests/test_feature_matrix.py)."""
-        cfg, ec = self.model_cfg, self.engine_cfg
-        if not cfg.moe_dropless or cfg.has_mla:
-            return
-
-        def refuse(feature, why):
-            raise FeatureUnsupported(feature, cfg.name, why, DROPLESS_ROUTED)
-
-        if jnp.dtype(ec.cache_dtype) == jnp.int8:
-            refuse("kv_int8", "the int8 pool's per-layer slices under a "
-                   "window that binds are not tested")
-        if ec.quantize == "int8":
-            refuse("weight_int8", "the grouped product reads the expert "
-                   "stacks unquantised")
-        if ec.drafter == "mesh":
-            refuse("spec_mesh_drafter", "the verify forward is not tested "
-                   "with it")
-        if ec.drafter:
-            refuse("spec_model_drafter", "the verify forward is not tested "
-                   "with it")
-        if ec.spec_tokens > 0:
-            refuse("spec_ngram", "the verify forward is not tested with it")
-        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
-            refuse("seq_attention", "the sp partials know no window")
-        if self.mesh.shape.get("model", 1) > 1:
-            refuse("mesh_model", "the dropless expert layer's grouped product "
-                   "is not partitioned over a model axis (--mesh-shape "
-                   "model:N)")
-        if self.mesh.shape.get("expert", 1) > 1:
-            refuse("mesh_expert", "the dropless expert layer's grouped product "
-                   "is not partitioned over an expert axis")
-        if ec.max_adapters > 0:
-            refuse("multi_lora", "adapters are not tested with it")
-
-    def _validate_looped_features(self):
-        """Refuse, by name, every configured feature that is not proven for
-        a looped stack (FeatureUnsupported, LOOPED_STACK; cfg.loop_steps > 1:
-        ouro). Pipeline stages refuse in stage_runner, a looped DRAFTER in
-        drafter.py. Chunked prefill, both readers, the prefix cache and the
-        block export / import are tested (tests/test_ouro.py)."""
-        cfg, ec = self.model_cfg, self.engine_cfg
-        if cfg.loop_steps == 1:
-            return
-
-        def refuse(feature, why):
-            raise FeatureUnsupported(feature, cfg.name, why, LOOPED_STACK)
-
-        if jnp.dtype(ec.cache_dtype) == jnp.int8:
-            refuse("kv_int8", "the int8 pool's per-layer slices inside the "
-                   "pass loop are not tested")
-        if ec.quantize == "int8":
-            refuse("weight_int8", "quantised weights read once a pass are "
-                   "not tested")
-        if ec.drafter == "mesh":
-            refuse("spec_mesh_drafter", "the verify forward is not tested "
-                   "with it")
-        if ec.drafter:
-            refuse("spec_model_drafter", "the verify forward is not tested "
-                   "with it")
-        if ec.spec_tokens > 0:
-            refuse("spec_ngram", "the verify forward is not tested with it")
-        if self.mesh.shape.get("seq", 1) > 1 or ec.attention == "sp":
-            refuse("seq_attention", "the sp path's cache is not indexed by pass")
-        if self.mesh.shape.get("model", 1) > 1:
-            refuse("mesh_model", "the pass loop around a sharded pool is not "
-                   "tested (--mesh-shape model:N)")
-        if self.mesh.shape.get("expert", 1) > 1:
-            refuse("mesh_expert", "it has no experts to place on an expert axis")
-        if ec.max_adapters > 0:
-            refuse("multi_lora", "the adapter stacks are [n_layers, ...] and "
-                   "the pass loop does not hand them round again")
+    @staticmethod
+    def _features_in_use(ec: EngineConfig, mesh, max_seq_len: int) -> set[str]:
+        """The features a configuration turns on, by the names
+        models/support.REFUSED knows them under: ONE condition a feature.
+        (pipeline_stages, kv_export and the paths that walk the layers
+        themselves are asked about where they are built.)"""
+        axis = mesh.shape.get
+        on = {
+            "kv_int8": jnp.dtype(ec.cache_dtype) == jnp.int8,
+            "weight_int8": ec.quantize == "int8",
+            "spec_ngram": ec.spec_tokens > 0,
+            "spec_model_drafter": bool(ec.drafter),
+            "spec_mesh_drafter": ec.drafter == "mesh",
+            "seq_attention": axis("seq", 1) > 1 or ec.attention == "sp",
+            "mesh_model": axis("model", 1) > 1,
+            "mesh_expert": axis("expert", 1) > 1,
+            "multi_lora": ec.max_adapters > 0,
+            "prefix_cache": ec.prefix_cache_entries > 0,
+            # a chunk that does not divide the context re-anchors its last
+            # window over tokens already fed
+            "prefill_chunk": bool(ec.prefill_chunk
+                                  and max_seq_len % ec.prefill_chunk),
+        }
+        return {feature for feature, used in on.items() if used}
 
     @property
     def state_info(self) -> dict | None:

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..metrics import get_registry
-from ..models import core
+from ..models import core, support
 from ..tracing import prog_scope
 
 # block-pool occupancy for /metrics (one engine per serving node, so
@@ -138,36 +138,6 @@ def best_prefix_key(keys, ids) -> tuple[tuple | None, int]:
         else:
             best_key, best_m = key, m
     return best_key, best_m
-
-
-class FeatureUnsupported(ValueError):
-    """An engine feature that is not proven for a kind of model was asked for
-    with such a model: ``feature`` names it, ``ground`` is the model's
-    property it stumbles on (one of the four below). Raised when the engine,
-    a stage runner or a drafter is built: none of these may be silently
-    wrong. RECURRENT_STATE (falcon-h1's Mamba-2 mixer): rollback is not free
-    for a recurrence (spec verify), pinned blocks do not hold the state at a
-    prefix's end (prefix cache), and the state is not sharded over a mesh
-    yet. LATENT_POOL (latent attention caches one [c_kv | k_rope] row a token,
-    no per-head K/V: core.pool_layout). DROPLESS_ROUTED (smallthinker: every
-    layer a dropless expert layer over a plain K/V pool, its router fed the
-    pre-attention norm). LOOPED_STACK (ouro: the layers run loop_steps times a
-    token, a layer of cache a (pass, layer): cfg.cache_layers)."""
-
-    def __init__(self, feature: str, model: str, why: str, ground: str):
-        self.feature, self.ground = feature, ground
-        super().__init__(
-            f"{feature} is not supported for {model!r}: {ground}, and {why}")
-
-
-RECURRENT_STATE = "its rows carry recurrent state beside their K/V pages"
-LATENT_POOL = "its rows cache latent rows (no per-head K/V)"
-DROPLESS_ROUTED = ("its every layer is a dropless expert layer routed from "
-                   "the pre-attention norm")
-
-
-LOOPED_STACK = ("its layers run several times a token with a cache of "
-                "their own in every pass")
 
 
 class PoolExhausted(RuntimeError):
@@ -783,12 +753,8 @@ class RowCache:
         """-> (nb, {leaf: [L, Hkv, nb, ...]} | None): the pages holding
         positions [0, upto) of row b at the model's head size, with the
         int8 pool's scales under their own keys. Pure read."""
-        if self.recurrent:
-            # a row's blocks are NOT its complete state here
-            raise FeatureUnsupported(
-                "kv_export", self.engine.model_cfg.name,
-                "the state has no export format yet", RECURRENT_STATE,
-            )
+        # a recurrent row's blocks are NOT its complete state
+        support.require(self.engine.model_cfg, "kv_export")
         nb = ceil_div(upto, self.block_size)
         if not nb:
             return 0, None

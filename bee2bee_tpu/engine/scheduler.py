@@ -68,13 +68,13 @@ import numpy as np
 from ..health import get_recorder
 from ..metrics import get_registry
 from ..models import core
+from ..models.support import FeatureUnsupported
 from ..router.fairness import WdrrQueue
 from ..router.tenants import load_tenant_config
 from ..tracing import RequestTiming, annotate, get_tracer, prog_scope
 from .introspect import _C_HOST_SYNCS, _C_SYNC_STALLS, _G_OVERLAP
 from .engine import PREFILL_GROUP_MAX_BUCKET
 from .paged import (
-    FeatureUnsupported,
     PoolExhausted,
     RowCache,
     best_prefix_key,
@@ -524,10 +524,8 @@ class SchedulerStats:
     # tier's acceptance — the model tier earning 0.6 while n-gram sits
     # at 0.05 is exactly the signal the tier ladder acts on.
     spec_tiers: dict = field(default_factory=dict)
-    # decode hot loop (docs/PERF.md): windows whose dispatch carried the
-    # [B, 2, V] penalty counts (fused root or split pen root alike) — the
-    # "penalized rows park the whole batch on the counts window" cost is
-    # exactly this counter's growth rate vs chunks
+    # decode hot loop (docs/PERF.md): windows (and spec verifies) whose
+    # dispatch carried the [B, 2, V] penalty counts
     counts_windows: int = 0
     # sticky-width growth attempts the HBM ledger's headroom gate denied
     # (the request requeues at the front and retries after retirements)
@@ -567,6 +565,12 @@ class _Admission:
     start: int  # [0, start) came from the prefix cache: the write floor
     windows: list  # where each chunk of its walk starts
     recompute: bool = False  # the re-prefill rung: no useful token in it
+
+
+# Decode windows in flight at most (the readback ring): with two, a fetched
+# window's tokens are settled while the next already runs, so the chip never
+# waits for the host between windows; every run on file used 2.
+RING_DEPTH = 2
 
 
 class BatchScheduler:
@@ -657,16 +661,8 @@ class BatchScheduler:
         self._meter = ic.meter
         tw_ok = self.cache.declared_table_width
         bs_ok = engine._declared_batch_sizes
-        # decode hot-loop mechanisms (docs/PERF.md "Decode hot loop"):
-        # resolved once from EngineConfig (env knobs already folded in by
-        # its __post_init__) — the step loop branches on plain bools.
-        cfg = e.engine_cfg
-        self._fused = bool(cfg.fused_root)
-        self._overlap = bool(cfg.decode_overlap)
-        self._depth = max(1, int(cfg.readback_depth))
-        self._sticky = bool(cfg.batch_sticky)
-        # sticky-width idle release: an all-idle batch holds its bucket
-        # this long after the last dispatch before dropping to 1 (an
+        # idle release of the batch bucket: an all-idle batch holds its
+        # width this long after the last dispatch before dropping to 1 (an
         # instance attr so tests can collapse the hysteresis window)
         self._sticky_idle_s = 5.0
         self._last_dispatch_t = 0.0
@@ -707,18 +703,6 @@ class BatchScheduler:
             key_fn=self._decode_key,
             allowed=lambda key: key[0] in bs_ok and tw_ok(key[1]),
         )
-        if self._fused:
-            # penalty counts ride the fused root (counts flag in
-            # _decode_key); the split pen root never compiles
-            self._decode_pen = None
-        else:
-            self._decode_pen = ic.sentinel.watch(
-                "decode_penalized",
-                jax.jit(self._decode_pen_fn, donate_argnums=(2, 4),
-                        donate_argnames=("state",)),
-                key_fn=self._decode_pen_key,
-                allowed=lambda key: key[0] in bs_ok and tw_ok(key[1]),
-            )
         # jitted: sample_batched run eagerly is ~15 tiny ops = ~15
         # dispatches per admission
         self._sample_first = e.stored_programs(
@@ -876,17 +860,6 @@ class BatchScheduler:
             counts is not None,
         )
 
-    @staticmethod
-    def _decode_pen_key(params, cur, cache, offsets, counts,
-                        temps, topks, topps, minps, reps, press, freqs,
-                        key, tables=None, adapters=None, aids=None,
-                        ascales=None, state=None, steps=None):
-        return (
-            int(cur.shape[0]),
-            None if tables is None else int(tables.shape[1]),
-            minps is not None, adapters is not None,
-        )
-
     @prog_scope("prog.decode")
     def _decode_fn(self, params, cur, cache, offsets, temps, topks, topps,
                    minps, key, tables=None, adapters=None, aids=None,
@@ -907,14 +880,13 @@ class BatchScheduler:
         selects the paged-pool path: attention gathers only the mapped
         blocks. `adapters`/`aids`/`ascales` (adapters/pool.py) select
         per-row LoRA deltas inside the same step; None keeps the base
-        trace. THE FUSED ROOT (docs/PERF.md "Decode hot loop"): when
-        ``counts`` [B, 2, V] rides along, penalty application + the
-        per-token occurrence bump run inside this same loop — penalized
+        trace. When ``counts`` [B, 2, V] rides along, penalty application +
+        the per-token occurrence bump run inside this same loop — penalized
         rows cost one extra trace (the counts None-flag in _decode_key),
         never a separate root, and rep=1/pres=0/freq=0 rows pass through
-        apply_penalties unchanged, so mixed batches stay token-for-token
-        identical to the split-root path. counts=None keeps the
-        counts-free graph (None is a valid loop-carry pytree leaf)."""
+        apply_penalties unchanged, so every row of a mixed batch samples
+        what it would alone. counts=None keeps the counts-free graph (None
+        is a valid loop-carry pytree leaf)."""
         e = self.engine
         B = cur.shape[0]
         K = e.engine_cfg.decode_chunk
@@ -961,23 +933,6 @@ class BatchScheduler:
         if not self.engine.model_cfg.moe_dropless:
             return cache
         return dict(cache, moe_stats=jnp.zeros((len(core.MOE_STATS),), jnp.int32))
-
-    def _decode_pen_fn(
-        self, params, cur, cache, offsets, counts,
-        temps, topks, topps, minps, reps, press, freqs, key, tables=None,
-        adapters=None, aids=None, ascales=None, state=None, *, steps,
-    ):
-        """Penalty-carrying decode chunk: counts ride the loop carry and
-        every sampled token scatters into its row. The PRE-FUSION split
-        root — registered only when fused_root is off (the parity
-        reference the fused path is tested against): the fused root's own
-        body under the split root's calling convention, so the two are
-        token-for-token identical."""
-        return self._decode_fn(
-            params, cur, cache, offsets, temps, topks, topps, minps, key,
-            tables, adapters, aids, ascales, counts, reps, press, freqs,
-            state, steps=steps,
-        )
 
     # ------------------------------------------------------------ loop
 
@@ -1327,7 +1282,7 @@ class BatchScheduler:
     @_phase("compact")
     def _compact_and_shrink(self):
         """Close retirement holes by moving the highest active row down,
-        then drop to a smaller bucket when occupancy allows."""
+        then release the bucket of a batch that has stood idle."""
         while True:
             hole = next(
                 (i for i, r in enumerate(self._rows) if r is None), None
@@ -1351,29 +1306,16 @@ class BatchScheduler:
             self._rows[hole] = self._rows[last]
             self._rows[last] = None
             self._row_params_dirty = True
-        A = self.active
-        if self._sticky:
-            # persistent-width batches (docs/PERF.md "Decode hot loop"):
-            # the batch bucket is GROW-ONLY while work flows — each bucket
-            # size is a distinct decode trace, and the pow2 resize ladder's
-            # shrink-then-regrow churn showed up in the compile ledger as
-            # the dominant retrace source under bursty admission. A fully
-            # idle batch releases the bucket only after the hysteresis
-            # window, so a burst arriving right after a drain reuses the
-            # already-compiled width instead of re-climbing the ladder.
-            if (A == 0 and self._bsz > 1
-                    and time.perf_counter() - self._last_dispatch_t
-                    > self._sticky_idle_s):
-                self._resize(1)
-            return
-        if A == 0 and self._bsz > 1:
-            # the pool and prefix pins persist across idle — only the
-            # host bucket shrinks (no device state to rebuild)
+        # the batch bucket is GROW-ONLY while work flows: each bucket size
+        # is a distinct decode trace, and a bucket that shrank with every
+        # retirement would retrace at every burst. A fully idle batch
+        # releases it only after the hysteresis window, so a burst arriving
+        # right after a drain reuses the width already compiled. The pool
+        # and prefix pins persist across idle — only the host bucket shrinks
+        if (self.active == 0 and self._bsz > 1
+                and time.perf_counter() - self._last_dispatch_t
+                > self._sticky_idle_s):
             self._resize(1)
-        elif self._bsz > 1 and A * 2 <= self._bsz // 2:
-            # quarter-occupancy hysteresis: halve without thrashing at the
-            # boundary (A*2 <= bsz/2  ⇔  A <= bsz/4)
-            self._resize(max(1, self._bsz // 2))
 
     def _plan_prefill(self, req: Request, seq: list):
         """-> (start, cached blocks | None), and req.bucket: the longest
@@ -1505,8 +1447,8 @@ class BatchScheduler:
                 tokens[i, :len(chunk)] = chunk
                 true_len[i], offset[i] = len(chunk), pos
             if recurrent and (offset != fed).any():
-                # engine._validate_recurrent_features makes the walk
-                # monotone; a re-fed token would be absorbed twice, so
+                # the prefill_chunk refusal (models/support.py) makes the
+                # walk monotone; a re-fed token would be absorbed twice, so
                 # never run past this
                 raise RuntimeError(
                     f"recurrent prefill walk re-anchored: windows at "
@@ -2002,7 +1944,7 @@ class BatchScheduler:
         part): to the longest budget with nobody queued, else to the first
         of the stops that lose the fewest tokens.
 
-        ``pending`` is the token depth already in flight (overlap mode
+        ``pending`` is the token depth already in flight (the ring
         dispatches ahead of the readback): it comes off every budget so
         look-ahead windows never stack past a row's remaining tokens."""
         e = self.engine
@@ -2108,20 +2050,21 @@ class BatchScheduler:
         blocks_per_row). A window pinned to 1 chunk while every spec step
         is vetoed would be pure sync-cadence loss.
 
-        The penalized-row veto applies only to the SPLIT roots: with the
-        fused root on, counts ride the verify call too
-        (engine._spec_verify_fn), so one penalized row no longer parks
-        the whole batch's speculation on the counts window."""
+        A penalized row is no veto: its counts ride the verify call too
+        (engine._spec_verify_fn)."""
         e = self.engine
         K = e.engine_cfg.spec_tokens
-        for b, req in enumerate(self._rows):
-            if req is None:
-                continue
-            if req.penalized and not self._fused:
-                return False
-            if int(self._offsets[b]) + K + 1 > e.max_seq_len:
-                return False
-        return True
+        return all(
+            int(self._offsets[b]) + K + 1 <= e.max_seq_len
+            for b, req in enumerate(self._rows) if req is not None
+        )
+
+    def _counts_ride(self) -> bool:
+        """Does the next device call carry the penalty counts? (Some live
+        row is penalised: the counts-carrying trace of its root.)"""
+        return self._counts is not None and any(
+            r is not None and r.penalized for r in self._rows
+        )
 
     def _spec_wants_sync(self) -> bool:
         """Does some live row want a draft look at the NEXT readback?
@@ -2189,11 +2132,9 @@ class BatchScheduler:
         each drafter sees its rows in ONE batched propose call (the model
         tier turns that into a single [B, 2]+scan device pass). Returns
         (drafts [bsz, K], lens [bsz]) or None when this step must take
-        the plain/penalized window instead: no row drafted anything, a
-        penalized row is active under the SPLIT roots (pre-fusion, the
-        counts graph existed only on the window path — see
-        _spec_possible), or any active row is too close to capacity for
-        the fixed [B, K+1] write extent (_spec_possible).
+        the plain/penalized window instead: no row drafted anything, or
+        any active row is too close to capacity for the fixed [B, K+1]
+        write extent (_spec_possible).
 
         Tier bookkeeping per row: a None proposal is PENDING (mesh tier,
         draft still in flight — the row just skips this step, no
@@ -2282,14 +2223,10 @@ class BatchScheduler:
             self._mean_active_ctx() + (e.engine_cfg.spec_tokens + 1) / 2.0,
             scheduled=self.active * (e.engine_cfg.spec_tokens + 1),
         )
-        # fused penalty bookkeeping: with the fused root on, a penalized
-        # row no longer vetoes the whole batch's speculation — its counts
-        # ride the verify call (engine._spec_verify_fn) and it advances
-        # its normal one penalty-sampled token per step
-        pen = (
-            self._fused and self._counts is not None
-            and any(r is not None and r.penalized for r in self._rows)
-        )
+        # a penalized row's counts ride the verify call
+        # (engine._spec_verify_fn) and it advances its normal one
+        # penalty-sampled token per step
+        pen = self._counts_ride()
         t_step = time.perf_counter()
         with self._phases.phase("fetch"):
             with get_tracer().span(
@@ -2484,13 +2421,12 @@ class BatchScheduler:
 
     def _step(self):
         """One hot-loop turn (docs/PERF.md "Decode hot loop"): keep the
-        readback ring full, fetch the OLDEST in-flight window (the only
-        host sync), refill the ring BEFORE touching its tokens, then
-        SETTLE it (_settle_window: which rows ended). Its delivery to the
-        callers is left pending for _loop's next turn, which puts the
-        freed rows' prefills in flight first (_admit) and delivers under
-        them. With overlap off the ring depth is 1 and this collapses to
-        the classic dispatch→sync→settle loop.
+        readback ring full (RING_DEPTH windows), fetch the OLDEST in-flight
+        window (the only host sync), refill the ring BEFORE touching its
+        tokens, then SETTLE it (_settle_window: which rows ended). Its
+        delivery to the callers is left pending for _loop's next turn,
+        which puts the freed rows' prefills in flight first (_admit) and
+        delivers under them.
         With speculation enabled, a turn where some greedy row drafted
         becomes ONE serialized [B, K+1] verify call instead (_spec_step
         — the drafter needs each verdict before proposing again, so spec
@@ -2498,10 +2434,9 @@ class BatchScheduler:
         if (not self._inflight and self._spec is not None
                 and self._spec_step()):
             return
-        # fill the ring: the first window dispatches unconditionally (the
-        # classic step); look-ahead windows pass the _overlap_ready gate
-        depth = self._depth if self._overlap else 1
-        while len(self._inflight) < depth:
+        # fill the ring: the first window dispatches unconditionally;
+        # look-ahead windows pass the _overlap_ready gate
+        while len(self._inflight) < RING_DEPTH:
             pending = sum(r["n"] for r in self._inflight)
             chosen = self._overlap_ready(pending) if self._inflight else None
             if self._inflight and chosen is None:
@@ -2513,21 +2448,17 @@ class BatchScheduler:
             return
         rec = self._inflight.popleft()
         toks_host = self._fetch_window(rec)
-        # async dispatch overlap: with rec's tokens on the host, put the
-        # NEXT window in flight before doing any host-side token work
-        # (rec's tokens count toward pending — they are not in out_ids
-        # yet). At depth 1 this alone keeps the device busy through the
-        # processing below; at depth 2 the ring already holds a window
-        # and this tops it back up.
-        if self._overlap:
-            while len(self._inflight) < self._depth:
-                pending = sum(r["n"] for r in self._inflight) + rec["n"]
-                chosen = self._overlap_ready(pending)
-                if chosen is None or not self._dispatch_window(pending, chosen):
-                    break
+        # with rec's tokens on the host, top the ring back up before doing
+        # any host-side token work (rec's tokens count toward pending —
+        # they are not in out_ids yet)
+        while len(self._inflight) < RING_DEPTH:
+            pending = sum(r["n"] for r in self._inflight) + rec["n"]
+            chosen = self._overlap_ready(pending)
+            if chosen is None or not self._dispatch_window(pending, chosen):
+                break
         if not self._inflight:
             # the device goes idle while the host processes this window —
-            # the stall the overlap machinery exists to remove
+            # the stall the ring exists to remove
             _C_SYNC_STALLS.inc()
         # settle only: which rows ended is all the next dispatch needs. The
         # tokens reach their streams once the chip has work again: under
@@ -2568,9 +2499,7 @@ class BatchScheduler:
         _H_WINDOW_STEPS.observe(n)
         _C_WINDOWS.inc(cut=cut)
         temps, topks, topps = self._row_sampling_arrays()
-        pen = self._counts is not None and any(
-            r is not None and r.penalized for r in self._rows
-        )
+        pen = self._counts_ride()
         # None selects the min_p-free trace: the relative-floor softmax
         # must cost nothing when no active row asked for it. Gate on the
         # SAME array the sampler receives — a row scan could silently
@@ -2623,37 +2552,18 @@ class BatchScheduler:
             if c.recurrent:
                 # the state chains through the windows like the pool does
                 lora["state"] = c.state
-            if self._fused:
-                cur_d, c.pool, off_d, cnts, toks, st = self._decode(
-                    e.params, cur_d, c.pool, off_d,
-                    temps, topks, topps, minps, e._next_key(), tables,
-                    counts=self._counts if pen else None,
-                    reps=self._reps if pen else None,
-                    press=self._press if pen else None,
-                    freqs=self._freqs if pen else None,
-                    **lora,
-                )
-                if pen:
-                    self._counts = cnts
-            elif pen:
-                cur_d, c.pool, off_d, self._counts, toks, st = (
-                    self._decode_pen(
-                        e.params, cur_d, c.pool, off_d, self._counts,
-                        temps, topks, topps, minps,
-                        self._reps, self._press, self._freqs,
-                        e._next_key(), tables, **lora,
-                    )
-                )
-            else:
-                # _decode is the fused root in BOTH modes; with counts
-                # left None it lowers to the counts-free graph, so the
-                # unfused setting differs only in routing pen windows to
-                # the split _decode_pen root above
-                cur_d, c.pool, off_d, _, toks, st = self._decode(
-                    e.params, cur_d, c.pool, off_d,
-                    temps, topks, topps, minps, e._next_key(), tables,
-                    **lora,
-                )
+            # counts left None lower to the counts-free graph
+            cur_d, c.pool, off_d, cnts, toks, st = self._decode(
+                e.params, cur_d, c.pool, off_d,
+                temps, topks, topps, minps, e._next_key(), tables,
+                counts=self._counts if pen else None,
+                reps=self._reps if pen else None,
+                press=self._press if pen else None,
+                freqs=self._freqs if pen else None,
+                **lora,
+            )
+            if pen:
+                self._counts = cnts
             if st is not None and "moe_stats" in st:  # an expert model's counters
                 st = dict(st)
                 moe_parts.append(st.pop("moe_stats"))
@@ -2702,7 +2612,7 @@ class BatchScheduler:
         (queued admissions, checkpoints, streaming flushes, spec drafts).
         Everything here reads post-in-flight offsets (_dispatch_window
         advances them at dispatch)."""
-        if not self._overlap or self.active == 0:
+        if self.active == 0:
             return None
         # queued/checkpoint work needs settled rows at the next sync;
         # streaming rows need token flushes at chunk cadence, not
@@ -2727,7 +2637,7 @@ class BatchScheduler:
             (b, int(self._offsets[b]) + n)
             for b, r in enumerate(self._rows) if r is not None
         ]
-        # hard capacity: the non-overlap path may overshoot into the
+        # hard capacity: a ring's first window may overshoot into the
         # decode_chunk margin once; stacked look-ahead may not
         if any(upto > e.max_seq_len for _, upto in growth):
             return None

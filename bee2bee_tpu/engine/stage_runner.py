@@ -29,14 +29,7 @@ import numpy as np
 
 from ..metrics import get_registry
 from ..models import config as model_config
-from ..models import core, stages
-from .paged import (
-    DROPLESS_ROUTED,
-    LATENT_POOL,
-    LOOPED_STACK,
-    RECURRENT_STATE,
-    FeatureUnsupported,
-)
+from ..models import core, stages, support
 
 STALE_CACHE_S = 600.0  # drop request caches untouched this long
 
@@ -82,24 +75,7 @@ class StageRunner:
         # same any-checkpoint rule as the engine
         # (`serve-stage --model auto --checkpoint <dir>`)
         self.model_cfg = model_config.resolve_model_config(model, checkpoint_path)
-        if self.model_cfg.has_ssm:
-            raise FeatureUnsupported(
-                "pipeline_stages", self.model_cfg.name,
-                "a stage's per-microbatch cache holds K/V only", RECURRENT_STATE)
-        if self.model_cfg.has_mla:
-            raise FeatureUnsupported(
-                "pipeline_stages", self.model_cfg.name,
-                "a stage's per-microbatch cache is rectangular K/V", LATENT_POOL)
-        if self.model_cfg.moe_dropless:
-            raise FeatureUnsupported(
-                "pipeline_stages", self.model_cfg.name,
-                "a stage's loop reads a layer's experts sliced out of the "
-                "stack and is not tested", DROPLESS_ROUTED)
-        if self.model_cfg.loop_steps > 1:
-            raise FeatureUnsupported(
-                "pipeline_stages", self.model_cfg.name,
-                "a stage's layers would have to come round once a pass",
-                LOOPED_STACK)
+        support.require(self.model_cfg, "pipeline_stages")
         # the mesh addresses runners by the COORDINATOR'S model string —
         # remember what the caller asked for so add_stage_runner can alias
         # it to the resolved config name

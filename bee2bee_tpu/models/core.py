@@ -2097,18 +2097,6 @@ def forward(
     return final_logits(params, cfg, x), new_cache
 
 
-def require_plain_stack(cfg: ModelConfig, what: str):
-    """Refuse a looped stack BY NAME on a path that walks the layers itself
-    (pipeline stages, the ring trainer, the pipeline trunk): it would run
-    ONE pass and norm once, silently another model."""
-    if cfg.loop_steps > 1:
-        raise ValueError(
-            f"{what} is not built for {cfg.name!r}: its layers run "
-            f"{cfg.loop_steps} times a token with the final norm after every "
-            "pass (cfg.loop_steps), and this path walks them once; use "
-            "core.forward")
-
-
 def unstack_layers(params: Params) -> Params:
     """Convert stacked [L, ...] layer params into a list of per-layer
     contiguous trees (forward()'s unrolled path). Host-side numpy copies

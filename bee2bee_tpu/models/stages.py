@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import core
+from . import core, support
 from .config import ModelConfig
 
 Params = dict[str, Any]
@@ -72,7 +72,7 @@ class StageSpec:
     def build(cls, cfg: ModelConfig, n_stages: int, stage: int) -> "StageSpec":
         if not 0 <= stage < n_stages:
             raise ValueError(f"stage={stage} must be in [0, {n_stages})")
-        core.require_plain_stack(cfg, "a pipeline stage split")
+        support.require(cfg, "pipeline_stage_split")
         a, b = layer_ranges(cfg.n_layers, n_stages)[stage]
         return cls(n_stages=n_stages, stage=stage, start=a, end=b)
 

@@ -33,7 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..compat import shard_map
 
-from ..models import core
+from ..models import core, support
 from ..models.config import ModelConfig
 
 PIPE_AXIS = "pipe"
@@ -86,7 +86,7 @@ def pipeline_apply(staged_params, cfg: ModelConfig, mesh: Mesh, x_mbs):
     (replicated over `pipe`, batch dim shardable on `data`). Returns the
     trunk output with the same shape.
     """
-    core.require_plain_stack(cfg, "the pipeline trunk")
+    support.require(cfg, "pipeline_trunk")
     S = mesh.shape[PIPE_AXIS]
     M = x_mbs.shape[0]
     T = x_mbs.shape[2]

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models import core
+from ..models import core, support
 from ..compat import shard_map
 from ..models.config import ModelConfig
 
@@ -133,7 +133,7 @@ def make_sp_forward(cfg: ModelConfig, mesh: Mesh, remat: bool = False):
             raise ValueError(
                 f"make_sp_forward needs {ax}=1 in the mesh (got {mesh.shape})"
             )
-    core.require_plain_stack(cfg, "the ring (seq) forward")
+    support.require(cfg, "ring_forward")
     n_seq = mesh.shape["seq"]
     attn = partial(ring_attention_local, axis_name="seq", axis_size=n_seq)
 

@@ -26,9 +26,9 @@ The full run (`python bench.py`, no argument) measures the device: it
 needs a TPU and fails without one — it never shrinks itself onto a CPU.
 One process touches jax (a chip belongs to one process at a time). A rung
 that fails is recorded and the others still run, then the run exits
-non-zero. The named standalone rungs (`python bench.py decode_hotloop`,
+non-zero. The named standalone rungs (`python bench.py spec_model`,
 ...) run on whatever backend jax resolves and stamp it; scripts/lint.sh
-uses three of them on the CPU as counts-and-ratio checks.
+uses two of them on the CPU as counts-and-ratio checks.
 """
 
 from __future__ import annotations
@@ -1696,185 +1696,6 @@ def bench_lora_multi(msl: int = 256, new_tokens: int = 32,
         eng.close()
 
 
-def bench_decode_hotloop(new_tokens: int = 96) -> dict:
-    """Decode hot-loop rung (ISSUE 16): fixed-batch decode tok/s,
-    host-syncs-per-step, and decode-root retraces, each overlap mechanism
-    off/on — async dispatch (BEE2BEE_OVERLAP), the two-deep readback
-    ring (BEE2BEE_READBACK_DEPTH), the fused sampling+penalties decode
-    root (BEE2BEE_FUSED_ROOT), and sticky batch width
-    (BEE2BEE_BATCH_STICKY).
-
-    The model is tiny-llama (random init) ON PURPOSE: the mechanisms
-    under test remove HOST-side cost — stall windows, resize churn,
-    split-root retraces — so the rung runs in the regime the ISSUE
-    names, where the device step is cheap and orchestration is the
-    bottleneck. A weight-bound model would bury the orchestration delta
-    under seconds-per-window of matmul and measure only machine noise.
-
-    Each attempt gets a FRESH engine, warmed with one steady-state
-    width-4 uniform batch (exactly the traces a long-running server
-    holds), then times an alternating uniform/staggered serving trace:
-
-    - UNIFORM reps (4 greedy rows, equal budgets, one penalized) are the
-      shape where look-ahead windows are legal — heterogeneous budgets
-      make every window cover the shortest row's whole remainder, so the
-      overlap gate refuses overshoot by design. These reps carry the
-      ``host_syncs_per_step`` story: all-off every fetch is a stall
-      (ratio 1.0 by construction); overlap keeps the ring non-empty.
-    - CHURN reps (staggered budgets 24/48/72/96) retire rows mid-batch.
-      Non-sticky width walks the pow2 resize ladder down and back up,
-      and the narrower buckets are traces the warm steady-state server
-      NEVER compiled — a mid-serve XLA retrace, the exact churn the
-      retrace sentinel exists to catch. Sticky width holds the bucket
-      and pays zero retraces. These reps carry the tok/s story.
-
-    On this box the tok/s delta is the retrace cost (CPU-proxy: a
-    single-core host cannot cash latency-hiding into wall-clock, so
-    overlap/dbuf show up in the stall ratio, not tok/s — on TPU both
-    move). Spec is off: the drafter pins the window to 1 chunk, which is
-    a different rung's story (bench_spec). Best-of-2 attempts, counters
-    taken from the best: admission is threaded, so window/width visit
-    order is racy and one attempt can eat an unlucky counts-util
-    compile.
-
-    ``host_syncs_per_step`` is stall windows / readback windows — the
-    fraction of fetches where the device sat idle behind host token
-    processing. Lower is better, so the key deliberately does NOT match
-    benchdiff's higher-is-better watch regex; ``tok_per_s`` per cell
-    does and is gated. CPU-proxy numbers until the rung runs on the chip —
-    judged per the rung's platform stamp (PR 6 bench hygiene)."""
-    import jax
-
-    from bee2bee_tpu.engine import EngineConfig, InferenceEngine
-    from bee2bee_tpu.engine.introspect import (
-        _C_HOST_SYNCS,
-        _C_SYNC_STALLS,
-        bench_snapshot,
-    )
-
-    CONFIGS = {
-        "all_off": dict(decode_overlap=False, fused_root=False,
-                        batch_sticky=False, readback_depth=1),
-        "overlap": dict(decode_overlap=True, fused_root=False,
-                        batch_sticky=False, readback_depth=1),
-        "dbuf": dict(decode_overlap=True, fused_root=False,
-                     batch_sticky=False, readback_depth=2),
-        "fused": dict(decode_overlap=False, fused_root=True,
-                      batch_sticky=False, readback_depth=1),
-        "sticky": dict(decode_overlap=False, fused_root=False,
-                       batch_sticky=True, readback_depth=1),
-        "all_on": dict(decode_overlap=True, fused_root=True,
-                       batch_sticky=True, readback_depth=2),
-    }
-    ROWS = 4
-    HOT_PROMPT = 32
-    prompts = [
-        [1 + (i * 37 + j) % 500 for j in range(HOT_PROMPT)]
-        for i in range(ROWS)
-    ]
-    UNIFORM = [new_tokens] * ROWS
-    CHURN = [new_tokens * f // 4 for f in (1, 2, 3, 4)]
-    out: dict = {"platform": jax.devices()[0].platform, "rows": ROWS,
-                 "new_tokens": new_tokens}
-
-    def run_batch(eng, budgets) -> int:
-        results: list = [None] * ROWS
-        errors: list = []
-
-        def run(i):
-            # the last row penalized: the row class the fused root keeps
-            # on the shared graph instead of the split counts root
-            kw = dict(temperature=0.0)
-            if i == ROWS - 1:
-                kw["repetition_penalty"] = 1.2
-            try:
-                results[i] = eng.generate(
-                    prompts[i], max_new_tokens=budgets[i], **kw
-                )
-            except Exception as e:  # noqa: BLE001 — re-raised below
-                errors.append(e)
-
-        threads = [threading.Thread(target=run, args=(i,)) for i in range(ROWS)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        if errors:
-            raise RuntimeError(f"{len(errors)}/{ROWS} rows failed") from errors[0]
-        return sum(r.new_tokens for r in results)
-
-    def jit_compiles() -> tuple:
-        c = bench_snapshot().get("compiles") or {}
-        return (
-            sum(v.get("count", 0) for v in c.values()),
-            sum(v.get("seconds", 0.0) for v in c.values()),
-        )
-
-    def attempt(knobs) -> dict:
-        eng = InferenceEngine(
-            "tiny-llama",
-            engine_config=EngineConfig(
-                max_seq_len=256, max_batch=ROWS, prefill_buckets=(32,),
-                dtype="float32", cache_dtype="float32",
-                decode_chunk=4, max_inflight_chunks=4, spec_tokens=0,
-                **knobs,
-            ),
-        )
-        try:
-            run_batch(eng, UNIFORM)  # warm: steady-state width-4 traces
-            c0, cs0 = jit_compiles()
-            syncs0, stalls0 = _C_HOST_SYNCS.value(), _C_SYNC_STALLS.value()
-            t0 = time.perf_counter()
-            total = sum(
-                run_batch(eng, b) for b in (UNIFORM, CHURN, UNIFORM, CHURN)
-            )
-            wall = time.perf_counter() - t0
-            c1, cs1 = jit_compiles()
-            syncs = _C_HOST_SYNCS.value() - syncs0
-            stalls = _C_SYNC_STALLS.value() - stalls0
-            return {
-                "tokens": total, "wall_s": round(wall, 4),
-                "tok_per_s": round(total / wall, 2) if wall > 0 else 0.0,
-                "readback_windows": int(syncs),
-                "stall_windows": int(stalls),
-                "host_syncs_per_step": (
-                    round(stalls / syncs, 4) if syncs else None
-                ),
-                "retraces": int(c1 - c0),
-                "retrace_seconds": round(cs1 - cs0, 3),
-                "decode_mfu": (
-                    eng.introspect.refresh().get("goodput") or {}
-                ).get("mfu"),
-            }
-        finally:
-            eng.close()
-
-    for cname, knobs in CONFIGS.items():
-        entry = attempt(knobs)
-        second = attempt(knobs)
-        if second["tok_per_s"] > entry["tok_per_s"]:
-            entry = second
-        out[cname] = entry
-        log(f"decode_hotloop [{cname}]: {entry['tok_per_s']} tok/s, "
-            f"{entry['host_syncs_per_step']} stalls/window "
-            f"({entry['stall_windows']}/{entry['readback_windows']}), "
-            f"{entry['retraces']} retraces ({entry['retrace_seconds']}s)")
-
-    off, on = out["all_off"], out["all_on"]
-    out["speedup"] = (
-        round(on["tok_per_s"] / off["tok_per_s"], 3)
-        if off["tok_per_s"] > 0 else 0.0
-    )
-    log(
-        f"decode_hotloop rung [{out['platform']}]: all-on "
-        f"{on['tok_per_s']} tok/s @ {on['host_syncs_per_step']} "
-        f"stalls/window vs all-off {off['tok_per_s']} tok/s @ "
-        f"{off['host_syncs_per_step']} (x{out['speedup']})"
-    )
-    out["introspect"] = _introspect_stamp()
-    return out
-
-
 def bench_obs_overhead(
     tokens: int = 200_000, cadence_s: float = 0.005, repeats: int = 3,
 ) -> dict:
@@ -2069,10 +1890,6 @@ def main() -> int:
     # from one engine in mixed batches, per-adapter greedy parity vs the
     # merged-weights reference, tok/s overhead vs adapter-less decode)
     rung("lora_multi", bench_lora_multi)
-    # decode hot-loop rung (ISSUE 16 acceptance: fixed-batch tok/s AND
-    # host-syncs-per-step strictly improved with async dispatch + the
-    # readback ring + the fused root + sticky width all on vs all off)
-    rung("decode_hotloop", bench_decode_hotloop)
     # per-tenant fairness rung (ISSUE 7 acceptance: ~4:1 completed-token
     # ratio at 4:1 weights under saturation) — model-free
     rung("router_fairness", bench_router_fairness)
@@ -2193,14 +2010,10 @@ if __name__ == "__main__":
         _use_compile_cache()
         print(json.dumps(bench_lora_multi()), flush=True)
         sys.exit(0)
-    # `python bench.py decode_hotloop`: the hot-loop overlap rung
-    # standalone. Prints a FULL mini-artifact (schema_version, top-level
-    # platform stamp, rung under extras) rather than the bare rung so
-    # scripts/benchdiff.py can gate two standalone runs against each
-    # other — that is the scripts/lint.sh trajectory gate.
     # `python bench.py spec_model`: the model-tier speculative-decoding
     # rung standalone (tiny random-init models, loopback mesh cell, any
-    # platform). Prints a FULL mini-artifact like decode_hotloop so
+    # platform). Prints a FULL mini-artifact (schema_version, top-level
+    # platform stamp, rung under extras) rather than the bare rung so
     # scripts/benchdiff.py can gate two standalone runs against each
     # other — that is the scripts/lint.sh trajectory gate.
     # `python bench.py obs_overhead`: the observatory sampler-overhead
@@ -2232,20 +2045,6 @@ if __name__ == "__main__":
             "schema_version": 2,
             "platform": _jax.devices()[0].platform,
             "extras": {"spec_model": rung},
-        }), flush=True)
-        sys.exit(0)
-    if len(sys.argv) > 1 and sys.argv[1] == "decode_hotloop":
-        _use_compile_cache()
-        import jax as _jax
-
-        rung = bench_decode_hotloop()
-        print(json.dumps({
-            "metric": "decode_hotloop_tok_per_s_all_on",
-            "value": rung["all_on"]["tok_per_s"],
-            "unit": "tok/s",
-            "schema_version": 2,
-            "platform": _jax.devices()[0].platform,
-            "extras": {"decode_hotloop": rung},
         }), flush=True)
         sys.exit(0)
     sys.exit(main())

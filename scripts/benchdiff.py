@@ -7,7 +7,7 @@ honestly — this tool is the comparator:
 
     python scripts/benchdiff.py BENCH_r05.json BENCH_r06.json
     python scripts/benchdiff.py BENCH_r0*.json --threshold 0.10
-    python scripts/benchdiff.py BENCH_decode_hotloop_r01.json \\
+    python scripts/benchdiff.py BENCH_spec_model_r01.json \\
         --live http://127.0.0.1:8080 --series decode_tok_s --window 600
     python scripts/benchdiff.py --self-check
 

@@ -19,25 +19,6 @@ if [ "${SKIP_BENCHDIFF:-0}" != "1" ]; then
   echo "[lint] benchdiff self-check"
   "$PY" scripts/benchdiff.py --self-check
 
-  # decode hot-loop regression gate (docs/PERF.md "Decode hot loop"):
-  # re-run the rung and diff against the recorded round-16 baseline.
-  # Threshold 0.75 absorbs shared-CPU noise; the mechanism deltas the
-  # rung guards (sticky retrace avoidance, overlap stall ratio) are
-  # 6x-scale, far outside it. Cross-platform runs exit 2 = refused,
-  # which is a skip, not a failure (benchdiff's own contract).
-  echo "[lint] decode_hotloop rung vs BENCH_decode_hotloop_r01.json"
-  FRESH="$(mktemp "${TMPDIR:-/tmp}/decode_hotloop.XXXXXX.json")"
-  JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-    "$PY" bench.py decode_hotloop | tail -1 > "$FRESH"
-  rc=0
-  "$PY" scripts/benchdiff.py BENCH_decode_hotloop_r01.json "$FRESH" \
-    --threshold 0.75 || rc=$?
-  rm -f "$FRESH"
-  if [ "$rc" -ne 0 ] && [ "$rc" -ne 2 ]; then
-    echo "[lint] decode_hotloop regression (benchdiff rc=$rc)" >&2
-    exit "$rc"
-  fi
-
   # model-tier speculative-decoding gate (docs/PERF.md "Model-tier
   # speculative decoding"): re-run the spec_model rung and diff against
   # the recorded round-19 baseline. The headline is acceptance-weighted

@@ -546,9 +546,6 @@ def _roots_of(eng, monkeypatch, prompts, **gen) -> dict[str, list[str]]:
         names, "prog.sample", sch._sample_first, sch._sample_first))
     monkeypatch.setattr(eng, "_spec_verify", _lowering(
         names, "prog.verify", eng._spec_verify.__wrapped__, eng._spec_verify))
-    if sch._decode_pen is not None:
-        monkeypatch.setattr(sch, "_decode_pen", _lowering(
-            names, "prog.decode_pen", sch._decode_pen.__wrapped__, sch._decode_pen))
     for p in prompts:
         eng.generate(p, max_new_tokens=16, **gen)
     monkeypatch.undo()
@@ -558,27 +555,24 @@ def _roots_of(eng, monkeypatch, prompts, **gen) -> dict[str, list[str]]:
 def _assert_rooted(names: dict[str, list[str]], want: set[str]):
     assert set(names) == want
     for root, ops in names.items():
-        scope = "prog.decode" if root == "prog.decode_pen" else root
         assert ops
         for op in ops:
             m = PROG.search(op)
-            assert m and m.group(1) == scope and re.match(r"jit\([^)]*\)/prog\.", op), (root, op)
+            assert m and m.group(1) == root and re.match(r"jit\([^)]*\)/prog\.", op), (root, op)
 
 
 @pytest.mark.parametrize("over,gen,want", [
     ({}, {}, {"prog.prefill", "prog.decode", "prog.sample"}),
     ({"spec_tokens": 6}, {}, {"prog.prefill", "prog.decode", "prog.sample", "prog.verify"}),
-    ({"fused_root": False}, {"repetition_penalty": 1.3},
-     {"prog.prefill", "prog.sample", "prog.decode_pen"}),
-], ids=["plain", "spec", "split_penalty_root"])
+    ({}, {"repetition_penalty": 1.3}, {"prog.prefill", "prog.decode", "prog.sample"}),
+], ids=["plain", "spec", "penalised"])
 def test_every_serving_root_lowers_under_its_program_scope(monkeypatch, over, gen, want):
     eng = _engine(**over)
     try:
         names = _roots_of(eng, monkeypatch, [[5, 6, 7, 8, 9] * 3 + [5, 6, 7]], **gen)
         _assert_rooted(names, want)
         # the sampler INSIDE a decode window stays the decode program's
-        decode = names.get("prog.decode") or names["prog.decode_pen"]
-        assert any("/while/" in op for op in decode)
+        assert any("/while/" in op for op in names["prog.decode"])
     finally:
         eng.close()
 

@@ -1,21 +1,22 @@
-"""Decode hot-loop tests (ISSUE 16, docs/PERF.md "Decode hot loop"):
-async dispatch overlap, double-buffered readback, the fused sampling
-root, and persistent-width (sticky) batches.
+"""Decode hot-loop tests (docs/PERF.md "Decode hot loop"): the readback
+ring, the one decode root that carries the penalty counts, and the
+grow-only batch bucket.
 
 The acceptance pins live here:
 
-- the FUSED decode root serves a mixed penalized/plain batch
-  token-for-token identical to the pre-fusion split-root path;
-- a penalized row no longer parks the whole batch: the split
-  ``decode_penalized`` root never exists under the fused root, and the
-  batch-level speculation gate stops vetoing on penalized rows;
-- overlap look-ahead changes NO tokens under retirement churn,
-  admission queueing, or re-admission — and actually removes host-sync
-  stalls on the uniform-budget steady state it is designed for;
-- the sticky batch bucket holds its width through retirement churn
-  (zero fresh decode traces where the resize ladder recompiles), grows
-  only under HBM-ledger headroom, and releases the bucket on idle;
-- the overlap chain's compile space stays pinned: repeat steady-state
+- the decode root serves a mixed penalized/plain batch with every row
+  token-for-token identical to its SOLO run (a row alone shares no window,
+  ring slot or bucket with another);
+- a penalized row does not park the whole batch: its window runs the ONE
+  ``decode`` root (the sentinel knows no other decode root), and the
+  batch-level speculation gate does not veto on penalized rows;
+- look-ahead changes NO tokens under retirement churn, admission
+  queueing, or re-admission — and removes host-sync stalls on the
+  uniform-budget steady state it is designed for;
+- the batch bucket holds its width through retirement churn (zero fresh
+  decode traces), grows only under HBM-ledger headroom, and releases the
+  bucket on idle;
+- the ring's chain keeps its compile space pinned: repeat steady-state
   batches — including ring-empty re-entries from the host mirrors,
   which carry different arg shardings than chained device outputs —
   trigger zero new decode compiles (the sharding-keyed double-compile
@@ -61,21 +62,22 @@ def _engine(**knobs) -> InferenceEngine:
 
 
 @pytest.fixture(scope="module")
-def fused_engine():
-    """All hot-loop mechanisms explicitly ON (the shipping default)."""
-    eng = _engine(decode_overlap=True, fused_root=True, batch_sticky=True,
-                  readback_depth=2)
+def engine():
+    eng = _engine()
     yield eng
     eng.close()
 
 
-@pytest.fixture(scope="module")
-def unfused_engine():
-    """The pre-fusion reference: split penalized root, no overlap."""
-    eng = _engine(decode_overlap=False, fused_root=False,
-                  batch_sticky=False, readback_depth=1)
-    yield eng
-    eng.close()
+def _solo(eng, budgets, penalize_last=False):
+    """Each row's rollout ALONE on the engine, one after another: the
+    reference a batched row is held to."""
+    out = []
+    for i, budget in enumerate(budgets):
+        kw = {"max_new_tokens": budget, "temperature": 0.0}
+        if penalize_last and i == len(budgets) - 1:
+            kw["repetition_penalty"] = 1.3
+        out.append(eng.generate(PROMPTS[i % ROWS], **kw).token_ids)
+    return out
 
 
 def _run_batch(eng, budgets, penalize_last=False):
@@ -142,95 +144,66 @@ def test_sample_batched_counts_none_is_the_prefusion_graph():
     np.testing.assert_array_equal(np.asarray(noop), np.asarray(plain))
 
 
-def test_fused_mixed_batch_token_parity(fused_engine, unfused_engine):
-    """THE fusion acceptance: a mixed batch (3 plain greedy rows + 1
-    repetition-penalized row) decodes token-for-token identically on the
-    fused root and on the pre-fusion split-root engine — and both match
-    the unbatched sequential ground truth."""
+def test_fused_mixed_batch_token_parity(engine):
+    """A mixed batch (3 plain greedy rows + 1 repetition-penalized row)
+    decodes every row token-for-token as that row does alone."""
     budgets = [16] * ROWS
-    fused = _run_batch(fused_engine, budgets, penalize_last=True)
-    split = _run_batch(unfused_engine, budgets, penalize_last=True)
-    assert fused == split, "fused root diverged from the pre-fusion path"
-
-    sequential = []
-    for i in range(ROWS):
-        kw = {"max_new_tokens": budgets[i], "temperature": 0.0}
-        if i == ROWS - 1:
-            kw["repetition_penalty"] = 1.3
-        sequential.append(
-            unfused_engine.generate(PROMPTS[i], **kw).token_ids
-        )
-    assert fused == sequential, "mixed batch diverged from sequential"
-
-
-def test_fused_root_retires_the_split_pen_root(fused_engine,
-                                               unfused_engine):
-    """Fused on: counts ride the ONE decode root — the split
-    ``decode_penalized`` root is never even registered, while the
-    counts-bearing windows are still accounted. Fused off: the split
-    root compiles and serves the penalized batch (the parked-batch
-    behavior the fusion removes)."""
-    before = fused_engine.scheduler.stats.counts_windows
-    _run_batch(fused_engine, [8] * ROWS, penalize_last=True)
-    assert fused_engine.scheduler._decode_pen is None
-    snap = fused_engine.introspect.sentinel.snapshot()
-    assert "decode_penalized" not in snap, (
-        "split pen root compiled despite the fused root"
+    batched = _run_batch(engine, budgets, penalize_last=True)
+    assert batched == _solo(engine, budgets, penalize_last=True), (
+        "mixed batch diverged from the rows' solo runs"
     )
+
+
+def test_a_penalised_window_runs_the_one_decode_root(engine):
+    """Counts ride the ONE decode root: a penalised window traces `decode`
+    under its counts key, is accounted as a counts window, and the
+    sentinel knows no other decode root."""
+    before = engine.scheduler.stats.counts_windows
+    _run_batch(engine, [8] * ROWS, penalize_last=True)
+    snap = engine.introspect.sentinel.snapshot()
+    assert [root for root in snap if root.startswith("decode")] == ["decode"]
+    assert "decode_penalized" not in snap
     assert snap["decode"]["traces"] >= 1
-    assert fused_engine.scheduler.stats.counts_windows > before
-
-    _run_batch(unfused_engine, [8] * ROWS, penalize_last=True)
-    snap = unfused_engine.introspect.sentinel.snapshot()
-    assert snap.get("decode_penalized", {"traces": 0})["traces"] >= 1, (
-        "pre-fusion engine never exercised the split pen root"
-    )
+    assert engine.scheduler.stats.counts_windows > before
 
 
-def test_fused_root_unparks_batch_speculation():
-    """`_spec_possible` (the batch-level speculation gate): one
-    penalized row vetoes speculation for the WHOLE batch on split roots
-    (counts cannot thread the verify call), but not on the fused root —
-    the parked-batch acceptance pin at the gate level."""
-    for fused, expect in ((True, True), (False, False)):
-        eng = _engine(fused_root=fused, spec_tokens=2, max_seq_len=64,
-                      prefill_buckets=(16,))
+def test_a_penalised_row_does_not_park_batch_speculation():
+    """`_spec_possible` (the batch-level speculation gate): a penalized
+    row does not veto speculation for the batch — its counts thread the
+    verify call."""
+    eng = _engine(spec_tokens=2, max_seq_len=64, prefill_buckets=(16,))
+    try:
+        sch = eng.scheduler
+        saved = sch._rows, sch._offsets
+        sch._rows = [
+            SimpleNamespace(penalized=True),
+            SimpleNamespace(penalized=False),
+        ]
+        sch._offsets = np.zeros((2,), np.int32)
         try:
-            sch = eng.scheduler
-            saved = sch._rows, sch._offsets
-            sch._rows = [
-                SimpleNamespace(penalized=True),
-                SimpleNamespace(penalized=False),
-            ]
-            sch._offsets = np.zeros((2,), np.int32)
-            try:
-                assert sch._spec_possible() is expect, (
-                    f"fused={fused}: penalized-row veto wrong"
-                )
-            finally:
-                sch._rows, sch._offsets = saved
+            assert sch._spec_possible() is True
         finally:
-            eng.close()
+            sch._rows, sch._offsets = saved
+    finally:
+        eng.close()
 
 
 # ------------------------------------------------- overlap / readback
 
 
-def test_overlap_parity_under_retirement_and_admission(fused_engine,
-                                                       unfused_engine):
-    """Overlap look-ahead must be invisible in the tokens: 6 requests
-    through 4 rows (queueing + re-admission) with staggered budgets
-    (retirement churn mid-flight) decode identically with the ring on
-    and off."""
+def test_overlap_parity_under_retirement_and_admission(engine):
+    """Look-ahead must be invisible in the tokens: 6 requests through 4
+    rows (queueing + re-admission) with staggered budgets (retirement
+    churn mid-flight) decode each as it does alone."""
     budgets = [8, 12, 16, 20, 24, 28]
-    on = _run_batch(fused_engine, budgets)
-    off = _run_batch(unfused_engine, budgets)
-    assert on == off, "overlap changed tokens under retirement/admission"
+    solo = _solo(engine, budgets)
+    assert _run_batch(engine, budgets) == solo, (
+        "look-ahead changed tokens under retirement/admission"
+    )
     # STREAMED rows (no look-ahead: a window's delivery waits for the burst
     # that its retirements admit, ISSUE 40) send the same tokens, event by
-    # event, on both engines
-    assert _run_streams(fused_engine, budgets) == off
-    assert _run_streams(unfused_engine, budgets) == off
+    # event
+    assert _run_streams(engine, budgets) == solo
 
 
 def _run_streams(eng, budgets):
@@ -277,37 +250,27 @@ def _run_burst(eng, budgets):
         assert ev.get("result") is not None, ev
 
 
-def test_overlap_removes_host_sync_stalls(fused_engine, unfused_engine):
-    """The overlap steady state (uniform budgets, no queue/stream/spec):
-    with the ring on, some readback windows must find another window
-    already in flight (stalls < syncs). With overlap off, EVERY sync is
-    a stall by construction — the serialized loop's 1.0 ratio.
+def test_overlap_removes_host_sync_stalls(engine):
+    """The ring's steady state (uniform budgets, no queue/stream/spec):
+    some readback windows must find another window already in flight
+    (stalls < syncs).
 
-    The overlap side is driven as one admission burst (_run_burst): four
-    rows of 48 tokens at decode_chunk 4 are a first window of 8 chunks with
-    a second of 4 dispatched behind it, so the first readback finds the
-    ring occupied and only the last one stalls. Arriving one by one, as
-    threads under a loaded machine do, each row can run alone on windows
-    that cover its whole budget, and every sync is then rightly a stall."""
+    Driven as one admission burst (_run_burst): four rows of 48 tokens at
+    decode_chunk 4 are a first window of 8 chunks with a second of 4
+    dispatched behind it, so the first readback finds the ring occupied
+    and only the last one stalls. Arriving one by one, as threads under a
+    loaded machine do, each row can run alone on windows that cover its
+    whole budget, and every sync is then rightly a stall."""
     budgets = [48] * ROWS
     s0, t0 = _C_HOST_SYNCS.value(), _C_SYNC_STALLS.value()
-    _run_burst(fused_engine, budgets)
+    _run_burst(engine, budgets)
     syncs, stalls = _C_HOST_SYNCS.value() - s0, _C_SYNC_STALLS.value() - t0
-    assert syncs > 0
-    assert stalls < syncs, (
-        f"overlap never kept the ring full: {stalls}/{syncs} stalled"
-    )
-
-    _run_batch(unfused_engine, budgets)  # warm
-    s0, t0 = _C_HOST_SYNCS.value(), _C_SYNC_STALLS.value()
-    _run_batch(unfused_engine, budgets)
-    syncs, stalls = _C_HOST_SYNCS.value() - s0, _C_SYNC_STALLS.value() - t0
-    assert syncs > 0 and stalls == syncs, (
-        f"serialized loop must stall every sync: {stalls}/{syncs}"
+    assert (syncs, stalls) == (2, 1), (
+        f"the ring did not hold its second window: {stalls}/{syncs} stalled"
     )
 
 
-def test_overlap_chain_compile_space_is_pinned(fused_engine):
+def test_overlap_chain_compile_space_is_pinned(engine):
     """Sharding-keyed double-compile regression: a ring-empty dispatch
     re-enters the decode chain from the host numpy mirrors, which lower
     with a DIFFERENT arg sharding than chained device outputs — without
@@ -316,14 +279,14 @@ def test_overlap_chain_compile_space_is_pinned(fused_engine):
     Post-warm, repeat steady-state batches (each one draining the ring
     and re-entering from the mirrors) must compile NOTHING new."""
     budgets = [32] * ROWS
-    _run_batch(fused_engine, budgets)  # warm every (bsz, width) key
-    traces0 = _decode_traces(fused_engine)
+    _run_batch(engine, budgets)  # warm every (bsz, width) key
+    traces0 = _decode_traces(engine)
     for _ in range(2):
-        _run_batch(fused_engine, budgets)
-    assert _decode_traces(fused_engine) == traces0, (
+        _run_batch(engine, budgets)
+    assert _decode_traces(engine) == traces0, (
         "steady-state repeat batches recompiled the decode root"
     )
-    snap = fused_engine.introspect.sentinel.snapshot()
+    snap = engine.introspect.sentinel.snapshot()
     assert snap["decode"]["storms"] == 0
 
 
@@ -332,9 +295,9 @@ def test_overlap_chain_compile_space_is_pinned(fused_engine):
 
 def test_sticky_width_holds_bucket_and_releases_on_idle():
     """Grow-only while work flows: after a staggered batch fully
-    retires, the sticky bucket holds its width through the hysteresis
-    window — and only an idle sweep past `_sticky_idle_s` drops it."""
-    eng = _engine(batch_sticky=True)
+    retires, the bucket holds its width through the hysteresis window —
+    and only an idle sweep past `_sticky_idle_s` drops it."""
+    eng = _engine()
     try:
         _run_batch(eng, [4, 8, 12, 16])
         sch = eng.scheduler
@@ -352,52 +315,17 @@ def test_sticky_width_holds_bucket_and_releases_on_idle():
         eng.close()
 
 
-def test_nonsticky_width_walks_the_resize_ladder():
-    """The pre-sticky behavior the knob reverts to: quarter-occupancy
-    halving plus idle release — after the staggered batch retires the
-    bucket is back at 1."""
-    eng = _engine(batch_sticky=False)
-    try:
-        _run_batch(eng, [4, 8, 12, 16])
-        sch = eng.scheduler
-        deadline = time.monotonic() + 5.0
-        while sch._bsz != 1 and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert sch._bsz == 1, (
-            f"non-sticky bucket held width {sch._bsz} after idle"
-        )
-    finally:
-        eng.close()
-
-
 def test_sticky_width_avoids_retirement_retraces():
-    """The retrace economics the sticky bucket buys (the decode_hotloop
-    rung's tok/s story): post-warm, a staggered-budget batch walks the
-    pow2 resize ladder through decode traces the warm server never
-    compiled on the non-sticky engine — and through ZERO new traces on
-    the sticky one."""
+    """What the grow-only bucket buys: post-warm, a staggered-budget
+    batch retires row by row through ZERO new decode traces."""
     churn = [8, 16, 24, 32]
-    eng = _engine(batch_sticky=True)
+    eng = _engine()
     try:
         _run_batch(eng, [32] * ROWS)  # warm the full-width traces
         traces0 = _decode_traces(eng)
         _run_batch(eng, churn)
         assert _decode_traces(eng) == traces0, (
-            "sticky engine recompiled decode during retirement churn"
-        )
-    finally:
-        eng.close()
-
-    eng = _engine(batch_sticky=False)
-    try:
-        _run_batch(eng, [32] * ROWS)
-        traces0 = _decode_traces(eng)
-        _run_batch(eng, churn)
-        assert _decode_traces(eng) > traces0, (
-            "expected the non-sticky resize ladder to hit fresh decode "
-            "traces under staggered retirement (the churn cost sticky "
-            "removes) — if this now passes without sticky, the rung's "
-            "mechanism story needs re-measuring"
+            "the engine recompiled decode during retirement churn"
         )
     finally:
         eng.close()
@@ -409,7 +337,7 @@ def test_sticky_growth_is_hbm_gated(monkeypatch):
     the denial is counted, and the queued requests still complete by
     retrying into retirement holes at the current width."""
     monkeypatch.setenv("BEE2BEE_HBM_BYTES", "1024")
-    eng = _engine(batch_sticky=True)
+    eng = _engine()
     try:
         tokens = _run_batch(eng, [4, 4, 4, 4])
         assert all(len(t) == 4 for t in tokens)

@@ -257,8 +257,7 @@ def test_engine_decode_matches_full_forward(solo):
         eng.close()
 
 
-@pytest.mark.parametrize("over", [{}, {"prefill_chunk": 16}, {"decode_overlap": False},
-                                  {"batch_sticky": False}])
+@pytest.mark.parametrize("over", [{}, {"prefill_chunk": 16}])
 def test_rows_admitted_retired_compacted_resized_equal_their_solo_runs(solo, over):
     """(d) five requests over four rows, admitted at different times, of
     different lengths (so rows retire while others decode, holes compact and

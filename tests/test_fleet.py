@@ -948,15 +948,21 @@ async def test_dead_replica_below_min_is_repaired_from_standby():
     crashed replica's digest goes stale and vanishes — it reports no
     burn, so only the repair path can activate the warm standby."""
     async with _fleet(
-        controllers=1, actives=1, standbys=1, cfg=_cfg(min_replicas=2),
+        controllers=1, actives=1, standbys=1,
     ) as (nodes, ctrls, acts, stands):
         leader = await _settle_leader(ctrls)
         standby = stands[0]
-        # eligible = controller + active = min_replicas: steady state
         assert await _settle(
             lambda: (leader.fleet._last_agg or {}).get("eligible") == 2,
             timeout=10,
         )
+        # the floor goes up only once the fleet it guards stands whole:
+        # _fleet assembles the nodes around a controller that already
+        # ticks, and a leader that reigns before the active's first digest
+        # arrives (a starved worker) would "repair" a replica that is only
+        # late to report — the standby gone before the kill, eligible 3
+        assert standby.fleet_state == "standby"
+        leader.fleet.config = _cfg(min_replicas=2)  # = controller + active
         await hard_kill(acts[0])  # no drain flag, no burn — just gone
         assert await _settle(
             lambda: standby.fleet_state is None, timeout=30
@@ -964,7 +970,11 @@ async def test_dead_replica_below_min_is_repaired_from_standby():
             f"standby never activated after the replica died; journal: "
             f"{list(leader.fleet.decisions)[-5:]}"
         )
-        assert leader.fleet.stats["scale_out"] == 1
+        # the standby flips (and gossips) BEFORE it acks, and the leader
+        # books the scale-out only on that ack
+        assert await _settle(
+            lambda: leader.fleet.stats["scale_out"] == 1, timeout=10
+        ), leader.fleet.stats
 
 
 @pytest.mark.async_timeout(120)

@@ -566,8 +566,7 @@ def test_engine_decode_matches_full_forward(solo):
         eng.close()
 
 
-@pytest.mark.parametrize("over", [{}, {"attention": "flash"}, {"prefill_chunk": 16},
-                                  {"decode_overlap": False}],
+@pytest.mark.parametrize("over", [{}, {"attention": "flash"}, {"prefill_chunk": 16}],
                          ids=lambda o: "-".join(f"{k}={v}" for k, v in o.items()) or "default")
 def test_rows_admitted_retired_compacted_equal_their_solo_runs(solo, over):
     """Five requests over four rows, admitted at different times, of different

@@ -574,17 +574,16 @@ def test_paged_prefix_entries_reclaimed_under_pressure():
 def test_cache_reads_scale_with_live_blocks_not_capacity():
     """The acceptance property: with max_batch=8 and ONE short active
     request, the decode gather reads a few live blocks per step — not the
-    rectangular bsz * ceil(max_seq/block) equivalent. Pinned on the
-    resize-ladder path: the sticky bucket (docs/PERF.md "Decode hot
-    loop") deliberately holds the retired batch's width through its
-    idle-hysteresis window, so the lone request would gather across the
-    held 8-row bucket — the documented trace-stability-for-read-width
-    trade, not a violation of this property."""
+    rectangular bsz * ceil(max_seq/block) equivalent. The bucket's idle
+    hysteresis is collapsed: the grow-only bucket (docs/PERF.md "Decode hot
+    loop") deliberately holds the retired batch's width through that
+    window, so the lone request would gather across the held 8-row bucket
+    — the documented trace-stability-for-read-width trade, not a violation
+    of this property."""
     eng = InferenceEngine(
-        "tiny-llama",
-        engine_config=EngineConfig(max_batch=8,
-                                   batch_sticky=False, **KW),
+        "tiny-llama", engine_config=EngineConfig(max_batch=8, **KW),
     )
+    eng.scheduler._sticky_idle_s = 0.0
     try:
         # warm the batch up to 8 rows so the engine has seen full occupancy
         threads = [

@@ -4,8 +4,8 @@ is an OPERAND of the one decode program, chosen at every dispatch.
 - the program: ``n`` steps of the traced-bound loop are the first ``n``
   steps of the scan it replaces (tokens, carry, pool, state, the expert
   counters), for a dense paged model, a recurrent one, a dropless-expert one
-  and a looped stack, greedy and seeded, the fused root and the split
-  penalty root; windows of a and of b steps equal one of a + b;
+  and a looped stack, greedy and seeded, without and with the penalty
+  counts; windows of a and of b steps equal one of a + b;
 - the policy as a pure function of what the scheduler observes;
 - the host's books after a window of ``n`` steps;
 - streamed requests behind a standing queue keep more of their decode slots
@@ -59,8 +59,8 @@ def _engine(model: str = "tiny-llama", **over) -> InferenceEngine:
 
 def _scan_reference(sch, n: int):
     """The decode chunk as a plain ``lax.scan`` over the first ``n`` of the
-    chunk's keys (the program this PR replaced, cut to ``n`` steps), under
-    the fused root's calling convention. Not donating: it runs BESIDE the
+    chunk's keys (the program PR 49 replaced, cut to ``n`` steps), under
+    the decode root's calling convention. Not donating: it runs BESIDE the
     served call on the same arguments."""
     e = sch.engine
 
@@ -107,20 +107,19 @@ _REFS: dict = {}
 
 
 class _Beside:
-    """Wraps a decode root: every served call is first run as the scan
+    """Wraps the decode root: every served call is first run as the scan
     reference on the same arguments, and the two are compared AFTER the
     served call (on the scheduler thread: what differs is kept for the test
     thread to assert on)."""
 
-    def __init__(self, sch, served, to_fused):
-        self.sch, self.served, self.to_fused = sch, served, to_fused
+    def __init__(self, sch, served):
+        self.sch, self.served = sch, served
         self.steps: list[int] = []
         self.wrong: list[str] = []
 
     def __call__(self, *args, **kwargs):
         kw = dict(kwargs)
         n = int(kw.pop("steps"))
-        fargs, fkw = self.to_fused(args, kw)
         # carry, pool and extras are compared at n = K and at the 3 steps a
         # request alone is sure to run; any other n against the first n
         # tokens of the K-step scan (a compile an (engine, n) is the cost)
@@ -129,7 +128,7 @@ class _Beside:
         if ref is None:
             ref = _REFS[id(self.sch), n if whole else K] = _scan_reference(
                 self.sch, n if whole else K)
-        want = ref(*fargs, **fkw)
+        want = ref(*args, **kw)
         got = self.served(*args, **kwargs)
         self.steps.append(n)
         cur, pool, off, cnt, toks, extras = got
@@ -143,19 +142,6 @@ class _Beside:
         if not np.array_equal(np.asarray(toks)[:, :n], np.asarray(want[4])[:, :n]):
             self.wrong.append(f"tokens of {n} steps")
         return got
-
-
-def _fused_args(args, kw):
-    return args, kw
-
-
-def _pen_args(args, kw):
-    """_decode_pen's positional convention -> the fused root's."""
-    (params, cur, cache, offsets, counts, temps, topks, topps, minps,
-     reps, press, freqs, key, *rest) = args
-    tables = rest[0] if rest else kw.pop("tables", None)
-    return ((params, cur, cache, offsets, temps, topks, topps, minps, key, tables),
-            dict(kw, counts=counts, reps=reps, press=press, freqs=freqs))
 
 
 def _generate_all(eng, budgets, **gen):
@@ -199,7 +185,7 @@ def test_n_steps_of_the_loop_are_the_first_n_of_the_scan(model_engine, monkeypat
     """tokens, carry, pool and what rides the carry (the recurrent state
     after n steps, moe_stats of n steps) against the scan, at n = K and n < K."""
     sch = model_engine.scheduler
-    beside = _Beside(sch, sch._decode, _fused_args)
+    beside = _Beside(sch, sch._decode)
     monkeypatch.setattr(sch, "_decode", beside)
     got = _generate_all(model_engine, BUDGETS, **SAMPLING[sampling])
     monkeypatch.undo()
@@ -210,19 +196,13 @@ def test_n_steps_of_the_loop_are_the_first_n_of_the_scan(model_engine, monkeypat
 
 
 @pytest.mark.parametrize("sampling", list(SAMPLING))
-@pytest.mark.parametrize("root", ["fused_counts", "split_penalty_root"])
-def test_the_penalty_roots_run_n_steps_token_for_token(root, sampling):
-    """counts ride the loop carry: the fused root with counts, and the split
-    _decode_pen_fn under its own calling convention."""
-    eng = _engine(fused_root=(root == "fused_counts"))
+def test_the_penalty_roots_run_n_steps_token_for_token(sampling):
+    """counts ride the loop carry of the one decode root."""
+    eng = _engine()
     try:
         sch = eng.scheduler
-        if root == "fused_counts":
-            beside = _Beside(sch, sch._decode, _fused_args)
-            sch._decode = beside
-        else:
-            beside = _Beside(sch, sch._decode_pen, _pen_args)
-            sch._decode_pen = beside
+        beside = _Beside(sch, sch._decode)
+        sch._decode = beside
         got = _generate_all(eng, BUDGETS, repetition_penalty=1.3,
                             presence_penalty=0.2, **SAMPLING[sampling])
         assert not beside.wrong, beside.wrong
@@ -271,47 +251,44 @@ def test_windows_of_a_and_b_steps_equal_one_of_a_plus_b(model_engine, monkeypatc
     assert _same(ex_two, ex_one)
 
 
-# ------------------------------------------- the sentinel still keys the decode roots
+# ------------------------------------------- the sentinel still keys the decode root
 
 
-@pytest.mark.parametrize("root", ["decode", "decode_penalized"])
-def test_the_sentinel_keys_a_dispatch_that_passes_steps(root, tmp_path):
-    """Every dispatch passes ``steps=``: the roots' key functions take it
+@pytest.mark.parametrize("window", ["plain", "penalised"])
+def test_the_sentinel_keys_a_dispatch_that_passes_steps(window, tmp_path):
+    """Every dispatch passes ``steps=``: the root's key function takes it
     (a key function that raised would leave the root un-keyed: counted,
-    never classified), the key does not gain a field, and a batch width off
-    the declared ladder still raises the incident."""
-    eng = _engine(fused_root=(root == "decode"))
+    never classified), the key does not gain a field (its last flag says
+    whether the counts ride), and a batch width off the declared ladder
+    still raises the incident."""
+    eng = _engine()
     rec = FlightRecorder(incident_dir=tmp_path)
     sentinel = eng.introspect.sentinel
     sentinel._recorder = rec
     try:
-        pen = dict(repetition_penalty=1.3) if root == "decode_penalized" else {}
-        eng.generate(_prompt(0, 9), max_new_tokens=6, **pen)  # served windows
+        pen = window == "penalised"
+        eng.generate(_prompt(0, 9), max_new_tokens=6,  # served windows
+                     **(dict(repetition_penalty=1.3) if pen else {}))
         sch = eng.scheduler
-        watched = sentinel._roots[root]
+        watched = sentinel._roots["decode"]
         assert watched.traces >= 1 and watched.storms == 0
-        flags = (False, False) if root == "decode_penalized" else (False, False, False)
         assert watched.seen and all(
-            key[0] in eng._declared_batch_sizes and key[2:] == flags
+            key[0] in eng._declared_batch_sizes and key[2:] == (False, False, pen)
             for key in watched.seen), watched.seen
         B = 3  # max_batch 4: the ladder is 1, 2, 4
         assert B not in eng._declared_batch_sizes
         zi, zf = np.zeros(B, np.int32), np.zeros(B, np.float32)
         tables = np.zeros((B, sch.cache.tables.shape[1]), np.int32)
-        key = eng._next_key()
-        if root == "decode":
-            out = sch._decode(eng.params, zi, sch.cache.pool, zi, zf, zi, zf + 1, None,
-                              key, tables, steps=np.int32(2))
-        else:
-            counts = jnp.zeros((B, 2, eng.model_cfg.vocab_size), jnp.int32)
-            out = sch._decode_pen(eng.params, zi, sch.cache.pool, zi, counts, zf, zi,
-                                  zf + 1, None, zf + 1, zf, zf, key, tables,
-                                  steps=np.int32(2))
+        counts = dict(
+            counts=jnp.zeros((B, 2, eng.model_cfg.vocab_size), jnp.int32),
+            reps=zf + 1, press=zf, freqs=zf) if pen else {}
+        out = sch._decode(eng.params, zi, sch.cache.pool, zi, zf, zi, zf + 1, None,
+                          eng._next_key(), tables, steps=np.int32(2), **counts)
         sch.cache.pool = out[1]  # the call donated the pool
-        assert sentinel.snapshot()[root]["storms"] == 1
+        assert sentinel.snapshot()["decode"]["storms"] == 1
         rec.flush()
         bundle = rec.load_incident(rec.list_incidents()[0]["id"])
-        assert bundle["extra"]["root"] == root and "UNDECLARED" in bundle["detail"]
+        assert bundle["extra"]["root"] == "decode" and "UNDECLARED" in bundle["detail"]
         assert bundle["extra"]["key"].startswith(f"({B}, ")
     finally:
         eng.close()
