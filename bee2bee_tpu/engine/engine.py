@@ -334,7 +334,7 @@ class InferenceEngine:
             # layer dot drops to a naive kernel (measured 20x per block on
             # distilgpt2 decode). Unrolled layers compile O(L) but CPU
             # compiles fast; TPU keeps the stacked lax.scan (core.forward).
-            params = core.unstack_layers(jax.device_get(params))
+            params = core.unstack_layers(jax.device_get(params), self.model_cfg)
         self.params = partition.shard_params(params, self.mesh, cfg=self.model_cfg)
         self.tokenizer = tokenizer or load_tokenizer(checkpoint_path, self.model_cfg.vocab_size)
 
@@ -698,10 +698,12 @@ class InferenceEngine:
         cfg = self.model_cfg
         if not cfg.has_ssm:
             return None
-        ssm = (cfg.n_layers, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-        conv = (cfg.n_layers, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
+        # as deep as the layers that HOLD a mixer (cfg.state_layers)
+        ssm = (cfg.state_layers, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        conv = (cfg.state_layers, cfg.ssm_conv - 1, cfg.ssm_conv_dim)
         row = int(np.prod(ssm)) * 4 + int(np.prod(conv)) * self.dtype.itemsize
         return {
+            "layers": int(cfg.state_layers),
             "ssm_row_shape": list(ssm), "ssm_dtype": "float32",
             "conv_row_shape": list(conv), "conv_dtype": str(self.dtype),
             "bytes_per_row": row,
@@ -750,6 +752,7 @@ class InferenceEngine:
             state = core.init_ssm_state(
                 self.model_cfg, tokens.shape[0], dtype=self.dtype)
         moe = self.model_cfg.moe_dropless
+        stats = len(core.moe_stats_names(self.model_cfg))
         # the head for ONE position a row (recurrent models since PR 28, and
         # latent-attention ones: at a 129,280-token vocabulary the full
         # [1, 512, V] logits are 0.27 GB; smallthinker's [1, 2048, 151,936]
@@ -757,8 +760,7 @@ class InferenceEngine:
         one_logit = recurrent or self.model_cfg.has_mla or moe
         last = jnp.maximum(jnp.asarray(true_len, jnp.int32) - 1, 0)  # dead row: 0
         if moe:  # the forward's expert-layer counters: extras["moe_stats"]
-            cache = dict(cache, moe_stats=jnp.zeros(
-                (len(core.MOE_STATS),), jnp.int32))
+            cache = dict(cache, moe_stats=jnp.zeros((stats,), jnp.int32))
         logits, cache = core.forward(
             params, self.model_cfg, tokens,
             dict(cache, **state) if recurrent else cache, offset,

@@ -202,12 +202,13 @@ def _phase(name: str):
 _C_SSM_STEP_ROWS = _REG.counter(
     "engine.ssm_step_rows",
     "one-step recurrent updates dispatched: rows x decode steps x layers "
-    "(kind label: live | dead rows of the batch bucket)",
+    "that hold a mixer (kind label: live | dead rows of the batch bucket)",
 )
 _C_SSM_STEP_KERNEL_CALLS = _REG.counter(
     "engine.ssm_step_kernel_calls",
     "calls of the one-step state kernel (ops/ssm_step.py) dispatched: "
-    "decode steps x layers of every decode window of a recurrent model",
+    "decode steps x layers that hold a mixer, of every decode window of a "
+    "recurrent model",
 )
 _C_SSM_SCAN_TOKENS = _REG.counter(
     "engine.ssm_scan_tokens",
@@ -219,7 +220,9 @@ _C_MOE_ASSIGNMENTS = _REG.counter(
     "(token, expert) assignments of the dropless expert layer dispatched: "
     "positions x experts a token x expert layers of every forward (kind "
     "label: live | dead = dead rows of the batch bucket and a prefill "
-    "bucket's padded tail, which reach no expert)",
+    "bucket's padded tail, which reach no expert | elsewhere = live "
+    "assignments whose expert another chip holds, under an expert share: "
+    "no product here; live then counts the assignments computed HERE)",
 )
 _C_MOE_LAYER_CALLS = _REG.counter(
     "engine.moe_layer_calls",
@@ -228,7 +231,8 @@ _C_MOE_LAYER_CALLS = _REG.counter(
 )
 _C_MOE_EXPERTS_HIT = _REG.counter(
     "engine.moe_experts_hit",
-    "distinct experts with at least one live assignment, summed over "
+    "distinct experts (of those held here) with at least one live "
+    "assignment, summed over "
     "expert-layer calls: counted on the device, fetched with the window's "
     "tokens (x an expert's bytes = the weights the grouped products read)",
 )
@@ -932,7 +936,8 @@ class BatchScheduler:
         out. Models without a dropless expert layer keep their trace."""
         if not self.engine.model_cfg.moe_dropless:
             return cache
-        return dict(cache, moe_stats=jnp.zeros((len(core.MOE_STATS),), jnp.int32))
+        return dict(cache, moe_stats=jnp.zeros(
+            (len(core.moe_stats_names(self.engine.model_cfg)),), jnp.int32))
 
     # ------------------------------------------------------------ loop
 
@@ -2535,7 +2540,7 @@ class BatchScheduler:
                 off_d = jax.device_put(off_d, self._chain_sharding[1])
         lora = dict(self._lora_args())
         if c.recurrent:
-            steps = n * e.model_cfg.n_layers
+            steps = n * e.model_cfg.state_layers  # the layers with a mixer
             _C_SSM_STEP_ROWS.inc(self.active * steps, kind="live")
             _C_SSM_STEP_ROWS.inc((self._bsz - self.active) * steps, kind="dead")
             _C_SSM_STEP_KERNEL_CALLS.inc(steps)
@@ -2687,21 +2692,27 @@ class BatchScheduler:
         if not cfg.moe_dropless:
             return
         per = cfg.n_experts_per_tok * cfg.n_expert_layers
-        _C_MOE_ASSIGNMENTS.inc(live * per, kind="live")
+        if not cfg.expert_share:  # a share's split is the device's to say
+            _C_MOE_ASSIGNMENTS.inc(live * per, kind="live")
         _C_MOE_ASSIGNMENTS.inc(dead * per, kind="dead")
         _C_MOE_LAYER_CALLS.inc(forwards * cfg.n_expert_layers)
 
     def _note_moe(self, stats: list, windows: int):
         """The device's half, fetched with a window's tokens: ``stats`` are
-        the [hit, max_load, live] vectors (core.MOE_STATS) of the
+        the [hit, max_load, live] vectors (core.moe_stats_names) of the
         prefills since the last window, then of this window's ``windows``
-        chunks (the gauge reads those alone)."""
+        chunks (the gauge reads those alone). Under an expert share they
+        speak of the experts held HERE, a fourth entry counts the live
+        assignments held elsewhere, and both kinds are counted from it."""
+        cfg = self.engine.model_cfg
         got = np.asarray(stats, np.int64)
         _C_MOE_EXPERTS_HIT.inc(int(got[:, 0].sum()))
-        _, max_load, live = got[-windows:].sum(axis=0)
+        if cfg.expert_share:
+            _C_MOE_ASSIGNMENTS.inc(int(got[:, 2].sum()), kind="live")
+            _C_MOE_ASSIGNMENTS.inc(int(got[:, 3].sum()), kind="elsewhere")
+        _, max_load, live = got[-windows:, :3].sum(axis=0)
         if live:
-            _G_MOE_LOAD.set(
-                float(max_load) * self.engine.model_cfg.n_experts / float(live))
+            _G_MOE_LOAD.set(float(max_load) * cfg.experts_held / float(live))
 
     @_phase("settle")
     def _settle_window(self, rec, toks_host: np.ndarray) -> bool:

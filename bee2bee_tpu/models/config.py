@@ -28,7 +28,8 @@ class ModelConfig:
     max_seq_len: int = 2048
     # architecture switches
     pos_embedding: str = "rope"  # "rope" | "learned" | "alibi" (bloom:
-    # linear attention-score bias per head, no embedding-side positions)
+    # linear attention-score bias per head, no embedding-side positions) |
+    # "nope" (granite-4.0-h: no positional encoding anywhere)
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
     norm_bias: bool = True  # layernorm only: mpt ships weight-only norms
     activation: str = "silu"  # "silu" (gated) | "gelu" (tanh approx, gpt2/
@@ -174,6 +175,27 @@ class ModelConfig:
     # every pass keeps K/V of its own: cache_layers below is what sizes a
     # cache, n_layers stays the count of WEIGHT layers. 1 = a plain stack
     loop_steps: int = 1
+    # granite-4.0-h (granitemoehybrid): a layer's token mixer is ONE of a
+    # recurrent mixer or attention, by config.json's ``layer_types`` ("mamba"
+    # / "attention", one entry a layer). () = every layer alike, and ssm_heads
+    # then means a mixer BESIDE attention in every block (falcon-h1). The
+    # state is as deep as the recurrent layers (state_layers), every cache as
+    # deep as the attention layers (cache_layers); a layer finds its own by
+    # state_slots / cache_slots, and n_layers sizes neither
+    layer_types: tuple = ()
+    # the chip's share of every layer's routed experts (a dropless expert
+    # layer only): it holds n_experts_held of them from expert_first on
+    # (0 = all). The router keeps n_experts outputs and n_experts_per_tok
+    # choices; an assignment to an expert held elsewhere is computed
+    # elsewhere: no product here, weight zero, counted apart. Nothing stands
+    # in for the absent chips or their exchange
+    n_experts_held: int = 0
+    expert_first: int = 0
+    # the shared expert's own width (granite's shared_intermediate_size);
+    # 0 = n_shared_experts experts of the routed width
+    d_ff_shared: int = 0
+    # granite: BOTH residual adds of a block take their branch times this
+    residual_multiplier: float = 1.0
 
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
@@ -217,10 +239,10 @@ class ModelConfig:
                 "no_pre_norms requires post_norms — the block would have "
                 "ZERO normalization otherwise (olmo2 sets both)"
             )
-        if self.pos_embedding not in ("rope", "learned", "alibi"):
+        if self.pos_embedding not in ("rope", "learned", "alibi", "nope"):
             raise ValueError(
                 f"pos_embedding={self.pos_embedding!r} must be 'rope', "
-                f"'learned', or 'alibi'"
+                f"'learned', 'alibi' or 'nope'"
             )
         if self.rope_style not in ("half", "interleaved"):
             # a typo here would silently rotate the wrong way (core._rope
@@ -250,13 +272,43 @@ class ModelConfig:
                 "(moe_router 'sigmoid' / 'softmax_topk') of a sequential "
                 "pre-norm block"
             )
-        if (self.moe_router == "softmax_topk" and self.n_experts
-                and self.moe_router_input != "attn_norm"):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            kinds = set(self.layer_types)
+            if (len(self.layer_types) != self.n_layers
+                    or not kinds <= {"mamba", "attention"}
+                    or kinds != {"mamba", "attention"} or not self.ssm_heads):
+                raise ValueError(
+                    f"layer_types={self.layer_types!r} must name each of the "
+                    f"{self.n_layers} layers 'mamba' or 'attention', hold at "
+                    "least one of each (the state and the pool are as deep "
+                    "as their kinds) and come with the mixer's sizes "
+                    "(ssm_heads)"
+                )
+            if (self.loop_steps > 1 or self.mla_kv_rank or self.first_k_dense
+                    or self.parallel_block or self.no_pre_norms
+                    or self.sliding_window):
+                raise ValueError(
+                    "layer_types (one mixer kind a layer) is built for "
+                    "sequential pre-norm blocks with plain full attention: no "
+                    "looped stack, latent attention, leading dense layers, "
+                    "parallel block or sliding window"
+                )
+        if self.n_experts_held or self.expert_first:
+            held = self.n_experts_held or self.n_experts
+            if (self.moe_router == "softmax" or held < 1
+                    or self.expert_first < 0
+                    or self.expert_first + held > self.n_experts):
+                raise ValueError(
+                    f"n_experts_held={self.n_experts_held} from expert_first="
+                    f"{self.expert_first} must be a range of the "
+                    f"{self.n_experts} experts of a dropless expert layer "
+                    "(moe_router 'sigmoid' / 'softmax_topk')"
+                )
+        if self.d_ff_shared and not self.n_shared_experts:
             raise ValueError(
-                "moe_router='softmax_topk' is built with moe_router_input="
-                "'attn_norm' only (core.center_router evens a seeded router "
-                "on the pre-attention norm's output)"
-            )
+                f"d_ff_shared={self.d_ff_shared} needs a shared expert "
+                "(n_shared_experts)")
         if self.rope_sliding_only and not (
             self.sliding_window and self.sliding_window_every > 1
             and self.pos_embedding == "rope"
@@ -322,8 +374,60 @@ class ModelConfig:
         looped stack, pass ``t``'s layer ``l`` at index ``t * n_layers + l``
         (core.forward). THE one number that sizes a pool, a rectangular
         cache, a block's bytes and a cached token's attention work; equal to
-        n_layers for every plain stack."""
+        n_layers for every plain stack. Under ``layer_types`` only the
+        attention layers cache anything (cache_slots)."""
+        if self.layer_types:
+            return self.layer_types.count("attention")
         return self.n_layers * self.loop_steps
+
+    @property
+    def state_layers(self) -> int:
+        """Layers of recurrent STATE a row holds (core.init_ssm_state's
+        leading axis): every layer where the mixer runs beside attention in
+        every block, the "mamba" layers under ``layer_types``, 0 without a
+        mixer."""
+        if not self.ssm_heads:
+            return 0
+        return (self.layer_types.count("mamba") if self.layer_types
+                else self.n_layers)
+
+    def _slots(self, kind: str) -> tuple:
+        n, out = 0, []
+        for t in self.layer_types:
+            out.append(n if t == kind else -1)
+            n += t == kind
+        return tuple(out)
+
+    @property
+    def state_slots(self) -> tuple:
+        """Layer -> its slot of the state (-1: the layer has no mixer). The
+        identity where every layer has one."""
+        if self.layer_types:
+            return self._slots("mamba")
+        return tuple(range(self.n_layers)) if self.ssm_heads else ()
+
+    @property
+    def cache_slots(self) -> tuple:
+        """Layer -> its layer of a cache (-1: the layer has no attention).
+        The identity for a plain stack (a looped stack adds the pass's base:
+        core.forward)."""
+        if self.layer_types:
+            return self._slots("attention")
+        return tuple(range(self.n_layers))
+
+    @property
+    def layer_runs(self) -> tuple:
+        """``layer_types`` as RUNS of like layers, in order: (kind, first
+        layer, count, the first layer's slot of its kind). core.forward
+        scans a run at a time; a pattern need not be periodic."""
+        runs, slots = [], {"mamba": self.state_slots,
+                           "attention": self.cache_slots}
+        for i, t in enumerate(self.layer_types):
+            if runs and runs[-1][0] == t:
+                runs[-1][2] += 1
+            else:
+                runs.append([t, i, 1, slots[t][i]])
+        return tuple(tuple(r) for r in runs)
 
     @property
     def layer_windows(self) -> tuple:
@@ -331,6 +435,8 @@ class ModelConfig:
         fully): core.is_sliding_layer's rule, for what is counted per layer
         KIND (the ragged read's tiles, the tokens behind a window)."""
         w = int(self.sliding_window or 0)
+        if self.layer_types:  # one entry a CACHE layer: the attention layers'
+            return (0,) * self.cache_layers
         return tuple(
             w if i % self.sliding_window_every in self.sliding_window_residues
             else 0 for i in range(self.n_layers)) * self.loop_steps
@@ -372,9 +478,26 @@ class ModelConfig:
 
     @property
     def has_ssm(self) -> bool:
-        """A recurrent mixer runs in every block: each row then owns a
-        slot of recurrent state beside its K/V pages (core.init_ssm_state)."""
+        """A recurrent mixer runs in some or all layers (beside attention in
+        every block, or INSTEAD of it in the "mamba" layers of
+        ``layer_types``): each row then owns a slot of recurrent state,
+        state_layers deep, beside its K/V pages (core.init_ssm_state)."""
         return self.ssm_heads > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts a layer holds HERE (all of them without a share)."""
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Does this chip hold only a share of every layer's experts?"""
+        return 0 < self.experts_held < self.n_experts
+
+    @property
+    def shared_ff(self) -> int:
+        """The shared expert's width (0 = none)."""
+        return self.d_ff_shared or self.n_shared_experts * self.expert_ff
 
     @property
     def ssm_inner(self) -> int:
@@ -843,6 +966,58 @@ CONFIGS["tiny-smallthinker"] = ModelConfig(
 )
 
 
+_GRANITE_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+_GRANITE_4_H_SMALL = dict(
+    # ibm-granite/granite-4.0-h-small config.json (model_type
+    # granitemoehybrid, 32B-A9B): a layer's mixer is a Mamba-2 mixer (128
+    # heads x 64, state 128, 1 group, conv 4 with bias, chunk 256) OR GQA
+    # 32/8 x 128 attention with NO positional encoding and scores times
+    # 1/128, in a period of ten (m m m m m a m m m m); every layer 72 SwiGLU
+    # experts 768 wide, top-10 by logit, weights a softmax over the ten, the
+    # router fed the post-mixer norm, beside one shared SwiGLU expert 1,536
+    # wide; both residual adds times 0.22; embeddings times 12, a tied head
+    # over 100,352 tokens with logits / 16
+    vocab_size=100352, d_model=4096, n_heads=32, n_kv_heads=8, d_ff=768,
+    max_seq_len=131072, pos_embedding="nope",
+    norm_eps=1e-5, tie_embeddings=True, attn_scale=16384.0,
+    ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1, ssm_conv=4,
+    ssm_chunk=256,
+    embedding_multiplier=12.0, lm_head_multiplier=0.0625,
+    residual_multiplier=0.22,
+    n_experts=72, n_experts_per_tok=10, moe_router="softmax_topk",
+    n_shared_experts=1, d_ff_shared=1536,
+)
+CONFIGS["granite-4.0-h-small"] = ModelConfig(
+    # as published: 40 layers, every expert held (64 GB of bf16: it loads
+    # only where it fits)
+    name="granite-4.0-h-small", n_layers=40,
+    layer_types=_GRANITE_PERIOD * 4, **_GRANITE_4_H_SMALL)
+CONFIGS["granite-4.0-h-small-10l-e36"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/granite-4.0-h-small-
+    # 10l-e36.json): one period of ten layers, every width and the whole
+    # vocabulary, experts 0-35 of every layer's 72: the first chip of the
+    # first of four two-chip pipeline stages, with the final norm and head
+    # added; experts 36-71 lie on the partner chip
+    name="granite-4.0-h-small-10l-e36", n_layers=10,
+    layer_types=_GRANITE_PERIOD, n_experts_held=36, **_GRANITE_4_H_SMALL)
+CONFIGS["tiny-granite"] = ModelConfig(
+    # every mechanism at CPU-test size: m m a m m (two runs of unlike length
+    # round the one attention layer), 8 experts top-3 of which 4 are held
+    # from the 4th on, a shared expert of another width, head size != state
+    # size, a chunk shorter than the test prompts, every scalar off 1
+    name="tiny-granite", vocab_size=512, d_model=64, n_layers=5, n_heads=4,
+    n_kv_heads=2, d_ff=24, max_seq_len=256,
+    pos_embedding="nope", norm_eps=1e-5, tie_embeddings=True, attn_scale=64.0,
+    ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=1, ssm_conv=4,
+    ssm_chunk=8,
+    embedding_multiplier=3.0, lm_head_multiplier=0.25,
+    residual_multiplier=0.5,
+    layer_types=("mamba", "mamba", "attention", "mamba", "mamba"),
+    n_experts=8, n_experts_per_tok=3, moe_router="softmax_topk",
+    n_experts_held=4, expert_first=4, n_shared_experts=1, d_ff_shared=40,
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -1173,6 +1348,84 @@ def _ouro_from_hf(d: dict, nm: str) -> ModelConfig:
     )
 
 
+def _granite_hybrid_from_hf(d: dict, nm: str) -> ModelConfig:
+    """granitemoehybrid (ibm-granite/granite-4.0-h-*): a Mamba-2 mixer OR
+    NoPE GQA attention a layer (``layer_types``), every layer softmax-top-k
+    experts beside a shared expert. What core does not build is refused BY
+    NAME. ``num_local_experts_held`` / ``expert_first`` (not published keys:
+    a cut configuration's own) give the chip's share of the experts."""
+    published = {  # key -> the one value the implementation covers
+        "position_embedding_type": "nope", "attention_bias": False,
+        "mamba_proj_bias": False, "mamba_conv_bias": True,
+        "rope_scaling": None, "hidden_act": "silu",
+        "normalization_function": "rmsnorm",
+    }
+    for key, want in published.items():
+        got = d.get(key, want)
+        if got != want:
+            raise ValueError(
+                f"granitemoehybrid config with {key}={got!r} is not "
+                f"implemented (only {key}={want!r}, the published setting)"
+            )
+    heads, groups = d["mamba_n_heads"], d.get("mamba_n_groups", 1)
+    if heads % groups:
+        raise ValueError(
+            f"granitemoehybrid config with mamba_n_groups={groups} is not "
+            f"implemented (it must divide mamba_n_heads {heads})"
+        )
+    L = d["num_hidden_layers"]
+    types = tuple(d.get("layer_types") or ())
+    if len(types) != L or not set(types) <= {"mamba", "attention"}:
+        raise ValueError(
+            f"granitemoehybrid config with layer_types={list(types)!r} is "
+            f"not implemented (it must name each of the {L} layers 'mamba' "
+            "or 'attention')"
+        )
+    if not d.get("num_local_experts") or not d.get("shared_intermediate_size"):
+        raise ValueError(
+            "granitemoehybrid config with num_local_experts="
+            f"{d.get('num_local_experts')!r} / shared_intermediate_size="
+            f"{d.get('shared_intermediate_size')!r} is not implemented "
+            "(every layer routes experts beside a shared expert)"
+        )
+    D, H = d["hidden_size"], d["num_attention_heads"]
+    inner = int(d.get("mamba_expand", 2) * D)
+    d_head = d.get("mamba_d_head", "auto")
+    if d_head in (None, "auto"):
+        d_head = inner // heads
+    if d_head * heads != inner:
+        raise ValueError(
+            f"granitemoehybrid config: mamba_n_heads {heads} x mamba_d_head "
+            f"{d_head} != mamba_expand x hidden_size {inner}"
+        )
+    hd = d.get("head_dim") or D // H
+    return ModelConfig(
+        name=nm, vocab_size=d["vocab_size"], d_model=D, n_layers=L,
+        n_heads=H, n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],  # read as ONE expert's width
+        head_dim_override=None if hd * H == D else hd,
+        max_seq_len=d.get("max_position_embeddings", 131072),
+        pos_embedding="nope", norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_embeddings=d.get("tie_word_embeddings", True),
+        # scores times attention_multiplier = scores / sqrt(attn_scale)
+        attn_scale=1.0 / float(d["attention_multiplier"]) ** 2
+        if d.get("attention_multiplier") else None,
+        ssm_heads=heads, ssm_head_dim=d_head,
+        ssm_state=d.get("mamba_d_state", 128), ssm_groups=groups,
+        ssm_conv=d.get("mamba_d_conv", 4),
+        ssm_chunk=d.get("mamba_chunk_size", 256),
+        embedding_multiplier=float(d.get("embedding_multiplier", 1.0)),
+        lm_head_multiplier=1.0 / float(d.get("logits_scaling", 1.0)),
+        residual_multiplier=float(d.get("residual_multiplier", 1.0)),
+        layer_types=types,
+        n_experts=d["num_local_experts"],
+        n_experts_per_tok=d["num_experts_per_tok"], moe_router="softmax_topk",
+        n_experts_held=d.get("num_local_experts_held") or 0,
+        expert_first=d.get("expert_first") or 0,
+        n_shared_experts=1, d_ff_shared=d["shared_intermediate_size"],
+    )
+
+
 def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     """Synthesize a ModelConfig from an HF ``config.json`` dict — the
     any-checkpoint path: a checkpoint whose architecture is NOT in the
@@ -1472,6 +1725,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
                                      or d.get("model_name") or nm)
     if mt == "ouro":
         return _ouro_from_hf(d, nm)
+    if mt == "granitemoehybrid":
+        return _granite_hybrid_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1640,7 +1895,7 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
-        f"falcon_h1/joyai_llm_flash/smallthinker/ouro; "
+        f"falcon_h1/joyai_llm_flash/smallthinker/ouro/granitemoehybrid; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

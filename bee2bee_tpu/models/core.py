@@ -82,8 +82,10 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     mlp_one = (3 if gated else 2) * D * F
     if cfg.is_moe:
         one = (3 if gated else 2) * D * cfg.expert_ff
-        moe = D * cfg.n_experts + (
-            cfg.n_experts_per_tok + cfg.n_shared_experts) * one
+        # under an expert share a token pays HERE for its choices held here
+        routed = cfg.n_experts_per_tok * cfg.experts_held / cfg.n_experts
+        moe = (D * cfg.n_experts + routed * one
+               + (3 if gated else 2) * D * cfg.shared_ff)
         # leading dense layers (first_k_dense) pay the dense MLP instead
         mlps = cfg.first_k_dense * mlp_one + cfg.n_expert_layers * moe
     else:
@@ -91,8 +93,11 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     # falcon-h1: the mixer's in- and out-projection (the scan itself is
     # O(inner * state) a token — under 1 % of the block — and not counted)
     ssm = D * cfg.ssm_proj_dim + cfg.ssm_inner * D if cfg.has_ssm else 0
+    if cfg.layer_types:  # a layer pays for ONE mixer kind
+        return int(cfg.cache_layers * attn + cfg.state_layers * ssm + mlps
+                   + D * cfg.vocab_size)
     # a looped stack streams every layer loop_steps times a token, the head once
-    return cfg.loop_steps * (L * (attn + ssm) + mlps) + D * cfg.vocab_size
+    return int(cfg.loop_steps * (L * (attn + ssm) + mlps) + D * cfg.vocab_size)
 
 
 def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
@@ -131,6 +136,14 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
              balances it, balance_router_bias: nonzero, so selection and
              weight differ), shared {w_gate, w_up [L, D, Fs], w_down
              [L, Fs, D]}; expert matrices are cfg.expert_ff wide
+      under cfg.layer_types (granite-4.0-h: ONE mixer kind a layer) ``layers``
+        holds ln1 / ln2 / moe stacked over all L layers, ``ssm`` over the
+        cfg.state_layers "mamba" layers alone and ``attn`` over the
+        cfg.cache_layers "attention" layers alone, each in layer order: a
+        layer finds its mixer by cfg.state_slots / cfg.cache_slots. Under an
+        expert share (cfg.n_experts_held) the expert stacks are [L, held,
+        ...], the router stays [L, D, E]; the shared expert is cfg.shared_ff
+        wide
       dense_layers/ (cfg.first_k_dense > 0 only): the LEADING dense
         layers, the same schema with a dense mlp, stacked [k, ...];
         ``layers`` then holds the n_layers - k expert layers. Layers of
@@ -174,6 +187,13 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
     # behind thousands of context tokens (at 0.02 every row 6k deep held
     # nearly the same state and 32 rows hit 62 % of a layer's experts, PR 43)
     embed_std = 1.0 if cfg.moe_router == "softmax_topk" else 0.02
+    if cfg.tie_embeddings and cfg.moe_dropless:
+        # a TIED head reads a token's own embedding back: behind the
+        # embedding multiplier m the input's own logit stands 12 s sqrt(D) /
+        # rms(x) deviations over the rest at std s, and every row would
+        # repeat its last token. At 0.75 / (m sqrt(D)) (granite: 2^-10) it is
+        # one logit among the others; the first norm restores the scale
+        embed_std = 0.75 / (cfg.embedding_multiplier * math.sqrt(D))
     params: Params = {
         "tok_embed": _dense_init(next(keys), (V, D), scale=embed_std, dtype=dtype),
     }
@@ -184,28 +204,41 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         if cfg.norm == "layernorm" and cfg.norm_bias:
             params["embed_norm"]["bias"] = jnp.zeros((D,), dtype)
 
-    def layer_group(L, moe_layers):
-        """One group of ``L`` like layers (dense MLP or expert layers)."""
+    def layer_group(L, moe_layers, La=None, Ls=None):
+        """One group of ``L`` like layers (dense MLP or expert layers);
+        under cfg.layer_types ``La`` of them hold attention and ``Ls`` a
+        mixer (default: all)."""
+        La, Ls = (L if La is None else La), (L if Ls is None else Ls)
         if cfg.has_mla:
             qk = cfg.mla_nope_dim + cfg.mla_rope_dim
             attn = {
-                "wq_a": dense((L, D, cfg.mla_q_rank)),
-                "q_a_norm": jnp.ones((L, cfg.mla_q_rank), dtype),
-                "wq_b": dense((L, cfg.mla_q_rank, H * qk)),
-                "wkv_a": dense((L, D, cfg.latent_width)),
-                "kv_a_norm": jnp.ones((L, cfg.mla_kv_rank), dtype),
-                "wkv_b": dense((L, cfg.mla_kv_rank,
+                "wq_a": dense((La, D, cfg.mla_q_rank)),
+                "q_a_norm": jnp.ones((La, cfg.mla_q_rank), dtype),
+                "wq_b": dense((La, cfg.mla_q_rank, H * qk)),
+                "wkv_a": dense((La, D, cfg.latent_width)),
+                "kv_a_norm": jnp.ones((La, cfg.mla_kv_rank), dtype),
+                "wkv_b": dense((La, cfg.mla_kv_rank,
                                 H * (cfg.mla_nope_dim + cfg.mla_v_dim))),
-                "wo": dense((L, H * cfg.mla_v_dim, D)),
+                "wo": dense((La, H * cfg.mla_v_dim, D)),
             }
         else:
             attn = {
-                "wq": dense((L, D, H * hd)),
-                "wk": dense((L, D, Hkv * hd)),
-                "wv": dense((L, D, Hkv * hd)),
-                "wo": dense((L, H * hd, D), scale=1.0 / math.sqrt(H * hd)),
+                "wq": dense((La, D, H * hd)),
+                "wk": dense((La, D, Hkv * hd)),
+                "wv": dense((La, D, Hkv * hd)),
+                "wo": dense((La, H * hd, D), scale=1.0 / math.sqrt(H * hd)),
             }
         layers: Params = {"attn": attn}
+        if cfg.use_bias or cfg.qkv_bias:
+            layers["attn"]["bq"] = jnp.zeros((La, H * hd), dtype)
+            layers["attn"]["bk"] = jnp.zeros((La, Hkv * hd), dtype)
+            layers["attn"]["bv"] = jnp.zeros((La, Hkv * hd), dtype)
+        if cfg.qk_norm:  # qwen3: per-head scales; olmo2: full-width scales
+            qn = (H * hd, Hkv * hd) if cfg.qk_norm_full else (hd, hd)
+            layers["attn"]["q_norm"] = jnp.ones((La, qn[0]), dtype)
+            layers["attn"]["k_norm"] = jnp.ones((La, qn[1]), dtype)
+        if cfg.use_bias:  # qwen2 (qkv_bias) has NO output-projection bias
+            layers["attn"]["bo"] = jnp.zeros((La, D), dtype)
         if not cfg.no_pre_norms:  # olmo2 blocks norm only their OUTPUTS
             layers["ln1"] = {"scale": jnp.ones((L, D), dtype)}
             if not cfg.parallel_block or cfg.parallel_norms == 2:
@@ -228,32 +261,22 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             for ln in ("ln1", "ln2", "ln1_post", "ln2_post"):
                 if ln in layers:
                     layers[ln]["bias"] = jnp.zeros((L, D), dtype)
-        if cfg.use_bias or cfg.qkv_bias:
-            layers["attn"]["bq"] = jnp.zeros((L, H * hd), dtype)
-            layers["attn"]["bk"] = jnp.zeros((L, Hkv * hd), dtype)
-            layers["attn"]["bv"] = jnp.zeros((L, Hkv * hd), dtype)
-        if cfg.qk_norm:  # qwen3: per-head scales; olmo2: full-width scales
-            qn = (H * hd, Hkv * hd) if cfg.qk_norm_full else (hd, hd)
-            layers["attn"]["q_norm"] = jnp.ones((L, qn[0]), dtype)
-            layers["attn"]["k_norm"] = jnp.ones((L, qn[1]), dtype)
-        if cfg.use_bias:  # qwen2 (qkv_bias) has NO output-projection bias
-            layers["attn"]["bo"] = jnp.zeros((L, D), dtype)
-
         gated = cfg.gated_mlp
         if moe_layers:
-            E, Fe = cfg.n_experts, cfg.expert_ff
+            # (Eh: the experts held HERE; the router keeps every output)
+            E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.expert_ff
             moe = {
                 "router": dense((L, D, E)),
-                "w_up": dense((L, E, D, Fe)),
-                "w_down": dense((L, E, Fe, D), scale=1.0 / math.sqrt(Fe)),
+                "w_up": dense((L, Eh, D, Fe)),
+                "w_down": dense((L, Eh, Fe, D), scale=1.0 / math.sqrt(Fe)),
             }
             if gated:
-                moe["w_gate"] = dense((L, E, D, Fe))
+                moe["w_gate"] = dense((L, Eh, D, Fe))
             if cfg.moe_router == "sigmoid":
                 # float32 always; init_params sets it (balance_router_bias)
                 moe["router_bias"] = jnp.zeros((L, E), jnp.float32)
             if cfg.n_shared_experts:
-                Fs = cfg.n_shared_experts * Fe
+                Fs = cfg.shared_ff
                 moe["shared"] = {
                     "w_gate": dense((L, D, Fs)),
                     "w_up": dense((L, D, Fs)),
@@ -280,25 +303,29 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             # The three per-head vectors stay float32 whatever the dtype:
             # exp(A_log) and softplus(dt + dt_bias) set every step's decay
             dt = jnp.exp(
-                jax.random.uniform(next(keys), (L, Hs), jnp.float32)
+                jax.random.uniform(next(keys), (Ls, Hs), jnp.float32)
                 * (math.log(1e-1) - math.log(1e-3)) + math.log(1e-3)
             )
             layers["ssm"] = {
-                "w_in": dense((L, D, cfg.ssm_proj_dim)),
-                "conv_w": dense((L, C, K), scale=1.0 / math.sqrt(K)),
-                "conv_b": jnp.zeros((L, C), dtype),
+                "w_in": dense((Ls, D, cfg.ssm_proj_dim)),
+                "conv_w": dense((Ls, C, K), scale=1.0 / math.sqrt(K)),
+                "conv_b": jnp.zeros((Ls, C), dtype),
                 "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
                 "A_log": jnp.broadcast_to(
-                    jnp.log(jnp.arange(1, Hs + 1, dtype=jnp.float32)), (L, Hs)),
-                "D": jnp.ones((L, Hs), jnp.float32),
-                "norm": jnp.ones((L, cfg.ssm_inner), dtype),
-                "w_out": dense((L, cfg.ssm_inner, D)),
+                    jnp.log(jnp.arange(1, Hs + 1, dtype=jnp.float32)), (Ls, Hs)),
+                "D": jnp.ones((Ls, Hs), jnp.float32),
+                "norm": jnp.ones((Ls, cfg.ssm_inner), dtype),
+                "w_out": dense((Ls, cfg.ssm_inner, D)),
             }
 
         return layers
 
     k_dense = cfg.first_k_dense
-    params["layers"] = layer_group(L - k_dense, cfg.is_moe)
+    if cfg.layer_types:
+        params["layers"] = layer_group(
+            L, cfg.is_moe, La=cfg.cache_layers, Ls=cfg.state_layers)
+    else:
+        params["layers"] = layer_group(L - k_dense, cfg.is_moe)
     if k_dense:
         params["dense_layers"] = layer_group(k_dense, False)
     params["final_norm"] = {"scale": jnp.ones((D,), dtype)}
@@ -732,6 +759,13 @@ def _moe(x, p, cfg: ModelConfig):
 MOE_STATS = ("hit", "max_load", "live")  # the entries of a forward's
 # ``moe_stats`` int32 [3], each summed over its expert layers: experts with at
 # least one live assignment, the busiest expert's assignments, live assignments
+# — of the experts held HERE; under an expert share (moe_stats_names) a fourth,
+# "elsewhere": live assignments whose expert another chip holds
+
+
+def moe_stats_names(cfg: ModelConfig) -> tuple:
+    """The entries of ``cfg``'s ``moe_stats`` vector."""
+    return MOE_STATS + (("elsewhere",) if cfg.expert_share else ())
 
 
 def _router_logits(xf, p):
@@ -905,35 +939,71 @@ def center_router(params: Params, cfg: ModelConfig):
     tokens, every position) 69.0 %, text only 69.0 %, its deeper half 75.9 %,
     this batch 87.7 %; removing the top 2-16 principal directions of the
     router input's second moment instead of the mean 86.9-88.7 % (not worth
-    an eigendecomposition a layer)."""
-    tokens, _ = _balance_tokens(
-        cfg, _CENTER_ROWS, min(_CENTER_WIDTH, cfg.max_seq_len), prompt_only=True)
+    an eigendecomposition a layer).
+
+    The mean is taken of the router's OWN input, whichever norm feeds it:
+    the pre-attention norm's output (cfg.moe_router_input "attn_norm",
+    smallthinker: computed here, ahead of the block) or the pre-FFN norm's
+    ("ffn_norm", granite: known only behind the layer's mixer, so the block
+    centres the matrix where it reads it, _moe_dropless's ``router_fix``)."""
+    width = min(_CENTER_WIDTH, cfg.max_seq_len)
+    # the combine holds rows x width x k float32 rows of d_model at once: no
+    # more of them than smallthinker's batch (k = 6) makes, whatever the k
+    # (granite's k = 10: 512 tokens a row, and its contexts end at 704)
+    while _CENTER_ROWS * width * cfg.n_experts_per_tok > _CENTER_ROWS * _CENTER_WIDTH * 6:
+        width //= 2
+    tokens, _ = _balance_tokens(cfg, _CENTER_ROWS, width, prompt_only=True)
     R, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (R, T))
     layer_mask = make_layer_mask(cfg, positions, T)
     rest, stack = _split_expert_stack(params["layers"]["moe"])
 
+    def centred(w, m):
+        w32 = w.astype(jnp.float32)
+        return (w32 - jnp.outer(m, jnp.dot(m, w32, precision=_HI))
+                / jnp.dot(m, m)).astype(w.dtype)
+
+    def deep_mean(a):  # [R, T, D] -> [D] over the deeper half of every row
+        return jnp.mean(a.astype(jnp.float32)[:, T // 2:].reshape(
+            R * (T - T // 2), -1), axis=0)
+
     def layer(x, xs):
         lp, i = xs
-        m = jnp.mean(_norm(x, lp["ln1"], cfg).astype(jnp.float32)[
-            :, T // 2:].reshape(R * (T - T // 2), -1), axis=0)  # [D]
-        w = lp["moe"]["router"].astype(jnp.float32)
-        w = (w - jnp.outer(m, jnp.dot(m, w, precision=_HI)) / jnp.dot(m, m)
-             ).astype(lp["moe"]["router"].dtype)
+        w = centred(lp["moe"]["router"],
+                    deep_mean(_norm(x, lp["ln1"], cfg)))
         x = transformer_block(
             dict(lp, moe=dict(lp["moe"], router=w)), cfg, x, positions,
             layer_mask(i), rope_local=layer_rope_flag(cfg, i),
             moe_kw={"experts": stack, "layer": i})
         return x, w
 
-    _, router = lax.scan(
-        layer, embed_tokens(params, cfg, jnp.asarray(tokens), positions),
-        (dict(params["layers"], moe=rest), jnp.arange(cfg.n_layers)))
-    return router
+    def layer_behind_mixer(x, xs):
+        lp, i = xs
+        got = []
+
+        def fix(rx, w):
+            got.append(centred(w, deep_mean(rx.reshape(R, T, -1))))
+            return got[0]
+
+        x = transformer_block(
+            lp, cfg, x, positions, layer_mask(i),
+            rope_local=layer_rope_flag(cfg, i),
+            moe_kw={"experts": stack, "layer": i, "router_fix": fix})
+        return x, got[0]
+
+    x = embed_tokens(params, cfg, jnp.asarray(tokens), positions)
+    layers = dict(params["layers"], moe=rest)
+    if not cfg.layer_types:
+        body = (layer if cfg.moe_router_input == "attn_norm"
+                else layer_behind_mixer)
+        return lax.scan(body, x, (layers, jnp.arange(cfg.n_layers)))[1]
+    # one mixer kind a layer: a scan a run of like layers (core.forward)
+    return jnp.concatenate(
+        _scan_layer_runs(cfg, layers, layer_behind_mixer, x)[1], axis=0)
 
 
 def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
-                  router_x=None):
+                  router_x=None, router_fix=None):
     """The expert layer behind a sigmoid or softmax-top-k router (_moe_router),
     DROPLESS: every chosen assignment is computed, whatever the imbalance.
     Returns (out [B, T, D], stats int32 [3] as MOE_STATS names them).
@@ -959,19 +1029,39 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
     the STACKED [L, E, ...] arrays, read in place: the stack is viewed as
     L*E groups of which only this layer's E get rows, so no layer's
     experts are sliced out of the stack (a 0.8 GB copy a matrix a layer
-    otherwise)."""
+    otherwise).
+
+    Under an expert SHARE (cfg.expert_share) the stacks hold the
+    cfg.experts_held experts from cfg.expert_first on: the router still
+    scores every expert and takes its k, an assignment to an expert held
+    ELSEWHERE joins the pad group (no product, weight zero) and is counted
+    in a fourth stat, and the layer returns the held experts' part plus the
+    shared expert: what this chip would hand to the exchange with its
+    partners, which is not built. ``router_fix(router input [N, D], W_r) ->
+    W_r`` replaces the router's matrix before it is read (center_router)."""
     B, T, D = x.shape
-    E, k = cfg.n_experts, cfg.n_experts_per_tok
+    k = cfg.n_experts_per_tok
+    E = cfg.experts_held  # the groups of the product: the experts held HERE
     N, M = B * T, B * T * k
     xf = x.reshape(N, D)
     experts = p if experts is None else experts
 
     with jax.named_scope("moe.router"):
-        topi, w = _moe_router(
-            xf if router_x is None else router_x.reshape(N, D), p, cfg)
+        rx = xf if router_x is None else router_x.reshape(N, D)
+        if router_fix is not None:
+            p = dict(p, router=router_fix(rx, p["router"]))
+        topi, w = _moe_router(rx, p, cfg)
 
     with jax.named_scope("moe.dispatch"):
         flat = topi.reshape(M)
+        elsewhere = None
+        if cfg.expert_share:
+            flat = flat - cfg.expert_first
+            away = (flat < 0) | (flat >= E)
+            if live is not None:
+                away = away & jnp.repeat(live.reshape(N), k)
+            elsewhere = jnp.sum(away)
+            flat = jnp.where(away, E, flat)
         if live is not None:
             flat = jnp.where(jnp.repeat(live.reshape(N), k), flat, E)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [M]
@@ -1006,7 +1096,9 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
         with jax.named_scope("moe.shared"):
             out = out + _mlp(xf, p["shared"], cfg).astype(jnp.float32)
 
-    stats = jnp.stack([jnp.sum(gs > 0), jnp.max(gs), n_live]).astype(jnp.int32)
+    stats = jnp.stack(
+        [jnp.sum(gs > 0), jnp.max(gs), n_live]
+        + ([] if elsewhere is None else [elsewhere])).astype(jnp.int32)
     return out.astype(x.dtype).reshape(B, T, D), stats
 
 
@@ -1104,14 +1196,16 @@ def _mla_attention(p, cfg: ModelConfig, h, positions, mask, kv_hook=None,
 
 
 def init_ssm_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
-    """A batch of rows' recurrent state, all zero (= "no token seen"):
+    """A batch of rows' recurrent state, all zero (= "no token seen"), L =
+    cfg.state_layers deep (every layer of falcon-h1; the "mamba" layers alone
+    under cfg.layer_types, a layer's slot cfg.state_slots):
     {"ssm": [L, B, heads, head_dim, state] float32 — the recurrence
     accumulates over hundreds of steps, so it is not kept in the model
     dtype; "conv": [L, B, K-1, C] in ``dtype`` — the conv's last K-1
     inputs, channels minor (a trailing 3 would pad to 128 TPU lanes)}.
     The engine keeps one such tree beside the paged pool, one slot a row
     of the batch bucket (engine/scheduler.py)."""
-    L = cfg.n_layers
+    L = cfg.state_layers
     return {
         "ssm": jnp.zeros(
             (L, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
@@ -1362,6 +1456,11 @@ def transformer_block(
     The parts of a dropless-expert model's plain attention run under the
     scopes ``attn.qkv`` / ``attn.rope`` / ``attn.write`` / ``attn.read`` /
     ``attn.out`` (the dense block's carry none).
+
+    Under cfg.layer_types (granite-4.0-h) the layer's tree holds ONE mixer:
+    ``"ssm"`` (the mixer's output is the whole branch: no attention runs,
+    no page is written) or ``"attn"`` (no mixer runs); both residual adds
+    take their branch times cfg.residual_multiplier.
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -1373,8 +1472,15 @@ def transformer_block(
             lp["attn"], cfg, h, positions, mask, kv_hook, attn_fn)
         return x + _ffn(_norm(x, lp["ln2"], cfg), lp, cfg, lora, moe_kw,
                         moe_sink)
+    if cfg.layer_types and "ssm" in lp:  # a recurrent-only layer
+        mix_out = (ssm_hook(h) if ssm_hook is not None
+                   else ssm_mixer(lp["ssm"], cfg, h)[0])
+        x = x + _residual(mix_out, cfg)
+        return x + _residual(
+            _ffn(_norm(x, lp["ln2"], cfg), lp, cfg, lora, moe_kw, moe_sink),
+            cfg)
     mix_out = None
-    if cfg.has_ssm:
+    if cfg.has_ssm and not cfg.layer_types:  # beside attention (falcon-h1)
         mix_out = (ssm_hook(h) if ssm_hook is not None
                    else ssm_mixer(lp["ssm"], cfg, h)[0])
         mix_out = mix_out * jnp.asarray(cfg.ssm_out_multiplier, mix_out.dtype)
@@ -1456,7 +1562,7 @@ def transformer_block(
         return x + attn_out + _mlp(h_mlp, lp["mlp"], cfg, lora)
     if cfg.post_norms:  # gemma-2/olmo2: norm the attn OUTPUT
         attn_out = _norm(attn_out, lp["ln1_post"], cfg)
-    x = x + attn_out
+    x = x + _residual(attn_out, cfg)
 
     h2 = x if cfg.no_pre_norms else _norm(x, lp["ln2"], cfg)
     if cfg.moe_router_input == "attn_norm":
@@ -1464,7 +1570,15 @@ def transformer_block(
     mlp_out = _ffn(h2, lp, cfg, lora, moe_kw, moe_sink)
     if cfg.post_norms:
         mlp_out = _norm(mlp_out, lp["ln2_post"], cfg)
-    return x + mlp_out
+    return x + _residual(mlp_out, cfg)
+
+
+def _residual(branch, cfg: ModelConfig):
+    """A branch's output as the residual add takes it: times granite's
+    ``residual_multiplier`` (1 = as it is)."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
 def _attn_scoped(cfg: ModelConfig) -> bool:
@@ -1482,8 +1596,9 @@ def _stack_scoped(cfg: ModelConfig) -> bool:
     """Do the REST of this model's dense stack run under scopes: the MLP's
     ``mlp.gate_up`` / ``mlp.down``, the norm between a looped stack's passes
     ``loop.norm`` and the head ``head.logits``? A looped stack alone (ouro,
-    first built with them): smallthinker's head was built bare and stays so."""
-    return cfg.loop_steps > 1
+    first built with them): smallthinker's head was built bare and stays so.
+    PR 51: a stack of one mixer kind a layer too (granite: a new program)."""
+    return cfg.loop_steps > 1 or bool(cfg.layer_types)
 
 
 def _scope_if(on: bool):
@@ -1692,9 +1807,9 @@ def forward(
 
     **Recurrent state** (cfg.has_ssm, falcon-h1): the cache dict also
     carries ``ssm`` [L, B, heads, head_dim, state] f32 and ``conv``
-    [L, B, K-1, C] (init_ssm_state), one slot a BATCH ROW (not a pool
-    block: the state has no positions to page). Every layer's mixer reads
-    its slice and writes the state after this chunk back; ``valid_len``
+    [L, B, K-1, C] (init_ssm_state; L = cfg.state_layers), one slot a BATCH
+    ROW (not a pool block: the state has no positions to page). Every
+    layer's mixer reads its slice and writes the state after this chunk back; ``valid_len``
     marks a prefill bucket's padded tail, which must leave the state
     untouched. Chunks of one row must arrive in order, each starting
     where the last ended — a recurrent state cannot re-feed or skip a
@@ -1711,6 +1826,14 @@ def forward(
     norm follows EVERY pass, and the head reads the last pass's normed
     output with no second norm. With loop_steps == 1 there is no pass loop
     at all: a plain stack's program is what it was.
+
+    **One mixer kind a layer** (cfg.layer_types, granite-4.0-h): the layer
+    loop is one ``lax.scan`` a RUN of like layers (cfg.layer_runs), each
+    reading its layer of the stacked parameters where it lies; a "mamba"
+    layer reads and writes slot cfg.state_slots[l] of the state and no page,
+    an "attention" layer layer cfg.cache_slots[l] of the pool and no state.
+    The state, the pool and the expert stack are the carries of every run,
+    in place.
     """
     B, T = input_ids.shape
     if cfg.has_ssm and cache is not None and "ssm" not in cache:
@@ -1835,6 +1958,10 @@ def forward(
         # the weights' layer ``layer_idx`` (masks, rotation, experts) against
         # the CACHE's ``cache_idx`` = pass * n_layers + layer (cfg.cache_layers)
         cache_idx = layer_idx if cache_base is None else cache_base + layer_idx
+        state_idx = layer_idx
+        if cfg.layer_types:  # a layer's slot of ITS kind's state or cache
+            state_idx = _slot_of(cfg.state_slots, layer_idx)
+            cache_idx = _slot_of(cfg.cache_slots, layer_idx)
         lora = lora_for(xs[2]) if len(xs) > 2 else None
         moe_kw = None
         if cfg.moe_dropless and "moe" in lp:
@@ -1891,13 +2018,13 @@ def forward(
             # it in place, a longer chunk writes its slice back)
             out, new = ssm_mixer(
                 lp["ssm"], cfg, h,
-                {"ssm": lcache["ssm"], "conv": lcache["conv"][layer_idx]},
-                valid_len, layer=layer_idx,
+                {"ssm": lcache["ssm"], "conv": lcache["conv"][state_idx]},
+                valid_len, layer=state_idx,
             )
             with jax.named_scope("ssm.state_write"):  # the conv's tail
                 lcache = dict(
                     lcache, ssm=new["ssm"],
-                    conv=lcache["conv"].at[layer_idx].set(new["conv"]),
+                    conv=lcache["conv"].at[state_idx].set(new["conv"]),
                 )
             return out
 
@@ -2028,6 +2155,11 @@ def forward(
         """Every layer once, in order: (x, cache) in, (x, cache) out."""
         nonlocal expert_stack
         layer_params = params["layers"]
+        if cfg.layer_types and not isinstance(layer_params, (list, tuple)):
+            if cfg.moe_dropless:
+                rest, expert_stack = _split_expert_stack(layer_params["moe"])
+                layer_params = dict(layer_params, moe=rest)
+            return _scan_layer_runs(cfg, layer_params, layer_body, carry)[0]
         if isinstance(layer_params, (list, tuple)):
             # Unstacked layers (list of per-layer trees): unrolled loop. This
             # is the CPU serving fast path — XLA:CPU cannot pre-pack a GEMM
@@ -2094,25 +2226,80 @@ def forward(
     if cfg.loop_steps > 1:  # the last pass's norm WAS the final norm
         with jax.named_scope("head.logits"):
             return head_logits(params, cfg, x), new_cache
-    return final_logits(params, cfg, x), new_cache
+    with _scope_if(_stack_scoped(cfg))("head.logits"):
+        return final_logits(params, cfg, x), new_cache
 
 
-def unstack_layers(params: Params) -> Params:
+def _layer_of(stack: Params, index):
+    """Layer ``index`` (traced) of every stacked [L, ...] leaf of ``stack``."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, index, keepdims=False), stack)
+
+
+def _slot_of(slots: tuple, layer_idx):
+    """``slots[layer_idx]`` (cfg.state_slots / cfg.cache_slots) for a host
+    integer (the unrolled layer list) or a traced one (a layer scan)."""
+    if isinstance(layer_idx, int):
+        return slots[layer_idx]
+    return jnp.asarray(slots, jnp.int32)[layer_idx]
+
+
+def _scan_layer_runs(cfg: ModelConfig, layers: Params, body, carry):
+    """The stacked layers of a model of one mixer kind a layer
+    (cfg.layer_types), one ``lax.scan`` a RUN of like layers
+    (cfg.layer_runs): ``body(carry, (lp, layer index)) -> (carry, y)`` gets a
+    layer's tree read out of the stacks where they lie (what scan does with
+    its xs): the common parts at the layer's index, its ``ssm`` OR ``attn``
+    at its slot of that kind. Returns (carry, [a run's stacked ys])."""
+    common = {n: a for n, a in layers.items() if n not in ("ssm", "attn")}
+    ys = []
+    for kind, start, count, slot in cfg.layer_runs:
+        mixer = "ssm" if kind == "mamba" else "attn"
+
+        def run_body(c, i, mixer=mixer, delta=start - slot):
+            lp = dict(_layer_of(common, i),
+                      **{mixer: _layer_of(layers[mixer], i - delta)})
+            return body(c, (lp, i))
+
+        carry, y = lax.scan(
+            run_body, carry, jnp.arange(start, start + count, dtype=jnp.int32))
+        ys.append(y)
+    return carry, ys
+
+
+def unstack_layers(params: Params, cfg: ModelConfig | None = None) -> Params:
     """Convert stacked [L, ...] layer params into a list of per-layer
     contiguous trees (forward()'s unrolled path). Host-side numpy copies
     so each weight is its own packed buffer — the whole point is giving
     XLA:CPU pre-packable GEMM operands; quantized {"q","s"} subtrees pass
-    through like any other leaves."""
+    through like any other leaves. A model of one mixer kind a layer
+    (``cfg.layer_types``: the schema alone does not say which layer holds
+    which) needs its ``cfg``: layer ``l``'s tree holds the common parts at
+    ``l`` and ``ssm`` at cfg.state_slots[l] OR ``attn`` at cfg.cache_slots[l]."""
     import numpy as np
 
     stacked = params["layers"]
     if isinstance(stacked, (list, tuple)):
         return params  # already unstacked: slicing again would shred weights
     out = dict(params)
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda a: np.ascontiguousarray(np.asarray(a[i])), tree)
+
+    if cfg is not None and cfg.layer_types:
+        common = {n: a for n, a in stacked.items() if n not in ("ssm", "attn")}
+        out["layers"] = [
+            dict(at(common, i), **(
+                {"ssm": at(stacked["ssm"], cfg.state_slots[i])}
+                if t == "mamba" else
+                {"attn": at(stacked["attn"], cfg.cache_slots[i])}))
+            for i, t in enumerate(cfg.layer_types)]
+        return out
     # a model's leading dense layers (their own stacked group) come first:
     # the list is in the layers' absolute order, trees unlike
     out["layers"] = [
-        jax.tree.map(lambda a: np.ascontiguousarray(np.asarray(a[i])), group)
+        at(group, i)
         for group in (out.pop("dense_layers", None), stacked)
         if group is not None
         for i in range(len(jax.tree.leaves(group)[0]))
@@ -2136,6 +2323,16 @@ def restack_layers(params: Params) -> Params:
     def stack(group):
         return jax.tree.map(
             lambda *leaves: np.stack([np.asarray(a) for a in leaves]), *group)
+
+    if any("attn" not in lp for lp in layers):
+        # one mixer kind a layer: the common parts over every layer, each
+        # mixer over the layers that hold it, in layer order
+        out["layers"] = dict(
+            stack([{n: a for n, a in lp.items() if n not in ("ssm", "attn")}
+                   for lp in layers]),
+            ssm=stack([lp["ssm"] for lp in layers if "ssm" in lp]),
+            attn=stack([lp["attn"] for lp in layers if "attn" in lp]))
+        return out
 
     # leading dense layers of an expert model go back to their own group
     k = 0

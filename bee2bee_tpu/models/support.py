@@ -31,21 +31,34 @@ class FeatureUnsupported(ValueError):
 GROUNDS = (
     # falcon-h1's Mamba-2 mixer: rollback is not free for a recurrence, and
     # a row's pages are not its complete state
-    ("recurrent_state", lambda cfg: cfg.has_ssm,
+    ("recurrent_state", lambda cfg: cfg.has_ssm and not _layer_kinds(cfg),
      "its rows carry recurrent state beside their K/V pages"),
     # latent attention caches one [c_kv | k_rope] row a token
     # (core.pool_layout)
     ("latent_pool", lambda cfg: cfg.has_mla,
      "its rows cache latent rows (no per-head K/V)"),
     # smallthinker: a plain K/V pool under dropless expert layers
-    ("dropless_routed", lambda cfg: cfg.moe_dropless and not cfg.has_mla,
+    ("dropless_routed", lambda cfg: (cfg.moe_dropless and not cfg.has_mla
+                                     and not _layer_kinds(cfg)),
      "its every layer is a dropless expert layer routed from the "
      "pre-attention norm"),
     # ouro: a layer of cache a (pass, layer) (cfg.cache_layers)
     ("looped_stack", lambda cfg: cfg.loop_steps > 1,
      "its layers run several times a token with a cache of their own in "
      "every pass"),
+    # granite-4.0-h: a recurrent mixer OR attention a layer (cfg.layer_types:
+    # a state as deep as the one kind, a pool as deep as the other) under
+    # dropless expert layers of which the chip may hold a share
+    ("layer_kinds", lambda cfg: _layer_kinds(cfg),
+     "its layers hold one mixer kind each (recurrent state in some, K/V "
+     "pages in the others) under dropless expert layers, of whose experts "
+     "the chip may hold a share"),
 )
+
+
+def _layer_kinds(cfg: ModelConfig) -> bool:
+    return bool(cfg.layer_types) or cfg.expert_share
+
 
 _NO_ROLLBACK = "a rejected draft cannot be rolled back out of the state"
 _WALKS_ONCE = ("the final norm comes after every pass (cfg.loop_steps), and "
@@ -129,6 +142,39 @@ REFUSED = {
         ("pipeline_stage_split", _WALKS_ONCE),
         ("pipeline_trunk", _WALKS_ONCE),
         ("ring_forward", _WALKS_ONCE),
+    ),
+    "layer_kinds": (
+        ("prefix_cache", "a pinned block holds K/V only — the recurrent "
+         "layers' state at the prefix's end would have to be snapshotted"),
+        ("spec_mesh_drafter", _NO_ROLLBACK),
+        ("spec_model_drafter", _NO_ROLLBACK),
+        ("spec_ngram", _NO_ROLLBACK),
+        ("seq_attention", "the state is not sharded over a seq axis"),
+        ("mesh_model", "neither the mixer's heads and state nor the grouped "
+         "product are partitioned over a model axis (--mesh-shape model:N)"),
+        ("mesh_expert", "a share of the experts is a property of the "
+         "configuration (n_experts_held): the exchange of the partial sums "
+         "between the chips of a layer is not built, and the grouped product "
+         "is not partitioned over an expert axis"),
+        ("multi_lora", "neither the mixer's projections nor the expert "
+         "layers have an adapter path"),
+        ("prefill_chunk", "a chunk of {prefill_chunk} does not divide "
+         "max_seq_len {max_seq_len}, so the last window would re-feed tokens "
+         "the state already absorbed"),
+        ("pipeline_stages", "a stage's per-microbatch cache holds K/V only, "
+         "as deep as the stage's layers"),
+        ("kv_export", "the state has no export format yet"),
+        ("kv_int8", "the int8 pool's per-layer slices indexed by a layer's "
+         "cache slot are not tested"),
+        ("weight_int8", "the grouped product reads the expert stacks "
+         "unquantised"),
+        ("pipeline_stage_split", "a stage's walk reads every layer's mixer "
+         "at the layer's own index, not its slot of its kind; use "
+         "core.forward"),
+        ("pipeline_trunk", "the trunk's walk reads every layer's mixer at "
+         "the layer's own index, not its slot of its kind; use core.forward"),
+        ("ring_forward", "the ring's walk reads every layer's mixer at the "
+         "layer's own index, not its slot of its kind; use core.forward"),
     ),
 }
 

@@ -34,7 +34,7 @@ DOC = Path(__file__).resolve().parent.parent / "docs" / "OBSERVABILITY.md"
 # must exist in the catalog (pinned below) — retiring the metric means
 # retiring this entry too.
 ALLOWED_ABSENT = {
-    # recurrent models only (falcon-h1): this boot serves tiny-llama, whose
+    # recurrent models only (falcon-h1, granite-4.0-h): this boot serves tiny-llama, whose
     # rows own K/V pages and nothing else (tests/test_falcon_h1.py reads them)
     "engine.state_rows": "no recurrent state: the boot's model has no mixer",
     "engine.state_bytes": "no recurrent state: the boot's model has no mixer",

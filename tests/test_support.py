@@ -20,6 +20,7 @@ PRESET = {  # a tiny model of each kind
     "latent_pool": "tiny-joyai",
     "dropless_routed": "tiny-smallthinker",
     "looped_stack": "tiny-ouro",
+    "layer_kinds": "tiny-granite",
 }
 DETAIL = dict(prefill_chunk=48, max_seq_len=128)
 ROWS = [(ground, feature, why)
@@ -92,6 +93,8 @@ def test_the_engine_names_each_feature_for_the_configuration_that_turns_it_on():
     ("tiny-falcon-h1", ("spec_ngram", "spec_model_drafter", "spec_mesh_drafter"),
      "spec_mesh_drafter"),
     ("tiny-smallthinker", ("pipeline_stages", "spec_ngram"), "spec_ngram"),
+    ("tiny-granite", ("weight_int8", "mesh_expert", "kv_export"), "mesh_expert"),
+    ("granite-4.0-h-small-10l-e36", ("kv_int8", "prefix_cache"), "prefix_cache"),
 ])
 def test_of_two_refused_features_the_table_s_first_is_raised(model, features, first):
     with pytest.raises(FeatureUnsupported) as err:

@@ -764,6 +764,71 @@ def test_the_ouro_cell_programs_keep_the_pool_in_place_through_both_loops(one_ch
     assert 13.7e9 < total < 15.75e9, total
 
 
+# ---- granite-4.0-h-small-10l-e36 (PR 51): a Mamba-2 mixer OR attention a layer
+# (m m m m m a m m m m), 36 of every layer's 72 experts held: three runs of like
+# layers over a 9-deep state, a 1-deep pool and a [10, 36, ...] expert stack
+
+GRANITE = get_config("granite-4.0-h-small-10l-e36")
+
+
+@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (8, 128, 8), (1, 512, 32)],
+                         ids=["granite-decode-64", "granite-prefill-8x128",
+                              "granite-prefill-512"])
+def test_the_granite_cell_programs_run_by_runs_and_keep_state_pool_and_experts_in_place(
+        one_chip, mosaic_state_step, mosaic_grouped, B, T, MB):
+    """The cut preset as the cell serves it (3,200 pool blocks, 64 rows): one
+    ``while`` a run of like layers; the 2.45 GB state, the pool and the 6.79 GB
+    expert stack are the runs' carries, touched by the Mosaic calls and a
+    layer's own slice alone (no instruction produces a copy of the state, of
+    an expert matrix stack, or of a layer's share of one); every part runs
+    under its scope; and the program fits the chip's 15.75 GB."""
+    cfg = GRANITE
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, 3200, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("while(") >= 3, "one layer loop a run of like layers"
+    assert _custom_calls(text, "kv.write") == 1 and _custom_calls(text, "attn.read") == 1
+    assert _custom_calls(text, "moe.experts") == 3 * len(cfg.layer_runs)
+    if T == 1:  # the state-step kernel in each recurrent run
+        assert _custom_calls(text, "ssm.step") == 2
+    for scope in ("ssm.in_proj", "ssm.out_proj", "attn.qkv", "attn.out", "moe.router",
+                  "moe.dispatch", "moe.experts", "moe.combine", "moe.shared", "head.logits"):
+        assert re.search(rf'op_name="[^"]*{re.escape(scope)}', text), scope
+    one_matrix = cfg.experts_held * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_layers) == []
+    state_layer = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+    if T == 1:  # a longer chunk writes its layer's slice back (one fusion a layer)
+        assert _pool_sized_ops(text, state_layer, cfg.state_layers) == []
+    else:
+        whole = [ln for ln in _pool_sized_ops(text, state_layer, cfg.state_layers)
+                 if f"[{cfg.state_layers}," in ln and "dynamic-update-slice" not in ln]
+        assert whole == []
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(f"granite {B}x{T}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, alias "
+          f"{m.alias_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert total < 15.75e9, total
+
+
+def test_the_granite_centring_program_fits_beside_the_weights(one_chip, mosaic_grouped):
+    """core.center_router at the cut preset: 9.93 GB of weights in, ten
+    [4096, 72] routers out, the balancing batch's temporaries beside them."""
+    cfg = dataclasses.replace(GRANITE, max_seq_len=2048)
+    shapes = jax.eval_shape(
+        lambda: jax.jit(core._init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(jnp.bfloat16)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(core.center_router, static_argnums=1).lower(args, cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes == cfg.n_layers * cfg.d_model * cfg.n_experts * 2
+    print(f"granite centring: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB")
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
+
+
 # ---- q / k / v read where they lie (PR 48): to core.QKV_IN_PLACE_ROWS rows a
 # barrier keeps the three products plain, so each takes the stacked parameter
 # and the layer index; the parent's folded the head split into them and
