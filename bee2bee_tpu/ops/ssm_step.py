@@ -19,19 +19,48 @@ it back through XLA.
 
 **The tile follows from the shapes** (_head_tile): a grid step holds ``Th``
 heads of one row, block ``(Th, P, N)`` with the whole ``(P, N)`` of a head
-(falcon-h1: N = 256 on the lanes, P = 128 on the sublanes, 128 KB a head),
-so any head size and state size compiles — nothing falls back to XLA by
-shape. ``Th`` is the largest divisor of H that Mosaic can block ``x`` and
-``y`` by (a multiple of 8, or H itself) whose block stays within 2 MB: the
-pipeline holds four of them (in and out, two buffers each). On a v5e the
-call runs at the rate of a bare copy of the same blocks whatever ``Th``
-(8, 16, 32: 842-845 us a layer of 64 rows, the copy alone 844 us = 636
-GB/s of HBM read + write): the HBM copy bounds it, not the VPU's 5 ops a
-vreg nor the 16 lane reductions a head for ``y``.
+(N on the lanes, P on the sublanes), so any head size and state size
+compiles — nothing falls back to XLA by shape. ``Th`` is the largest divisor
+of H that Mosaic can block ``x`` and ``y`` by (a multiple of 8, or H itself)
+whose block stays within 2 MB: the pipeline holds four of them (in and out,
+two buffers each). Both served mixers get the SAME 2 MB, 512 vregs a grid
+step: falcon-h1 16 heads x [128, 256], granite-4.0-h-small 64 heads x
+[64, 128].
 
-All float32 on the VPU (no MXU, so no rounding to bf16): the new state is
-bit-for-bit XLA's (the same products in the same order), ``y`` differs by
-the order of its 256-term sum only.
+**The body has to hide under the block's copy** (on a v5e 6.5 us for 2 MB in
+and 2 MB out: a layer call of 64 rows 831-835 us = 643 GB/s of HBM read +
+write, whatever ``Th``), and what decides that is its CROSS-LANE work, not
+the VPU's 4 ops a vreg. A vreg of state ``[8, N <= 128]`` needs one lane
+broadcast of ``dt x`` (a permute on one of three cross-lane units; at N 256
+one serves two vregs) and, while ``y`` was a lane reduction a head
+(``sum(h * C, -1)``, each result then selected into a ``[P, Th]`` column block
+that was transposed at the end), one reduction too: 256 + 256 cross-lane
+ops a block at falcon-h1's shapes, 512 + 512 at granite's. Either kind
+alone hides (granite 834 us a call with ``y`` left out, 834 with the
+broadcast left out: my chip runs, PR 52); both did not: **1,094 us against
+the copy's 835**, where falcon-h1 read 833 against 831. So ``y`` is formed on
+the MXU, which the step did not use: ``[8, N] x [P, N]^T`` a head (C's row
+against the head's rows of state, the state the transposed operand) at
+``Precision.HIGHEST``, float32 in and out (six bf16 passes), its first row
+the head's ``[1, P]`` of ``y``, lane-dense: **835 us at granite's shapes,
+831 at falcon-h1's: the copy's rate at both.** Also kept: B's and C's rows
+are loaded once a run of ``gcd(Th, H / G)`` heads (such a run lies in one
+group wherever the block does: the whole block in both models) and not once
+a head at a dynamic group index: 1,094 -> 1,044 us alone. Tried and dropped,
+granite / falcon-h1 us a call: the whole block's ``y`` as ONE product after
+the loop (862 / 831: the MXU's pushes no longer interleave with the
+update), a ``[128, 128]`` transpose and adds down the sublanes (860 / 832),
+a butterfly of lane rotations and selects that sums 128 vregs in 127
+rotations (841 / 832, and ``y`` leaves in an order XLA has to undo), ``dt x``
+transposed by XLA outside the kernel (no gain beside the MXU form, and
+falcon-h1 slower: 860 / 841), the lane broadcast of ``dt x`` as an identity
+product on the MXU (890 / 835).
+
+All float32; the state's update stays on the VPU (the MXU would round its
+products), so the new state is bit-for-bit XLA's (the same products in the
+same order); ``y`` differs by the order of its N-term sum and the MXU's
+six-pass split only, far inside the bound any two orders of the sum keep
+(at most 0.6 % of it at either model's shapes on the chip).
 
 On devices that are not TPUs the kernel runs in pallas interpret mode
 (ops/flash.interpret_off_tpu), so the CPU test suite runs the same code.
@@ -40,6 +69,7 @@ On devices that are not TPUs the kernel runs in pallas interpret mode
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -78,17 +108,28 @@ def _step_kernel(
     heads: int,
     group_heads: int,
 ):
-    Th = h_ref.shape[0]
+    Th, _, N = h_ref.shape
     first = pl.program_id(1) * Th
     scalars = pl.program_id(0) * heads + first
     dtx = dtx_ref[...].T  # [P, Th]: a head's dt x down the sublanes
-    cols = []
-    for t in range(Th):
-        g = (first + t) // group_heads
-        h = h_ref[t] * dA_ref[scalars + t] + dtx[:, t:t + 1] * B_ref[pl.ds(g, 1), :]
-        hout_ref[t] = h
-        cols.append(jnp.sum(h * C_ref[pl.ds(g, 1), :], axis=-1, keepdims=True))
-    y_ref[...] = jnp.concatenate(cols, axis=1).T
+    # heads [t0, t0 + span) share one group wherever the block lies (span
+    # divides both counts): B's and C's rows are loaded once a span
+    span = math.gcd(Th, group_heads)
+    rows = []
+    for t0 in range(0, Th, span):
+        g = (first + t0) // group_heads
+        Bg = B_ref[pl.ds(g, 1), :]
+        Cg = jnp.broadcast_to(C_ref[pl.ds(g, 1), :], (8, N))  # an MXU operand's 8 rows
+        for t in range(t0, t0 + span):
+            h = h_ref[t] * dA_ref[scalars + t] + dtx[:, t:t + 1] * Bg
+            hout_ref[t] = h
+            # y of the head on the MXU, C's row against the head's rows of
+            # state: [8, N] x [P, N]^T, float32 in six bf16 passes
+            y = jax.lax.dot_general(
+                Cg, h, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            rows.append(y[:1])
+    y_ref[...] = jnp.concatenate(rows, axis=0)
 
 
 def ssm_state_step_xla(h, dt, x, Bm, Cm, A):

@@ -7,10 +7,11 @@ Tolerances. The new state is the same three float32 products and one sum in
 the same order as the XLA lines, so it may differ only where one side
 contracts ``a*b + c`` into a fused multiply-add: two units in the last place
 of the larger term. ``y`` is a sum of N products whose ORDER differs (the
-kernel adds the two 128-lane halves, then reduces across lanes; XLA reduces
-as it likes): any two orders of a float32 sum of n terms differ by at most
-``2 (n - 1) eps sum|terms|``, which is the bound asserted, element by
-element."""
+kernel forms it on the MXU, float32 at ``Precision.HIGHEST``: each product
+split over bf16 passes and accumulated in float32 in the unit's own order;
+XLA reduces as it likes): any two orders of a float32 sum of n terms differ
+by at most ``2 (n - 1) eps sum|terms|``, which is the bound asserted,
+element by element (the chip reads at most 0.6 % of it, chip_smoke)."""
 
 from __future__ import annotations
 
@@ -26,15 +27,27 @@ from bee2bee_tpu.models.config import get_config
 from bee2bee_tpu.ops.ssm_step import _head_tile, ssm_state_step, ssm_state_step_xla
 
 EPS = float(np.finfo(np.float32).eps)
-# (heads, head size, state size, groups): falcon-h1-34b's mixer and tiny-falcon-h1's
-SHAPES = {"falcon-h1": (32, 128, 256, 2), "tiny-falcon-h1": (4, 8, 16, 2)}
-# rows: one, an odd few, and the cell's 64-row bucket cut to what the CPU affords
-ROWS = {"falcon-h1": (1, 3, 8), "tiny-falcon-h1": (1, 3, 64)}
+# (heads, head size, state size, groups): the mixers of falcon-h1-34b, of
+# granite-4.0-h-small (both cells' shapes) and of tiny-falcon-h1, and two whose
+# block of 16 heads spans FOUR groups (a once-a-step load of B and C would
+# give twelve heads another group's rows): lane-aligned, and off the tiling
+SHAPES = {
+    "falcon-h1": (32, 128, 256, 2), "granite": (128, 64, 128, 1),
+    "tiny-falcon-h1": (4, 8, 16, 2),
+    "four-groups": (16, 16, 128, 4), "four-groups-narrow": (16, 8, 16, 4),
+}
+# rows: one, an odd few, and the cells' 64-row bucket cut to what the CPU affords
+ROWS = {
+    "falcon-h1": (1, 3, 8), "granite": (1, 3, 8), "tiny-falcon-h1": (1, 3, 64),
+    "four-groups": (1, 5), "four-groups-narrow": (1, 5),
+}
 LAYERS = 3
 
 
 def test_shapes_are_the_presets():
-    for name, preset in (("falcon-h1", "falcon-h1-34b"), ("tiny-falcon-h1",) * 2):
+    for name, preset in (
+            ("falcon-h1", "falcon-h1-34b"), ("granite", "granite-4.0-h-small-10l-e36"),
+            ("tiny-falcon-h1",) * 2):
         cfg = get_config(preset)
         assert SHAPES[name] == (
             cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups)
@@ -93,7 +106,8 @@ def test_kernel_equals_the_xla_step(shape, B, layer):
     assert np.all(np.abs(np.asarray(y) - np.asarray(want_y)) <= bound)
 
 
-@pytest.mark.parametrize("shape,B", [("falcon-h1", 2), ("tiny-falcon-h1", 5)])
+@pytest.mark.parametrize(
+    "shape,B", [("falcon-h1", 2), ("granite", 2), ("tiny-falcon-h1", 5), ("four-groups", 3)])
 def test_chained_steps_equal_chained_xla_steps(shape, B):
     """32 decode steps, the state carried through a donated buffer as the
     engine's window carries it, against 32 XLA steps. An error made in one

@@ -455,6 +455,8 @@ STATE_STEP_CASES = {
     "falcon-h1-decode-one-row": (1, 32, 128, 256, 2),
     "tiny-falcon-h1-decode": (4, 4, 8, 16, 2),  # blocks off the (8, 128) tiling
     "mamba2-64x128-decode": (8, 24, 64, 128, 1),
+    "granite-decode": (64, 128, 64, 128, 1),
+    "four-groups-a-block-decode": (8, 16, 16, 128, 4),
 }
 
 
