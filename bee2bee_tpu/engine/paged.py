@@ -1,4 +1,4 @@
-"""Paged KV cache: block pool, free-list allocator, block-level prefix sharing.
+"""Paged KV cache: block pool, free-interval allocator, block-level prefix sharing.
 
 The rectangular shared cache ``[L, bsz, max_seq, Hkv, hd]`` makes every
 row — idle or short — stream its full ``max_seq`` slice through HBM each
@@ -13,7 +13,7 @@ vLLM/"Ragged Paged Attention" (PAPERS.md, arxiv 2604.15464) pool model:
   ``(table[p // block_size], p % block_size)``. The map is
   order-preserving, so masks and position biases apply unchanged over
   the gathered view (models/core.forward's ``block_tables`` path).
-- **Host-side free-list allocator with refcounts**: blocks are allocated
+- **Host-side free-interval allocator with refcounts**: blocks are allocated
   lazily as decode crosses block boundaries and freed at retirement.
   Refcounts make blocks shareable — the block-level prefix cache pins a
   prompt's blocks and a matching request references the full ones
@@ -42,6 +42,7 @@ bit-identical, and a rewrite would perturb co-borrowers mid-decode.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import functools
 from collections.abc import Iterable
@@ -76,6 +77,14 @@ _C_KV_TILES = get_registry().counter(
     "grid steps of the ragged read: one layer's call x the dispatched "
     "window's attention calls (kind label: live = steps with a work item "
     "| stepped = all; live / stepped = the share of the grid that does work)",
+)
+_C_KV_PAGES_READ = get_registry().counter(
+    "engine.kv_pages_read",
+    "pool pages the ragged read's work items bring, counted as engine.kv_tiles "
+    "is (one layer's call x the dispatch's attention calls; kind label: in_run "
+    "= pages that arrive R at a time in ONE copy of a run of adjacent pool "
+    "blocks | single = pages copied one by one; in_run / all = how much of the "
+    "read the allocator's runs serve)",
 )
 _G_KV_TOKENS_HELD = get_registry().gauge(
     "engine.kv_tokens_held",
@@ -169,15 +178,24 @@ def prefill_chunk_positions(n: int, start: int, bucket: int, S: int) -> list[int
 
 
 class BlockAllocator:
-    """Free-list + refcount allocator over pool blocks 1..num_blocks-1
-    (block 0 is the reserved null block and is never handed out)."""
+    """Free-interval + refcount allocator over pool blocks 1..num_blocks-1
+    (block 0 is the reserved null block and is never handed out).
+
+    The free blocks are kept as disjoint intervals ``[start, end)``, sorted,
+    merged whenever a freed block touches one: the pool is page-major, so a
+    RUN of adjacent blocks is one stretch of a layer's memory and the ragged
+    read brings it with one copy (ops/ragged._tile_plan's ``R``). ``alloc``
+    therefore hands out ascending ids in the fewest runs the free space
+    allows. Nothing is reserved ahead: every free block is anyone's."""
 
     def __init__(self, num_blocks: int):
         if num_blocks < 2:
             raise ValueError(f"paged pool needs >= 2 blocks, got {num_blocks}")
         self.num_blocks = num_blocks
-        # pop() hands out low ids first — keeps early pool pages hot
-        self._free: list[int] = list(range(num_blocks - 1, 0, -1))
+        # one interval: low ids go out first — keeps early pool pages hot
+        self._starts: list[int] = [1]
+        self._ends: list[int] = [num_blocks]
+        self._n_free = num_blocks - 1
         self._refs = np.zeros((num_blocks,), np.int32)
         self.hwm = 0  # high-water mark of blocks in use (observability)
         _G_BLOCKS_TOTAL.set(num_blocks)
@@ -189,21 +207,52 @@ class BlockAllocator:
 
     @property
     def free_count(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     @property
     def used_count(self) -> int:
-        return self.num_blocks - 1 - len(self._free)
+        return self.num_blocks - 1 - self._n_free
 
-    def alloc(self, n: int) -> list[int] | None:
+    def free_runs(self) -> list[tuple[int, int]]:
+        """The free intervals ``(start, end)``, ascending (tests, debugging)."""
+        return list(zip(self._starts, self._ends))
+
+    def _take(self, i: int, n: int) -> range:
+        """The first n blocks of free interval i."""
+        start = self._starts[i]
+        if start + n == self._ends[i]:
+            del self._starts[i], self._ends[i]
+        else:
+            self._starts[i] = start + n
+        return range(start, start + n)
+
+    def alloc(self, n: int, after: int = 0) -> list[int] | None:
         """n fresh blocks (refcount 1), or None when the pool can't cover
         the whole request — partial allocations would leak on the caller's
-        retry path."""
-        if n > len(self._free):
+        retry path. Ascending ids in the FEWEST runs the free intervals
+        allow: the smallest interval that holds all n (the lowest of equals:
+        a fresh pool goes out from block 1 up), else the largest intervals
+        whole and the smallest that holds the rest. With ``after`` (a row's
+        last block: decode growth) the blocks that follow it come first, as
+        far as they are free, so the row's run goes on."""
+        if n > self._n_free:
             return None
-        out = [self._free.pop() for _ in range(n)]
-        for b in out:
-            self._refs[b] = 1
+        out: list[int] = []
+        if after:
+            i = bisect.bisect_left(self._starts, after + 1)
+            if i < len(self._starts) and self._starts[i] == after + 1:
+                out.extend(self._take(i, min(n, self._ends[i] - after - 1)))
+        grown = len(out)
+        while len(out) < n:
+            need = n - len(out)
+            sizes = [e - s for s, e in zip(self._starts, self._ends)]
+            fit = min((size for size in sizes if size >= need), default=0)
+            if not fit:  # no interval holds the rest: the largest goes whole
+                need = fit = max(sizes)
+            out.extend(self._take(sizes.index(fit), need))
+        out[grown:] = sorted(out[grown:])
+        self._refs[out] = 1
+        self._n_free -= n
         self.hwm = max(self.hwm, self.used_count)
         self._set_gauges()
         return out
@@ -215,16 +264,38 @@ class BlockAllocator:
 
     def deref(self, blocks: Iterable[int]) -> int:
         """Drop one reference per block; blocks reaching zero return to
-        the free list. Returns how many were freed."""
-        freed = 0
+        the free intervals, merged with the neighbours they touch. Returns
+        how many were freed."""
+        freed = []
         for b in blocks:
             assert self._refs[b] > 0, f"deref of free block {b}"
             self._refs[b] -= 1
             if self._refs[b] == 0:
-                self._free.append(b)
-                freed += 1
+                freed.append(b)
+        freed.sort()
+        i = 0
+        while i < len(freed):
+            j = i + 1
+            while j < len(freed) and freed[j] == freed[j - 1] + 1:
+                j += 1
+            self._release(freed[i], freed[j - 1] + 1)
+            i = j
+        self._n_free += len(freed)
         self._set_gauges()
-        return freed
+        return len(freed)
+
+    def _release(self, start: int, end: int) -> None:
+        """[start, end) joins the free intervals."""
+        i = bisect.bisect_left(self._starts, start)
+        if i and self._ends[i - 1] == start:  # grows the interval below it
+            i -= 1
+            self._ends[i] = end
+        else:
+            self._starts.insert(i, start)
+            self._ends.insert(i, end)
+        if i + 1 < len(self._starts) and self._starts[i + 1] == end:
+            self._ends[i] = self._ends.pop(i + 1)
+            del self._starts[i + 1]
 
     def refcount(self, block: int) -> int:
         return int(self._refs[block])
@@ -487,8 +558,9 @@ class RowCache:
 
     # ---- blocks
 
-    def _alloc_fresh(self, n: int) -> list[int]:
-        """n fresh blocks, reclaiming LRU prefix pins under pressure;
+    def _alloc_fresh(self, n: int, after: int = 0) -> list[int]:
+        """n fresh blocks (those behind block ``after`` first, where free:
+        BlockAllocator.alloc), reclaiming LRU prefix pins under pressure;
         raises PoolExhausted when even that can't cover it. On an int8
         pool the fresh blocks' scale entries reset to zero here — the
         quantize-on-write running max would otherwise inherit the PREVIOUS
@@ -496,10 +568,10 @@ class RowCache:
         Every allocation path (admission prefill, decode growth, CoW copy
         targets, KV imports) funnels through this method (the CoW copy and
         the import scatter then overwrite with the real scales)."""
-        fresh = self.alloc.alloc(n)
+        fresh = self.alloc.alloc(n, after)
         if fresh is None and self.prefix is not None:
             if self.prefix.evict_for_pressure(n):
-                fresh = self.alloc.alloc(n)
+                fresh = self.alloc.alloc(n, after)
         if fresh is None:
             raise PoolExhausted(
                 f"paged KV pool exhausted: need {n} blocks, "
@@ -520,7 +592,10 @@ class RowCache:
         if need <= have:
             return
         assert need <= self.blocks_per_row, (need, upto)
-        fresh = self._alloc_fresh(need - have)
+        # growth goes on behind the row's last block where that is free: a
+        # row's pages stay a run, which the ragged read brings in one copy
+        fresh = self._alloc_fresh(
+            need - have, after=self.row_blocks[b][-1] if have else 0)
         self.row_blocks[b].extend(fresh)
         self.tables[b, have:need] = fresh
 
@@ -631,35 +706,39 @@ class RowCache:
             )
 
     def count_tiles(self, tables, offsets, chunk: int, calls: int = 1):
-        """engine.kv_tiles for one dispatch of ``calls`` attention calls a
-        layer over ``tables`` [rows, tw], ``chunk`` tokens a row from
-        ``offsets``: the grid steps of ONE layer's ragged read, and those
-        with a work item — ops/ragged.work_counts, the call's own tile plan
-        and live-tile arithmetic on host integers, at the shapes one shard
-        of the pool sees. A model whose layers are of several kinds (full
-        beside windowed) counts each kind with its own window and adds the
-        mean over its layers, rounded down: the MEAN layer's call. Only
-        where the ragged kernel reads."""
+        """engine.kv_tiles and engine.kv_pages_read for one dispatch of
+        ``calls`` attention calls a layer over ``tables`` [rows, tw],
+        ``chunk`` tokens a row from ``offsets``: the grid steps of ONE
+        layer's ragged read, those with a work item, and the pages the items
+        bring in a run copy / one by one — ops/ragged.read_counts, the call's
+        own tile plan, live-tile and run arithmetic on host integers, at the
+        shapes one shard of the pool sees. A model whose layers are of
+        several kinds (full beside windowed) counts each kind with its own
+        window and adds the mean over its layers, rounded down: the MEAN
+        layer's call. Only where the ragged kernel reads."""
         e, cfg = self.engine, self.engine.model_cfg
         if e.engine_cfg.attention != "flash":
             return
-        from ..ops.ragged import work_counts  # loaded with the attn_fn
+        from ..ops.ragged import read_counts  # loaded with the attn_fn
 
         pages = next(iter(self.pool.values()))  # K beside V, or latent rows
         *_, heads, block, head_dim = pages.sharding.shard_shape(pages.shape)
-        live = stepped = 0
+        total = np.zeros(4, np.int64)  # live, stepped, in_run, single
         for window, n in collections.Counter(cfg.layer_windows).items():
-            one = work_counts(
+            total += n * np.array(read_counts(
                 tables, offsets[: len(tables)], window, heads=heads,
                 # query heads a stored head: all of them read a latent row
                 group=cfg.n_heads // next(iter(self.layout.values()))[0],
                 chunk=chunk,
                 head_dim=head_dim, block_size=block, itemsize=e.dtype.itemsize,
-                quantized=e.kv_quantized,
-            )
-            live, stepped = live + n * one[0], stepped + n * one[1]
-        _C_KV_TILES.inc(live * calls // cfg.cache_layers, kind="live")
-        _C_KV_TILES.inc(stepped * calls // cfg.cache_layers, kind="stepped")
+                quantized=e.kv_quantized, latent="latent" in self.layout,
+            ))
+        live, stepped, in_run, single = (
+            int(x) for x in total * calls // cfg.cache_layers)
+        _C_KV_TILES.inc(live, kind="live")
+        _C_KV_TILES.inc(stepped, kind="stepped")
+        _C_KV_PAGES_READ.inc(in_run, kind="in_run")
+        _C_KV_PAGES_READ.inc(single, kind="single")
 
     def note_tokens_held(self, contexts):
         """The gauges engine.kv_tokens_held / engine.kv_tokens_behind_window

@@ -23,21 +23,38 @@ the pool:
   The pipeline double-buffers all of them and fetches the next step's
   tile while this one computes. No gathered view, no [T, S] score
   materialization. What a step pays is copies ISSUED before bytes moved
-  (~0.1 us a copy; PERF.md Findings, PR 44): with K and V two head-major
-  arrays a page of 4 GQA heads was 2 operands of 4 strided 4 KB pieces,
-  and the read ran at 12 % of its roofline. A latent pool
+  (~0.15 us a page OPERAND of the pipeline; PERF.md Findings, PR 44 and
+  PR 53): with K and V two head-major arrays a page of 4 GQA heads was 2
+  operands of 4 strided 4 KB pieces, and the read ran at 12 % of its
+  roofline. A latent pool
   (``[NB, 1, BS, W]``, MLA) is the same operand with a unit axis where the
   two halves of heads stand, and no V.
   Every tensor operand's trailing block dims are ``(rows, hd)`` —
   Mosaic-tileable (a layout with the head axis between BS and hd would
   put a 1-blocked head axis second-to-last and fail to lower, and a
   bool-mask operand blocked per 16-lane page would violate the same rule
-  — the constraint that shaped ops/flash.py's head-major layout). The
-  copies stay BlockSpec copies, not hand-started DMAs from an ``ANY``
-  operand: one kernel then serves every pool (Mosaic refuses to slice an
-  HBM ref whose head size is off the 128-lane tiling — phi-3's 96,
-  gpt2's 64 as the dense readers' and the int8 pool's slices keep them —
-  and takes those as block shapes).
+  — the constraint that shaped ops/flash.py's head-major layout).
+- **Small pages: the kernel starts its own copies, and a RUN of adjacent
+  pages is ONE copy** (PR 53). Where a page is under 128 KB (4 GQA heads x
+  128: 32 KB) 32 page operands a step cost more than their bytes, so the
+  pool is ONE operand left where it lies (``pl.ANY``) and the kernel
+  brings a tile with ``pltpu.make_async_copy`` into one of two VMEM tile
+  buffers ``[Tp, 2, Th, BS, hd]``, the NEXT step's copies started before
+  this step's products (the work list holds every step's pages in SMEM).
+  The pool is page-major, so table entries whose pool blocks are p, p+1,
+  .. are one stretch of a layer: _work_list marks, a (step, copy group of
+  ``R`` entries — _tile_plan: the whole tile), whether the group is such a
+  run, and the kernel brings a marked group with one copy and any other
+  page by page (engine/paged.BlockAllocator hands a row ascending runs,
+  so nearly all are). An entry that names the null block is not copied at
+  all. The items, their order and the arithmetic are the page operands'
+  to the bit. Pages of 128 KB or more (phi-3's 256 KB, ouro's 128 KB), the
+  int8 pool, a latent pool, a head size off the 128 lanes (Mosaic refuses
+  to slice an HBM ref there — phi-3's 96, gpt2's 64 as the dense readers'
+  and the int8 pool's slices keep them — and takes those as block shapes)
+  and a step that takes some of the KV heads keep the page operands:
+  which form runs follows from the shapes (_tile_plan's ``R``), never from
+  a flag or a model's name.
 - **The grid walks a compacted work list** (PR 31), not the table: the
   grid is ``(Hkv/Th, B x q blocks x table width/Tp)`` — head groups,
   then ONE sequential axis of the table's static length (so the compile
@@ -88,7 +105,10 @@ the pool:
   after the call (fused into the cut of the pad rows). Both the compute
   and the cache traffic follow the row's live pages rounded up to a
   tile, while the table (and with it the compile space) stays as it
-  was. Dead entries INSIDE a live tile still copy the null block. ALiBi
+  was. Dead entries INSIDE a live tile (the null block's, past a row's
+  last page) still copy the null block as page operands; the kernel's own
+  copies skip them, and the tile buffers start as zeros so that what no
+  copy has reached is finite behind its zero weight. ALiBi
   stays dense-only (the bias needs absolute key positions per head; the
   engine validates).
 - **Online softmax** over the tile iterations with f32 m/l/acc VMEM
@@ -185,14 +205,18 @@ _TILE_BYTES = 2**20  # K+V bytes one page tile aims to move
 _TILE_TOKENS = 512  # most key positions a tile may span
 _TILE_PAGES = 32  # most table entries a step: each is one page operand
 _SCORE_ELEMS = 128 * 1024  # most [bq, Tp*BS] f32 score elements a head
+_RUN_BYTES = 2**20  # most bytes ONE copy of a run of adjacent pages moves
+_RUN_PAGE_BYTES = 128 * 1024  # a page this large is a copy of its own: R = 1
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
-    """(Th, Tp, bq): KV heads, table entries and q rows of one grid step —
+def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256,
+               latent=False):
+    """(Th, Tp, bq, R): KV heads, table entries and q rows of one grid step,
+    and the pages of one COPY GROUP —
     a pure function of the call's shapes (``Hkv`` is the heads THIS shard
     holds; ``itemsize`` the compute dtype's, the pool's is 1 when
     ``quantized``). ``Th`` is the largest divisor of Hkv whose per-head
@@ -206,7 +230,23 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
     step's ``Th`` heads (a latent row is reckoned as if it had a V: its
     plan is what it was). So 32 MHA heads of 96 take the whole head axis
     and 4 pages a step, a 4-head GQA group 32 pages, a 2048-row prefill
-    chunk 4 heads, 256 q rows and 32 pages."""
+    chunk 4 heads, 256 q rows and 32 pages.
+
+    ``R`` is how many consecutive table entries ONE copy may bring when
+    their pool blocks are adjacent (a run: the pool is page-major, so
+    blocks p .. p+R-1 of a layer are one stretch of memory): the largest
+    power of two, at most ``Tp``, that keeps such a copy at 1 MB — which is
+    the tile itself wherever it applies (32 pages of 4 GQA heads x 128, 16
+    of granite's 8 heads: my chip runs, PR 53, a whole tile in one copy read
+    as fast as 4 copies of 256 KB at decode and 7 % faster in the 2,048
+    chunk, and tiles copied page by page 4 % faster than groups of 8 were).
+    A page that is 128 KB or more already moves near the bytes' rate
+    (phi-3's 256 KB, ouro's 128 KB): ``R`` = 1, which is the page-operand
+    program as it was, as for an int8 pool (its scales are a page's), a
+    latent pool, a head size off the 128 lanes (Mosaic slices no HBM ref
+    there; every served pool is lane-aligned) and a step that takes some
+    of the KV heads only (``Th`` < ``Hkv``, phi-3's 2,048 bucket: its share
+    of a page is pieces, and adjacent pages do not join them)."""
     nq = G * T
     bq = min(block_q, max(nq, 8))
     lanes = _round_up(hd, _LANES)
@@ -242,7 +282,13 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256):
     Tp = 1
     while Tp < MB and fits(2 * Tp):
         Tp *= 2
-    return Th, Tp, bq
+    R = 1
+    page_bytes = 2 * Th * BS * hd * itemsize  # what one copy of a page moves
+    if not (quantized or latent or hd % _LANES or Th != Hkv
+            or page_bytes >= _RUN_PAGE_BYTES):
+        while 2 * R <= Tp and 2 * R * page_bytes <= _RUN_BYTES:
+            R *= 2
+    return Th, Tp, bq, R
 
 
 def _live_tiles(off, win, i, *, chunk, block_q, tile_tokens, n_tiles, xp=jnp):
@@ -253,7 +299,7 @@ def _live_tiles(off, win, i, *, chunk, block_q, tile_tokens, n_tiles, xp=jnp):
     (block_q divides T) has its own, any other spans the chunk. A tile
     outside [lo, hi) is wholly past the causal frontier or wholly below
     the window. ``xp`` is the array module: jnp for the call's traced
-    scalars, numpy for the scheduler's host integers (work_counts)."""
+    scalars, numpy for the scheduler's host integers (read_counts)."""
     if chunk % block_q == 0:
         qlo = off + (i * block_q) % chunk
         qhi = qlo + block_q - 1
@@ -290,8 +336,27 @@ def _item_counts(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
 _WORK, _FIRST, _LAST = 1, 2, 4  # bits of a work item's flags
 
 
+def _run_bits(pages, run_pages, xp=jnp):
+    """One int32 word a table tile, out of ``pages`` [..., Tp]: bit g says
+    that copy group g — entries g*R .. g*R+R-1 — is a RUN, pool blocks p,
+    p+1, .. p+R-1 with p not the null block (one copy brings it), bit 16+g
+    that none of its entries is the null block (its copies, however many,
+    move R pages: what the wait expects). Tp / R <= 16 groups. The word
+    comes out of a reduction (_work_list: why)."""
+    R = run_pages
+    grp = pages.reshape(*pages.shape[:-1], -1, R)
+    ramp = xp.arange(R, dtype=xp.int32)
+    run = (grp[..., 0] != 0) & xp.all(grp == grp[..., :1] + ramp, axis=-1)
+    whole = xp.all(grp != 0, axis=-1)
+    g = xp.arange(grp.shape[-2], dtype=xp.int32)
+    return xp.sum(
+        (run.astype(xp.int32) << g) + (whole.astype(xp.int32) << (16 + g)),
+        axis=-1, dtype=xp.int32,
+    )
+
+
 def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
-               block_size):
+               block_size, run_pages=1):
     """((seg, tile, flags, pages), visited): the call's compacted work
     list, one entry a step of the sequential grid axis — three [S] int32
     with S = B x q blocks x tiles and ``pages`` [S * Tp], the Tp pool blocks
@@ -303,7 +368,9 @@ def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
     of its (row, q block). The steps past the last item repeat ITS seg, tile
     and pages with no flag: no block index changes there, so the pipeline
     starts no copy, and the body computes nothing. Built from what the
-    kernel prefetches anyway (tables, offsets, the window).
+    kernel prefetches anyway (tables, offsets, the window). With
+    ``run_pages`` R > 1 the tuple ends with ``runs`` [S]: the step's copy
+    groups that are runs of adjacent pool blocks (_run_bits).
 
     Inside a layer loop the list is loop-invariant wherever the window is
     one constant, and the TPU compiler hoists it — all but an operand of
@@ -330,39 +397,58 @@ def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
     flags = jnp.where(
         step < n_live, _WORK + _FIRST * (k == 0) + _LAST * (k == n_ - 1), 0
     ).astype(jnp.int32)
-    pages = tables.reshape(B, n_tiles, Tp)[seg // n_qblocks, tile].reshape(-1)
+    pages = tables.reshape(B, n_tiles, Tp)[seg // n_qblocks, tile]
+    runs = (_run_bits(pages, run_pages),) if run_pages > 1 else ()
+    pages = pages.reshape(-1)
     visited = jnp.any(
         (seg[None, :] == jnp.arange(B * n_qblocks)[:, None]) & (flags[None, :] != 0),
         axis=1,
     )
-    return (seg, tile, flags, pages), visited.reshape(B, n_qblocks)
+    return (seg, tile, flags, pages, *runs), visited.reshape(B, n_qblocks)
 
 
-def work_counts(tables, offsets, window, *, heads, group, chunk, head_dim,
-                block_size, itemsize, quantized=False):
-    """(live, stepped) of ONE layer's call on host integers: the work items
-    the read's grid does and the grid steps it takes, head groups included —
-    the same _tile_plan and _live_tiles arithmetic the call runs on the
-    device, on numpy ``tables`` [B, MB] and ``offsets`` [B]. ``heads`` is
-    the KV heads a shard holds, ``head_dim`` the pool's. For the
-    scheduler's engine.kv_tiles counter."""
+def read_counts(tables, offsets, window, *, heads, group, chunk, head_dim,
+                block_size, itemsize, quantized=False, latent=False):
+    """(live, stepped, in_run, single) of ONE layer's call on host integers:
+    the work items the read's grid does and the grid steps it takes, head
+    groups included, and the pages (table entries that are not the null
+    block) its items bring in a run copy / one by one — the same _tile_plan,
+    _live_tiles and _run_bits arithmetic the call runs on the device, on
+    numpy ``tables`` [B, MB] and ``offsets`` [B]. ``heads`` is the KV heads
+    a shard holds, ``head_dim`` the pool's. For the scheduler's
+    engine.kv_tiles and engine.kv_pages_read counters."""
     import numpy as np
 
     tables = np.asarray(tables, np.int32)
     B, MB = tables.shape
-    Th, Tp, bq = _tile_plan(
-        heads, group, chunk, head_dim, block_size, MB, itemsize, quantized
+    Th, Tp, bq, R = _tile_plan(
+        heads, group, chunk, head_dim, block_size, MB, itemsize, quantized,
+        latent=latent,
     )
     if MB % Tp:
         tables = np.pad(tables, ((0, 0), (0, -MB % Tp)))
     n_qblocks = _round_up(group * chunk, bq) // bq
-    _, n = _item_counts(
+    lo, n = _item_counts(
         tables, np.asarray(offsets, np.int32), np.int32(window), chunk=chunk,
         block_q=bq, n_qblocks=n_qblocks, tile_pages=Tp, block_size=block_size,
         xp=np,
     )
     groups = heads // Th
-    return groups * int(n.sum()), groups * B * n_qblocks * (tables.shape[1] // Tp)
+    tiles = tables.reshape(B, -1, Tp)
+    # a tile's mapped pages, and those of its copy groups that are runs
+    a_tile = np.zeros((B, tiles.shape[1], 2), np.int64)
+    a_tile[..., 0] = (tiles != 0).sum(axis=2)
+    if R > 1:
+        bits = _run_bits(tiles, R, xp=np) & 0xFFFF
+        a_tile[..., 1] = R * (bits[..., None] >> np.arange(Tp // R) & 1).sum(axis=2)
+    # summed over every item's tile: tiles [first, first + n) of a (row, q block)
+    upto = np.zeros((B, tiles.shape[1] + 1, 2), np.int64)
+    np.cumsum(a_tile, axis=1, out=upto[:, 1:])
+    first = np.minimum(lo, tiles.shape[1])  # (an item-less row's may lie past)
+    row = np.arange(B)[:, None]
+    mapped, in_run = (upto[row, first + n] - upto[row, first]).sum(axis=(0, 1))
+    return (groups * int(n.sum()), groups * B * n_qblocks * tiles.shape[1],
+            groups * int(in_run), groups * int(mapped - in_run))
 
 
 def _ragged_kernel(
@@ -380,17 +466,24 @@ def _ragged_kernel(
     # quantized=True prepends one more scalar-prefetch ref:
     #   scale_ref   SMEM [2, Hkv, B, MBp] f32: K's and V's page scales,
     #               pre-gathered through the block tables per row
+    # run_pages > 1 likewise:
+    #   runs_ref    SMEM [S] the step's copy groups that are runs (_run_bits)
     # then the tensor operands either way:
     #   q_ref        [1, Th, BQ, hd]  q rows: GQA group g major, chunk pos t minor
     #   page_refs[p] [2, Th, BS, hd]  Tp operands: K beside V of Th heads of the
     #                pool block at entry p of the step's table tile ([Th, BS, W]
     #                of a latent pool: Th is its unit axis)
+    #                — or, run_pages > 1, ONE operand: the pool where it lies
+    #                (pl.ANY), its tiles brought by the kernel's own copies
     #   o_ref        [1, Th, BQ, hd]
     #   m_ref        VMEM [Th, BQ, 128] f32 running max
     #   l_ref        VMEM [Th, BQ, 128] f32 running sum
     #   acc_ref      VMEM [Th, BQ, hd] f32
     # and, quantized, the tile's dequantized keys and values:
     #   kdq_ref, vdq_ref  VMEM [Th, Tp*BS, hd] compute dtype
+    # or, run_pages > 1, where the copies land and what they signal:
+    #   buf_ref      VMEM [2, Tp, 2, Th, BS, hd] this step's tile, the next's
+    #   sem_ref      DMA semaphores [2], one a buffer
     sm_scale: float,
     softcap: float,
     block_size: int,
@@ -403,17 +496,82 @@ def _ragged_kernel(
     v_width: int = 0,  # latent rows (MLA): a page holds no V, a fetched tile
     #                    is the keys as it is and the values by its first
     #                    v_width columns; o_ref / acc_ref are v_width wide
+    run_pages: int = 1,  # R: table entries one copy brings when their pool
+    #                      blocks are adjacent (_tile_plan); 1 = page operands
+    stacked: bool = False,  # run_pages > 1: the pool operand leads with L
 ):
-    Th, Tp, BS = tile_heads, tile_pages, block_size
+    Th, Tp, BS, R = tile_heads, tile_pages, block_size, run_pages
     if quantized:
         scale_ref, *refs = refs
-    q_ref, *refs = refs
-    page_refs = refs[:Tp]
-    o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[Tp:]
+    if R > 1:
+        runs_ref, q_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref, buf_ref, sem_ref = refs
+        dq_refs = ()
+    else:
+        q_ref, *refs = refs
+        page_refs = refs[:Tp]
+        o_ref, m_ref, l_ref, acc_ref, *dq_refs = refs[Tp:]
     tile_tokens = Tp * BS
     h0 = pl.program_id(0) * Th
     step = pl.program_id(1)
     flags = flag_ref[step]
+
+    def tile_copies(t, slot, wait):
+        """Start the copies that bring step t's tile into buffer ``slot``,
+        or wait for them: a copy group that is a run arrives as ONE copy of
+        R adjacent pool blocks, any other page by page, and an entry that
+        names the null block is not copied at all (it lies past the row's
+        last token: its keys are masked, whatever the buffer holds there).
+        Every copy of a buffer signals its one semaphore, which counts what
+        arrived: a group with no null entry is waited for as a whole,
+        however it was started."""
+        src = pool_ref.at[lay_ref[0]] if stacked else pool_ref
+        word = runs_ref[t]
+
+        def move(entry, page, n):  # whole pages: Th == Hkv (_tile_plan)
+            copy = pltpu.make_async_copy(
+                src.at[pl.ds(page, n)],
+                buf_ref.at[slot, pl.ds(entry, n)],
+                sem_ref.at[slot],
+            )
+            copy.wait() if wait else copy.start()
+
+        for g in range(Tp // R):
+            first = t * Tp + g * R
+            whole = (word >> (g + 16 * wait)) & 1
+
+            @pl.when(whole != 0)
+            def _run():
+                move(g * R, pages_ref[first], R)
+
+            @pl.when(whole == 0)
+            def _pages():
+                for r in range(R):
+                    page = pages_ref[first + r]
+
+                    @pl.when(page != 0)
+                    def _page():
+                        move(g * R + r, page, 1)
+
+    if R > 1:
+        n_steps = pl.num_programs(1)
+        slot = step % 2  # two tile buffers: this step's and the next's
+
+        @pl.when(step == 0)
+        def _prime():
+            # a buffer's entries that no copy has reached yet meet the
+            # values' product behind a zero weight: they must be finite
+            buf_ref[...] = jnp.zeros_like(buf_ref)
+
+            @pl.when(flags & _WORK != 0)
+            def _first_tile():
+                tile_copies(step, slot, wait=False)
+
+        # the NEXT step's tile is in flight while this one computes
+        nxt = jnp.minimum(step + 1, n_steps - 1)
+
+        @pl.when((step + 1 < n_steps) & (flag_ref[nxt] & _WORK != 0))
+        def _prefetch():
+            tile_copies(nxt, 1 - slot, wait=False)
 
     @pl.when(flags & _FIRST != 0)
     def _init():
@@ -429,7 +587,14 @@ def _ragged_kernel(
         off = off_ref[b]
         win = win_ref[0]
         q = q_ref[0]  # [Th, BQ, hd]
-        if quantized:
+        if R > 1:
+            tile_copies(step, slot, wait=True)
+            k, v = (
+                jnp.concatenate(
+                    [buf_ref[slot, p, half] for p in range(Tp)], axis=1)
+                for half in (0, 1)
+            )
+        elif quantized:
             # every key (value) row of a page shares ONE scale per kv head:
             # the wrapper pre-gathered the per-page scales through the
             # block tables to [2, Hkv, B, MBp], so the item's row and tile
@@ -561,9 +726,11 @@ def ragged_paged_attention(
     quantized = scale is not None
 
     nq = G * T
-    Th, Tp, bq = _tile_plan(
-        Hkv, G, T, hd, BS, MB, q.dtype.itemsize, quantized, block_q
+    Th, Tp, bq, R = _tile_plan(
+        Hkv, G, T, hd, BS, MB, q.dtype.itemsize, quantized, block_q, latent
     )
+    while R > NB:  # (a test's pool: smaller than a copy group, it holds no such run)
+        R //= 2
     nqp = _round_up(nq, bq)
     n_qblocks = nqp // bq
     # [B, T, H, hd] -> [B, Hkv, G*T, hd]: head h = kvh*G + g attends kv
@@ -587,7 +754,7 @@ def ragged_paged_attention(
     lay = jnp.asarray(layer if stacked else 0, jnp.int32).reshape(-1)[:1]
     work, visited = _work_list(
         tables, off, win[0], chunk=T, block_q=bq, n_qblocks=n_qblocks,
-        tile_pages=Tp, block_size=BS,
+        tile_pages=Tp, block_size=BS, run_pages=R,
     )
 
     hd_o = v_width if latent else hd  # the output block's width
@@ -603,6 +770,8 @@ def ragged_paged_attention(
         tile_pages=Tp,
         quantized=quantized,
         v_width=v_width or 0,
+        run_pages=R,
+        stacked=stacked,
     )
 
     # index maps take the grid indices (head group, step) and the
@@ -611,7 +780,9 @@ def ragged_paged_attention(
     # both). The page maps ARE the gather: entry p of the step's tile reads
     # K and V of Th heads of pool block pages[s*Tp + p] (of layer lay[0]
     # when the pool is stacked) in ONE copy — one SMEM load a map; a step
-    # whose pages are the step before's (the tail) starts no copy
+    # whose pages are the step before's (the tail) starts no copy. Where a
+    # copy may bring a RUN of pages (R > 1) the pool is ONE operand left
+    # where it lies, and the kernel starts its copies itself (tile_copies)
     def page_map(p):
         def index(h, s, seg_, tile_, flag_, pages_, off_, win_, lay_, *_):
             page = (pages_[s * Tp + p], *(0,) * (len(parts) - 1), h, 0, 0)
@@ -627,17 +798,25 @@ def ragged_paged_attention(
         )
 
     page_block = (None,) * (stacked + 1) + (*parts[:-1], Th, BS, hd)
+    if R > 1:
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        tile_scratch = [
+            pltpu.VMEM((2, Tp, 2, Th, BS, hd), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    else:
+        pool_specs = [pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)]
+        tile_scratch = [pltpu.VMEM((Th, Tp * BS, hd), q.dtype)] * (2 * quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7 + quantized,
+        num_scalar_prefetch=len(work) + 3 + quantized,
         grid=(Hkv // Th, B * n_qblocks * n_tiles),
-        in_specs=[qo_spec(hd)] + [
-            pl.BlockSpec(page_block, page_map(p)) for p in range(Tp)],
+        in_specs=[qo_spec(hd)] + pool_specs,
         out_specs=qo_spec(hd_o),
         scratch_shapes=[
             pltpu.VMEM((Th, bq, _LANES), jnp.float32),
             pltpu.VMEM((Th, bq, _LANES), jnp.float32),
             pltpu.VMEM((Th, bq, hd_o), jnp.float32),
-        ] + [pltpu.VMEM((Th, Tp * BS, hd), q.dtype)] * (2 * quantized),
+        ] + tile_scratch,
     )
     # pre-gather the per-page scales through the block tables OUTSIDE the
     # kernel: the SMEM operand is then [2, Hkv, B, MBp] — bounded by the
@@ -662,7 +841,7 @@ def ragged_paged_attention(
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(*work, off, win, lay, *scales, qT, *[pool] * Tp)
+    )(*work[:4], off, win, lay, *scales, *work[4:], qT, *[pool] * len(pool_specs))
     # a (row, q block) with no item was never visited: its block of `out`
     # holds whatever the buffer held. Zero it (fused into the cut below)
     out = jnp.where(

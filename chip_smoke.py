@@ -920,10 +920,152 @@ def _state_step_case(device_kind: str, preset: str, rows: int = 64) -> dict:
     }
 
 
+# The read alone where a copy may pass a page (PR 53): smallthinker's decode
+# and 2,048 chunk (GQA 28 / 4 x 128, 32 KB pages, 1,024-page tables), full
+# and behind the 4,096 window. (rows, queries a row, each row's LENGTH range)
+READ_RUNS_CASES = {
+    "st_decode": dict(B=32, T=1, lengths=(4300, 8400)),
+    "st_chunk_2048": dict(B=1, T=2048, lengths=(6144, 8192)),
+}
+
+
+def _bare_read_us(n_bytes: int, piece_bytes: int = 2**20) -> float:
+    """Microseconds to bring ``n_bytes`` of one array into VMEM and nothing
+    else: a pipelined pallas read in pieces of 1 MB (what a tile of the
+    ragged read moves a step), the body one store."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    rows = piece_bytes // (128 * 2)
+    n = max(n_bytes // piece_bytes, 1)
+    x = jnp.ones((n, rows, 128), jnp.bfloat16)
+
+    def body(x_ref, o_ref):
+        o_ref[...] = x_ref[0, :8].astype(jnp.float32)
+
+    fn = jax.jit(lambda x: pl.pallas_call(
+        body, grid=(n,), in_specs=[pl.BlockSpec((1, rows, 128), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32))(x))
+    fn(x).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(KERNEL_TIMED_CALLS):
+        out = fn(x)
+    out.block_until_ready()
+    return (time.perf_counter() - t0) / KERNEL_TIMED_CALLS * 1e6 * n_bytes / (n * piece_bytes)
+
+
+def _read_runs_case(case: dict, window: int, run_bytes=(None, 0), layers: int = 8) -> dict:
+    """ragged_paged_attention alone on a stacked pool of ``layers`` layers,
+    a scan over them as core.forward's layer loop calls it, on tables whose
+    every row is ONE ascending run of pool blocks (what the allocator hands
+    a prompt since PR 53) and on the same pages PERMUTED (every copy group
+    broken: the page-by-page side), each under the planned copy group and
+    with ``ops.ragged._RUN_BYTES`` = 0 (R = 1: the page-operand program, the
+    parent's kernel byte for byte; any other budget: the copy groups PR 53
+    tried before it kept the whole tile): microseconds a call, the GB/s of the
+    pages its items bring, and a bare read of the same bytes. The two tables
+    name the same keys in the same order: their outputs must be bit-equal,
+    and within the kernel tolerance of the page-operand program's."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bee2bee_tpu.ops import ragged
+
+    H, Hkv, hd, BS, MB = 28, 4, 128, KERNEL_BLOCK, 1024
+    B, T = case["B"], case["T"]
+    rng = np.random.default_rng(SEED)
+    lengths = rng.integers(case["lengths"][0], case["lengths"][1] + 1, size=B)
+    pages = -(-lengths // BS)
+    NB = int(pages.sum()) + 1
+    key = jax.random.key(SEED)
+    pool = jax.random.normal(key, (layers, NB, 2, Hkv, BS, hd), jnp.bfloat16)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, T, H, hd), jnp.bfloat16)
+    off = jnp.asarray(lengths - T, jnp.int32)
+    runs = np.zeros((B, MB), np.int32)
+    start = 1
+    for b in range(B):
+        runs[b, : pages[b]] = np.arange(start, start + pages[b])
+        start += pages[b]
+    perm = np.concatenate([[0], 1 + rng.permutation(NB - 1)]).astype(np.int32)
+    inverse = np.argsort(perm)
+    tables = {"runs": (pool, runs)}
+    # block perm[i] of the permuted pool holds what block i held
+    tables["permuted"] = (pool[:, inverse], perm[runs])
+
+    def every_layer():  # a function of its own a budget: jit keys on the function
+        def read(q, pool, table, off):
+            def one(acc, li):
+                out = ragged.ragged_paged_attention(
+                    q, pool, table, off, window=window, interpret=False, layer=li)
+                return acc + out.astype(jnp.float32), None
+            return jax.lax.scan(
+                one, jnp.zeros((B, T, H * hd), jnp.float32),
+                jnp.arange(layers, dtype=jnp.int32))[0]
+        return jax.jit(read)
+
+    shapes = dict(heads=Hkv, group=H // Hkv, chunk=T, head_dim=hd, block_size=BS,
+                  itemsize=2)
+    # the pages a call's items bring: the same under every copy group
+    moved = sum(ragged.read_counts(runs, np.asarray(off), window, **shapes)[2:]
+                ) * 2 * Hkv * BS * hd * 2
+    line: dict = {"B": B, "T": T, "window": window, "table_width": MB,
+                  "layers": layers, "bytes_per_call": moved}
+    outs = {}
+    for budget in run_bytes:
+        patch = mock.patch.object(
+            ragged, "_RUN_BYTES", ragged._RUN_BYTES if budget is None else budget)
+        with patch:
+            R = ragged._tile_plan(Hkv, H // Hkv, T, hd, BS, MB, 2, False)[3]
+            counts = ragged.read_counts(runs, np.asarray(off), window, **shapes)
+            fn = every_layer()  # the budget is read at trace time
+            for name, (pl_, tb) in tables.items():
+                args = (q, pl_, jnp.asarray(tb), off)
+                out = fn(*args)
+                out.block_until_ready()
+                t0 = time.perf_counter()
+                for _ in range(KERNEL_TIMED_CALLS):
+                    out = fn(*args)
+                out.block_until_ready()
+                us = (time.perf_counter() - t0) / (KERNEL_TIMED_CALLS * layers) * 1e6
+                outs[R, name] = np.asarray(out)
+                line[f"R{R}_{name}"] = {
+                    "us_per_call": round(us, 1), "GBs": round(moved / us / 1e3, 1),
+                    "items": counts[0], "pages": counts[2] + counts[3],
+                    "pages_in_run_on_run_tables": counts[2],
+                }
+    line["bare_read_us"] = round(_bare_read_us(moved), 1)
+    base = outs[1, "permuted"]
+    line["bit_equal_runs_vs_permuted"] = all(
+        np.array_equal(outs[R, "runs"], outs[R, "permuted"]) for R, _ in outs)
+    line["worst_diff_over_tolerance_vs_page_operands"] = float(max(
+        np.max(np.abs(o - base) / (layers * (KERNEL_ATOL + KERNEL_RTOL * np.abs(base / layers))))
+        for o in outs.values()))
+    line["ok"] = bool(line["bit_equal_runs_vs_permuted"]
+                      and line["worst_diff_over_tolerance_vs_page_operands"] <= 1.0)
+    return line
+
+
+def _read_runs_cases() -> dict:
+    """Case ``st_read_runs``: the ragged read alone at smallthinker's decode
+    and chunk shapes, behind the 4,096 window and without, run tables against
+    permuted ones, the kernel's own copies against the page operands."""
+    cases = {
+        f"{name}_window{window}": _read_runs_case(case, window)
+        for name, case in READ_RUNS_CASES.items() for window in (4096, 0)
+    }
+    return {**cases, "ok": all(c["ok"] for c in cases.values())}
+
+
 def child_kernel() -> None:
     """The ragged kernel, compiled, against the dense path on the chip
     (16-slot pages, bf16), and its microseconds a call; then the state-step
-    kernel at falcon-h1's and granite's shapes against the XLA recurrence."""
+    kernel at falcon-h1's and granite's shapes against the XLA recurrence,
+    and the read alone on run tables and permuted ones (``st_read_runs``)."""
     from bee2bee_tpu.utils import enable_compile_cache
 
     enable_compile_cache()
@@ -939,6 +1081,7 @@ def child_kernel() -> None:
     line["cases"]["h1_state_step"] = _state_step_case(dev.device_kind, "falcon-h1-34b-6l")
     line["cases"]["granite_state_step"] = _state_step_case(
         dev.device_kind, "granite-4.0-h-small-10l-e36")
+    line["cases"]["st_read_runs"] = _read_runs_cases()  # PR 53
     # JoyAI-LLM-Flash (PR 39): the latent pool's kernels, the grouped product
     line["cases"]["joyai_latent_decode"] = _latent_case(64, 1, 64, 700)
     line["cases"]["joyai_latent_decode_table8"] = _latent_case(64, 1, 8, 120)

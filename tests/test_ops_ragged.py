@@ -13,6 +13,7 @@ prefill, paged decode and a spec-verify row in a single batch through
 
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import replace
 
@@ -429,7 +430,9 @@ def test_tile_plan_follows_shapes_within_vmem_budget():
     VMEM arithmetic under the budget for every shape the engine issues."""
     from bee2bee_tpu.ops import ragged
 
-    plan = ragged._tile_plan
+    def plan(*shapes):  # the copy group R has a test of its own, below
+        return ragged._tile_plan(*shapes)[:3]
+
     # phi-3-mini decode: all 32 MHA heads, 4 pages a step (a page operand is
     # K beside V of every head: 256 KB, 1 MB a step; my chip runs, PR 44), int8
     # pages the same; its 2048-row prefill chunk: fewer heads so that q, scores
@@ -598,10 +601,10 @@ def test_scheduler_counts_live_and_stepped_tiles():
     steps of one layer's call x the window's calls - all of them
     (``stepped``: head groups x rows x q blocks x tiles of the tile plan)
     and those with a work item (``live``: the rows' live tiles), by the
-    call's own arithmetic (ops/ragged.work_counts)."""
+    call's own arithmetic (ops/ragged.read_counts)."""
     from bee2bee_tpu.engine import EngineConfig, InferenceEngine
     from bee2bee_tpu.metrics import get_registry
-    from bee2bee_tpu.ops.ragged import _tile_plan, work_counts
+    from bee2bee_tpu.ops.ragged import _tile_plan, read_counts
 
     tiles = get_registry().counter("engine.kv_tiles")
 
@@ -622,7 +625,7 @@ def test_scheduler_counts_live_and_stepped_tiles():
         tables = orig(extra, calls)
         if tables is not None:
             B, MB = tables.shape
-            Th, Tp, _ = _tile_plan(
+            Th, Tp, _, _ = _tile_plan(
                 cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, 1, cfg.head_dim,
                 BS, MB, eng.dtype.itemsize, False)
             tt = Tp * BS
@@ -651,10 +654,10 @@ def test_scheduler_counts_live_and_stepped_tiles():
     tables[0, :13], tables[2, :3] = 1, 2  # rows 1 and 3 map no page
     kw = dict(heads=32, group=1, chunk=1, head_dim=128, block_size=16, itemsize=2)
     # 32 MHA heads: tiles of 4 pages = 64 keys, 8 of them across the table
-    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (4 + 1, 4 * 8)
-    assert work_counts(tables, [200, 999, 40, 9], 64, **kw) == (2 + 1, 4 * 8)
+    assert read_counts(tables, [200, 999, 40, 9], 0, **kw)[:2] == (4 + 1, 4 * 8)
+    assert read_counts(tables, [200, 999, 40, 9], 64, **kw)[:2] == (2 + 1, 4 * 8)
     tables[:] = 0
-    assert work_counts(tables, [200, 999, 40, 9], 0, **kw) == (0, 4 * 8)
+    assert read_counts(tables, [200, 999, 40, 9], 0, **kw)[:2] == (0, 4 * 8)
 
 
 # (Hkv, G, T, hd, MB, offs, window): the cells' shapes at the plan PR 44's
@@ -674,11 +677,11 @@ WORK_COUNT_CASES = {
 
 @pytest.mark.parametrize("case", sorted(WORK_COUNT_CASES))
 def test_work_counts_equal_the_devices_item_count_at_the_new_plan(case):
-    """ops/ragged.work_counts (host integers, for engine.kv_tiles) against the
+    """ops/ragged.read_counts (host integers, for engine.kv_tiles) against the
     work list the call itself builds on the device, under the same tile plan:
     the items flagged as work and the grid's steps, head groups included; one
     row of every case is retired (table nulled, offset stale)."""
-    from bee2bee_tpu.ops.ragged import _WORK, _round_up, _tile_plan, _work_list, work_counts
+    from bee2bee_tpu.ops.ragged import _WORK, _round_up, _tile_plan, _work_list, read_counts
 
     Hkv, G, T, hd, MB, offs, window = WORK_COUNT_CASES[case]
     BS, B = 16, len(offs)
@@ -689,17 +692,185 @@ def test_work_counts_equal_the_devices_item_count_at_the_new_plan(case):
         tables[b, :n] = rng.integers(1, 5000, n)
     if B > 1:
         tables[1] = 0
-    Th, Tp, bq = _tile_plan(Hkv, G, T, hd, BS, MB, 2, False)
+    Th, Tp, bq, _ = _tile_plan(Hkv, G, T, hd, BS, MB, 2, False)
     n_qblocks = _round_up(G * T, bq) // bq
     work, _ = _work_list(
         jnp.asarray(tables), jnp.asarray(offs, jnp.int32), jnp.int32(window),
         chunk=T, block_q=bq, n_qblocks=n_qblocks, tile_pages=Tp, block_size=BS)
     flags = np.asarray(work[2])
     groups = Hkv // Th
-    got = work_counts(tables, offs, window, heads=Hkv, group=G, chunk=T,
-                      head_dim=hd, block_size=BS, itemsize=2)
+    got = read_counts(tables, offs, window, heads=Hkv, group=G, chunk=T,
+                      head_dim=hd, block_size=BS, itemsize=2)[:2]
     assert got == (groups * int((flags & _WORK != 0).sum()), groups * len(flags))
     assert 0 < got[0] < got[1]
+
+
+# ------------------------- a run of adjacent pages is ONE copy (PR 53)
+#
+# Where a page is under 128 KB the read takes the pool as ONE operand and
+# starts its own copies: a copy group of R table entries whose pool blocks
+# are adjacent arrives as one copy, any other page by page, a null entry not
+# at all. The cases below are small (16 pages a row, 2-3 rows) and share six
+# compiled programs (a table's CONTENTS are an input).
+
+RUN_BS, RUN_MB, RUN_HD = 16, 16, 128
+# (rows' lengths, how each row's table is filled): the lengths leave null
+# entries inside the last live tile — mid-group (37 tokens = 3 pages), at a
+# group's edge (128 tokens = 8 pages) and none (256 = the whole table)
+RUN_TABLES = {
+    "all-runs": "run",
+    "none-descending-ids": "descending",
+    "mixed-a-run-broken-mid-group": "broken",
+    "null-tail-inside-a-live-tile": "short",
+    "a-run-that-starts-off-alignment": "offset",
+}
+RUN_CHUNKS = {"decode": 1, "verify-k4": 5, "prefill-2-q-blocks": 64}
+
+
+def _run_tables(kind, lengths, rng):
+    """[B, RUN_MB] tables for rows of ``lengths`` tokens, and the pool size."""
+    tables = np.zeros((len(lengths), RUN_MB), np.int32)
+    nxt = 8  # all-runs: every row's run starts on a multiple of the group
+    for b, n in enumerate(lengths):
+        need = -(-n // RUN_BS)
+        if kind == "offset":
+            # three blocks from elsewhere, then a run from pool block 8k+3 on:
+            # off the group in the table AND in the pool
+            lead = min(3, need)
+            ids = np.r_[nxt + 40 - np.arange(lead), nxt + 3 + np.arange(need - lead)]
+        else:
+            ids = nxt + np.arange(need)
+        if kind == "descending":
+            ids = ids[::-1]
+        if kind == "broken" and need > 6:
+            ids[[5, 6]] = ids[[6, 5]]  # entries 5, 6 swapped inside group 0
+        tables[b, :need] = ids
+        nxt += 48
+    return tables, nxt + 48
+
+
+@functools.lru_cache(maxsize=None)
+def _run_read(T, budget):  # (the budget is read at trace time: a jit each)
+    return jax.jit(lambda q, kv, tables, off, win: ragged_paged_attention(
+        q, kv, tables, off, window=win))
+
+
+# st's heads with the copy group the chip chose (the whole 16-page tile), h1's
+# with a budget that cuts a tile into four groups (the general path)
+@pytest.mark.parametrize("heads", [(28, 4, None), (20, 4, 256 * 1024)],
+                         ids=["gqa-28-4-a-tile-a-group", "gqa-20-4-four-groups"])
+@pytest.mark.parametrize("window", [0, 48], ids=["no-window", "window-binds"])
+@pytest.mark.parametrize("chunk", sorted(RUN_CHUNKS))
+@pytest.mark.parametrize("tables", sorted(RUN_TABLES))
+def test_run_copies_match_dense_and_the_page_by_page_side_bit_for_bit(
+        tables, chunk, window, heads, monkeypatch):
+    """The run path under interpret mode at st's and h1's heads x 128: equal
+    to the dense reference over the gathered view, and BIT-equal to the same
+    call on a permuted copy of the pool and tables (every group broken: the
+    same items in the same order, copied page by page)."""
+    from bee2bee_tpu.ops import ragged
+    from bee2bee_tpu.ops.ragged import _tile_plan
+
+    H, Hkv, budget = heads
+    if budget:
+        monkeypatch.setattr(ragged, "_RUN_BYTES", budget)
+    T = RUN_CHUNKS[chunk]
+    kind = RUN_TABLES[tables]
+    lengths = [256, 37 + T, 128 + T] if kind == "short" else [256, 200 + T, 120 + T]
+    lengths = [min(n, RUN_MB * RUN_BS) for n in lengths]
+    rng = np.random.default_rng(len(tables) + T)
+    tb, NB = _run_tables(kind, lengths, rng)
+    Th, Tp, bq, R = _tile_plan(Hkv, H // Hkv, T, RUN_HD, RUN_BS, RUN_MB, 4, False)
+    assert (R, Tp // R) == ((4, 4) if budget else (16, 1))
+    assert T < 64 or H // Hkv * T > bq  # the prefill chunk: several q blocks
+    kv = jnp.asarray(rng.standard_normal((NB, 2, Hkv, RUN_BS, RUN_HD)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((len(lengths), T, H, RUN_HD)), jnp.float32)
+    off = np.asarray(lengths, np.int32) - T
+    args = (jnp.asarray(off), jnp.int32(window))
+    got = _run_read(T, budget)(q, kv, jnp.asarray(tb), *args)
+    kg, vg = _gathered(kv, tb)
+    qpos = (off[:, None] + np.arange(T)[None, :])[:, :, None]
+    kvpos = np.arange(RUN_MB * RUN_BS)[None, None, :]
+    mask = kvpos <= qpos
+    if window:
+        mask &= kvpos > qpos - window
+    cfg = replace(CFG, attn_scale=RUN_HD)  # the scores scaled by the head size
+    want = _dense_ref(q, kg, vg, jnp.asarray(mask), cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+    perm = np.r_[0, 1 + rng.permutation(NB - 1)].astype(np.int32)
+    kv_perm = kv[np.argsort(perm)]  # block perm[i] holds what block i held
+    permuted = _run_read(T, budget)(q, kv_perm, jnp.asarray(perm[tb]), *args)
+    assert np.array_equal(np.asarray(got), np.asarray(permuted))
+
+
+@pytest.mark.parametrize("tables", sorted(RUN_TABLES))
+def test_host_page_counts_equal_the_work_lists_run_bits(tables, monkeypatch):
+    """ops/ragged.read_counts' pages in a run copy / copied one by one (the
+    host's numpy, for engine.kv_pages_read) against the bits the call's own
+    work list marks on the same tables: R pages a marked group of a step
+    with a work item, the rest of its non-null entries single."""
+    from bee2bee_tpu.ops.ragged import (
+        _WORK, _round_up, _tile_plan, _work_list, read_counts)
+
+    from bee2bee_tpu.ops import ragged
+
+    H, Hkv, T = 28, 4, 1
+    kind = RUN_TABLES[tables]
+    lengths = [256, 38, 129] if kind == "short" else [256, 201, 121]
+    tb, _ = _run_tables(kind, lengths, np.random.default_rng(0))
+    off = np.asarray(lengths, np.int32) - T
+    for window, budget in ((0, None), (48, None), (0, 128 * 1024), (48, 128 * 1024)):
+        if budget:  # four groups a tile
+            monkeypatch.setattr(ragged, "_RUN_BYTES", budget)
+        Th, Tp, bq, R = _tile_plan(Hkv, H // Hkv, T, RUN_HD, RUN_BS, RUN_MB, 2, False)
+        assert (R, Tp // R) == ((4, 4) if budget else (16, 1))
+        work, _ = _work_list(
+            jnp.asarray(tb), jnp.asarray(off), jnp.int32(window), chunk=T,
+            block_q=bq, n_qblocks=_round_up(H // Hkv * T, bq) // bq,
+            tile_pages=Tp, block_size=RUN_BS, run_pages=R)
+        _, _, flags, pages, runs = (np.asarray(x) for x in work)
+        live = flags & _WORK != 0
+        marked = (runs[live, None] >> np.arange(Tp // R) & 1).sum()
+        mapped = (pages.reshape(-1, Tp)[live] != 0).sum()
+        got = read_counts(tb, off, window, heads=Hkv, group=H // Hkv, chunk=T,
+                          head_dim=RUN_HD, block_size=RUN_BS, itemsize=2)
+        assert got[2:] == (R * marked, mapped - R * marked)
+        want_runs = {"run": True, "descending": False}.get(kind)
+        if want_runs is not None and not window:
+            assert (got[2] > 0) == want_runs
+    assert got[2] + got[3] > 0
+
+
+# (heads a shard holds, group, head size, quantized, latent) -> R at 16-token
+# bf16 pages and a wide table: what decides is the page's BYTES, never a name
+RUN_GROUPS = {
+    "st-gqa-28-4-32KB": ((4, 7, 128, False, False), 32),
+    "h1-gqa-20-4-32KB": ((4, 5, 128, False, False), 32),
+    "granite-8-kv-heads-64KB": ((8, 4, 128, False, False), 16),
+    "mistral-shard-2-kv-heads-16KB": ((2, 4, 128, False, False), 32),
+    "phi3-mha-32-lane-aligned-256KB": ((32, 1, 128, False, False), 1),
+    "ouro-mha-16-128KB": ((16, 1, 128, False, False), 1),
+    "phi3-head-96-off-the-lanes": ((32, 1, 96, False, False), 1),
+    "gpt2-head-64-off-the-lanes": ((12, 1, 64, False, False), 1),
+    "int8-pool": ((4, 7, 128, True, False), 1),
+    "joyai-latent-rows": ((1, 32, 640, False, True), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RUN_GROUPS))
+def test_copy_group_follows_the_pages_bytes(case):
+    from bee2bee_tpu.ops.ragged import _RUN_BYTES, _tile_plan
+
+    (Hkv, G, hd, quantized, latent), want = RUN_GROUPS[case]
+    for T in (1, 2048):
+        Th, Tp, _, R = _tile_plan(Hkv, G, T, hd, 16, 1024, 2, quantized,
+                                  latent=latent)
+        if T == 1:
+            assert R == want
+        assert R == 1 or (Th == Hkv and 2 * Th * 16 * hd * 2 * R <= _RUN_BYTES)
+        assert R in (1, Tp)  # what the chip chose: the whole tile, or a page
+        assert want > 1 or R == 1
+
 
 
 # ------------------------------------- the pool written and read in place

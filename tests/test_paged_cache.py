@@ -75,6 +75,100 @@ def test_paged_prefix_cache_pins_and_evicts():
     assert a.free_count == 7 and len(pc) == 0
 
 
+def _n_runs(ids) -> int:
+    """Maximal runs of consecutive ascending ids in ``ids``."""
+    return 1 + sum(b != a + 1 for a, b in zip(ids, ids[1:])) if ids else 0
+
+
+def _check_free_runs(a: BlockAllocator):
+    """The free intervals are sorted, disjoint, MERGED (no two touch) and are
+    exactly the blocks no one references."""
+    runs = a.free_runs()
+    assert all(s < e for s, e in runs)
+    assert all(e0 < s1 for (_, e0), (s1, _) in zip(runs, runs[1:]))
+    free = [b for s, e in runs for b in range(s, e)]
+    assert free == [b for b in range(1, a.num_blocks) if a.refcount(b) == 0]
+    assert a.free_count == len(free) == a.num_blocks - 1 - a.used_count
+    assert a.refcount(0) == 0 and (not runs or runs[0][0] >= 1)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_allocator_after_churn_hands_out_ascending_runs(seed):
+    """Seeded alloc / growth / release / CoW-style ref + deref / prefix pins
+    in random order: ``alloc(n)`` returns ascending ids in the FEWEST runs
+    the free intervals allow, growth takes ``last + 1`` whenever it is free,
+    intervals merge at ``deref``, and every refcount ends at zero."""
+    rng = np.random.default_rng(seed)
+    a = BlockAllocator(160)
+    pc = PagedPrefixCache(3, a)
+    rows: list[list[int]] = []
+    for step in range(400):
+        op = rng.choice(["alloc", "alloc", "grow", "grow", "release", "pin", "cow"])
+        before = a.free_runs()
+        sizes = sorted((e - s for s, e in before), reverse=True)
+        if op == "alloc":
+            n = int(rng.integers(1, 48))
+            got = a.alloc(n)
+            if n > sum(sizes):
+                assert got is None and a.free_runs() == before
+                continue
+            assert got == sorted(set(got)) and len(got) == n
+            assert all(a.refcount(b) == 1 for b in got)
+            assert all(any(s <= b < e for s, e in before) for b in got)
+            fewest = next(k for k in range(1, len(sizes) + 1) if sum(sizes[:k]) >= n)
+            assert _n_runs(got) == fewest, (got, before)
+            rows.append(got)
+        elif op == "grow" and rows:
+            row = rows[int(rng.integers(len(rows)))]
+            n = int(rng.integers(1, 4))
+            last = row[-1]
+            behind = next((e - last - 1 for s, e in before if s == last + 1), 0)
+            got = a.alloc(n, after=last)
+            if got is None:
+                assert n > sum(sizes)
+                continue
+            take = min(n, behind)
+            assert got[:take] == list(range(last + 1, last + 1 + take))
+            assert got[take:] == sorted(got[take:]) and len(set(got)) == n
+            row.extend(got)
+        elif op == "release" and rows:
+            row = rows.pop(int(rng.integers(len(rows))))
+            freed = a.deref(row)
+            assert freed == sum(a.refcount(b) == 0 for b in row)
+        elif op == "pin" and rows:
+            row = rows[int(rng.integers(len(rows)))]
+            pc.put([seed, step], row[: int(rng.integers(1, len(row) + 1))])
+        elif op == "cow" and rows:
+            row = rows[int(rng.integers(len(rows)))]
+            a.ref([row[-1]])  # the donor's partial block, held across the copy
+            target = a.alloc(1)
+            a.deref([row[-1]])
+            if target is not None:
+                rows.append(target)
+        _check_free_runs(a)
+    for row in rows:
+        a.deref(row)
+    pc.clear()
+    _check_free_runs(a)
+    assert a.free_runs() == [(1, 160)] and a.used_count == 0 and not a._refs.any()
+
+
+def test_allocator_prefers_one_interval_then_the_largest():
+    a = BlockAllocator(64)
+    assert a.alloc(5) == [1, 2, 3, 4, 5]  # a fresh pool: low ids first
+    rows = [a.alloc(n) for n in (4, 10, 3, 20, 6)]  # 6..9, 10..19, 20..22, 23..42, 43..48
+    a.deref(rows[0]), a.deref(rows[2]), a.deref(rows[4])
+    assert a.free_runs() == [(6, 10), (20, 23), (43, 64)]
+    assert a.alloc(3) == [20, 21, 22]  # the smallest interval that holds it
+    assert a.alloc(4) == [6, 7, 8, 9]
+    a.deref([6, 7, 8, 9]), a.deref(rows[1])  # 6..19 merge into one interval
+    assert a.free_runs() == [(6, 20), (43, 64)]
+    # none holds 30: the largest whole, the rest from the smallest that holds it
+    got = a.alloc(30)
+    assert got == list(range(6, 15)) + list(range(43, 64))
+    assert a.alloc(1, after=14) == [15] and a.alloc(2, after=1) == [16, 17]
+
+
 def test_pow2_and_ceil_helpers():
     assert [pow2_at_least(n) for n in (1, 2, 3, 5, 8, 9)] == [1, 2, 4, 8, 8, 16]
     assert ceil_div(7, 4) == 2 and ceil_div(8, 4) == 2

@@ -411,3 +411,45 @@ def test_export_import_keeps_the_head_major_wire_format(cache_dtype):
         _assert_all_free(dst)
     finally:
         eng.close()
+
+
+def test_growth_goes_on_behind_the_rows_last_block_and_pages_read_are_counted():
+    """A prompt's blocks are one ascending run; decode growth takes the
+    block behind the row's last while it is free, any other once a neighbour
+    holds it; and count_tiles books engine.kv_pages_read by the read's own
+    host arithmetic (ops/ragged.read_counts) on the dispatched tables."""
+    from bee2bee_tpu.metrics import get_registry
+    from bee2bee_tpu.ops.ragged import read_counts
+
+    eng, rc = _cache(attention="flash")
+    try:
+        rc.cover(0, 3 * BS)
+        rc.cover(1, 2 * BS)
+        first, second = list(rc.row_blocks[0]), list(rc.row_blocks[1])
+        assert first == [first[0], first[0] + 1, first[0] + 2]
+        assert second == [first[-1] + 1, first[-1] + 2]
+        rc.cover(1, 3 * BS)  # behind row 1's last: free
+        assert rc.row_blocks[1] == second + [second[-1] + 1]
+        rc.cover(0, 4 * BS)  # behind row 0's last sits row 1: any other
+        assert rc.row_blocks[0][-1] not in first + rc.row_blocks[1]
+        rc.release(1)
+        rc.cover(0, 6 * BS)  # the freed blocks merged: a run of two
+        assert rc.row_blocks[0][-1] == rc.row_blocks[0][-2] + 1
+
+        pages = get_registry().counter("engine.kv_pages_read")
+        before = [pages.value(kind=k) for k in ("in_run", "single")]
+        tables, mapped = rc.window_table([0], 2)
+        offsets = np.asarray([6 * BS - 1, 0], np.int32)
+        rc.count_tiles(tables, offsets, 1, calls=3)
+        cfg = eng.model_cfg
+        want = read_counts(
+            tables, offsets, 0, heads=cfg.n_kv_heads,
+            group=cfg.n_heads // cfg.n_kv_heads, chunk=1, head_dim=cfg.head_dim,
+            block_size=BS, itemsize=eng.dtype.itemsize)
+        got = [pages.value(kind=k) - b for k, b in zip(("in_run", "single"), before)]
+        assert got == [3 * want[2], 3 * want[3]]
+        assert sum(got) == 3 * mapped  # every mapped page of the one live row
+        rc.release(0)
+        _assert_all_free(rc)
+    finally:
+        eng.close()

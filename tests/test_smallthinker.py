@@ -25,7 +25,7 @@ from bee2bee_tpu.metrics import get_registry
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import config_from_hf, get_config
 from bee2bee_tpu.ops.grouped import grouped_matmul
-from bee2bee_tpu.ops.ragged import make_ragged_attn_fn, work_counts
+from bee2bee_tpu.ops.ragged import make_ragged_attn_fn, read_counts
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "benchmark"))
@@ -449,7 +449,7 @@ def test_rows_served_together_equal_their_solo_runs(solo, over):
 
 def test_tiles_are_counted_a_layer_kind_and_the_window_gauges_follow_the_rows():
     """RowCache.count_tiles counts the MEAN layer's read: one full layer and
-    three behind the window of 24, each by ops/ragged.work_counts with its own
+    three behind the window of 24, each by ops/ragged.read_counts with its own
     window (the counter took window 0 for every alternating model before).
     note_tokens_held sets the two gauges from the live rows' contexts."""
     eng = InferenceEngine("tiny-smallthinker", engine_config=EngineConfig(
@@ -459,8 +459,8 @@ def test_tiles_are_counted_a_layer_kind_and_the_window_gauges_follow_the_rows():
     tables[0, :52], tables[1, :5] = np.arange(1, 53), np.arange(53, 58)
     offsets = np.asarray([410, 33], np.int32)
     kw = dict(heads=CFG.n_kv_heads, group=2, chunk=1, head_dim=16, block_size=8, itemsize=4)
-    full = work_counts(tables, offsets, 0, **kw)
-    bound = work_counts(tables, offsets, 24, **kw)
+    full = read_counts(tables, offsets, 0, **kw)[:2]
+    bound = read_counts(tables, offsets, 24, **kw)[:2]
     assert bound[0] < full[0] and bound[1] == full[1]
     tiles = get_registry().counter("engine.kv_tiles")
     live0, step0 = tiles.value(kind="live"), tiles.value(kind="stepped")

@@ -200,9 +200,13 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize(
-    "shape", [(1, 1, 1, 4), (2, 1, 1, 2)], ids=["model-4", "data-2-model-2"])
-def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo, shape):
+@pytest.mark.parametrize("model,shape", [
+    ("zephyr-7b", (1, 1, 1, 4)), ("zephyr-7b", (2, 1, 1, 2)),
+    # PR 53: one KV head of four a shard, a page of 8 KB: the kernel's own
+    # copies (the pool ONE operand of each shard's call), a tile a run
+    ("smallthinker-21b-a3b-8l", (1, 1, 1, 4)), ("falcon-h1-34b", (1, 1, 1, 4)),
+], ids=["model-4", "data-2-model-2", "smallthinker-model-4", "falcon-h1-model-4"])
+def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo, model, shape):
     """Tensor-parallel serving: the attn_fn the engine builds for a
     model:4 mesh runs the kernel per shard (q heads and the pool's kv
     heads over `model`: zephyr-7b's 8 kv heads are 2 a chip); on 2x2 the
@@ -210,7 +214,7 @@ def test_ragged_kernel_under_shard_map_compiles_for_four_chips(topo, shape):
     its own rows. The mesh is the four DESCRIBED devices, so interpret mode
     resolves off from the mesh itself — the same rule the engine follows
     on the chip."""
-    cfg = get_config("zephyr-7b")
+    cfg = get_config(model)
     mesh = Mesh(np.array(topo.devices, dtype=object).reshape(shape), AXES)
     attn = make_ragged_attn_fn(mesh)
 
