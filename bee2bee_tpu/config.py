@@ -100,6 +100,11 @@ class NodeConfig:
     # draft-role node this names the model the DraftServer hosts.
     # Requires spec_tokens > 0 (EngineConfig.drafter)
     drafter: str = ""
+    # a speculating row's acceptance floor (EngineConfig.spec_min_accept:
+    # under it, after the probe's tokens, the row leaves its tier). None =
+    # the engine's default; config.json only (no flag, no environment name):
+    # a node whose every decode step IS a verify states 0
+    spec_min_accept: float | None = None
     # batched multi-LoRA serving (adapters/): comma-separated
     # name=path.npz adapters preloaded into the engine's hot-swap pool
     # AND published as pieces manifests on the DHT (BEE2BEE_ADAPTERS /
@@ -144,6 +149,8 @@ class NodeConfig:
             kv_pool_blocks=self.kv_pool_blocks or None,
             spec_tokens=self.spec_tokens,
             drafter=self.drafter,
+            **({} if self.spec_min_accept is None
+               else {"spec_min_accept": float(self.spec_min_accept)}),
             # --adapters implies a pool even when no slot count was set:
             # the operator clearly wants multi-adapter serving
             max_adapters=self.max_adapters or (8 if self.adapters else 0),

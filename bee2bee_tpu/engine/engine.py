@@ -277,10 +277,17 @@ class InferenceEngine:
         # what this kind of model cannot run yet (models/support.py)
         support.require(
             self.model_cfg,
-            *self._features_in_use(self.engine_cfg, self.mesh, self.max_seq_len),
+            *self._features_in_use(self.engine_cfg, self.mesh,
+                                   self.max_seq_len, self.model_cfg),
             prefill_chunk=self.engine_cfg.prefill_chunk,
             max_seq_len=self.max_seq_len,
         )
+        if self.model_cfg.mtp_layers and self.engine_cfg.spec_tokens > 1:
+            raise ValueError(
+                f"spec_tokens={self.engine_cfg.spec_tokens}: {self.model_cfg.name!r} "
+                f"drafts with its {self.model_cfg.mtp_layers} multi-token-"
+                "prediction layer, one token a step (spec_tokens 1, or 0 = off)"
+            )
         if self.engine_cfg.attention == "auto":
             # replace, don't mutate: the caller may share one EngineConfig
             # across engines on different backends/meshes
@@ -494,7 +501,8 @@ class InferenceEngine:
     @staticmethod
     def _prefill_key(params, tokens, cache, true_len, offset,
                      block_tables=None, write_floor=None, write_ceil=None,
-                     adapters=None, aids=None, ascales=None, state=None):
+                     adapters=None, aids=None, ascales=None, state=None,
+                     mtp_next=None):
         """Sentinel shape key for the prefill root: the dims that select
         a compiled variant — batch rows, the padded token width (the
         bucket), the block-table width bucket, and the None-flags of the
@@ -505,7 +513,7 @@ class InferenceEngine:
             None if block_tables is None else int(block_tables.shape[1]),
             write_floor is not None, write_ceil is not None,
             adapters is not None, state is not None,
-        )
+        ) + ((True,) if mtp_next is not None else ())
 
     @staticmethod
     def _spec_verify_key(params, cur, drafts, draft_lens, cache, offsets,
@@ -666,16 +674,21 @@ class InferenceEngine:
             validate_sp_mesh(self.model_cfg, self.engine_cfg, self.mesh)
 
     @staticmethod
-    def _features_in_use(ec: EngineConfig, mesh, max_seq_len: int) -> set[str]:
+    def _features_in_use(ec: EngineConfig, mesh, max_seq_len: int,
+                         cfg=None) -> set[str]:
         """The features a configuration turns on, by the names
         models/support.REFUSED knows them under: ONE condition a feature.
         (pipeline_stages, kv_export and the paths that walk the layers
-        themselves are asked about where they are built.)"""
+        themselves are asked about where they are built.) A model with a
+        multi-token-prediction layer (``cfg``) speculates with THAT
+        (``spec_mtp``, which no kind refuses), not with the n-gram floor."""
         axis = mesh.shape.get
+        own = bool(cfg is not None and cfg.mtp_layers)
         on = {
             "kv_int8": jnp.dtype(ec.cache_dtype) == jnp.int8,
             "weight_int8": ec.quantize == "int8",
-            "spec_ngram": ec.spec_tokens > 0,
+            "spec_ngram": ec.spec_tokens > 0 and not own,
+            "spec_mtp": ec.spec_tokens > 0 and own,
             "spec_model_drafter": bool(ec.drafter),
             "spec_mesh_drafter": ec.drafter == "mesh",
             "seq_attention": axis("seq", 1) > 1 or ec.attention == "sp",
@@ -689,6 +702,14 @@ class InferenceEngine:
                                   and max_seq_len % ec.prefill_chunk),
         }
         return {feature for feature, used in on.items() if used}
+
+    @property
+    def mtp_on(self) -> bool:
+        """Does this engine draft with the model's own multi-token-prediction
+        layer (the ``mtp`` tier)? Its prefill and verify programs then run
+        that layer behind the trunk."""
+        return bool(self.model_cfg.mtp_layers
+                    and self.engine_cfg.spec_tokens == 1)
 
     @property
     def state_info(self) -> dict | None:
@@ -721,7 +742,8 @@ class InferenceEngine:
     @prog_scope("prog.prefill")
     def _prefill_fn(self, params, tokens, cache, true_len, offset,
                     block_tables=None, write_floor=None, write_ceil=None,
-                    adapters=None, aids=None, ascales=None, state=None):
+                    adapters=None, aids=None, ascales=None, state=None,
+                    mtp_next=None):
         """tokens [B, Tb] padded; returns (cache, last_logits [B, V]). The
         rows are the requests of one admission group (scheduler._admit:
         B on PREFILL_GROUP_ROWS), each with its own `true_len`, `offset`,
@@ -746,7 +768,15 @@ class InferenceEngine:
         after the chunk is returned third, in a dict that also holds an
         expert model's ``moe_stats``. The bucket's padded tail
         leaves it untouched (``valid_len``), and only the last real
-        position's logits are computed."""
+        position's logits are computed.
+        ``mtp_next`` [B] (an engine on the ``mtp`` tier: mtp_on): the token
+        that follows the chunk's last real position where the PROMPT says it
+        (a chunk that is not the walk's last), else -1: the greedy token of
+        the last position's logits, which is a greedy row's first token.
+        The model's multi-token-prediction layer then runs over the chunk
+        behind the trunk (position t with h_t and token t+1: its K/V rows
+        for the prompt) and its greedy token at the last position, the
+        row's first DRAFT, rides the extras as ``mtp_draft`` [B]."""
         recurrent = self.model_cfg.has_ssm
         if recurrent and state is None:
             state = core.init_ssm_state(
@@ -757,11 +787,12 @@ class InferenceEngine:
         # latent-attention ones: at a 129,280-token vocabulary the full
         # [1, 512, V] logits are 0.27 GB; smallthinker's [1, 2048, 151,936]
         # would be 1.24 GB); phi-3's programs stay as they were
-        one_logit = recurrent or self.model_cfg.has_mla or moe
+        one_logit = (recurrent or self.model_cfg.has_mla or moe
+                     or mtp_next is not None)
         last = jnp.maximum(jnp.asarray(true_len, jnp.int32) - 1, 0)  # dead row: 0
         if moe:  # the forward's expert-layer counters: extras["moe_stats"]
             cache = dict(cache, moe_stats=jnp.zeros((stats,), jnp.int32))
-        logits, cache = core.forward(
+        logits, cache, *hidden = core.forward(
             params, self.model_cfg, tokens,
             dict(cache, **state) if recurrent else cache, offset,
             attn_fn=self._attn_fn(), block_tables=block_tables,
@@ -769,11 +800,30 @@ class InferenceEngine:
             adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
             valid_len=true_len if recurrent else None,
             last_index=last if one_logit else None,
+            **({"return_hidden": True} if mtp_next is not None else {}),
         )
+        draft = None
+        if mtp_next is not None:
+            # the tokens that follow the chunk's positions: the prompt's own,
+            # and at the last real one the prompt's next or the greedy token
+            rows = jnp.arange(tokens.shape[0])
+            follow = jnp.where(mtp_next >= 0, mtp_next,
+                               jnp.argmax(logits[:, 0, :], axis=-1))
+            nxt = jnp.roll(tokens, -1, axis=1).at[rows, last].set(
+                follow.astype(tokens.dtype))
+            mtp_logits, cache = core.mtp_forward(
+                params, self.model_cfg, hidden[0], nxt, cache, offset,
+                attn_fn=self._attn_fn(), block_tables=block_tables,
+                paged_write_floor=write_floor, paged_write_ceil=write_ceil,
+                last_index=last,
+            )
+            draft = jnp.argmax(mtp_logits[:, 0, :], axis=-1).astype(jnp.int32)
         # what the chunk hands back beside the pool, by NAME: the row's
         # recurrent state and / or the expert layers' counters
         extras = {k: cache.pop(k) for k in
                   tuple(state or ()) + (("moe_stats",) if moe else ())}
+        if draft is not None:
+            extras["mtp_draft"] = draft
         if not one_logit:
             idx = last.reshape(-1, 1, 1)  # [B,1,1]
             logits = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
@@ -806,29 +856,68 @@ class InferenceEngine:
         position 0. Rejected positions hold stale K/V but sit at/past
         the row's new offset (offset + accepted + 1), where the causal
         invariant masks or overwrites them — rollback costs nothing.
+
+        An engine on the ``mtp`` tier (mtp_on) runs the model's multi-token-
+        prediction layer in the SAME program, behind the verdict: over the
+        chunk's positions with the tokens that follow them (the accepted
+        drafts, then the token just chosen), so its K/V rows of every
+        accepted position are final and a rejected position's is rewritten
+        when that position comes round again (the trunk's own invariant);
+        its greedy token at the last ACCEPTED position is the next step's
+        draft. A dict {``mtp_draft`` [B], ``moe_stats``} is then returned
+        fourth, before the counts: no draft dispatch, no hidden state on
+        the host.
         """
         from .sampling import sample_batched
 
         B, K = drafts.shape
+        own = self.mtp_on
         tokens = jnp.concatenate([cur[:, None], drafts], axis=1)  # [B, K+1]
-        logits, cache = core.forward(
-            params, self.model_cfg, tokens, cache, offsets,
-            attn_fn=self._attn_fn(), block_tables=tables,
-            adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
-        )
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K+1]
-        pos = jnp.arange(K, dtype=jnp.int32)[None, :]
-        match = (drafts == greedy[:, :-1]) & (pos < draft_lens[:, None])
-        # longest all-match prefix: cumprod zeroes everything after the
-        # first mismatch, the sum counts the survivors
-        accepted = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
-        idx = accepted.reshape(-1, 1, 1)  # [B,1,1]
-        last = jnp.take_along_axis(
-            logits, jnp.broadcast_to(idx, (B, 1, logits.shape[2])), axis=1
-        )[:, 0, :]
+        if own and self.model_cfg.moe_dropless:
+            cache = dict(cache, moe_stats=jnp.zeros(
+                (len(core.moe_stats_names(self.model_cfg)),), jnp.int32))
+        with core._scope_if(own)("spec.verify"):
+            logits, cache, *hidden = core.forward(
+                params, self.model_cfg, tokens, cache, offsets,
+                attn_fn=self._attn_fn(), block_tables=tables,
+                adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
+                **({"return_hidden": True} if own else {}),
+            )
+        with core._scope_if(own)("spec.accept"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K+1]
+            pos = jnp.arange(K, dtype=jnp.int32)[None, :]
+            match = (drafts == greedy[:, :-1]) & (pos < draft_lens[:, None])
+            # longest all-match prefix: cumprod zeroes everything after the
+            # first mismatch, the sum counts the survivors
+            accepted = jnp.sum(
+                jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
+            # (a select over K + 1: no element-wise gather a step)
+            last = core.take_position(logits, accepted)[:, 0, :]
+
+        def mtp_extras(nxt, cache):
+            """The MTP layer behind the verdict -> ({mtp_draft, moe_stats},
+            cache): position j of the chunk with the token that follows it."""
+            follow = jnp.where(
+                jnp.arange(K + 1, dtype=jnp.int32)[None, :] < accepted[:, None],
+                jnp.concatenate([drafts, nxt[:, None]], axis=1), nxt[:, None])
+            mtp_logits, cache = core.mtp_forward(
+                params, self.model_cfg, hidden[0], follow, cache, offsets,
+                attn_fn=self._attn_fn(), block_tables=tables,
+                last_index=accepted,
+            )
+            extras = {"mtp_draft": jnp.argmax(
+                mtp_logits[:, 0, :], axis=-1).astype(jnp.int32)}
+            if "moe_stats" in cache:
+                extras["moe_stats"] = cache.pop("moe_stats")
+            return extras, cache
+
         if counts is None:
-            nxt = sample_batched(last, key, temps, topks, topps, minps)
-            return nxt.astype(jnp.int32), cache, accepted
+            nxt = sample_batched(
+                last, key, temps, topks, topps, minps).astype(jnp.int32)
+            if own:
+                extras, cache = mtp_extras(nxt, cache)
+                return nxt, cache, accepted, extras
+            return nxt, cache, accepted
         # fused penalty bookkeeping (docs/PERF.md "Decode hot loop"): a
         # penalized row never drafts (scheduler._spec_eligible), so its
         # accepted is 0 and the draft bump below is a masked no-op for it;
@@ -840,6 +929,9 @@ class InferenceEngine:
                              counts, reps, press, freqs)
         nxt = nxt.astype(jnp.int32)
         counts = counts.at[jnp.arange(B), 1, nxt].add(1)
+        if own:
+            extras, cache = mtp_extras(nxt, cache)
+            return nxt, cache, accepted, extras, counts
         return nxt, cache, accepted, counts
 
     # ------------------------------------------------------------ helpers

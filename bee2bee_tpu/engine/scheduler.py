@@ -85,6 +85,7 @@ from .spec import (
     TIER_OFF,
     DrafterStack,
     MeshDrafter,
+    MtpDrafter,
     NgramDrafter,
     should_disable,
 )
@@ -161,6 +162,10 @@ _C_SPEC_DRAFTED = _REG.counter(
 )
 _C_SPEC_ACCEPTED = _REG.counter(
     "engine.spec_accepted", "speculative tokens accepted (tier label)"
+)
+_C_SPEC_STEPS = _REG.counter(
+    "engine.spec_steps",
+    "speculative verify steps: one [B, K+1] forward of every live row",
 )
 _C_SPEC_DEGRADED = _REG.counter(
     "engine.spec_mesh_degraded",
@@ -440,6 +445,10 @@ class Request:
         self.spec_tier_drafted = 0
         self.spec_tier_accepted = 0
         self.spec_tier_misses = 0
+        # the ``mtp`` tier's draft for this row, made on the device by the
+        # last prefill or verify program: (context length it was made at,
+        # token), spec.MtpDrafter hands it over
+        self.mtp_draft: tuple | None = None
 
     # ---- token accounting (runs on the scheduler thread) ----
 
@@ -695,6 +704,8 @@ class BatchScheduler:
         # an expert model's prefill counters (device arrays) waiting for the
         # next window's fetch
         self._moe_pending: list = []
+        # the ``mtp`` tier: the last prefill program's drafts [n], on the device
+        self._mtp_first = None
         # (cur, offsets) shardings of the decode root's outputs, captured
         # at the first dispatch. Ring-empty dispatches re-enter the chain
         # from the numpy host mirrors, which must be committed to these
@@ -751,7 +762,10 @@ class BatchScheduler:
                 # drafter is remote (--drafter mesh) — meshnet wires its
                 # transport via attach_drafter_transport. Per-row tier
                 # choice + probe-driven transitions live in _spec_drafts.
-                tiers = {
+                # a model with a multi-token-prediction layer drafts with
+                # THAT and nothing else (models/support.py refuses it the
+                # other tiers)
+                tiers = {"mtp": MtpDrafter()} if e.mtp_on else {
                     "ngram": NgramDrafter(
                         e.engine_cfg.spec_tokens,
                         e.engine_cfg.spec_min_match,
@@ -1460,20 +1474,31 @@ class BatchScheduler:
                     f"{offset.tolist()}, states hold {fed.tolist()} tokens"
                 )
             fed = offset + true_len
+            more = {}
+            if e.mtp_on:
+                # the token that follows a chunk in the PROMPT (-1: none, the
+                # walk's last chunk: the program takes its own greedy token)
+                more["mtp_next"] = np.asarray(
+                    [a.seq[a.windows[w] + bucket]
+                     if a.windows[w] + bucket < len(a.seq) else -1
+                     for a in group] + [-1] * dead, np.int32)
             out = e._prefill(
                 e.params, tokens, self.cache.pool, true_len, offset,
                 self.cache.rows_table(rows, bucket), floors, ceils,
                 **({"state": state} if state is not None
                    else self._lora_args_row(group[0].req) if group else {}),
+                **more,
             )
             self.cache.count_pages_written(n, bucket)
             self.cache.pool, last_logits, *extras = out
             extras = dict(extras[0]) if extras else {}
             real = int(true_len.sum())
             pad = n * bucket - real  # the buckets' tails and the dead rows
+            # (the walk's last chunk's is the rows' first draft: _first_tokens)
+            self._mtp_first = extras.pop("mtp_draft", None)
             if "moe_stats" in extras:  # an expert model's counters
                 self._moe_pending.append(extras.pop("moe_stats"))
-                self._count_moe(real, pad, 1)
+                self._count_moe(real, pad, 1, mtp=e.mtp_on)
             _C_PREFILL_CALLS.inc(bucket=str(bucket))
             _C_LOOP_PASSES.inc(e.model_cfg.loop_steps, kind="prefill")
             if group and len(group[0].windows) > 1:
@@ -1536,7 +1561,9 @@ class BatchScheduler:
                     np.asarray([req.presence_penalty], np.float32),
                     np.asarray([req.frequency_penalty], np.float32),
                 ]
-            return self._sample_first(*sample_args)
+            first = self._sample_first(*sample_args)
+            # (the ``mtp`` tier: the rows' first drafts ride the same fetch)
+            return (first, self._mtp_first) if e.mtp_on else first
 
     def warm_prefill(self):
         """Make every prefill program a burst can ask for resident, before
@@ -1580,7 +1607,8 @@ class BatchScheduler:
                 table = self.cache.rows_table([-1] * n, bucket)
                 return e._prefill.warm(
                     params, ints(n, bucket), pool, ints(n), ints(n),
-                    ints(*table.shape), ints(n), ints(n))
+                    ints(*table.shape), ints(n), ints(n),
+                    **({"mtp_next": ints(n)} if e.mtp_on else {}))
 
             with ThreadPoolExecutor(max_workers=4) as side_by_side:
                 loaded = list(side_by_side.map(resident, shapes))
@@ -1811,7 +1839,11 @@ class BatchScheduler:
         _H_BURST.observe(len(placed))
         now = time.perf_counter()
         for req, b, i, j in placed:
-            tok = int(toks[i][j])
+            draft = None
+            if self.engine.mtp_on:
+                tok, draft = int(toks[i][0][j]), int(toks[i][1][j])
+            else:
+                tok = int(toks[i][j])
             req.timing.t_first = now
             t = req.timing
             _H_QUEUE_WAIT.observe((t.t_admit - t.t_submit) * 1000.0)
@@ -1841,6 +1873,8 @@ class BatchScheduler:
                     self._counts, np.int32(b), np.int32(tok)
                 )
             self._cur[b] = tok
+            if draft is not None:  # made with the greedy first token
+                req.mtp_draft = (len(req.ids) + len(req.out_ids), draft)
             self._row_params_dirty = True
             self.stats.peak_active = max(self.stats.peak_active, self.active)
         # disaggregated prefill→decode: a prefill-designated node offers
@@ -2182,7 +2216,12 @@ class BatchScheduler:
                 if not d:
                     req.spec_misses += 1
                     req.spec_tier_misses += 1
-                    self._spec_tier_check(req)
+                    if tier == "mtp":
+                        # the row's context moved on without a verify step:
+                        # its MTP rows have a hole, the tier has nothing below
+                        self._spec_transition(req, tier)
+                    else:
+                        self._spec_tier_check(req)
                     continue
                 left = req.max_new_tokens - len(req.out_ids)
                 # past-budget draft positions are dead weight; a remote
@@ -2194,7 +2233,18 @@ class BatchScheduler:
                 lens[b] = len(d)
                 self._draft_tier[b] = tier
                 any_draft = True
-        return (drafts, lens) if any_draft else None
+        if any_draft:
+            return drafts, lens
+        # the ``mtp`` tier: a step in which no row drafts (every row of the
+        # tier at its last token: requests that end together) is a verify step
+        # all the same, the rows riding it for their one token: such a node's
+        # decode steps are ONE program a shape, and the decode window's is
+        # compiled only where sampled or penalised rows decode alone
+        if e.mtp_on and any(
+                r is not None and r.spec_tier == "mtp" and not r.cancelled
+                for r in self._rows):
+            return drafts, lens
+        return None
 
     def _spec_step(self) -> bool:
         """One speculative step: verify every drafting row's proposal in
@@ -2208,6 +2258,7 @@ class BatchScheduler:
             return False
         drafts, lens = proposal
         e = self.engine
+        K = e.engine_cfg.spec_tokens
         # cover the whole [offset, offset+K+1) write extent — blocks
         # claimed for later-rejected slots stay owned by the row
         # (over-allocated tail) and free normally at retirement
@@ -2246,21 +2297,34 @@ class BatchScheduler:
                     self._offsets, temps, topks, topps, minps,
                     e._next_key(), tables, **self._lora_args(), **pen_args,
                 )
+                # the ``mtp`` tier: the next step's drafts and the expert
+                # layers' counters come back with the verdict
+                own = dict(cnts.pop(0)) if e.mtp_on else {}
                 if pen:
                     (self._counts,) = cnts
                     self.stats.counts_windows += 1
+                moe = []
+                if "moe_stats" in own:  # the prefills' since, then this step's
+                    moe = self._moe_pending + [own.pop("moe_stats")]
+                    self._moe_pending = []
+                    self._count_moe(self.active * (K + 1),
+                                    (self._bsz - self.active) * (K + 1), 1,
+                                    mtp=True)
                 # a spec step is always a serialized sync: the drafter needs
                 # the verdict before it can propose again
                 _C_HOST_SYNCS.inc()
                 _C_SYNC_STALLS.inc()
                 _G_OVERLAP.set(0)
-                nxt, acc = (np.asarray(x) for x in jax.device_get((nxt_d, acc_d)))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
+                nxt, acc, own, moe = jax.device_get((nxt_d, acc_d, own, moe))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
+        if moe:
+            self._note_moe(moe, windows=1)
         _H_STEP.observe((time.perf_counter() - t_step) * 1000.0)
         self._t_fetched = None  # a verify step is no turn of the host
         self._last_dispatch_t = time.perf_counter()
         self._cur = nxt.astype(np.int32).copy()
         self._offsets = (self._offsets + acc + 1).astype(np.int32)
         self.stats.spec_steps += 1
+        _C_SPEC_STEPS.inc()
 
         retired_any = False
         live_rows = kept = 0  # the verify's [bsz, K+1] slots (engine.decode_slots)
@@ -2304,6 +2368,11 @@ class BatchScheduler:
                 if drafter is not None:
                     drafter.observe(req, a)
                 self._spec_tier_check(req)
+            if "mtp_draft" in own and not retired:
+                # made at the last accepted position with the token just
+                # chosen: the draft of the token after ``cur``
+                req.mtp_draft = (len(req.ids) + len(req.out_ids),
+                                 int(own["mtp_draft"][b]))
         self._meter.note_slots(self._bsz, live_rows,
                                e.engine_cfg.spec_tokens + 1, kept)
         # a spec step is serialized: nothing ran while its rows were delivered
@@ -2683,19 +2752,23 @@ class BatchScheduler:
         self._t_fetched = now
         return toks_host
 
-    def _count_moe(self, live: int, dead: int, forwards: int):
+    def _count_moe(self, live: int, dead: int, forwards: int,
+                   mtp: bool = False):
         """The host's half of the expert layer's counters for one dispatch
         of ``forwards`` forwards over ``live`` real and ``dead`` padded
         positions in all: assignments and layer calls follow from shapes
-        (what the device's live mask will do: core.forward's token_live)."""
+        (what the device's live mask will do: core.forward's token_live).
+        ``mtp``: the program ran the model's MTP layer behind the trunk, over
+        the same positions (a prefill or a verify step of the ``mtp`` tier)."""
         cfg = self.engine.model_cfg
         if not cfg.moe_dropless:
             return
-        per = cfg.n_experts_per_tok * cfg.n_expert_layers
+        layers = cfg.n_expert_calls if mtp else cfg.n_expert_layers
+        per = cfg.n_experts_per_tok * layers
         if not cfg.expert_share:  # a share's split is the device's to say
             _C_MOE_ASSIGNMENTS.inc(live * per, kind="live")
         _C_MOE_ASSIGNMENTS.inc(dead * per, kind="dead")
-        _C_MOE_LAYER_CALLS.inc(forwards * cfg.n_expert_layers)
+        _C_MOE_LAYER_CALLS.inc(forwards * layers)
 
     def _note_moe(self, stats: list, windows: int):
         """The device's half, fetched with a window's tokens: ``stats`` are

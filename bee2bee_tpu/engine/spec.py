@@ -27,6 +27,16 @@ decides when a row's current tier has failed its probe):
   timed-out one is a miss, and a dead peer flips ``dead`` so the
   scheduler demotes every mesh row to the local tier, typed.
 
+- ``mtp``: the target's OWN multi-token-prediction layer (a model that
+  ships one, ModelConfig.mtp_layers; ``MtpDrafter``). It is the only tier of
+  such a model and every greedy unpenalised row starts on it. Its draft for
+  step n+1 is a by-product of step n's verify program (the prefill's for a
+  row's first step): that program runs the MTP layer behind the verdict, at
+  the last ACCEPTED position with the token just chosen, and returns the
+  draft beside (next_tok, accepted); no draft dispatch, no hidden state on
+  the host. The tier has nothing below it: a row that fails its probe, or
+  that a plain decode window carried past its draft, goes to ``off``.
+
 Rows move between tiers instead of dying: when a tier fails its probe
 budget the row DEMOTES down the ladder (mesh → model → ngram → off) —
 or ESCALATES from ngram to a model-class tier when one is configured,
@@ -60,7 +70,7 @@ import numpy as np
 # escalation from ngram picks the best model-class tier present. "off"
 # is the terminal state when every configured tier has failed its probe
 # — it is a row state, not a drafter.
-TIER_LADDER = ("mesh", "model", "ngram")
+TIER_LADDER = ("mesh", "model", "ngram", "mtp")
 TIER_OFF = "off"
 
 
@@ -188,6 +198,27 @@ class NgramDrafter(Drafter):
 
     def propose_batch(self, rows):
         return {b: self.propose(req.ids, req.out_ids) for b, req in rows}
+
+
+class MtpDrafter(Drafter):
+    """Tier "mtp": the drafts are made on the device by the target's own
+    multi-token-prediction layer, inside the prefill and verify programs
+    (engine._prefill_fn / _spec_verify_fn, ``mtp_draft``); the scheduler
+    notes each on its Request as (context length it was made at, token), and
+    this object only hands it over. A row whose context moved on without a
+    verify step (a plain decode window ran: its MTP rows for those positions
+    were never written) has no draft any more: [] — the scheduler retires it
+    from the tier."""
+
+    tier = "mtp"
+    spec_tokens = 1
+
+    def propose_batch(self, rows):
+        out = {}
+        for b, req in rows:
+            at, tok = req.mtp_draft or (-1, 0)
+            out[b] = [tok] if at == len(req.ids) + len(req.out_ids) else []
+        return out
 
 
 class _MeshRow:

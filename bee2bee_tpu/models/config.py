@@ -196,6 +196,24 @@ class ModelConfig:
     d_ff_shared: int = 0
     # granite: BOTH residual adds of a block take their branch times this
     residual_multiplier: float = 1.0
+    # K-EXAONE (exaone_moe): a sigmoid router with NO selection bias (the
+    # top k of the scores themselves; seeded weights are evened by
+    # core.center_router, a rule on the weights, as a softmax-top-k router's).
+    # True = joyai's noaux_tc bias (``router_bias``)
+    moe_select_bias: bool = True
+    # multi-token-prediction layers behind the trunk (the published
+    # num_nextn_predict_layers; 0 = none, 1 = what is built): ``u = W_eh
+    # [RMS_e(Emb(x_{t+1})); RMS_h(h_t)]``, one block of the trunk's kind
+    # (full attention; NoPE where the full layers carry none; an expert layer
+    # where the trunk has them) over a cache layer of its OWN behind the
+    # trunk's (cache_layers counts it), then the trunk's final norm and head:
+    # the model's own drafter (core.mtp_forward, the engine's ``mtp`` tier)
+    mtp_layers: int = 0
+    # a chip that holds a SLICE of the vocabulary: vocab_size is then the
+    # rows HELD here (ids 0 .. vocab_size - 1 of the embedding and the head:
+    # logits and sampling are over the slice) and this the published size
+    # (0 = vocab_size is the whole vocabulary)
+    vocab_published: int = 0
 
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
@@ -305,6 +323,25 @@ class ModelConfig:
                     f"{self.n_experts} experts of a dropless expert layer "
                     "(moe_router 'sigmoid' / 'softmax_topk')"
                 )
+        if not self.moe_select_bias and self.moe_router != "sigmoid":
+            raise ValueError(
+                "moe_select_bias=False is a sigmoid router's switch (the "
+                f"other routers have no selection bias), got moe_router="
+                f"{self.moe_router!r}")
+        if self.mtp_layers not in (0, 1) or (self.mtp_layers and (
+                self.has_ssm or self.mla_kv_rank or self.loop_steps > 1
+                or self.layer_types or self.parallel_block
+                or self.pos_embedding not in ("rope", "nope"))):
+            raise ValueError(
+                f"mtp_layers={self.mtp_layers} must be 0 or 1, and a "
+                "multi-token-prediction layer is built for a plain stack of "
+                "attention layers with K/V pages: no recurrent mixer, latent "
+                "attention, looped stack, layer_types or parallel block")
+        if self.vocab_published and self.vocab_published < self.vocab_size:
+            raise ValueError(
+                f"vocab_published={self.vocab_published} is the WHOLE "
+                f"vocabulary of which vocab_size={self.vocab_size} rows are "
+                "held here")
         if self.d_ff_shared and not self.n_shared_experts:
             raise ValueError(
                 f"d_ff_shared={self.d_ff_shared} needs a shared expert "
@@ -378,7 +415,8 @@ class ModelConfig:
         attention layers cache anything (cache_slots)."""
         if self.layer_types:
             return self.layer_types.count("attention")
-        return self.n_layers * self.loop_steps
+        # (a multi-token-prediction block's K/V: the layer behind the trunk's)
+        return self.n_layers * self.loop_steps + self.mtp_layers
 
     @property
     def state_layers(self) -> int:
@@ -439,7 +477,8 @@ class ModelConfig:
             return (0,) * self.cache_layers
         return tuple(
             w if i % self.sliding_window_every in self.sliding_window_residues
-            else 0 for i in range(self.n_layers)) * self.loop_steps
+            else 0 for i in range(self.n_layers)) * self.loop_steps + (
+                (0,) * self.mtp_layers)  # an MTP block attends fully
 
     @property
     def is_moe(self) -> bool:
@@ -475,6 +514,12 @@ class ModelConfig:
     @property
     def n_expert_layers(self) -> int:
         return self.n_layers - self.first_k_dense if self.n_experts else 0
+
+    @property
+    def n_expert_calls(self) -> int:
+        """Expert-layer calls of ONE forward that runs everything the model
+        has: the trunk's expert layers and an MTP block's."""
+        return self.n_expert_layers + (self.mtp_layers if self.n_experts else 0)
 
     @property
     def has_ssm(self) -> bool:
@@ -1018,6 +1063,54 @@ CONFIGS["tiny-granite"] = ModelConfig(
 )
 
 
+_K_EXAONE_236B = dict(
+    # LGAI-EXAONE/K-EXAONE-236B-A23B config.json (model_type exaone_moe,
+    # 236B-A23B): GQA 64/8 x 128 with per-head RMSNorm on q and k; layers in
+    # periods of four (L L L G): three behind a 128-token window WITH RoPE
+    # (theta 1e6), one full WITHOUT positions; a block norms its branches'
+    # OUTPUTS, not their inputs (EXAONE 4.0's placement); layer 0 a dense
+    # SwiGLU MLP 18,432 wide, every other layer 128 SwiGLU experts 2,048 wide,
+    # top-8 by sigmoid score with no selection bias, weights normalised x 2.5,
+    # beside one shared expert; one multi-token-prediction layer; an untied
+    # head over 153,600 tokens
+    d_model=6144, n_heads=64, n_kv_heads=8, head_dim_override=128, d_ff=18432,
+    max_seq_len=262144, qk_norm=True, rope_theta=1000000.0, norm_eps=1e-5,
+    tie_embeddings=False, no_pre_norms=True, post_norms=True,
+    sliding_window=128, sliding_window_every=4,
+    sliding_window_residues=(0, 1, 2), rope_sliding_only=True,
+    n_experts=128, n_experts_per_tok=8, moe_router="sigmoid",
+    moe_select_bias=False, moe_scale=2.5, n_shared_experts=1,
+    d_ff_expert=2048, first_k_dense=1, mtp_layers=1,
+)
+CONFIGS["k-exaone-236b-a23b"] = ModelConfig(
+    # as published: 48 layers, every expert, the whole vocabulary (472 GB of
+    # bf16: it loads only where it fits)
+    name="k-exaone-236b-a23b", n_layers=48, vocab_size=153600,
+    **_K_EXAONE_236B)
+CONFIGS["k-exaone-236b-a23b-5l-e16"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/k-exaone-236b-a23b-
+    # 5l-e16.json): layer_types[:5] = L L L G L (layer 0 dense), every width,
+    # experts 0-15 of every layer's 128 and rows 0-19,199 of the embedding and
+    # the head: one chip's share of the eight that share each layer, the first
+    # stage's first chip with the final norm, the head and the MTP layer added
+    name="k-exaone-236b-a23b-5l-e16", n_layers=5, vocab_size=19200,
+    vocab_published=153600, n_experts_held=16, **_K_EXAONE_236B)
+CONFIGS["tiny-exaone"] = ModelConfig(
+    # every mechanism at CPU-test size: L(dense) L L G L + one MTP layer,
+    # window 8 (shorter than the test prompts), 16 experts top-4 of which 4
+    # are held from the 4th on, a shared expert, a sliced untied vocabulary
+    name="tiny-exaone", vocab_size=320, vocab_published=512, d_model=64,
+    n_layers=5, n_heads=4, n_kv_heads=2, head_dim_override=16, d_ff=96,
+    max_seq_len=256, qk_norm=True, rope_theta=10000.0, norm_eps=1e-5,
+    tie_embeddings=False, no_pre_norms=True, post_norms=True,
+    sliding_window=8, sliding_window_every=4,
+    sliding_window_residues=(0, 1, 2), rope_sliding_only=True,
+    n_experts=16, n_experts_per_tok=4, moe_router="sigmoid",
+    moe_select_bias=False, moe_scale=2.5, n_shared_experts=1, d_ff_expert=24,
+    first_k_dense=1, n_experts_held=4, expert_first=4, mtp_layers=1,
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -1426,6 +1519,103 @@ def _granite_hybrid_from_hf(d: dict, nm: str) -> ModelConfig:
     )
 
 
+def _exaone_moe_from_hf(d: dict, nm: str) -> ModelConfig:
+    """exaone_moe (LGAI-EXAONE/K-EXAONE-*): window layers with RoPE beside
+    full layers without positions (``layer_types`` / ``sliding_windows``, in
+    the period ``sliding_window_pattern`` spells), sigmoid-routed experts
+    beside a shared expert behind ``first_k_dense_replace`` dense layers, and
+    ``num_nextn_predict_layers`` multi-token-prediction layers. What core does
+    not build is refused BY NAME. ``num_experts_held`` / ``expert_first`` /
+    ``vocab_size_held`` (not published keys: a cut configuration's own) give
+    the chip's share of the experts and of the vocabulary's rows."""
+    L = d["num_hidden_layers"]
+    rope = d.get("rope_parameters") or {}
+    published = {  # key -> the one value the implementation covers
+        "scoring_func": "sigmoid", "n_group": 1, "topk_group": 1,
+        "norm_topk_prob": True, "hidden_act": "silu",
+        "mtp_layer_types": ["full_attention"], "mtp_sliding_windows": [0],
+    }
+    if not d.get("num_nextn_predict_layers"):
+        published.pop("mtp_layer_types"), published.pop("mtp_sliding_windows")
+    for key, want in published.items():
+        got = d.get(key, want)
+        if got != want:
+            raise ValueError(
+                f"exaone_moe config with {key}={got!r} is not implemented "
+                f"(only {key}={want!r}, the published setting)"
+            )
+    if rope.get("rope_type", "default") != "default" or d.get("rope_scaling"):
+        raise ValueError(
+            f"exaone_moe config with rope_parameters={rope!r} / rope_scaling="
+            f"{d.get('rope_scaling')!r} is not implemented (only rope_type="
+            "'default', no scaling)"
+        )
+    if d.get("num_nextn_predict_layers", 0) not in (0, 1):
+        raise ValueError(
+            "exaone_moe config with num_nextn_predict_layers="
+            f"{d['num_nextn_predict_layers']!r} is not implemented (0 or 1)"
+        )
+    pattern = str(d.get("sliding_window_pattern") or "")
+    window = d.get("sliding_window") or 0
+    types = list(d.get("layer_types") or ())
+    names = {"L": "sliding_attention", "G": "full_attention"}
+    if (not window or set(pattern) != {"L", "G"} or len(types) != L
+            or types != [names[pattern[i % len(pattern)]] for i in range(L)]
+            or list(d.get("sliding_windows") or [
+                window if t == "sliding_attention" else 0 for t in types
+            ]) != [window if t == "sliding_attention" else 0 for t in types]):
+        raise ValueError(
+            f"exaone_moe config with layer_types={types!r} / sliding_windows="
+            f"{d.get('sliding_windows')!r} is not implemented (the {L} layers "
+            f"must repeat sliding_window_pattern={pattern!r} of L and G, the "
+            f"L layers behind sliding_window={window!r})"
+        )
+    k_dense = d.get("first_k_dense_replace", 0)
+    mlps = list(d.get("mlp_layer_types")
+                or ["dense"] * k_dense + ["sparse"] * (L - k_dense))
+    if mlps != ["dense"] * k_dense + ["sparse"] * (L - k_dense):
+        raise ValueError(
+            f"exaone_moe config with mlp_layer_types={mlps!r} is not "
+            f"implemented (first_k_dense_replace={k_dense} dense layers, then "
+            "sparse ones)"
+        )
+    if not d.get("num_experts") or not d.get("num_shared_experts"):
+        raise ValueError(
+            f"exaone_moe config with num_experts={d.get('num_experts')!r} / "
+            f"num_shared_experts={d.get('num_shared_experts')!r} is not "
+            "implemented (every sparse layer routes experts beside a shared "
+            "expert)"
+        )
+    D, H = d["hidden_size"], d["num_attention_heads"]
+    hd = d.get("head_dim") or D // H
+    held = d.get("vocab_size_held") or 0
+    return ModelConfig(
+        name=nm, vocab_size=held or d["vocab_size"],
+        vocab_published=d["vocab_size"] if held else 0, d_model=D,
+        n_layers=L, n_heads=H, n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],
+        head_dim_override=None if hd * H == D else hd,
+        max_seq_len=d.get("max_position_embeddings", 262144),
+        qk_norm=True, rope_theta=float(rope.get("rope_theta", 1000000.0)),
+        norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        no_pre_norms=True, post_norms=True,
+        sliding_window=window, sliding_window_every=len(pattern),
+        sliding_window_residues=tuple(
+            i for i, c in enumerate(pattern) if c == "L"),
+        rope_sliding_only=True,
+        n_experts=d["num_experts"],
+        n_experts_per_tok=d["num_experts_per_tok"], moe_router="sigmoid",
+        moe_select_bias=False,
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=d["num_shared_experts"],
+        d_ff_expert=d["moe_intermediate_size"], first_k_dense=k_dense,
+        n_experts_held=d.get("num_experts_held") or 0,
+        expert_first=d.get("expert_first") or 0,
+        mtp_layers=d.get("num_nextn_predict_layers", 0),
+    )
+
+
 def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     """Synthesize a ModelConfig from an HF ``config.json`` dict — the
     any-checkpoint path: a checkpoint whose architecture is NOT in the
@@ -1727,6 +1917,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         return _ouro_from_hf(d, nm)
     if mt == "granitemoehybrid":
         return _granite_hybrid_from_hf(d, nm)
+    if mt == "exaone_moe":
+        return _exaone_moe_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -1895,7 +2087,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
-        f"falcon_h1/joyai_llm_flash/smallthinker/ouro/granitemoehybrid; "
+        f"falcon_h1/joyai_llm_flash/smallthinker/ouro/granitemoehybrid/"
+        f"exaone_moe; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

@@ -144,6 +144,10 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
         expert share (cfg.n_experts_held) the expert stacks are [L, held,
         ...], the router stays [L, D, E]; the shared expert is cfg.shared_ff
         wide
+      mtp/ (cfg.mtp_layers, K-EXAONE's multi-token-prediction layer): enorm /
+        hnorm {scale [D]}, eh_proj [2 D, D], block: ONE layer of the trunk's
+        schema stacked [1, ...] (an expert layer where the trunk has them);
+        the embedding, the final norm and the head are the trunk's
       dense_layers/ (cfg.first_k_dense > 0 only): the LEADING dense
         layers, the same schema with a dense mlp, stacked [k, ...];
         ``layers`` then holds the n_layers - k expert layers. Layers of
@@ -161,14 +165,21 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
         # A router with no bias gets the same even load by a rule on its
         # weights (center_router): no parameter is added
         name, rule = (("router_bias", balance_router_bias)
-                      if cfg.moe_router == "sigmoid"
+                      if cfg.moe_router == "sigmoid" and cfg.moe_select_bias
                       else ("router", center_router))
         moe = params["layers"]["moe"]
-        new = jax.jit(
-            rule, static_argnums=1,
-            out_shardings=(None if out_shardings is None
-                           else out_shardings["layers"]["moe"][name]),
-        )(params, cfg)
+        sharding = (None if out_shardings is None
+                    else out_shardings["layers"]["moe"][name])
+        if cfg.mtp_layers:  # (the trunk's routers, the MTP block's)
+            sharding = (None if out_shardings is None else (
+                sharding, out_shardings["mtp"]["block"]["moe"][name]))
+        new = jax.jit(rule, static_argnums=1, out_shardings=sharding)(
+            params, cfg)
+        if cfg.mtp_layers:
+            new, new_mtp = new
+            block = params["mtp"]["block"]
+            params = dict(params, mtp=dict(params["mtp"], block=dict(
+                block, moe=dict(block["moe"], **{name: new_mtp}))))
         params = dict(params, layers=dict(
             params["layers"], moe=dict(moe, **{name: new})))
     return params
@@ -186,7 +197,9 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
     # (center_router): at unit scale a seeded token's own part stays visible
     # behind thousands of context tokens (at 0.02 every row 6k deep held
     # nearly the same state and 32 rows hit 62 % of a layer's experts, PR 43)
-    embed_std = 1.0 if cfg.moe_router == "softmax_topk" else 0.02
+    # (a sigmoid router without a selection bias is evened the same way)
+    embed_std = 1.0 if (cfg.moe_router == "softmax_topk"
+                        or not cfg.moe_select_bias) else 0.02
     if cfg.tie_embeddings and cfg.moe_dropless:
         # a TIED head reads a token's own embedding back: behind the
         # embedding multiplier m the input's own logit stands 12 s sqrt(D) /
@@ -272,7 +285,7 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             }
             if gated:
                 moe["w_gate"] = dense((L, Eh, D, Fe))
-            if cfg.moe_router == "sigmoid":
+            if cfg.moe_router == "sigmoid" and cfg.moe_select_bias:
                 # float32 always; init_params sets it (balance_router_bias)
                 moe["router_bias"] = jnp.zeros((L, E), jnp.float32)
             if cfg.n_shared_experts:
@@ -335,6 +348,15 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         params["lm_head"] = dense((D, V))
         if cfg.lm_head_bias:  # phi: untied head carries a bias
             params["lm_head_bias"] = jnp.zeros((V,), dtype)
+    if cfg.mtp_layers:
+        # keys of its own: the trunk's weights are what they are without it
+        keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
+        params["mtp"] = {
+            "enorm": {"scale": jnp.ones((D,), dtype)},
+            "hnorm": {"scale": jnp.ones((D,), dtype)},
+            "eh_proj": dense((2 * D, D)),
+            "block": layer_group(cfg.mtp_layers, cfg.is_moe),
+        }
     return params
 
 
@@ -802,7 +824,10 @@ def _moe_router(xf, p, cfg: ModelConfig):
         z, topi = lax.top_k(_router_logits(xf, p), cfg.n_experts_per_tok)
         return topi.astype(jnp.int32), jax.nn.softmax(z, axis=-1) * cfg.moe_scale
     s = _router_scores(xf, p)
-    _, topi = lax.top_k(s + p["router_bias"], cfg.n_experts_per_tok)
+    # (no selection bias, cfg.moe_select_bias False: the top k of the scores)
+    _, topi = lax.top_k(
+        s + p["router_bias"] if "router_bias" in p else s,
+        cfg.n_experts_per_tok)
     w = jnp.take_along_axis(s, topi, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.moe_scale
     return topi.astype(jnp.int32), w
@@ -945,7 +970,12 @@ def center_router(params: Params, cfg: ModelConfig):
     the pre-attention norm's output (cfg.moe_router_input "attn_norm",
     smallthinker: computed here, ahead of the block) or the pre-FFN norm's
     ("ffn_norm", granite: known only behind the layer's mixer, so the block
-    centres the matrix where it reads it, _moe_dropless's ``router_fix``)."""
+    centres the matrix where it reads it, _moe_dropless's ``router_fix``).
+    A model's leading dense layers (cfg.first_k_dense) run first and route
+    nothing. With a multi-token-prediction layer (cfg.mtp_layers) the MTP
+    block's router is centred too, behind the centred trunk, on what
+    mtp_forward feeds it; returns (the trunk's [L, D, E], the MTP block's
+    [1, D, E])."""
     width = min(_CENTER_WIDTH, cfg.max_seq_len)
     # the combine holds rows x width x k float32 rows of d_model at once: no
     # more of them than smallthinker's batch (k = 6) makes, whatever the k
@@ -963,6 +993,9 @@ def center_router(params: Params, cfg: ModelConfig):
         return (w32 - jnp.outer(m, jnp.dot(m, w32, precision=_HI))
                 / jnp.dot(m, m)).astype(w.dtype)
 
+    def stack_index(i):  # a layer's place in the EXPERT layers' stack
+        return i - cfg.first_k_dense if cfg.first_k_dense else i
+
     def deep_mean(a):  # [R, T, D] -> [D] over the deeper half of every row
         return jnp.mean(a.astype(jnp.float32)[:, T // 2:].reshape(
             R * (T - T // 2), -1), axis=0)
@@ -974,21 +1007,28 @@ def center_router(params: Params, cfg: ModelConfig):
         x = transformer_block(
             dict(lp, moe=dict(lp["moe"], router=w)), cfg, x, positions,
             layer_mask(i), rope_local=layer_rope_flag(cfg, i),
-            moe_kw={"experts": stack, "layer": i})
+            moe_kw={"experts": stack, "layer": stack_index(i)})
         return x, w
 
-    def layer_behind_mixer(x, xs):
-        lp, i = xs
+    def centring():
+        """(_moe_dropless's ``router_fix`` that centres the matrix on the
+        router's own input where the block reads it, [the centred matrix])."""
         got = []
 
         def fix(rx, w):
             got.append(centred(w, deep_mean(rx.reshape(R, T, -1))))
             return got[0]
 
+        return fix, got
+
+    def layer_behind_mixer(x, xs):
+        lp, i = xs
+        fix, got = centring()
         x = transformer_block(
             lp, cfg, x, positions, layer_mask(i),
             rope_local=layer_rope_flag(cfg, i),
-            moe_kw={"experts": stack, "layer": i, "router_fix": fix})
+            moe_kw={"experts": stack, "layer": stack_index(i),
+                    "router_fix": fix})
         return x, got[0]
 
     x = embed_tokens(params, cfg, jnp.asarray(tokens), positions)
@@ -996,7 +1036,28 @@ def center_router(params: Params, cfg: ModelConfig):
     if not cfg.layer_types:
         body = (layer if cfg.moe_router_input == "attn_norm"
                 else layer_behind_mixer)
-        return lax.scan(body, x, (layers, jnp.arange(cfg.n_layers)))[1]
+        k_dense = cfg.first_k_dense
+        for i in range(k_dense):  # the leading dense layers route nothing
+            x = transformer_block(
+                jax.tree.map(lambda a: a[i], params["dense_layers"]), cfg,  # noqa: B023
+                x, positions, layer_mask(i), rope_local=layer_rope_flag(cfg, i))
+        x, routers = lax.scan(
+            body, x, (layers, jnp.arange(k_dense, cfg.n_layers)))
+        if not cfg.mtp_layers:
+            return routers
+        # the MTP block's router, behind the centred trunk: its input is
+        # what mtp_forward makes of the trunk's last hidden state and the
+        # NEXT token's embedding
+        fix, got = centring()
+        behind = cfg.n_layers  # (mtp_forward's block, without a cache)
+        transformer_block(
+            jax.tree.map(lambda a: a[0], params["mtp"]["block"]), cfg,
+            mtp_input(params, cfg, x, jnp.roll(jnp.asarray(tokens), -1, axis=1),
+                      positions),
+            positions, layer_mask(behind),
+            rope_local=layer_rope_flag(cfg, behind),
+            moe_kw={"router_fix": fix})
+        return routers, got[0][None]
     # one mixer kind a layer: a scan a run of like layers (core.forward)
     return jnp.concatenate(
         _scan_layer_runs(cfg, layers, layer_behind_mixer, x)[1], axis=0)
@@ -1692,7 +1753,10 @@ def is_sliding_layer(cfg: ModelConfig, global_idx):
     implementation of the local/global layer pattern (gemma-2: residue 0
     mod 2; gemma-3: residues 0..4 mod 6)."""
     res = jnp.asarray(cfg.sliding_window_residues, jnp.int32)
-    return jnp.any(res == (global_idx % cfg.sliding_window_every))
+    sliding = jnp.any(res == (global_idx % cfg.sliding_window_every))
+    if cfg.mtp_layers:  # the block BEHIND the trunk attends fully (cfg.layer_windows)
+        sliding = sliding & (global_idx < cfg.n_layers)
+    return sliding
 
 
 def make_layer_mask(cfg: ModelConfig, positions, T: int, S: int | None = None,
@@ -1744,6 +1808,7 @@ def forward(
     adapter_scales=None,  # [N] f32: per-slot alpha/rank scaling
     valid_len=None,  # [B] int32: real positions a row (rest = bucket pad)
     last_index=None,  # [B] int32: logits of THIS position only -> [B, 1, V]
+    return_hidden: bool = False,  # also return the final norm's INPUT [B, T, D]
 ):
     """Run a [B, T] token chunk. Returns (logits [B, T, V], new_cache).
 
@@ -1827,6 +1892,11 @@ def forward(
     output with no second norm. With loop_steps == 1 there is no pass loop
     at all: a plain stack's program is what it was.
 
+    **A multi-token-prediction layer** (cfg.mtp_layers, K-EXAONE): with
+    ``return_hidden`` the trunk's last hidden state (the final norm's input,
+    every position) is returned third, for mtp_forward, which runs the layer
+    BEHIND the trunk over the same chunk (_run_layers with that one block).
+
     **One mixer kind a layer** (cfg.layer_types, granite-4.0-h): the layer
     loop is one ``lax.scan`` a RUN of like layers (cfg.layer_runs), each
     reading its layer of the stacked parameters where it lies; a "mamba"
@@ -1836,6 +1906,50 @@ def forward(
     in place.
     """
     B, T = input_ids.shape
+    off_b, positions = _chunk_positions(offset, B, T)
+    x = embed_tokens(params, cfg, input_ids, positions)
+    x, new_cache = _run_layers(
+        params, cfg, x, cache, off_b, positions, remat=remat, attn_fn=attn_fn,
+        block_tables=block_tables, paged_write_floor=paged_write_floor,
+        paged_write_ceil=paged_write_ceil, adapters=adapters,
+        adapter_ids=adapter_ids, adapter_scales=adapter_scales,
+        valid_len=valid_len)
+    hidden = x
+    if last_index is not None:
+        x = take_position(x, last_index)
+    if cfg.loop_steps > 1:  # the last pass's norm WAS the final norm
+        with jax.named_scope("head.logits"):
+            return head_logits(params, cfg, x), new_cache
+    with _scope_if(_stack_scoped(cfg))("head.logits"):
+        logits = final_logits(params, cfg, x)
+    if return_hidden:
+        return logits, new_cache, hidden
+    return logits, new_cache
+
+
+def _chunk_positions(offset, B: int, T: int):
+    """(a chunk's first position a row [B], every position [B, T]) from
+    ``offset`` [] or [B]."""
+    off = jnp.asarray(offset, jnp.int32)
+    off_b = jnp.broadcast_to(off.reshape(-1), (B,))  # [B]
+    return off_b, off_b[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+
+
+def _run_layers(
+    params: Params, cfg: ModelConfig, x, cache, off_b, positions, only=None, *,
+    remat: bool = False, attn_fn=None, block_tables=None,
+    paged_write_floor=None, paged_write_ceil=None, adapters=None,
+    adapter_ids=None, adapter_scales=None, valid_len=None,
+):
+    """forward's layer loop over the embedded chunk ``x`` [B, T, D] at
+    ``positions`` [B, T] (``off_b`` [B] the rows' first): every layer of the
+    stack once, in order (every pass of a looped stack), through the cache
+    paths forward's docstring describes; the keywords are forward's.
+    ``only`` = (one layer's parameters, its index): that layer ALONE, as the
+    layer at that index of the stack runs (its mask and rotation by
+    is_sliding_layer's rule, cache layer ``index``): mtp_forward's block
+    behind the trunk, at index cfg.n_layers. Returns (x, the cache after)."""
+    B, T = positions.shape
     if cfg.has_ssm and cache is not None and "ssm" not in cache:
         raise ValueError(
             f"{cfg.name!r} has a recurrent mixer: its cache must carry the "
@@ -1854,12 +1968,6 @@ def forward(
             "latent pool (core.init_paged_pool + block_tables); the "
             "rectangular cache has no latent form"
         )
-
-    off = jnp.asarray(offset, jnp.int32)
-    off_b = jnp.broadcast_to(off.reshape(-1), (B,))  # [B]
-    positions = off_b[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [B, T]
-
-    x = embed_tokens(params, cfg, input_ids, positions)
 
     if block_tables is not None:
         bt = jnp.asarray(block_tables, jnp.int32)
@@ -2201,6 +2309,8 @@ def forward(
             xs = xs + (adapters,)
         return lax.scan(layer_body, carry, xs)[0]
 
+    if only is not None:
+        return layer_body((x, cache), only)[0]
     if cfg.loop_steps == 1:
         x, new_cache = run_layers((x, cache))
     else:
@@ -2220,14 +2330,73 @@ def forward(
         (x, new_cache), _ = lax.scan(
             one_pass, (x, cache), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
 
+    return x, new_cache
+
+
+# a chunk of at most this many positions (a verify step's K + 1: far under
+# the narrowest prefill bucket) has take_position SELECT its one position
+SELECT_POSITIONS = 16
+
+
+def take_position(x, index):
+    """``x[b, index[b]]`` of x [B, T, W] as [B, 1, W]. Over a chunk of a few
+    positions (T <= SELECT_POSITIONS) by a select and a sum over T (exact: one
+    term a row is not zero), which reads the chunk once: an element-wise
+    gather of [B, 1, W] indices costs the chip ~12 ns an ELEMENT (a
+    [64, 1, 19200] take_along_axis read 16 ms a verify step, my chip run,
+    PR 54). A prefill bucket's one position a row keeps the gather (a few
+    rows of d_model once a prompt; the select there is not measured)."""
+    B, T, W = x.shape
+    index = jnp.asarray(index, jnp.int32)
+    if T > SELECT_POSITIONS:
+        idx = index.reshape(B, 1, 1)
+        return jnp.take_along_axis(x, jnp.broadcast_to(idx, (B, 1, W)), axis=1)
+    at = jnp.arange(T, dtype=jnp.int32)[None, :] == index.reshape(-1, 1)
+    return jnp.sum(jnp.where(at[:, :, None], x, jnp.zeros((), x.dtype)),
+                   axis=1, keepdims=True)
+
+
+def mtp_input(params: Params, cfg: ModelConfig, hidden, next_ids, positions):
+    """The MTP block's input ``W_eh [RMS_e(Emb(x_{t+1})) ; RMS_h(h_t)]``
+    [B, T, D] of the trunk's last hidden state ``hidden`` [B, T, D] and the
+    tokens that follow each position ``next_ids`` [B, T]."""
+    mp = params["mtp"]
+    e = embed_tokens(params, cfg, next_ids, positions)
+    return matmul(jnp.concatenate(
+        [_norm(e, mp["enorm"], cfg),
+         _norm(hidden.astype(e.dtype), mp["hnorm"], cfg)], axis=-1),
+        mp["eh_proj"])
+
+
+def mtp_forward(params: Params, cfg: ModelConfig, hidden, next_ids, cache,
+                offset, last_index=None, **kw):
+    """The multi-token-prediction layer (cfg.mtp_layers) on a chunk the trunk
+    has run: ``hidden`` [B, T, D] is forward's ``return_hidden`` (position
+    t's h_t), ``next_ids`` [B, T] the tokens that FOLLOW each position
+    (x_{t+1}), ``cache`` / ``offset``, ``last_index`` and the cache keywords
+    (``attn_fn``, ``block_tables``, the write floor and ceil) as forward
+    takes them for the same chunk. mtp_input, then ONE block of the trunk's
+    kind run as the layer BEHIND the trunk (_run_layers: it attends fully,
+    unrotated where the full layers are; its K/V go to cache layer
+    cfg.n_layers at the chunk's own positions), then the trunk's final norm
+    and head, under the scopes ``mtp.proj``, ``mtp.block`` (around the
+    block's own ``attn.*`` / ``moe.*``) and ``mtp.head``. Returns (logits
+    [B, T, V] over x_{t+2}, the model's draft of the token after next;
+    cache)."""
+    if not cfg.mtp_layers:
+        raise ValueError(f"{cfg.name!r} has no multi-token-prediction layer")
+    off_b, positions = _chunk_positions(offset, *next_ids.shape)
+    with jax.named_scope("mtp.proj"):
+        x = mtp_input(params, cfg, hidden, next_ids, positions)
+    with jax.named_scope("mtp.block"):
+        x, cache = _run_layers(
+            params, cfg, x, cache, off_b, positions,
+            (jax.tree.map(lambda a: a[0], params["mtp"]["block"]),
+             cfg.n_layers), **kw)
     if last_index is not None:
-        idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
-        x = jnp.take_along_axis(x, jnp.broadcast_to(idx, (B, 1, x.shape[2])), axis=1)
-    if cfg.loop_steps > 1:  # the last pass's norm WAS the final norm
-        with jax.named_scope("head.logits"):
-            return head_logits(params, cfg, x), new_cache
-    with _scope_if(_stack_scoped(cfg))("head.logits"):
-        return final_logits(params, cfg, x), new_cache
+        x = take_position(x, last_index)
+    with jax.named_scope("mtp.head"):
+        return final_logits(params, cfg, x), cache
 
 
 def _layer_of(stack: Params, index):

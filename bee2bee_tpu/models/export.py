@@ -417,6 +417,96 @@ def _export_gptj_state(params, cfg: ModelConfig, dtype) -> dict[str, np.ndarray]
     return state
 
 
+def _export_exaone_moe(params, cfg: ModelConfig, np_dtype) -> tuple[dict, dict]:
+    """(state dict, config.json) of an exaone_moe model (K-EXAONE) under the
+    names loader._convert_exaone_moe reads: EXAONE 4.0's for a block,
+    DeepSeek-V3's for the expert layer and for the multi-token-prediction
+    layer (layer ``num_hidden_layers`` of the file, with the trunk's embedding
+    and head repeated as DeepSeek-V3's files repeat them). A configuration
+    that holds a SHARE (experts, vocabulary rows) writes what it holds, the
+    experts under their place among all (``num_experts_held`` /
+    ``expert_first`` / ``vocab_size_held`` say so in config.json)."""
+    if cfg.expert_share or cfg.vocab_published:
+        raise ValueError(
+            f"{cfg.name!r} holds a share of its experts or vocabulary: an "
+            "exaone_moe checkpoint states every expert and row")
+    t = lambda a: np.ascontiguousarray(_np(a, np_dtype).T)  # noqa: E731
+    proj = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    state = {
+        "model.embed_tokens.weight": _np(params["tok_embed"], np_dtype),
+        "model.norm.weight": _np(params["final_norm"]["scale"], np_dtype),
+        "lm_head.weight": t(params["lm_head"]),
+    }
+
+    def put(group, first: int):
+        for j in range(len(group["ln1_post"]["scale"])):
+            pre = f"model.layers.{first + j}."
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wo", "o_proj")):
+                state[f"{pre}self_attn.{theirs}.weight"] = t(group["attn"][ours][j])
+            for n in ("q_norm", "k_norm"):
+                state[f"{pre}self_attn.{n}.weight"] = _np(group["attn"][n][j], np_dtype)
+            state[f"{pre}post_attention_layernorm.weight"] = _np(
+                group["ln1_post"]["scale"][j], np_dtype)
+            state[f"{pre}post_feedforward_layernorm.weight"] = _np(
+                group["ln2_post"]["scale"][j], np_dtype)
+            if "mlp" in group:
+                for ours, theirs in proj:
+                    state[f"{pre}mlp.{theirs}.weight"] = t(group["mlp"][ours][j])
+                continue
+            moe = group["moe"]
+            state[f"{pre}mlp.gate.weight"] = t(moe["router"][j])
+            for ours, theirs in proj:
+                for e in range(cfg.n_experts):
+                    state[f"{pre}mlp.experts.{e}.{theirs}.weight"] = t(moe[ours][j][e])
+                state[f"{pre}mlp.shared_experts.{theirs}.weight"] = t(moe["shared"][ours][j])
+
+    k, L = cfg.first_k_dense, cfg.n_layers
+    if k:
+        put(params["dense_layers"], 0)
+    put(params["layers"], k)
+    if cfg.mtp_layers:
+        mtp = params["mtp"]
+        put(mtp["block"], L)
+        pre = f"model.layers.{L}."
+        state[f"{pre}enorm.weight"] = _np(mtp["enorm"]["scale"], np_dtype)
+        state[f"{pre}hnorm.weight"] = _np(mtp["hnorm"]["scale"], np_dtype)
+        state[f"{pre}eh_proj.weight"] = t(mtp["eh_proj"])
+        state[f"{pre}embed_tokens.weight"] = state["model.embed_tokens.weight"]
+        state[f"{pre}shared_head.norm.weight"] = state["model.norm.weight"]
+        state[f"{pre}shared_head.head.weight"] = state["lm_head.weight"]
+    pattern = "".join(
+        "L" if i in cfg.sliding_window_residues else "G"
+        for i in range(cfg.sliding_window_every))
+    types = [("sliding_attention" if pattern[i % len(pattern)] == "L"
+              else "full_attention") for i in range(L)]
+    conf = {
+        "model_type": "exaone_moe", "architectures": ["ExaoneMoeForCausalLM"],
+        "vocab_size": cfg.vocab_size, "hidden_size": cfg.d_model,
+        "intermediate_size": cfg.d_ff, "moe_intermediate_size": cfg.expert_ff,
+        "num_hidden_layers": L, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim,
+        "hidden_act": "silu", "rms_norm_eps": cfg.norm_eps,
+        "max_position_embeddings": cfg.max_seq_len,
+        "rope_parameters": {"rope_theta": cfg.rope_theta, "rope_type": "default"},
+        "tie_word_embeddings": cfg.tie_embeddings,
+        "sliding_window": cfg.sliding_window, "sliding_window_pattern": pattern,
+        "layer_types": types,
+        "sliding_windows": [cfg.sliding_window if ty == "sliding_attention" else 0
+                            for ty in types],
+        "first_k_dense_replace": k,
+        "mlp_layer_types": ["dense"] * k + ["sparse"] * (L - k),
+        "num_experts": cfg.n_experts, "num_experts_per_tok": cfg.n_experts_per_tok,
+        "num_shared_experts": cfg.n_shared_experts, "scoring_func": "sigmoid",
+        "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+        "routed_scaling_factor": cfg.moe_scale,
+        "num_nextn_predict_layers": cfg.mtp_layers,
+    }
+    if cfg.mtp_layers:
+        conf.update(mtp_layer_types=["full_attention"], mtp_sliding_windows=[0])
+    return state, conf
+
+
 def hf_config_dict(cfg: ModelConfig, qkv_bias: bool | None = None,
                    qk_norm: bool | None = None) -> dict:
     """A transformers-compatible config.json for the exported checkpoint.
@@ -886,6 +976,15 @@ def export_hf(params, cfg: ModelConfig, out_dir: str | Path,
     # unstacked list — the exporters index stacked [L, ...] arrays
     out = Path(out_dir)
     out.mkdir(parents=True, exist_ok=True)
+    if cfg.moe_router == "sigmoid" and not cfg.moe_select_bias:
+        # exaone_moe (K-EXAONE): a family of its own, MTP layer and all
+        state, conf = _export_exaone_moe(
+            params, cfg, np.dtype(dtype) if dtype != "bfloat16" else _bf16_dtype())
+        write_safetensors(
+            out / "model.safetensors", state,
+            metadata={"format": "pt", "exported_by": "bee2bee_tpu"})
+        (out / "config.json").write_text(json.dumps(conf, indent=2))
+        return out
     # key the family choice on the ACTUAL params: a bias-carrying tree
     # under a biasless config must still export as qwen2 (see hf_config_dict)
     has_qkv_bias = (

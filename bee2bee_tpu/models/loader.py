@@ -579,6 +579,76 @@ def _convert_joyai(state, cfg: ModelConfig) -> dict:
     return params
 
 
+def _convert_exaone_moe(state, cfg: ModelConfig) -> dict:
+    """HF exaone_moe (LGAI-EXAONE/K-EXAONE-*) names -> our layout. The names
+    are EXAONE 4.0's for a block (self_attn.{q,k,v,o}_proj, q_norm / k_norm,
+    post_attention_layernorm / post_feedforward_layernorm on the branches'
+    OUTPUTS) and DeepSeek-V3's, whose config keys the model uses, for the
+    expert layer (mlp.gate.weight, mlp.experts.N.*, mlp.shared_experts.*) and
+    for the multi-token-prediction layer, which is layer ``num_hidden_layers``
+    of the file (enorm, hnorm, eh_proj beside a block's own names; its
+    embed_tokens and shared_head repeat the trunk's and are not read):
+    its tensors are NOT skipped. A cut configuration (cfg.n_experts_held,
+    cfg.vocab_published) takes its share of every layer's experts and the
+    rows it holds of the embedding and the head out of the whole."""
+    pre = "model." if any(k.startswith("model.") for k in state) else ""
+    t = lambda a: np.ascontiguousarray(a.T)  # noqa: E731  HF linear is [out, in]
+    w = lambda i, k: state[f"{pre}layers.{i}.{k}"]  # noqa: E731
+    proj = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    held = range(cfg.expert_first, cfg.expert_first + cfg.experts_held)
+
+    def group(idx, moe: bool) -> dict:
+        out = {
+            "attn": {
+                **{ours: _stack([t(w(i, f"self_attn.{theirs}.weight")) for i in idx])
+                   for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                        ("wv", "v_proj"), ("wo", "o_proj"))},
+                "q_norm": _stack([w(i, "self_attn.q_norm.weight") for i in idx]),
+                "k_norm": _stack([w(i, "self_attn.k_norm.weight") for i in idx]),
+            },
+            "ln1_post": {"scale": _stack(
+                [w(i, "post_attention_layernorm.weight") for i in idx])},
+            "ln2_post": {"scale": _stack(
+                [w(i, "post_feedforward_layernorm.weight") for i in idx])},
+        }
+        swiglu = lambda at: {  # noqa: E731
+            ours: _stack([t(w(i, f"{at}.{theirs}.weight")) for i in idx])
+            for ours, theirs in proj
+        }
+        if not moe:
+            out["mlp"] = swiglu("mlp")
+            return out
+        out["moe"] = {
+            "router": _stack([t(w(i, "mlp.gate.weight")) for i in idx]),
+            **{
+                ours: _stack([
+                    _stack([t(w(i, f"mlp.experts.{e}.{theirs}.weight")) for e in held])
+                    for i in idx])
+                for ours, theirs in proj
+            },
+            "shared": swiglu("mlp.shared_experts"),
+        }
+        return out
+
+    k, L, V = cfg.first_k_dense, cfg.n_layers, cfg.vocab_size
+    params = {
+        "tok_embed": state[f"{pre}embed_tokens.weight"][:V],
+        "layers": group(range(k, L), True),
+        "final_norm": {"scale": state[f"{pre}norm.weight"]},
+        "lm_head": t(state["lm_head.weight"][:V]),
+    }
+    if k:
+        params["dense_layers"] = group(range(k), False)
+    if cfg.mtp_layers:
+        params["mtp"] = {
+            "enorm": {"scale": w(L, "enorm.weight")},
+            "hnorm": {"scale": w(L, "hnorm.weight")},
+            "eh_proj": t(w(L, "eh_proj.weight")),
+            "block": group(range(L, L + cfg.mtp_layers), True),
+        }
+    return params
+
+
 def _convert_llama(state, cfg: ModelConfig) -> dict:
     """HF Llama/Mistral names → our layout (weights transpose: HF linear is
     [out, in]; ours is [in, out])."""
@@ -744,6 +814,9 @@ def load_checkpoint(
         params = _convert_falcon_h1(state, cfg)
     elif any(".self_attn.kv_a_proj_with_mqa." in k for k in state):
         params = _convert_joyai(state, cfg)  # latent attention's unique name
+    elif any(".eh_proj." in k for k in state) or (
+            cfg.moe_router == "sigmoid" and not cfg.moe_select_bias):
+        params = _convert_exaone_moe(state, cfg)  # MTP tensors and all
     else:
         params = _convert_llama(state, cfg)
     return _materialize(params, dtype, host)

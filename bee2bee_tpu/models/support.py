@@ -39,7 +39,8 @@ GROUNDS = (
      "its rows cache latent rows (no per-head K/V)"),
     # smallthinker: a plain K/V pool under dropless expert layers
     ("dropless_routed", lambda cfg: (cfg.moe_dropless and not cfg.has_mla
-                                     and not _layer_kinds(cfg)),
+                                     and not _layer_kinds(cfg)
+                                     and not cfg.mtp_layers),
      "its every layer is a dropless expert layer routed from the "
      "pre-attention norm"),
     # ouro: a layer of cache a (pass, layer) (cfg.cache_layers)
@@ -53,11 +54,19 @@ GROUNDS = (
      "its layers hold one mixer kind each (recurrent state in some, K/V "
      "pages in the others) under dropless expert layers, of whose experts "
      "the chip may hold a share"),
+    # K-EXAONE: attention in every layer (a window in some), dropless expert
+    # layers of which the chip may hold a share behind a leading dense layer,
+    # and a multi-token-prediction layer, the model's OWN drafter (the engine's
+    # ``mtp`` tier, the one speculation proven for it)
+    ("mtp_layer", lambda cfg: cfg.mtp_layers > 0,
+     "its layers are attention (a window in some) under dropless expert "
+     "layers, of whose experts the chip may hold a share, and it drafts with "
+     "a multi-token-prediction layer of its own"),
 )
 
 
 def _layer_kinds(cfg: ModelConfig) -> bool:
-    return bool(cfg.layer_types) or cfg.expert_share
+    return (bool(cfg.layer_types) or cfg.expert_share) and not cfg.mtp_layers
 
 
 _NO_ROLLBACK = "a rejected draft cannot be rolled back out of the state"
@@ -175,6 +184,40 @@ REFUSED = {
          "the layer's own index, not its slot of its kind; use core.forward"),
         ("ring_forward", "the ring's walk reads every layer's mixer at the "
          "layer's own index, not its slot of its kind; use core.forward"),
+    ),
+    "mtp_layer": (
+        ("prefix_cache", "a pinned block holds the MTP layer's K/V too, and "
+         "its row at a prefix's last position is made with the token that "
+         "FOLLOWS the prefix"),
+        ("spec_mesh_drafter", "the verify forward with a drafter other than "
+         "the model's own layer is not tested with it"),
+        ("spec_model_drafter", "the verify forward with a drafter other than "
+         "the model's own layer is not tested with it (as the drafter: the "
+         "drafter's loop is not tested with it)"),
+        ("spec_ngram", "the verify forward with a drafter other than the "
+         "model's own layer is not tested with it"),
+        ("kv_int8", "the int8 pool's per-layer slices under a window that "
+         "binds and under the MTP layer's writes are not tested"),
+        ("weight_int8", "the grouped product reads the expert stacks "
+         "unquantised"),
+        ("seq_attention", "the sp partials know no window"),
+        ("mesh_model", "the dropless expert layer's grouped product is not "
+         "partitioned over a model axis (--mesh-shape model:N)"),
+        ("mesh_expert", "a share of the experts is a property of the "
+         "configuration (n_experts_held): the exchange of the partial sums "
+         "between the chips of a layer is not built, and the grouped product "
+         "is not partitioned over an expert axis"),
+        ("multi_lora", "adapters are not tested with it"),
+        ("pipeline_stages", "a stage's loop reads a layer's experts sliced "
+         "out of the stack, knows no MTP layer and is not tested"),
+        ("kv_export", "a row's blocks hold the MTP layer's K/V, whose export "
+         "and import are not tested"),
+        ("pipeline_stage_split", "a stage's walk knows no MTP layer; use "
+         "core.forward"),
+        ("pipeline_trunk", "the trunk's walk knows no MTP layer; use "
+         "core.forward"),
+        ("ring_forward", "the ring's walk knows no MTP layer; use "
+         "core.forward"),
     ),
 }
 

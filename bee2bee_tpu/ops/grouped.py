@@ -19,7 +19,7 @@ kernel 1,034 at a (128, K, N) tile against 872 for reading the touched
 experts once at 819 GB/s; 4,096 rows over 256 experts: 3,945 / 1,313 / 983.
 Row tiles of 16-256 read 1,126 / 1,067 / 1,044 / 1,034 / 1,093; splitting K
 or N costs 2-15 %. So the tile is 128 rows x the whole matrix where that
-fits, from the shapes alone.
+fits (_k_tile: blocks of rows where it does not), from the shapes alone.
 
 On devices that are not TPUs the kernel runs in pallas interpret mode
 (ops/flash.interpret_off_tpu), so the CPU suite runs the same code.
@@ -33,6 +33,26 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 from .flash import interpret_off_tpu
 
 _ROW_TILE = 128  # rows a grid step: past it a visit's product outgrows its copy
+# A grid step holds its [tk, N] block of a group's matrix twice (the next
+# one's copy runs under this one's product) beside the rows, the float32
+# accumulator and the output tile, in 16 MiB of VMEM. A WHOLE matrix fits to
+# 6 MiB (granite's [4096, 768], the largest served so far); a larger one
+# (K-EXAONE's [6144, 2048] / [2048, 6144]: 24 MiB) is read in blocks of rows
+# of at most 4 MiB: rows stay contiguous in HBM, the k steps of a visit
+# accumulate in VMEM
+_WHOLE_MATRIX_BYTES = 6 << 20
+_MATRIX_BLOCK_BYTES = 4 << 20
+
+
+def _k_tile(K: int, N: int, itemsize: int) -> int:
+    """Rows of a group's [K, N] matrix a grid step reads: all of them where
+    the matrix fits, else the largest multiple of 128 that divides K and
+    keeps the block inside _MATRIX_BLOCK_BYTES."""
+    if K * N * itemsize <= _WHOLE_MATRIX_BYTES:
+        return K
+    fits = [tk for tk in range(128, K, 128)
+            if K % tk == 0 and tk * N * itemsize <= _MATRIX_BLOCK_BYTES]
+    return max(fits) if fits else 128
 
 
 def grouped_matmul(x, w, group_sizes, interpret: bool | None = None):
@@ -49,7 +69,7 @@ def grouped_matmul(x, w, group_sizes, interpret: bool | None = None):
         x = jnp.pad(x, ((0, pad), (0, 0)))
     out = gmm(
         x, w.astype(x.dtype), group_sizes.astype(jnp.int32), x.dtype,
-        (tm, K, N),
+        (tm, _k_tile(K, N, x.dtype.itemsize), N),
         interpret=interpret_off_tpu() if interpret is None else interpret,
     )
     return out[:M] if pad else out

@@ -21,6 +21,7 @@ PRESET = {  # a tiny model of each kind
     "dropless_routed": "tiny-smallthinker",
     "looped_stack": "tiny-ouro",
     "layer_kinds": "tiny-granite",
+    "mtp_layer": "tiny-exaone",
 }
 DETAIL = dict(prefill_chunk=48, max_seq_len=128)
 ROWS = [(ground, feature, why)
@@ -85,6 +86,15 @@ def test_the_engine_names_each_feature_for_the_configuration_that_turns_it_on():
     asked_elsewhere = {"pipeline_stages", "kv_export", "pipeline_stage_split",
                        "pipeline_trunk", "ring_forward"}
     assert set(IN_USE) | asked_elsewhere == {f for _, f, _ in ROWS}
+    # a model with a multi-token-prediction layer speculates with THAT: no
+    # kind refuses spec_mtp, and the n-gram floor is not what spec_tokens asks
+    own = get_config("tiny-exaone")
+    assert in_use(EngineConfig(spec_tokens=1), NO_MESH, 128, own) == {"spec_mtp"}
+    assert in_use(EngineConfig(), NO_MESH, 128, own) == set()
+    assert in_use(EngineConfig(spec_tokens=1, drafter="tiny-llama"), NO_MESH, 128, own) == {
+        "spec_mtp", "spec_model_drafter"}
+    assert "spec_mtp" not in {f for _, f, _ in ROWS}
+    assert support.require(own, "spec_mtp", "prefill_chunk", **DETAIL) is None
 
 
 @pytest.mark.parametrize("model,features,first", [
@@ -95,6 +105,9 @@ def test_the_engine_names_each_feature_for_the_configuration_that_turns_it_on():
     ("tiny-smallthinker", ("pipeline_stages", "spec_ngram"), "spec_ngram"),
     ("tiny-granite", ("weight_int8", "mesh_expert", "kv_export"), "mesh_expert"),
     ("granite-4.0-h-small-10l-e36", ("kv_int8", "prefix_cache"), "prefix_cache"),
+    ("tiny-exaone", ("kv_export", "spec_ngram", "mesh_expert"), "spec_ngram"),
+    ("k-exaone-236b-a23b-5l-e16", ("weight_int8", "prefix_cache"), "prefix_cache"),
+    ("k-exaone-236b-a23b", ("pipeline_stages", "spec_model_drafter"), "spec_model_drafter"),
 ])
 def test_of_two_refused_features_the_table_s_first_is_raised(model, features, first):
     with pytest.raises(FeatureUnsupported) as err:

@@ -886,3 +886,105 @@ def test_a_call_of_more_rows_keeps_the_head_split_in_its_products(one_chip):
     assert 2 * 2048 > core.QKV_IN_PLACE_ROWS
     lowered, _ = _forward_program(cfg, 2, 2048, 128, 385, sharding=one_chip)
     assert "optimization_barrier" not in lowered.as_text()
+
+
+# ---- k-exaone-236b-a23b-5l-e16 (PR 54): L(dense) L L G L behind a 128-token
+# window, 16 of every layer's 128 experts held, rows 0-19,199 of the vocabulary,
+# and the MTP layer behind the trunk in the SAME program (the ``mtp`` tier)
+
+EXAONE = get_config("k-exaone-236b-a23b-5l-e16")
+
+
+def _exaone_engine_program(fn_name: str, B: int, T: int, MB: int, one_chip):
+    """The engine's own verify / prefill function (engine.InferenceEngine's,
+    on a stand-in that carries what it reads of ``self``) lowered for the
+    described chip at the cell's sizes: 3,200 pool blocks, six cache layers."""
+    from types import SimpleNamespace
+
+    from bee2bee_tpu.engine.engine import InferenceEngine
+
+    cfg = EXAONE
+    attn = make_ragged_attn_fn(None, interpret=False)
+    stand_in = SimpleNamespace(model_cfg=cfg, mtp_on=True, dtype=jnp.bfloat16,
+                               _attn_fn=lambda: attn)
+
+    def place(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    pool = place(jax.eval_shape(lambda: core.init_paged_pool(
+        cfg, 3200, BS, jnp.bfloat16, lane_aligned=True)))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def floats(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    fn = getattr(InferenceEngine, fn_name)
+    if fn_name == "_spec_verify_fn":
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        return jax.jit(
+            lambda *a: fn(stand_in, *a), donate_argnums=(4,)
+        ).lower(params, ints(B), ints(B, T - 1), ints(B), pool, ints(B), floats(B),
+                ints(B), floats(B), None, key, ints(B, MB))
+    return jax.jit(
+        lambda p, t, c, n, o, bt, fl, ce, nx: fn(
+            stand_in, p, t, c, n, o, bt, fl, ce, mtp_next=nx),
+        donate_argnums=(2,),
+    ).lower(params, ints(B, T), pool, ints(B), ints(B), ints(B, MB), ints(B), ints(B),
+            ints(B))
+
+
+@pytest.mark.parametrize("fn,B,T,MB", [
+    ("_spec_verify_fn", 64, 2, 64), ("_prefill_fn", 8, 128, 8), ("_prefill_fn", 1, 512, 32)],
+    ids=["exaone-verify-64", "exaone-prefill-8x128", "exaone-prefill-512"])
+def test_the_exaone_cell_programs_hold_the_mtp_layer_and_fit_the_chip(
+        one_chip, mosaic_grouped, fn, B, T, MB):
+    """The cut preset as the cell serves it (3,200 pool blocks, 64 rows): the
+    verify step is ONE program (the [64, 2] chunk through the trunk, the
+    verdict, the MTP layer behind it), a prefill runs the MTP layer over its
+    chunk too; every part under its scope, the 3.62 GB expert stacks read where
+    they lie, and all of it under the chip's 15.75 GB."""
+    cfg = EXAONE
+    compiled = _exaone_engine_program(fn, B, T, MB, one_chip).compile()
+    text = compiled.as_text()
+    scopes = ["attn.qkv", "attn.read", "attn.out", "moe.router", "moe.dispatch",
+              "moe.experts", "moe.combine", "moe.shared", "mtp.proj",
+              "mtp.block/attn.read", "mtp.block/moe.experts", "mtp.head"]
+    if fn == "_spec_verify_fn":
+        scopes += ["spec.verify", "spec.accept"]
+    for scope in scopes:
+        assert re.search(rf'op_name="[^"]*{re.escape(scope)}', text), scope
+    # the trunk's layers write and read their pages by layer, the MTP block its own
+    assert _custom_calls(text, "kv.write") == 3 and _custom_calls(text, "attn.read") == 3
+    one_matrix = cfg.experts_held * cfg.d_model * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_expert_layers) == []
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(f"exaone {fn} {B}x{T}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, alias "
+          f"{m.alias_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 10.0e9 < total < 15.75e9, total
+
+
+def test_the_exaone_centring_program_fits_beside_the_weights(one_chip, mosaic_grouped):
+    """core.center_router at the cut preset: 9.09 GB of weights in, the four
+    trunk routers and the MTP block's out, the balancing batch's temporaries
+    beside them."""
+    cfg = dataclasses.replace(EXAONE, max_seq_len=2048)
+    shapes = jax.eval_shape(
+        lambda: jax.jit(core._init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(jnp.bfloat16)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(core.center_router, static_argnums=1).lower(args, cfg).compile()
+    m = compiled.memory_analysis()
+    routers = (cfg.n_expert_layers + cfg.mtp_layers) * cfg.d_model * cfg.n_experts * 2
+    assert routers <= m.output_size_in_bytes < routers + 4096  # (+ the pair's table)
+    print(f"exaone centring: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB")
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
