@@ -417,6 +417,14 @@ class InferenceEngine:
                 and key[1] == self.engine_cfg.spec_tokens
             ),
         )
+        # the verify WINDOW of an engine that drafts for itself (mtp_on): up
+        # to decode_chunk verify steps in one dispatch, the draft fed back on
+        # the chip; such a node compiles this root INSTEAD of the one above
+        self._spec_window = self.introspect.sentinel.watch(
+            "spec_verify",
+            jax.jit(self._spec_window_fn, donate_argnums=(5,)),
+            key_fn=self._spec_window_key,
+        )
         self._state_zeros = jax.jit(
             functools.partial(prog_scope("prog.pool")(core.init_ssm_state),
                               self.model_cfg, dtype=self.dtype),
@@ -531,6 +539,18 @@ class InferenceEngine:
             adapters is not None,
             counts is not None,
         )
+
+    @staticmethod
+    def _spec_window_key(params, cur, draft, drafting, budget, cache, offsets,
+                         temps, topks, topps, minps=None, key=None,
+                         tables=None, adapters=None, aids=None, ascales=None,
+                         counts=None, reps=None, press=None, freqs=None,
+                         steps=None):
+        """Sentinel shape key for the verify-window root: _spec_verify_key's
+        (``steps`` is an operand's VALUE, as the decode root's: not keyed)."""
+        return InferenceEngine._spec_verify_key(
+            params, cur, draft, drafting, cache, offsets, temps, topks, topps,
+            minps, key, tables, adapters, aids, ascales, counts)
 
     def _attn_fn(self):
         """attn_fn for core.forward per the engine's attention setting.
@@ -868,14 +888,33 @@ class InferenceEngine:
         fourth, before the counts: no draft dispatch, no hidden state on
         the host.
         """
+        own = self.mtp_on
+        if own and self.model_cfg.moe_dropless:
+            cache = dict(cache, moe_stats=jnp.zeros(
+                (len(core.moe_stats_names(self.model_cfg)),), jnp.int32))
+        nxt, cache, accepted, draft, counts = self._verify_step(
+            params, cur, drafts, draft_lens, cache, offsets, temps, topks,
+            topps, minps, key, tables, adapters, aids, ascales,
+            counts, reps, press, freqs)
+        extras = {"mtp_draft": draft} if own else None
+        if own and "moe_stats" in cache:
+            extras["moe_stats"] = cache.pop("moe_stats")
+        return tuple(x for x in (nxt, cache, accepted, extras, counts)
+                     if x is not None)
+
+    def _verify_step(self, params, cur, drafts, draft_lens, cache, offsets,
+                     temps, topks, topps, minps, key, tables, adapters, aids,
+                     ascales, counts, reps, press, freqs):
+        """ONE verify step, the body both verify roots run (_spec_verify_fn:
+        a step a call; _spec_window_fn: a step a turn of its loop) ->
+        (next_tok [B], cache, accepted [B], the MTP layer's next draft [B] |
+        None, counts | None). An expert model's ``moe_stats`` ride ``cache``
+        through it and are the root's to zero and to pop."""
         from .sampling import sample_batched
 
         B, K = drafts.shape
         own = self.mtp_on
         tokens = jnp.concatenate([cur[:, None], drafts], axis=1)  # [B, K+1]
-        if own and self.model_cfg.moe_dropless:
-            cache = dict(cache, moe_stats=jnp.zeros(
-                (len(core.moe_stats_names(self.model_cfg)),), jnp.int32))
         with core._scope_if(own)("spec.verify"):
             logits, cache, *hidden = core.forward(
                 params, self.model_cfg, tokens, cache, offsets,
@@ -893,46 +932,90 @@ class InferenceEngine:
                 jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
             # (a select over K + 1: no element-wise gather a step)
             last = core.take_position(logits, accepted)[:, 0, :]
-
-        def mtp_extras(nxt, cache):
-            """The MTP layer behind the verdict -> ({mtp_draft, moe_stats},
-            cache): position j of the chunk with the token that follows it."""
-            follow = jnp.where(
-                jnp.arange(K + 1, dtype=jnp.int32)[None, :] < accepted[:, None],
-                jnp.concatenate([drafts, nxt[:, None]], axis=1), nxt[:, None])
-            mtp_logits, cache = core.mtp_forward(
-                params, self.model_cfg, hidden[0], follow, cache, offsets,
-                attn_fn=self._attn_fn(), block_tables=tables,
-                last_index=accepted,
-            )
-            extras = {"mtp_draft": jnp.argmax(
-                mtp_logits[:, 0, :], axis=-1).astype(jnp.int32)}
-            if "moe_stats" in cache:
-                extras["moe_stats"] = cache.pop("moe_stats")
-            return extras, cache
-
-        if counts is None:
-            nxt = sample_batched(
-                last, key, temps, topks, topps, minps).astype(jnp.int32)
-            if own:
-                extras, cache = mtp_extras(nxt, cache)
-                return nxt, cache, accepted, extras
-            return nxt, cache, accepted
-        # fused penalty bookkeeping (docs/PERF.md "Decode hot loop"): a
-        # penalized row never drafts (scheduler._spec_eligible), so its
-        # accepted is 0 and the draft bump below is a masked no-op for it;
-        # non-drafting rows still need their ACCEPTED drafts counted so
-        # the shared [B,2,V] gen-counts stay coherent across the batch.
-        gain = (pos < accepted[:, None]).astype(counts.dtype)  # [B, K]
-        counts = counts.at[jnp.arange(B)[:, None], 1, drafts].add(gain)
+        if counts is not None:
+            # fused penalty bookkeeping (docs/PERF.md "Decode hot loop"): a
+            # penalized row never drafts (scheduler._spec_eligible), so its
+            # accepted is 0 and the draft bump below is a masked no-op for it;
+            # non-drafting rows still need their ACCEPTED drafts counted so
+            # the shared [B,2,V] gen-counts stay coherent across the batch.
+            gain = (pos < accepted[:, None]).astype(counts.dtype)  # [B, K]
+            counts = counts.at[jnp.arange(B)[:, None], 1, drafts].add(gain)
         nxt = sample_batched(last, key, temps, topks, topps, minps,
-                             counts, reps, press, freqs)
-        nxt = nxt.astype(jnp.int32)
-        counts = counts.at[jnp.arange(B), 1, nxt].add(1)
-        if own:
-            extras, cache = mtp_extras(nxt, cache)
-            return nxt, cache, accepted, extras, counts
-        return nxt, cache, accepted, counts
+                             counts, reps, press, freqs).astype(jnp.int32)
+        if counts is not None:
+            counts = counts.at[jnp.arange(B), 1, nxt].add(1)
+        if not own:
+            return nxt, cache, accepted, None, counts
+        # the MTP layer behind the verdict: position j of the chunk with the
+        # token that follows it
+        follow = jnp.where(
+            jnp.arange(K + 1, dtype=jnp.int32)[None, :] < accepted[:, None],
+            jnp.concatenate([drafts, nxt[:, None]], axis=1), nxt[:, None])
+        mtp_logits, cache = core.mtp_forward(
+            params, self.model_cfg, hidden[0], follow, cache, offsets,
+            attn_fn=self._attn_fn(), block_tables=tables,
+            last_index=accepted,
+        )
+        draft = jnp.argmax(mtp_logits[:, 0, :], axis=-1).astype(jnp.int32)
+        return nxt, cache, accepted, draft, counts
+
+    @prog_scope("prog.verify")
+    def _spec_window_fn(self, params, cur, draft, drafting, budget, cache,
+                        offsets, temps, topks, topps, minps, key, tables=None,
+                        adapters=None, aids=None, ascales=None, counts=None,
+                        reps=None, press=None, freqs=None, *, steps):
+        """The verify WINDOW of an engine that drafts for itself (mtp_on):
+        ``steps`` verify steps (an int32 scalar OPERAND, 1 <= steps <= N =
+        decode_chunk; the decode root's pattern, scheduler._decode_fn) in ONE
+        program, each _verify_step on what the step before left: ``cur`` <-
+        its token, ``draft`` [B, K] <- its MTP layer's draft, ``offsets`` <-
+        offsets + accepted + 1. Step i draws with key i of the N split from
+        ``key`` and computes what the i-th of ``steps`` chained _spec_verify
+        calls would: same positions, same pool rows (a rejected position's
+        K/V and MTP row are rewritten when the position comes round), same
+        keys at the same steps.
+
+        A step's draft lengths are made here: ``drafting`` [B] marks the rows
+        that draft at all (greedy, unpenalised, on the tier: fixed for the
+        window; the others ride with length 0, one token a step) and
+        ``budget`` [B] is each row's remaining tokens, less what the window
+        has given it so far: a row at its last token does not draft.
+
+        Returns (cur', cache', offsets', counts', toks [N, B, K+1], accepted
+        [N, B], draft' [B, K], extras): row b's tokens of step i are
+        toks[i, b, :accepted[i, b]] (the drafts kept) and toks[i, b, K] (the
+        step's own); only the first ``steps`` entries hold a step. ``extras``
+        is an expert model's ``moe_stats`` summed over the steps, else None."""
+        B, K = draft.shape
+        N = self.engine_cfg.decode_chunk
+        if self.model_cfg.moe_dropless:
+            cache = dict(cache, moe_stats=jnp.zeros(
+                (len(core.moe_stats_names(self.model_cfg)),), jnp.int32))
+        keys = jax.random.split(key, N)
+        start = offsets
+
+        def step(i, carry):
+            cur, draft, cache, off, cnt, toks, accs = carry
+            lens = jnp.where(drafting > 0,
+                             jnp.clip(budget - (off - start) - 1, 0, K), 0)
+            nxt, cache, accepted, made, cnt = self._verify_step(
+                params, cur, draft, lens, cache, off, temps, topks, topps,
+                minps, keys[i], tables, adapters, aids, ascales,
+                cnt, reps, press, freqs)
+            toks = jax.lax.dynamic_update_index_in_dim(
+                toks, jnp.concatenate([draft, nxt[:, None]], axis=1), i, 0)
+            accs = jax.lax.dynamic_update_index_in_dim(accs, accepted, i, 0)
+            return (nxt, jnp.broadcast_to(made[:, None], draft.shape), cache,
+                    off + accepted + 1, cnt, toks, accs)
+
+        cur, draft, cache, offsets, counts, toks, accs = jax.lax.fori_loop(
+            0, steps, step,
+            (cur, draft, cache, offsets, counts,
+             jnp.zeros((N, B, K + 1), jnp.int32), jnp.zeros((N, B), jnp.int32)),
+        )
+        extras = ({"moe_stats": cache.pop("moe_stats")}
+                  if "moe_stats" in cache else None)
+        return cur, cache, offsets, counts, toks, accs, draft, extras
 
     # ------------------------------------------------------------ helpers
 

@@ -104,19 +104,22 @@ _H_PREFILL = _REG.histogram(
     "admission prefill through first-token readback per request (ms)",
 )
 _H_STEP = _REG.histogram(
-    "engine.step_ms", "one decode window / spec verify step wall time (ms)"
+    "engine.step_ms",
+    "one decode window / verify window / serialized verify step wall time (ms)"
 )
 _H_WINDOW_STEPS = _REG.histogram(
     "engine.window_steps",
-    "decode steps of each dispatched window (chosen at dispatch: "
-    "choose_window_steps); engine.step_ms / this = ms a decode step",
+    "decode steps of each dispatched window, verify steps of each verify "
+    "window (chosen at dispatch: choose_window_steps); engine.step_ms / this "
+    "= ms a step",
     buckets=(1, 2, 4, 8, 12, 16, 24, 32, 64, 128, 256),
 )
 _C_WINDOWS = _REG.counter(
     "engine.windows",
-    "decode windows dispatched (cut label: full = ran to the cap | budget = "
-    "shortened for a row's end with the queue waiting | drain = nobody "
-    "queued and every row ends sooner | sync = pinned for a spec draft)",
+    "decode and verify windows dispatched (cut label: full = ran to the cap "
+    "| budget = shortened for a row's end with the queue waiting | drain = "
+    "nobody queued and every row ends sooner | sync = pinned for a spec "
+    "draft | room = a verify window cut to what a row's context still holds)",
 )
 _H_BURST = _REG.histogram(
     "engine.admit_burst_requests",
@@ -165,7 +168,8 @@ _C_SPEC_ACCEPTED = _REG.counter(
 )
 _C_SPEC_STEPS = _REG.counter(
     "engine.spec_steps",
-    "speculative verify steps: one [B, K+1] forward of every live row",
+    "speculative verify steps: one [B, K+1] forward of every live row (a "
+    "verify window counts its steps)",
 )
 _C_SPEC_DEGRADED = _REG.counter(
     "engine.spec_mesh_degraded",
@@ -695,6 +699,9 @@ class BatchScheduler:
             _Observed(), _Observed(), _ObservedBursts())
         self._t_fetched: float | None = None
         self._admit_since = 0.0
+        # tokens a row made a verify step, over the last verify windows (a
+        # row's budget in STEPS is its tokens over this: _verify_window_size)
+        self._spec_rate = _Observed()
         # settled, not yet delivered: one deque of (request, accepted
         # tokens, ended) a fetched window, oldest first. _settle_row has
         # already done what the SCHEDULER needs of those tokens (out_ids,
@@ -2258,7 +2265,6 @@ class BatchScheduler:
             return False
         drafts, lens = proposal
         e = self.engine
-        K = e.engine_cfg.spec_tokens
         # cover the whole [offset, offset+K+1) write extent — blocks
         # claimed for later-rejected slots stay owned by the row
         # (over-allocated tail) and free normally at retirement
@@ -2279,45 +2285,26 @@ class BatchScheduler:
             self._mean_active_ctx() + (e.engine_cfg.spec_tokens + 1) / 2.0,
             scheduled=self.active * (e.engine_cfg.spec_tokens + 1),
         )
-        # a penalized row's counts ride the verify call
-        # (engine._spec_verify_fn) and it advances its normal one
-        # penalty-sampled token per step
-        pen = self._counts_ride()
+        pen_args = self._pen_args()
         t_step = time.perf_counter()
         with self._phases.phase("fetch"):
             with get_tracer().span(
                 "engine.spec_verify", active=self.active, drafted=int(lens.sum())
             ):
-                pen_args = dict(
-                    counts=self._counts, reps=self._reps,
-                    press=self._press, freqs=self._freqs,
-                ) if pen else {}
                 nxt_d, self.cache.pool, acc_d, *cnts = e._spec_verify(
                     e.params, self._cur, drafts, lens, self.cache.pool,
                     self._offsets, temps, topks, topps, minps,
                     e._next_key(), tables, **self._lora_args(), **pen_args,
                 )
-                # the ``mtp`` tier: the next step's drafts and the expert
-                # layers' counters come back with the verdict
-                own = dict(cnts.pop(0)) if e.mtp_on else {}
-                if pen:
+                if pen_args:
                     (self._counts,) = cnts
                     self.stats.counts_windows += 1
-                moe = []
-                if "moe_stats" in own:  # the prefills' since, then this step's
-                    moe = self._moe_pending + [own.pop("moe_stats")]
-                    self._moe_pending = []
-                    self._count_moe(self.active * (K + 1),
-                                    (self._bsz - self.active) * (K + 1), 1,
-                                    mtp=True)
                 # a spec step is always a serialized sync: the drafter needs
                 # the verdict before it can propose again
                 _C_HOST_SYNCS.inc()
                 _C_SYNC_STALLS.inc()
                 _G_OVERLAP.set(0)
-                nxt, acc, own, moe = jax.device_get((nxt_d, acc_d, own, moe))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
-        if moe:
-            self._note_moe(moe, windows=1)
+                nxt, acc = jax.device_get((nxt_d, acc_d))  # meshlint: ignore[ML-J003] -- the spec verdict IS the readback window's one host sync
         _H_STEP.observe((time.perf_counter() - t_step) * 1000.0)
         self._t_fetched = None  # a verify step is no turn of the host
         self._last_dispatch_t = time.perf_counter()
@@ -2337,21 +2324,7 @@ class BatchScheduler:
             a = int(acc[b])
             drafted_here = int(lens[b])
             tier = self._draft_tier.get(b, "ngram")
-            if drafted_here:
-                req.spec_drafted += drafted_here
-                req.spec_accepted += a
-                req.spec_tier_drafted += drafted_here
-                req.spec_tier_accepted += a
-                self.stats.spec_drafted += drafted_here
-                self.stats.spec_accepted += a
-                ts = self.stats.spec_tiers.setdefault(
-                    tier, {"drafted": 0, "accepted": 0}
-                )
-                ts["drafted"] += drafted_here
-                ts["accepted"] += a
-                _C_SPEC_DRAFTED.inc(drafted_here, tier=tier)
-                _C_SPEC_ACCEPTED.inc(a, tier=tier)
-                self._meter.note_spec(tier, drafted_here, a)
+            self._book_spec(req, tier, drafted_here, a)
             # accepted draft prefix, then the verify's own next token
             retired = self._process_row_tokens(
                 b, req, np.append(drafts[b, :a], nxt[b])
@@ -2368,11 +2341,6 @@ class BatchScheduler:
                 if drafter is not None:
                     drafter.observe(req, a)
                 self._spec_tier_check(req)
-            if "mtp_draft" in own and not retired:
-                # made at the last accepted position with the token just
-                # chosen: the draft of the token after ``cur``
-                req.mtp_draft = (len(req.ids) + len(req.out_ids),
-                                 int(own["mtp_draft"][b]))
         self._meter.note_slots(self._bsz, live_rows,
                                e.engine_cfg.spec_tokens + 1, kept)
         # a spec step is serialized: nothing ran while its rows were delivered
@@ -2380,6 +2348,188 @@ class BatchScheduler:
         if retired_any:
             self._compact_and_shrink()
         return True
+
+    def _verify_window_size(self) -> tuple[int, str]:
+        """The verify steps of the next verify window, and why (the `cut`
+        label of engine.windows): _window_size's policy with a row's budget
+        counted in STEPS, at the tokens a row has been observed to make a
+        verify step (1 where no draft is accepted), under the cap of one
+        program call (decode_chunk steps). Then cut to what the furthest
+        row's context still holds: a step writes K + 1 positions at a row's
+        offset whatever it accepts, so n steps may reach offset + n (K + 1)
+        (cut `room`; _spec_possible has said that one step fits)."""
+        e = self.engine
+        K = e.engine_cfg.spec_tokens
+        rate = max(1.0, self._spec_rate.value or 1.0)
+        n, cut = choose_window_steps(
+            np.ceil(np.asarray(self._budgets(), np.float64) / rate),
+            bool(self._queue), e.engine_cfg.decode_chunk,
+            self._step_s.value, self._turn_s.value, self._bursts.fixed,
+        )
+        room = min((e.max_seq_len - int(self._offsets[b])) // (K + 1)
+                   for b, r in enumerate(self._rows) if r is not None)
+        return (n, cut) if n <= room else (max(1, room), "room")
+
+    @_phase("dispatch")
+    def _dispatch_verify_window(self) -> bool:
+        """A node whose model drafts for itself (engine.mtp_on): dispatch ONE
+        verify window (engine._spec_window_fn: the steps _verify_window_size
+        chose, each row's draft fed back on the chip) where the host-drafted
+        tiers run one serialized _spec_step, and push its record onto the
+        readback ring, whence it takes the decode window's road: one fetch
+        (_fetch_window), one settle of a row's tokens of all its steps
+        (_settle_window), one stream event a row, delivered under the next
+        burst's prefills. The host's offsets advance AT THE FETCH (what a
+        row accepts is the device's to say), so nothing is stacked on a
+        verify window (_overlap_ready). Returns False when the step was not
+        taken and the caller should run a normal decode window; True with
+        nothing in flight when no row was left to decode."""
+        proposal = self._spec_drafts()
+        if proposal is None:
+            return False
+        drafts, lens = proposal
+        e, c = self.engine, self.cache
+        K = e.engine_cfg.spec_tokens
+        n, cut = self._verify_window_size()
+        # cover the furthest a row can get (every draft accepted); blocks
+        # past where it got stay the row's until it ends, as a serialized
+        # step's rejected slots do
+        tables = self._prepare_window_tables(n * (K + 1), n)
+        if tables is None:
+            self._compact_and_shrink()
+            return True
+        _H_WINDOW_STEPS.observe(n)
+        _C_WINDOWS.inc(cut=cut)
+        temps, topks, topps = self._row_sampling_arrays()
+        minps = self._minps if self._minps.any() else None
+        self._set_fill_gauges()
+        live = [(b, r) for b, r in enumerate(self._rows) if r is not None]
+        # economics: the hardware runs bsz * n * (K+1) positions, the batch
+        # SCHEDULED active * n * (K+1) token slots (_spec_step's, n times)
+        self._meter.record_dispatch(
+            self._bsz * n * (K + 1),
+            self._mean_active_ctx() + n * (K + 1) / 2.0,
+            scheduled=len(live) * n * (K + 1),
+        )
+        # which rows draft at all is fixed for the window; a step's draft
+        # length is made on the device from it and the row's remaining budget
+        drafting = lens > 0
+        budget = np.zeros((self._bsz,), np.int32)
+        for b, r in live:
+            budget[b] = r.max_new_tokens - len(r.out_ids)
+        pen_args = self._pen_args()
+        t0 = time.perf_counter()
+        cur_d, c.pool, off_d, cnts, toks, accs, draft_d, extras = e._spec_window(
+            e.params, self._cur, drafts, drafting, budget,
+            c.pool, self._offsets, temps, topks, topps, minps, e._next_key(),
+            tables, **self._lora_args(), **pen_args, steps=np.int32(n),
+        )
+        if pen_args:
+            self._counts = cnts
+            self.stats.counts_windows += 1
+        moe = []
+        if extras:  # the prefills' counters since the last window, then this one's
+            moe = self._moe_pending + [extras["moe_stats"]]
+            self._moe_pending = []
+            self._count_moe(len(live) * n * (K + 1),
+                            (self._bsz - len(live)) * n * (K + 1), n, mtp=True)
+        self._inflight.append({
+            "verify": True, "cur": cur_d, "off": off_d, "toks": [toks],
+            "acc": accs, "draft": draft_d, "n": n, "slots": n * (K + 1),
+            "moe": moe, "rows": live,
+            "t0": t0, "drafting": drafting, "budget": budget,
+            "tiers": dict(self._draft_tier),
+        })
+        self._note_turn(t0)
+        self.stats.spec_steps += n
+        _C_SPEC_STEPS.inc(n)
+        self._last_dispatch_t = time.perf_counter()
+        return True
+
+    def _verify_rows(self, rec, toks, acc, draft, off) -> list:
+        """A fetched verify window on the host: the mirrors take the device's
+        word (cur = the last step's token, offsets = where each row got), and
+        row b's tokens of the window are its steps' kept drafts and own
+        token laid end to end, by a mask over [n, K+1] -> [bsz] arrays. What
+        _settle_window books a row by stays on the record: a step's verdict,
+        its draft length (the device's rule: engine._spec_window_fn) and the
+        tokens the row had been given before it."""
+        n, K = rec["n"], toks.shape[2] - 1
+        toks, acc = toks[:n], acc[:n].astype(np.int64)
+        self._cur = toks[-1, :, K].astype(np.int32).copy()
+        self._offsets = off.astype(np.int32).copy()
+        given = np.cumsum(acc + 1, axis=0) - (acc + 1)
+        lens = np.where(rec["drafting"][None, :],
+                        np.clip(rec["budget"][None, :] - given - 1, 0, K), 0)
+        rec.update(acc_h=acc, lens_h=lens, given_h=given, draft_h=draft)
+        at = np.arange(K + 1)
+        keep = (at < acc[:, :, None]) | (at == K)  # [n, bsz, K+1]
+        drafting = [b for b, _ in rec["rows"] if rec["drafting"][b]]
+        if drafting:
+            self._spec_rate.note(
+                1.0 + float(acc[:, drafting].sum()) / (n * len(drafting)))
+        return [toks[:, b][keep[:, b]] for b in range(toks.shape[1])]
+
+    def _book_verify_row(self, rec, b: int, entry: tuple):
+        """The tier's books of row b for one settled verify window, from the
+        sums over the steps the row LIVED (all of them, or up to the step
+        whose token ended it: what the serialized steps would have run), and
+        the draft it goes on with."""
+        req, kept, ended = entry
+        used = rec["n"]
+        if ended:
+            taken = len(kept) + (req.finish in ("eos", "stop"))
+            if req.finish == "cancelled":
+                taken = 0
+            used = int(np.searchsorted(rec["given_h"][:, b], taken, side="left"))
+        drafted = int(rec["lens_h"][:used, b].sum())
+        a = int(rec["acc_h"][:used, b].sum())
+        self._book_spec(req, rec["tiers"].get(b, "mtp"), drafted, a)
+        if ended:
+            return
+        if drafted:
+            self._spec_tier_check(req)
+        # made at the window's last accepted position with its last token:
+        # the draft of the token after ``cur``
+        req.mtp_draft = (len(req.ids) + len(req.out_ids),
+                         int(rec["draft_h"][b, 0]))
+
+    def _book_spec(self, req: Request, tier: str, drafted: int, accepted: int):
+        """One row's drafted / accepted tokens on every book of its tier: the
+        request's (lifetime and the current tier's probe), the stats', the
+        counters', the goodput meter's. Nothing where it drafted nothing."""
+        if not drafted:
+            return
+        req.spec_drafted += drafted
+        req.spec_accepted += accepted
+        req.spec_tier_drafted += drafted
+        req.spec_tier_accepted += accepted
+        self.stats.spec_drafted += drafted
+        self.stats.spec_accepted += accepted
+        ts = self.stats.spec_tiers.setdefault(tier, {"drafted": 0, "accepted": 0})
+        ts["drafted"] += drafted
+        ts["accepted"] += accepted
+        _C_SPEC_DRAFTED.inc(drafted, tier=tier)
+        _C_SPEC_ACCEPTED.inc(accepted, tier=tier)
+        self._meter.note_spec(tier, drafted, accepted)
+
+    def _pen_args(self) -> dict:
+        """The penalty operands of a verify call, where some live row is
+        penalised (_counts_ride): its counts ride the call and it advances
+        its normal one penalty-sampled token a step. Else empty: the
+        counts-free trace."""
+        if not self._counts_ride():
+            return {}
+        return dict(counts=self._counts, reps=self._reps,
+                    press=self._press, freqs=self._freqs)
+
+    def _note_turn(self, t0: float):
+        """A window dispatched at ``t0`` into an empty ring: the chip stood
+        still from the last fetch to here, for the bursts placed meanwhile
+        and the host's turn (what the window policy weighs)."""
+        if self._t_fetched is not None and len(self._inflight) == 1:
+            self._turn_s.note(t0 - self._t_fetched - self._admit_since)
+        self._admit_since = 0.0
 
     def _set_fill_gauges(self):
         """Batch utilization snapshot before a device step: how full the
@@ -2503,10 +2653,15 @@ class BatchScheduler:
         delivers under them.
         With speculation enabled, a turn where some greedy row drafted
         becomes ONE serialized [B, K+1] verify call instead (_spec_step
-        — the drafter needs each verdict before proposing again, so spec
-        steps never ride the ring)."""
-        if (not self._inflight and self._spec is not None
-                and self._spec_step()):
+        — a drafter on the HOST needs each verdict before proposing again,
+        so its spec steps never ride the ring). Where the model drafts for
+        itself (engine.mtp_on) nothing of a step's input is made on the
+        host, and the turn dispatches a verify WINDOW
+        (_dispatch_verify_window) that takes the road below: fetched,
+        settled and delivered once, as a decode window."""
+        if not self._inflight and self._spec is not None and (
+                self._dispatch_verify_window() if self.engine.mtp_on
+                else self._spec_step()) and not self._inflight:
             return
         # fill the ring: the first window dispatches unconditionally;
         # look-ahead windows pass the _overlap_ready gate
@@ -2525,7 +2680,7 @@ class BatchScheduler:
         # with rec's tokens on the host, top the ring back up before doing
         # any host-side token work (rec's tokens count toward pending —
         # they are not in out_ids yet)
-        while len(self._inflight) < RING_DEPTH:
+        while len(self._inflight) < RING_DEPTH and not rec.get("verify"):
             pending = sum(r["n"] for r in self._inflight) + rec["n"]
             chosen = self._overlap_ready(pending)
             if chosen is None or not self._dispatch_window(pending, chosen):
@@ -2663,12 +2818,7 @@ class BatchScheduler:
             ],
             "t0": time.perf_counter(),
         })
-        if self._t_fetched is not None and len(self._inflight) == 1:
-            # the chip stood still from the last fetch to here: the bursts
-            # placed meanwhile, and the host's turn
-            self._turn_s.note(self._inflight[0]["t0"] - self._t_fetched
-                              - self._admit_since)
-        self._admit_since = 0.0
+        self._note_turn(self._inflight[0]["t0"])
         self._moe_pending = []
         self._offsets = self._offsets + np.int32(n)
         self.stats.chunks += len(toks_parts)
@@ -2687,6 +2837,10 @@ class BatchScheduler:
         Everything here reads post-in-flight offsets (_dispatch_window
         advances them at dispatch)."""
         if self.active == 0:
+            return None
+        # a verify window's rows advance by what it accepts: the host's
+        # offsets come from its fetch, so nothing is stacked on it
+        if self._inflight and self._inflight[-1].get("verify"):
             return None
         # queued/checkpoint work needs settled rows at the next sync;
         # streaming rows need token flushes at chunk cadence, not
@@ -2720,26 +2874,34 @@ class BatchScheduler:
         return chosen if self.cache.growth_fits(growth) else None
 
     @_phase("fetch")
-    def _fetch_window(self, rec) -> np.ndarray:
+    def _fetch_window(self, rec):
         """THE host sync of the decode hot loop: block on one in-flight
-        window's token buffers. Everything else the step needs came back
-        with earlier fetches or never left the host."""
+        window's token buffers -> its tokens, [B, n] (a verify window's: a
+        row's array each, _verify_rows). Everything else the step needs came
+        back with earlier fetches or never left the host."""
         _G_OVERLAP.set(len(self._inflight))
         _C_HOST_SYNCS.inc()
+        verify = rec.get("verify", False)
+        # a verify window's verdicts, last draft and offsets ride the fetch
+        more = [rec[k] for k in ("acc", "draft", "off")] if verify else []
         with get_tracer().span(
-            "engine.decode_window",
+            "engine.spec_verify" if verify else "engine.decode_window",
             active=len(rec["rows"]), chunks=len(rec["toks"]), steps=rec["n"],
             inflight=len(self._inflight),
         ):
             # (an expert model's counters ride the same fetch: rec["moe"])
-            parts = [np.asarray(x) for x in jax.device_get(rec["toks"] + rec.get("moe", []))]  # meshlint: ignore[ML-J003] -- the one sanctioned sync per readback window (docs/PERF.md)
-        parts, moe = parts[:len(rec["toks"])], parts[len(rec["toks"]):]
+            got = [np.asarray(x) for x in jax.device_get(rec["toks"] + rec.get("moe", []) + more)]  # meshlint: ignore[ML-J003] -- the one sanctioned sync per readback window (docs/PERF.md)
+        k, m = len(rec["toks"]), len(got) - len(more)
+        parts, moe, more = got[:k], got[k:m], got[m:]
         if moe:
             self._note_moe(moe, windows=len(rec["toks"]))
-        # [B, n]: a chunk's buffer is decode_chunk wide, its steps come first
-        # (a window's chunks are full but the last)
-        toks_host = np.concatenate(parts, axis=1)[:, :rec["n"]]
-        if not self._inflight:
+        if verify:
+            toks_host = self._verify_rows(rec, parts[0], *more)
+        else:
+            # [B, n]: a chunk's buffer is decode_chunk wide, its steps come
+            # first (a window's chunks are full but the last)
+            toks_host = np.concatenate(parts, axis=1)[:, :rec["n"]]
+        if not verify and not self._inflight:
             # ring drained: the host mirror of the latest sampled token
             # is this window's last column (mid-ring fetches skip this —
             # a NEWER window is already chained off the device value)
@@ -2795,12 +2957,18 @@ class BatchScheduler:
         dispatch are skipped — their overshoot tokens are scheduled-only
         work the goodput meter already books as waste."""
         window = []
+        verify = rec.get("verify", False)
         for b, req in rec["rows"]:
             if self._rows[b] is not req or req.done:
                 continue
-            req.chunks_decoded += len(rec["toks"])
+            req.chunks_decoded += rec["n"] if verify else len(rec["toks"])
             window.append(self._settle_row(b, req, toks_host[b]))
-        rows, steps = toks_host.shape
+            if verify:
+                self._book_verify_row(rec, b, window[-1])
+        if verify:  # a step's K + 1 slots a row
+            rows, steps = len(toks_host), rec["slots"]
+        else:
+            rows, steps = toks_host.shape
         self._meter.note_slots(rows, len(rec["rows"]), steps,
                                sum(len(entry[1]) for entry in window))
         # the ended rows' callers first: what they send next fills the rows

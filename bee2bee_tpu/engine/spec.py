@@ -203,9 +203,10 @@ class NgramDrafter(Drafter):
 class MtpDrafter(Drafter):
     """Tier "mtp": the drafts are made on the device by the target's own
     multi-token-prediction layer, inside the prefill and verify programs
-    (engine._prefill_fn / _spec_verify_fn, ``mtp_draft``); the scheduler
-    notes each on its Request as (context length it was made at, token), and
-    this object only hands it over. A row whose context moved on without a
+    (engine._prefill_fn / _verify_step, ``mtp_draft``); the scheduler notes
+    the last on its Request as (context length it was made at, token), and
+    this object only hands it over: to the FIRST step of a verify window
+    (engine._spec_window_fn feeds the later steps' on the chip). A row whose context moved on without a
     verify step (a plain decode window ran: its MTP rows for those positions
     were never written) has no draft any more: [] — the scheduler retires it
     from the tier."""

@@ -72,6 +72,27 @@ def _echo_weights(kind: str):
                 dense_layers=quiet(p["dense_layers"], alpha), mtp=mtp)
 
 
+def _spy_on_the_verify_steps(eng, seen: list):
+    """Every verify step the engine runs from here on, read out of its verify
+    windows' own buffers: (cur, drafts, draft lengths, offsets) of the rows at
+    each step, as the serialized step's arguments were."""
+    window = eng._spec_window
+
+    def spy(params, cur, draft, drafting, budget, pool, offsets, *rest, steps, **kw):
+        cur, start = np.asarray(cur).copy(), np.asarray(offsets).copy()
+        drafting, budget = np.asarray(drafting).copy(), np.asarray(budget).copy()
+        out = window(params, cur, draft, drafting, budget, pool, offsets, *rest,
+                     steps=steps, **kw)
+        toks, accs, off = np.asarray(out[4]), np.asarray(out[5]), start
+        for i in range(int(steps)):
+            lens = np.where(drafting > 0, np.clip(budget - (off - start) - 1, 0, 1), 0)
+            seen.append((cur, toks[i, :, :-1], lens, off))
+            cur, off = toks[i, :, -1], off + accs[i] + 1
+        return out
+
+    eng._spec_window = spy
+
+
 @pytest.mark.parametrize("kind", ["accepted", "rejected", "mixed"])
 def test_speculation_changes_nothing(kind):
     """Greedy text with the ``mtp`` tier on equals the text with it off, token
@@ -126,14 +147,7 @@ def test_the_engines_drafts_are_the_references_mtp_argmax_and_its_text_the_trunk
     the reference trunk's greedy text."""
     eng = _engine(spec_tokens=1)
     seen = []
-    verify = eng._spec_verify
-
-    def spy(params, cur, drafts, lens, pool, offsets, *rest, **kw):
-        seen.append((np.asarray(cur).copy(), np.asarray(drafts).copy(),
-                     np.asarray(lens).copy(), np.asarray(offsets).copy()))
-        return verify(params, cur, drafts, lens, pool, offsets, *rest, **kw)
-
-    eng._spec_verify = spy
+    _spy_on_the_verify_steps(eng, seen)
     try:
         full = jax.tree.map(jnp.asarray, core.restack_layers(eng.params))
         dims = _dims(eng.model_cfg)
@@ -142,6 +156,8 @@ def test_the_engines_drafts_are_the_references_mtp_argmax_and_its_text_the_trunk
         whole = np.asarray([ids + got], np.int32)
         ref, ref_mtp = plain.full_forward(dims, full, whole)
         assert got == [int(t) for t in ref[0, len(ids) - 1:-1].argmax(-1)]
+        # (a window's steps past the row's last token made nothing that was kept)
+        seen = [step for step in seen if step[3][0] < whole.shape[1]]
         assert len(seen) >= 10
         for cur, drafts, lens, offsets in seen:
             p = int(offsets[0])  # ``cur`` sits at p: the draft is of token p + 1
@@ -160,16 +176,10 @@ def test_chunked_prefill_hands_the_mtp_layer_the_prompts_next_token():
     def drafts_of(**over):
         eng = _engine(spec_tokens=1, **over)
         seen = []
-        verify = eng._spec_verify
-
-        def spy(params, cur, drafts, *rest, **kw):
-            seen.append(int(np.asarray(drafts)[0, 0]))
-            return verify(params, cur, drafts, *rest, **kw)
-
-        eng._spec_verify = spy
+        _spy_on_the_verify_steps(eng, seen)
         try:
             text = eng.generate(_prompt(4, 41), max_new_tokens=8).token_ids
-            return text, seen
+            return text, [int(drafts[0, 0]) for _, drafts, _, _ in seen]
         finally:
             eng.close()
 
@@ -251,3 +261,125 @@ def test_counters_count_the_verify_steps_the_tier_and_the_share():
         assert 0 < hit.value() - was[1] <= forwards * 5 * 4
     finally:
         eng.close()
+
+
+# ------------------------------------------------------------ the verify window
+
+
+def _programs_start(eng, pen: bool):
+    """Four rows prefilled into a fresh pool by the engine's own program -> the
+    operands of a verify step: row 0 greedy with its first draft FORCED accepted
+    (the program's own next token), row 1 greedy with a budget that ends inside
+    the window, row 2 sampled, row 3 sampled and, with ``pen``, penalised (its
+    counts ride)."""
+    R, BS, V = 4, eng.engine_cfg.kv_block_size, eng.model_cfg.vocab_size
+    lengths = np.asarray([21, 9, 30, 13], np.int32)
+    pages = -(-(int(lengths.max()) + 2 * eng.engine_cfg.decode_chunk + 2) // BS)
+    tables = np.zeros((R, 1 << (pages - 1).bit_length()), np.int32)
+    tables[:, :pages] = 1 + np.random.default_rng(0).permutation(R * pages).reshape(R, pages)
+    tok = np.zeros((R, 32), np.int32)
+    for r, n in enumerate(lengths):
+        tok[r, :n] = _prompt(r, int(n))
+    zero = np.zeros((R,), np.int32)
+    pool, logits, extras = eng._prefill(
+        eng.params, tok, eng.new_pool(), lengths, zero, tables, zero, lengths,
+        mtp_next=np.full((R,), -1, np.int32))
+    cur = np.asarray(logits).argmax(-1).astype(np.int32)
+    draft = np.asarray(extras["mtp_draft"]).astype(np.int32)
+    sampling = (np.asarray([0, 0, 0.8, 0.7], np.float32), np.asarray([0, 0, 20, 0], np.int32),
+                np.asarray([1, 1, 1, 0.9], np.float32))
+    more = {}
+    if pen:
+        counts = np.zeros((R, 2, V), np.int32)
+        for r, n in enumerate(lengths):
+            counts[r, 0] = np.bincount(tok[r, :n], minlength=V)
+        more = dict(counts=jnp.asarray(counts), reps=np.asarray([1, 1, 1, 1.3], np.float32),
+                    press=np.asarray([0, 0, 0, 0.2], np.float32),
+                    freqs=np.asarray([0, 0, 0, 0.1], np.float32))
+    return dict(pool=pool, tables=tables, cur=cur, draft=draft, offsets=lengths.copy(),
+                sampling=sampling, more=more,
+                drafting=np.asarray([1, 1, 0, 0], np.int32),
+                budget=np.asarray([40, 3, 40, 40], np.int32))
+
+
+def _pool_rows(pool, tables, r: int, upto: int):
+    """Row r's cached positions below ``upto``, every cache layer (the MTP
+    block's is the last): [L, upto, 2, Hkv, hd]."""
+    kv = np.asarray(pool["kv"])
+    BS = kv.shape[4]
+    at = np.arange(upto)
+    return kv[:, tables[r, at // BS], :, :, at % BS, :]
+
+
+@pytest.fixture(scope="module")
+def window_engine():
+    eng = _engine(params=jax.tree.map(jnp.asarray, _echo_weights("mixed")), spec_tokens=1)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("pen", [False, True], ids=["plain", "counts"])
+@pytest.mark.parametrize("n", [1, 3, 4])
+def test_a_verify_window_is_its_steps_chained_by_hand(window_engine, n, pen):
+    """A window of n steps (1, 3, decode_chunk) computes what n _spec_verify
+    calls chained on the host do with the same keys: the tokens, the verdicts,
+    the final cur / draft / offsets / counts, the pool's rows below every row's
+    offset in every cache layer and the MTP layer's."""
+    eng = window_engine
+    N, key = eng.engine_cfg.decode_chunk, jax.random.key(7)
+    keys = jax.random.split(key, N)
+    # the program's own next token of row 0: its first draft is then accepted
+    s = _programs_start(eng, pen)
+    own, *_ = eng._spec_verify(
+        eng.params, s["cur"], s["draft"][:, None], np.zeros((4,), np.int32), s["pool"],
+        s["offsets"], *s["sampling"], None, keys[0], s["tables"], **s["more"])
+    forced = int(np.asarray(own)[0])
+
+    # by hand: today's serialized steps, the draft carried by the host
+    s = _programs_start(eng, pen)
+    s["draft"][0] = forced
+    pool, cur, draft, off, more = s["pool"], s["cur"], s["draft"].copy(), s["offsets"], dict(s["more"])
+    toks, accs = [], []
+    for i in range(n):
+        left = s["budget"] - (off - s["offsets"])
+        lens = (s["drafting"] * np.clip(left - 1, 0, 1)).astype(np.int32)
+        nxt, pool, acc, extras, *cnt = eng._spec_verify(
+            eng.params, cur, draft[:, None], lens, pool, off, *s["sampling"], None,
+            keys[i], s["tables"], **more)
+        if pen:
+            more["counts"] = cnt[0]
+        nxt, acc = np.asarray(nxt), np.asarray(acc)
+        toks.append(np.stack([draft, nxt], axis=1))
+        accs.append(acc)
+        cur, draft, off = nxt, np.asarray(extras["mtp_draft"]), off + acc + 1
+    toks, accs = np.stack(toks), np.stack(accs)
+    assert accs[0, 0] == 1  # the forced draft
+    assert (accs[:, 2:] == 0).all()  # sampled rows never draft
+    if n >= 3:
+        assert accs[:, :2].sum() < accs[:, :2].size  # ... and some draft is rejected
+        # row 1's budget is 3 tokens: it stops drafting once 2 are given
+        given = np.cumsum(accs[:, 1] + 1) - (accs[:, 1] + 1)
+        assert (accs[given >= 2, 1] == 0).all()
+
+    # the window: the same steps, the draft fed back on the device
+    w = _programs_start(eng, pen)
+    w["draft"][0] = forced
+    wcur, wpool, woff, wcnt, wtoks, waccs, wdraft, extras = eng._spec_window(
+        eng.params, w["cur"], w["draft"][:, None], w["drafting"], w["budget"], w["pool"],
+        w["offsets"], *w["sampling"], None, key, w["tables"], **w["more"],
+        steps=np.int32(n))
+    assert np.asarray(wtoks).shape == (N, 4, 2) and np.asarray(waccs).shape == (N, 4)
+    np.testing.assert_array_equal(np.asarray(wtoks)[:n], toks)
+    np.testing.assert_array_equal(np.asarray(waccs)[:n], accs)
+    np.testing.assert_array_equal(np.asarray(wcur), cur)
+    np.testing.assert_array_equal(np.asarray(wdraft)[:, 0], draft)
+    np.testing.assert_array_equal(np.asarray(woff), off)
+    assert (wcnt is None) == (not pen)
+    if pen:
+        np.testing.assert_array_equal(np.asarray(wcnt), np.asarray(more["counts"]))
+        assert int(np.asarray(wcnt)[3, 1].sum()) == n  # a token a step, generated
+    assert extras["moe_stats"].shape == (len(core.moe_stats_names(eng.model_cfg)),)
+    for r in range(4):
+        np.testing.assert_allclose(
+            _pool_rows(wpool, w["tables"], r, int(off[r])),
+            _pool_rows(pool, s["tables"], r, int(off[r])), rtol=0, atol=0)

@@ -906,7 +906,9 @@ def _exaone_engine_program(fn_name: str, B: int, T: int, MB: int, one_chip):
     cfg = EXAONE
     attn = make_ragged_attn_fn(None, interpret=False)
     stand_in = SimpleNamespace(model_cfg=cfg, mtp_on=True, dtype=jnp.bfloat16,
-                               _attn_fn=lambda: attn)
+                               _attn_fn=lambda: attn,
+                               engine_cfg=SimpleNamespace(decode_chunk=32))
+    stand_in._verify_step = lambda *a: InferenceEngine._verify_step(stand_in, *a)
 
     def place(tree):
         return jax.tree.map(
@@ -924,6 +926,12 @@ def _exaone_engine_program(fn_name: str, B: int, T: int, MB: int, one_chip):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     fn = getattr(InferenceEngine, fn_name)
+    if fn_name == "_spec_window_fn":  # [cur | draft], who drafts, the budgets; steps
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        return jax.jit(
+            lambda *a: fn(stand_in, *a[:-1], steps=a[-1]), donate_argnums=(5,)
+        ).lower(params, ints(B), ints(B, T - 1), ints(B), ints(B), pool, ints(B),
+                floats(B), ints(B), floats(B), None, key, ints(B, MB), ints())
     if fn_name == "_spec_verify_fn":
         key = jax.eval_shape(lambda: jax.random.key(0))
         return jax.jit(
@@ -939,8 +947,10 @@ def _exaone_engine_program(fn_name: str, B: int, T: int, MB: int, one_chip):
 
 
 @pytest.mark.parametrize("fn,B,T,MB", [
-    ("_spec_verify_fn", 64, 2, 64), ("_prefill_fn", 8, 128, 8), ("_prefill_fn", 1, 512, 32)],
-    ids=["exaone-verify-64", "exaone-prefill-8x128", "exaone-prefill-512"])
+    ("_spec_verify_fn", 64, 2, 64), ("_prefill_fn", 8, 128, 8), ("_prefill_fn", 1, 512, 32),
+    ("_spec_window_fn", 64, 2, 64)],
+    ids=["exaone-verify-64", "exaone-prefill-8x128", "exaone-prefill-512",
+         "exaone-verify-window-64"])
 def test_the_exaone_cell_programs_hold_the_mtp_layer_and_fit_the_chip(
         one_chip, mosaic_grouped, fn, B, T, MB):
     """The cut preset as the cell serves it (3,200 pool blocks, 64 rows): the
@@ -954,7 +964,7 @@ def test_the_exaone_cell_programs_hold_the_mtp_layer_and_fit_the_chip(
     scopes = ["attn.qkv", "attn.read", "attn.out", "moe.router", "moe.dispatch",
               "moe.experts", "moe.combine", "moe.shared", "mtp.proj",
               "mtp.block/attn.read", "mtp.block/moe.experts", "mtp.head"]
-    if fn == "_spec_verify_fn":
+    if fn != "_prefill_fn":
         scopes += ["spec.verify", "spec.accept"]
     for scope in scopes:
         assert re.search(rf'op_name="[^"]*{re.escape(scope)}', text), scope
