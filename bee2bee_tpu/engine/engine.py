@@ -846,7 +846,8 @@ class InferenceEngine:
             extras["mtp_draft"] = draft
         if not one_logit:
             idx = last.reshape(-1, 1, 1)  # [B,1,1]
-            logits = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
+            with jax.named_scope("head.logits"):  # the head's one position a row
+                logits = jnp.take_along_axis(logits, jnp.broadcast_to(idx, (logits.shape[0], 1, logits.shape[2])), axis=1)
         if extras:
             return cache, logits[:, 0, :], extras
         return cache, logits[:, 0, :]
@@ -915,14 +916,14 @@ class InferenceEngine:
         B, K = drafts.shape
         own = self.mtp_on
         tokens = jnp.concatenate([cur[:, None], drafts], axis=1)  # [B, K+1]
-        with core._scope_if(own)("spec.verify"):
+        with jax.named_scope("spec.verify"):
             logits, cache, *hidden = core.forward(
                 params, self.model_cfg, tokens, cache, offsets,
                 attn_fn=self._attn_fn(), block_tables=tables,
                 adapters=adapters, adapter_ids=aids, adapter_scales=ascales,
                 **({"return_hidden": True} if own else {}),
             )
-        with core._scope_if(own)("spec.accept"):
+        with jax.named_scope("spec.accept"):
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K+1]
             pos = jnp.arange(K, dtype=jnp.int32)[None, :]
             match = (drafts == greedy[:, :-1]) & (pos < draft_lens[:, None])

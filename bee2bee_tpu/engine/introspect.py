@@ -86,6 +86,11 @@ _C_RETRACE_STORMS = _REG.counter(
     "steady-state retraces detected per root (undeclared shapes / "
     "repeat-key compile storms)",
 )
+_C_KEY_ERRORS = _REG.counter(
+    "engine.compile_key_errors",
+    "compiles of a root whose key function raised: counted un-keyed, "
+    "never classified",
+)
 _G_MFU = _REG.gauge(
     "engine.mfu",
     "model FLOP/s over platform peak FLOP/s, trailing window (0..1)",
@@ -278,7 +283,7 @@ def _wire_monitoring_listener() -> None:
 class _Root:
     __slots__ = (
         "name", "allowed", "seen", "traces", "last_cache_size",
-        "repeat_ts", "storms", "last_storm_ts",
+        "repeat_ts", "storms", "last_storm_ts", "key_errors",
     )
 
     def __init__(self, name: str, allowed: Callable | None):
@@ -293,6 +298,7 @@ class _Root:
         self.repeat_ts: dict = {}
         self.storms = 0
         self.last_storm_ts = 0.0
+        self.key_errors = 0  # compiles whose key function raised
 
 
 class RetraceSentinel:
@@ -392,7 +398,18 @@ class RetraceSentinel:
                 try:
                     key = key_fn(*args, **kwargs)
                 except Exception:  # noqa: BLE001
+                    # the root runs UN-KEYED from here (counted, never
+                    # classified): say so, once a root, and count each
                     key = None
+                    _C_KEY_ERRORS.inc(root=root.name)
+                    with self._lock:
+                        root.key_errors += 1
+                        first = root.key_errors == 1
+                    if first:
+                        logger.warning(
+                            "compile: the key function of root=%s raised; "
+                            "its compiles are counted but not classified",
+                            root.name, exc_info=True)
             # every counted compile says which shape: /trace?name=
             # engine.compile lists what compiled and when, the log line
             # puts it beside the server's other events

@@ -79,6 +79,7 @@ def apply_penalties(
     return logits
 
 
+@jax.named_scope("sample.draw")  # tracing.DEVICE_PARTS: the sampler's ops carry the part
 def sample_batched(
     logits,  # [B, V] float32
     key,

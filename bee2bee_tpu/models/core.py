@@ -21,7 +21,6 @@ Partition rules over the same paths live in partition.py.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 from typing import Any
@@ -657,8 +656,7 @@ def expert_einsum(spec, x, w, s_expand):
 
 
 def _mlp(x, p, cfg: ModelConfig, lora=None):
-    scope = _scope_if(_stack_scoped(cfg))  # mlp.gate_up / mlp.down
-    with scope("mlp.gate_up"):
+    with jax.named_scope("mlp.gate_up"):
         up = lora_matmul(x, p["w_up"], "w_up", lora)
         if "b_up" in p:
             up = up + p["b_up"]
@@ -667,12 +665,12 @@ def _mlp(x, p, cfg: ModelConfig, lora=None):
         if gate is not None and gate_mult != 1.0:
             gate = gate * jnp.asarray(gate_mult, gate.dtype)
         h = _activate(up, gate, cfg)
-    with scope("mlp.down"):
+    with jax.named_scope("mlp.down"):
         out = lora_matmul(h, p["w_down"], "w_down", lora)
-    if "b_down" in p:
-        out = out + p["b_down"]
-    if down_mult != 1.0:
-        out = out * jnp.asarray(down_mult, out.dtype)
+        if "b_down" in p:
+            out = out + p["b_down"]
+        if down_mult != 1.0:
+            out = out * jnp.asarray(down_mult, out.dtype)
     return out
 
 
@@ -1157,10 +1155,11 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
         with jax.named_scope("moe.shared"):
             out = out + _mlp(xf, p["shared"], cfg).astype(jnp.float32)
 
-    stats = jnp.stack(
-        [jnp.sum(gs > 0), jnp.max(gs), n_live]
-        + ([] if elsewhere is None else [elsewhere])).astype(jnp.int32)
-    return out.astype(x.dtype).reshape(B, T, D), stats
+    with jax.named_scope("moe.combine"):
+        stats = jnp.stack(
+            [jnp.sum(gs > 0), jnp.max(gs), n_live]
+            + ([] if elsewhere is None else [elsewhere])).astype(jnp.int32)
+        return out.astype(x.dtype).reshape(B, T, D), stats
 
 
 # ------------------------------------------- latent attention (MLA)
@@ -1387,14 +1386,16 @@ def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None,
                 lambda e, n: lax.dynamic_slice_in_dim(e, n, K - 1, axis=0)
             )(ext, valid_len)
 
-    x = xbc_c[..., :inner].reshape(B, T, G, Hg, P)
-    Bm = xbc_c[..., inner:inner + G * N].reshape(B, T, G, N)
-    Cm = xbc_c[..., inner + G * N:].reshape(B, T, G, N)
-    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"]).reshape(B, T, G, Hg)
-    if valid_len is not None:
-        real = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
-        dt = jnp.where(real[..., None, None], dt, 0.0)
-    A = -jnp.exp(p["A_log"].astype(f32)).reshape(G, Hg)
+    with jax.named_scope("ssm.conv"):  # the conv's three zones
+        x = xbc_c[..., :inner].reshape(B, T, G, Hg, P)
+        Bm = xbc_c[..., inner:inner + G * N].reshape(B, T, G, N)
+        Cm = xbc_c[..., inner + G * N:].reshape(B, T, G, N)
+    with jax.named_scope("ssm.in_proj"):  # the projection's dt zone, and A
+        dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"]).reshape(B, T, G, Hg)
+        if valid_len is not None:
+            real = jnp.arange(T, dtype=jnp.int32)[None, :] < valid_len[:, None]
+            dt = jnp.where(real[..., None, None], dt, 0.0)
+        A = -jnp.exp(p["A_log"].astype(f32)).reshape(G, Hg)
     stacked = layer is not None
 
     if T == 1 and state is not None:
@@ -1451,15 +1452,16 @@ def ssm_mixer(p: Params, cfg: ModelConfig, u, state=None, valid_len=None,
 
 def embed_tokens(params: Params, cfg: ModelConfig, input_ids, positions):
     """Token (+learned-pos) embedding. input_ids [B,T], positions [B,T]."""
-    x = jnp.take(params["tok_embed"], input_ids, axis=0)
-    if cfg.embedding_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    if cfg.embedding_multiplier != 1.0:  # falcon-h1
-        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-    if cfg.pos_embedding == "learned":
-        x = x + jnp.take(params["pos_embed"], positions, axis=0)
-    if cfg.embedding_norm:  # bloom: LayerNorm before block 0
-        x = _norm(x, params["embed_norm"], cfg)
+    with jax.named_scope("embed.tokens"):
+        x = jnp.take(params["tok_embed"], input_ids, axis=0)
+        if cfg.embedding_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.embedding_multiplier != 1.0:  # falcon-h1
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        if cfg.pos_embedding == "learned":
+            x = x + jnp.take(params["pos_embed"], positions, axis=0)
+        if cfg.embedding_norm:  # bloom: LayerNorm before block 0
+            x = _norm(x, params["embed_norm"], cfg)
     return x
 
 
@@ -1514,9 +1516,13 @@ def transformer_block(
     "attn_norm". ``rope_local`` (the traced is-sliding flag of this layer,
     layer_rope_flag) also decides WHETHER a layer rotates under
     cfg.rope_sliding_only: the full layers carry no positional encoding.
-    The parts of a dropless-expert model's plain attention run under the
-    scopes ``attn.qkv`` / ``attn.rope`` / ``attn.write`` / ``attn.read`` /
-    ``attn.out`` (the dense block's carry none).
+
+    Every model's block runs under the parts of tracing.DEVICE_PARTS
+    (``norm.block``, ``attn.qkv`` / ``attn.rope`` / ``attn.write`` /
+    ``attn.read`` / ``attn.out``, ``mlp.*`` or ``moe.*``, ``mla.*``,
+    ``ssm.*``): a small op between two products (a bias, a multiplier, the
+    residual add) sits under the part whose result it finishes, for a device
+    capture books a fusion by its root instruction's scope.
 
     Under cfg.layer_types (granite-4.0-h) the layer's tree holds ONE mixer:
     ``"ssm"`` (the mixer's output is the whole branch: no attention runs,
@@ -1525,51 +1531,72 @@ def transformer_block(
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    scope = _scope_if(_attn_scoped(cfg))
 
-    h = x if cfg.no_pre_norms else _norm(x, lp["ln1"], cfg)
+    def norm(a, name):
+        with jax.named_scope("norm.block"):
+            return _norm(a, lp[name], cfg)
+
+    def join(x, branch, part, post=None, scale=True):
+        """``x`` + a branch's output, under the part that finishes the branch
+        (its post norm's where it has one: ``post``). ``scale``: the branch
+        times cfg.residual_multiplier (a latent-attention block adds bare)."""
+        if post is not None:
+            part, branch = "norm.block", norm(branch, post)
+        with jax.named_scope(part):
+            return x + (_residual(branch, cfg) if scale else branch)
+
+    ffn_part = ("mlp.down" if "moe" not in lp
+                else "moe.combine" if cfg.moe_dropless else "moe.experts")
+
+    h = x if cfg.no_pre_norms else norm(x, "ln1")
     if cfg.has_mla:
-        x = x + _mla_attention(
-            lp["attn"], cfg, h, positions, mask, kv_hook, attn_fn)
-        return x + _ffn(_norm(x, lp["ln2"], cfg), lp, cfg, lora, moe_kw,
-                        moe_sink)
+        x = join(x, _mla_attention(
+            lp["attn"], cfg, h, positions, mask, kv_hook, attn_fn), "mla.out",
+            scale=False)
+        return join(x, _ffn(norm(x, "ln2"), lp, cfg, lora, moe_kw, moe_sink),
+                    ffn_part, scale=False)
     if cfg.layer_types and "ssm" in lp:  # a recurrent-only layer
         mix_out = (ssm_hook(h) if ssm_hook is not None
                    else ssm_mixer(lp["ssm"], cfg, h)[0])
-        x = x + _residual(mix_out, cfg)
-        return x + _residual(
-            _ffn(_norm(x, lp["ln2"], cfg), lp, cfg, lora, moe_kw, moe_sink),
-            cfg)
+        x = join(x, mix_out, "ssm.out_proj")
+        return join(x, _ffn(norm(x, "ln2"), lp, cfg, lora, moe_kw, moe_sink),
+                    ffn_part)
     mix_out = None
     if cfg.has_ssm and not cfg.layer_types:  # beside attention (falcon-h1)
         mix_out = (ssm_hook(h) if ssm_hook is not None
                    else ssm_mixer(lp["ssm"], cfg, h)[0])
-        mix_out = mix_out * jnp.asarray(cfg.ssm_out_multiplier, mix_out.dtype)
+        with jax.named_scope("ssm.out_proj"):
+            mix_out = mix_out * jnp.asarray(
+                cfg.ssm_out_multiplier, mix_out.dtype)
         if cfg.attention_in_multiplier != 1.0:
-            h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
-    with scope("attn.qkv"):
+            with jax.named_scope("attn.qkv"):
+                h = h * jnp.asarray(cfg.attention_in_multiplier, h.dtype)
+    with jax.named_scope("attn.qkv"):
         q = lora_matmul(h, lp["attn"]["wq"], "wq", lora)
         k = lora_matmul(h, lp["attn"]["wk"], "wk", lora)
         v = lora_matmul(h, lp["attn"]["wv"], "wv", lora)
         if B * T <= QKV_IN_PLACE_ROWS:
             q, k, v = lax.optimization_barrier((q, k, v))
-    if "bq" in lp["attn"]:
-        q = q + lp["attn"]["bq"]
-        k = k + lp["attn"]["bk"]
-        v = v + lp["attn"]["bv"]
+        if "bq" in lp["attn"]:
+            q = q + lp["attn"]["bq"]
+            k = k + lp["attn"]["bk"]
+            v = v + lp["attn"]["bv"]
     if "q_norm" in lp["attn"] and cfg.qk_norm_full:
         # olmo2: RMSNorm over the WHOLE projection width, before reshape
-        q = _qk_rmsnorm(q, lp["attn"]["q_norm"], cfg.norm_eps)
-        k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
+        with jax.named_scope("norm.block"):
+            q = _qk_rmsnorm(q, lp["attn"]["q_norm"], cfg.norm_eps)
+            k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, Hkv, hd)
     v = v.reshape(B, T, Hkv, hd)
     if "q_norm" in lp["attn"] and not cfg.qk_norm_full:
         # qwen3/gemma3: head-wise RMSNorm BEFORE rope
-        q = _qk_rmsnorm(q, lp["attn"]["q_norm"], cfg.norm_eps)
-        k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
+        with jax.named_scope("norm.block"):
+            q = _qk_rmsnorm(q, lp["attn"]["q_norm"], cfg.norm_eps)
+            k = _qk_rmsnorm(k, lp["attn"]["k_norm"], cfg.norm_eps)
     if cfg.key_multiplier != 1.0:  # falcon-h1: k scaled BEFORE the rotation
-        k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
+        with jax.named_scope("attn.qkv"):
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
     if cfg.rope_sliding_only:
         if rope_local is None:
             raise ValueError(
@@ -1577,61 +1604,63 @@ def transformer_block(
                 "hands transformer_block no per-layer flag (layer_rope_flag), "
                 "so its full layers would be rotated too"
             )
-        with scope("attn.rope"):
+        with jax.named_scope("attn.rope"):
             q, k = (jnp.where(rope_local, _rope(
                 a, positions, cfg.rope_theta, cfg.rotary_dim, cfg.rope_style,
                 None), a) for a in (q, k))
     elif cfg.pos_embedding == "rope":
-        if cfg.local_rope_theta is not None and rope_local is not None:
-            # gemma-3: SLIDING layers rotate with the local theta and no
-            # scaling; global layers use rope_theta + rope_scaling.
-            # rope_local is the (traced) is-sliding flag for this layer
-            def rot2(v):
-                g_ = _rope(v, positions, cfg.rope_theta, cfg.rotary_dim,
-                           cfg.rope_style, cfg.rope_scaling)
-                l_ = _rope(v, positions, cfg.local_rope_theta,
-                           cfg.rotary_dim, cfg.rope_style, None)
-                return jnp.where(rope_local, l_, g_)
+        with jax.named_scope("attn.rope"):
+            if cfg.local_rope_theta is not None and rope_local is not None:
+                # gemma-3: SLIDING layers rotate with the local theta and no
+                # scaling; global layers use rope_theta + rope_scaling.
+                # rope_local is the (traced) is-sliding flag for this layer
+                def rot2(v):
+                    g_ = _rope(v, positions, cfg.rope_theta, cfg.rotary_dim,
+                               cfg.rope_style, cfg.rope_scaling)
+                    l_ = _rope(v, positions, cfg.local_rope_theta,
+                               cfg.rotary_dim, cfg.rope_style, None)
+                    return jnp.where(rope_local, l_, g_)
 
-            q, k = rot2(q), rot2(k)
-        else:
-            q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim,
-                      cfg.rope_style, cfg.rope_scaling)
-            k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim,
-                      cfg.rope_style, cfg.rope_scaling)
+                q, k = rot2(q), rot2(k)
+            else:
+                q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim,
+                          cfg.rope_style, cfg.rope_scaling)
+                k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim,
+                          cfg.rope_style, cfg.rope_scaling)
     if kv_hook is not None:
-        with scope("attn.write"):
+        with jax.named_scope("attn.write"):
             k, v = kv_hook(k, v)
-    with scope("attn.read"):
+    with jax.named_scope("attn.read"):
         if attn_fn is None:
             attn_out = _attention(q, k, v, mask, cfg)
         else:
             attn_out = attn_fn(q, k, v, mask, cfg, positions=positions)
-    with scope("attn.out"):
+    with jax.named_scope("attn.out"):
         attn_out = lora_matmul(attn_out, lp["attn"]["wo"], "wo", lora)
-    if "bo" in lp["attn"]:
-        attn_out = attn_out + lp["attn"]["bo"]
-    if mix_out is not None:
-        # two kinds of token mixer, one residual add
-        attn_out = mix_out + attn_out * jnp.asarray(
-            cfg.attention_out_multiplier, attn_out.dtype)
+        if "bo" in lp["attn"]:
+            attn_out = attn_out + lp["attn"]["bo"]
+        if mix_out is not None:
+            # two kinds of token mixer, one residual add
+            attn_out = mix_out + attn_out * jnp.asarray(
+                cfg.attention_out_multiplier, attn_out.dtype)
     if cfg.parallel_block:
         # parallel residual: attention and MLP branches sum into x. phi
         # (parallel_norms=1) feeds both from ln1's output; gpt-neox
         # (parallel_norms=2) norms the mlp branch separately with ln2
-        h_mlp = h if cfg.parallel_norms == 1 else _norm(x, lp["ln2"], cfg)
-        return x + attn_out + _mlp(h_mlp, lp["mlp"], cfg, lora)
-    if cfg.post_norms:  # gemma-2/olmo2: norm the attn OUTPUT
-        attn_out = _norm(attn_out, lp["ln1_post"], cfg)
-    x = x + _residual(attn_out, cfg)
+        h_mlp = h if cfg.parallel_norms == 1 else norm(x, "ln2")
+        with jax.named_scope("attn.out"):
+            x = x + attn_out
+        mlp_out = _mlp(h_mlp, lp["mlp"], cfg, lora)
+        with jax.named_scope("mlp.down"):
+            return x + mlp_out
+    # (gemma-2/olmo2, cfg.post_norms: a branch's OUTPUT is normed)
+    x = join(x, attn_out, "attn.out", "ln1_post" if cfg.post_norms else None)
 
-    h2 = x if cfg.no_pre_norms else _norm(x, lp["ln2"], cfg)
+    h2 = x if cfg.no_pre_norms else norm(x, "ln2")
     if cfg.moe_router_input == "attn_norm":
         moe_kw = dict(moe_kw or {}, router_x=h)
     mlp_out = _ffn(h2, lp, cfg, lora, moe_kw, moe_sink)
-    if cfg.post_norms:
-        mlp_out = _norm(mlp_out, lp["ln2_post"], cfg)
-    return x + _residual(mlp_out, cfg)
+    return join(x, mlp_out, ffn_part, "ln2_post" if cfg.post_norms else None)
 
 
 def _residual(branch, cfg: ModelConfig):
@@ -1640,31 +1669,6 @@ def _residual(branch, cfg: ModelConfig):
     if cfg.residual_multiplier == 1.0:
         return branch
     return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
-
-
-def _attn_scoped(cfg: ModelConfig) -> bool:
-    """Does this model's plain attention run under the ``attn.*`` scopes? The
-    models whose programs were first built with them (PR 43: smallthinker,
-    the only dropless-expert model over a K/V pool). A scope renames every
-    op under it, so the older programs (phi-3, falcon-h1: the benchmark's
-    readers and the program store know their op names) stay bare until a
-    tracing PR opens the scopes for all and re-anchors those. PR 46: a
-    looped stack's too (ouro: a new program)."""
-    return (cfg.moe_dropless and not cfg.has_mla) or _stack_scoped(cfg)
-
-
-def _stack_scoped(cfg: ModelConfig) -> bool:
-    """Do the REST of this model's dense stack run under scopes: the MLP's
-    ``mlp.gate_up`` / ``mlp.down``, the norm between a looped stack's passes
-    ``loop.norm`` and the head ``head.logits``? A looped stack alone (ouro,
-    first built with them): smallthinker's head was built bare and stays so.
-    PR 51: a stack of one mixer kind a layer too (granite: a new program)."""
-    return cfg.loop_steps > 1 or bool(cfg.layer_types)
-
-
-def _scope_if(on: bool):
-    """jax.named_scope, or a context that names nothing."""
-    return jax.named_scope if on else lambda _: contextlib.nullcontext()
 
 
 def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
@@ -1677,7 +1681,8 @@ def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
     if "moe" not in lp:
         return _mlp(h2, lp["mlp"], cfg, lora)
     if not cfg.moe_dropless:
-        return _moe(h2, lp["moe"], cfg)
+        with jax.named_scope("moe.experts"):
+            return _moe(h2, lp["moe"], cfg)
     out, stats = _moe_dropless(h2, lp["moe"], cfg, **(moe_kw or {}))
     if moe_sink is not None:
         moe_sink(stats)
@@ -1685,10 +1690,13 @@ def _ffn(h2, lp: Params, cfg: ModelConfig, lora=None, moe_kw=None,
 
 
 def final_logits(params: Params, cfg: ModelConfig, x):
-    """Final norm + LM head (+softcap), f32 logits."""
-    return head_logits(params, cfg, _norm(x, params["final_norm"], cfg))
+    """Final norm + LM head (+softcap), f32 logits, under ``head.logits``."""
+    with jax.named_scope("head.logits"):
+        x = _norm(x, params["final_norm"], cfg)
+    return head_logits(params, cfg, x)
 
 
+@jax.named_scope("head.logits")
 def head_logits(params: Params, cfg: ModelConfig, x):
     """LM head (+softcap) on an ALREADY normed ``x``, f32 logits: a looped
     stack norms inside its pass loop (forward) and must not norm twice."""
@@ -1916,12 +1924,11 @@ def forward(
         valid_len=valid_len)
     hidden = x
     if last_index is not None:
-        x = take_position(x, last_index)
-    if cfg.loop_steps > 1:  # the last pass's norm WAS the final norm
         with jax.named_scope("head.logits"):
-            return head_logits(params, cfg, x), new_cache
-    with _scope_if(_stack_scoped(cfg))("head.logits"):
-        logits = final_logits(params, cfg, x)
+            x = take_position(x, last_index)
+    # (a looped stack's last pass's norm WAS the final norm)
+    logits = (head_logits if cfg.loop_steps > 1 else final_logits)(
+        params, cfg, x)
     if return_hidden:
         return logits, new_cache, hidden
     return logits, new_cache
@@ -2393,9 +2400,9 @@ def mtp_forward(params: Params, cfg: ModelConfig, hidden, next_ids, cache,
             params, cfg, x, cache, off_b, positions,
             (jax.tree.map(lambda a: a[0], params["mtp"]["block"]),
              cfg.n_layers), **kw)
-    if last_index is not None:
-        x = take_position(x, last_index)
     with jax.named_scope("mtp.head"):
+        if last_index is not None:
+            x = take_position(x, last_index)
         return final_logits(params, cfg, x), cache
 
 

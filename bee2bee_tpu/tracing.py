@@ -242,6 +242,29 @@ def prog_scope(name: str):
     return deco
 
 
+# The parts of a model's program on the device: the `jax.named_scope`s that
+# `models/core.py`, `engine._verify_step` and `sampling.sample_batched` open,
+# unconditionally, for EVERY model (no setting, config field or model's name
+# decides one), under the `prog.*` root of the jit that runs them. A scope is
+# `op_name` metadata on the HLO instruction: it costs nothing at run time and
+# the compiler's fusion does not read it; a fusion is booked under the scope
+# of its ROOT instruction, so the small ops that finish a product (a bias, a
+# multiplier, the residual add) sit under the product's part. A WRAPPER
+# (DEVICE_WRAPPERS) encloses whole parts and a capture books an op under the
+# part inside it. Where each is opened, which models run it and which metric
+# reads it: docs/OBSERVABILITY.md's table "The block's parts", a row a name.
+DEVICE_PARTS = (
+    "embed.tokens", "norm.block",
+    "attn.qkv", "attn.rope", "attn.write", "kv.write", "attn.read", "attn.out",
+    "mla.q_proj", "mla.kv_proj", "mla.write", "mla.read", "mla.out",
+    "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.step", "ssm.state_write", "ssm.out_proj",
+    "mlp.gate_up", "mlp.down",
+    "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+    "loop.norm", "head.logits", "mtp.proj", "mtp.head", "spec.accept", "sample.draw",
+)
+DEVICE_WRAPPERS = ("spec.verify", "mtp.block")
+
+
 class PhaseClock:
     """The named phases of ONE thread's loop. `phase(name)` opens a
     `jax.profiler.TraceAnnotation("<prefix>.<name>")` — it lands on the

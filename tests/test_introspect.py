@@ -96,6 +96,33 @@ def test_sentinel_undeclared_key_storms_immediately(tmp_path):
     assert "(7,)" in bundle["extra"]["key"]
 
 
+def test_sentinel_counts_and_logs_a_key_function_that_raises(tmp_path, caplog):
+    """A key function that raises leaves its root UN-KEYED (counted, never
+    classified): each such compile is counted under
+    ``engine.compile_key_errors{root}`` and the first of a root is logged;
+    the call itself never sees the error."""
+    from bee2bee_tpu.engine.introspect import _C_KEY_ERRORS
+
+    def errors():
+        return sum(s["value"] for s in _C_KEY_ERRORS.snapshot()["series"]
+                   if s["labels"] == {"root": "unit_keyless"})
+
+    def key_fn(x):
+        raise TypeError("no such argument")
+
+    s = RetraceSentinel(recorder=FlightRecorder(incident_dir=tmp_path))
+    fn = s.watch("unit_keyless", jax.jit(lambda x: x * 3), key_fn=key_fn)
+    before = errors()
+    with caplog.at_level("WARNING", logger="bee2bee_tpu.introspect"):
+        assert float(fn(jnp.ones((2,)))[0]) == 3.0
+        fn(jnp.ones((3,)))
+    assert errors() - before == 2
+    said = [r for r in caplog.records if "key function of root=unit_keyless" in r.getMessage()]
+    assert len(said) == 1 and said[0].exc_info
+    snap = s.snapshot()["unit_keyless"]
+    assert snap["traces"] == 2 and snap["storms"] == 0
+
+
 def test_sentinel_repeat_key_storms_only_past_threshold(tmp_path):
     """A single recompile of a seen key (weak-type flip, clear_caches) is
     noise; a per-step retrace is the storm. Constant key + changing

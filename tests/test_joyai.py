@@ -366,6 +366,19 @@ def test_the_leading_layer_is_dense_and_the_next_ones_are_not(params):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
+def test_a_latent_block_adds_its_branches_bare(params):
+    """``residual_multiplier`` is granite's: a latent-attention block takes no
+    notice of one (as before PR 56 put its adds under ``mla.out`` / the FFN's
+    part)."""
+    import dataclasses
+
+    ids = _ids(1, 9)
+    a, _ = core.forward(params, CFG, ids, None, 0)
+    half = dataclasses.replace(CFG, residual_multiplier=0.5)
+    b, _ = core.forward(params, half, ids, None, 0)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # --------------------------------------------------- configuration and loader
 
 

@@ -280,23 +280,25 @@ async def test_full_surface_scrape_matches_catalog():
 
 
 def test_every_device_trace_scope_the_model_opens_is_documented():
-    """The ``jax.named_scope``s of models/core.py (``ssm.*``, ``kv.write``,
-    ``mla.*``, ``moe.*``) are what the benchmark's scope readers book device
-    time by: each one opened in the source is named in the document."""
+    """``tracing.DEVICE_PARTS`` (+ ``DEVICE_WRAPPERS``) IS the set of
+    ``jax.named_scope``s that models/core.py, engine/engine.py and
+    engine/sampling.py open, which the benchmark's scope readers book device
+    time by: the sources open no other (the ``prog.*`` roots are
+    ``tracing.prog_scope``'s) and none behind a condition on the model, and
+    the document's table names each one."""
     import re
 
-    src = (DOC.parent.parent / "bee2bee_tpu" / "models" / "core.py").read_text()
-    opened = set(re.findall(r'named_scope\("([a-z_]+\.[a-z_]+)"\)', src))
-    assert {"mla.q_proj", "mla.kv_proj", "mla.write", "mla.read", "mla.out",
-            "moe.router", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
-            "ssm.step", "kv.write"} <= opened
-    # the plain attention's parts, opened where core._attn_scoped says
-    # (transformer_block's ``scope``: smallthinker)
-    attn = set(re.findall(r'scope\("(attn\.[a-z_]+)"\)', src))
-    assert attn == {"attn.qkv", "attn.rope", "attn.write", "attn.read", "attn.out"}
-    # a looped stack's MLP, the norm between its passes and its head
-    # (core._stack_scoped: ouro)
-    mlp = set(re.findall(r'scope\("(mlp\.[a-z_]+)"\)', src))
-    assert mlp == {"mlp.gate_up", "mlp.down"} and {"loop.norm", "head.logits"} <= opened
+    from bee2bee_tpu.tracing import DEVICE_PARTS, DEVICE_WRAPPERS
+
+    pkg = DOC.parent.parent / "bee2bee_tpu"
+    src = "".join((pkg / f).read_text() for f in (
+        "models/core.py", "engine/engine.py", "engine/sampling.py"))
+    opened = set(re.findall(r'named_scope\(\s*"([a-z_]+\.[a-z_]+)"', src))
+    table = DEVICE_PARTS + DEVICE_WRAPPERS
+    assert len(table) == len(set(table))
+    # ``ffn_part`` / ``join``: the residual add's scope is a NAME of the table
+    # picked by the branch's kind, never a scope or none
+    assert opened == set(table), sorted(opened ^ set(table))
+    assert "nullcontext" not in src  # no scope that may open as nothing
     doc = DOC.read_text()
-    assert not sorted(s for s in opened | attn | mlp if f"`{s}`" not in doc)
+    assert not sorted(s for s in table if f"| `{s}` |" not in doc)

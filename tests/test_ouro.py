@@ -209,16 +209,18 @@ def test_a_plain_stack_has_one_layer_scan_and_no_pass_loop():
     assert _scans(looped.jaxpr) == [[[]]]  # the layer scan INSIDE the scan over passes: not unrolled
 
 
-def test_only_a_looped_stack_carries_the_new_scopes():
+def test_a_plain_stack_carries_every_scope_of_a_looped_one_but_the_pass_norm():
+    """The block's parts are opened for every model (tracing.DEVICE_PARTS);
+    what a looped stack alone has is the norm between its passes."""
     def text(cfg):
         shapes = jax.eval_shape(lambda: core.init_params(cfg, jax.random.key(0)))
         return jax.jit(lambda p: core.forward(p, cfg, jnp.zeros((1, 8), jnp.int32), None, 0)[0]
                        ).lower(shapes).as_text(debug_info=True)
 
-    looped, bare = text(CFG), text(get_config("tiny-llama"))
-    for scope in ("attn.qkv", "attn.read", "attn.out", "mlp.gate_up", "mlp.down", "loop.norm",
-                  "head.logits"):
-        assert scope in looped and scope not in bare, scope
+    looped, plain = text(CFG), text(get_config("tiny-llama"))
+    for scope in ("attn.qkv", "attn.read", "attn.out", "mlp.gate_up", "mlp.down", "head.logits"):
+        assert scope in looped and scope in plain, scope
+    assert "loop.norm" in looped and "loop.norm" not in plain
 
 
 def test_the_training_path_differentiates_through_both_loops(params):

@@ -874,9 +874,8 @@ def test_a_call_reads_wq_wk_wv_where_they_lie(
         assert _pool_sized_ops(text, D * n, L) == [], n
         same = widths.count(n) + (n == D)  # wo [L, H * hd, D]
         assert _products_on(text, (L, D, n)) == same, n
-    if core._attn_scoped(cfg):
-        assert len(re.findall(
-            r'convolution\(.*op_name="[^"]*attn\.qkv/dot_general', text)) == 3
+    assert len(re.findall(
+        r'convolution\(.*op_name="[^"]*attn\.qkv/dot_general', text)) == 3
 
 
 def test_a_call_of_more_rows_keeps_the_head_split_in_its_products(one_chip):
