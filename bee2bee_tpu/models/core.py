@@ -1074,16 +1074,23 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
     argsort, a bincount for the group sizes, a row gather), the three
     expert matrices are grouped products over the sorted rows
     (``moe.experts``: ops/grouped.py, which visits only experts that got a
-    row and reads each visited expert's matrix from where it lies), the
-    outputs go back by the inverse permutation, weighted, summed over a
-    token's k in float32 (``moe.combine``), and the shared expert is added
-    (``moe.shared``).
+    row and reads each visited expert's matrix from where it lies). The way
+    back (``moe.combine``) gathers the product's OWN rows, in the dtype it
+    left them (bf16 on the served path), by the inverse permutation, choice
+    by choice: [k, N, D], every token's j-th choice in slab j. One float32
+    expression then converts them, selects zero where an assignment's sorted
+    row lies past the last group (``inv >= n_live``: a dead position, an
+    expert held elsewhere; such a row may hold NaN), weighs them by the
+    router's weights as the router gave them and sums a token's k choices in
+    the router's order, so no float32 array of the sorted rows is ever made.
+    The shared expert is added to that float32 sum (``moe.shared``), cast
+    once.
 
     ``live`` [B, T] bool: positions that are real. A dead row of a batch
     bucket or a prefill bucket's padded tail is assigned to a pad group
     past the last expert: it sorts last, belongs to no group of the
-    product and gets weight zero, so it neither touches an expert nor
-    counts as load. ``experts`` (default ``p``) holds w_gate / w_up /
+    product and is masked on the way back, so it neither touches an expert
+    nor counts as load. ``experts`` (default ``p``) holds w_gate / w_up /
     w_down; with ``layer`` (a traced scalar: forward's layer scan) they are
     the STACKED [L, E, ...] arrays, read in place: the stack is viewed as
     L*E groups of which only this layer's E get rows, so no layer's
@@ -1093,7 +1100,7 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
     Under an expert SHARE (cfg.expert_share) the stacks hold the
     cfg.experts_held experts from cfg.expert_first on: the router still
     scores every expert and takes its k, an assignment to an expert held
-    ELSEWHERE joins the pad group (no product, weight zero) and is counted
+    ELSEWHERE joins the pad group (no product, masked) and is counted
     in a fourth stat, and the layer returns the held experts' part plus the
     shared expert: what this chip would hand to the exchange with its
     partners, which is not built. ``router_fix(router input [N, D], W_r) ->
@@ -1125,8 +1132,7 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
             flat = jnp.where(jnp.repeat(live.reshape(N), k), flat, E)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [M]
         gs = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
-        n_live = jnp.sum(gs)
-        row_live = jnp.arange(M, dtype=jnp.int32) < n_live  # of SORTED rows
+        n_live = jnp.sum(gs)  # the sorted rows before it belong to a group
         xs = jnp.take(xf, order // k, axis=0)  # [M, D]
         if layer is not None:
             L = experts["w_up"].shape[0]
@@ -1145,11 +1151,15 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
             _activate(up, gate, cfg), stack(experts["w_down"]), sizes)
 
     with jax.named_scope("moe.combine"):
-        y = jnp.where(row_live[:, None], y.astype(jnp.float32), 0.0)
-        y = y * jnp.take(w.reshape(M), order)[:, None]
+        # (assignment -> its sorted row, laid [k, N]: gathered choice-major
+        # the rows view as [k, N, D] for nothing, where [N, k, D] is a copy
+        # on the chip, k being no multiple of a tile's rows)
         inv = jnp.zeros((M,), jnp.int32).at[order].set(
-            jnp.arange(M, dtype=jnp.int32))
-        out = jnp.sum(jnp.take(y, inv, axis=0).reshape(N, k, D), axis=1)
+            jnp.arange(M, dtype=jnp.int32)).reshape(N, k).T
+        yg = jnp.take(y, inv.reshape(M), axis=0).reshape(k, N, D)
+        # (a select, never a 0 / 1 multiplier: a masked row may hold NaN)
+        kept = jnp.where((inv < n_live)[..., None], yg.astype(jnp.float32), 0.0)
+        out = jnp.sum(kept * w.T[..., None], axis=0)
 
     if "shared" in p:
         with jax.named_scope("moe.shared"):

@@ -307,16 +307,12 @@ def _top_level_ops(text: str):
             yield m[1], m[2], m[3], line
 
 
-def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
-    """The instructions of a compiled module that PRODUCE an array with as
-    many elements as one layer's pool slice, or as the stacked pool
-    (whatever its dims: a relayout, a select, a slice, a write-back and
-    their bitcast-fused forms all keep the count; no weight or state of
-    these models shares it). Not counted: the plumbing that only passes
-    the pool along (parameter, tuple, get-tuple-element, bitcast, while),
-    the inside of fusions (a fusion counts by its result) and the Mosaic
-    calls, which alias the pool."""
-    found = []
+def _ops_of_size(text: str, sizes: set[int]):
+    """(name, result types, opcode, line) of the instructions of a compiled
+    module that PRODUCE an array of one of ``sizes`` elements, whatever its
+    dims. Not counted: the plumbing that only passes an array along
+    (parameter, tuple, get-tuple-element, bitcast, while), the inside of
+    fusions (a fusion counts by its result) and the Mosaic calls."""
     for name, result, op, line in _top_level_ops(text):
         if op in (
             "parameter", "tuple", "get-tuple-element", "bitcast", "while"
@@ -326,9 +322,18 @@ def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
             int(np.prod([int(d) for d in dims.split(",") if d]))
             for _, dims in _HLO_ARRAY.findall(result)
         }
-        if elems & {slice_elems, slice_elems * layers}:
-            found.append(f"{name} {op}")
-    return found
+        if elems & sizes:
+            yield name, result, op, line
+
+
+def _pool_sized_ops(text: str, slice_elems: int, layers: int) -> list[str]:
+    """The instructions of a compiled module that PRODUCE an array with as
+    many elements as one layer's pool slice, or as the stacked pool
+    (_ops_of_size: a relayout, a select, a slice, a write-back and their
+    bitcast-fused forms all keep the count; no weight or state of these
+    models shares it; the Mosaic calls alias the pool)."""
+    return [f"{name} {op}" for name, _, op, _ in
+            _ops_of_size(text, {slice_elems, slice_elems * layers})]
 
 
 def _products_on(text: str, shape: tuple[int, ...]) -> int:
@@ -570,6 +575,27 @@ def test_latent_write_and_read_compile_for_v5e(one_chip, case):
     assert read.memory_analysis().temp_size_in_bytes < 2 * 3201 * BS * 640 * 2
 
 
+def _dropless_layer_compiled(cfg, B, T, sharding):
+    """core._moe_dropless alone, compiled for the described chip at ``cfg``'s
+    published widths: the three expert stacks whole (read in place by a traced
+    layer index), one layer's router and shared expert, ``x`` [B, T, D] bf16."""
+    shapes = jax.eval_shape(
+        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))["layers"]["moe"]
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), t)
+    names = ("w_gate", "w_up", "w_down")
+    experts = place({n: shapes[n] for n in names})
+    rest = place(jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+        {n: a for n, a in shapes.items() if n not in names}))
+    x = jax.ShapeDtypeStruct((B, T, cfg.d_model), jnp.bfloat16, sharding=sharding)
+    lay = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
+    return jax.jit(
+        lambda x, rest, experts, lay: core._moe_dropless(
+            x, rest, cfg, experts=experts, layer=lay)
+    ).lower(x, rest, experts, lay).compile()
+
+
 @pytest.mark.parametrize("tokens", [64, 512])
 def test_dropless_expert_layer_compiles_for_v5e(one_chip, mosaic_grouped, tokens):
     """512 and 4,096 assignments over 256 experts of 2048 x 768: three Mosaic
@@ -577,26 +603,44 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip, mosaic_grouped, tokens
     (no instruction produces an array of a layer's expert matrix's size: no
     slice of the stack, no re-laid copy)."""
     cfg = JOYAI
-    shapes = jax.eval_shape(
-        lambda: core.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))["layers"]["moe"]
-    place = lambda t: jax.tree.map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
-    names = ("w_gate", "w_up", "w_down")
-    experts = place({n: shapes[n] for n in names})
-    rest = place(jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
-        {n: a for n, a in shapes.items() if n not in names}))
-    x = jax.ShapeDtypeStruct((tokens, 1, cfg.d_model), jnp.bfloat16, sharding=one_chip)
-    lay = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(
-        lambda x, rest, experts, lay: core._moe_dropless(
-            x, rest, cfg, experts=experts, layer=lay)
-    ).lower(x, rest, experts, lay).compile()
+    compiled = _dropless_layer_compiled(cfg, tokens, 1, one_chip)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
     one_matrix = cfg.n_experts * cfg.d_model * cfg.expert_ff
     assert _pool_sized_ops(text, one_matrix, cfg.n_expert_layers) == []
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix * 2 // 4
+
+
+# (model, B, T): smallthinker's 2,048-token prefill chunk (M = 12,288 sorted
+# rows of 2,560) and its 32-row decode step; granite's 64-row step under its
+# expert share (M = 640 rows of 4,096, half of them in the pad group)
+COMBINE_CASES = {
+    "st-chunk-2048": ("smallthinker-21b-a3b-8l", 1, 2048),
+    "st-decode-32": ("smallthinker-21b-a3b-8l", 32, 1),
+    "granite-share-decode-64": ("granite-4.0-h-small-10l-e36", 64, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_the_combine_makes_no_float32_array_of_the_sorted_rows(
+        one_chip, mosaic_grouped, case):
+    """``moe.combine`` gathers the grouped product's rows as the product left
+    them (bf16) and converts, masks, weights and sums them in ONE fusion: no
+    instruction of the compiled layer (a fusion counts by its result) makes a
+    float32 array of M x D elements, whatever its dims (``f32[12288,2560]``
+    twice at the chunk before PR 57), and the part's ONE array of that size is
+    the gather's, in the stream's dtype: gathered choice-major, its [k, N, D]
+    view is no copy (viewed [N, k, D] the rows were re-laid, k being no
+    multiple of a tile's rows: a ``reshape`` as long as the gather itself)."""
+    model, B, T = COMBINE_CASES[case]
+    cfg = get_config(model)
+    text = _dropless_layer_compiled(cfg, B, T, one_chip).as_text()
+    assert _custom_calls(text) == 3
+    sized = list(_ops_of_size(
+        text, {B * T * cfg.n_experts_per_tok * cfg.d_model}))
+    assert [name for name, result, _, _ in sized if "f32[" in result] == []
+    combine = [(op, line) for _, _, op, line in sized if "moe.combine" in line]
+    assert [op for op, _ in combine] == ["fusion"] and "gather" in combine[0][1], combine
 
 
 def test_the_balancing_pass_compiles_for_v5e_beside_the_weights(one_chip, mosaic_grouped):
