@@ -34,7 +34,8 @@ class ModelConfig:
     norm_bias: bool = True  # layernorm only: mpt ships weight-only norms
     activation: str = "silu"  # "silu" (gated) | "gelu" (tanh approx, gpt2/
     # phi) | "gelu_exact" (erf — gpt-neox) | "geglu" | "reglu" (gated by a
-    # ReLU: smallthinker's sparse experts)
+    # ReLU: smallthinker's sparse experts) | "relu2" (relu(x)^2, NO gate
+    # matrix: nemotron-h's experts and shared expert)
     use_bias: bool = False  # attn/mlp biases (gpt2 style)
     qkv_bias: bool = False  # bias on q/k/v ONLY (qwen2 style; no bo/mlp bias)
     qk_norm: bool = False  # per-head RMSNorm on q and k before rope
@@ -214,6 +215,15 @@ class ModelConfig:
     # logits and sampling are over the slice) and this the published size
     # (0 = vocab_size is the whole vocabulary)
     vocab_published: int = 0
+    # nemotron-h: ``layer_types`` may name a THIRD kind, "moe": a layer is
+    # then ONE branch under ONE norm, ``x + branch(norm(x))`` with the branch
+    # a mixer, attention OR an expert layer (single_branch); an "moe" layer
+    # owns neither state nor cache, and the expert stacks are as deep as the
+    # "moe" layers (moe_slots). moe_latent (the published moe_latent_size; 0 =
+    # the experts read the model's width): the routed experts live in a
+    # latent of this width, ``z = h W_in`` before the dispatch and ``W_out``
+    # once on the weighted sum; router and shared expert read the full width
+    moe_latent: int = 0
 
     def __post_init__(self):
         # json lists (the native-checkpoint model_config.json round-trip)
@@ -294,14 +304,15 @@ class ModelConfig:
         if self.layer_types:
             kinds = set(self.layer_types)
             if (len(self.layer_types) != self.n_layers
-                    or not kinds <= {"mamba", "attention"}
-                    or kinds != {"mamba", "attention"} or not self.ssm_heads):
+                    or not {"mamba", "attention"} <= kinds
+                    or not kinds <= {"mamba", "attention", "moe"}
+                    or not self.ssm_heads):
                 raise ValueError(
                     f"layer_types={self.layer_types!r} must name each of the "
-                    f"{self.n_layers} layers 'mamba' or 'attention', hold at "
-                    "least one of each (the state and the pool are as deep "
-                    "as their kinds) and come with the mixer's sizes "
-                    "(ssm_heads)"
+                    f"{self.n_layers} layers 'mamba' or 'attention' (or, a "
+                    "layer of one branch, 'moe'), hold at least one of each "
+                    "of the two (the state and the pool are as deep as their "
+                    "kinds) and come with the mixer's sizes (ssm_heads)"
                 )
             if (self.loop_steps > 1 or self.mla_kv_rank or self.first_k_dense
                     or self.parallel_block or self.no_pre_norms
@@ -312,6 +323,22 @@ class ModelConfig:
                     "looped stack, latent attention, leading dense layers, "
                     "parallel block or sliding window"
                 )
+            if "moe" in kinds and (
+                    self.moe_router == "softmax" or not self.n_experts
+                    or self.post_norms or self.moe_router_input != "ffn_norm"
+                    or self.residual_multiplier != 1.0):
+                raise ValueError(
+                    f"layer_types={self.layer_types!r} names 'moe' layers (a "
+                    "layer of ONE branch): they are dropless expert layers "
+                    "(moe_router 'sigmoid' / 'softmax_topk', n_experts) fed "
+                    "their own norm, with no post norm and no residual "
+                    "multiplier"
+                )
+        if self.moe_latent and (self.moe_latent < 0 or not self.moe_dropless):
+            raise ValueError(
+                f"moe_latent={self.moe_latent} is the width a dropless expert "
+                "layer's routed experts live in (moe_router 'sigmoid' / "
+                "'softmax_topk')")
         if self.n_experts_held or self.expert_first:
             held = self.n_experts_held or self.n_experts
             if (self.moe_router == "softmax" or held < 1
@@ -454,18 +481,66 @@ class ModelConfig:
         return tuple(range(self.n_layers))
 
     @property
+    def single_branch(self) -> bool:
+        """A layer is ONE branch under ONE norm (nemotron-h): a mixer,
+        attention OR an expert layer, by ``layer_types``' three kinds."""
+        return "moe" in self.layer_types
+
+    @property
+    def moe_slots(self) -> tuple:
+        """Layer -> its slot of the expert stacks (-1: the layer has no
+        expert layer), where the expert layers are a KIND of layer
+        (single_branch); elsewhere an expert layer's slot is its place behind
+        the leading dense layers."""
+        return self._slots("moe")
+
+    @property
+    def kind_slots(self) -> dict:
+        """Layer kind -> (layer -> its slot among the layers of that kind)."""
+        return {"mamba": self.state_slots, "attention": self.cache_slots,
+                "moe": self.moe_slots}
+
+    @property
     def layer_runs(self) -> tuple:
         """``layer_types`` as RUNS of like layers, in order: (kind, first
-        layer, count, the first layer's slot of its kind). core.forward
-        scans a run at a time; a pattern need not be periodic."""
-        runs, slots = [], {"mamba": self.state_slots,
-                           "attention": self.cache_slots}
+        layer, count, the first layer's slot of its kind); a pattern need not
+        be periodic. What core.forward scans is layer_units."""
+        runs, slots = [], self.kind_slots
         for i, t in enumerate(self.layer_types):
             if runs and runs[-1][0] == t:
                 runs[-1][2] += 1
             else:
                 runs.append([t, i, 1, slots[t][i]])
         return tuple(tuple(r) for r in runs)
+
+    @property
+    def layer_units(self) -> tuple:
+        """``layer_types`` as runs of a repeated UNIT (a short tuple of
+        kinds), in order: (unit, first layer, repeats). core.forward scans a
+        run at a time with the whole unit as the scan's body, so a pattern
+        that ALTERNATES (nemotron-h's ``E M E M E M E M E M *``: ("moe",
+        "mamba") x 5, ("attention",) x 1) is two bodies and not eleven. At
+        each layer the unit that covers the most layers with at least two
+        repeats is taken, the shortest among equals, else the layer's own
+        kind: runs of like layers (granite's ``m m m m m a m m m m``) come out
+        as layer_runs has them, a unit of one kind. A unit is at most four
+        layers: a longer body (granite's period of ten, four times over) is no
+        shorter a program than its runs."""
+        types, units, i = self.layer_types, [], 0
+        while i < len(types):
+            best, covered = 1, 0
+            for p in range(1, min(4, (len(types) - i) // 2) + 1):
+                r = 1
+                while types[i + r * p:i + (r + 1) * p] == types[i:i + p]:
+                    r += 1
+                if r >= 2 and p * r > covered:
+                    best, covered = p, p * r
+            # (a run of like layers is the unit of one that covers it whole:
+            # no longer unit of one kind covers more, and ties go to the shorter)
+            r = max(covered // best, 1)
+            units.append((types[i:i + best], i, r))
+            i += best * r
+        return tuple(units)
 
     @property
     def layer_windows(self) -> tuple:
@@ -505,7 +580,7 @@ class ModelConfig:
     @property
     def gated_mlp(self) -> bool:
         """The MLP / an expert has a gate matrix beside up and down."""
-        return self.activation in ("silu", "geglu", "reglu")
+        return self.activation in ("silu", "geglu", "reglu")  # not "relu2"
 
     @property
     def expert_ff(self) -> int:
@@ -513,7 +588,15 @@ class ModelConfig:
 
     @property
     def n_expert_layers(self) -> int:
+        if self.single_branch:  # the "moe" layers alone
+            return self.layer_types.count("moe")
         return self.n_layers - self.first_k_dense if self.n_experts else 0
+
+    @property
+    def expert_in(self) -> int:
+        """The width a routed expert reads and writes: the latent's
+        (moe_latent) or the model's."""
+        return self.moe_latent or self.d_model
 
     @property
     def n_expert_calls(self) -> int:
@@ -1111,6 +1194,54 @@ CONFIGS["tiny-exaone"] = ModelConfig(
 )
 
 
+_NEMOTRON_PERIOD = ("moe", "mamba") * 5 + ("attention",)
+_NEMOTRON_3_SUPER = dict(
+    # nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 config.json (model_type
+    # nemotron_h, 120B-A12B): a layer is ONE branch under ONE norm, by the
+    # character of hybrid_override_pattern a Mamba-2 mixer (M: 128 heads x 64,
+    # state 128, 8 groups, conv 4 with bias, chunk 128), GQA 32/2 x 128
+    # attention with no positional encoding (*) or a LatentMoE expert layer
+    # (E: 512 experts of TWO matrices 1,024 x 2,688 x 1,024 with relu^2
+    # between, in a latent 1,024 wide, top-22 by sigmoid score + selection
+    # bias, weights normalised x 5, beside one shared expert 5,376 wide at the
+    # model's width); an untied head over 131,072 tokens. Its multi-token-
+    # prediction module is not built (models/support.py)
+    d_model=4096, n_heads=32, n_kv_heads=2, d_ff=2688, pos_embedding="nope",
+    norm_eps=1e-5, tie_embeddings=False, activation="relu2",
+    ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8, ssm_conv=4,
+    ssm_chunk=128,
+    n_experts=512, n_experts_per_tok=22, moe_router="sigmoid", moe_scale=5.0,
+    n_shared_experts=1, d_ff_expert=2688, d_ff_shared=5376, moe_latent=1024,
+)
+CONFIGS["nemotron-3-super-120b-a12b-11l-e128"] = ModelConfig(
+    # the served cut of the benchmark (benchmark/configs/nemotron-3-super-
+    # 120b-a12b-11l-e128.json): hybrid_override_pattern[26:37] = E M E M E M E
+    # M E M *, one whole period, every width, experts 0-127 of every expert
+    # layer's 512 and rows 0-32,767 of the embedding and the head: the first
+    # of the four chips that share the layers of one pipeline stage, with the
+    # embedding, the final norm and its slice of the head added
+    name="nemotron-3-super-120b-a12b-11l-e128", n_layers=11,
+    vocab_size=32768, vocab_published=131072, max_seq_len=2048,
+    layer_types=_NEMOTRON_PERIOD, n_experts_held=128, **_NEMOTRON_3_SUPER)
+CONFIGS["tiny-nemotron"] = ModelConfig(
+    # every mechanism at CPU-test size: E M E M * M (a unit of two kinds
+    # twice, then two runs of one; the FIRST layer an expert layer), 16
+    # experts top-5 in a latent of 24 of which 4 are held from the 4th on, a
+    # shared expert of another width, 2 groups, a chunk shorter than the test
+    # prompts, a sliced untied vocabulary
+    name="tiny-nemotron", vocab_size=320, vocab_published=512, d_model=64,
+    n_layers=6, n_heads=4, n_kv_heads=2, d_ff=40, max_seq_len=256,
+    pos_embedding="nope", norm_eps=1e-5, tie_embeddings=False,
+    activation="relu2",
+    ssm_heads=8, ssm_head_dim=16, ssm_state=8, ssm_groups=2, ssm_conv=4,
+    ssm_chunk=8,
+    layer_types=("moe", "mamba", "moe", "mamba", "attention", "mamba"),
+    n_experts=16, n_experts_per_tok=5, moe_router="sigmoid", moe_scale=2.5,
+    n_shared_experts=1, d_ff_expert=40, d_ff_shared=48, moe_latent=24,
+    n_experts_held=4, expert_first=4,
+)
+
+
 def _neox_act(hidden_act: str) -> str:
     if hidden_act in ("gelu_new", "gelu_pytorch_tanh", "gelu_fast"):
         return "gelu"
@@ -1616,6 +1747,99 @@ def _exaone_moe_from_hf(d: dict, nm: str) -> ModelConfig:
     )
 
 
+def _nemotron_h_from_hf(d: dict, nm: str) -> ModelConfig:
+    """nemotron_h (nvidia/NVIDIA-Nemotron-3-*): a layer is ONE branch under
+    ONE norm, by the character of ``hybrid_override_pattern`` a Mamba-2 mixer
+    (``M``), attention without positions (``*``) or a LatentMoE expert layer
+    (``E``: sigmoid-routed relu^2 experts of two matrices in a latent
+    ``moe_latent_size`` wide, beside a shared expert at the model's width).
+    What core does not build is refused BY NAME: a dense MLP-alone layer
+    (``-``), the multi-token-prediction module, every bias. ``layers`` /
+    ``layer_first`` (the slice of the pattern that runs),
+    ``n_routed_experts_held`` / ``expert_first`` and ``vocab_size_held`` (not
+    published keys: a cut configuration's own) give the chip's share of the
+    depth, the experts and the vocabulary's rows."""
+    published = {  # key -> the one value the implementation covers
+        "mamba_proj_bias": False, "use_bias": False, "attention_bias": False,
+        "mlp_bias": False, "use_conv_bias": True, "n_group": 1,
+        "topk_group": 1, "norm_topk_prob": True,
+        "moe_shared_expert_overlap": False, "sliding_window": None,
+        "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+        "n_shared_experts": 1, "residual_in_fp32": False,
+        "num_nextn_predict_layers": 0,
+    }
+    from .support import why  # (support imports this module)
+
+    for key, want in published.items():
+        got = d.get(key, want)
+        if got != want:
+            raise ValueError(
+                f"nemotron_h config with {key}={got!r} is not implemented "
+                f"(only {key}={want!r}"
+                + (f": {why('single_branch', 'mtp_module')})"
+                   if key == "num_nextn_predict_layers" else ")")
+            )
+    eps = d.get("layer_norm_epsilon", 1e-5)
+    if d.get("norm_eps", eps) != eps:
+        raise ValueError(
+            f"nemotron_h config with norm_eps={d['norm_eps']!r} beside "
+            f"layer_norm_epsilon={eps!r} is not implemented (one epsilon for "
+            "every norm)")
+    pattern = str(d.get("hybrid_override_pattern") or "")
+    kinds = {"M": "mamba", "E": "moe", "*": "attention"}
+    if len(pattern) != d["num_hidden_layers"] or not set(pattern) <= set(kinds):
+        raise ValueError(
+            f"nemotron_h config with hybrid_override_pattern={pattern!r} is "
+            f"not implemented (one of M, E, * for each of the "
+            f"{d['num_hidden_layers']} layers; "
+            f"{why('single_branch', 'mlp_alone_layer')})")
+    first, L = d.get("layer_first") or 0, d.get("layers") or len(pattern)
+    if first < 0 or L < 1 or first + L > len(pattern):
+        raise ValueError(
+            f"nemotron_h config with layers={L} from layer_first={first} is "
+            f"not a slice of the {len(pattern)} published layers")
+    heads, groups = d["mamba_num_heads"], d.get("n_groups", 1)
+    D, H = d["hidden_size"], d["num_attention_heads"]
+    if heads % groups or heads * d["mamba_head_dim"] != d.get("expand", 2) * D:
+        raise ValueError(
+            f"nemotron_h config: n_groups={groups} must divide "
+            f"mamba_num_heads {heads}, and mamba_num_heads x mamba_head_dim "
+            f"{d['mamba_head_dim']} equal expand x hidden_size")
+    if not d.get("n_routed_experts") or not d.get("moe_latent_size"):
+        raise ValueError(
+            f"nemotron_h config with n_routed_experts="
+            f"{d.get('n_routed_experts')!r} / moe_latent_size="
+            f"{d.get('moe_latent_size')!r} is not implemented (an E layer "
+            "routes experts in a latent)")
+    hd = d.get("head_dim") or D // H
+    held = d.get("vocab_size_held") or 0
+    return ModelConfig(
+        name=nm, vocab_size=held or d["vocab_size"],
+        vocab_published=d["vocab_size"] if held else 0, d_model=D, n_layers=L,
+        n_heads=H, n_kv_heads=d.get("num_key_value_heads") or H,
+        d_ff=d["intermediate_size"],  # a '-' layer's width: none is built
+        head_dim_override=None if hd * H == D else hd,
+        max_seq_len=d.get("max_position_embeddings", 262144),
+        # the Nemotron-H block applies no rotation: rope_theta and
+        # partial_rotary_factor are carried keys
+        pos_embedding="nope", norm_eps=eps,
+        tie_embeddings=d.get("tie_word_embeddings", False),
+        activation="relu2",
+        ssm_heads=heads, ssm_head_dim=d["mamba_head_dim"],
+        ssm_state=d.get("ssm_state_size", 128), ssm_groups=groups,
+        ssm_conv=d.get("conv_kernel", 4), ssm_chunk=d.get("chunk_size", 128),
+        layer_types=tuple(kinds[c] for c in pattern[first:first + L]),
+        n_experts=d["n_routed_experts"],
+        n_experts_per_tok=d["num_experts_per_tok"], moe_router="sigmoid",
+        moe_scale=float(d.get("routed_scaling_factor", 1.0)),
+        n_shared_experts=1, d_ff_expert=d["moe_intermediate_size"],
+        d_ff_shared=d["moe_shared_expert_intermediate_size"],
+        moe_latent=d["moe_latent_size"],
+        n_experts_held=d.get("n_routed_experts_held") or 0,
+        expert_first=d.get("expert_first") or 0,
+    )
+
+
 def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
     """Synthesize a ModelConfig from an HF ``config.json`` dict — the
     any-checkpoint path: a checkpoint whose architecture is NOT in the
@@ -1919,6 +2143,8 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         return _granite_hybrid_from_hf(d, nm)
     if mt == "exaone_moe":
         return _exaone_moe_from_hf(d, nm)
+    if mt == "nemotron_h":
+        return _nemotron_h_from_hf(d, nm)
     if mt == "gemma3":
         raise ValueError(
             "gemma3 multimodal configs are not supported; extract the "
@@ -2088,7 +2314,7 @@ def config_from_hf(d: dict, name: str | None = None) -> ModelConfig:
         f"unsupported model_type {mt!r} in config.json — native serving "
         f"covers gpt2/llama/mistral/qwen2/gemma/mixtral/phi/gpt_neox/gptj/"
         f"falcon_h1/joyai_llm_flash/smallthinker/ouro/granitemoehybrid/"
-        f"exaone_moe; "
+        f"exaone_moe/nemotron_h; "
         f"other architectures can be served via the ollama/remote backends"
     )
 

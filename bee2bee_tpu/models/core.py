@@ -80,11 +80,14 @@ def matmul_params_per_token(cfg: ModelConfig) -> int:
     gated = cfg.gated_mlp
     mlp_one = (3 if gated else 2) * D * F
     if cfg.is_moe:
-        one = (3 if gated else 2) * D * cfg.expert_ff
+        # (experts in a latent, cfg.moe_latent, read and write ITS width; the
+        # two projections into and out of it are paid once a token)
+        one = (3 if gated else 2) * cfg.expert_in * cfg.expert_ff
         # under an expert share a token pays HERE for its choices held here
         routed = cfg.n_experts_per_tok * cfg.experts_held / cfg.n_experts
         moe = (D * cfg.n_experts + routed * one
-               + (3 if gated else 2) * D * cfg.shared_ff)
+               + (3 if gated else 2) * D * cfg.shared_ff
+               + 2 * D * cfg.moe_latent)
         # leading dense layers (first_k_dense) pay the dense MLP instead
         mlps = cfg.first_k_dense * mlp_one + cfg.n_expert_layers * moe
     else:
@@ -143,6 +146,14 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16,
         expert share (cfg.n_experts_held) the expert stacks are [L, held,
         ...], the router stays [L, D, E]; the shared expert is cfg.shared_ff
         wide
+      under cfg.single_branch (nemotron-h: a layer is ONE branch under ONE
+        norm, ``layer_types``' third kind "moe") ``layers`` holds ln1 alone
+        over all L layers, ``ssm`` / ``attn`` as above and ``moe`` over the
+        cfg.n_expert_layers "moe" layers alone (cfg.moe_slots): no ln2, no
+        second branch. With cfg.moe_latent the expert matrices are [.., Eh,
+        Dl, F] / [.., Eh, F, Dl] beside ``latent_in`` [.., D, Dl] and
+        ``latent_out`` [.., Dl, D]; an ungated activation ("relu2") has no
+        ``w_gate``, in the experts or in ``shared``
       mtp/ (cfg.mtp_layers, K-EXAONE's multi-token-prediction layer): enorm /
         hnorm {scale [D]}, eh_proj [2 D, D], block: ONE layer of the trunk's
         schema stacked [1, ...] (an expert layer where the trunk has them);
@@ -216,11 +227,13 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         if cfg.norm == "layernorm" and cfg.norm_bias:
             params["embed_norm"]["bias"] = jnp.zeros((D,), dtype)
 
-    def layer_group(L, moe_layers, La=None, Ls=None):
+    def layer_group(L, moe_layers, La=None, Ls=None, Lm=None):
         """One group of ``L`` like layers (dense MLP or expert layers);
         under cfg.layer_types ``La`` of them hold attention and ``Ls`` a
-        mixer (default: all)."""
+        mixer (default: all), under cfg.single_branch ``Lm`` an expert layer
+        (``L`` then counts the one norm a layer)."""
         La, Ls = (L if La is None else La), (L if Ls is None else Ls)
+        Lm = L if Lm is None else Lm
         if cfg.has_mla:
             qk = cfg.mla_nope_dim + cfg.mla_rope_dim
             attn = {
@@ -253,9 +266,11 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
             layers["attn"]["bo"] = jnp.zeros((La, D), dtype)
         if not cfg.no_pre_norms:  # olmo2 blocks norm only their OUTPUTS
             layers["ln1"] = {"scale": jnp.ones((L, D), dtype)}
-            if not cfg.parallel_block or cfg.parallel_norms == 2:
+            if (not cfg.parallel_block or cfg.parallel_norms == 2) and (
+                    not cfg.single_branch):
                 # sequential blocks AND neox-style dual-norm parallel blocks
-                # have ln2; only phi's shared-norm parallel blocks drop it
+                # have ln2; only phi's shared-norm parallel blocks and a
+                # layer of one branch drop it
                 layers["ln2"] = {"scale": jnp.ones((L, D), dtype)}
         if cfg.post_norms:  # gemma-2: norms on the attn/mlp outputs too
             # a looped stack's SEEDED output norms start at 1 / sqrt(the
@@ -277,23 +292,28 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
         if moe_layers:
             # (Eh: the experts held HERE; the router keeps every output)
             E, Eh, Fe = cfg.n_experts, cfg.experts_held, cfg.expert_ff
+            De = cfg.expert_in  # the experts' own width: a latent's or D
             moe = {
-                "router": dense((L, D, E)),
-                "w_up": dense((L, Eh, D, Fe)),
-                "w_down": dense((L, Eh, Fe, D), scale=1.0 / math.sqrt(Fe)),
+                "router": dense((Lm, D, E)),
+                "w_up": dense((Lm, Eh, De, Fe)),
+                "w_down": dense((Lm, Eh, Fe, De), scale=1.0 / math.sqrt(Fe)),
             }
             if gated:
-                moe["w_gate"] = dense((L, Eh, D, Fe))
+                moe["w_gate"] = dense((Lm, Eh, De, Fe))
             if cfg.moe_router == "sigmoid" and cfg.moe_select_bias:
                 # float32 always; init_params sets it (balance_router_bias)
-                moe["router_bias"] = jnp.zeros((L, E), jnp.float32)
+                moe["router_bias"] = jnp.zeros((Lm, E), jnp.float32)
             if cfg.n_shared_experts:
                 Fs = cfg.shared_ff
-                moe["shared"] = {
-                    "w_gate": dense((L, D, Fs)),
-                    "w_up": dense((L, D, Fs)),
-                    "w_down": dense((L, Fs, D), scale=1.0 / math.sqrt(Fs)),
-                }
+                # (the keys' order is the seeded weights': gate, up, down)
+                shared = {"w_gate": dense((Lm, D, Fs))} if gated else {}
+                shared["w_up"] = dense((Lm, D, Fs))
+                shared["w_down"] = dense(
+                    (Lm, Fs, D), scale=1.0 / math.sqrt(Fs))
+                moe["shared"] = shared
+            if cfg.moe_latent:
+                moe["latent_in"] = dense((Lm, D, De))
+                moe["latent_out"] = dense((Lm, De, D))
             layers["moe"] = moe
         else:
             mlp = {
@@ -335,7 +355,8 @@ def _init_params(cfg: ModelConfig, key, dtype) -> Params:
     k_dense = cfg.first_k_dense
     if cfg.layer_types:
         params["layers"] = layer_group(
-            L, cfg.is_moe, La=cfg.cache_layers, Ls=cfg.state_layers)
+            L, cfg.is_moe, La=cfg.cache_layers, Ls=cfg.state_layers,
+            Lm=cfg.n_expert_layers if cfg.single_branch else None)
     else:
         params["layers"] = layer_group(L - k_dense, cfg.is_moe)
     if k_dense:
@@ -485,6 +506,8 @@ def _activate(up, gate, cfg: ModelConfig):
         return jax.nn.relu(gate) * up
     if cfg.activation == "gelu_exact":  # gpt-neox: erf, not tanh approx
         return jax.nn.gelu(up, approximate=False)
+    if cfg.activation == "relu2":  # nemotron-h: relu(x)^2, no gate
+        return jnp.square(jax.nn.relu(up))
     return jax.nn.gelu(up, approximate=True)
 
 
@@ -801,11 +824,12 @@ def _router_scores(xf, p):
 
 def _split_expert_stack(moe: Params):
     """A stacked expert group's tree as (the rest, {w_gate, w_up, w_down}):
-    the three [L, E, ...] stacks stay out of a layer scan's xs and are read
-    in place (_moe_dropless's ``experts`` / ``layer``)."""
+    the three [L, E, ...] stacks (two under an ungated activation) stay out
+    of a layer scan's xs and are read in place (_moe_dropless's ``experts`` /
+    ``layer``)."""
     names = ("w_gate", "w_up", "w_down")
     return ({n: a for n, a in moe.items() if n not in names},
-            {n: moe[n] for n in names})
+            {n: moe[n] for n in names if n in moe})
 
 
 def _moe_router(xf, p, cfg: ModelConfig):
@@ -915,6 +939,15 @@ def balance_router_bias(params: Params, cfg: ModelConfig):
                                experts=stack, layer=i)
         return x + out, bias
 
+    def continued(x, tokens):
+        """The batch for the next pass: behind its prompt every row goes on
+        with the model's own greedy tokens of this pass."""
+        # a row's logits at a time: [T, V] float32, never [R, T, V]
+        greedy = lax.map(
+            lambda xr: jnp.argmax(final_logits(params, cfg, xr[None])[0], -1), x)
+        shifted = jnp.concatenate([tokens[:, :1], greedy[:, :-1]], axis=1)
+        return jnp.where(prompt, tokens, shifted.astype(tokens.dtype))
+
     def one_pass(_, carry):
         tokens = carry[1]
         x = embed_tokens(params, cfg, tokens, positions)
@@ -924,14 +957,35 @@ def balance_router_bias(params: Params, cfg: ModelConfig):
                 x, positions, mask)
         x, bias = lax.scan(
             layer, x, (layers, jnp.arange(cfg.n_layers - cfg.first_k_dense)))
-        # a row's logits at a time: [T, V] float32, never [R, T, V]
-        greedy = lax.map(
-            lambda xr: jnp.argmax(final_logits(params, cfg, xr[None])[0], -1), x)
-        shifted = jnp.concatenate([tokens[:, :1], greedy[:, :-1]], axis=1)
-        return bias, jnp.where(prompt, tokens, shifted.astype(tokens.dtype))
+        return bias, continued(x, tokens)
+
+    def layer_of_a_kind(x, xs):
+        """One mixer kind a layer (cfg.layer_types): the block as forward
+        runs it, stateless; an expert layer's bias is solved where the block
+        reads its router (_moe_dropless's ``router_fix``)."""
+        lp, i = xs
+        got = []
+
+        def fix(rx, p):
+            got.append(_balanced_bias(_router_scores(rx, p),
+                                      cfg.n_experts_per_tok))
+            return dict(p, router_bias=got[0])
+
+        x = transformer_block(
+            lp, cfg, x, positions, mask,
+            moe_kw={"experts": stack, "layer": _slot_of(cfg.moe_slots, i)
+                    if cfg.single_branch else i, "router_fix": fix})
+        return x, got[0] if got else None
+
+    def one_pass_of_kinds(_, carry):
+        tokens = carry[1]
+        x = embed_tokens(params, cfg, tokens, positions)
+        x, bias = _scan_layer_runs(cfg, layers, layer_of_a_kind, x)
+        return jnp.concatenate(bias, axis=0), continued(x, tokens)
 
     bias, _ = lax.fori_loop(
-        0, _BALANCE_PASSES, one_pass,
+        0, _BALANCE_PASSES,
+        one_pass_of_kinds if cfg.layer_types else one_pass,
         (jnp.zeros_like(moe["router_bias"]), jnp.asarray(tokens)))
     return bias
 
@@ -970,7 +1024,8 @@ def center_router(params: Params, cfg: ModelConfig):
     ("ffn_norm", granite: known only behind the layer's mixer, so the block
     centres the matrix where it reads it, _moe_dropless's ``router_fix``).
     A model's leading dense layers (cfg.first_k_dense) run first and route
-    nothing. With a multi-token-prediction layer (cfg.mtp_layers) the MTP
+    nothing, as do the layers of another kind where an expert layer is a kind
+    of layer (cfg.single_branch). With a multi-token-prediction layer (cfg.mtp_layers) the MTP
     block's router is centred too, behind the centred trunk, on what
     mtp_forward feeds it; returns (the trunk's [L, D, E], the MTP block's
     [1, D, E])."""
@@ -992,6 +1047,8 @@ def center_router(params: Params, cfg: ModelConfig):
                 / jnp.dot(m, m)).astype(w.dtype)
 
     def stack_index(i):  # a layer's place in the EXPERT layers' stack
+        if cfg.single_branch:
+            return _slot_of(cfg.moe_slots, i)
         return i - cfg.first_k_dense if cfg.first_k_dense else i
 
     def deep_mean(a):  # [R, T, D] -> [D] over the deeper half of every row
@@ -1013,9 +1070,9 @@ def center_router(params: Params, cfg: ModelConfig):
         router's own input where the block reads it, [the centred matrix])."""
         got = []
 
-        def fix(rx, w):
-            got.append(centred(w, deep_mean(rx.reshape(R, T, -1))))
-            return got[0]
+        def fix(rx, p):
+            got.append(centred(p["router"], deep_mean(rx.reshape(R, T, -1))))
+            return dict(p, router=got[0])
 
         return fix, got
 
@@ -1027,7 +1084,7 @@ def center_router(params: Params, cfg: ModelConfig):
             rope_local=layer_rope_flag(cfg, i),
             moe_kw={"experts": stack, "layer": stack_index(i),
                     "router_fix": fix})
-        return x, got[0]
+        return x, got[0] if got else None  # (a layer that routes nothing)
 
     x = embed_tokens(params, cfg, jnp.asarray(tokens), positions)
     layers = dict(params["layers"], moe=rest)
@@ -1104,7 +1161,18 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
     in a fourth stat, and the layer returns the held experts' part plus the
     shared expert: what this chip would hand to the exchange with its
     partners, which is not built. ``router_fix(router input [N, D], W_r) ->
-    W_r`` replaces the router's matrix before it is read (center_router)."""
+    p`` replaces the router's parameters before they are read (center_router's
+    matrix, balance_router_bias's selection bias).
+
+    Experts in a LATENT (``latent_in`` / ``latent_out`` in ``p``: nemotron-h's
+    LatentMoE, cfg.moe_latent): ``z = x W_in`` [N, Dl] is made BEFORE the
+    dispatch, so the sorted rows, the grouped products and the combine run Dl
+    wide, and ``W_out`` is applied ONCE to the weighted float32 sum (it is
+    linear and has no bias); router and shared expert read ``x`` at the
+    model's width. Both projections run under ``moe.experts`` in scopes of
+    their own (``latent.in`` / ``latent.out``: tracing.DEVICE_NESTED). An
+    ungated activation (cfg.gated_mlp False: "relu2") has no ``w_gate``: two
+    grouped products an expert."""
     B, T, D = x.shape
     k = cfg.n_experts_per_tok
     E = cfg.experts_held  # the groups of the product: the experts held HERE
@@ -1115,8 +1183,14 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
     with jax.named_scope("moe.router"):
         rx = xf if router_x is None else router_x.reshape(N, D)
         if router_fix is not None:
-            p = dict(p, router=router_fix(rx, p["router"]))
+            p = router_fix(rx, p)
         topi, w = _moe_router(rx, p, cfg)
+
+    xe = xf  # what the routed experts read
+    if "latent_in" in p:
+        with jax.named_scope("moe.experts"), jax.named_scope("latent.in"):
+            xe = matmul(xf, p["latent_in"])
+    De = xe.shape[-1]
 
     with jax.named_scope("moe.dispatch"):
         flat = topi.reshape(M)
@@ -1133,7 +1207,7 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # [M]
         gs = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
         n_live = jnp.sum(gs)  # the sorted rows before it belong to a group
-        xs = jnp.take(xf, order // k, axis=0)  # [M, D]
+        xs = jnp.take(xe, order // k, axis=0)  # [M, De]
         if layer is not None:
             L = experts["w_up"].shape[0]
             sizes = lax.dynamic_update_slice(
@@ -1143,7 +1217,8 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
             sizes, stack = gs, lambda a: a  # noqa: E731
 
     with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(xs, stack(experts["w_gate"]), sizes)
+        gate = (grouped_matmul(xs, stack(experts["w_gate"]), sizes)
+                if "w_gate" in experts else None)
         up = grouped_matmul(xs, stack(experts["w_up"]), sizes)
         # (rows past the last group hold whatever the buffer held, through
         # all three products: a row's product reads no other row)
@@ -1156,10 +1231,15 @@ def _moe_dropless(x, p, cfg: ModelConfig, live=None, experts=None, layer=None,
         # on the chip, k being no multiple of a tile's rows)
         inv = jnp.zeros((M,), jnp.int32).at[order].set(
             jnp.arange(M, dtype=jnp.int32)).reshape(N, k).T
-        yg = jnp.take(y, inv.reshape(M), axis=0).reshape(k, N, D)
+        yg = jnp.take(y, inv.reshape(M), axis=0).reshape(k, N, De)
         # (a select, never a 0 / 1 multiplier: a masked row may hold NaN)
         kept = jnp.where((inv < n_live)[..., None], yg.astype(jnp.float32), 0.0)
         out = jnp.sum(kept * w.T[..., None], axis=0)
+
+    if "latent_out" in p:  # back to the model's width, once, in float32
+        with jax.named_scope("moe.experts"), jax.named_scope("latent.out"):
+            out = jnp.dot(out.astype(x.dtype), p["latent_out"].astype(x.dtype),
+                          preferred_element_type=jnp.float32)
 
     if "shared" in p:
         with jax.named_scope("moe.shared"):
@@ -1538,6 +1618,10 @@ def transformer_block(
     ``"ssm"`` (the mixer's output is the whole branch: no attention runs,
     no page is written) or ``"attn"`` (no mixer runs); both residual adds
     take their branch times cfg.residual_multiplier.
+
+    Under cfg.single_branch (nemotron-h) the layer's tree holds ONE branch,
+    ``"ssm"``, ``"attn"`` OR ``"moe"``, and ONE norm: ``x + branch(ln1(x))``,
+    one residual add. Nothing runs in the place of a second branch.
     """
     B, T, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -1565,10 +1649,14 @@ def transformer_block(
             scale=False)
         return join(x, _ffn(norm(x, "ln2"), lp, cfg, lora, moe_kw, moe_sink),
                     ffn_part, scale=False)
+    if cfg.single_branch and "moe" in lp:  # an expert layer alone
+        return join(x, _ffn(h, lp, cfg, lora, moe_kw, moe_sink), ffn_part)
     if cfg.layer_types and "ssm" in lp:  # a recurrent-only layer
         mix_out = (ssm_hook(h) if ssm_hook is not None
                    else ssm_mixer(lp["ssm"], cfg, h)[0])
         x = join(x, mix_out, "ssm.out_proj")
+        if cfg.single_branch:
+            return x
         return join(x, _ffn(norm(x, "ln2"), lp, cfg, lora, moe_kw, moe_sink),
                     ffn_part)
     mix_out = None
@@ -1665,6 +1753,8 @@ def transformer_block(
             return x + mlp_out
     # (gemma-2/olmo2, cfg.post_norms: a branch's OUTPUT is normed)
     x = join(x, attn_out, "attn.out", "ln1_post" if cfg.post_norms else None)
+    if cfg.single_branch:  # an attention layer alone
+        return x
 
     h2 = x if cfg.no_pre_norms else norm(x, "ln2")
     if cfg.moe_router_input == "attn_norm":
@@ -1921,7 +2011,10 @@ def forward(
     layer reads and writes slot cfg.state_slots[l] of the state and no page,
     an "attention" layer layer cfg.cache_slots[l] of the pool and no state.
     The state, the pool and the expert stack are the carries of every run,
-    in place.
+    in place. Where the pattern repeats a UNIT of unlike layers (nemotron-h's
+    ``E M`` five times, cfg.layer_units) the scan's body is the whole unit:
+    an "moe" layer (cfg.single_branch) reads its slot cfg.moe_slots[l] of
+    the expert stacks and neither state nor page.
     """
     B, T = input_ids.shape
     off_b, positions = _chunk_positions(offset, B, T)
@@ -2092,8 +2185,10 @@ def _run_layers(
         if cfg.moe_dropless and "moe" in lp:
             moe_kw = {"live": token_live}
             if expert_stack is not None:
-                moe_kw.update(experts=expert_stack,
-                              layer=layer_idx - cfg.first_k_dense)
+                moe_kw.update(
+                    experts=expert_stack,
+                    layer=_slot_of(cfg.moe_slots, layer_idx)
+                    if cfg.single_branch else layer_idx - cfg.first_k_dense)
 
         if lcache is None:  # training/scoring path: plain block
             return (
@@ -2430,26 +2525,55 @@ def _slot_of(slots: tuple, layer_idx):
     return jnp.asarray(slots, jnp.int32)[layer_idx]
 
 
+_KIND_STACK = {"mamba": "ssm", "attention": "attn", "moe": "moe"}  # a layer
+# kind -> the stack of ``layers`` that only layers of that kind hold
+
+
+def _kind_stacks(cfg: ModelConfig) -> dict:
+    """{kind: (its stack's name, layer -> slot)} for the kinds ``cfg``'s
+    ``layer_types`` names; every other part of ``layers`` is stacked over all
+    the layers (granite's expert layers: every layer has one)."""
+    return {k: (_KIND_STACK[k], cfg.kind_slots[k]) for k in set(cfg.layer_types)}
+
+
 def _scan_layer_runs(cfg: ModelConfig, layers: Params, body, carry):
     """The stacked layers of a model of one mixer kind a layer
-    (cfg.layer_types), one ``lax.scan`` a RUN of like layers
-    (cfg.layer_runs): ``body(carry, (lp, layer index)) -> (carry, y)`` gets a
-    layer's tree read out of the stacks where they lie (what scan does with
-    its xs): the common parts at the layer's index, its ``ssm`` OR ``attn``
-    at its slot of that kind. Returns (carry, [a run's stacked ys])."""
-    common = {n: a for n, a in layers.items() if n not in ("ssm", "attn")}
+    (cfg.layer_types), one ``lax.scan`` a RUN of a repeated unit of layers
+    (cfg.layer_units: a run of like layers is a unit of one): ``body(carry,
+    (lp, layer index)) -> (carry, y)`` gets a layer's tree read out of the
+    stacks where they lie (what scan does with its xs): the common parts at
+    the layer's index, the stack of its KIND (``ssm``, ``attn``, or ``moe``
+    under cfg.single_branch) at its slot of that kind. A unit of several
+    layers is ONE scan body that runs them in order. Returns (carry, [a run's
+    ys that are not None, stacked in layer order])."""
+    kinds = _kind_stacks(cfg)
+    own = {name for name, _ in kinds.values()}
+    common = {n: a for n, a in layers.items() if n not in own}
     ys = []
-    for kind, start, count, slot in cfg.layer_runs:
-        mixer = "ssm" if kind == "mamba" else "attn"
+    for unit, start, repeats in cfg.layer_units:
+        # (unit position j of repetition r: layer start + r p + j, at slot
+        # slot0[j] + r x the unit's layers of that kind)
+        p = len(unit)
+        slot0 = [kinds[t][1][start + j] for j, t in enumerate(unit)]
 
-        def run_body(c, i, mixer=mixer, delta=start - slot):
-            lp = dict(_layer_of(common, i),
-                      **{mixer: _layer_of(layers[mixer], i - delta)})
-            return body(c, (lp, i))
+        def run_body(c, r, unit=unit, start=start, p=p, slot0=slot0):
+            out = []
+            for j, t in enumerate(unit):
+                name = kinds[t][0]
+                i = start + r * p + j
+                lp = dict(_layer_of(common, i), **{name: _layer_of(
+                    layers[name], slot0[j] + r * unit.count(t))})
+                c, y = body(c, (lp, i))
+                out.append(y)
+            return c, tuple(out)
 
         carry, y = lax.scan(
-            run_body, carry, jnp.arange(start, start + count, dtype=jnp.int32))
-        ys.append(y)
+            run_body, carry, jnp.arange(repeats, dtype=jnp.int32))
+        y = [a for a in y if a is not None]
+        if y:  # [repeats, ...] a unit position -> layer order
+            ys.append(y[0] if len(y) == 1 else jax.tree.map(
+                lambda *a: jnp.stack(a, axis=1).reshape(-1, *a[0].shape[1:]),
+                *y))
     return carry, ys
 
 
@@ -2461,7 +2585,8 @@ def unstack_layers(params: Params, cfg: ModelConfig | None = None) -> Params:
     through like any other leaves. A model of one mixer kind a layer
     (``cfg.layer_types``: the schema alone does not say which layer holds
     which) needs its ``cfg``: layer ``l``'s tree holds the common parts at
-    ``l`` and ``ssm`` at cfg.state_slots[l] OR ``attn`` at cfg.cache_slots[l]."""
+    ``l`` and ``ssm`` at cfg.state_slots[l] OR ``attn`` at cfg.cache_slots[l]
+    (OR, under cfg.single_branch, ``moe`` at cfg.moe_slots[l])."""
     import numpy as np
 
     stacked = params["layers"]
@@ -2474,12 +2599,12 @@ def unstack_layers(params: Params, cfg: ModelConfig | None = None) -> Params:
             lambda a: np.ascontiguousarray(np.asarray(a[i])), tree)
 
     if cfg is not None and cfg.layer_types:
-        common = {n: a for n, a in stacked.items() if n not in ("ssm", "attn")}
+        kinds = _kind_stacks(cfg)
+        own = {name for name, _ in kinds.values()}
+        common = {n: a for n, a in stacked.items() if n not in own}
         out["layers"] = [
-            dict(at(common, i), **(
-                {"ssm": at(stacked["ssm"], cfg.state_slots[i])}
-                if t == "mamba" else
-                {"attn": at(stacked["attn"], cfg.cache_slots[i])}))
+            dict(at(common, i), **{
+                kinds[t][0]: at(stacked[kinds[t][0]], kinds[t][1][i])})
             for i, t in enumerate(cfg.layer_types)]
         return out
     # a model's leading dense layers (their own stacked group) come first:
@@ -2512,12 +2637,15 @@ def restack_layers(params: Params) -> Params:
 
     if any("attn" not in lp for lp in layers):
         # one mixer kind a layer: the common parts over every layer, each
-        # mixer over the layers that hold it, in layer order
+        # kind's stack over the layers that hold it, in layer order (the
+        # expert layers are such a kind where some layer has none)
+        own = ("ssm", "attn") + (
+            ("moe",) if any("moe" not in lp for lp in layers) else ())
         out["layers"] = dict(
-            stack([{n: a for n, a in lp.items() if n not in ("ssm", "attn")}
+            stack([{n: a for n, a in lp.items() if n not in own}
                    for lp in layers]),
-            ssm=stack([lp["ssm"] for lp in layers if "ssm" in lp]),
-            attn=stack([lp["attn"] for lp in layers if "attn" in lp]))
+            **{name: stack([lp[name] for lp in layers if name in lp])
+               for name in own})
         return out
 
     # leading dense layers of an expert model go back to their own group

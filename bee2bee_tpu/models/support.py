@@ -50,7 +50,7 @@ GROUNDS = (
     # granite-4.0-h: a recurrent mixer OR attention a layer (cfg.layer_types:
     # a state as deep as the one kind, a pool as deep as the other) under
     # dropless expert layers of which the chip may hold a share
-    ("layer_kinds", lambda cfg: _layer_kinds(cfg),
+    ("layer_kinds", lambda cfg: _layer_kinds(cfg) and not cfg.single_branch,
      "its layers hold one mixer kind each (recurrent state in some, K/V "
      "pages in the others) under dropless expert layers, of whose experts "
      "the chip may hold a share"),
@@ -62,6 +62,15 @@ GROUNDS = (
      "its layers are attention (a window in some) under dropless expert "
      "layers, of whose experts the chip may hold a share, and it drafts with "
      "a multi-token-prediction layer of its own"),
+    # nemotron-h: ``layer_types``' third kind, a layer of ONE branch under ONE
+    # norm (a recurrent mixer, attention OR an expert layer of ungated
+    # experts in a latent); state, pool and expert stacks each as deep as
+    # their kind (cfg.single_branch)
+    ("single_branch", lambda cfg: cfg.single_branch,
+     "its layers are ONE branch each (a recurrent mixer, attention or a "
+     "dropless expert layer, whose experts may live in a latent and of which "
+     "the chip may hold a share): recurrent state in some, K/V pages in one "
+     "kind, neither in the expert layers"),
 )
 
 
@@ -219,7 +228,60 @@ REFUSED = {
         ("ring_forward", "the ring's walk knows no MTP layer; use "
          "core.forward"),
     ),
+    "single_branch": (
+        # (the first two are properties of a published config.json, asked
+        # about where it is read: config._nemotron_h_from_hf)
+        ("mtp_module", "the published multi-token-prediction module (an "
+         "attention layer and an expert layer, each of one branch, with "
+         "weights shared across draft steps) is not built: core.mtp_forward "
+         "runs ONE block of two branches and the engine's mtp tier drafts one "
+         "token a step; a cut configuration leaves it out "
+         "(num_nextn_predict_layers 0) and says so in `reduced`"),
+        ("mlp_alone_layer", "a dense MLP-alone layer ('-' in "
+         "hybrid_override_pattern) is a fourth kind of layer, which "
+         "layer_types does not name"),
+        ("prefix_cache", "a pinned block holds K/V only — the recurrent "
+         "layers' state at the prefix's end would have to be snapshotted"),
+        ("spec_mesh_drafter", _NO_ROLLBACK),
+        ("spec_model_drafter", _NO_ROLLBACK),
+        ("spec_ngram", _NO_ROLLBACK),
+        ("seq_attention", "the state is not sharded over a seq axis"),
+        ("mesh_model", "neither the mixer's heads and state nor the grouped "
+         "product are partitioned over a model axis (--mesh-shape model:N)"),
+        ("mesh_expert", "a share of the experts is a property of the "
+         "configuration (n_experts_held): the exchange of the partial latent "
+         "sums between the chips of a layer is not built, and the grouped "
+         "product is not partitioned over an expert axis"),
+        ("multi_lora", "neither the mixer's projections, the latent "
+         "projections nor the expert layers have an adapter path"),
+        ("prefill_chunk", "a chunk of {prefill_chunk} does not divide "
+         "max_seq_len {max_seq_len}, so the last window would re-feed tokens "
+         "the state already absorbed"),
+        ("pipeline_stages", "a stage's per-microbatch cache holds K/V only, "
+         "as deep as the stage's layers, and its loop knows no layer without "
+         "attention"),
+        ("kv_export", "the state has no export format yet"),
+        ("kv_int8", "the int8 pool's per-layer slices indexed by a layer's "
+         "cache slot are not tested"),
+        ("weight_int8", "the grouped product reads the expert stacks "
+         "unquantised, and the latent projections are not tested quantised"),
+        ("pipeline_stage_split", "a stage's walk reads every layer's branch "
+         "at the layer's own index, not its slot of its kind, and runs two "
+         "branches a layer; use core.forward"),
+        ("pipeline_trunk", "the trunk's walk reads every layer's branch at "
+         "the layer's own index, not its slot of its kind, and runs two "
+         "branches a layer; use core.forward"),
+        ("ring_forward", "the ring's walk reads every layer's branch at the "
+         "layer's own index, not its slot of its kind, and runs two branches "
+         "a layer; use core.forward"),
+    ),
 }
+
+
+def why(ground: str, feature: str) -> str:
+    """The sentence of ``REFUSED``'s row (ground, feature): for a refusal
+    raised where no ModelConfig exists yet (a converter of config.json)."""
+    return dict(REFUSED[ground])[feature]
 
 
 def require(cfg: ModelConfig, *features: str, **detail):

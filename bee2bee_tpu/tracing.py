@@ -251,7 +251,8 @@ def prog_scope(name: str):
 # of its ROOT instruction, so the small ops that finish a product (a bias, a
 # multiplier, the residual add) sit under the product's part. A WRAPPER
 # (DEVICE_WRAPPERS) encloses whole parts and a capture books an op under the
-# part inside it. Where each is opened, which models run it and which metric
+# part inside it; a NESTED scope (DEVICE_NESTED) names a piece of the part that
+# encloses it. Where each is opened, which models run it and which metric
 # reads it: docs/OBSERVABILITY.md's table "The block's parts", a row a name.
 DEVICE_PARTS = (
     "embed.tokens", "norm.block",
@@ -263,6 +264,11 @@ DEVICE_PARTS = (
     "loop.norm", "head.logits", "mtp.proj", "mtp.head", "spec.accept", "sample.draw",
 )
 DEVICE_WRAPPERS = ("spec.verify", "mtp.block")
+# Scopes opened INSIDE a part, for a piece of it that a reader of its own
+# tells apart: an op under ``moe.experts/latent.in`` is the part's
+# (``block_scopes`` books the first part in the path) and the latent
+# projection's (``benchmark/readers/nemotron_scopes.py`` books the longer name)
+DEVICE_NESTED = ("latent.in", "latent.out")
 
 
 class PhaseClock:

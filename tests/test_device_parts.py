@@ -1,5 +1,5 @@
 """Every model's program carries the parts of ``tracing.DEVICE_PARTS``: the
-seven benchmarked configurations' ``core.forward`` (the multi-token-prediction
+eight benchmarked configurations' ``core.forward`` (the multi-token-prediction
 layer behind it where the model has one), cut to one or two layers at the
 published widths, lowered on the CPU over the paged pool with the ragged
 reader interpreted. Shapes only: nothing is compiled or run."""
@@ -16,10 +16,10 @@ import pytest
 from bee2bee_tpu.models import core
 from bee2bee_tpu.models.config import get_config
 from bee2bee_tpu.ops.ragged import make_ragged_attn_fn
-from bee2bee_tpu.tracing import DEVICE_PARTS, DEVICE_WRAPPERS
+from bee2bee_tpu.tracing import DEVICE_NESTED, DEVICE_PARTS, DEVICE_WRAPPERS
 
 PARTS = set(DEVICE_PARTS)
-WRAPPERS = set(DEVICE_WRAPPERS)
+WRAPPERS = set(DEVICE_WRAPPERS) | set(DEVICE_NESTED)
 BS = 16  # pool block
 
 # the benchmark's configurations (BENCHMARK.json ``configs``: their server
@@ -33,6 +33,8 @@ CUTS = {
     "granite-4.0-h-small-10l-e36": {
         "n_layers": 2, "layer_types": ("mamba", "attention")},
     "k-exaone-236b-a23b-5l-e16": {"n_layers": 2},
+    "nemotron-3-super-120b-a12b-11l-e128": {  # a layer of each of its kinds
+        "n_layers": 3, "layer_types": ("moe", "mamba", "attention")},
 }
 SHAPES = {"decode": (4, 1), "prefill": (2, 32)}  # [B, T]
 

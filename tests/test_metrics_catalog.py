@@ -280,7 +280,7 @@ async def test_full_surface_scrape_matches_catalog():
 
 
 def test_every_device_trace_scope_the_model_opens_is_documented():
-    """``tracing.DEVICE_PARTS`` (+ ``DEVICE_WRAPPERS``) IS the set of
+    """``tracing.DEVICE_PARTS`` (+ ``DEVICE_WRAPPERS`` + ``DEVICE_NESTED``) IS the set of
     ``jax.named_scope``s that models/core.py, engine/engine.py and
     engine/sampling.py open, which the benchmark's scope readers book device
     time by: the sources open no other (the ``prog.*`` roots are
@@ -288,13 +288,13 @@ def test_every_device_trace_scope_the_model_opens_is_documented():
     the document's table names each one."""
     import re
 
-    from bee2bee_tpu.tracing import DEVICE_PARTS, DEVICE_WRAPPERS
+    from bee2bee_tpu.tracing import DEVICE_NESTED, DEVICE_PARTS, DEVICE_WRAPPERS
 
     pkg = DOC.parent.parent / "bee2bee_tpu"
     src = "".join((pkg / f).read_text() for f in (
         "models/core.py", "engine/engine.py", "engine/sampling.py"))
     opened = set(re.findall(r'named_scope\(\s*"([a-z_]+\.[a-z_]+)"', src))
-    table = DEVICE_PARTS + DEVICE_WRAPPERS
+    table = DEVICE_PARTS + DEVICE_WRAPPERS + DEVICE_NESTED
     assert len(table) == len(set(table))
     # ``ffn_part`` / ``join``: the residual add's scope is a NAME of the table
     # picked by the branch's kind, never a scope or none

@@ -22,6 +22,7 @@ PRESET = {  # a tiny model of each kind
     "looped_stack": "tiny-ouro",
     "layer_kinds": "tiny-granite",
     "mtp_layer": "tiny-exaone",
+    "single_branch": "tiny-nemotron",
 }
 DETAIL = dict(prefill_chunk=48, max_seq_len=128)
 ROWS = [(ground, feature, why)
@@ -84,7 +85,9 @@ def test_the_engine_names_each_feature_for_the_configuration_that_turns_it_on():
     # every feature the engine can name is a feature of the table, and the
     # table's others are asked about where they are built
     asked_elsewhere = {"pipeline_stages", "kv_export", "pipeline_stage_split",
-                       "pipeline_trunk", "ring_forward"}
+                       "pipeline_trunk", "ring_forward",
+                       # (of a published config.json: config._nemotron_h_from_hf)
+                       "mtp_module", "mlp_alone_layer"}
     assert set(IN_USE) | asked_elsewhere == {f for _, f, _ in ROWS}
     # a model with a multi-token-prediction layer speculates with THAT: no
     # kind refuses spec_mtp, and the n-gram floor is not what spec_tokens asks
@@ -108,6 +111,8 @@ def test_the_engine_names_each_feature_for_the_configuration_that_turns_it_on():
     ("tiny-exaone", ("kv_export", "spec_ngram", "mesh_expert"), "spec_ngram"),
     ("k-exaone-236b-a23b-5l-e16", ("weight_int8", "prefix_cache"), "prefix_cache"),
     ("k-exaone-236b-a23b", ("pipeline_stages", "spec_model_drafter"), "spec_model_drafter"),
+    ("tiny-nemotron", ("weight_int8", "mesh_expert", "kv_export"), "mesh_expert"),
+    ("nemotron-3-super-120b-a12b-11l-e128", ("kv_int8", "prefix_cache"), "prefix_cache"),
 ])
 def test_of_two_refused_features_the_table_s_first_is_raised(model, features, first):
     with pytest.raises(FeatureUnsupported) as err:

@@ -879,6 +879,71 @@ def test_the_granite_centring_program_fits_beside_the_weights(one_chip, mosaic_g
     assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
 
 
+# ---- nemotron-3-super-120b-a12b-11l-e128 (PR 58): a layer is ONE of a Mamba-2
+# mixer, attention or a LatentMoE layer (E M E M E M E M E M *), 128 of every
+# expert layer's 512 experts held: TWO layer bodies (the unit E M five times,
+# then *) over a 5-deep state, a 1-deep pool and a [5, 128, ...] expert stack
+
+NEMOTRON = get_config("nemotron-3-super-120b-a12b-11l-e128")
+
+
+@pytest.mark.parametrize("B,T,MB", [(64, 1, 64), (8, 128, 8), (1, 512, 32)],
+                         ids=["nemotron-decode-64", "nemotron-prefill-8x128",
+                              "nemotron-prefill-512"])
+def test_the_nemotron_cell_programs_hold_two_layer_bodies_and_keep_their_stacks_in_place(
+        one_chip, mosaic_state_step, mosaic_grouped, B, T, MB):
+    """The cut preset as the cell serves it (3,200 pool blocks, 64 rows): one
+    ``while`` a run of the repeated UNIT (two layer loops: eleven runs of like
+    layers would be eleven); the 1.36 GB state, the pool and the 7.05 GB expert
+    stack are the runs' carries, touched by the Mosaic calls and a layer's own
+    slice alone; an expert is TWO grouped products in the latent's width; every
+    part runs under its scope, the latent projections NESTED in ``moe.experts``;
+    and the program fits the chip's 15.75 GB."""
+    cfg = NEMOTRON
+    lowered, slice_elems = _forward_program(cfg, B, T, MB, 3200, sharding=one_chip)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(cfg.layer_units) == 2
+    assert _custom_calls(text, "kv.write") == 1 and _custom_calls(text, "attn.read") == 1
+    assert _custom_calls(text, "moe.experts") == 2  # up and down, in ONE body
+    if T == 1:  # the state-step kernel, once: the unit's mixer
+        assert _custom_calls(text, "ssm.step") == 1
+    for scope in ("ssm.in_proj", "ssm.out_proj", "attn.qkv", "attn.out", "moe.router",
+                  "moe.dispatch", "moe.experts", "moe.experts/latent.in",
+                  "moe.experts/latent.out", "moe.combine", "moe.shared", "head.logits"):
+        assert re.search(rf'op_name="[^"]*{re.escape(scope)}', text), scope
+    one_matrix = cfg.experts_held * cfg.expert_in * cfg.expert_ff
+    assert _pool_sized_ops(text, one_matrix, cfg.n_expert_layers) == []
+    state_layer = B * cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+    if T == 1:  # a longer chunk writes its layer's slice back (one fusion a layer)
+        assert _pool_sized_ops(text, state_layer, cfg.state_layers) == []
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.temp_size_in_bytes
+             + m.output_size_in_bytes - m.alias_size_in_bytes)
+    print(f"nemotron {B}x{T}: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, alias "
+          f"{m.alias_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB, "
+          f"whiles {text.count('while(')}")
+    assert total < 15.75e9, total
+
+
+def test_the_nemotron_balancing_program_fits_beside_the_weights(one_chip, mosaic_grouped):
+    """core.balance_router_bias at the cut preset: 9.30 GB of weights in, five
+    [512] selection biases out, the balancing batch's temporaries beside them."""
+    cfg = NEMOTRON
+    shapes = jax.eval_shape(
+        lambda: jax.jit(core._init_params, static_argnums=(0, 2))(
+            cfg, jax.random.PRNGKey(0), jnp.dtype(jnp.bfloat16)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    compiled = jax.jit(core.balance_router_bias, static_argnums=1).lower(args, cfg).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes >= cfg.n_expert_layers * cfg.n_experts * 4  # (tiled: 8 rows)
+    print(f"nemotron balancing: arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB")
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.0e9
+
+
 # ---- q / k / v read where they lie (PR 48): to core.QKV_IN_PLACE_ROWS rows a
 # barrier keeps the three products plain, so each takes the stacked parameter
 # and the layer index; the parent's folded the head split into them and
