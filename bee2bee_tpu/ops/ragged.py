@@ -34,27 +34,44 @@ the pool:
   put a 1-blocked head axis second-to-last and fail to lower, and a
   bool-mask operand blocked per 16-lane page would violate the same rule
   — the constraint that shaped ops/flash.py's head-major layout).
-- **Small pages: the kernel starts its own copies, and a RUN of adjacent
-  pages is ONE copy** (PR 53). Where a page is under 128 KB (4 GQA heads x
-  128: 32 KB) 32 page operands a step cost more than their bytes, so the
-  pool is ONE operand left where it lies (``pl.ANY``) and the kernel
-  brings a tile with ``pltpu.make_async_copy`` into one of two VMEM tile
-  buffers ``[Tp, 2, Th, BS, hd]``, the NEXT step's copies started before
-  this step's products (the work list holds every step's pages in SMEM).
-  The pool is page-major, so table entries whose pool blocks are p, p+1,
-  .. are one stretch of a layer: _work_list marks, a (step, copy group of
-  ``R`` entries — _tile_plan: the whole tile), whether the group is such a
-  run, and the kernel brings a marked group with one copy and any other
-  page by page (engine/paged.BlockAllocator hands a row ascending runs,
-  so nearly all are). An entry that names the null block is not copied at
-  all. The items, their order and the arithmetic are the page operands'
-  to the bit. Pages of 128 KB or more (phi-3's 256 KB, ouro's 128 KB), the
-  int8 pool, a latent pool, a head size off the 128 lanes (Mosaic refuses
-  to slice an HBM ref there — phi-3's 96, gpt2's 64 as the dense readers'
-  and the int8 pool's slices keep them — and takes those as block shapes)
-  and a step that takes some of the KV heads keep the page operands:
-  which form runs follows from the shapes (_tile_plan's ``R``), never from
-  a flag or a model's name.
+- **Whole pages: the kernel starts its own copies, a RUN of adjacent
+  pages is ONE copy, and a page no query of the step can see is none**
+  (PR 53 for pages under 128 KB; every such pool since PR 59). Where a step
+  takes WHOLE pages of a float pool on the 128 lanes — K beside V of every
+  KV head (4 GQA heads x 128: 32 KB; ouro's 16 MHA heads: 128 KB; phi-3's
+  32 in its lane-aligned pool: 256 KB) or a latent row's page (640 = 5 x
+  128 lanes: 20 KB) — a page is one aligned stretch of HBM, so the pool is
+  ONE operand left where it lies (``pl.ANY``) and the kernel brings a tile
+  with ``pltpu.make_async_copy`` into one of two VMEM tile buffers
+  ``[Tp, 2, Th, BS, hd]`` (``[Tp, 1, BS, W]`` latent), the NEXT step's
+  copies started before this step's products (the work list holds every
+  step's pages in SMEM). The pool is page-major, so table entries whose
+  pool blocks are p, p+1, .. are one stretch of a layer: _work_list marks,
+  a (step, copy group of ``R`` entries — _tile_plan: the whole tile),
+  whether the group is such a run, and the kernel brings a marked group
+  with one copy and any other page by page (engine/paged.BlockAllocator
+  hands a row ascending runs, so nearly all are). An entry that names the
+  null block is not copied at all, and _work_list names the null block
+  for every entry that lies wholly past its row's causal frontier
+  (_visible_pages: the 1-2 blocks a row owns AHEAD of its offset for the
+  decode window): the copies follow the pages a query can SEE, where a
+  page operand copies whatever the table names. The items, their order
+  and the arithmetic are the page operands' to the bit. What keeps the
+  page operands is what Mosaic or the data forces: the int8 pool (its
+  scales are a page's, dequantized from the operand), a head size off the
+  128 lanes (Mosaic refuses to slice an HBM ref there — phi-3's 96, gpt2's
+  64 as the dense readers' and the int8 pool's slices keep them — and
+  takes those as block shapes) and a step that takes some of the KV heads
+  (its share of a page is pieces): which form runs follows from the shapes
+  (_tile_plan's ``R``), never from a page's size, a flag or a model's
+  name. Us a layer call, page operands -> the kernel's own copies (my chip
+  runs, PR 59; chip_smoke case ``own_copies``, rows owning 0-2 blocks
+  ahead): ouro's decode call (16 rows of 40-250 tokens) 52.5 -> 39.9,
+  joyai's latent one (64 rows of 60-480) 162.6 -> 120.3, phi-3's 108.2 ->
+  107.1 and 162.0 -> 162.5 at 1,560-1,850 tokens: operands of 256 KB were
+  at the bytes' rate, those of 128 KB and of 20 KB were not; without the
+  frontier's mask ouro reads 40.6, and st's whole-tile runs 453.9 against
+  454.3 with it (a tile that holds the frontier is rarely a whole run).
 - **The grid walks a compacted work list** (PR 31), not the table: the
   grid is ``(Hkv/Th, B x q blocks x table width/Tp)`` — head groups,
   then ONE sequential axis of the table's static length (so the compile
@@ -106,9 +123,10 @@ the pool:
   and the cache traffic follow the row's live pages rounded up to a
   tile, while the table (and with it the compile space) stays as it
   was. Dead entries INSIDE a live tile (the null block's, past a row's
-  last page) still copy the null block as page operands; the kernel's own
-  copies skip them, and the tile buffers start as zeros so that what no
-  copy has reached is finite behind its zero weight. ALiBi
+  last page; a block the row owns past its frontier) are still copied as
+  page operands; the kernel's own copies skip them, and the tile buffers
+  start as zeros so that what no copy has reached is finite behind its
+  zero weight. ALiBi
   stays dense-only (the bias needs absolute key positions per head; the
   engine validates).
 - **Online softmax** over the tile iterations with f32 m/l/acc VMEM
@@ -206,7 +224,6 @@ _TILE_TOKENS = 512  # most key positions a tile may span
 _TILE_PAGES = 32  # most table entries a step: each is one page operand
 _SCORE_ELEMS = 128 * 1024  # most [bq, Tp*BS] f32 score elements a head
 _RUN_BYTES = 2**20  # most bytes ONE copy of a run of adjacent pages moves
-_RUN_PAGE_BYTES = 128 * 1024  # a page this large is a copy of its own: R = 1
 
 
 def _round_up(n: int, m: int) -> int:
@@ -237,16 +254,21 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256,
     blocks p .. p+R-1 of a layer are one stretch of memory): the largest
     power of two, at most ``Tp``, that keeps such a copy at 1 MB — which is
     the tile itself wherever it applies (32 pages of 4 GQA heads x 128, 16
-    of granite's 8 heads: my chip runs, PR 53, a whole tile in one copy read
-    as fast as 4 copies of 256 KB at decode and 7 % faster in the 2,048
-    chunk, and tiles copied page by page 4 % faster than groups of 8 were).
-    A page that is 128 KB or more already moves near the bytes' rate
-    (phi-3's 256 KB, ouro's 128 KB): ``R`` = 1, which is the page-operand
-    program as it was, as for an int8 pool (its scales are a page's), a
-    latent pool, a head size off the 128 lanes (Mosaic slices no HBM ref
-    there; every served pool is lane-aligned) and a step that takes some
-    of the KV heads only (``Th`` < ``Hkv``, phi-3's 2,048 bucket: its share
-    of a page is pieces, and adjacent pages do not join them)."""
+    of granite's 8 heads, 8 of ouro's 16 MHA heads, 4 of phi-3's 32, a
+    latent row's 16: my chip runs, PR 53, a whole tile in one copy read as
+    fast as 4 copies of 256 KB at decode and 7 % faster in the 2,048 chunk,
+    and tiles copied page by page 4 % faster than groups of 8 were).
+    ``R`` > 1 wherever a page can be copied WHOLE, whatever its size (my
+    chip runs, PR 59: against the page operands ouro's decode call of
+    128 KB pages read 24 % faster, joyai's latent one of 20 KB pages 26 %,
+    phi-3's of 256 KB pages the same to 1 %; copy groups that keep a run
+    whole across the frontier read no faster than masking every page past
+    it): ``R`` = 1, the page-operand program, is what Mosaic or the data
+    forces — an int8 pool (its scales are a page's), a head size off
+    the 128 lanes (Mosaic slices no HBM ref there; every served pool is
+    lane-aligned) and a step that takes some of the KV heads only (``Th`` <
+    ``Hkv``, the 2,048 bucket of 32 MHA heads, the 128 bucket of ouro's 16:
+    its share of a page is pieces, and adjacent pages do not join them)."""
     nq = G * T
     bq = min(block_q, max(nq, 8))
     lanes = _round_up(hd, _LANES)
@@ -283,9 +305,9 @@ def _tile_plan(Hkv, G, T, hd, BS, MB, itemsize, quantized, block_q=256,
     while Tp < MB and fits(2 * Tp):
         Tp *= 2
     R = 1
-    page_bytes = 2 * Th * BS * hd * itemsize  # what one copy of a page moves
-    if not (quantized or latent or hd % _LANES or Th != Hkv
-            or page_bytes >= _RUN_PAGE_BYTES):
+    # what one copy of a page moves: K beside V of the heads, or a latent row
+    page_bytes = (1 if latent else 2) * Th * BS * hd * itemsize
+    if not (quantized or hd % _LANES or Th != Hkv):
         while 2 * R <= Tp and 2 * R * page_bytes <= _RUN_BYTES:
             R *= 2
     return Th, Tp, bq, R
@@ -355,6 +377,17 @@ def _run_bits(pages, run_pages, xp=jnp):
     )
 
 
+def _visible_pages(tables, off, *, chunk, block_size, xp=jnp):
+    """``tables`` [B, width] with the null block in every entry that lies
+    wholly past its row's causal frontier — its first position is above the
+    row's last query position ``off + chunk - 1``: the blocks a row owns
+    AHEAD of its offset (the decode window's, engine.blocks_per_row) hold no
+    key a query of this step can see, and the kernel's own copies start none
+    for the null block. The page operands (R = 1) take the table as it is."""
+    first = xp.arange(tables.shape[1], dtype=xp.int32) * block_size
+    return xp.where(first[None, :] <= (off + chunk - 1)[:, None], tables, 0)
+
+
 def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
                block_size, run_pages=1):
     """((seg, tile, flags, pages), visited): the call's compacted work
@@ -397,6 +430,8 @@ def _work_list(tables, off, win, *, chunk, block_q, n_qblocks, tile_pages,
     flags = jnp.where(
         step < n_live, _WORK + _FIRST * (k == 0) + _LAST * (k == n_ - 1), 0
     ).astype(jnp.int32)
+    if run_pages > 1:
+        tables = _visible_pages(tables, off, chunk=chunk, block_size=block_size)
     pages = tables.reshape(B, n_tiles, Tp)[seg // n_qblocks, tile]
     runs = (_run_bits(pages, run_pages),) if run_pages > 1 else ()
     pages = pages.reshape(-1)
@@ -412,14 +447,16 @@ def read_counts(tables, offsets, window, *, heads, group, chunk, head_dim,
     """(live, stepped, in_run, single) of ONE layer's call on host integers:
     the work items the read's grid does and the grid steps it takes, head
     groups included, and the pages (table entries that are not the null
-    block) its items bring in a run copy / one by one — the same _tile_plan,
-    _live_tiles and _run_bits arithmetic the call runs on the device, on
+    block, nor — under the kernel's own copies — past the row's frontier:
+    _visible_pages) its items bring in a run copy / one by one — the same
+    _tile_plan, _live_tiles and _run_bits arithmetic the call runs on the device, on
     numpy ``tables`` [B, MB] and ``offsets`` [B]. ``heads`` is the KV heads
     a shard holds, ``head_dim`` the pool's. For the scheduler's
     engine.kv_tiles and engine.kv_pages_read counters."""
     import numpy as np
 
     tables = np.asarray(tables, np.int32)
+    offsets = np.asarray(offsets, np.int32)
     B, MB = tables.shape
     Th, Tp, bq, R = _tile_plan(
         heads, group, chunk, head_dim, block_size, MB, itemsize, quantized,
@@ -429,11 +466,14 @@ def read_counts(tables, offsets, window, *, heads, group, chunk, head_dim,
         tables = np.pad(tables, ((0, 0), (0, -MB % Tp)))
     n_qblocks = _round_up(group * chunk, bq) // bq
     lo, n = _item_counts(
-        tables, np.asarray(offsets, np.int32), np.int32(window), chunk=chunk,
+        tables, offsets, np.int32(window), chunk=chunk,
         block_q=bq, n_qblocks=n_qblocks, tile_pages=Tp, block_size=block_size,
         xp=np,
     )
     groups = heads // Th
+    if R > 1:  # the copies follow the pages a query can see (_visible_pages)
+        tables = _visible_pages(
+            tables, offsets, chunk=chunk, block_size=block_size, xp=np)
     tiles = tables.reshape(B, -1, Tp)
     # a tile's mapped pages, and those of its copy groups that are runs
     a_tile = np.zeros((B, tiles.shape[1], 2), np.int64)
@@ -474,7 +514,8 @@ def _ragged_kernel(
     #                pool block at entry p of the step's table tile ([Th, BS, W]
     #                of a latent pool: Th is its unit axis)
     #                — or, run_pages > 1, ONE operand: the pool where it lies
-    #                (pl.ANY), its tiles brought by the kernel's own copies
+    #                (pl.ANY), its tiles brought by the kernel's own copies:
+    #                whole pages of a float pool on the lanes (_tile_plan)
     #   o_ref        [1, Th, BQ, hd]
     #   m_ref        VMEM [Th, BQ, 128] f32 running max
     #   l_ref        VMEM [Th, BQ, 128] f32 running sum
@@ -483,6 +524,7 @@ def _ragged_kernel(
     #   kdq_ref, vdq_ref  VMEM [Th, Tp*BS, hd] compute dtype
     # or, run_pages > 1, where the copies land and what they signal:
     #   buf_ref      VMEM [2, Tp, 2, Th, BS, hd] this step's tile, the next's
+    #                ([2, Tp, 1, BS, W] of a latent pool: pages as they lie)
     #   sem_ref      DMA semaphores [2], one a buffer
     sm_scale: float,
     softcap: float,
@@ -523,7 +565,8 @@ def _ragged_kernel(
         last token: its keys are masked, whatever the buffer holds there).
         Every copy of a buffer signals its one semaphore, which counts what
         arrived: a group with no null entry is waited for as a whole,
-        however it was started."""
+        however it was started. (A page past the row's causal frontier
+        arrives here AS a null entry: _visible_pages.)"""
         src = pool_ref.at[lay_ref[0]] if stacked else pool_ref
         word = runs_ref[t]
 
@@ -587,14 +630,12 @@ def _ragged_kernel(
         off = off_ref[b]
         win = win_ref[0]
         q = q_ref[0]  # [Th, BQ, hd]
-        if R > 1:
+        if R > 1:  # the tile's pages where the copies put them
             tile_copies(step, slot, wait=True)
-            k, v = (
-                jnp.concatenate(
-                    [buf_ref[slot, p, half] for p in range(Tp)], axis=1)
-                for half in (0, 1)
-            )
-        elif quantized:
+            tile = [buf_ref.at[slot, p] for p in range(Tp)]
+        else:
+            tile = page_refs
+        if quantized:
             # every key (value) row of a page shares ONE scale per kv head:
             # the wrapper pre-gathered the per-page scales through the
             # block tables to [2, Hkv, B, MBp], so the item's row and tile
@@ -604,17 +645,17 @@ def _ragged_kernel(
                     rows = pl.ds(p * BS, BS)
                     for half, out_ref in enumerate(dq_refs):
                         out_ref[h, rows] = (
-                            page_refs[p][half, h].astype(jnp.float32)
+                            tile[p][half, h].astype(jnp.float32)
                             * scale_ref[half, h0 + h, b, j * Tp + p]
                         ).astype(out_ref.dtype)
 
             jax.lax.fori_loop(0, Th, dequant, None)
             k, v = (r[...] for r in dq_refs)
         elif v_width:
-            k = jnp.concatenate([r[...] for r in page_refs], axis=1)
+            k = jnp.concatenate([r[...] for r in tile], axis=1)
             v = k[:, :, :v_width]
         else:
-            k, v = (jnp.concatenate([r[half] for r in page_refs], axis=1)
+            k, v = (jnp.concatenate([r[half] for r in tile], axis=1)
                     for half in (0, 1))
         # all Th heads in one batched dot: their dot -> softmax -> dot
         # chains are independent, and the compiler interleaves them
@@ -801,7 +842,7 @@ def ragged_paged_attention(
     if R > 1:
         pool_specs = [pl.BlockSpec(memory_space=pl.ANY)]
         tile_scratch = [
-            pltpu.VMEM((2, Tp, 2, Th, BS, hd), pool.dtype),
+            pltpu.VMEM((2, Tp, *parts[:-1], Th, BS, hd), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ]
     else:

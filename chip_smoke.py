@@ -927,6 +927,23 @@ READ_RUNS_CASES = {
     "st_decode": dict(B=32, T=1, lengths=(4300, 8400)),
     "st_chunk_2048": dict(B=1, T=2048, lengths=(6144, 8192)),
 }
+# The pools PR 59 moved onto the kernel's own copies, at their cells' decode
+# shapes: (H, Hkv, pool width), table width, the contexts of the cell's mix,
+# and ``ahead``: every row owns 0-2 blocks past its offset (the decode
+# window's), which the page operands copy and the kernel's own copies skip
+OWN_COPIES_CASES = {
+    # ouro-2.6b: 16 MHA heads x 128, pages of 128 KB, a tile of 8
+    "ouro_decode": dict(B=16, T=1, heads=(16, 16, 128), MB=32, lengths=(40, 250),
+                        ahead=2),
+    # joyai's latent rows: 32 heads over ONE 640-lane row, pages of 20 KB
+    "joyai_decode": dict(B=64, T=1, heads=(32, 1, 640), MB=32, v_width=512,
+                         lengths=(60, 480), ahead=2),
+    # phi-3-mini's lane-aligned pool: 32 MHA heads x 128, pages of 256 KB
+    "phi3_decode": dict(B=16, T=1, heads=(32, 32, 128), MB=32, lengths=(40, 420),
+                        ahead=2),
+    "phi3_long_decode": dict(B=4, T=1, heads=(32, 32, 128), MB=128,
+                             lengths=(1560, 1850), ahead=2, layers=16),
+}
 
 
 def _bare_read_us(n_bytes: int, piece_bytes: int = 2**20) -> float:
@@ -976,14 +993,18 @@ def _read_runs_case(case: dict, window: int, run_bytes=(None, 0), layers: int = 
 
     from bee2bee_tpu.ops import ragged
 
-    H, Hkv, hd, BS, MB = 28, 4, 128, KERNEL_BLOCK, 1024
+    (H, Hkv, hd), BS = case.get("heads", (28, 4, 128)), KERNEL_BLOCK
+    MB, v_width = case.get("MB", 1024), case.get("v_width")
     B, T = case["B"], case["T"]
     rng = np.random.default_rng(SEED)
     lengths = rng.integers(case["lengths"][0], case["lengths"][1] + 1, size=B)
-    pages = -(-lengths // BS)
+    # (a row's table maps the blocks its tokens fill and those it owns ahead)
+    pages = np.minimum(
+        -(-lengths // BS) + rng.integers(0, case.get("ahead", 0) + 1, size=B), MB)
     NB = int(pages.sum()) + 1
     key = jax.random.key(SEED)
-    pool = jax.random.normal(key, (layers, NB, 2, Hkv, BS, hd), jnp.bfloat16)
+    parts = (1,) if v_width else (2, Hkv)  # a latent row's unit axis
+    pool = jax.random.normal(key, (layers, NB, *parts, BS, hd), jnp.bfloat16)
     q = jax.random.normal(jax.random.fold_in(key, 1), (B, T, H, hd), jnp.bfloat16)
     off = jnp.asarray(lengths - T, jnp.int32)
     runs = np.zeros((B, MB), np.int32)
@@ -1001,26 +1022,33 @@ def _read_runs_case(case: dict, window: int, run_bytes=(None, 0), layers: int = 
         def read(q, pool, table, off):
             def one(acc, li):
                 out = ragged.ragged_paged_attention(
-                    q, pool, table, off, window=window, interpret=False, layer=li)
+                    q, pool, table, off, window=window, interpret=False, layer=li,
+                    v_width=v_width)
                 return acc + out.astype(jnp.float32), None
             return jax.lax.scan(
-                one, jnp.zeros((B, T, H * hd), jnp.float32),
+                one, jnp.zeros((B, T, H * (v_width or hd)), jnp.float32),
                 jnp.arange(layers, dtype=jnp.int32))[0]
         return jax.jit(read)
 
     shapes = dict(heads=Hkv, group=H // Hkv, chunk=T, head_dim=hd, block_size=BS,
-                  itemsize=2)
-    # the pages a call's items bring: the same under every copy group
+                  itemsize=2, latent=bool(v_width))
+    page_bytes = len(parts) * Hkv * BS * hd * 2
+    # the pages a call's items bring under the planned copy group (the page
+    # operands bring every entry of a live tile: ``pages`` below), and the
+    # bytes of the tokens a query can see (the roofline's numerator)
     moved = sum(ragged.read_counts(runs, np.asarray(off), window, **shapes)[2:]
-                ) * 2 * Hkv * BS * hd * 2
+                ) * page_bytes
+    seen = np.minimum(lengths, window) if window else lengths
     line: dict = {"B": B, "T": T, "window": window, "table_width": MB,
-                  "layers": layers, "bytes_per_call": moved}
+                  "layers": layers, "bytes_per_call": moved,
+                  "bytes_needed_per_call": int(seen.sum()) * page_bytes // BS}
     outs = {}
     for budget in run_bytes:
         patch = mock.patch.object(
             ragged, "_RUN_BYTES", ragged._RUN_BYTES if budget is None else budget)
         with patch:
-            R = ragged._tile_plan(Hkv, H // Hkv, T, hd, BS, MB, 2, False)[3]
+            R = ragged._tile_plan(Hkv, H // Hkv, T, hd, BS, MB, 2, False,
+                                  latent=bool(v_width))[3]
             counts = ragged.read_counts(runs, np.asarray(off), window, **shapes)
             fn = every_layer()  # the budget is read at trace time
             for name, (pl_, tb) in tables.items():
@@ -1045,6 +1073,8 @@ def _read_runs_case(case: dict, window: int, run_bytes=(None, 0), layers: int = 
     line["worst_diff_over_tolerance_vs_page_operands"] = float(max(
         np.max(np.abs(o - base) / (layers * (KERNEL_ATOL + KERNEL_RTOL * np.abs(base / layers))))
         for o in outs.values()))
+    line["bit_equal_to_page_operands"] = all(
+        np.array_equal(o, outs[1, name]) for (_, name), o in outs.items())
     line["ok"] = bool(line["bit_equal_runs_vs_permuted"]
                       and line["worst_diff_over_tolerance_vs_page_operands"] <= 1.0)
     return line
@@ -1057,6 +1087,18 @@ def _read_runs_cases() -> dict:
     cases = {
         f"{name}_window{window}": _read_runs_case(case, window)
         for name, case in READ_RUNS_CASES.items() for window in (4096, 0)
+    }
+    return {**cases, "ok": all(c["ok"] for c in cases.values())}
+
+
+def _own_copies_cases() -> dict:
+    """Case ``own_copies`` (PR 59): the read alone at ouro's, joyai's and
+    phi-3's decode shapes, rows that own blocks ahead of their offsets: the
+    kernel's own copies (no copy past the frontier) against the page operands
+    those pools had (``_RUN_BYTES`` 0), run tables and permuted ones."""
+    cases = {
+        name: _read_runs_case(case, 0, layers=case.get("layers", 32))
+        for name, case in OWN_COPIES_CASES.items()
     }
     return {**cases, "ok": all(c["ok"] for c in cases.values())}
 
@@ -1082,6 +1124,7 @@ def child_kernel() -> None:
     line["cases"]["granite_state_step"] = _state_step_case(
         dev.device_kind, "granite-4.0-h-small-10l-e36")
     line["cases"]["st_read_runs"] = _read_runs_cases()  # PR 53
+    line["cases"]["own_copies"] = _own_copies_cases()  # PR 59
     # JoyAI-LLM-Flash (PR 39): the latent pool's kernels, the grouped product
     line["cases"]["joyai_latent_decode"] = _latent_case(64, 1, 64, 700)
     line["cases"]["joyai_latent_decode_table8"] = _latent_case(64, 1, 8, 120)

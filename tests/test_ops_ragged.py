@@ -707,7 +707,8 @@ def test_work_counts_equal_the_devices_item_count_at_the_new_plan(case):
 
 # ------------------------- a run of adjacent pages is ONE copy (PR 53)
 #
-# Where a page is under 128 KB the read takes the pool as ONE operand and
+# Where a step takes whole lane-aligned pages of a float pool (under 128 KB
+# until PR 59, of any size since) the read takes the pool as ONE operand and
 # starts its own copies: a copy group of R table entries whose pool blocks
 # are adjacent arrives as one copy, any other page by page, a null entry not
 # at all. The cases below are small (16 pages a row, 2-3 rows) and share six
@@ -841,19 +842,118 @@ def test_host_page_counts_equal_the_work_lists_run_bits(tables, monkeypatch):
     assert got[2] + got[3] > 0
 
 
+# Since PR 59 the kernel's own copies serve every lane-aligned bf16 pool whose
+# step takes whole pages — ouro's 128 KB pages (16 MHA heads x 128: the tile
+# of 8 is the copy group) and joyai's latent rows (640 = 5 x 128 lanes, the
+# tile of 16) — and start no copy for a page past the row's causal frontier:
+# the blocks a row owns AHEAD of its offset for the decode window.
+OWN_COPY_POOLS = {
+    "ouro-mha-16x128": dict(H=16, Hkv=16, hd=128, v_width=None, R=8),
+    "joyai-latent-640": dict(H=32, Hkv=1, hd=640, v_width=512, R=16),
+}
+# (how a row's table is filled, blocks it owns ahead of its offset)
+OWN_COPY_TABLES = {
+    "runs-null-tail": ("run", 0),
+    "runs-pages-owned-ahead": ("run", 2),
+    "descending-pages-owned-ahead": ("descending", 2),
+    "broken-mid-group-owned-ahead": ("broken", 1),
+}
+
+
+def _own_copy_case(pool, tables):
+    """(q, pool [NB, parts.., BS, hd], tables, off, blocks owned ahead): one
+    decode query a row of 38, 128 and 201 tokens (null entries mid-tile, at a
+    tile's edge, a tile and a half) whose tables map ``ahead`` more blocks
+    than their tokens fill."""
+    c, (kind, ahead) = OWN_COPY_POOLS[pool], OWN_COPY_TABLES[tables]
+    lengths = np.asarray([38, 128, 201], np.int32)
+    rng = np.random.default_rng(len(pool) + len(tables))
+    tb, NB = _run_tables(kind, [int(n) + ahead * RUN_BS for n in lengths], rng)
+    parts = (1,) if c["v_width"] else (2, c["Hkv"])
+    kv = jnp.asarray(rng.standard_normal((NB, *parts, RUN_BS, c["hd"])), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((3, 1, c["H"], c["hd"])), jnp.float32)
+    owned_ahead = [
+        int(blk) for b, n in enumerate(lengths)
+        for blk in tb[b, -(-int(n) // RUN_BS):] if blk]
+    assert len(owned_ahead) == 3 * ahead
+    return q, kv, tb, lengths - 1, owned_ahead
+
+
+@pytest.mark.parametrize("tables", sorted(OWN_COPY_TABLES))
+@pytest.mark.parametrize("pool", sorted(OWN_COPY_POOLS))
+def test_own_copies_of_large_pages_and_latent_rows_equal_the_page_operands(
+        pool, tables, monkeypatch):
+    """ouro's and joyai's decode call under the kernel's own copies
+    (interpret mode) against the SAME call as page operands (a copy budget
+    of 0: R = 1, the form both had before PR 59): bit for bit, with null
+    entries inside a live tile, blocks owned ahead of the offset and a
+    descending table. And no copy is started for a block past the frontier:
+    NaN in those blocks never reaches the output (the page operands would
+    bring them, behind a zero weight that NaN survives)."""
+    from bee2bee_tpu.ops import ragged
+
+    c = OWN_COPY_POOLS[pool]
+    q, kv, tb, off, owned_ahead = _own_copy_case(pool, tables)
+    plan = dict(latent=bool(c["v_width"]))
+    G = c["H"] // c["Hkv"]
+
+    def read(kv):  # (the budget is read at trace time: no jit to key on it)
+        return np.asarray(ragged_paged_attention(
+            q, kv, jnp.asarray(tb), jnp.asarray(off), v_width=c["v_width"]))
+
+    Th, Tp, _, R = ragged._tile_plan(c["Hkv"], G, 1, c["hd"], RUN_BS, RUN_MB, 4, False, **plan)
+    assert (Th, R, Tp) == (c["Hkv"], c["R"] // 2, c["R"] // 2)  # float32: half the bf16 group
+    got = read(kv)
+    poisoned = read(kv.at[np.asarray(owned_ahead, np.int32)].set(jnp.nan)
+                    if owned_ahead else kv)
+    assert np.isfinite(poisoned).all() and np.array_equal(got, poisoned)
+    monkeypatch.setattr(ragged, "_RUN_BYTES", 0)
+    assert ragged._tile_plan(c["Hkv"], G, 1, c["hd"], RUN_BS, RUN_MB, 4, False, **plan)[3] == 1
+    assert np.array_equal(got, read(kv))
+
+
+@pytest.mark.parametrize("pool", sorted(OWN_COPY_POOLS))
+def test_read_counts_count_no_page_past_the_frontier(pool):
+    """engine.kv_pages_read follows the copies: of a row's mapped blocks
+    only those that hold a position at or under its last query's are
+    counted, in a run or singly; the blocks owned ahead are not. The page
+    operands (an int8 pool's here) bring every mapped entry of a live tile."""
+    from bee2bee_tpu.ops.ragged import read_counts
+
+    c = OWN_COPY_POOLS[pool]
+    kw = dict(heads=c["Hkv"], group=c["H"] // c["Hkv"], chunk=1,
+              head_dim=c["hd"], block_size=RUN_BS, itemsize=2,
+              latent=bool(c["v_width"]))
+    R = c["R"]
+    tb = np.zeros((3, 32), np.int32)
+    tb[0, :R] = 8 + np.arange(R)  # a whole run, every page visible
+    tb[1, :R] = 64 + np.arange(R)  # a whole run, its last 2 pages ahead
+    tb[2, :5] = 128 + np.arange(5)[::-1]  # 5 single pages, 2 of them ahead
+    off = np.asarray([R * RUN_BS - 1, (R - 2) * RUN_BS - 1, 3 * RUN_BS - 1])
+    live, stepped, in_run, single = read_counts(tb, off, 0, **kw)
+    assert (live, stepped) == (3, 3 * 32 // R)
+    assert (in_run, single) == (R, (R - 2) + 3)
+    # one position on, each row's next block comes into sight
+    assert read_counts(tb, off + 1, 0, **kw)[2:] == (R, (R - 1) + 4)
+    if not c["v_width"]:
+        assert read_counts(tb, off, 0, **{**kw, "quantized": True})[2:] == (0, 2 * R + 5)
+
+
 # (heads a shard holds, group, head size, quantized, latent) -> R at 16-token
-# bf16 pages and a wide table: what decides is the page's BYTES, never a name
+# bf16 pages and a wide table: what decides is whether a page can be copied
+# WHOLE (a bf16 stretch on the lanes, every KV head in the step), never its
+# size (PR 59) and never a name
 RUN_GROUPS = {
     "st-gqa-28-4-32KB": ((4, 7, 128, False, False), 32),
     "h1-gqa-20-4-32KB": ((4, 5, 128, False, False), 32),
     "granite-8-kv-heads-64KB": ((8, 4, 128, False, False), 16),
     "mistral-shard-2-kv-heads-16KB": ((2, 4, 128, False, False), 32),
-    "phi3-mha-32-lane-aligned-256KB": ((32, 1, 128, False, False), 1),
-    "ouro-mha-16-128KB": ((16, 1, 128, False, False), 1),
+    "phi3-mha-32-lane-aligned-256KB": ((32, 1, 128, False, False), 4),
+    "ouro-mha-16-128KB": ((16, 1, 128, False, False), 8),
     "phi3-head-96-off-the-lanes": ((32, 1, 96, False, False), 1),
     "gpt2-head-64-off-the-lanes": ((12, 1, 64, False, False), 1),
     "int8-pool": ((4, 7, 128, True, False), 1),
-    "joyai-latent-rows": ((1, 32, 640, False, True), 1),
+    "joyai-latent-rows": ((1, 32, 640, False, True), 16),
 }
 
 
@@ -867,9 +967,14 @@ def test_copy_group_follows_the_pages_bytes(case):
                                   latent=latent)
         if T == 1:
             assert R == want
-        assert R == 1 or (Th == Hkv and 2 * Th * 16 * hd * 2 * R <= _RUN_BYTES)
+        halves = 1 if latent else 2  # a latent page holds no V
+        assert R == 1 or (
+            Th == Hkv and halves * Th * 16 * hd * 2 * R <= _RUN_BYTES)
         assert R in (1, Tp)  # what the chip chose: the whole tile, or a page
         assert want > 1 or R == 1
+        # the 2,048 chunk of 16 or 32 MHA heads takes SOME heads a step: a
+        # page is pieces there, and the page operands stay
+        assert (R == 1) == (want == 1 or Th < Hkv)
 
 
 

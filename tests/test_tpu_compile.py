@@ -32,7 +32,7 @@ from bee2bee_tpu.models import core, partition
 from bee2bee_tpu.models.config import get_config
 from bee2bee_tpu.ops.flash import flash_attention
 from bee2bee_tpu.ops.ragged import (
-    make_ragged_attn_fn, paged_kv_write, ragged_paged_attention,
+    _tile_plan, make_ragged_attn_fn, paged_kv_write, ragged_paged_attention,
 )
 from bee2bee_tpu.ops.ssm_step import ssm_state_step
 from bee2bee_tpu.parallel.mesh import AXES
@@ -149,6 +149,21 @@ RAGGED_CASES = {
         "smallthinker-21b-a3b-8l", dict(B=4, T=1, MB=1024, layers=2, window=4096)),
     "smallthinker-window-read-chunk": (
         "smallthinker-21b-a3b-8l", dict(B=1, T=2048, MB=1024, layers=2, window=4096)),
+    # PR 59: pages of 128 KB and more on the kernel's own copies — ouro's
+    # decode call and 64 bucket (16 MHA heads x 128: a tile of 8 pages is ONE
+    # copy group), phi-3's lane-aligned decode above (4 pages of 256 KB); its
+    # 128 bucket takes 8 of the 16 heads a step and keeps the page operands
+    "ouro-2.6b-decode-stacked": ("ouro-2.6b", dict(B=16, T=1, MB=32, layers=2)),
+    "ouro-2.6b-prefill-64-stacked": ("ouro-2.6b", dict(B=4, T=64, MB=32, layers=2)),
+    "ouro-2.6b-prefill-128-stacked": ("ouro-2.6b", dict(B=4, T=128, MB=32, layers=2)),
+}
+# the copy group (ops/ragged._tile_plan's R) a case's call must plan: which
+# form compiled above follows from the shapes
+RAGGED_COPY_GROUPS = {
+    "ouro-2.6b-decode-stacked": 8, "ouro-2.6b-prefill-64-stacked": 8,
+    "ouro-2.6b-prefill-128-stacked": 1, "phi-3-mini-decode-stacked": 4,
+    "phi-3-mini-decode": 1, "phi-3-mini-prefill-2048-stacked": 1,
+    "falcon-h1-decode-stacked": 32, "smallthinker-window-read-decode": 32,
 }
 
 
@@ -164,6 +179,11 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, case):
         *_ragged_args(one_chip, **_heads(model), **shape),
     )
     assert "tpu_custom_call" in text
+    if case in RAGGED_COPY_GROUPS:
+        h = _heads(model)
+        hd = -(-h["hd"] // 128) * 128 if shape.get("layers") else h["hd"]
+        assert _tile_plan(h["Hkv"], h["H"] // h["Hkv"], shape["T"], hd, BS,
+                          shape["MB"], 2, False)[3] == RAGGED_COPY_GROUPS[case]
 
 
 @pytest.mark.parametrize("model,B,MB", [("gemma-2b", 8, 8), ("phi-3-mini", 16, 32)])
@@ -540,6 +560,9 @@ LATENT_CASES = {
     "decode-64-rows": (64, 1, 64),
     "decode-table-8": (64, 1, 8),
     "prefill-512": (1, 512, 32),
+    # PR 59: the cell's decode call (tables 32 wide) — a tile of 16 latent
+    # pages of 20 KB is ONE copy group of the kernel's own copies
+    "decode-table-32": (64, 1, 32),
 }
 
 
@@ -547,10 +570,13 @@ LATENT_CASES = {
 def test_latent_write_and_read_compile_for_v5e(one_chip, case):
     """One latent row a token (576 stored in 640 lanes), a unit axis for heads:
     the page-write stores it in place (aliased), the read fetches a page tile
-    ONCE — one pool operand a table entry, no V operands — and returns the 32
-    heads' 512-wide latent outputs."""
+    ONCE — since PR 59 by the kernel's own copies out of the pool where it
+    lies (640 = 5 x 128 lanes: a page is one aligned stretch), no V — and
+    returns the 32 heads' 512-wide latent outputs."""
     B, T, MB = LATENT_CASES[case]
     W, R, H = JOYAI.latent_width, JOYAI.mla_kv_rank, JOYAI.n_heads
+    Tp, _, group = _tile_plan(1, H, T, 640, BS, MB, 2, False, latent=True)[1:]
+    assert group == Tp == min(16, MB)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
